@@ -15,9 +15,10 @@ The proof reads the LOWERED computation, not the python: jax marks
 input-output aliasing in the StableHLO ``@main`` signature as
 ``{tf.aliasing_output = K}`` arg attributes. A carry must carry that
 attribute whenever a shape/dtype-compatible output slot exists for it
-(a donated input whose shape matches no remaining output — e.g. a
-ladder run consumed by a pad-up merge — cannot alias anything and is
-exempt: donation still releases its buffer, but no copy is saved).
+(a carry whose shape matches no remaining output — e.g. a ladder run
+folded into the seen merge — cannot alias anything and is exempt: the
+engines build such inputs undonated by declaration, see
+checker/util.py jit_with_donation).
 
 Coverage vs budget: the full device + sharded + LSM surface is lowered
 for one family (raft); for the other five families the fused wave
@@ -81,15 +82,7 @@ def tensor_bytes(type_str: str) -> int:
 def audit_entry(entry: dict, scope: str, findings: list) -> None:
     """Lower one audit entry and check its declared carries/pins
     against the ``tf.aliasing_output`` attributes in the result."""
-    import warnings
-
-    with warnings.catch_warnings():
-        # alias-impossible donations (pad-up merges, CPU truncate-
-        # merges) warn at lowering; the span check below reasons about
-        # them explicitly
-        warnings.filterwarnings(
-            "ignore", message="Some donated buffers were not usable")
-        txt = entry["fn"].lower(*entry["args"]).as_text()
+    txt = entry["fn"].lower(*entry["args"]).as_text()
     args, results = parse_main_aliasing(txt)
     path, line = entry["site"]
     # output slots by type, minus the slots aliased args already consume
@@ -115,7 +108,7 @@ def audit_entry(entry: dict, scope: str, findings: list) -> None:
         if avail.get(ty, 0) <= 0:
             # no compatible output slot remains — aliasing is
             # impossible for this carry (e.g. ladder runs folded into
-            # a pad-up merge); donation still frees the buffer
+            # the seen merge), declared undonated by the engine
             continue
         avail[ty] -= 1
         per_wave = entry.get("per_wave", 1)

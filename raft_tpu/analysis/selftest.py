@@ -47,7 +47,7 @@ def undonated_carry():
 @contextlib.contextmanager
 def open_signature():
     """Skew the runtime merge-target chooser off the precompiled
-    ladder — the BENCH_r05 retrace cliff, reintroduced."""
+    ladder — the round-5 retrace cliff, reintroduced."""
     from ..checker.device_bfs import DeviceBFS
 
     orig = DeviceBFS._seen_size_for

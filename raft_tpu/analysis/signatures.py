@@ -1,10 +1,10 @@
 """Signature-closure auditor: prove a deep run dispatches only
 precompiled program signatures — the retrace-cliff class, symbolically.
 
-The BENCH_r05 depth-32 cliff was one mid-run compile: a seen merge
-whose target outgrew the concat total left a non-ladder-size run, and
-the next wave retraced the whole wave program at a never-precompiled
-shape (~117 s of a 152.6 s wave). The engine now precompiles exactly
+Round 5's depth-32 wave-time cliff was one mid-run compile: a seen
+merge whose target outgrew the concat total left a non-ladder-size run,
+and the next wave retraced the whole wave program at a
+never-precompiled shape (most of that wave's wall time). The engine now precompiles exactly
 ``DeviceBFS.signature_inventory()``; this pass independently recomputes
 the REACHABLE signature set from the geometry primitives and proves the
 two are equal:

@@ -22,7 +22,7 @@ from dataclasses import dataclass, field
 import jax
 import numpy as np
 
-from ..obs import MemWatch, NULL_TELEMETRY
+from ..obs import MemWatch, NULL_TELEMETRY, device_budget
 from ..obs.events import hashv_of
 from ..ops.hashing import U64_MAX
 from ..ops.symmetry import Canonicalizer
@@ -252,7 +252,10 @@ class BFSChecker:
         tl_every = int(getattr(tel, "timeline_every", 0) or 0)
         tl_wave_s: list[float] = []
         fused_wave_s: list[float] = []
-        memwatch = MemWatch(tel) if tel.active else None
+        memwatch = (
+            MemWatch(tel, device_budget(jax.devices()[0]))
+            if tel.active else None
+        )
         tel_s_last = 0.0
         while len(frontier) and violation is None:
             if preempt is not None and preempt.requested:

@@ -212,7 +212,6 @@ class LivenessChecker:
                 # ---- pass B: fetch exactly the new states' vectors.
                 # lanes are padded to power-of-two buckets so jit compiles
                 # a handful of shapes, not one per distinct new-count
-                # (the remote-compile service costs ~20 s per shape)
                 nf_wave_lane = np.nonzero(nf_mask)[0][first_u]  # per uq
                 new_states = np.empty((new_count, W), np.int32)
                 bounds = np.cumsum([0] + [len(v) for v in chunk_vidx])

@@ -71,25 +71,30 @@ def emit_append(buf, block, count, n_new, cap: int):
     return buf, count + n_new > cap
 
 
-def jit_with_donation(fn, donate_argnums, probe_args, **jit_kw):
-    """``jax.jit(fn, donate_argnums=...)`` when the backend can actually
-    alias the donated buffers, a plain ``jax.jit(fn)`` otherwise.
+def jit_with_donation(fn, donate_argnums, name, warm_args, **jit_kw):
+    """``jax.jit(fn, donate_argnums=...)``, compiled and run once on
+    ``warm_args()``, with a refused donation an error naming the program.
 
-    XLA only reports an unusable donation as a UserWarning at the first
-    EXECUTION (e.g. a sort-concat-truncate merge never aliases on the
-    CPU backend even at matching sizes), so the compiled program is
-    probed once on throwaway buffers — fresh from ``probe_args()``,
-    because a successful donation consumes them. Production calls then
-    never warn and never silently copy a buffer the caller believed was
-    updated in place.
+    JAX decides at lowering, from shapes alone and the same way on every
+    backend, whether a donated input can take an output's place; one
+    that cannot is reported as a UserWarning and silently kept alive —
+    the caller then holds old + new where it believed the update was in
+    place. Callers therefore DECLARE donation only for inputs an output
+    can alias (same shape and dtype; see RunLSM.merge_spec and
+    DeviceBFS._seen_merge_spec) and build everything else undonated, so
+    a warning here means the declaration is wrong. ``warm_args`` must
+    return fresh throwaway buffers: a successful donation consumes them.
     """
     jitted = jax.jit(fn, donate_argnums=donate_argnums, **jit_kw)
-    with warnings.catch_warnings(record=True) as caught:
-        warnings.simplefilter("always")
-        out = jitted(*probe_args())
-        jax.block_until_ready(out)
-    if any("donated" in str(w.message) for w in caught):
-        return jax.jit(fn, **jit_kw)
+    with warnings.catch_warnings():
+        warnings.filterwarnings(
+            "error", message="Some donated buffers were not usable")
+        try:
+            jax.block_until_ready(jitted(*warm_args()))
+        except UserWarning as w:
+            raise RuntimeError(
+                f"device program {name}: donation refused — {w}"
+            ) from None
     return jitted
 
 

@@ -40,7 +40,7 @@ from .events import (
     validate_event,
     validate_lines,
 )
-from .memwatch import MemWatch, budget_from_env
+from .memwatch import MemWatch, budget_from_env, device_budget
 from .progress import ProgressRenderer, format_count
 from .trace import TraceHooks
 
@@ -71,6 +71,7 @@ __all__ = [
     "budget_from_env",
     "coverage_digest",
     "dead_actions",
+    "device_budget",
     "format_count",
     "hashv_of",
     "render_coverage_table",

@@ -90,7 +90,7 @@ TIMELINE_STAGES = (
 # contiguous cursor-append emit landed, bytes it wrote, and frontier-
 # buffer occupancy (worst shard; 0.0 on the unbounded host engine) — so
 # the stall watchdog can tell an emit-bound or growth/recompile wave
-# from a compute-bound one (the depth-32 cliff of BENCH_r05.json was
+# from a compute-bound one (round 5's depth-32 wave-time cliff was
 # attributed with exactly these gauges).
 # enabled_density/expand_budget_ovf (guard-first sparse expansion):
 # enabled fraction of the dense [chunk, A] candidate grid this wave

@@ -11,21 +11,25 @@ event whenever a wave sets a new watermark (so the stream stays
 low-volume and peak_bytes is monotone within a run by construction).
 
 ``frac`` = total live bytes / budget is the gauge the progress line
-renders (``hbm NN%``) and the wave event carries (``hbm_frac``). The
-budget defaults to the ``RAFT_TPU_HBM_BUDGET`` environment variable
-(bytes) or 16 GiB — one TPUv4 core's HBM — because the point of the
-gauge on a CPU dry-run is to predict where the same geometry will sit
-on the real chip. A frac above 1.0 is legal and is exactly the signal
-ROADMAP item 2 (out-of-core BFS) plans from.
+renders (``hbm NN%``) and the wave event carries (``hbm_frac``). On an
+accelerator the budget is what the device itself reports
+(``memory_stats()["bytes_limit"]``; a device that reports none is an
+error, not a guess). On the CPU backend — a dry run whose point is to
+predict where the same geometry will sit on a chip — it is the
+``RAFT_TPU_HBM_BUDGET`` environment variable (bytes) or 16 GiB, one
+TPU v5e chip's HBM. A frac above 1.0 is legal and is exactly the signal
+out-of-core planning starts from.
 
-Dependency-free (no jax/numpy): byte math is host ints.
+Dependency-free (no jax/numpy): byte math is host ints, and the device
+is whatever object the engine hands in.
 """
 
 from __future__ import annotations
 
 import os
 
-# one TPUv4 core's HBM; override with RAFT_TPU_HBM_BUDGET (bytes)
+# CPU dry runs only: one TPU v5e chip's HBM; RAFT_TPU_HBM_BUDGET (bytes)
+# overrides
 DEFAULT_BUDGET_BYTES = 16 << 30
 
 
@@ -36,6 +40,19 @@ def budget_from_env(default: int = DEFAULT_BUDGET_BYTES) -> int:
     except ValueError:
         return default
     return v if v > 0 else default
+
+
+def device_budget(device) -> int:
+    """The HBM budget of the ``jax.Device`` a run's buffers live on."""
+    if device.platform == "cpu":
+        return budget_from_env()
+    limit = (device.memory_stats() or {}).get("bytes_limit")
+    if not limit:
+        raise RuntimeError(
+            f"{device.platform} device {device.device_kind!r} reports no "
+            "memory_stats()['bytes_limit']; cannot size the HBM budget"
+        )
+    return int(limit)
 
 
 class MemWatch:
@@ -50,6 +67,8 @@ class MemWatch:
 
     def __init__(self, tel=None, budget_bytes: int | None = None):
         self.tel = tel
+        # engines pass device_budget(<their device>); None is the CPU
+        # dry-run budget
         self.budget_bytes = int(budget_bytes or budget_from_env())
         self.peak_bytes = 0
         self.peak_wave = 0

@@ -5,7 +5,7 @@ With ``--trace-dir=DIR`` every wave is bracketed by a
 shows one step per BFS wave) and the named host-side phases —
 ``precompile``, ``seen_merge``, ``checkpoint``, ``consolidate`` — carry
 ``TraceAnnotation`` spans whose names match the offline stage profiler's
-vocabulary (checker/profile.py), so a live trace and a PROFILE.md row
+vocabulary (checker/profile.py), so a live trace and a ``--profile`` row
 talk about the same things.
 
 Without a trace dir every hook degrades to a shared nullcontext — zero

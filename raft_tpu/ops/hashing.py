@@ -8,18 +8,17 @@ together with its position, lanes reduce, and a final mix finishes.
 v4 (round 5): all MIXING arithmetic runs as TWO INDEPENDENT 32-bit
 streams (murmur3-style fmix32 with distinct multiplicative constants and
 positional salts), combined into one u64 only at the end. Rationale,
-measured on this TPU backend (scripts/hash32_micro.py + /tmp chained
-micro-benches, round 5):
+measured on the TPU backend of round 5 (scripts/hash32_micro.py +
+chained micro-benches; not re-measured on the present installation):
 
   u64 multiply   ~150 ms / 12.5M lanes   (emulated/scalarized)
   u64 == / sort  ~55-58 ms / 12.5M       (comparator path)
   u32 mix stream  ~0.2 ms / 75M lanes    (native VPU)
 
 i.e. the v1-v3 splitmix64 hash paid a ~400x penalty on every lane, which
-is why canonicalization owned 96-98% of chunk time through round 4
-(VERDICT.md Weak #2/#3). Two independent 32-bit streams keep the
-2^-64-class collision budget (the audit's second hash family still
-fails independently via `seed`).
+is why canonicalization owned 96-98% of chunk time through round 4.
+Two independent 32-bit streams keep the 2^-64-class collision budget
+(the audit's second hash family still fails independently via `seed`).
 
 Empirical TPU rules encoded here (see also `sort_u64` / `ne_u64`):
   - never MULTIPLY u64 lanes -> u32-pair streams

@@ -808,6 +808,7 @@ class RaftOracle:
         total = 1
         distinct = 1
         depth_counts = [1]
+        terminal = 0  # expanded states with no successor (`-deadlock`)
         violation = None
         depth = 0
         while frontier and violation is None:
@@ -817,7 +818,9 @@ class RaftOracle:
                 break
             next_frontier = []
             for st in frontier:
-                for _label, s2 in self.successors(st):
+                succs = self.successors(st)
+                terminal += not succs
+                for _label, s2 in succs:
                     total += 1
                     key = self.canon(s2, symmetry)
                     if key in seen:
@@ -847,5 +850,6 @@ class RaftOracle:
             "distinct": distinct,
             "total": total,
             "depth_counts": depth_counts,
+            "terminal": terminal,
             "violation": violation,
         }

@@ -146,7 +146,7 @@ def row2():
     out["manifest"] = manifest_fields(tel)
     # round 6 provenance: (a) the emit is the compact+cursor-append path
     # (scripts/emit_micro.py measures it against the retired scatter);
-    # (b) the BENCH_r05 4.3x final-wave cliff at depth 32 was NOT emit
+    # (b) round 5's 4.3x final-wave cliff at depth 32 was NOT emit
     # cost — the seen truncate-merge's `[:target]` left a non-ladder-size
     # run when target > concat, forcing a full wave-program retrace at a
     # never-precompiled shape on the next wave. The merge now pads its
@@ -161,7 +161,7 @@ def row2():
                   "gauges in the metrics stream; scripts/expand_micro."
                   "py prices it against both dense baselines "
                   "(materialized and gather-fused)",
-        "final_wave_cliff": "BENCH_r05 depth-32 4.3x wave-time cliff "
+        "final_wave_cliff": "round-5 depth-32 4.3x wave-time cliff "
                             "diagnosed as a seen-merge shape retrace "
                             "(truncated non-ladder run size), fixed by "
                             "padding merged seen runs to the ladder "

@@ -21,7 +21,7 @@ Usage:
 
 Writes EMIT_MICRO.json at the repo root (device provenance + one row per
 (VC, FCAP) cell). scripts/profile_workloads.py --md-only folds the
-summary into PROFILE.md.
+summary into the profile report it writes.
 
 W defaults to 64 (not a workload's real row width) to keep the 4M-row
 cell around 1 GiB/buffer; pass --w to match a specific workload.

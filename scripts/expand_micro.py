@@ -52,7 +52,7 @@ Usage:
 
 Writes EXPAND_MICRO.json at the repo root (device provenance + one row
 per (chunk, vpg) cell). scripts/profile_workloads.py --md-only folds the
-summary into PROFILE.md.
+summary into the profile report it writes.
 """
 
 import argparse

@@ -6,7 +6,7 @@ TLC-style per-action coverage table, the frontier depth histogram, an
 occupancy sparkline over waves, and any stall events. Runs recorded
 with ``--timeline`` additionally get the wave-timeline observatory
 sections: a stage-share table aggregated over the sampled waves (the
-live counterpart of PROFILE.md's offline per-stage isolation), an
+live counterpart of ``--profile``'s offline per-stage isolation), an
 analytic HBM watermark digest from the memwatch events, and — on
 sharded runs — a per-shard critical-path table (work share, emigrant
 lanes/bytes, shard seconds, skew) from the shard_wave events.
@@ -96,7 +96,7 @@ def _fmt_bytes(n) -> str:
 
 def _render_timeline(out: list[str], events: list[dict], summ) -> None:
     """Stage-share table over the sampled --timeline waves. The live
-    counterpart of PROFILE.md's offline stage profile: these shares come
+    counterpart of ``--profile``'s offline stage profile: these shares come
     from real full-wave dispatches, not isolated micro-runs."""
     tls = [e for e in events if e["event"] == "timeline"]
     if not tls:
@@ -108,7 +108,7 @@ def _render_timeline(out: list[str], events: list[dict], summ) -> None:
         f"{len(tls)} sampled wave(s) at stride {every}: each sample ran "
         f"as separately timed stage dispatches (bit-identical to the "
         f"fused program). Shares are of summed stage seconds across samples — "
-        f"compare with PROFILE.md's offline per-stage isolation."
+        f"compare with --profile's offline per-stage isolation."
     )
     out.append("")
     totals: dict[str, float] = {}

@@ -9,7 +9,10 @@ from raft_tpu.checker.bfs import BFSChecker
 from raft_tpu.models.raft import RaftModel, RaftParams, cached_model
 from raft_tpu.oracle.raft_oracle import RaftOracle
 
-REF_CFG = "/root/reference/specifications/standard-raft/Raft.cfg"
+REF_CFG = str(
+    Path(__file__).resolve().parent.parent
+    / "configs" / "standard-raft" / "Raft.cfg"
+)
 
 
 def _bfs_pair(params, invariants, symmetry=True, max_depth=None, chunk=256):
@@ -51,10 +54,6 @@ def test_bfs_counts_match_oracle_with_restarts():
     assert res.total == ores["total"]
 
 
-@pytest.mark.skipif(
-    not Path("/root/reference").exists(),
-    reason="reference TLA+ spec tree not checked out at /root/reference",
-)
 def test_cfg_parse_reference_raft():
     from raft_tpu.utils.cfg import parse_cfg
     from raft_tpu.models.registry import build_from_cfg

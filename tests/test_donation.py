@@ -6,9 +6,9 @@ Donation that silently fails is worse than none: XLA copies the buffer
 AND emits a UserWarning per dispatch. These tests pin:
 
   1. no donation warning anywhere in a full DeviceBFS / ShardedBFS run
-     under ``-W error`` semantics (jit_with_donation probes each merge
-     signature once and falls back to an undonated program where the
-     backend cannot alias — e.g. truncate-merges on CPU);
+     under ``-W error`` semantics (the merges declare donation only for
+     the input an output can alias, and jit_with_donation turns a
+     refused donation into an error instead of an undonated program);
   2. the wave program's donated inputs are really consumed
      (``.is_deleted()`` on the donated carries after a wave);
   3. two back-to-back ``run()`` calls on ONE engine instance produce
@@ -104,27 +104,24 @@ def test_wave_program_consumes_donated_carries():
     assert not frontier.is_deleted()
 
 
-def test_jit_with_donation_probe_and_fallback():
-    """Plain same-shape programs donate (input deleted, no warning);
-    programs XLA cannot alias on this backend fall back to an undonated
-    jit instead of warning on every production call."""
+def test_jit_with_donation_sticks_or_raises():
+    """A donation an output can alias sticks (input deleted, no
+    warning); one no output can alias is an error naming the program —
+    never a silently undonated program."""
     with warnings.catch_warnings():
         warnings.simplefilter("error")
-        # same-shape elementwise: always aliasable
         fn = jit_with_donation(
-            lambda x: x + 1, (0,), lambda: (jnp.zeros((128,), jnp.int32),)
+            lambda x: x + 1, (0,), "inc",
+            lambda: (jnp.zeros((128,), jnp.int32),),
         )
         arg = jnp.zeros((128,), jnp.int32)
-        out = fn(arg)
-        jax.block_until_ready(out)
-        if arg.is_deleted():
-            donated = True
-        else:
-            donated = False  # backend declined: fallback path, no warning
-        # either way, calling again must not warn
-        out2 = fn(jnp.ones((128,), jnp.int32))
-        jax.block_until_ready(out2)
-        assert donated or not out2.is_deleted()
+        jax.block_until_ready(fn(arg))
+        assert arg.is_deleted()
+    with pytest.raises(RuntimeError, match="program widen: donation refused"):
+        jit_with_donation(
+            lambda x: jnp.concatenate([x, x]), (0,), "widen",
+            lambda: (jnp.zeros((128,), jnp.int32),),
+        )
 
 
 @pytest.mark.slow
