@@ -22,6 +22,7 @@ Layout of the package:
 """
 
 import os
+import re
 
 import jax
 
@@ -47,18 +48,30 @@ def enable_compcache() -> None:
     features the host lacks), which buries a run's stderr. Called once
     the backend is known, from Canonicalizer.for_model/__init__,
     Simulator and LivenessChecker — the chokepoints every checker and
-    simulation path goes through."""
+    simulation path goes through — so the process's compile counters
+    (obs/compiles.py) are switched on here too, cache or no cache.
+
+    The cache key takes the programs' metadata in: the stage scopes of
+    obs/trace.py are metadata and nothing else, and a key that strips it
+    (JAX's default) would serve a profile the op names of whichever
+    commit filled the cache — a trace must never show another commit's
+    names. The metadata names source files, so the checkout's own path
+    is cut from them: the same commit in another directory (a cache
+    placed from outside, shared by two checkouts) still hits."""
+    from .obs.compiles import COMPILES
+
+    COMPILES.install()
+    checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         if jax.default_backend() == "cpu":
             return
         jax.config.update(
-            "jax_compilation_cache_dir",
-            os.path.join(
-                os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-                ".jax_cache",
-            ),
-        )
+            "jax_compilation_cache_dir", os.path.join(checkout, ".jax_cache"))
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    jax.config.update(
+        "jax_hlo_source_file_canonicalization_regex",
+        "^" + re.escape(checkout + os.sep))
 
 
 __version__ = "0.1.0"

@@ -250,9 +250,11 @@ def main(argv=None):
         "--trace-dir",
         default=None,
         metavar="DIR",
-        help="capture a jax.profiler trace: each BFS wave is an xprof "
-        "step (StepTraceAnnotation) and precompile/seen_merge/checkpoint "
-        "are named spans",
+        help="open a jax.profiler session for the run and write its trace "
+        "to DIR. The spans (run, init, wave with dispatch/fetch/"
+        "seen_merge/..., finish; each wave an xprof step) and the stage "
+        "scopes on the device ops are always there: this only records "
+        "them",
     )
     ap.add_argument(
         "--json",

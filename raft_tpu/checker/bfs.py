@@ -114,6 +114,11 @@ class CheckResult:
     # why the run ended (obs.events.EXIT_CAUSES vocabulary); the CLI
     # maps "preempted" to exit code 4
     exit_cause: str | None = None
+    # what the run loaded into the process, by the program's own count
+    # (obs/compiles.py): programs_loaded (cumulative in the process),
+    # run_compiles, run_compile_s, run_cache_hits, run_cache_read_s.
+    # DeviceBFS fills it; ShardedResult.stats carries the same keys
+    stats: dict | None = None
 
 
 class BFSChecker:
@@ -507,14 +512,15 @@ class BFSChecker:
                         n_cand_total / max(1, prev_frontier * model.A), 4
                     ),
                     "expand_budget_ovf": wave_extra,
-                    "wave_s": round(wave_s_val, 3),
-                    "elapsed_s": round(el, 3),
+                    # clocks unrounded: device_s + host_s + ckpt_s ==
+                    # wave_s
+                    "wave_s": wave_s_val,
+                    "elapsed_s": el,
                     "distinct_per_s": round(distinct / el, 1),
-                    "device_s": round(dev_s, 4),
-                    "host_s": round(
-                        max(0.0, wave_s_val - dev_s - ckpt_s), 4),
-                    "ckpt_s": round(ckpt_s, 4),
-                    "tel_s": round(tel_s_last, 4),
+                    "device_s": dev_s,
+                    "host_s": max(0.0, wave_s_val - dev_s - ckpt_s),
+                    "ckpt_s": ckpt_s,
+                    "tel_s": tel_s_last,
                     "exchange_share": None,
                     "hbm_frac": hbm_frac,
                 }
@@ -848,6 +854,7 @@ class BFSChecker:
                 m = active[fjobs]
                 frontier, fjobs, fgids = frontier[m], fjobs[m], fgids[m]
             if tel.active or verbose:
+                wave_s_val = time.perf_counter() - tw
                 el = time.perf_counter() - t0
                 distinct = int(distinct_j.sum())
                 total = int(total_j.sum())
@@ -875,15 +882,15 @@ class BFSChecker:
                         n_cand_total / max(1, prev_frontier * model.A), 4
                     ),
                     "expand_budget_ovf": 0,
-                    "wave_s": round(time.perf_counter() - tw, 3),
-                    "elapsed_s": round(el, 3),
+                    "wave_s": wave_s_val,
+                    "elapsed_s": el,
                     "distinct_per_s": round(distinct / el, 1),
                     # packed-fleet waves are not phase-split (the shared
                     # group run is throughput-oriented); the declared
                     # observatory keys still appear so one consumer
                     # reads every engine's stream
                     "device_s": 0.0,
-                    "host_s": round(time.perf_counter() - tw, 4),
+                    "host_s": wave_s_val,
                     "ckpt_s": 0.0,
                     "tel_s": 0.0,
                     "exchange_share": None,

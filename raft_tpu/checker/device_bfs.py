@@ -50,7 +50,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from ..obs import MemWatch, NULL_TELEMETRY, device_budget
+from ..obs import (
+    COMPILES, MemWatch, NULL_TELEMETRY, device_budget, stage, traced_run,
+)
 from ..obs.events import hashv_of
 from ..ops.hashing import U64_MAX, ne_u64, sort_u64, sort_u64_with_idx
 from ..ops.symmetry import Canonicalizer
@@ -229,7 +231,6 @@ class DeviceBFS:
         self._jparent = None
         self._jcand = None
         self._jcount = 0
-        self._tel = NULL_TELEMETRY  # active only inside run(telemetry=...)
         # wave-timeline observatory programs, built on first sampled
         # wave only (a run without --timeline never compiles them)
         self._tl_fns: dict | None = None
@@ -297,6 +298,7 @@ class DeviceBFS:
         size, lshapes, target = key
         total = size + sum(lshapes)
 
+        @stage("seen_merge")
         def merge(s, *lv):
             out = sort_u64(jnp.concatenate([s, *lv]))[:target]
             if total < target:
@@ -335,8 +337,11 @@ class DeviceBFS:
     # (--timeline) dispatches the same stages as separate jits with
     # block_until_ready between them to attribute a sampled wave's
     # wall clock (obs/events.py TIMELINE_STAGES). Bit-identity of the
-    # two paths is parity-gated by tests/test_obs.py.
+    # two paths is parity-gated by tests/test_obs.py. Each stage method
+    # carries its obs.stage scope, so every program built from it names
+    # its ops by stage in a profile (obs/trace.py).
 
+    @stage("expand")
     def _st_expand(self, frontier, cursor, fcount):
         """Stages 1-2: guard/dense expand + compaction (+ the budgeted
         sparse apply). Returns the compacted successor block and every
@@ -385,6 +390,7 @@ class DeviceBFS:
         return (flatc, sel, selv, valid, rank, n_gen, terminal,
                 expand_ovf, compact_ovf)
 
+    @stage("canon")
     def _st_canon(self, flatc, selv, memo):
         """Stage 3: canonical fingerprints on compacted lanes only,
         through the raw-keyed canon memo (duplicate successors skip the
@@ -400,6 +406,7 @@ class DeviceBFS:
             n_memo_hit = jnp.asarray(0, jnp.int32)
         return fps, memo, n_memo_hit
 
+    @stage("dedup")
     def _st_dedup(self, fps, occ, *runs):
         """Stage 4: probe every OCCUPIED LSM run, then first-occurrence
         in chunk. Runs inserted by earlier chunks of this wave are in
@@ -421,6 +428,7 @@ class DeviceBFS:
         first = jnp.zeros((VC,), bool).at[order].set(first_s)
         return fresh & first
 
+    @stage("emit")
     def _st_finish(
         self, next_buf, jparent, jcand, viol, stats, cov, flatc, fps,
         sel, valid, rank, new, n_gen, terminal, expand_ovf, compact_ovf,
@@ -443,23 +451,27 @@ class DeviceBFS:
         # writer lanes (rank gathered through the compaction `sel`).
         K = self.n_actions
         if K:
-            rk = jnp.where(valid, rank, K)
-            fired_k = jax.ops.segment_sum(
-                jnp.ones((C * A,), jnp.int64), rk.reshape(-1),
-                num_segments=K + 1,
-            )[:K]
-            en = (rank[:, :, None] == jnp.arange(K, dtype=rank.dtype)) & (
-                valid[:, :, None]
-            )  # [C, A, K] one-hot (compare beats a scatter on TPU)
-            enabled_k = jnp.sum(jnp.any(en, axis=1), axis=0, dtype=jnp.int64)
-            flat_rk = jnp.concatenate(
-                [rk.reshape(-1), jnp.full((1,), K, rk.dtype)]
-            )[sel]  # [VC] rank per compacted lane (drop row -> bucket K)
-            new_k = jax.ops.segment_sum(
-                new.astype(jnp.int64), jnp.where(new, flat_rk, K),
-                num_segments=K + 1,
-            )[:K]
-            cov = cov + jnp.stack([enabled_k, fired_k, new_k], axis=1)
+            with jax.named_scope("coverage"):
+                rk = jnp.where(valid, rank, K)
+                fired_k = jax.ops.segment_sum(
+                    jnp.ones((C * A,), jnp.int64), rk.reshape(-1),
+                    num_segments=K + 1,
+                )[:K]
+                en = (
+                    rank[:, :, None] == jnp.arange(K, dtype=rank.dtype)
+                ) & (
+                    valid[:, :, None]
+                )  # [C, A, K] one-hot (compare beats a scatter on TPU)
+                enabled_k = jnp.sum(
+                    jnp.any(en, axis=1), axis=0, dtype=jnp.int64)
+                flat_rk = jnp.concatenate(
+                    [rk.reshape(-1), jnp.full((1,), K, rk.dtype)]
+                )[sel]  # [VC] rank per compacted lane (drop row -> bucket K)
+                new_k = jax.ops.segment_sum(
+                    new.astype(jnp.int64), jnp.where(new, flat_rk, K),
+                    num_segments=K + 1,
+                )[:K]
+                cov = cov + jnp.stack([enabled_k, fired_k, new_k], axis=1)
 
         # 5. emit: compact survivors to a dense prefix of a [VC, W]
         # block (scatter confined to a chunk-sized index buffer), then
@@ -502,10 +514,12 @@ class DeviceBFS:
 
         # 6. invariants on the compacted candidates; fold first-bad gid
         jidx = jnp.where(new, jcount + npos, I32_MAX)
-        for k, name in enumerate(self.invariants):
-            ok = model.invariants[name](flatc)
-            bad = new & ~ok
-            viol = viol.at[k].min(jnp.min(jnp.where(bad, jidx, I32_MAX)))
+        with jax.named_scope("invariants"):
+            for k, name in enumerate(self.invariants):
+                ok = model.invariants[name](flatc)
+                bad = new & ~ok
+                viol = viol.at[k].min(
+                    jnp.min(jnp.where(bad, jidx, I32_MAX)))
 
         ovf_bits = (
             expand_ovf.astype(jnp.int64)
@@ -640,7 +654,8 @@ class DeviceBFS:
                 k * C, fcount, base_gid, occ_all, jnp.asarray(False),
                 *runs, *ladder,
             )
-            ladder = cascade(k, new_run, ladder)
+            with stage("seen_merge"), jax.named_scope("cascade"):
+                ladder = cascade(k, new_run, ladder)
             return (k + 1, next_buf, jparent, jcand, viol, stats, memo,
                     cov, *ladder)
 
@@ -700,7 +715,7 @@ class DeviceBFS:
             else:
                 def merge(r, *lv):
                     return sort_u64(jnp.concatenate([r, *lv]))[:topsz]
-            fn = jax.jit(merge)
+            fn = jax.jit(stage("seen_merge")(merge))
             self._tl_merge_cache[key] = fn
         return fn
 
@@ -985,24 +1000,39 @@ class DeviceBFS:
         journal growth is exact (it grows by ncount per wave). The
         seen-set needs no growth pass — LSM levels appear on demand."""
         W = self.W
-        if ncount * self.HEADROOM > self.FCAP and self.FCAP < self.MAX_FCAP:
-            new = self._next_cap(
-                ncount * self.HEADROOM, self.FCAP, self.MAX_FCAP, self.GROWTH, self.chunk
-            )
-            pad = new - self.FCAP  # old buffer already carries its VC pad rows
-            frontier = jnp.concatenate(
-                [frontier, jnp.zeros((pad, W), jnp.int32)], axis=0
-            )
-            next_buf = jnp.zeros((new + self.VC, W), jnp.int32)
-            self.FCAP = new
-        if jcount + ncount * self.HEADROOM > self.JCAP and self.JCAP < self.MAX_JCAP:
-            new = self._next_cap(
-                jcount + ncount * self.HEADROOM, self.JCAP, self.MAX_JCAP, self.GROWTH, 1
-            )
-            pad = new - self.JCAP
-            jparent = jnp.concatenate([jparent, jnp.zeros((pad,), jnp.int32)])
-            jcand = jnp.concatenate([jcand, jnp.zeros((pad,), jnp.int32)])
-            self.JCAP = new
+        grow_f = ncount * self.HEADROOM > self.FCAP and self.FCAP < self.MAX_FCAP
+        grow_j = (
+            jcount + ncount * self.HEADROOM > self.JCAP
+            and self.JCAP < self.MAX_JCAP
+        )
+        if not (grow_f or grow_j):
+            return frontier, next_buf, jparent, jcand
+        # the `grow` span and the row's grow_s exist only on a wave that
+        # grows; the wave program's retrace at the new shapes lands in
+        # the NEXT wave's dispatch (its row's compiles/compile_s)
+        with self._ph("grow"):
+            if grow_f:
+                new = self._next_cap(
+                    ncount * self.HEADROOM, self.FCAP, self.MAX_FCAP,
+                    self.GROWTH, self.chunk,
+                )
+                # the old buffer already carries its VC pad rows
+                pad = new - self.FCAP
+                frontier = jnp.concatenate(
+                    [frontier, jnp.zeros((pad, W), jnp.int32)], axis=0
+                )
+                next_buf = jnp.zeros((new + self.VC, W), jnp.int32)
+                self.FCAP = new
+            if grow_j:
+                new = self._next_cap(
+                    jcount + ncount * self.HEADROOM, self.JCAP,
+                    self.MAX_JCAP, self.GROWTH, 1,
+                )
+                pad = new - self.JCAP
+                jparent = jnp.concatenate(
+                    [jparent, jnp.zeros((pad,), jnp.int32)])
+                jcand = jnp.concatenate([jcand, jnp.zeros((pad,), jnp.int32)])
+                self.JCAP = new
         return frontier, next_buf, jparent, jcand
 
     def grow_for_overflow(self, bits: int) -> dict | None:
@@ -1037,6 +1067,7 @@ class DeviceBFS:
 
     # ---------------- host driver ----------------
 
+    @traced_run("device")
     def run(
         self,
         max_depth: int | None = None,
@@ -1054,13 +1085,18 @@ class DeviceBFS:
         model = self.model
         C, W = self.chunk, self.W
         t0 = time.perf_counter()
+        # host spans (obs/trace.py): `init` up to the first wave, one
+        # `wave` per loop iteration, `finish` after the loop; the phases
+        # inside a wave are bracketed once, for the trace and the row
+        ph = self._ph
+        ph.top("init")
+        comp_run = COMPILES.snapshot()
         exhausted = True
         exit_cause = None
         # telemetry consumes the SAME once-per-wave host snapshot the
         # loop already fetches (stats_h below), so an instrumented run
         # adds no device syncs and stays bit-identical (tests/test_obs.py)
         tel = telemetry if telemetry is not None else NULL_TELEMETRY
-        self._tel = tel
         self._ckpt_keep = checkpoint_keep
         self._chaos = chaos
 
@@ -1198,7 +1234,6 @@ class DeviceBFS:
         ladder_bytes = sum(
             (self.R0 << i) * 8 for i in range(self._wave_geom() + 1)
         )
-        tel_s_last = 0.0
 
         while fcount and violation is None:
             if preempt is not None and preempt.requested:
@@ -1222,6 +1257,9 @@ class DeviceBFS:
                 exhausted = False
                 exit_cause = "time_budget"
                 break
+            ph.wave(self._run_id, depth + 1, fcount)
+            tw = time.perf_counter()
+            comp_wave = COMPILES.snapshot()
             # capacity guard: the top-level absorb truncates at TOPSZ
             # lanes, which is only sound while every real fingerprint is
             # guaranteed to fit; FCAP bounds the wave's new states
@@ -1255,7 +1293,6 @@ class DeviceBFS:
                     gen_prev, depth_counts, cov_h,
                 )
                 last_ckpt = time.perf_counter()
-            tw = time.perf_counter()
             tl_sample = tl_every > 0 and (depth + 1) % tl_every == 0
             stage_s = (
                 {s: 0.0 for s in ("expand", "canon", "dedup", "emit",
@@ -1271,17 +1308,18 @@ class DeviceBFS:
             # with per-stage timing instead (bit-identical, parity-
             # gated); untimed waves keep the fused program.
             with tel.wave_annotation(depth + 1):
-                if tl_sample:
-                    out = self._run_timeline_wave(
-                        frontier, next_buf, jparent, jcand, viol, stats,
-                        memo, cov, fcount, base_gid, stage_s,
-                    )
-                else:
-                    out = self._wave_fn(
-                        frontier, next_buf, jparent, jcand, viol, stats,
-                        memo, cov, np.int32(fcount), np.int32(base_gid),
-                        self._occ_one, self._seen,
-                    )
+                with ph("dispatch"):
+                    if tl_sample:
+                        out = self._run_timeline_wave(
+                            frontier, next_buf, jparent, jcand, viol,
+                            stats, memo, cov, fcount, base_gid, stage_s,
+                        )
+                    else:
+                        out = self._wave_fn(
+                            frontier, next_buf, jparent, jcand, viol,
+                            stats, memo, cov, np.int32(fcount),
+                            np.int32(base_gid), self._occ_one, self._seen,
+                        )
                 next_buf, jparent, jcand, viol, stats, memo, cov = out[:7]
                 ladder = out[7:]
                 # one host round-trip per wave: stats, the invariant
@@ -1289,9 +1327,10 @@ class DeviceBFS:
                 # device_gets are two syncs on small configs, where
                 # per-wave latency dominates) — and telemetry rides
                 # this same snapshot
-                # lint: sync-ok(once-per-wave snapshot)
-                stats_h, viol_h, cov_w = jax.device_get((stats, viol, cov))
-            device_s = time.perf_counter() - tw
+                with ph("fetch"):
+                    # lint: sync-ok(once-per-wave snapshot)
+                    stats_h, viol_h, cov_w = jax.device_get(
+                        (stats, viol, cov))
             stats_h = np.asarray(stats_h)
             viol_h = np.asarray(viol_h)
             ncount = int(stats_h[0])
@@ -1345,13 +1384,10 @@ class DeviceBFS:
             # fold the wave ladder into the single seen run (device-side
             # sort-concat; the merge-program signature set is warmed by
             # precompile)
-            with tel.annotate("seen_merge"):
-                tm = time.perf_counter()
+            with ph("seen_merge"):
                 self._merge_seen(ladder, scount)
-                merge_s = time.perf_counter() - tm
-            device_s += merge_s
             if stage_s is not None:
-                stage_s["seen_merge"] += merge_s
+                stage_s["seen_merge"] += ph.s["seen_merge"]
             depth += 1
             distinct += ncount
             depth_counts.append(ncount)
@@ -1371,26 +1407,36 @@ class DeviceBFS:
             frontier, next_buf, jparent, jcand = self._maybe_grow(
                 ncount, frontier, next_buf, jparent, jcand, scount - n0
             )
-            ckpt_s = 0.0
             if (
                 checkpoint_path is not None
                 and violation is None  # a saved file must not mask a violation
                 and time.perf_counter() - last_ckpt > checkpoint_every_s
             ):
-                tck = time.perf_counter()
                 self._save_checkpoint(
                     checkpoint_path, frontier, jparent, jcand, fcount,
                     scount, distinct, total, terminal, depth, base_gid,
                     gen_prev, depth_counts, cov_h,
                 )
                 last_ckpt = time.perf_counter()
-                ckpt_s = last_ckpt - tck
                 if stage_s is not None:
-                    stage_s["checkpoint"] += ckpt_s
+                    stage_s["checkpoint"] += ph.s["checkpoint"]
             memo_hits = int(stats_h[5])
             wave_memo = memo_hits - memo_prev
             memo_prev = memo_hits
             wave_s_val = time.perf_counter() - tw
+            # the wave's brackets, read once: each phase's seconds are
+            # those of its span. device_s is the host's WAIT on the
+            # device (dispatch, the one blocking fetch, the seen merge's
+            # dispatch), never device time; what the brackets leave of
+            # the wave is host_s. `telemetry` is the previous wave's
+            # bracket (Phases.take)
+            ph_s = ph.take()
+            dispatch_s = ph_s.get("dispatch", 0.0)
+            fetch_s = ph_s.get("fetch", 0.0)
+            merge_s = ph_s.get("seen_merge", 0.0)
+            device_s = dispatch_s + fetch_s + merge_s
+            ckpt_s = ph_s.get("checkpoint", 0.0)
+            comp_now = COMPILES.snapshot()
             if tl_every:
                 (tl_wave_s if tl_sample else fused_wave_s).append(wave_s_val)
                 tl_waves += 1 if tl_sample else 0
@@ -1409,7 +1455,9 @@ class DeviceBFS:
                     "chunk": self.VC * (4 * W + 8),
                     "memo": self.MCAP * 16 if self._use_memo else 0,
                 })
-            if tel.active or metrics is not None or verbose:
+            if not (tel.active or metrics is not None or verbose):
+                continue
+            with ph("telemetry"):
                 el = time.perf_counter() - t0
                 wm = {
                     "depth": depth,
@@ -1425,8 +1473,8 @@ class DeviceBFS:
                         wave_memo / max(1, wave_gen), 4
                     ),
                     "overflow_bits": ovf_bits,
-                    "wave_s": round(time.perf_counter() - tw, 3),
-                    "elapsed_s": round(el, 3),
+                    "wave_s": wave_s_val,
+                    "elapsed_s": el,
                     "distinct_per_s": round(distinct / el, 1),
                     "lsm_runs": 1,
                     "lsm_lanes": int(self._seen.shape[0]),
@@ -1453,23 +1501,30 @@ class DeviceBFS:
                         wave_gen / max(1, prev_fcount * self.A), 4
                     ),
                     "expand_budget_ovf": (ovf_bits >> 1) & 1,
-                    # host-side phase split (perf_counter brackets the
-                    # loop already runs — zero extra device syncs):
-                    # device dispatch+sync vs checkpoint I/O vs residual
-                    # host bookkeeping; tel_s is the PREVIOUS wave's
-                    # telemetry-emission cost (only known one wave late)
-                    "device_s": round(device_s, 4),
-                    "host_s": round(
-                        max(0.0, wave_s_val - device_s - ckpt_s), 4
-                    ),
-                    "ckpt_s": round(ckpt_s, 4),
-                    "tel_s": round(tel_s_last, 4),
+                    # host-side phase split, unrounded (zero extra
+                    # device syncs): device_s + host_s + ckpt_s ==
+                    # wave_s, and device_s == dispatch_s + fetch_s +
+                    # merge_s; tel_s is the PREVIOUS wave's telemetry
+                    # bracket (only known one wave late)
+                    "device_s": device_s,
+                    "host_s": max(0.0, wave_s_val - device_s - ckpt_s),
+                    "ckpt_s": ckpt_s,
+                    "tel_s": ph_s.get("telemetry", 0.0),
+                    "dispatch_s": dispatch_s,
+                    "fetch_s": fetch_s,
+                    "merge_s": merge_s,
+                    "grow_s": ph_s.get("grow", 0.0),
+                    # programs this iteration loaded (compiled, or read
+                    # from the persistent cache) and the seconds that
+                    # took: a growth or ladder-step compile is booked to
+                    # its wave (obs/compiles.py)
+                    "compiles": comp_now[0] - comp_wave[0],
+                    "compile_s": comp_now[1] - comp_wave[1],
                     "exchange_share": None,
                     "hbm_frac": (
                         round(hbm_frac, 4) if hbm_frac is not None else None
                     ),
                 }
-                t_tel = time.perf_counter()
                 tel.wave(wm)
                 if tel.active:
                     tel.coverage(self._coverage_fields(
@@ -1493,8 +1548,8 @@ class DeviceBFS:
                         f"total {total}, {distinct/el:.0f} distinct/s",
                         file=sys.stderr,
                     )
-                tel_s_last = time.perf_counter() - t_tel
 
+        ph.top("finish")
         if checkpoint_path is not None and violation is None and not exhausted:
             # budget/depth-capped exit: the loop broke at a wave boundary,
             # so save a final resumable snapshot (the periodic timer alone
@@ -1552,6 +1607,7 @@ class DeviceBFS:
                 "timeline_waves": tl_waves,
                 "timeline_overhead": overhead,
             }
+        run_stats = COMPILES.run_stats(comp_run)
         tel.close_run({
             "engine": "device",
             "ident": self._ckpt_ident(),
@@ -1568,6 +1624,7 @@ class DeviceBFS:
             "peak_journal_cap": self.JCAP,
             "seen_lanes": int(self._seen.shape[0]),
             "canon_memo_hit_rate": round(memo_prev / max(1, gen_prev), 4),
+            **run_stats,
             **tl_extras,
             **(memwatch.summary_fields() if memwatch is not None else {}),
         })
@@ -1589,6 +1646,7 @@ class DeviceBFS:
                 if self.n_actions else None
             ),
             exit_cause=exit_cause,
+            stats=run_stats,
         )
         return res
 
@@ -1765,7 +1823,7 @@ class DeviceBFS:
     ):
         """Spill the resumable run state to an .npz (atomic rename).
         Saved at wave boundaries only, so the arrays are consistent."""
-        with self._tel.annotate("checkpoint"):
+        with self._ph("checkpoint"):
             self._write_checkpoint(
                 path, frontier, jparent, jcand, fcount, scount, distinct,
                 total, terminal, depth, base_gid, gen_prev, depth_counts,

@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs.trace import stage
 from ..ops.hashing import U64_MAX, sort_u64
 from .util import jit_with_donation
 
@@ -154,7 +155,7 @@ class RunLSM:
                 return sort_u64(
                     jnp.concatenate([x, y], axis=-1), axis=-1
                 )[..., :out]
-        return body, ((0,) if na == out else ())
+        return stage("seen_merge")(body), ((0,) if na == out else ())
 
     def _merge(self, a, b, out: int | None = None):
         """Per-row sort-concat merge along the lane axis (2-key u32 sort,

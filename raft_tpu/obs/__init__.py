@@ -2,7 +2,8 @@
 
 Live counterpart of the offline stage profiler (checker/profile.py):
 per-wave JSONL metrics (events.py), a TLC-style progress line
-(progress.py), jax.profiler trace hooks (trace.py), the
+(progress.py), the tracing spine — device scopes, host spans, the
+profiler session (trace.py) — and compile counters (compiles.py), the
 collector/facade threading them through the engines (collector.py),
 and TLC-style per-action coverage rendering (coverage.py).
 
@@ -42,7 +43,8 @@ from .events import (
 )
 from .memwatch import MemWatch, budget_from_env, device_budget
 from .progress import ProgressRenderer, format_count
-from .trace import TraceHooks
+from .compiles import COMPILES
+from .trace import Phases, TraceSession, span, stage, traced_run
 
 __all__ = [
     "CKPT_GENERATION_KEYS",
@@ -61,13 +63,15 @@ __all__ = [
     "TIMELINE_KEYS",
     "TIMELINE_STAGES",
     "WAVE_KEYS",
+    "COMPILES",
     "JobTaggedTelemetry",
     "MemWatch",
     "MetricsCollector",
     "NULL_TELEMETRY",
+    "Phases",
     "ProgressRenderer",
     "Telemetry",
-    "TraceHooks",
+    "TraceSession",
     "budget_from_env",
     "coverage_digest",
     "dead_actions",
@@ -75,6 +79,9 @@ __all__ = [
     "format_count",
     "hashv_of",
     "render_coverage_table",
+    "span",
+    "stage",
+    "traced_run",
     "validate_event",
     "validate_lines",
 ]

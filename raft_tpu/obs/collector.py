@@ -18,8 +18,12 @@ checkpoint spill on a slow disk, or a preempted device.
 
 ``Telemetry`` is the facade the engines thread through ``run()``: one
 object bundling the collector, the optional TLC-style progress renderer
-and the jax.profiler trace hooks. ``NULL_TELEMETRY`` is the do-nothing
-instance engines default to, so the hot loop never branches on None.
+and the ``--trace-dir`` profiler session. ``NULL_TELEMETRY`` is the
+do-nothing instance engines default to, so the hot loop never branches
+on None. The host spans do not depend on either: every facade's
+``annotate`` is ``trace.span`` (obs/trace.py), and ``wave_annotation``
+is a hook that does nothing here — it marks where a wave's dispatch and
+fetch are, and the benchmark's adapter hangs its clock on it.
 """
 
 from __future__ import annotations
@@ -31,7 +35,9 @@ from contextlib import nullcontext
 
 from .events import EVENT_KEYS
 from .progress import ProgressRenderer
-from .trace import TraceHooks
+from .trace import TraceSession, span
+
+_NO_SPAN = nullcontext()
 
 
 class MetricsCollector:
@@ -112,6 +118,10 @@ class MetricsCollector:
                     "wave_s": round(wave_s, 3),
                     "median_wave_s": round(med, 3),
                     "factor": round(wave_s / med, 1),
+                    # programs the wave loaded: a growth or ladder-step
+                    # compile names itself
+                    "compiles": fields.get("compiles"),
+                    "compile_s": fields.get("compile_s"),
                 }
                 self._write(stall)
                 self._notify(stall)
@@ -188,7 +198,7 @@ class MetricsCollector:
 
 class Telemetry:
     """Everything an engine run() threads through: collector + progress
-    renderer + trace hooks. Construct once, pass as ``telemetry=``;
+    renderer + profiler session. Construct once, pass as ``telemetry=``;
     reusable across multiple runs (each emits manifest..summary);
     ``close()`` (or the context manager) flushes the JSONL file and
     stops the profiler trace."""
@@ -221,12 +231,11 @@ class Telemetry:
                 every_s=progress_every, stream=progress_stream
             )
             self.collector.add_listener(self.progress)
-        self.trace = TraceHooks(trace_dir)
+        self.trace = TraceSession(trace_dir)
 
     # -- engine-facing --
 
     def open_run(self, manifest: dict) -> None:
-        self.trace.ensure_started()
         self.collector.manifest(manifest)
 
     def wave(self, fields: dict) -> None:
@@ -242,10 +251,10 @@ class Telemetry:
         self.collector.summary(summary)
 
     def wave_annotation(self, depth: int):
-        return self.trace.wave(depth)
+        return _NO_SPAN
 
     def annotate(self, name: str):
-        return self.trace.section(name)
+        return span(name)
 
     # -- caller-facing --
 
@@ -355,10 +364,10 @@ class _NullTelemetry:
         pass
 
     def wave_annotation(self, depth: int):
-        return nullcontext()
+        return _NO_SPAN
 
     def annotate(self, name: str):
-        return nullcontext()
+        return span(name)
 
     def close(self) -> None:
         pass

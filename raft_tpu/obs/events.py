@@ -100,12 +100,21 @@ TIMELINE_STAGES = (
 # beyond one per chunk — it loops instead of aborting). Both derive
 # from counters the wave already fetched: zero extra device syncs.
 # device_s/host_s/ckpt_s/tel_s (wave-timeline observatory): the
-# host-side phase split of the wave's wall clock — seconds blocked on
-# device work (dispatch + the one stats fetch), residual host
-# bookkeeping, checkpoint I/O, and the telemetry emission cost of the
-# PREVIOUS wave (this wave's own emission cost is only known after the
-# event is written; 0.0 on wave 1). All four come from perf_counter
-# brackets around code the wave already runs: zero extra device syncs.
+# host-side phase split of the wave's wall clock. device_s is the
+# HOST'S WAIT on the device, never device time (a profile has that):
+# the seconds its thread spent in the wave's dispatch, in the one
+# blocking stats fetch and in the seen merge's dispatch. host_s is what
+# those and checkpoint I/O (ckpt_s) leave of wave_s, so
+# device_s + host_s + ckpt_s == wave_s; tel_s is the telemetry cost of
+# the PREVIOUS wave (this wave's own is only known after the event is
+# written; 0.0 on wave 1). Every clock of a row is the unrounded
+# perf_counter difference of the bracket that also opens the phase's
+# profiler span (obs/trace.py Phases): zero extra device syncs. The
+# device engines add, as extra keys, the brackets themselves —
+# dispatch_s, fetch_s, merge_s (their sum is device_s), grow_s (a wave
+# that grew a buffer) — and compiles/compile_s: programs the iteration
+# loaded, compiled or read from the persistent cache, and the seconds
+# that took (obs/compiles.py).
 # exchange_share: sharded engine only, fraction of the sampled wave's
 # device seconds spent in the all-to-all (null on other engines and on
 # unsampled waves). hbm_frac: analytic live-bytes / budget from
@@ -320,7 +329,8 @@ def validate_event(ev: object, lineno: int | None = None) -> list[str]:
                 f"{where}wave expand_budget_ovf {bovf!r} must be a "
                 f"non-negative int"
             )
-        for key in ("device_s", "host_s", "ckpt_s", "tel_s"):
+        for key in ("device_s", "host_s", "ckpt_s", "tel_s", "dispatch_s",
+                    "fetch_s", "merge_s", "grow_s", "compile_s"):
             v = ev.get(key)
             if v is not None and (
                 isinstance(v, bool) or not isinstance(v, (int, float))

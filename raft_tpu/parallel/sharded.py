@@ -64,7 +64,9 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..checker.lsm import CanonMemo, RunLSM, pow2_at_least
-from ..obs import MemWatch, NULL_TELEMETRY, device_budget
+from ..obs import (
+    COMPILES, MemWatch, NULL_TELEMETRY, device_budget, stage, traced_run,
+)
 from ..obs.events import hashv_of
 from ..checker.util import (
     GROWTH, HEADROOM, I32_MAX, dense_prefix_sel, emit_append,
@@ -331,6 +333,7 @@ class ShardedBFS:
                 )
                 return sp[None], sf[None], memo2[None], cg[None], ps[None]
 
+            @stage("exchange")
             def ex_step(send_pay, send_fps):
                 rp = lax.all_to_all(send_pay[0], AXIS, 0, 0, tiled=True)
                 rf = lax.all_to_all(send_fps[0], AXIS, 0, 0, tiled=True)
@@ -492,8 +495,9 @@ class ShardedBFS:
         )
         # 5. ICI all-to-all: block d of my send goes to chip d; received
         # block d came from chip d (=> parent shard = recv row // RC)
-        recv_pay = lax.all_to_all(send_pay, AXIS, 0, 0, tiled=True)
-        recv_fps = lax.all_to_all(send_fps, AXIS, 0, 0, tiled=True)
+        with stage("exchange"):
+            recv_pay = lax.all_to_all(send_pay, AXIS, 0, 0, tiled=True)
+            recv_fps = lax.all_to_all(send_fps, AXIS, 0, 0, tiled=True)
         (next_buf, jps, jpl, jcand, jfp, viol, stats, cov, new_run,
          ) = self._cs_post(
             recv_pay, recv_fps, next_buf, jps, jpl, jcand, jfp, viol,
@@ -516,103 +520,106 @@ class ShardedBFS:
         C, VC, RC = self.chunk, self.VC, self.RC
         K = self.n_actions
 
-        # 1. expand `chunk` rows starting at the wave cursor
-        batch = lax.dynamic_slice(frontier, (cursor, jnp.int32(0)), (C, W))
-        live = (jnp.arange(C, dtype=jnp.int32) + cursor) < fcount
-        if self._sparse:
-            # guard pass: valid/rank/ovf only — no W-wide successor
-            # rows (DCE-derived from _expand1, bit-identical)
-            valid, rank, ovf = jax.vmap(model.guards1)(batch)
-        else:
-            succs, valid, rank, ovf = jax.vmap(model._expand1)(batch)
-        valid = valid & live[:, None]
-        expand_ovf = jnp.any(valid & ovf)
-        n_gen = jnp.sum(valid)
-        term = jnp.sum(live & ~jnp.any(valid, axis=1))
+        with stage("expand"):
+            # 1. expand `chunk` rows starting at the wave cursor
+            batch = lax.dynamic_slice(frontier, (cursor, jnp.int32(0)), (C, W))
+            live = (jnp.arange(C, dtype=jnp.int32) + cursor) < fcount
+            if self._sparse:
+                # guard pass: valid/rank/ovf only — no W-wide successor
+                # rows (DCE-derived from _expand1, bit-identical)
+                valid, rank, ovf = jax.vmap(model.guards1)(batch)
+            else:
+                succs, valid, rank, ovf = jax.vmap(model._expand1)(batch)
+            valid = valid & live[:, None]
+            expand_ovf = jnp.any(valid & ovf)
+            n_gen = jnp.sum(valid)
+            term = jnp.sum(live & ~jnp.any(valid, axis=1))
 
-        # 1b. enabled/fired per action rank, tallied where the lanes are
-        # generated (numpy mirror in checker/bfs.py; invalid lanes route
-        # to drop bucket K)
-        if K:
-            rk = jnp.where(valid, rank, K)
-            fired_k = jax.ops.segment_sum(
-                jnp.ones((C * A,), jnp.int64), rk.reshape(-1),
-                num_segments=K + 1,
-            )[:K]
-            en = (rank[:, :, None] == jnp.arange(K, dtype=rank.dtype)) & (
-                valid[:, :, None]
-            )  # [C, A, K] one-hot (compare beats a scatter on TPU)
-            enabled_k = jnp.sum(jnp.any(en, axis=1), axis=0, dtype=jnp.int64)
+            # 1b. enabled/fired per action rank, tallied where the lanes are
+            # generated (numpy mirror in checker/bfs.py; invalid lanes route
+            # to drop bucket K)
+            if K:
+                rk = jnp.where(valid, rank, K)
+                fired_k = jax.ops.segment_sum(
+                    jnp.ones((C * A,), jnp.int64), rk.reshape(-1),
+                    num_segments=K + 1,
+                )[:K]
+                en = (rank[:, :, None] == jnp.arange(K, dtype=rank.dtype)) & (
+                    valid[:, :, None]
+                )  # [C, A, K] one-hot (compare beats a scatter on TPU)
+                enabled_k = jnp.sum(jnp.any(en, axis=1), axis=0, dtype=jnp.int64)
 
-        # 2. compact the valid lanes (sel[j] = flat lane of the j-th valid)
-        vflat = valid.reshape(-1)
-        vpos = jnp.cumsum(vflat) - 1
-        compact_ovf = n_gen > VC
-        sdst = jnp.where(vflat, jnp.minimum(vpos, VC), VC)
-        sel = (
-            jnp.full((VC + 1,), C * A, jnp.int32)
-            .at[sdst]
-            .set(jnp.arange(C * A, dtype=jnp.int32))[:VC]
-        )
-        selv = sel < C * A
-        if self._sparse:
-            # apply pass over the compacted worklist only; budget
-            # overflow folds into the compaction bit (same remedy:
-            # raise the static budget knob)
-            flatc, apply_ovf = model.sparse_apply(batch, sel, selv, self._plan)
-            compact_ovf = compact_ovf | apply_ovf
-        else:
-            flatp = jnp.concatenate(
-                [succs.reshape(C * A, W), jnp.zeros((1, W), jnp.int32)],
-                axis=0,
+            # 2. compact the valid lanes (sel[j] = flat lane of the j-th valid)
+            vflat = valid.reshape(-1)
+            vpos = jnp.cumsum(vflat) - 1
+            compact_ovf = n_gen > VC
+            sdst = jnp.where(vflat, jnp.minimum(vpos, VC), VC)
+            sel = (
+                jnp.full((VC + 1,), C * A, jnp.int32)
+                .at[sdst]
+                .set(jnp.arange(C * A, dtype=jnp.int32))[:VC]
             )
-            flatc = flatp[sel]  # [VC, W]
-        parent_lgid = base_lgid + cursor + sel // A
-        cand = sel % A
+            selv = sel < C * A
+            if self._sparse:
+                # apply pass over the compacted worklist only; budget
+                # overflow folds into the compaction bit (same remedy:
+                # raise the static budget knob)
+                flatc, apply_ovf = model.sparse_apply(batch, sel, selv, self._plan)
+                compact_ovf = compact_ovf | apply_ovf
+            else:
+                flatp = jnp.concatenate(
+                    [succs.reshape(C * A, W), jnp.zeros((1, W), jnp.int32)],
+                    axis=0,
+                )
+                flatc = flatp[sel]  # [VC, W]
+            parent_lgid = base_lgid + cursor + sel // A
+            cand = sel % A
 
-        # 3. canonical fingerprints on the compacted lanes — memoized on
-        # the GENERATING chip (raw keys are shard-local; the all-to-all
-        # below only ever moves canonical fingerprints)
-        if self._use_memo:
-            fps, memo, n_memo_hit = self.canon.fingerprints_memo(
-                flatc, selv, memo
-            )
-        else:
-            fps = self.canon._fingerprints(flatc)
-            fps = jnp.where(selv, fps, U64_MAX)
-            n_memo_hit = jnp.asarray(0, jnp.int32)
+        with stage("canon"):
+            # 3. canonical fingerprints on the compacted lanes — memoized on
+            # the GENERATING chip (raw keys are shard-local; the all-to-all
+            # below only ever moves canonical fingerprints)
+            if self._use_memo:
+                fps, memo, n_memo_hit = self.canon.fingerprints_memo(
+                    flatc, selv, memo
+                )
+            else:
+                fps = self.canon._fingerprints(flatc)
+                fps = jnp.where(selv, fps, U64_MAX)
+                n_memo_hit = jnp.asarray(0, jnp.int32)
 
-        # 4. route to owner chip = fp mod D: sort by owner, positional
-        # slots. The action rank rides the payload so the OWNER chip can
-        # attribute new-distinct states per action after dedup.
-        lane_rank = jnp.concatenate(
-            [rank.reshape(-1), jnp.full((1,), -1, rank.dtype)]
-        )[sel]  # [VC] rank per compacted lane (drop row -> -1)
-        payload = jnp.concatenate(
-            [flatc, parent_lgid[:, None], cand[:, None],
-             lane_rank[:, None].astype(jnp.int32)], axis=1
-        )  # [VC, W+3] i32
-        # fp mod D in u32 pieces (u64 div/mod lanes are slow on this TPU):
-        # (hi*2^32 + lo) % D == ((hi%D) * (2^32%D) + lo%D) % D
-        # exact only while (D-1)*(2^32%D) + (D-1) fits u32 — enforced at
-        # construction (D <= 2^16), and real meshes are far smaller
-        fhi, flo = split_u64(fps)
-        t32 = np.uint32((1 << 32) % D)
-        owner = (((fhi % np.uint32(D)) * t32 + flo % np.uint32(D))
-                 % np.uint32(D)).astype(jnp.int32)
-        owner = jnp.where(eq_u64(fps, U64_MAX), D, owner)  # invalid -> drop
-        order = jnp.argsort(owner, stable=True)
-        owner_s = owner[order]
-        fps_s = fps[order]
-        start = jnp.searchsorted(owner_s, jnp.arange(D + 1), side="left")
-        pos_in_owner = jnp.arange(VC) - start[owner_s]
-        ok = (owner_s < D) & (pos_in_owner < RC)
-        route_ovf = jnp.any((owner_s < D) & (pos_in_owner >= RC))
-        n_routed = jnp.sum(ok)
-        slot = jnp.where(ok, owner_s * RC + pos_in_owner, D * RC)
-        send_pay = jnp.zeros((D * RC + 1, W + 3), jnp.int32).at[slot].set(payload[order])[:-1]
-        send_fps = jnp.full((D * RC + 1,), U64_MAX, jnp.uint64).at[slot].set(
-            jnp.where(ok, fps_s, U64_MAX))[:-1]
+        with stage("exchange"), jax.named_scope("route"):
+            # 4. route to owner chip = fp mod D: sort by owner, positional
+            # slots. The action rank rides the payload so the OWNER chip can
+            # attribute new-distinct states per action after dedup.
+            lane_rank = jnp.concatenate(
+                [rank.reshape(-1), jnp.full((1,), -1, rank.dtype)]
+            )[sel]  # [VC] rank per compacted lane (drop row -> -1)
+            payload = jnp.concatenate(
+                [flatc, parent_lgid[:, None], cand[:, None],
+                 lane_rank[:, None].astype(jnp.int32)], axis=1
+            )  # [VC, W+3] i32
+            # fp mod D in u32 pieces (u64 div/mod lanes are slow on this TPU):
+            # (hi*2^32 + lo) % D == ((hi%D) * (2^32%D) + lo%D) % D
+            # exact only while (D-1)*(2^32%D) + (D-1) fits u32 — enforced at
+            # construction (D <= 2^16), and real meshes are far smaller
+            fhi, flo = split_u64(fps)
+            t32 = np.uint32((1 << 32) % D)
+            owner = (((fhi % np.uint32(D)) * t32 + flo % np.uint32(D))
+                     % np.uint32(D)).astype(jnp.int32)
+            owner = jnp.where(eq_u64(fps, U64_MAX), D, owner)  # invalid -> drop
+            order = jnp.argsort(owner, stable=True)
+            owner_s = owner[order]
+            fps_s = fps[order]
+            start = jnp.searchsorted(owner_s, jnp.arange(D + 1), side="left")
+            pos_in_owner = jnp.arange(VC) - start[owner_s]
+            ok = (owner_s < D) & (pos_in_owner < RC)
+            route_ovf = jnp.any((owner_s < D) & (pos_in_owner >= RC))
+            n_routed = jnp.sum(ok)
+            slot = jnp.where(ok, owner_s * RC + pos_in_owner, D * RC)
+            send_pay = jnp.zeros((D * RC + 1, W + 3), jnp.int32).at[slot].set(payload[order])[:-1]
+            send_fps = jnp.full((D * RC + 1,), U64_MAX, jnp.uint64).at[slot].set(
+                jnp.where(ok, fps_s, U64_MAX))[:-1]
 
         pre_stats = jnp.stack([
             n_gen.astype(jnp.int64),
@@ -642,97 +649,99 @@ class ShardedBFS:
         F, JC = self.FCAP, self.JCAP
         K = self.n_actions
 
-        # 6. local dedup: probe the occupied LSM runs + first-occurrence
-        rf, sidx = sort_u64_with_idx(recv_fps)
-        uniq = jnp.ones_like(rf, dtype=bool).at[1:].set(ne_u64(rf[1:], rf[:-1]))
-        fresh = uniq & ne_u64(rf, U64_MAX)
-        for i, r in enumerate(runs):
-            hit = lax.cond(
-                occ[i],
-                lambda rr: _probe(rr, rf),
-                # rf != rf: an all-False array that carries the same
-                # varying-manual-axes type as the true branch (a plain
-                # jnp.zeros is unvarying and cond rejects the mismatch)
-                lambda rr: rf != rf,
-                r,
+        with stage("dedup"):
+            # 6. local dedup: probe the occupied LSM runs + first-occurrence
+            rf, sidx = sort_u64_with_idx(recv_fps)
+            uniq = jnp.ones_like(rf, dtype=bool).at[1:].set(ne_u64(rf[1:], rf[:-1]))
+            fresh = uniq & ne_u64(rf, U64_MAX)
+            for i, r in enumerate(runs):
+                hit = lax.cond(
+                    occ[i],
+                    lambda rr: _probe(rr, rf),
+                    # rf != rf: an all-False array that carries the same
+                    # varying-manual-axes type as the true branch (a plain
+                    # jnp.zeros is unvarying and cond rejects the mismatch)
+                    lambda rr: rf != rf,
+                    r,
+                )
+                fresh = fresh & ~hit
+            new = fresh
+            n_new = jnp.sum(new)
+
+        with stage("emit"):
+            # 7. emit survivors: compact to a dense prefix of a [D*RC, W]
+            # block, then ONE dynamic_update_slice per buffer appends at the
+            # running cursor (rows [F, F+D*RC) / [JC, JC+D*RC) are the drop
+            # region — checker/util.py emit_append; same redesign as
+            # DeviceBFS._chunk_step step 5, retiring full-capacity scatters)
+            ncount = stats[0].astype(jnp.int32)
+            jcount = stats[1].astype(jnp.int32)
+            npos = (jnp.cumsum(new) - 1).astype(jnp.int32)
+            states_s = recv_pay[sidx, :W]
+            B = D * RC
+            esel = dense_prefix_sel(new, npos, B)
+            blk = jnp.concatenate(
+                [states_s, jnp.zeros((1, W), jnp.int32)], axis=0
+            )[esel]
+            jps_blk = jnp.concatenate(
+                [(sidx // RC).astype(jnp.int32), jnp.zeros((1,), jnp.int32)]
+            )[esel]
+            jpl_blk = jnp.concatenate(
+                [recv_pay[sidx, W], jnp.zeros((1,), jnp.int32)]
+            )[esel]
+            jc_blk = jnp.concatenate(
+                [recv_pay[sidx, W + 1], jnp.zeros((1,), jnp.int32)]
+            )[esel]
+            jfp_blk = jnp.concatenate(
+                [rf, jnp.full((1,), U64_MAX, jnp.uint64)]
+            )[esel]
+            next_buf, frontier_ovf = emit_append(next_buf, blk, ncount, n_new, F)
+            jps, journal_ovf = emit_append(jps, jps_blk, jcount, n_new, JC)
+            jpl, _ = emit_append(jpl, jpl_blk, jcount, n_new, JC)
+            jcand, _ = emit_append(jcand, jc_blk, jcount, n_new, JC)
+            jfp, _ = emit_append(jfp, jfp_blk, jcount, n_new, JC)
+            if K:
+                # new-distinct per rank on the owner chip (non-new lanes ->
+                # drop bucket K; their routed rank column may be garbage 0s
+                # from unfilled send slots, but `new` masks them out)
+                recv_rank = recv_pay[sidx, W + 2]
+                new_k = jax.ops.segment_sum(
+                    new.astype(jnp.int64), jnp.where(new, recv_rank, K),
+                    num_segments=K + 1,
+                )[:K]
+                cov = cov + jnp.concatenate(
+                    [cov_gen, new_k[:, None]], axis=1)
+            # the chip's new fps as one sorted run (LSM level-0 insert)
+            new_run = sort_u64(jnp.where(new, rf, U64_MAX))
+            DRC = new_run.shape[0]
+            if self.R0 > DRC:
+                new_run = jnp.concatenate(
+                    [new_run, jnp.full((self.R0 - DRC,), U64_MAX, jnp.uint64)]
+                )
+
+            # 8. invariants on the received candidates; fold first-bad jidx
+            jidx = jnp.where(new, jcount + npos, I32_MAX)
+            for k, name in enumerate(self.invariants):
+                okv = model.invariants[name](states_s)
+                bad = new & ~okv
+                viol = viol.at[k].min(jnp.min(jnp.where(bad, jidx, I32_MAX)))
+
+            ovf_bits = (
+                pre_stats[2]
+                + 8 * frontier_ovf.astype(jnp.int64)
+                + 16 * journal_ovf.astype(jnp.int64)
             )
-            fresh = fresh & ~hit
-        new = fresh
-        n_new = jnp.sum(new)
-
-        # 7. emit survivors: compact to a dense prefix of a [D*RC, W]
-        # block, then ONE dynamic_update_slice per buffer appends at the
-        # running cursor (rows [F, F+D*RC) / [JC, JC+D*RC) are the drop
-        # region — checker/util.py emit_append; same redesign as
-        # DeviceBFS._chunk_step step 5, retiring full-capacity scatters)
-        ncount = stats[0].astype(jnp.int32)
-        jcount = stats[1].astype(jnp.int32)
-        npos = (jnp.cumsum(new) - 1).astype(jnp.int32)
-        states_s = recv_pay[sidx, :W]
-        B = D * RC
-        esel = dense_prefix_sel(new, npos, B)
-        blk = jnp.concatenate(
-            [states_s, jnp.zeros((1, W), jnp.int32)], axis=0
-        )[esel]
-        jps_blk = jnp.concatenate(
-            [(sidx // RC).astype(jnp.int32), jnp.zeros((1,), jnp.int32)]
-        )[esel]
-        jpl_blk = jnp.concatenate(
-            [recv_pay[sidx, W], jnp.zeros((1,), jnp.int32)]
-        )[esel]
-        jc_blk = jnp.concatenate(
-            [recv_pay[sidx, W + 1], jnp.zeros((1,), jnp.int32)]
-        )[esel]
-        jfp_blk = jnp.concatenate(
-            [rf, jnp.full((1,), U64_MAX, jnp.uint64)]
-        )[esel]
-        next_buf, frontier_ovf = emit_append(next_buf, blk, ncount, n_new, F)
-        jps, journal_ovf = emit_append(jps, jps_blk, jcount, n_new, JC)
-        jpl, _ = emit_append(jpl, jpl_blk, jcount, n_new, JC)
-        jcand, _ = emit_append(jcand, jc_blk, jcount, n_new, JC)
-        jfp, _ = emit_append(jfp, jfp_blk, jcount, n_new, JC)
-        if K:
-            # new-distinct per rank on the owner chip (non-new lanes ->
-            # drop bucket K; their routed rank column may be garbage 0s
-            # from unfilled send slots, but `new` masks them out)
-            recv_rank = recv_pay[sidx, W + 2]
-            new_k = jax.ops.segment_sum(
-                new.astype(jnp.int64), jnp.where(new, recv_rank, K),
-                num_segments=K + 1,
-            )[:K]
-            cov = cov + jnp.concatenate(
-                [cov_gen, new_k[:, None]], axis=1)
-        # the chip's new fps as one sorted run (LSM level-0 insert)
-        new_run = sort_u64(jnp.where(new, rf, U64_MAX))
-        DRC = new_run.shape[0]
-        if self.R0 > DRC:
-            new_run = jnp.concatenate(
-                [new_run, jnp.full((self.R0 - DRC,), U64_MAX, jnp.uint64)]
+            stats = jnp.stack(
+                [
+                    stats[0] + n_new,
+                    stats[1] + n_new,
+                    stats[2] + pre_stats[0],
+                    stats[3] + pre_stats[1],
+                    stats[4] | ovf_bits,
+                    stats[5] + pre_stats[3],
+                    stats[6] + pre_stats[4],
+                ]
             )
-
-        # 8. invariants on the received candidates; fold first-bad jidx
-        jidx = jnp.where(new, jcount + npos, I32_MAX)
-        for k, name in enumerate(self.invariants):
-            okv = model.invariants[name](states_s)
-            bad = new & ~okv
-            viol = viol.at[k].min(jnp.min(jnp.where(bad, jidx, I32_MAX)))
-
-        ovf_bits = (
-            pre_stats[2]
-            + 8 * frontier_ovf.astype(jnp.int64)
-            + 16 * journal_ovf.astype(jnp.int64)
-        )
-        stats = jnp.stack(
-            [
-                stats[0] + n_new,
-                stats[1] + n_new,
-                stats[2] + pre_stats[0],
-                stats[3] + pre_stats[1],
-                stats[4] | ovf_bits,
-                stats[5] + pre_stats[3],
-                stats[6] + pre_stats[4],
-            ]
-        )
         return next_buf, jps, jpl, jcand, jfp, viol, stats, cov, new_run
 
     # ---------------- capacity growth (between waves, host-mediated) ------
@@ -745,6 +754,13 @@ class ShardedBFS:
         ncount = int(fcounts.max())
         jc = int(jcounts.max())
         D, W = self.D, self.W
+        grow_f = ncount * self.HEADROOM > self.FCAP and self.FCAP < self.MAX_FCAP
+        grow_j = (
+            jc + ncount * self.HEADROOM > self.JCAP
+            and self.JCAP < self.MAX_JCAP
+        )
+        if not (grow_f or grow_j):
+            return state
 
         def repad(key, new_rows, old_rows, fill, cols=None):
             h = np.asarray(jax.device_get(state[key]))
@@ -753,21 +769,26 @@ class ShardedBFS:
             out[:, :old_rows] = h
             state[key] = jax.device_put(out, self._sharding)
 
-        if ncount * self.HEADROOM > self.FCAP and self.FCAP < self.MAX_FCAP:
-            new = _next_cap(ncount * self.HEADROOM, self.FCAP, self.MAX_FCAP,
-                            self.GROWTH, self.chunk)
-            repad("frontier", new + self.EPAD, self.FCAP + self.EPAD, 0, cols=W)
-            state["next_buf"] = jax.device_put(
-                np.zeros((D, new + self.EPAD, W), np.int32), self._sharding)
-            self.FCAP = new
-        if jc + ncount * self.HEADROOM > self.JCAP and self.JCAP < self.MAX_JCAP:
-            new = _next_cap(jc + ncount * self.HEADROOM, self.JCAP,
-                            self.MAX_JCAP, self.GROWTH, 1)
-            for key in ("jps", "jpl", "jcand"):
-                repad(key, new + self.EPAD, self.JCAP + self.EPAD, 0)
-            repad("jfp", new + self.EPAD, self.JCAP + self.EPAD,
-                  np.uint64(U64_MAX))
-            self.JCAP = new
+        # the `grow` span and the row's grow_s exist only on a wave that
+        # grows (as in DeviceBFS._maybe_grow)
+        with self._ph("grow"):
+            if grow_f:
+                new = _next_cap(ncount * self.HEADROOM, self.FCAP,
+                                self.MAX_FCAP, self.GROWTH, self.chunk)
+                repad("frontier", new + self.EPAD, self.FCAP + self.EPAD, 0,
+                      cols=W)
+                state["next_buf"] = jax.device_put(
+                    np.zeros((D, new + self.EPAD, W), np.int32),
+                    self._sharding)
+                self.FCAP = new
+            if grow_j:
+                new = _next_cap(jc + ncount * self.HEADROOM, self.JCAP,
+                                self.MAX_JCAP, self.GROWTH, 1)
+                for key in ("jps", "jpl", "jcand"):
+                    repad(key, new + self.EPAD, self.JCAP + self.EPAD, 0)
+                repad("jfp", new + self.EPAD, self.JCAP + self.EPAD,
+                      np.uint64(U64_MAX))
+                self.JCAP = new
         return state
 
     def grow_for_overflow(self, bits: int) -> dict | None:
@@ -836,6 +857,18 @@ class ShardedBFS:
         # seen_override: wave-start per-shard fingerprints computed by
         # _wave_start_seen when the LSM is contaminated by an aborted
         # wave (overflow / shard-loss abort paths)
+        with self._ph("checkpoint"):
+            self._write_checkpoint(
+                path, state, fcounts, scounts, jcounts, n0, base_lgid,
+                distinct, total, terminal, depth, gen_prev, routed_prev,
+                depth_counts, coverage, seen_override,
+            )
+
+    def _write_checkpoint(
+        self, path, state, fcounts, scounts, jcounts, n0, base_lgid,
+        distinct, total, terminal, depth, gen_prev, routed_prev, depth_counts,
+        coverage, seen_override,
+    ):
         seen = self._lsm_export() if seen_override is None else seen_override
         assert [len(s) for s in seen] == [int(x) for x in scounts], (
             "LSM export does not match per-shard scounts"
@@ -1224,6 +1257,7 @@ class ShardedBFS:
 
     # ---------------- host driver ----------------
 
+    @traced_run("sharded")
     def run(
         self,
         max_depth: int | None = None,
@@ -1242,6 +1276,13 @@ class ShardedBFS:
     ) -> ShardedResult:
         model, D, W, C = self.model, self.D, self.W, self.chunk
         t0 = time.perf_counter()
+        # host spans (obs/trace.py), as in DeviceBFS.run: `init`, one
+        # `wave` per loop iteration, `finish`; a wave's phases are
+        # bracketed once, for the trace and the row — here `dispatch`,
+        # `seen_merge` and (mid-wave, on shard loss) `fetch` once a chunk
+        ph = self._ph
+        ph.top("init")
+        comp_run = COMPILES.snapshot()
         exhausted = True
         exit_cause = None
         self._ckpt_keep = checkpoint_keep
@@ -1437,7 +1478,6 @@ class ShardedBFS:
             MemWatch(tel, device_budget(self.mesh.devices.flat[0]))
             if tel.active else None
         )
-        tel_s_last = 0.0
         routed_prev_d = np.zeros(D, np.int64)  # per-shard a2a cums
 
         while fcounts.sum() and violation is None:
@@ -1460,6 +1500,9 @@ class ShardedBFS:
                 exhausted = False
                 exit_cause = "time_budget"
                 break
+            ph.wave(self._run_id, depth + 1, int(fcounts.sum()))
+            tw = time.perf_counter()
+            comp_wave = COMPILES.snapshot()
             # top-absorb capacity guard, per chip (see DeviceBFS.run):
             # conservative — a chip's wave-new count is bounded by FCAP
             # and by the WHOLE mesh's routed candidates (fp%D routing can
@@ -1479,7 +1522,6 @@ class ShardedBFS:
                     what=("seen",), bits=self.SEEN_OVF_BIT,
                     checkpoint_saved=checkpoint_path is not None,
                 )
-            tw = time.perf_counter()
             fc_dev = jax.device_put(
                 fcounts.astype(np.int32).reshape(D, 1), self._sharding)
             bl_dev = jax.device_put(
@@ -1495,57 +1537,63 @@ class ShardedBFS:
                 for cursor in range(0, max_fc, C):
                     occ_dev = self._occ_dev()
                     if tl_sample:
-                        pre_fn, ex_fn, post_fn = self._get_timeline_fns(
-                            len(self._lsm.runs))
-                        t1 = time.perf_counter()
-                        (send_pay, send_fps, state["memo"], cov_gen,
-                         pre_stats) = pre_fn(
-                            state["frontier"], fc_dev, state["memo"],
-                            np.int32(cursor), bl_dev,
-                        )
-                        # lint: sync-ok(stage attribution on a sampled wave)
-                        jax.block_until_ready(
+                        with ph("dispatch"):
+                            pre_fn, ex_fn, post_fn = self._get_timeline_fns(
+                                len(self._lsm.runs))
+                            t1 = time.perf_counter()
                             (send_pay, send_fps, state["memo"], cov_gen,
-                             pre_stats))
-                        t2 = time.perf_counter()
-                        stage_s["expand"] += t2 - t1
-                        recv_pay, recv_fps = ex_fn(send_pay, send_fps)
-                        # lint: sync-ok(stage attribution on a sampled wave)
-                        jax.block_until_ready((recv_pay, recv_fps))
-                        t3 = time.perf_counter()
-                        stage_s["exchange"] += t3 - t2
-                        (state["next_buf"], state["jps"], state["jpl"],
-                         state["jcand"], state["jfp"], state["viol"],
-                         state["stats"], state["cov"], new_run,
-                         ) = post_fn(
-                            recv_pay, recv_fps, state["next_buf"],
-                            state["jps"], state["jpl"], state["jcand"],
-                            state["jfp"], state["viol"], state["stats"],
-                            state["cov"], cov_gen, pre_stats, occ_dev,
-                            *self._lsm.runs,
-                        )
-                        # lint: sync-ok(stage attribution on a sampled wave)
-                        jax.block_until_ready(new_run)
-                        t4 = time.perf_counter()
-                        stage_s["emit"] += t4 - t3
-                        self._lsm.insert(new_run)
-                        # lint: sync-ok(stage attribution on a sampled wave)
-                        jax.block_until_ready(self._lsm.runs)
+                             pre_stats) = pre_fn(
+                                state["frontier"], fc_dev, state["memo"],
+                                np.int32(cursor), bl_dev,
+                            )
+                            # lint: sync-ok(stage attribution on a sampled wave)
+                            jax.block_until_ready(
+                                (send_pay, send_fps, state["memo"], cov_gen,
+                                 pre_stats))
+                            t2 = time.perf_counter()
+                            stage_s["expand"] += t2 - t1
+                            recv_pay, recv_fps = ex_fn(send_pay, send_fps)
+                            # lint: sync-ok(stage attribution on a sampled wave)
+                            jax.block_until_ready((recv_pay, recv_fps))
+                            t3 = time.perf_counter()
+                            stage_s["exchange"] += t3 - t2
+                            (state["next_buf"], state["jps"], state["jpl"],
+                             state["jcand"], state["jfp"], state["viol"],
+                             state["stats"], state["cov"], new_run,
+                             ) = post_fn(
+                                recv_pay, recv_fps, state["next_buf"],
+                                state["jps"], state["jpl"], state["jcand"],
+                                state["jfp"], state["viol"], state["stats"],
+                                state["cov"], cov_gen, pre_stats, occ_dev,
+                                *self._lsm.runs,
+                            )
+                            # lint: sync-ok(stage attribution on a sampled wave)
+                            jax.block_until_ready(new_run)
+                            t4 = time.perf_counter()
+                            stage_s["emit"] += t4 - t3
+                        with ph("seen_merge"):
+                            self._lsm.insert(new_run)
+                            # lint: sync-ok(stage attribution on a sampled wave)
+                            jax.block_until_ready(self._lsm.runs)
                         stage_s["seen_merge"] += time.perf_counter() - t4
                     else:
-                        chunk_fn = self._get_chunk_fn(len(self._lsm.runs))
-                        (state["next_buf"], state["jps"], state["jpl"],
-                         state["jcand"], state["jfp"], state["viol"],
-                         state["stats"], state["memo"], state["cov"],
-                         new_run,
-                         ) = chunk_fn(
-                            state["frontier"], fc_dev, state["next_buf"],
-                            state["jps"], state["jpl"], state["jcand"],
-                            state["jfp"], state["viol"], state["stats"],
-                            state["memo"], state["cov"], np.int32(cursor),
-                            occ_dev, bl_dev, *self._lsm.runs,
-                        )
-                        self._lsm.insert(new_run)
+                        with ph("dispatch"):
+                            chunk_fn = self._get_chunk_fn(len(self._lsm.runs))
+                            (state["next_buf"], state["jps"], state["jpl"],
+                             state["jcand"], state["jfp"], state["viol"],
+                             state["stats"], state["memo"], state["cov"],
+                             new_run,
+                             ) = chunk_fn(
+                                state["frontier"], fc_dev,
+                                state["next_buf"], state["jps"],
+                                state["jpl"], state["jcand"], state["jfp"],
+                                state["viol"], state["stats"],
+                                state["memo"], state["cov"],
+                                np.int32(cursor), occ_dev, bl_dev,
+                                *self._lsm.runs,
+                            )
+                        with ph("seen_merge"):
+                            self._lsm.insert(new_run)
                     chunks_done += 1
                     if chaos is not None:
                         lost = chaos.shard_loss(depth + 1, D)
@@ -1557,9 +1605,10 @@ class ShardedBFS:
                             # jfp lane recorded exactly those), classify,
                             # and let the supervisor reshard onto the
                             # survivors
-                            # lint: sync-ok(wave-start spill on shard loss)
-                            stats_mid = np.asarray(
-                                jax.device_get(state["stats"]))
+                            with ph("fetch"):
+                                # lint: sync-ok(wave-start spill on shard loss)
+                                stats_mid = np.asarray(
+                                    jax.device_get(state["stats"]))
                             saved = self._abort_wave_start(
                                 checkpoint_path, state, stats_mid,
                                 fcounts, scounts, jcounts, n0, base_lgid,
@@ -1579,9 +1628,10 @@ class ShardedBFS:
                             )
                 # cov rides the same once-per-wave fetch — no extra
                 # device_get calls with coverage on
-                # lint: sync-ok(once-per-wave snapshot)
-                stats_h, viol_h, cov_w = jax.device_get(
-                    (state["stats"], state["viol"], state["cov"]))
+                with ph("fetch"):
+                    # lint: sync-ok(once-per-wave snapshot)
+                    stats_h, viol_h, cov_w = jax.device_get(
+                        (state["stats"], state["viol"], state["cov"]))
             stats_h = np.asarray(stats_h)  # [D,7]
             viol_h = np.asarray(viol_h)  # [D,K]
             new_d = stats_h[:, 0]
@@ -1644,11 +1694,6 @@ class ShardedBFS:
                         checkpoint_saved=saved,
                     )
             wave_times.append(wave_s_now)
-            # phase split: everything up to the stats fetch is device-
-            # blocked time; checkpoint I/O is bracketed below; the
-            # residual (growth, LSM bookkeeping) lands in host_s
-            device_s = wave_s_now
-            ckpt_s = 0.0
             # commit only after the ovf check: an aborted wave keeps the
             # wave-start counters (consistent with what a checkpoint saved)
             cov_hd = np.asarray(cov_w, dtype=np.int64)
@@ -1704,23 +1749,34 @@ class ShardedBFS:
                     checkpoint_path is not None
                     and time.perf_counter() - last_ckpt > checkpoint_every_s
                 ):
-                    t_ck = time.perf_counter()
-                    with tel.annotate("checkpoint"):
-                        self._save_checkpoint(
-                            checkpoint_path, state, fcounts, scounts,
-                            jcounts, n0, base_lgid, distinct, total,
-                            terminal + term_base, depth,
-                            gen_prev + gen_base,
-                            routed_prev + routed_base, depth_counts,
-                            cov_hd,
-                        )
+                    self._save_checkpoint(
+                        checkpoint_path, state, fcounts, scounts,
+                        jcounts, n0, base_lgid, distinct, total,
+                        terminal + term_base, depth,
+                        gen_prev + gen_base,
+                        routed_prev + routed_base, depth_counts,
+                        cov_hd,
+                    )
                     last_ckpt = time.perf_counter()
-                    ckpt_s = last_ckpt - t_ck
-                    stage_s["checkpoint"] += ckpt_s
+                    stage_s["checkpoint"] += ph.s["checkpoint"]
             wave_s_val = time.perf_counter() - tw
+            # the wave's brackets, read once (DeviceBFS.run): device_s is
+            # the host's wait on the device — every chunk's dispatch and
+            # LSM insert plus the blocking fetch — and what the brackets
+            # leave of the wave (growth, consolidation, loop bookkeeping)
+            # is host_s; `telemetry` is the previous wave's bracket
+            ph_s = ph.take()
+            dispatch_s = ph_s.get("dispatch", 0.0)
+            fetch_s = ph_s.get("fetch", 0.0)
+            merge_s = ph_s.get("seen_merge", 0.0)
+            device_s = dispatch_s + fetch_s + merge_s
+            ckpt_s = ph_s.get("checkpoint", 0.0)
+            comp_now = COMPILES.snapshot()
             if tl_every:
                 (tl_wave_s if tl_sample else fused_wave_s).append(wave_s_val)
-            if tel.active or metrics is not None or verbose:
+            if not (tel.active or metrics is not None or verbose):
+                continue
+            with ph("telemetry"):
                 el = time.perf_counter() - t0
                 hbm_frac = None
                 if memwatch is not None:
@@ -1755,14 +1811,24 @@ class ShardedBFS:
                         wave_memo / max(1, wave_gen), 4
                     ),
                     "overflow_bits": ovf_bits,
-                    "wave_s": round(wave_s_val, 3),
-                    "elapsed_s": round(el, 3),
+                    "wave_s": wave_s_val,
+                    "elapsed_s": el,
                     "distinct_per_s": round(distinct / el, 1),
-                    "device_s": round(device_s, 4),
-                    "host_s": round(
-                        max(0.0, wave_s_val - device_s - ckpt_s), 4),
-                    "ckpt_s": round(ckpt_s, 4),
-                    "tel_s": round(tel_s_last, 4),
+                    # unrounded: device_s + host_s + ckpt_s == wave_s,
+                    # device_s == dispatch_s + fetch_s + merge_s
+                    "device_s": device_s,
+                    "host_s": max(0.0, wave_s_val - device_s - ckpt_s),
+                    "ckpt_s": ckpt_s,
+                    "tel_s": ph_s.get("telemetry", 0.0),
+                    "dispatch_s": dispatch_s,
+                    "fetch_s": fetch_s,
+                    "merge_s": merge_s,
+                    "grow_s": ph_s.get("grow", 0.0),
+                    # programs this iteration loaded and the seconds
+                    # that took (obs/compiles.py): a chunk program for a
+                    # new LSM level count names its wave
+                    "compiles": comp_now[0] - comp_wave[0],
+                    "compile_s": comp_now[1] - comp_wave[1],
                     # exchange share of the sampled wave's staged device
                     # seconds; null on fused (unsampled) waves — the
                     # fused program cannot separate the all-to-all
@@ -1796,7 +1862,6 @@ class ShardedBFS:
                     ),
                     "expand_budget_ovf": (ovf_bits >> 1) & 1,
                 }
-                t_tel = time.perf_counter()
                 tel.wave(wm)
                 if tel.active:
                     tel.coverage(self._coverage_fields(
@@ -1839,8 +1904,8 @@ class ShardedBFS:
                         f"balance={new_d.min()}/{new_d.max()} "
                         f"({distinct/el:.0f} distinct/s)",
                         file=sys.stderr)
-                tel_s_last = time.perf_counter() - t_tel
 
+        ph.top("finish")
         if (checkpoint_path is not None and violation is None
                 and not exhausted):
             self._save_checkpoint(
@@ -1866,6 +1931,7 @@ class ShardedBFS:
         # already fetched — also returned on ShardedResult.stats
         fleet_rate = round(memo_prev / max(1, gen_prev), 4)
         fleet_cov = cov_hd.sum(axis=0)
+        run_stats = COMPILES.run_stats(comp_run)
         fleet_stats = {
             "canon_memo_hits": memo_prev,
             "canon_memo_hit_rate": fleet_rate,
@@ -1874,6 +1940,8 @@ class ShardedBFS:
             "shard_skew": round(
                 int(scounts.max()) / max(1, int(scounts.min())), 3),
             "coverage": [[int(x) for x in row] for row in fleet_cov],
+            # what the run loaded into the process (obs/compiles.py)
+            **run_stats,
         }
         # final canon-memo fill ratio: one device reduction, done whether
         # or not telemetry is attached so the zero-sync guarantee (equal
@@ -1923,6 +1991,7 @@ class ShardedBFS:
             # sharded extras (schema allows extra keys)
             "shard_memo_hits": fleet_stats["shard_memo_hits"],
             "shard_skew": fleet_stats["shard_skew"],
+            **run_stats,
             **tl_extras,
             **(memwatch.summary_fields() if memwatch is not None else {}),
         })

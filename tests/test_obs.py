@@ -15,6 +15,8 @@ The headline guarantees pinned here:
 
 import io
 import json
+import re
+import time
 
 import pytest
 
@@ -798,3 +800,222 @@ def test_cli_timeline_smoke_and_bench_gate(tmp_path, capsys):
     assert gate_main([str(mpath), str(tight)]) == 3
     cap = capsys.readouterr()
     assert "GATE FAIL distinct" in cap.err
+
+
+# ------------------------------------------------- the tracing spine
+#
+# Device side: every stage of the chunk pipeline is a jax.named_scope
+# (obs.stage) inside the programs the engines dispatch, so a profile
+# names its ops by stage. Host side: the wave loop's spans are written
+# by the engines themselves into whatever jax.profiler session is open,
+# whichever telemetry facade they were handed; the same brackets feed
+# the wave rows. Compile counters come from the program (obs.COMPILES).
+
+
+def _sharded(n_dev=4, **kw):
+    import jax
+
+    from raft_tpu.parallel.sharded import ShardedBFS
+
+    kw.setdefault("chunk", 512)
+    kw.setdefault("frontier_cap", 1024)
+    kw.setdefault("seen_cap", 1 << 12)
+    return ShardedBFS(
+        cached_model(SMALL), invariants=INVS, symmetry=True,
+        devices=jax.devices()[:n_dev], **kw)
+
+
+_LOWERED: dict = {}
+
+
+def _lowered_text(engine: str, program: str) -> str:
+    """Lowered text, with debug info, of one production program (the
+    audit surface hands out the jit objects and abstract arguments);
+    lowered once per test process, nothing compiled or run."""
+    key = (engine, program)
+    if key not in _LOWERED:
+        eng = _device() if engine == "device" else _sharded()
+        (entry,) = [e for e in eng.audit_programs() if e["name"] == program]
+        _LOWERED[key] = entry["fn"].lower(*entry["args"]).as_text(
+            debug_info=True)
+    return _LOWERED[key]
+
+
+@pytest.mark.parametrize("engine,program,stage", [
+    ("device", "wave", "expand"),
+    ("device", "wave", "canon"),
+    ("device", "wave", "dedup"),
+    ("device", "wave", "emit"),
+    ("device", "wave", "seen_merge"),
+    ("device", "seen_merge", "seen_merge"),
+    ("sharded", "chunk", "expand"),
+    ("sharded", "chunk", "canon"),
+    ("sharded", "chunk", "exchange"),
+    ("sharded", "chunk", "dedup"),
+    ("sharded", "chunk", "emit"),
+])
+def test_stage_scope_in_lowered_program(engine, program, stage):
+    assert stage in TIMELINE_STAGES
+    # a location reads "jit(_wave_step)/while/body/canon/...", or, inside
+    # a shard_map, starts at the scope: "canon/..."
+    assert re.search(rf'["/]{stage}/', _lowered_text(engine, program)), (
+        f"no op of {engine}:{program} carries the {stage!r} scope")
+
+
+def test_stage_scope_in_lsm_merge_program():
+    """The sharded engine's seen merge is RunLSM's, a program of its
+    own per (na, nb, out) signature."""
+    import jax
+    import jax.numpy as jnp
+
+    from raft_tpu.checker.lsm import RunLSM
+
+    body, _ = RunLSM.merge_spec(8, 8)
+    run = jax.ShapeDtypeStruct((8,), jnp.uint64)
+    text = jax.jit(body).lower(run, run).as_text(debug_info=True)
+    assert "/seen_merge/" in text
+
+
+def _host_spans(trace_dir):
+    """[(start_ns, end_ns, name, stats)] of the host plane, by start."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(f"{trace_dir}/plugins/profile/*/*.xplane.pb")
+    spans = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            spans.extend(
+                (e.start_ns, e.start_ns + e.duration_ns, e.name,
+                 dict(e.stats))
+                for e in line.events)
+    return sorted(spans, key=lambda s: (s[0], -s[1]))
+
+
+@pytest.mark.parametrize("facade", ["null", "wave_clock"])
+def test_program_spans_in_any_profiler_session(tmp_path, facade):
+    import jax
+
+    eng = _device()
+    eng.run(max_depth=4)  # compile outside the trace
+    # the benchmark's own facade: the do-nothing one for everything but
+    # wave_annotation, where it reads its clock
+    from benchmark.adapter import WaveClock
+
+    tel = None if facade == "null" else WaveClock(time.perf_counter)
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        eng.run(max_depth=4, telemetry=tel)
+    finally:
+        jax.profiler.stop_trace()
+    if tel is not None:
+        assert len(tel.stamps) == 4
+    spans = _host_spans(str(tmp_path))
+    by_name = {}
+    for sp in spans:
+        by_name.setdefault(sp[2], []).append(sp)
+    assert "verdict" not in by_name  # the benchmark's own span name
+    (run,) = by_name["run"]
+    (init,) = by_name["init"]
+    (finish,) = by_name["finish"]
+    assert run[3]["engine"] == "device" and run[3]["run"] >= 2
+    waves = by_name["wave"]
+    assert [w[3]["depth"] for w in waves] == [1, 2, 3, 4]
+    assert all(w[3]["run"] == run[3]["run"] for w in waves)
+    # init, the waves and finish tile the run, in that order
+    tops = [init, *waves, finish]
+    assert all(run[0] <= sp[0] and sp[1] <= run[1] for sp in tops)
+    assert all(a[1] <= b[0] for a, b in zip(tops, tops[1:]))
+    # each wave holds one dispatch, one fetch and one seen_merge
+    for w in waves:
+        inside = [sp[2] for sp in spans
+                  if w[0] <= sp[0] and sp[1] <= w[1] and sp is not w]
+        for name in ("dispatch", "fetch", "seen_merge"):
+            assert inside.count(name) == 1, (w[3], name, inside)
+
+
+def _rows_of(engine):
+    if engine == "device":
+        return _device().run(max_depth=4, collect_metrics=True).metrics
+    if engine == "host":
+        from raft_tpu.checker.bfs import BFSChecker
+
+        return BFSChecker(
+            cached_model(SMALL), invariants=INVS, symmetry=True, chunk=256,
+        ).run(max_depth=4, collect_metrics=True).metrics
+    return _sharded(2, frontier_cap=2048, seen_cap=1 << 13).run(
+        max_depth=4, collect_metrics=True).metrics
+
+
+@pytest.mark.parametrize("engine", [
+    "device", "host", pytest.param("sharded", marks=pytest.mark.slow)])
+def test_wave_row_clocks_unrounded_and_add_up(engine):
+    rows = _rows_of(engine)
+    assert [r["depth"] for r in rows] == [1, 2, 3, 4]
+    for r in rows:
+        assert r["device_s"] + r["host_s"] + r["ckpt_s"] == pytest.approx(
+            r["wave_s"], abs=1e-9)
+        if engine != "host":
+            assert r["dispatch_s"] + r["fetch_s"] + r["merge_s"] == (
+                pytest.approx(r["device_s"], abs=1e-9))
+            assert r["grow_s"] == 0.0
+    # perf_counter differences, not values rounded to a millisecond
+    for key in ("wave_s", "elapsed_s", "device_s", "host_s"):
+        assert any(r[key] != round(r[key], 4) for r in rows), key
+    assert all(a["elapsed_s"] < b["elapsed_s"] for a, b in zip(rows, rows[1:]))
+
+
+def test_unrounded_stream_still_validates(tmp_path):
+    from scripts.check_metrics_schema import main
+
+    path = tmp_path / "m.jsonl"
+    with Telemetry(metrics_path=str(path)) as tel:
+        _device().run(max_depth=4, telemetry=tel)
+    assert main([str(path)]) == 0
+    wave = tel.wave_events()[-1]
+    for key in ("dispatch_s", "fetch_s", "merge_s", "grow_s", "compiles",
+                "compile_s"):
+        assert key in wave, key
+    for key in ("programs_loaded", "run_compiles", "run_compile_s",
+                "run_cache_hits"):
+        assert key in tel.last_summary, key
+
+
+def test_compile_counters_by_run_and_by_wave():
+    from raft_tpu.obs import COMPILES
+
+    eng = _device()
+    first = eng.run(max_depth=4, collect_metrics=True)
+    again = eng.run(max_depth=4, collect_metrics=True)
+    assert first.stats["run_compiles"] >= 1
+    assert first.stats["run_compile_s"] > 0
+    assert again.stats["run_compiles"] == 0
+    assert again.stats["run_compile_s"] == 0
+    assert all(r["compiles"] == 0 for r in again.metrics)
+    # cumulative in the process, never falling
+    assert first.stats["programs_loaded"] <= again.stats["programs_loaded"]
+    assert again.stats["programs_loaded"] == COMPILES.loaded
+
+
+def test_growth_compile_is_booked_to_its_wave():
+    """Capacities so tiny that the journal outgrows them mid-run."""
+    eng = _device(chunk=32, frontier_cap=32, journal_cap=32)
+    rows = eng.run(collect_metrics=True).metrics
+    grew = [i for i, r in enumerate(rows) if r["grow_s"] > 0]
+    assert grew and grew[0] > 1, (
+        "nothing grew mid-run: the capacities are not tiny enough")
+    first = grew[0]
+    # wave 1 compiles the wave program; then nothing is loaded until the
+    # wave that grows loads the programs that re-shape its buffers, and
+    # the wave after it the wave program at the new shapes
+    assert rows[0]["compiles"] >= 1
+    assert all(r["compiles"] == 0 and r["grow_s"] == 0
+               for r in rows[1:first])
+    assert rows[first]["compiles"] >= 1 and rows[first]["compile_s"] > 0
+    assert rows[first + 1]["compiles"] >= 1
+    assert rows[first + 1]["compile_s"] <= rows[first + 1]["dispatch_s"]
