@@ -6,9 +6,11 @@ Level i holds at most one sorted u64 run of ``min(R0 << i, TOPSZ)`` lanes
 0; two runs at the same level merge (sort-concat — measured faster than
 scatter-merges on this TPU) into the next level, exactly a binary
 counter; the TOPSZ top level absorbs by truncate-merge (sound only while
-the engine's capacity guard holds, see the callers). Probing costs one
-searchsorted per OCCUPIED level; per-chunk dedup cost is therefore
-independent of the total state count.
+the engine's capacity guard holds, see the callers). A chunk looks its
+fingerprints up by sorting them together with every level short enough
+to sort once a chunk, occupied or not, and binary-searches only the
+OCCUPIED levels above that crossover (checker/util.py first_new); per-
+chunk dedup cost is therefore independent of the total state count.
 
 Lanes live on the LAST axis: DeviceBFS uses [lanes] arrays, ShardedBFS
 [D, lanes] sharded arrays — the per-row sorts/concats are identical code,

@@ -299,8 +299,10 @@ def profile_stages(
     else:
         st["canon_tier3_local"] = 0.0
 
-    # ---- stage 4: probe the occupied LSM runs (production skips empty
-    # levels via cond, so the occupied set is what a chunk pays for) ----
+    # ---- stage 4: binary-search the occupied LSM runs. Production
+    # (util.first_new) does this only to a run past the merge crossover
+    # and sorts the others with the chunk, so on the chip this bucket
+    # over-counts by the gathers it no longer pays (PERF.md, PR 25) ----
     def probe_all(f, *rs):
         hit = jnp.zeros(f.shape, bool)
         for r in rs:

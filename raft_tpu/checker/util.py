@@ -11,20 +11,107 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from ..ops.hashing import eq_u64
+from ..ops.hashing import eq_u64, ne_u64, split_u64
 
 GROWTH = 4  # enlarge factor per growth step
 HEADROOM = 3  # grow when the next wave could need more than cap/HEADROOM
 I32_MAX = np.int32(2**31 - 1)  # "no violation" sentinel in journal folds
+_U32_MAX = np.uint32(0xFFFFFFFF)
+
+
+# A sorted run of at most this many lanes per query lane is looked up by
+# the one merged sort of ``first_new``; a longer one by binary search.
+# Measured once on the v5e at 65,536 queries (scripts/probe_micro.py;
+# PERF.md section 6, PR 25, has the table): what a run of 2^21 lanes adds
+# to a chunk-step is 9.2 ms merged and 22.3 ms searched, one of 2^22
+# lanes 21.8 and 23.3 (and the search's gathers cost more inside the wave
+# program than alone), one of 2^23 lanes 48.8 and 24.3.
+MERGE_LANES_PER_QUERY = 64
 
 
 def probe_sorted(sorted_arr, vals):
-    """Membership of vals in a sorted u64 array padded with U64_MAX.
-    (u64 searchsorted is fast on this TPU; elementwise u64 == is not —
-    the equality check decomposes to u32, ops/hashing.py.)"""
+    """Membership of vals in a sorted u64 array padded with U64_MAX, by
+    binary search. Each of its log2(S)+1 steps is a gather of one lane a
+    query, and gathers are serial on this TPU: 467.5 us a step for
+    65,536 queries whatever they hold, where a 720,896-lane 2-key sort
+    takes 1.187 ms (benchmark/testdata/scoped_v5e, PR 24). So it pays
+    only for a run too long to sort once a chunk (``first_new``)."""
     pos = jnp.searchsorted(sorted_arr, vals)
     pos = jnp.clip(pos, 0, sorted_arr.shape[0] - 1)
     return eq_u64(sorted_arr[pos], vals)
+
+
+def merges(run_lanes: int, n_queries: int) -> bool:
+    """Whether ``first_new`` merges a run of ``run_lanes`` lanes with
+    ``n_queries`` queries or searches it: the static choice, from shapes
+    alone."""
+    return run_lanes <= MERGE_LANES_PER_QUERY * n_queries
+
+
+def dedup_plan(run_lanes, n_queries: int) -> dict:
+    """``first_new``'s choice for runs of ``run_lanes`` lanes, as the
+    engines' run records carry it: the sizes merged, the sizes searched
+    and the lanes sorted a chunk-step."""
+    merge = [int(n) for n in run_lanes if merges(n, n_queries)]
+    return {
+        "merge": merge,
+        "search": [int(n) for n in run_lanes if not merges(n, n_queries)],
+        "sort_lanes": sum(merge) + int(n_queries),
+    }
+
+
+def first_new(vals, occ, runs):
+    """bool[n] in lane order: lane i holds a value that is not U64_MAX,
+    is in none of the sorted U64_MAX-padded ``runs``, and is in no lower
+    lane of ``vals`` — the seen-set probe and first-occurrence-in-chunk
+    as one rule.
+
+    Membership by merging, not searching: the runs that ``merges``
+    says to merge, then ``vals``, are sorted together once as u32 pairs,
+    stably, with a payload that is 0 on a run's lane and lane + 1 on a
+    query's. A run's element therefore comes before an equal query and
+    equal queries keep lane order, and a lane is new iff it is a query,
+    is not U64_MAX and differs from its predecessor. A second,
+    single-operand sort of (lane << 1 | new) brings the bits back to
+    lane order: no gather, no scatter. A merged run is sorted whether
+    ``occ`` says it is occupied or not (an unoccupied run is all
+    padding, which sorts after every real query); a run above the
+    crossover keeps the binary search under its ``lax.cond`` and its
+    hits are and-ed out."""
+    n = vals.shape[0]
+    assert n < 1 << 31
+    merged = [r for r in runs if merges(r.shape[0], n)]
+    searched = [(i, r) for i, r in enumerate(runs) if not merges(r.shape[0], n)]
+    with jax.named_scope("merge"):
+        n_run = sum(r.shape[0] for r in merged)
+        hi, lo = split_u64(jnp.concatenate([*merged, vals]))
+        tag = jnp.concatenate([
+            jnp.zeros((n_run,), jnp.uint32),
+            jnp.arange(1, n + 1, dtype=jnp.uint32),
+        ])
+        hi, lo, tag = lax.sort((hi, lo, tag), num_keys=2, is_stable=True)
+        differs = jnp.concatenate([
+            jnp.ones((1,), bool),
+            (hi[1:] != hi[:-1]) | (lo[1:] != lo[:-1]),
+        ])
+        query = tag > 0
+        new = query & differs & ~((hi == _U32_MAX) & (lo == _U32_MAX))
+        back = jnp.where(
+            query, (tag - 1) << 1 | new.astype(jnp.uint32), _U32_MAX)
+        new = (lax.sort(back)[:n] & 1).astype(bool)
+    with jax.named_scope("search"):
+        for i, r in searched:
+            hit = lax.cond(
+                occ[i],
+                lambda rr: probe_sorted(rr, vals),
+                # vals != vals: all False, and typed as the other branch
+                # is inside a shard_map (a plain zeros is unvarying there
+                # and cond refuses the mismatch)
+                lambda rr: ne_u64(vals, vals),
+                r,
+            )
+            new = new & ~hit
+    return new
 
 
 def dense_prefix_sel(new, npos, n_lanes: int):

@@ -24,7 +24,14 @@ Empirical TPU rules encoded here (see also `sort_u64` / `ne_u64`):
   - never MULTIPLY u64 lanes -> u32-pair streams
   - never jnp.sort a u64 array -> 2-key (hi, lo) u32 lax.sort
   - never ==/!= u64 lanes at scale -> decomposed u32 compares
-  - u64 xor/shift/add/min/searchsorted/argsort are fine
+  - u64 xor/shift/add/min are fine
+  - never look a batch up by searchsorted where a sort can do it: each
+    step of the binary search is a gather, and gathers are serial on
+    this chip — 467.5 us a step for 65,536 queries whatever they hold,
+    where a 720,896-lane 2-key u32 sort takes 1.187 ms (the recorded
+    v5e trace benchmark/testdata/scoped_v5e, PR 24). Merge instead
+    (checker/util.py first_new); the search pays only against a run too
+    long to sort once a chunk
 
 One fusion caveat: TWO separate reductions over one producer hit an XLA
 fusion cliff (~400x); the pair streams are therefore STACKED into one

@@ -69,8 +69,8 @@ from ..obs import (
 )
 from ..obs.events import hashv_of
 from ..checker.util import (
-    GROWTH, HEADROOM, I32_MAX, dense_prefix_sel, emit_append,
-    next_cap as _next_cap, probe_sorted as _probe,
+    GROWTH, HEADROOM, I32_MAX, dedup_plan, dense_prefix_sel, emit_append,
+    first_new, next_cap as _next_cap,
 )
 from ..ops.hashing import (
     U64_MAX, eq_u64, ne_u64, sort_u64, sort_u64_with_idx, split_u64,
@@ -650,22 +650,13 @@ class ShardedBFS:
         K = self.n_actions
 
         with stage("dedup"):
-            # 6. local dedup: probe the occupied LSM runs + first-occurrence
+            # 6. local dedup, in sorted order (what the emit below keeps):
+            # not in any LSM run and first among equals, by the one
+            # merged sort of util.first_new — rf's equal lanes are
+            # adjacent in source order, so its lowest-lane rule is the
+            # first-occurrence rule here
             rf, sidx = sort_u64_with_idx(recv_fps)
-            uniq = jnp.ones_like(rf, dtype=bool).at[1:].set(ne_u64(rf[1:], rf[:-1]))
-            fresh = uniq & ne_u64(rf, U64_MAX)
-            for i, r in enumerate(runs):
-                hit = lax.cond(
-                    occ[i],
-                    lambda rr: _probe(rr, rf),
-                    # rf != rf: an all-False array that carries the same
-                    # varying-manual-axes type as the true branch (a plain
-                    # jnp.zeros is unvarying and cond rejects the mismatch)
-                    lambda rr: rf != rf,
-                    r,
-                )
-                fresh = fresh & ~hit
-            new = fresh
+            new = first_new(rf, occ, runs)
             n_new = jnp.sum(new)
 
         with stage("emit"):
@@ -1931,7 +1922,9 @@ class ShardedBFS:
         # already fetched — also returned on ShardedResult.stats
         fleet_rate = round(memo_prev / max(1, gen_prev), 4)
         fleet_cov = cov_hd.sum(axis=0)
-        run_stats = COMPILES.run_stats(comp_run)
+        run_stats = {
+            **COMPILES.run_stats(comp_run), "dedup_plan": self._dedup_plan(),
+        }
         fleet_stats = {
             "canon_memo_hits": memo_prev,
             "canon_memo_hit_rate": fleet_rate,
@@ -2161,7 +2154,14 @@ class ShardedBFS:
             "invariants": list(self.invariants),
             "action_names": list(getattr(self.model, "ACTION_NAMES", ())),
             "when": time.strftime("%Y-%m-%dT%H:%M:%S"),
+            "dedup_plan": self._dedup_plan(),
         }
+
+    def _dedup_plan(self) -> dict:
+        """util.dedup_plan of a chip's chunk program as it stands: every
+        LSM level against the D*RC lanes a chip receives."""
+        return dedup_plan(
+            [r.shape[-1] for r in self._lsm.runs], self.D * self.RC)
 
     def _check_init(self, init_d: np.ndarray):
         """(invariant name, index of first bad init state) or None."""

@@ -1,0 +1,201 @@
+"""Microbench: looking 65,536 fingerprints up in a sorted run, by
+merging or by searching — the measurement behind
+``checker/util.py::MERGE_LANES_PER_QUERY``.
+
+For VC queries and one run of S = 2^lo .. 2^hi lanes it times, on whatever
+device JAX has (a chip, or ``--platform cpu`` to rehearse):
+
+  probe          ``probe_sorted``: the binary search, log2(S)+1 gathers
+                 of VC lanes. What ``util.first_new`` adds for a run
+                 above the crossover (``chunk_only`` is the rest of it)
+  merge          ``util.first_new`` with the run under the crossover: one
+                 stable 2-key u32 sort of S+VC lanes with the lane index
+                 as payload, and the single-operand sort that brings the
+                 bits back to lane order. What the run adds is this
+                 minus ``chunk_only``
+  chunk_only     ``util.first_new`` with no run at all: first occurrence
+                 in the chunk by the same two sorts over VC lanes
+  merge_3key     ``merge`` with the lane index as a third key in place
+                 of the stable sort
+  rank_sort      ``jnp.searchsorted(..., method="sort")`` + the equality
+                 gather: the library's own sort-based lookup (an
+                 (S+VC)-update scatter and a u64 argsort)
+
+(the last two at ``--few`` sizes only: every sort is a minute of compile)
+and, at the benchmark cells' shape (a 2^18-lane seen run and the three
+ladder levels 2^16..2^18), all four runs merged against all four
+searched. Every variant's answer is checked against numpy before it is
+timed (that first call's seconds are the compile's). Milliseconds are the
+host's clock round ``block_until_ready``, median and fastest of ``--reps``
+calls.
+
+    python scripts/probe_micro.py [--vc 65536] [--lo 16] [--hi 25]
+        [--few 18 22 24] [--reps 10] [--out chiprun_out/probe_micro.json]
+        [--platform cpu]
+"""
+
+import argparse
+import json
+import os
+import sys
+import time
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+
+def _time(fn, args, reps, want):
+    """Check ``fn(*args)`` against ``want``, then time it. The checked
+    call is the first, so its seconds are the compile's."""
+    import jax
+    import numpy as np
+
+    t0 = time.perf_counter()
+    got = np.asarray(fn(*args))
+    first_s = time.perf_counter() - t0
+    assert (got == want).all(), "wrong answer"
+    ts = []
+    for _ in range(reps):
+        t0 = time.perf_counter()
+        jax.block_until_ready(fn(*args))
+        ts.append(time.perf_counter() - t0)
+    ts.sort()
+    return {"median_ms": 1e3 * ts[len(ts) // 2], "min_ms": 1e3 * ts[0],
+            "first_call_s": first_s}
+
+
+def main(argv=None):
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--vc", type=int, default=65536)
+    ap.add_argument("--lo", type=int, default=16)
+    ap.add_argument("--hi", type=int, default=25)
+    ap.add_argument("--few", type=int, nargs="*", default=[18, 22, 24])
+    ap.add_argument("--reps", type=int, default=10)
+    ap.add_argument("--out", default=os.path.join(
+        ROOT, "chiprun_out", "probe_micro.json"))
+    ap.add_argument("--platform", default=None)
+    args = ap.parse_args(argv)
+    if args.platform:
+        os.environ["JAX_PLATFORMS"] = args.platform
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax import lax
+
+    import raft_tpu  # noqa: F401  (x64 on, as the engines run)
+    from raft_tpu.checker import util
+    from raft_tpu.ops.hashing import U64_MAX, eq_u64, split_u64
+
+    vc = args.vc
+    rng = np.random.default_rng(25)
+    dev = jax.devices()[0]
+    occ = jnp.ones((8,), bool)
+
+    def make_run(size):
+        real = size // 2
+        r = np.full((size,), np.uint64(U64_MAX))
+        r[:real] = np.sort(rng.integers(
+            0, 1 << 63, size=real, dtype=np.uint64))
+        return r
+
+    def make_queries(runs):
+        v = rng.integers(0, 1 << 63, size=vc, dtype=np.uint64)
+        for j, r in enumerate(runs):  # planted hits, a few per run
+            v[j::16] = r[rng.integers(0, r.shape[0] // 2, size=len(v[j::16]))]
+        v[5::16] = v[4::16]  # duplicates inside the chunk
+        v[7::16] = np.uint64(U64_MAX)
+        return v
+
+    def reference(v, runs):
+        hit = np.zeros(v.shape, bool)
+        for r in runs:
+            hit |= np.isin(v, r)
+        _, first_idx = np.unique(v, return_index=True)
+        first = np.zeros(v.shape, bool)
+        first[first_idx] = True
+        return first & ~hit & (v != np.uint64(U64_MAX))
+
+    def with_ratio(ratio):
+        """first_new traced with every run on one side of the rule."""
+        def fn(v, *runs):
+            old = util.MERGE_LANES_PER_QUERY
+            util.MERGE_LANES_PER_QUERY = ratio
+            try:
+                return util.first_new(v, occ, runs)
+            finally:
+                util.MERGE_LANES_PER_QUERY = old
+        return jax.jit(fn)
+
+    def merge_3key(v, *runs):
+        n = v.shape[0]
+        n_run = sum(r.shape[0] for r in runs)
+        hi, lo = split_u64(jnp.concatenate([*runs, v]))
+        tag = jnp.concatenate([
+            jnp.zeros((n_run,), jnp.uint32),
+            jnp.arange(1, n + 1, dtype=jnp.uint32)])
+        hi, lo, tag = lax.sort((hi, lo, tag), num_keys=3)
+        differs = jnp.concatenate([
+            jnp.ones((1,), bool), (hi[1:] != hi[:-1]) | (lo[1:] != lo[:-1])])
+        query = tag > 0
+        m = np.uint32(0xFFFFFFFF)
+        new = query & differs & ~((hi == m) & (lo == m))
+        back = jnp.where(query, (tag - 1) << 1 | new.astype(jnp.uint32), m)
+        return (lax.sort(back)[:n] & 1).astype(bool)
+
+    def rank_sort(v, r):
+        pos = jnp.clip(
+            jnp.searchsorted(r, v, method="sort"), 0, r.shape[0] - 1)
+        return eq_u64(r[pos], v)
+
+    search, merge = with_ratio(0), with_ratio(1 << 40)
+    every = {
+        "probe": jax.jit(lambda v, r: util.probe_sorted(r, v)),
+        "merge": merge,
+    }
+    few = {"merge_3key": jax.jit(merge_3key), "rank_sort": jax.jit(rank_sort)}
+    v_h = make_queries([])
+    v = jnp.asarray(v_h)
+    chunk_only = _time(merge, (v,), args.reps, reference(v_h, []))
+    print(json.dumps({"chunk_only": chunk_only}), flush=True)
+    rows = []
+    for log2 in range(args.lo, args.hi + 1):
+        r_h = make_run(1 << log2)
+        v_h = make_queries([r_h])
+        want = {"probe": np.isin(v_h, r_h), "merge": reference(v_h, [r_h])}
+        want["rank_sort"], want["merge_3key"] = want["probe"], want["merge"]
+        r, v = jnp.asarray(r_h), jnp.asarray(v_h)
+        row = {"run_lanes": 1 << log2, "queries": vc}
+        for name, fn in {**every, **(few if log2 in args.few else {})}.items():
+            row[name] = _time(fn, (v, r), args.reps, want[name])
+        rows.append(row)
+        print(json.dumps(row), flush=True)
+
+    sizes = [1 << 18, 1 << 16, 1 << 17, 1 << 18]
+    runs_h = [make_run(s) for s in sizes]
+    v_h = make_queries(runs_h)
+    want = reference(v_h, runs_h)
+    runs = [jnp.asarray(r) for r in runs_h]
+    v = jnp.asarray(v_h)
+    cell = {"run_lanes": sizes, "queries": vc}
+    for name, fn in (("search", search), ("merge", merge)):
+        cell[name] = _time(fn, (v, *runs), args.reps, want)
+    print(json.dumps(cell), flush=True)
+
+    out = {
+        "platform": dev.platform,
+        "device": str(getattr(dev, "device_kind", dev.platform)),
+        "jax": jax.__version__,
+        "reps": args.reps,
+        "chunk_only": chunk_only,
+        "by_run_size": rows,
+        "cell_shape": cell,
+    }
+    os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)
+    with open(args.out, "w") as f:
+        json.dump(out, f, indent=1)
+    print(f"wrote {args.out}")
+
+
+if __name__ == "__main__":
+    main()

@@ -1,0 +1,127 @@
+"""What the stages of a chunk are made of, from chip traces: one traced
+verdict at each benchmark cell's depth, reduced by nested scope
+(``dedup/merge``, ``emit/coverage``, ...) and by op.
+
+The benchmark's ``scope_time`` reader books an op to its outermost stage
+and run.py deletes the trace it read; this keeps the second level and the
+op names, which is what PERF.md section 5 ("what the stages are made of")
+is written from. One process: the engine of ``raft3-small`` (both cells
+build the same one), a depth-8 warm-up verdict, then a traced verdict per
+``--depth``. Chip only in earnest; ``--platform cpu`` rehearses.
+
+    python scripts/stage_split.py [--depth 14 20] [--platform cpu]
+        [--out chiprun_out/stage_split.json]
+"""
+
+import argparse
+import collections
+import json
+import os
+import shutil
+import sys
+import time
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+_CONTROL = ("while", "body", "cond", "closed_call")
+
+
+def split(path):
+    """Seconds of device self time by scope path two levels deep, and the
+    heaviest (op, name stack) pairs, of one .xplane.pb."""
+    from benchmark import xplane, xspace
+    from benchmark.readers import scope_time
+
+    with open(path, "rb") as f:
+        buf = memoryview(f.read())
+    by_scope = collections.Counter()
+    by_op = collections.Counter()
+    n_planes = 0
+    for name, plane in xspace.planes(buf):
+        if not xplane.DEVICE_PLANE.match(name):
+            continue
+        n_planes += 1
+        tf_ops = xspace.op_stat(plane, "tf_op")
+        op = {key: xplane.op_name(xspace.text(xspace.first(meta, 2)))
+              for key, meta in xspace.map_entries(plane, 4)}
+        for start, end, meta in xplane.self_pieces(xspace.op_events(plane)):
+            stack = tf_ops.get(meta) or ""
+            stage = scope_time.stage_of(stack)
+            parts = stack.split("/")
+            if stage is None:
+                scope = "unscoped"
+            else:
+                # the next named scope under the stage: not a transform
+                # (``vmap()``, ``jit(f)``), a function's name, control flow
+                # or a closed call's repeat of the prefix, and not the
+                # last element, which is the op itself
+                inner = [p for p in parts[parts.index(stage) + 1:-1]
+                         if "(" not in p and "." not in p
+                         and p not in _CONTROL + scope_time.STAGES
+                         and not p.startswith("branch_")]
+                scope = "/".join([stage, *inner[:1]])
+            by_scope[scope] += end - start
+            by_op[(scope, op[meta], stack[-80:])] += end - start
+    per = 1e9 * max(1, n_planes)
+    return {
+        "by_scope_s": {k: ns / per for k, ns in by_scope.most_common()},
+        "top_ops_s": [[*k, ns / per] for k, ns in by_op.most_common(16)],
+    }
+
+
+def main(argv=None):
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--depth", type=int, nargs="*", default=[14, 20])
+    ap.add_argument("--out", default=os.path.join(
+        ROOT, "chiprun_out", "stage_split.json"))
+    ap.add_argument("--platform", default=None)
+    args = ap.parse_args(argv)
+    if args.platform:
+        os.environ["JAX_PLATFORMS"] = args.platform
+
+    import jax
+
+    from benchmark import adapter, xplane
+
+    bench = os.path.join(ROOT, "benchmark")
+    with open(os.path.join(bench, "workloads", "raft3-small.json")) as f:
+        cell = json.load(f)
+    cfg_dir = os.path.join(bench, "configs", cell["config"])
+    with open(os.path.join(cfg_dir, "config.json")) as f:
+        config = json.load(f)
+    engine = adapter.build_engine(
+        os.path.join(cfg_dir, config["cfg"]), "device",
+        cell["engine_params"], jax.devices()[:1])
+    clock = time.perf_counter
+    adapter.verdict(engine, 8, clock)
+    dev = jax.devices()[0]
+    out = {"platform": dev.platform,
+           "device": str(getattr(dev, "device_kind", dev.platform))}
+    for depth in args.depth:
+        adapter.verdict(engine, depth, clock)  # every seen size compiled
+        tdir = os.path.join(bench, "out", f"stage-split-{depth}")
+        shutil.rmtree(tdir, ignore_errors=True)
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(tdir, profiler_options=options)
+        try:
+            got = adapter.verdict(engine, depth, clock)
+        finally:
+            jax.profiler.stop_trace()
+        path = xplane.find_xplane(tdir)
+        res = split(path)
+        res["busy_s"] = xplane.busy_s(xplane.load(path))
+        res["distinct"] = got["distinct"]
+        res["dedup_plan"] = got["stats"].get("dedup_plan")
+        out[str(depth)] = res
+        print(depth, json.dumps(res["by_scope_s"]), flush=True)
+        shutil.rmtree(tdir, ignore_errors=True)
+    os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)
+    with open(args.out, "w") as f:
+        json.dump(out, f, indent=1)
+    print(f"wrote {args.out}")
+
+
+if __name__ == "__main__":
+    main()
