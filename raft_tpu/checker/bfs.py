@@ -493,6 +493,8 @@ class BFSChecker:
                     # still appear so one consumer reads all three engines
                     "canon_memo_hits": 0,
                     "canon_memo_hit_rate": 0.0,
+                    "canon_tier3_local": 0,
+                    "canon_tier3_full": 0,
                     "overflow_bits": 0,
                     "lsm_runs": 1,
                     "lsm_lanes": int(len(seen)),
@@ -601,6 +603,8 @@ class BFSChecker:
             "peak_journal_cap": int(next_gid - len(self._init_distinct)),
             "seen_lanes": int(len(seen)),
             "canon_memo_hit_rate": 0.0,
+            "canon_tier3_local": 0,
+            "canon_tier3_full": 0,
             **tl_extras,
             **(memwatch.summary_fields() if memwatch is not None else {}),
         })
@@ -871,6 +875,8 @@ class BFSChecker:
                         1.0 - len(wave_states) / max(1, n_cand_total), 4),
                     "canon_memo_hits": 0,
                     "canon_memo_hit_rate": 0.0,
+                    "canon_tier3_local": 0,
+                    "canon_tier3_full": 0,
                     "overflow_bits": 0,
                     "lsm_runs": 1,
                     "lsm_lanes": int(len(seen)),
@@ -964,6 +970,8 @@ class BFSChecker:
             "peak_journal_cap": int(next_gid - len(self._init_distinct)),
             "seen_lanes": int(len(seen)),
             "canon_memo_hit_rate": 0.0,
+            "canon_tier3_local": 0,
+            "canon_tier3_full": 0,
             "fleet_jobs": J,
         })
         # per-job synthesized runs: one manifest/coverage/summary triple
@@ -1001,6 +1009,8 @@ class BFSChecker:
                         next_gid - len(self._init_distinct)),
                     "seen_lanes": int(len(seen)),
                     "canon_memo_hit_rate": 0.0,
+                    "canon_tier3_local": 0,
+                    "canon_tier3_full": 0,
                     "job": name,
                 })
         return results

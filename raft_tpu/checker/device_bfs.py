@@ -63,7 +63,7 @@ from ..obs import (
 )
 from ..obs.events import hashv_of
 from ..ops.hashing import U64_MAX, ne_u64, sort_u64
-from ..ops.symmetry import Canonicalizer
+from ..ops.symmetry import Canonicalizer, canon_chunk
 from ..resilience import ckpt as rckpt
 from ..resilience.errors import CapacityOverflow
 from .bfs import CheckResult, Violation
@@ -110,6 +110,14 @@ class DeviceBFS:
     # this synthetic one so the supervisor's growth policy can key on it
     OVF_NAMES = ((1, "msg"), (2, "valid"), (4, "frontier"), (8, "journal"))
     SEEN_OVF_BIT = 16
+
+    # the in-program stats vector, i64[N_STATS]: [wave new count, journal
+    # count, cumulative generated, cumulative terminal, overflow bits,
+    # then cumulative canon counts: memo hits, tier-3 local lanes,
+    # tier-3 full lanes]. STATS_KEEP is what a wave's first chunk keeps
+    # of it (the wave-new and overflow lanes reset in-program).
+    N_STATS = 8
+    STATS_KEEP = (0, 1, 1, 1, 0, 1, 1, 1)
 
     # Donation contract for the wave/chunk programs: argument indices of
     # the capacity-shaped loop carries updated in place every dispatch
@@ -403,16 +411,9 @@ class DeviceBFS:
         """Stage 3: canonical fingerprints on compacted lanes only,
         through the raw-keyed canon memo (duplicate successors skip the
         tiered canon; invalid lanes come back masked to U64_MAX either
-        way)."""
-        if self._use_memo:
-            fps, memo, n_memo_hit = self.canon.fingerprints_memo(
-                flatc, selv, memo
-            )
-        else:
-            fps = self.canon._fingerprints(flatc)
-            fps = jnp.where(selv, fps, U64_MAX)
-            n_memo_hit = jnp.asarray(0, jnp.int32)
-        return fps, memo, n_memo_hit
+        way). ``canon_n`` is i32[3]: the chunk's memo hits and the lanes
+        its canon routed to tier 3's local and full buckets."""
+        return canon_chunk(self.canon, self._use_memo, flatc, selv, memo)
 
     @stage("dedup")
     def _st_dedup(self, fps, occ, *runs):
@@ -428,7 +429,7 @@ class DeviceBFS:
     def _st_finish(
         self, next_buf, jparent, jcand, viol, stats, cov, flatc, fps,
         sel, valid, rank, new, n_gen, terminal, expand_ovf, compact_ovf,
-        n_memo_hit, cursor, base_gid,
+        canon_n, cursor, base_gid,
     ):
         """Stages 4b-6: per-action coverage, the cursor-append emit,
         invariants on the new states and the stats fold. Returns the
@@ -530,7 +531,7 @@ class DeviceBFS:
                 stats[2] + n_gen,
                 stats[3] + terminal,
                 stats[4] | ovf_bits,
-                stats[5] + n_memo_hit,
+                *(stats[5:] + canon_n),
             ]
         )
         return next_buf, jparent, jcand, viol, stats, cov, new_run
@@ -540,10 +541,9 @@ class DeviceBFS:
         cursor, fcount, base_gid, occ, first, *runs,
     ):
         """One chunk of the current wave (the four stage methods above,
-        composed — one traced program). stats is i64[6]:
-        [wave new count, journal count, cumulative generated,
-         cumulative terminal, overflow bits, cumulative canon memo
-        hits]; memo is the [MCAP, 2] canon memo table (threaded through
+        composed — one traced program). stats is the i64[N_STATS]
+        vector the class comment lays out; memo is the [MCAP, 2] canon
+        memo table (threaded through
         the wave loop, donated); cov is the i64[n_actions, 3] per-action
         coverage accumulator — [enabled, fired, new-distinct] per Next-
         disjunct rank, cumulative over the WHOLE run (never reset, so
@@ -556,18 +556,18 @@ class DeviceBFS:
         the chunk's new fingerprints as a sorted R0-lane run."""
         stats = jnp.where(
             first,
-            stats * jnp.asarray([0, 1, 1, 1, 0, 1], dtype=stats.dtype),
+            stats * jnp.asarray(self.STATS_KEEP, dtype=stats.dtype),
             stats,
         )
         (flatc, sel, selv, valid, rank, n_gen, terminal, expand_ovf,
          compact_ovf) = self._st_expand(frontier, cursor, fcount)
-        fps, memo, n_memo_hit = self._st_canon(flatc, selv, memo)
+        fps, memo, canon_n = self._st_canon(flatc, selv, memo)
         new = self._st_dedup(fps, occ, *runs)
         (next_buf, jparent, jcand, viol, stats, cov,
          new_run) = self._st_finish(
             next_buf, jparent, jcand, viol, stats, cov, flatc, fps, sel,
             valid, rank, new, n_gen, terminal, expand_ovf, compact_ovf,
-            n_memo_hit, cursor, base_gid,
+            canon_n, cursor, base_gid,
         )
         return next_buf, jparent, jcand, viol, stats, memo, cov, new_run
 
@@ -599,7 +599,7 @@ class DeviceBFS:
         K = self._wave_geom()
         R0 = self.R0
 
-        stats = stats * jnp.asarray([0, 1, 1, 1, 0, 1], dtype=stats.dtype)
+        stats = stats * jnp.asarray(self.STATS_KEEP, dtype=stats.dtype)
         occ_all = jnp.concatenate(
             [occ, jnp.ones((K + 1,), bool)]
         )  # ladder levels always looked up (empties hold U64_MAX padding)
@@ -688,7 +688,7 @@ class DeviceBFS:
                     self._st_finish, donate_argnums=d["finish"]
                 ),
                 "statreset": jax.jit(
-                    lambda s: s * jnp.asarray([0, 1, 1, 1, 0, 1],
+                    lambda s: s * jnp.asarray(self.STATS_KEEP,
                                               dtype=s.dtype),
                     donate_argnums=d["statreset"],
                 ),
@@ -758,7 +758,7 @@ class DeviceBFS:
              c_ovf) = ex
             t = pc()
             # lint: sync-ok(stage attribution on a sampled wave)
-            fps, memo, n_memo_hit = jax.block_until_ready(
+            fps, memo, canon_n = jax.block_until_ready(
                 fns["canon"](flatc, selv, memo)
             )
             stage_s["canon"] += pc() - t
@@ -774,7 +774,7 @@ class DeviceBFS:
              new_run) = jax.block_until_ready(fns["finish"](
                 next_buf, jparent, jcand, viol, stats, cov, flatc, fps,
                 sel, valid, rank, new, n_gen, terminal, e_ovf, c_ovf,
-                n_memo_hit, np.int32(k * C), np.int32(base_gid),
+                canon_n, np.int32(k * C), np.int32(base_gid),
             ))
             stage_s["emit"] += pc() - t
             # binary-counter cascade, host-replayed: chain length =
@@ -848,7 +848,7 @@ class DeviceBFS:
                 viol = jnp.full(
                     (max(1, len(self.invariants)),), I32_MAX, jnp.int32
                 )
-                stats = jnp.zeros((6,), jnp.int64)
+                stats = jnp.zeros((self.N_STATS,), jnp.int64)
                 cov = jnp.zeros((self.n_actions, 3), jnp.int64)
                 self._wave_fn(
                     frontier, next_buf, jparent, jcand, viol, stats,
@@ -899,7 +899,7 @@ class DeviceBFS:
         jparent = sds((self.JCAP + self.VC,), jnp.int32)
         jcand = sds((self.JCAP + self.VC,), jnp.int32)
         viol = sds((max(1, len(self.invariants)),), jnp.int32)
-        stats = sds((6,), jnp.int64)
+        stats = sds((self.N_STATS,), jnp.int64)
         memo = sds((self.MCAP, 2), jnp.uint64)
         cov = sds((self.n_actions, 3), jnp.int64)
         occ = sds((1,), jnp.bool_)
@@ -936,7 +936,7 @@ class DeviceBFS:
         n_gen, terminal, e_ovf, c_ovf = ex_out[5:9]
         canon_out = jax.eval_shape(self._st_canon, flatc, selv, memo)
         fps = canon_out[0]
-        n_memo_hit = canon_out[2]
+        canon_n = canon_out[2]
         occ_all = sds((K + 2,), jnp.bool_)
         ladder = tuple(
             sds((self.R0 << i,), jnp.uint64) for i in range(K + 1)
@@ -954,7 +954,7 @@ class DeviceBFS:
             "name": "tl:finish", "fn": fns["finish"],
             "args": (next_buf, jparent, jcand, viol, stats, cov, flatc,
                      fps, sel, valid, rank, new, n_gen, terminal, e_ovf,
-                     c_ovf, n_memo_hit, i32s, i32s),
+                     c_ovf, canon_n, i32s, i32s),
             "carries": {0: "next_buf", 1: "jparent", 2: "jcand",
                         3: "viol", 4: "stats", 5: "cov"},
             "pinned": {},
@@ -993,7 +993,9 @@ class DeviceBFS:
     def _maybe_grow(self, ncount, frontier, next_buf, jparent, jcand, jcount):
         """Between waves: enlarge any buffer the next wave could outgrow.
         Frontier growth is speculative (next wave's new count is unknown;
-        observed BFS wave growth is <=~2.2x, HEADROOM=3 covers it);
+        observed BFS wave growth is <=~2.2x on Raft.cfg and 2.8x on the
+        five-server FlexibleRaft.cfg, depths 15-17: HEADROOM=3 covers
+        both, the second barely);
         journal growth is exact (it grows by ncount per wave). The
         seen-set needs no growth pass — LSM levels appear on demand."""
         W = self.W
@@ -1144,8 +1146,8 @@ class DeviceBFS:
             base_gid = int(ck["base_gid"])
             gen_prev = int(ck["gen_prev"])
             depth_counts = [int(x) for x in ck["depth_counts"]]
-            stats0 = np.array([0, jcount, gen_prev, terminal, 0, 0],
-                              dtype=np.int64)
+            stats0 = np.zeros((self.N_STATS,), dtype=np.int64)
+            stats0[1:4] = jcount, gen_prev, terminal
             # coverage joined the checkpoint format after version 1
             # shipped; older files resume with zeroed counters
             cov_h = (
@@ -1167,7 +1169,7 @@ class DeviceBFS:
             base_gid = 0
             depth_counts = [n0]
             gen_prev = 0
-            stats0 = np.zeros((6,), dtype=np.int64)
+            stats0 = np.zeros((self.N_STATS,), dtype=np.int64)
             cov_h = np.zeros((self.n_actions, 3), np.int64)
 
         # Buffers are allocated ON DEVICE and only the real rows upload:
@@ -1198,7 +1200,7 @@ class DeviceBFS:
         # never change a fingerprint), but starting cold keeps
         # back-to-back runs of one engine instance comparable
         memo = self._memo.reset()
-        memo_prev = 0
+        canon_prev = np.zeros((3,), np.int64)
 
         tel.open_run(self._telemetry_manifest())
         if resume is not None:
@@ -1401,9 +1403,13 @@ class DeviceBFS:
             frontier, next_buf = next_buf, frontier
             prev_fcount = fcount
             fcount = ncount
-            frontier, next_buf, jparent, jcand = self._maybe_grow(
-                ncount, frontier, next_buf, jparent, jcand, scount - n0
-            )
+            # no growth after the wave that max_depth ends: no wave would
+            # use the larger buffers, and FCAP would stay grown for the
+            # next run() of this engine (a new wave program to compile)
+            if max_depth is None or depth < max_depth:
+                frontier, next_buf, jparent, jcand = self._maybe_grow(
+                    ncount, frontier, next_buf, jparent, jcand, scount - n0
+                )
             if (
                 checkpoint_path is not None
                 and violation is None  # a saved file must not mask a violation
@@ -1417,9 +1423,11 @@ class DeviceBFS:
                 last_ckpt = time.perf_counter()
                 if stage_s is not None:
                     stage_s["checkpoint"] += ph.s["checkpoint"]
-            memo_hits = int(stats_h[5])
-            wave_memo = memo_hits - memo_prev
-            memo_prev = memo_hits
+            # the wave's canon counts (memo hits, tier-3 local and full
+            # lanes), from the cumulative lanes of the same snapshot
+            wave_memo, wave_t3l, wave_t3f = (
+                int(x) for x in stats_h[5:8] - canon_prev)
+            canon_prev = stats_h[5:8].copy()
             wave_s_val = time.perf_counter() - tw
             # the wave's brackets, read once: each phase's seconds are
             # those of its span. device_s is the host's WAIT on the
@@ -1469,6 +1477,8 @@ class DeviceBFS:
                     "canon_memo_hit_rate": round(
                         wave_memo / max(1, wave_gen), 4
                     ),
+                    "canon_tier3_local": wave_t3l,
+                    "canon_tier3_full": wave_t3f,
                     "overflow_bits": ovf_bits,
                     "wave_s": wave_s_val,
                     "elapsed_s": el,
@@ -1606,6 +1616,8 @@ class DeviceBFS:
             }
         run_stats = {
             **COMPILES.run_stats(comp_run), "dedup_plan": self._dedup_plan(),
+            "canon_tier3_local": int(canon_prev[1]),
+            "canon_tier3_full": int(canon_prev[2]),
         }
         tel.close_run({
             "engine": "device",
@@ -1622,7 +1634,8 @@ class DeviceBFS:
             "peak_frontier_cap": self.FCAP,
             "peak_journal_cap": self.JCAP,
             "seen_lanes": int(self._seen.shape[0]),
-            "canon_memo_hit_rate": round(memo_prev / max(1, gen_prev), 4),
+            "canon_memo_hit_rate": round(
+                int(canon_prev[0]) / max(1, gen_prev), 4),
             **run_stats,
             **tl_extras,
             **(memwatch.summary_fields() if memwatch is not None else {}),

@@ -274,7 +274,7 @@ def profile_stages(
         # against it is the realistic mixed hit/miss path
         m_warm = dev._memo.table
         st["canon"] = _time(fmemo, flatc, selv, m_warm, reps=reps)
-        fps, m_hit, _ = fmemo(flatc, selv, m_warm)
+        fps, m_hit, _, _ = fmemo(flatc, selv, m_warm)
         # after one pass the table holds every key of this chunk: the
         # second call is the pure-hit floor
         st["canon_memo_hit"] = _time(fmemo, flatc, selv, m_hit, reps=reps)
@@ -406,7 +406,7 @@ def profile_stages(
         jp = jnp.zeros((JCAP + VC,), jnp.int32)
         jc = jnp.zeros((JCAP + VC,), jnp.int32)
         viol = jnp.full((max(1, len(invariants)),), np.int32(2**31 - 1), jnp.int32)
-        stats = jnp.zeros((6,), jnp.int64)
+        stats = jnp.zeros((dev.N_STATS,), jnp.int64)
         memo = jnp.array(m_warm) if use_memo else dev._memo.reset()
         cov = jnp.zeros((dev.n_actions, 3), jnp.int64)
         args = [frontier_d, nb, jp, jc, viol, stats, memo, cov,

@@ -115,6 +115,13 @@ TIMELINE_STAGES = (
 # that grew a buffer) — and compiles/compile_s: programs the iteration
 # loaded, compiled or read from the persistent cache, and the seconds
 # that took (obs/compiles.py).
+# canon_tier3_local / canon_tier3_full: lanes the wave's canon routed to
+# tier 3's two buckets (ops/symmetry.py: the tie-group-local tables; the
+# S!-table masked min, which on a layout without tiers, S <= 4, is every
+# lane canonicalised). They count representatives that missed the memo,
+# so together they never exceed generated - canon_memo_hits. 0 on the
+# host engines, which have no tiered canon. From the stats vector the
+# wave already fetched: zero extra device syncs.
 # exchange_share: sharded engine only, fraction of the sampled wave's
 # device seconds spent in the all-to-all (null on other engines and on
 # unsampled waves). hbm_frac: analytic live-bytes / budget from
@@ -122,7 +129,8 @@ TIMELINE_STAGES = (
 WAVE_KEYS = (
     "event", "wave", "depth", "frontier", "new", "distinct",
     "generated", "generated_total", "terminal", "dedup_hit_rate",
-    "canon_memo_hits", "canon_memo_hit_rate", "overflow_bits",
+    "canon_memo_hits", "canon_memo_hit_rate", "canon_tier3_local",
+    "canon_tier3_full", "overflow_bits",
     "lsm_runs", "lsm_lanes", "wave_s", "elapsed_s", "distinct_per_s",
     "emit_rows", "emit_bytes", "frontier_fill",
     "enabled_density", "expand_budget_ovf",
@@ -154,6 +162,7 @@ SUMMARY_KEYS = (
     "total", "depth", "terminal", "seconds", "distinct_per_s",
     "exhausted", "waves", "stalls", "peak_frontier_cap",
     "peak_journal_cap", "seen_lanes", "canon_memo_hit_rate",
+    "canon_tier3_local", "canon_tier3_full",
 )
 
 # resilience events (self-healing runtime): the supervisor and the
@@ -328,6 +337,22 @@ def validate_event(ev: object, lineno: int | None = None) -> list[str]:
             problems.append(
                 f"{where}wave expand_budget_ovf {bovf!r} must be a "
                 f"non-negative int"
+            )
+        tiers = [ev.get("canon_tier3_local"), ev.get("canon_tier3_full")]
+        if any(isinstance(v, bool) or not isinstance(v, int) or v < 0
+               for v in tiers if v is not None):
+            problems.append(
+                f"{where}wave canon_tier3_local/_full {tiers!r} must be "
+                f"non-negative ints (lanes)"
+            )
+        elif None not in tiers and all(
+            isinstance(ev.get(k), int)
+            for k in ("generated", "canon_memo_hits")
+        ) and sum(tiers) > ev["generated"] - ev["canon_memo_hits"]:
+            problems.append(
+                f"{where}wave canon_tier3 lanes {tiers!r} exceed the "
+                f"lanes canonicalised less the memo's hits "
+                f"({ev['generated']} - {ev['canon_memo_hits']})"
             )
         for key in ("device_s", "host_s", "ckpt_s", "tel_s", "dispatch_s",
                     "fetch_s", "merge_s", "grow_s", "compile_s"):

@@ -39,6 +39,7 @@ class ProgressRenderer:
     CONSUMES = (
         "depth", "generated_total", "distinct", "distinct_per_s",
         "canon_memo_hit_rate", "exchange_share", "hbm_frac",
+        "generated", "canon_tier3_local", "canon_tier3_full",
     )
 
     def __init__(self, every_s: float = 10.0, stream=None):
@@ -57,6 +58,10 @@ class ProgressRenderer:
         # observatory gauges render only when present and non-zero so
         # the base line (pinned by tests) is unchanged on engines /
         # waves that don't carry them
+        tier3 = (ev.get("canon_tier3_local") or 0) + (
+            ev.get("canon_tier3_full") or 0)
+        if tier3:
+            line += f", tier3 {tier3 / max(1, ev['generated']):.0%}"
         if ev.get("exchange_share"):
             line += f", a2a {ev['exchange_share']:.0%}"
         if ev.get("hbm_frac"):

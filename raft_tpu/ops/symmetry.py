@@ -87,6 +87,7 @@ member sets inside reconfig-spec messages
 
 from __future__ import annotations
 
+import functools
 import itertools
 import math
 
@@ -265,6 +266,23 @@ def _tie_pattern_tables(S: int):
             row += 1
         assert row == counts[pat]
     return tab, mask, local
+
+
+def canon_chunk(canon, use_memo: bool, states, valid, memo):
+    """The engines' canon stage on one chunk's compacted lanes:
+    ``(fps, memo, canon_n)`` with invalid lanes masked to U64_MAX and
+    ``canon_n`` i32[3] = [memo hits, tier-3 local lanes, tier-3 full
+    lanes]. Through the raw-keyed memo when ``use_memo``; zeros from a
+    custom canonicalizer (``make_canonicalizer`` models), which has
+    neither a memo surface nor tiers to count."""
+    if use_memo:
+        fps, memo, n_hit, tiers = canon.fingerprints_memo(states, valid, memo)
+        return fps, memo, jnp.concatenate([n_hit[None], tiers])
+    if hasattr(canon, "fingerprints_tiers"):
+        fps, tiers = canon.fingerprints_tiers(states, valid)
+        return fps, memo, jnp.concatenate([jnp.zeros((1,), jnp.int32), tiers])
+    fps = jnp.where(valid, canon._fingerprints(states), U64_MAX)
+    return fps, memo, jnp.zeros((3,), jnp.int32)
 
 
 class Canonicalizer:
@@ -1064,28 +1082,50 @@ class Canonicalizer:
         signature machinery costs more than it saves at 6-24 perms,
         measured on the TPU); S >= 5 -> signature-pruned masked min
         (at 120+ perms the brute force is ~9x the whole chunk budget)."""
-        return self._canon_view(states[:, : self.VL])
+        return self._canon_view(states[:, : self.VL])[0]
 
-    def _canon_view(self, view):
-        """Tiered canonical hash of a [B, VL] view batch."""
+    def fingerprints_tiers(self, states, valid):
+        """``_fingerprints`` with invalid lanes masked to U64_MAX, and
+        the tier-3 lane counts of ``_canon_view`` over the valid ones."""
+        fps, tiers = self._canon_view(states[:, : self.VL], valid)
+        return jnp.where(valid, fps, U64_MAX), tiers
+
+    def _canon_view(self, view, valid=None):
+        """Tiered canonical hash of a [B, VL] view batch. Returns
+        ``(fp, tiers)``; ``tiers`` is i32[2], the lanes among ``valid``
+        (all, if None) that took ``[tier3_local, tier3_full]``. Lanes
+        outside ``valid`` (the zero rows that pad a block) are routed to
+        neither bucket: an all-zero view is all-tied, and would drain
+        through the S!-table min. What carries the ``tier3_full`` scope
+        is the S!-table min wherever it runs, so a layout without tiers
+        (S <= 4: every lane takes it) counts every valid lane there.
+
+        The nested device scopes (``canon/tier12`` ...) are what
+        scripts/stage_split.py splits the ``canon`` stage by."""
         if not self.symmetry:
-            return self._perm_hash(view)
-        if not self.prune:
-            return self._masked_min(view, None)
-        sig = self._signatures(view)
-        if self.mode == "full":
-            return self._masked_min(view, sig)
-        pre = self._tier_pre(view, sig)
-        return self._tier3_apply(view, sig, *pre)
+            return self._perm_hash(view), jnp.zeros((2,), jnp.int32)
+        if not self.prune or self.mode == "full":
+            n_all = view.shape[0] if valid is None else jnp.sum(valid)
+            with jax.named_scope("tier3_full"):
+                sig = self._signatures(view) if self.prune else None
+                return self._masked_min(view, sig), jnp.stack(
+                    [jnp.zeros((), jnp.int32),
+                     jnp.asarray(n_all, jnp.int32)])
+        with jax.named_scope("tier12"):
+            sig = self._signatures(view)
+            pre = self._tier_pre(view, sig, valid)
+        tiers = jnp.stack([jnp.sum(pre[3]), jnp.sum(pre[4])])
+        return self._tier3_apply(view, sig, *pre), tiers.astype(jnp.int32)
 
-    def _tier_pre(self, view, sig):
+    def _tier_pre(self, view, sig, valid=None):
         """Tiers 1+2 plus tie-pattern classification. Returns
         ``(fp, sigma, pat, is_local, is_full)``: the running min after
         the signature-argsort permutation (tier 1) and the static
         disjoint-adjacent-swap products (tier 2), the tier-1 sigma, each
         lane's adjacent-equality pattern id, and the two tier-3 route
         masks (tie group >= 3 with a locally enumerable admissible
-        set / needing the full S! table)."""
+        set / needing the full S! table; never a lane outside
+        ``valid``)."""
         S = self.S
 
         # ---- tier 1: one dynamic permutation (the signature argsort) ----
@@ -1129,6 +1169,8 @@ class Canonicalizer:
             adj_eq.astype(jnp.int32) << shifts[None, :], axis=1
         ).astype(jnp.int32)
         loc = self._p_local[pat]
+        if valid is not None:
+            heavy = heavy & valid
         return fp, sigma, pat, heavy & loc, heavy & ~loc
 
     def _tier3_apply(self, view, sig, fp, sigma, pat, is_local, is_full):
@@ -1137,8 +1179,10 @@ class Canonicalizer:
         ``lax.while_loop`` whose trip count adapts to the actual heavy
         population of the chunk — no static compaction budget, no
         whole-batch ``lax.cond`` fallback cliff."""
-        fp = self._tier3_local(view, fp, sigma, pat, is_local)
-        return self._tier3_full(view, fp, sig, is_full)
+        with jax.named_scope("tier3_local"):
+            fp = self._tier3_local(view, fp, sigma, pat, is_local)
+        with jax.named_scope("tier3_full"):
+            return self._tier3_full(view, fp, sig, is_full)
 
     def _tier3_local(self, view, fp, sigma, pat, is_local):
         """Tie-group-LOCAL masked min: for a lane whose tie pattern has
@@ -1232,8 +1276,10 @@ class Canonicalizer:
 
         ``memo`` is a [MCAP, 2] u64 direct-mapped table (MCAP a power
         of two): each row holds (raw view hash, canonical fingerprint),
-        empty rows keyed U64_MAX. Returns ``(fps, memo, n_hit)`` with
-        invalid lanes masked to U64_MAX.
+        empty rows keyed U64_MAX. Returns ``(fps, memo, n_hit, tiers)``
+        with invalid lanes masked to U64_MAX; ``tiers`` is i32[2], the
+        representatives that took ``[tier3_local, tier3_full]``
+        (``_canon_view``): together at most the valid lanes less the hits.
 
         The miss path first dedups equal raw keys WITHIN the chunk
         (sorted segments, one canon per distinct raw view — duplicate
@@ -1250,58 +1296,75 @@ class Canonicalizer:
         view = states[:, : self.VL]
         B = view.shape[0]
         memo = jnp.asarray(memo)  # accept host tables (tests, tools)
-        raw = self._perm_hash(view)
+        # derived from `view` for the loop carry's type under shard_map
+        # (as _masked_min's init)
+        no_tiers = jnp.zeros((2,), jnp.int32) + (view[0, 0] & 0)
+        # the `memo` scope is the probe, the in-chunk dedup and the
+        # write; it is opened piecewise so that the tiers' scopes in the
+        # loop's body stay its siblings under `canon`, not its children
+        memo_scope = functools.partial(jax.named_scope, "memo")
+        with memo_scope():
+            raw = self._perm_hash(view)
         if not self.symmetry:
             return (jnp.where(valid, raw, U64_MAX), memo,
-                    jnp.asarray(0, jnp.int32))
+                    jnp.asarray(0, jnp.int32), no_tiers)
         MCAP = memo.shape[0]
-        slot = memo_slot(raw, MCAP)
-        row = memo[slot]  # [B, 2]
-        # a raw key equal to the empty sentinel (p = 2^-64) never hits:
-        # it recomputes every time rather than aliasing empty rows
-        hit = valid & eq_u64(row[:, 0], raw) & ne_u64(raw, U64_MAX)
-        need = valid & ~hit
-        n_hit = jnp.sum(hit).astype(jnp.int32)
-
-        # in-chunk dedup: sort the missed raw keys, canon only segment
-        # heads, forward-fill each segment from its head
-        sraw, order = sort_u64_with_idx(jnp.where(need, raw, U64_MAX))
-        is_head = jnp.concatenate(
-            [jnp.ones((1,), bool), ne_u64(sraw[1:], sraw[:-1])]
-        )
-        head = is_head & ne_u64(sraw, U64_MAX)
-        n_rep = jnp.sum(head)
         CB = min(B, max(64, B // 4))
-        psel = jnp.argsort(~head).astype(jnp.int32)  # head positions first
-        psel = jnp.concatenate([psel, jnp.full((CB,), B, jnp.int32)])
-        orderp = jnp.concatenate([order, jnp.full((1,), B, jnp.int32)])
-        viewp = jnp.concatenate([view, jnp.zeros((1, self.VL), view.dtype)])
-        canon_sorted = jnp.full((B + 1,), U64_MAX, jnp.uint64)
-        jcb = jnp.arange(CB, dtype=jnp.int32)
+        with memo_scope():
+            slot = memo_slot(raw, MCAP)
+            row = memo[slot]  # [B, 2]
+            # a raw key equal to the empty sentinel (p = 2^-64) never
+            # hits: it recomputes every time rather than aliasing empty
+            # rows
+            hit = valid & eq_u64(row[:, 0], raw) & ne_u64(raw, U64_MAX)
+            need = valid & ~hit
+            n_hit = jnp.sum(hit).astype(jnp.int32)
+
+            # in-chunk dedup: sort the missed raw keys, canon only
+            # segment heads, forward-fill each segment from its head
+            sraw, order = sort_u64_with_idx(jnp.where(need, raw, U64_MAX))
+            is_head = jnp.concatenate(
+                [jnp.ones((1,), bool), ne_u64(sraw[1:], sraw[:-1])]
+            )
+            head = is_head & ne_u64(sraw, U64_MAX)
+            n_rep = jnp.sum(head)
+            psel = jnp.argsort(~head).astype(jnp.int32)  # head positions first
+            psel = jnp.concatenate([psel, jnp.full((CB,), B, jnp.int32)])
+            orderp = jnp.concatenate([order, jnp.full((1,), B, jnp.int32)])
+            viewp = jnp.concatenate(
+                [view, jnp.zeros((1, self.VL), view.dtype)])
+            canon_sorted = jnp.full((B + 1,), U64_MAX, jnp.uint64)
+            jcb = jnp.arange(CB, dtype=jnp.int32)
 
         def cond(c):
             return c[0] * CB < n_rep
 
         def body(c):
-            i, acc = c
-            pos = lax.dynamic_slice(psel, (i * CB,), (CB,))
-            pos = jnp.where(i * CB + jcb < n_rep, pos, B)
-            cfp = self._canon_view(viewp[orderp[pos]])
-            return i + 1, acc.at[pos].set(cfp)
+            i, acc, tiers = c
+            with memo_scope():
+                pos = lax.dynamic_slice(psel, (i * CB,), (CB,))
+                real = i * CB + jcb < n_rep
+                pos = jnp.where(real, pos, B)
+                block = viewp[orderp[pos]]
+            cfp, t = self._canon_view(block, real)
+            with memo_scope():
+                return i + 1, acc.at[pos].set(cfp), tiers + t
 
-        _, canon_sorted = lax.while_loop(
-            cond, body, (jnp.asarray(0, jnp.int32), canon_sorted)
+        _, canon_sorted, tiers = lax.while_loop(
+            cond, body, (jnp.asarray(0, jnp.int32), canon_sorted, no_tiers)
         )
-        hidx = lax.associative_scan(
-            jnp.maximum,
-            jnp.where(is_head, jnp.arange(B, dtype=jnp.int32), 0),
-        )
-        computed = (
-            jnp.zeros((B,), jnp.uint64)
-            .at[order]
-            .set(canon_sorted[:B][hidx])
-        )
-        fps = jnp.where(hit, row[:, 1], jnp.where(need, computed, U64_MAX))
-        kv = jnp.stack([raw, fps], axis=1)
-        memo = memo.at[jnp.where(need, slot, MCAP)].set(kv, mode="drop")
-        return fps, memo, n_hit
+        with memo_scope():
+            hidx = lax.associative_scan(
+                jnp.maximum,
+                jnp.where(is_head, jnp.arange(B, dtype=jnp.int32), 0),
+            )
+            computed = (
+                jnp.zeros((B,), jnp.uint64)
+                .at[order]
+                .set(canon_sorted[:B][hidx])
+            )
+            fps = jnp.where(
+                hit, row[:, 1], jnp.where(need, computed, U64_MAX))
+            kv = jnp.stack([raw, fps], axis=1)
+            memo = memo.at[jnp.where(need, slot, MCAP)].set(kv, mode="drop")
+        return fps, memo, n_hit, tiers

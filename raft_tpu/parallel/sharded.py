@@ -75,7 +75,7 @@ from ..checker.util import (
 from ..ops.hashing import (
     U64_MAX, eq_u64, ne_u64, sort_u64, sort_u64_with_idx, split_u64,
 )
-from ..ops.symmetry import Canonicalizer
+from ..ops.symmetry import Canonicalizer, canon_chunk
 from ..resilience import ckpt as rckpt
 from ..resilience.errors import CapacityOverflow, ShardLost, ShardStall
 
@@ -131,6 +131,7 @@ class ShardedBFS:
         (16, "journal"),
     )
     SEEN_OVF_BIT = 32
+    N_STATS = 9  # lanes of the per-shard stats vector (_chunk_step)
 
     # Donation contract (audited by `raft_tpu lint`, pass `donation`):
     # every capacity-shaped per-wave carry must alias an output of the
@@ -403,7 +404,7 @@ class ShardedBFS:
         jcand = sds((D, self.JCAP + self.EPAD), jnp.int32)
         jfp = sds((D, self.JCAP + self.EPAD), jnp.uint64)
         viol = sds((D, max(1, len(self.invariants))), jnp.int32)
-        stats = sds((D, 7), jnp.int64)
+        stats = sds((D, self.N_STATS), jnp.int64)
         memo = sds((D, self.MCAP, 2), jnp.uint64)
         cov = sds((D, self.n_actions, 3), jnp.int64)
         occ = sds((n_runs,), jnp.bool_)
@@ -475,7 +476,9 @@ class ShardedBFS:
         [enabled, fired, new] per action rank (enabled/fired tally on the
         GENERATING chip, new on the OWNER chip after the all-to-all);
         stats [1,S] i64 = [wave new, jcount, cum generated,
-        cum terminal, ovf bits, routed lanes, cum canon memo hits].
+        cum terminal, ovf bits, routed lanes, then the cumulative canon
+        counts: memo hits, tier-3 local lanes, tier-3 full lanes]
+        (N_STATS lanes).
         Returns (+ new_run [1,R0]).
         """
         # strip the leading local-block axis shard_map hands us
@@ -514,8 +517,10 @@ class ShardedBFS:
         plus everything the post stage needs: ``cov_gen`` [K,2] =
         per-action [enabled, fired] tallied on the generating chip
         ([1,2] zeros when the model has no action ranks) and
-        ``pre_stats`` [5] i64 = [n_gen, terminal, pre-exchange ovf bits
-        (1=msg 2=valid 4=route), routed lanes, canon memo hits]."""
+        ``pre_stats`` [7] i64 = [n_gen, terminal, pre-exchange ovf bits
+        (1=msg 2=valid 4=route), routed lanes, then the chunk's canon
+        counts as DeviceBFS._st_canon has them: memo hits, tier-3 local
+        lanes, tier-3 full lanes]."""
         model, D, A, W = self.model, self.D, self.A, self.W
         C, VC, RC = self.chunk, self.VC, self.RC
         K = self.n_actions
@@ -579,14 +584,8 @@ class ShardedBFS:
             # 3. canonical fingerprints on the compacted lanes — memoized on
             # the GENERATING chip (raw keys are shard-local; the all-to-all
             # below only ever moves canonical fingerprints)
-            if self._use_memo:
-                fps, memo, n_memo_hit = self.canon.fingerprints_memo(
-                    flatc, selv, memo
-                )
-            else:
-                fps = self.canon._fingerprints(flatc)
-                fps = jnp.where(selv, fps, U64_MAX)
-                n_memo_hit = jnp.asarray(0, jnp.int32)
+            fps, memo, canon_n = canon_chunk(
+                self.canon, self._use_memo, flatc, selv, memo)
 
         with stage("exchange"), jax.named_scope("route"):
             # 4. route to owner chip = fp mod D: sort by owner, positional
@@ -628,7 +627,7 @@ class ShardedBFS:
             + 2 * compact_ovf.astype(jnp.int64)
             + 4 * route_ovf.astype(jnp.int64),
             n_routed.astype(jnp.int64),
-            n_memo_hit.astype(jnp.int64),
+            *canon_n.astype(jnp.int64),
         ])
         cov_gen = (
             jnp.stack([enabled_k, fired_k], axis=1)
@@ -729,8 +728,7 @@ class ShardedBFS:
                     stats[2] + pre_stats[0],
                     stats[3] + pre_stats[1],
                     stats[4] | ovf_bits,
-                    stats[5] + pre_stats[3],
-                    stats[6] + pre_stats[4],
+                    *(stats[5:] + pre_stats[3:]),
                 ]
             )
         return next_buf, jps, jpl, jcand, jfp, viol, stats, cov, new_run
@@ -1362,7 +1360,7 @@ class ShardedBFS:
             # per-shard generated/terminal/routed cums are not persisted
             # per shard; resume them as deltas from zero and add the saved
             # totals back via the *_base offsets
-            stats_h0 = np.zeros((D, 7), np.int64)
+            stats_h0 = np.zeros((D, self.N_STATS), np.int64)
             stats_h0[:, 1] = jcounts
             gen_base, term_base, routed_base = gen_prev, terminal, routed_prev
             gen_prev = routed_prev = terminal = 0
@@ -1426,7 +1424,7 @@ class ShardedBFS:
                     np.full((D, max(1, len(self.invariants))), I32_MAX, np.int32),
                     self._sharding),
                 "stats": jax.device_put(
-                    np.zeros((D, 7), np.int64), self._sharding),
+                    np.zeros((D, self.N_STATS), np.int64), self._sharding),
             }
             distinct = int(len(init_d))
             total = int(len(init))  # pre-dedup, matching BFSChecker seeding
@@ -1457,6 +1455,7 @@ class ShardedBFS:
         state["memo"] = self._memo.reset()
         state["cov"] = jax.device_put(cov_hd, self._sharding)
         memo_prev = 0
+        tiers_prev = np.zeros((2,), np.int64)
         per_shard_memo = np.zeros(D, np.int64)
         wave_times: list[float] = []  # stall-watchdog rolling window
         # wave-timeline observatory (obs/): sampled waves dispatch the
@@ -1623,7 +1622,7 @@ class ShardedBFS:
                     # lint: sync-ok(once-per-wave snapshot)
                     stats_h, viol_h, cov_w = jax.device_get(
                         (state["stats"], state["viol"], state["cov"]))
-            stats_h = np.asarray(stats_h)  # [D,7]
+            stats_h = np.asarray(stats_h)  # [D, N_STATS]
             viol_h = np.asarray(viol_h)  # [D,K]
             new_d = stats_h[:, 0]
             ovf_bits = int(np.bitwise_or.reduce(stats_h[:, 4]))
@@ -1702,6 +1701,9 @@ class ShardedBFS:
             wave_memo = memo_hits - memo_prev
             memo_prev = memo_hits
             per_shard_memo = stats_h[:, 6].copy()
+            tiers_cum = stats_h[:, 7:9].sum(axis=0)
+            wave_t3l, wave_t3f = (int(x) for x in tiers_cum - tiers_prev)
+            tiers_prev = tiers_cum
             if global_new == 0:
                 exit_cause = "exhausted"
                 break
@@ -1730,7 +1732,10 @@ class ShardedBFS:
             prev_fcounts = fcounts
             fcounts = new_d.copy()
             if violation is None:
-                state = self._maybe_grow(state, fcounts, jcounts)
+                # not after the wave that max_depth ends
+                # (as DeviceBFS.run)
+                if max_depth is None or depth < max_depth:
+                    state = self._maybe_grow(state, fcounts, jcounts)
                 # per-chip floor is smaller than DeviceBFS's (1<<21):
                 # each chip holds ~1/D of the space
                 if self._lsm.lanes() > max(4 * int(scounts.max()), 1 << 20):
@@ -1801,6 +1806,8 @@ class ShardedBFS:
                     "canon_memo_hit_rate": round(
                         wave_memo / max(1, wave_gen), 4
                     ),
+                    "canon_tier3_local": wave_t3l,
+                    "canon_tier3_full": wave_t3f,
                     "overflow_bits": ovf_bits,
                     "wave_s": wave_s_val,
                     "elapsed_s": el,
@@ -1924,6 +1931,8 @@ class ShardedBFS:
         fleet_cov = cov_hd.sum(axis=0)
         run_stats = {
             **COMPILES.run_stats(comp_run), "dedup_plan": self._dedup_plan(),
+            "canon_tier3_local": int(tiers_prev[0]),
+            "canon_tier3_full": int(tiers_prev[1]),
         }
         fleet_stats = {
             "canon_memo_hits": memo_prev,

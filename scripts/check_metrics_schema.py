@@ -38,7 +38,10 @@ throughout, and a breakdown mapping buffer families to non-negative
 byte counts; a `shard_wave` event (per-shard critical-path row on
 sampled waves of a sharded run) must name a shard inside the mesh
 (0 <= shard < device_count) with non-negative lanes / bytes / seconds
-and a work_share in [0, 1]. Job-tagged streams (the one
+and a work_share in [0, 1]. A `wave` event's canon_tier3_local and
+canon_tier3_full (lanes its canon routed to tier 3's buckets) must be
+non-negative ints that together do not exceed generated -
+canon_memo_hits. Job-tagged streams (the one
 multiplexed file a `raft_tpu sweep --metrics-out` run writes) get the
 fleet rules: a `job` tag must be a non-empty string, each job's wave
 indices must be strictly increasing within its run, and every job

@@ -68,7 +68,7 @@ def main():
             jc = jnp.zeros((dev.JCAP + 1,), jnp.int32)
             viol = jnp.full((max(1, len(dev.invariants)),),
                             np.int32(2**31 - 1), jnp.int32)
-            stats = jnp.zeros((6,), jnp.int64)
+            stats = jnp.zeros((dev.N_STATS,), jnp.int64)
             memo = dev._memo.reset()
             cov = jnp.zeros((dev.n_actions, 3), jnp.int64)
             return [frontier, nb, jp, jc, viol, stats, memo, cov,

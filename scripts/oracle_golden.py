@@ -4,6 +4,12 @@ object on stdout — the provenance of tests/golden/raft_cfg_depth_counts.json
 
     JAX_PLATFORMS=cpu python scripts/oracle_golden.py \
         configs/standard-raft/Raft.cfg --max-depth 22
+
+`--workers N` maps the oracle's own `successors` and `canon` over each
+frontier in a process pool (at five servers `canon` is a brute-force min
+over 120 permutations in Python, ~190 successors a second a core); dedup,
+the invariants and every count stay in the parent, in the frontier's
+order, so the output is the one-process run's byte for byte.
 """
 
 from __future__ import annotations
@@ -15,22 +21,90 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
+_WORKER = None  # (oracle, symmetry) of a pool worker
+
+
+def _setup_and_oracle(cfg):
+    from raft_tpu.models.registry import build_from_cfg, oracle_for_setup
+    from raft_tpu.utils.cfg import parse_cfg
+
+    setup = build_from_cfg(parse_cfg(cfg))
+    return setup, oracle_for_setup(setup)
+
+
+def _worker_init(cfg):
+    global _WORKER
+    setup, oracle = _setup_and_oracle(cfg)
+    _WORKER = (oracle, setup.symmetry)
+
+
+def _expand(st):
+    """One frontier state's successors, each with its canonical key."""
+    oracle, symmetry = _WORKER
+    return [(s2, oracle.canon(s2, symmetry))
+            for _label, s2 in oracle.successors(st)]
+
+
+def pooled_bfs(cfg, setup, oracle, max_depth, workers):
+    """`oracle.bfs(max_depth=...)` with successors and keys from a pool."""
+    import multiprocessing as mp
+
+    init = oracle.init_state()
+    seen = {oracle.canon(init, setup.symmetry)}
+    frontier, depth_counts = [init], [1]
+    total, terminal, violation, depth = 1, 0, None, 0
+    with mp.get_context("spawn").Pool(workers, _worker_init, (cfg,)) as pool:
+        while frontier and violation is None and depth < max_depth:
+            next_frontier = []
+            chunk = max(1, min(64, len(frontier) // (4 * workers)))
+            for succs in pool.imap(_expand, frontier, chunksize=chunk):
+                terminal += not succs
+                for s2, key in succs:
+                    total += 1
+                    if key in seen:
+                        continue
+                    seen.add(key)
+                    for inv in setup.invariants:
+                        if not oracle.INVARIANTS[inv](oracle, s2):
+                            violation = {"invariant": inv, "state": s2, "depth": depth + 1}
+                            break
+                    next_frontier.append(s2)
+                    if violation:
+                        break
+                if violation:
+                    break
+            frontier = next_frontier
+            if frontier:
+                depth_counts.append(len(frontier))
+            depth += 1
+            print(f"depth {depth}: {len(frontier)} new, {len(seen)} distinct, "
+                  f"{total} generated, {terminal} terminal", file=sys.stderr, flush=True)
+    return {
+        "distinct": len(seen),
+        "total": total,
+        "depth_counts": depth_counts,
+        "terminal": terminal,
+        "violation": violation,
+    }
+
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("cfg")
     ap.add_argument("--max-depth", type=int, required=True)
+    ap.add_argument("--workers", type=int, default=1,
+                    help="processes that compute successors and canonical keys")
     args = ap.parse_args(argv)
 
-    from raft_tpu.models.registry import build_from_cfg, oracle_for_setup
-    from raft_tpu.utils.cfg import parse_cfg
-
-    setup = build_from_cfg(parse_cfg(args.cfg))
-    res = oracle_for_setup(setup).bfs(
-        invariants=setup.invariants,
-        symmetry=setup.symmetry,
-        max_depth=args.max_depth,
-    )
+    setup, oracle = _setup_and_oracle(args.cfg)
+    if args.workers > 1:
+        res = pooled_bfs(args.cfg, setup, oracle, args.max_depth, args.workers)
+    else:
+        res = oracle.bfs(
+            invariants=setup.invariants,
+            symmetry=setup.symmetry,
+            max_depth=args.max_depth,
+        )
     print(json.dumps({
         "cfg": args.cfg,
         "max_depth": args.max_depth,

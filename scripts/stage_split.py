@@ -5,11 +5,14 @@ verdict at each benchmark cell's depth, reduced by nested scope
 The benchmark's ``scope_time`` reader books an op to its outermost stage
 and run.py deletes the trace it read; this keeps the second level and the
 op names, which is what PERF.md section 5 ("what the stages are made of")
-is written from. One process: the engine of ``raft3-small`` (both cells
-build the same one), a depth-8 warm-up verdict, then a traced verdict per
-``--depth``. Chip only in earnest; ``--platform cpu`` rehearses.
+is written from. One process: the engine of ``--workload`` (default
+``raft3-small``; both raft3 cells build the same one), a depth-8 warm-up
+verdict, then a traced verdict per ``--depth`` (default: 14 and 20 for
+raft3, else the cell's own depth). Chip only in earnest; ``--platform
+cpu`` rehearses.
 
-    python scripts/stage_split.py [--depth 14 20] [--platform cpu]
+    python scripts/stage_split.py [--workload flexraft5-wide]
+        [--depth 14 20] [--platform cpu]
         [--out chiprun_out/stage_split.json]
 """
 
@@ -72,7 +75,8 @@ def split(path):
 
 def main(argv=None):
     ap = argparse.ArgumentParser()
-    ap.add_argument("--depth", type=int, nargs="*", default=[14, 20])
+    ap.add_argument("--workload", default="raft3-small")
+    ap.add_argument("--depth", type=int, nargs="*", default=None)
     ap.add_argument("--out", default=os.path.join(
         ROOT, "chiprun_out", "stage_split.json"))
     ap.add_argument("--platform", default=None)
@@ -85,8 +89,12 @@ def main(argv=None):
     from benchmark import adapter, xplane
 
     bench = os.path.join(ROOT, "benchmark")
-    with open(os.path.join(bench, "workloads", "raft3-small.json")) as f:
+    with open(os.path.join(bench, "workloads", f"{args.workload}.json")) as f:
         cell = json.load(f)
+    with open(os.path.join(bench, "traffic", f"{cell['traffic']}.json")) as f:
+        traffic = json.load(f)
+    depths = args.depth or (
+        [14, 20] if cell["config"] == "raft3" else [traffic["max_depth"]])
     cfg_dir = os.path.join(bench, "configs", cell["config"])
     with open(os.path.join(cfg_dir, "config.json")) as f:
         config = json.load(f)
@@ -94,11 +102,11 @@ def main(argv=None):
         os.path.join(cfg_dir, config["cfg"]), "device",
         cell["engine_params"], jax.devices()[:1])
     clock = time.perf_counter
-    adapter.verdict(engine, 8, clock)
+    adapter.verdict(engine, traffic["warmup_depth"], clock)
     dev = jax.devices()[0]
-    out = {"platform": dev.platform,
+    out = {"platform": dev.platform, "workload": args.workload,
            "device": str(getattr(dev, "device_kind", dev.platform))}
-    for depth in args.depth:
+    for depth in depths:
         adapter.verdict(engine, depth, clock)  # every seen size compiled
         tdir = os.path.join(bench, "out", f"stage-split-{depth}")
         shutil.rmtree(tdir, ignore_errors=True)
@@ -114,6 +122,10 @@ def main(argv=None):
         res["busy_s"] = xplane.busy_s(xplane.load(path))
         res["distinct"] = got["distinct"]
         res["dedup_plan"] = got["stats"].get("dedup_plan")
+        res["canon_lanes"] = {
+            k: sum(w[k] for w in got["waves"])
+            for k in ("generated", "canon_memo_hits", "canon_tier3_local",
+                      "canon_tier3_full") if k in got["waves"][0]}
         out[str(depth)] = res
         print(depth, json.dumps(res["by_scope_s"]), flush=True)
         shutil.rmtree(tdir, ignore_errors=True)

@@ -87,7 +87,7 @@ def test_wave_program_consumes_donated_carries():
         jparent=jnp.zeros((dev.JCAP + dev.VC,), jnp.int32),
         jcand=jnp.zeros((dev.JCAP + dev.VC,), jnp.int32),
         viol=jnp.full((len(INVS),), np.int32(2**31 - 1), jnp.int32),
-        stats=jnp.zeros((6,), jnp.int64),
+        stats=jnp.zeros((dev.N_STATS,), jnp.int64),
         memo=dev._memo.reset(),
         cov=jnp.zeros((dev.n_actions, 3), jnp.int64),
     )

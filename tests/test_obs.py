@@ -340,8 +340,19 @@ def test_sharded_stream_and_fleet_stats(tmp_path):
     # returned result
     assert res.stats is not None
     for k in ("canon_memo_hits", "canon_memo_hit_rate", "shard_memo_hits",
-              "shard_distinct", "shard_skew", "coverage"):
+              "shard_distinct", "shard_skew", "coverage",
+              "canon_tier3_local", "canon_tier3_full"):
         assert k in res.stats, k
+    # two servers have no tiers: every lane canonicalised takes the
+    # S!-table min, and the rows' lanes add up to the run's
+    waves = tel.wave_events()
+    assert res.stats["canon_tier3_local"] == 0
+    assert res.stats["canon_tier3_full"] == sum(
+        w["canon_tier3_full"] for w in waves) > 0
+    assert all(w["canon_tier3_full"] <= w["generated"] - w["canon_memo_hits"]
+               for w in waves)
+    assert tel.last_summary["canon_tier3_full"] == res.stats[
+        "canon_tier3_full"]
     assert len(res.stats["shard_memo_hits"]) == 4
     assert sum(res.stats["shard_distinct"]) == res.distinct
     # fleet-summed coverage: one row per action, new sums to distinct
@@ -516,6 +527,23 @@ def test_progress_renderer_observatory_gauges():
     # null/zero gauges leave the pinned base line untouched
     ev.update(exchange_share=None, hbm_frac=0)
     assert ProgressRenderer().render_wave(ev).endswith("memo 50%")
+    # lanes the canon routed to tier 3, as a share of the wave's lanes
+    ev.update(generated=200, canon_tier3_local=30, canon_tier3_full=20)
+    assert ProgressRenderer().render_wave(ev).endswith("memo 50%, tier3 25%")
+
+
+def test_wave_tier_counters_schema_rule():
+    from raft_tpu.obs.events import validate_event
+
+    ev = dict.fromkeys(WAVE_KEYS, 0)
+    ev.update(event="wave", generated=100, canon_memo_hits=40,
+              canon_tier3_local=35, canon_tier3_full=25)
+    assert validate_event(ev) == []
+    # more tier-3 lanes than lanes that missed the memo
+    (problem,) = validate_event(dict(ev, canon_tier3_full=26))
+    assert "exceed" in problem
+    (problem,) = validate_event(dict(ev, canon_tier3_local=-1))
+    assert "non-negative" in problem
 
 
 # ---------------------------------------- observatory schema fixtures
@@ -853,12 +881,20 @@ def _lowered_text(engine: str, program: str) -> str:
     ("sharded", "chunk", "exchange"),
     ("sharded", "chunk", "dedup"),
     ("sharded", "chunk", "emit"),
+    # canon's nested scopes (a layout without tiers has these two; the
+    # five-server ones are in test_flexraft5.py)
+    ("device", "wave", "canon/memo"),
+    ("device", "wave", "canon/tier3_full"),
+    ("sharded", "chunk", "canon/memo"),
+    ("sharded", "chunk", "canon/tier3_full"),
 ])
 def test_stage_scope_in_lowered_program(engine, program, stage):
-    assert stage in TIMELINE_STAGES
+    assert stage.split("/")[0] in TIMELINE_STAGES
     # a location reads "jit(_wave_step)/while/body/canon/...", or, inside
     # a shard_map, starts at the scope: "canon/..."
-    assert re.search(rf'["/]{stage}/', _lowered_text(engine, program)), (
+    # (a nested scope may sit below control flow: "canon/while/body/memo/")
+    path = '/(?:[^"]*/)?'.join(stage.split("/"))
+    assert re.search(rf'["/]{path}/', _lowered_text(engine, program)), (
         f"no op of {engine}:{program} carries the {stage!r} scope")
 
 

@@ -254,13 +254,13 @@ def test_memo_cold_equals_plain(name):
     auto, _ = canon_pair(model)
     valid = np.ones(len(batch), dtype=bool)
     plain = np.asarray(auto.fingerprints(batch))
-    cold, memo1, n_hit = auto.fingerprints_memo(
+    cold, memo1, n_hit, _tiers = auto.fingerprints_memo(
         batch, valid, _fresh_memo(1 << 12))
     assert np.array_equal(np.asarray(cold), plain)
     assert int(n_hit) == 0
 
     # warm pass over the same batch: hits must return the SAME values
-    warm, _memo2, n_hit2 = auto.fingerprints_memo(batch, valid, memo1)
+    warm, _memo2, n_hit2, _tiers = auto.fingerprints_memo(batch, valid, memo1)
     assert np.array_equal(np.asarray(warm), plain)
     assert int(n_hit2) > 0
 
@@ -269,7 +269,7 @@ def test_memo_invalid_lanes_masked():
     model, _oracle, _states, vecs = states_of("raft3", depth=3, cap=60)
     auto, _ = canon_pair(model)
     valid = np.arange(len(vecs)) % 3 != 0
-    fps, _memo, _n = auto.fingerprints_memo(
+    fps, _memo, _n, _tiers = auto.fingerprints_memo(
         vecs.astype(np.int32), valid, _fresh_memo(1 << 10))
     fps = np.asarray(fps)
     assert np.all(fps[~valid] == U64_MAX)
@@ -289,7 +289,7 @@ def test_memo_correct_across_eviction():
     plain = np.asarray(auto.fingerprints(batch))
     memo = _fresh_memo(2)
     for _ in range(3):  # repeated passes churn the tiny table
-        fps, memo, _n = auto.fingerprints_memo(batch, valid, memo)
+        fps, memo, _n, _tiers = auto.fingerprints_memo(batch, valid, memo)
         assert np.array_equal(np.asarray(fps), plain)
 
 
