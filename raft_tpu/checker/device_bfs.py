@@ -70,7 +70,7 @@ from .bfs import CheckResult, Violation
 from .lsm import CanonMemo, pow2_at_least
 from .util import (
     GROWTH, HEADROOM, I32_MAX, dedup_plan, dense_prefix_sel, emit_append,
-    first_new, jit_with_donation, next_cap,
+    first_new, jit_with_donation, next_cap, rank_counts, rank_onehot,
 )
 
 
@@ -440,35 +440,30 @@ class DeviceBFS:
         FCAP, JCAP = self.FCAP, self.JCAP
         n_new = jnp.sum(new)
 
-        # 4b. per-action coverage: segment_sum over the rank/valid lanes
-        # _expand1 already returns, invalid lanes routed to drop bucket
-        # K (rank is -1 only where valid is False, so the id stays in
-        # range). enabled counts states where the disjunct's guard held;
-        # fired counts valid candidate lanes; new-distinct counts first-
-        # writer lanes (rank gathered through the compaction `sel`).
+        # 4b. per-action coverage, by compare and sum (util.rank_counts:
+        # no scatter-add, no drop bucket) over the rank/valid lanes
+        # _expand1 already returns; rank is -1 or under a false mask
+        # wherever a lane does not count. enabled counts states where
+        # the disjunct's guard held; fired counts valid candidate lanes;
+        # new-distinct counts first-writer lanes (rank gathered through
+        # the compaction `sel`; a new lane is a valid one). A chunk-step
+        # counts at most C * A lanes in int32 and widens once into the
+        # cumulative i64 `cov`.
         K = self.n_actions
         if K:
             with jax.named_scope("coverage"):
-                rk = jnp.where(valid, rank, K)
-                fired_k = jax.ops.segment_sum(
-                    jnp.ones((C * A,), jnp.int64), rk.reshape(-1),
-                    num_segments=K + 1,
-                )[:K]
-                en = (
-                    rank[:, :, None] == jnp.arange(K, dtype=rank.dtype)
-                ) & (
-                    valid[:, :, None]
-                )  # [C, A, K] one-hot (compare beats a scatter on TPU)
+                en = rank_onehot(rank, valid, K)  # [C, A, K]
                 enabled_k = jnp.sum(
-                    jnp.any(en, axis=1), axis=0, dtype=jnp.int64)
+                    jnp.any(en, axis=1), axis=0, dtype=jnp.int32)
+                # the one-hot again: XLA keeps one compare for both
+                fired_k = rank_counts(rank, valid, K)
                 flat_rk = jnp.concatenate(
-                    [rk.reshape(-1), jnp.full((1,), K, rk.dtype)]
-                )[sel]  # [VC] rank per compacted lane (drop row -> bucket K)
-                new_k = jax.ops.segment_sum(
-                    new.astype(jnp.int64), jnp.where(new, flat_rk, K),
-                    num_segments=K + 1,
-                )[:K]
-                cov = cov + jnp.stack([enabled_k, fired_k, new_k], axis=1)
+                    [rank.reshape(-1), jnp.full((1,), -1, rank.dtype)]
+                )[sel]  # [VC] rank per compacted lane (drop row -> -1)
+                new_k = rank_counts(flat_rk, new, K)
+                cov = cov + jnp.stack(
+                    [enabled_k, fired_k, new_k], axis=1
+                ).astype(jnp.int64)
 
         # 5. emit: compact survivors to a dense prefix of a [VC, W]
         # block (scatter confined to a chunk-sized index buffer), then

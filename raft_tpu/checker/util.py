@@ -114,6 +114,37 @@ def first_new(vals, occ, runs):
     return new
 
 
+def rank_onehot(rank, mask, n_ranks: int):
+    """bool[..., n_ranks]: lane i's slot k says ``mask[i]`` and
+    ``rank[i] == k``. A lane under a false mask, or whose rank is
+    outside 0..n_ranks-1 (-1 on an invalid candidate), is all False:
+    there is no drop bucket."""
+    return (
+        rank[..., None] == jnp.arange(n_ranks, dtype=rank.dtype)
+    ) & mask[..., None]
+
+
+def rank_counts(rank, mask, n_ranks: int):
+    """i32[n_ranks]: for each k < n_ranks the lanes with ``mask`` set and
+    ``rank == k`` — the per-action coverage counters of both device
+    engines, counted once here.
+
+    By compare and sum, never by scatter: a scatter-add is a serial pass
+    on this TPU (60 ns a lane: 13.0 ms for a chunk's 217,088 candidate
+    lanes into 13 buckets; PERF.md section 6, PR 27), where the one-hot
+    compare fuses into a dense reduction of lanes x n_ranks
+    lane-compares. The sum is int32 (int64 reductions are emulated on
+    the chip), so a call counts fewer than 2^31 lanes; the caller widens
+    once when it adds into the cumulative i64 counters. Sized for the
+    12 to 21 ranks the spec lowerings have: a model with hundreds of
+    ranks would want another count."""
+    assert rank.size < 1 << 31
+    return jnp.sum(
+        rank_onehot(rank, mask, n_ranks),
+        axis=tuple(range(rank.ndim)), dtype=jnp.int32,
+    )
+
+
 def dense_prefix_sel(new, npos, n_lanes: int):
     """Gather indices compacting the ``new`` lanes to a dense prefix.
 

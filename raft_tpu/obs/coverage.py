@@ -4,7 +4,11 @@ and the digest attached to BENCH provenance.
 The input everywhere is the cumulative per-action counter block the
 engines accumulate on device — ``actions[rank] = [enabled, fired,
 new_distinct]`` with ``rank`` indexing the model's ``ACTION_NAMES``
-(the Next-disjunct order):
+(the Next-disjunct order). The device engines make each chunk-step's
+counts by comparing the lanes' ranks against 0..K-1 and summing the
+one-hot in int32 (``checker/util.py`` ``rank_onehot``/``rank_counts``;
+no scatter-add), then add them into the cumulative i64 block; the host
+engine counts with ``np.bincount``, and the counts are equal:
 
   enabled       (state, action) pairs where the disjunct's guard held
                 on a live frontier state — i.e. at least one candidate

@@ -70,7 +70,7 @@ from ..obs import (
 from ..obs.events import hashv_of
 from ..checker.util import (
     GROWTH, HEADROOM, I32_MAX, dedup_plan, dense_prefix_sel, emit_append,
-    first_new, next_cap as _next_cap,
+    first_new, next_cap as _next_cap, rank_counts, rank_onehot,
 )
 from ..ops.hashing import (
     U64_MAX, eq_u64, ne_u64, sort_u64, sort_u64_with_idx, split_u64,
@@ -541,18 +541,12 @@ class ShardedBFS:
             term = jnp.sum(live & ~jnp.any(valid, axis=1))
 
             # 1b. enabled/fired per action rank, tallied where the lanes are
-            # generated (numpy mirror in checker/bfs.py; invalid lanes route
-            # to drop bucket K)
+            # generated (numpy mirror in checker/bfs.py), by compare and
+            # sum: util.rank_counts, as DeviceBFS._st_finish
             if K:
-                rk = jnp.where(valid, rank, K)
-                fired_k = jax.ops.segment_sum(
-                    jnp.ones((C * A,), jnp.int64), rk.reshape(-1),
-                    num_segments=K + 1,
-                )[:K]
-                en = (rank[:, :, None] == jnp.arange(K, dtype=rank.dtype)) & (
-                    valid[:, :, None]
-                )  # [C, A, K] one-hot (compare beats a scatter on TPU)
-                enabled_k = jnp.sum(jnp.any(en, axis=1), axis=0, dtype=jnp.int64)
+                en = rank_onehot(rank, valid, K)  # [C, A, K]
+                enabled_k = jnp.sum(jnp.any(en, axis=1), axis=0, dtype=jnp.int32)
+                fired_k = rank_counts(rank, valid, K)
 
             # 2. compact the valid lanes (sel[j] = flat lane of the j-th valid)
             vflat = valid.reshape(-1)
@@ -630,7 +624,7 @@ class ShardedBFS:
             *canon_n.astype(jnp.int64),
         ])
         cov_gen = (
-            jnp.stack([enabled_k, fired_k], axis=1)
+            jnp.stack([enabled_k, fired_k], axis=1).astype(jnp.int64)
             if K else jnp.zeros((1, 2), jnp.int64)
         )
         return send_pay, send_fps, memo, cov_gen, pre_stats
@@ -691,14 +685,11 @@ class ShardedBFS:
             jcand, _ = emit_append(jcand, jc_blk, jcount, n_new, JC)
             jfp, _ = emit_append(jfp, jfp_blk, jcount, n_new, JC)
             if K:
-                # new-distinct per rank on the owner chip (non-new lanes ->
-                # drop bucket K; their routed rank column may be garbage 0s
-                # from unfilled send slots, but `new` masks them out)
+                # new-distinct per rank on the owner chip (a lane that is
+                # not new does not count: its routed rank column may be
+                # garbage 0s from unfilled send slots)
                 recv_rank = recv_pay[sidx, W + 2]
-                new_k = jax.ops.segment_sum(
-                    new.astype(jnp.int64), jnp.where(new, recv_rank, K),
-                    num_segments=K + 1,
-                )[:K]
+                new_k = rank_counts(recv_rank, new, K).astype(jnp.int64)
                 cov = cov + jnp.concatenate(
                     [cov_gen, new_k[:, None]], axis=1)
             # the chip's new fps as one sorted run (LSM level-0 insert)

@@ -42,14 +42,14 @@ MODEL_MODULES = (
 )
 
 
-def _device(model, **kw):
+def _device(model, invariants=INVS, **kw):
     from raft_tpu.checker.device_bfs import DeviceBFS
 
     kw.setdefault("chunk", 512)
     kw.setdefault("frontier_cap", 1 << 12)
     kw.setdefault("seen_cap", 1 << 15)
     kw.setdefault("journal_cap", 1 << 15)
-    return DeviceBFS(model, invariants=INVS, symmetry=True, **kw)
+    return DeviceBFS(model, invariants=invariants, symmetry=True, **kw)
 
 
 # ------------------------------------------------- rank/name registry
@@ -134,18 +134,96 @@ def test_device_coverage_accumulation_invariants():
     assert covs[-1]["canon_memo_fill"] is not None
 
 
-def test_host_and_device_engines_agree():
+def _raft2_setup():
+    return cached_model(COV_PARAMS), INVS, 6, {}
+
+
+def _flexraft5_setup():
+    """The benchmark's five-server lowering (A = 82 actions a state, 200
+    lanes a row), as tests/test_flexraft5.py builds it."""
+    import os
+
+    from raft_tpu.models.registry import build_from_cfg
+    from raft_tpu.utils.cfg import parse_cfg
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    setup = build_from_cfg(parse_cfg(os.path.join(
+        root, "configs", "flexible-raft", "FlexibleRaft.cfg")), msg_slots=32)
+    return setup.model, setup.invariants, 5, {"chunk": 256}
+
+
+@pytest.mark.parametrize(
+    "make", [_raft2_setup, _flexraft5_setup], ids=["raft2", "flexraft5"])
+def test_host_and_device_engines_agree(make):
     from raft_tpu.checker.bfs import BFSChecker
 
-    model = cached_model(COV_PARAMS)
-    host = BFSChecker(model, invariants=INVS, symmetry=True, chunk=512).run(
-        max_depth=6
+    model, invs, depth, kw = make()
+    host = BFSChecker(model, invariants=invs, symmetry=True, chunk=512).run(
+        max_depth=depth
     )
-    dev = _device(model).run(max_depth=6)
+    dev = _device(model, invariants=invs, **kw).run(max_depth=depth)
     h, d = np.asarray(host.coverage), np.asarray(dev.coverage)
+    assert d.shape == (len(model.ACTION_NAMES), 3) and d[:, 1].sum() > 0
     assert h[:, :2].tolist() == d[:, :2].tolist()  # enabled/fired exact
     assert int(h[:, 2].sum()) == int(d[:, 2].sum())
     assert int(d[:, 2].sum()) == dev.distinct - dev.depth_counts[0]
+
+
+# ------------------------------------------------- the counting helper
+
+
+COUNT_CASES = {  # name -> (K, shape of the lanes)
+    "k12": (12, (64, 53)),
+    "k21": (21, (32, 82)),
+    "odd-lanes": (12, (37, 53)),  # 1,961 lanes, not a multiple of 128
+    "flat-lanes": (12, (1000,)),  # the compacted [VC] lanes of new_k
+    "all-masked": (12, (16, 53)),
+    "minus-one-under-false-mask": (12, (16, 53)),
+    "one-bucket": (12, (16, 53)),
+}
+
+
+def _count_case(name):
+    """(rank, mask, K) for one case of the helper's test; ranks are drawn
+    from -1..K-1, -1 being what an invalid candidate lane carries."""
+    rng = np.random.default_rng(27)
+    K, shape = COUNT_CASES[name]
+    rank = rng.integers(-1, K, size=shape).astype(np.int32)
+    mask = rng.random(shape) < 0.5
+    if name == "all-masked":
+        mask[...] = False
+    elif name == "minus-one-under-false-mask":
+        # every -1 lane is under a false mask, as _expand1 leaves them
+        mask = rank >= 0
+        assert (rank == -1).any()
+    elif name == "one-bucket":
+        rank[...] = 7
+        mask[...] = True
+    return rank, mask, K
+
+
+@pytest.mark.parametrize("name", COUNT_CASES)
+def test_rank_counts_is_bincount(name):
+    """util.rank_counts (what both device engines count fired and
+    new-distinct with) against np.bincount, the host engine's count."""
+    import jax
+    import jax.numpy as jnp
+
+    from raft_tpu.checker.util import rank_counts, rank_onehot
+
+    rank, mask, K = _count_case(name)
+    keep = mask & (rank >= 0)
+    want = np.bincount(rank[keep], minlength=K)
+    got = jax.jit(rank_counts, static_argnums=2)(rank, mask, K)
+    assert got.dtype == jnp.int32 and got.shape == (K,)
+    assert np.asarray(got).tolist() == want.tolist()
+    hot = np.asarray(rank_onehot(jnp.asarray(rank), jnp.asarray(mask), K))
+    assert hot.shape == (*rank.shape, K)
+    assert (hot.sum(axis=-1) == keep).all()  # one slot a counted lane
+    if name == "all-masked":
+        assert not want.any()
+    if name == "one-bucket":
+        assert want[7] == rank.size and want.sum() == rank.size
 
 
 # ------------------------------------------------- schema round-trip

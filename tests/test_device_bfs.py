@@ -193,3 +193,67 @@ def test_device_bfs_checkpoint_spec_mismatch(tmp_path):
     _device(SMALL, INVS).run(max_depth=3, checkpoint_path=ck, checkpoint_every_s=0.0)
     with pytest.raises(ValueError, match="checkpoint is for spec"):
         _device(other, INVS).run(resume=ck)
+
+
+# ------------------------------------------------ no scatter-add in a wave
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations hold
+    (pjit, while, cond, shard_map, ...)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (list, tuple)) else (param,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub)
+
+
+def _device_wave():
+    eng = _device(SMALL, INVS, chunk=256, frontier_cap=1 << 12,
+                  seen_cap=1 << 14, journal_cap=1 << 14)
+    return eng, "wave"
+
+
+def _sharded_chunk():
+    import jax
+
+    from raft_tpu.parallel.sharded import ShardedBFS
+
+    eng = ShardedBFS(
+        cached_model(SMALL), invariants=INVS, symmetry=True,
+        devices=jax.devices()[:2], chunk=256, frontier_cap=1 << 10,
+        seen_cap=1 << 12,
+    )
+    return eng, "chunk"
+
+
+@pytest.mark.parametrize(
+    "make", [_device_wave, _sharded_chunk], ids=["device", "sharded"])
+def test_wave_program_scatter_adds_nothing(make):
+    """The engagement check of the compare-and-sum coverage counters
+    (util.rank_counts): the program a wave dispatches, traced with K > 0
+    actions, holds no ``scatter-add`` of the engine's own. A scatter-add
+    is a serial pass on the TPU (PERF.md section 6, PR 27: 13 ms a
+    chunk-step for the counters it used to make), so a feature that
+    brings a ``segment_sum`` or an ``.at[].add`` into the wave fails
+    here. The one place allowed is the spec lowering's own per-state
+    body, ``expand/vmap()`` (``log_len.at[i].add(1)``): the model's, not
+    the engine's."""
+    import jax
+
+    eng, name = make()
+    assert eng.n_actions > 0
+    (prog,) = [p for p in eng.audit_programs() if p["name"] == name]
+    eqns = list(_eqns(jax.make_jaxpr(prog["fn"])(*prog["args"]).jaxpr))
+    stacks = {str(e.source_info.name_stack) for e in eqns}
+    # the scopes this test reads are there, so an empty list means none
+    assert any(s.startswith("emit/coverage") or s.startswith("expand")
+               for s in stacks), sorted(stacks)[:20]
+    adds = [
+        str(e.source_info.name_stack) for e in eqns
+        if e.primitive.name == "scatter-add"
+        and "expand/vmap()" not in str(e.source_info.name_stack)
+    ]
+    assert not adds, f"scatter-add in the {name} program under: {adds}"
