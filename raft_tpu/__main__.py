@@ -148,16 +148,6 @@ def main(argv=None):
     )
     ap.add_argument("--chunk", type=int, default=1024, help="device batch size")
     ap.add_argument(
-        "--profile",
-        type=int,
-        default=None,
-        metavar="DEPTH",
-        help="instead of checking, warm a BFS to DEPTH and print a per-"
-        "stage time breakdown of the chunk pipeline (expand / compact / "
-        "canonicalize / probe / run-emit / scatter / invariants; "
-        "SURVEY.md §5.1); tpu checker only",
-    )
-    ap.add_argument(
         "--simulate",
         type=int,
         default=None,
@@ -234,19 +224,6 @@ def main(argv=None):
         "so the stream stays count-accurate)",
     )
     ap.add_argument(
-        "--timeline",
-        nargs="?",
-        const=8,
-        type=int,
-        default=0,
-        metavar="EVERY_N",
-        help="wave-timeline observatory: run every Nth wave (default 8) "
-        "as separately timed stage dispatches and emit `timeline` (and, "
-        "on the sharded checker, per-shard `shard_wave`) events into the "
-        "metrics stream; sampled waves are bit-identical to the fused "
-        "program, unsampled waves are untouched; BFS checkers only",
-    )
-    ap.add_argument(
         "--trace-dir",
         default=None,
         metavar="DIR",
@@ -254,7 +231,7 @@ def main(argv=None):
         "to DIR. The spans (run, init, wave with dispatch/fetch/"
         "seen_merge/..., finish; each wave an xprof step) and the stage "
         "scopes on the device ops are always there: this only records "
-        "them",
+        "them; scripts/stage_split.py --trace-dir DIR reduces it to stages",
     )
     ap.add_argument(
         "--json",
@@ -404,22 +381,6 @@ def main(argv=None):
                 file=sys.stderr,
             )
             return 70
-
-    if args.profile is not None:
-        if args.checker != "tpu" or args.simulate is not None:
-            print(
-                "error: --profile needs --checker tpu and no --simulate",
-                file=sys.stderr,
-            )
-            return 64
-        from .checker.profile import profile_stages, render
-
-        prof = profile_stages(
-            setup.model, invariants=setup.invariants, symmetry=symmetry,
-            chunk=args.chunk, warm_depth=args.profile, **cli_caps,
-        )
-        print(render(prof))
-        return 0
 
     if args.checker == "oracle" and args.simulate is not None:
         from .models.registry import oracle_for_setup
@@ -597,7 +558,7 @@ def main(argv=None):
     tel = None
     if (
         args.progress is not None or args.metrics_out is not None
-        or args.trace_dir is not None or args.json or args.timeline
+        or args.trace_dir is not None or args.json
     ):
         from .obs import Telemetry
 
@@ -606,7 +567,6 @@ def main(argv=None):
             every=args.metrics_every,
             progress_every=args.progress,
             trace_dir=args.trace_dir,
-            timeline_every=args.timeline,
         )
 
     def _finish(rc: int) -> int:

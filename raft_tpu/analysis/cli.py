@@ -13,8 +13,7 @@ Exit codes (the repo-wide convention, see raft_tpu/__main__.py):
 pass catalogue; ``--mutate NAME`` applies one seeded contract
 violation from the self-test kit and runs the targeted pass — the
 negative control proving the auditor fires (expected exit: 3).
-``--json`` emits one machine-readable document on stdout (the same
-shape bench.py embeds as the lint provenance verdict).
+``--json`` emits one machine-readable document on stdout (``verdict``).
 """
 
 from __future__ import annotations
@@ -53,7 +52,7 @@ def exit_code(results, strict: bool) -> int:
 
 
 def verdict(results, strict: bool) -> dict:
-    """The machine-readable summary (bench.py provenance block)."""
+    """The machine-readable summary (``--json``)."""
     return {
         "strict": strict,
         "errors": sum(r.errors for r in results),
@@ -62,12 +61,6 @@ def verdict(results, strict: bool) -> dict:
         "clean": exit_code(results, strict) == 0,
         "passes": [r.to_dict() for r in results],
     }
-
-
-def lint_verdict(strict: bool = True) -> dict:
-    """One-call in-process lint for tooling (bench.py): all passes,
-    verdict dict."""
-    return verdict(run_lint(), strict)
 
 
 def _usage(msg: str) -> int:

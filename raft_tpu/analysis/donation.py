@@ -2,8 +2,8 @@
 output of the lowered program that rebinds it.
 
 The engines declare their dispatch surface via ``audit_programs()``
-(DeviceBFS: fused wave + --timeline stages + seen-ladder merges;
-ShardedBFS: shard_map chunk + timeline pre/exchange/post; RunLSM: the
+(DeviceBFS: the fused wave program + the seen-ladder merge;
+ShardedBFS: the shard_map chunk program; RunLSM: the
 cascade merge closure). Each entry carries an INDEPENDENT ``carries``
 map — written out separately from the ``*_DONATE`` tuples the jits
 consume — so dropping an argnum from a donate tuple (the classic

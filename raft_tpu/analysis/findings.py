@@ -2,8 +2,8 @@
 
 A finding is ``file:line``-anchored (repo-relative, so output is stable
 across checkouts), carries the pass id and a severity, and serializes to
-JSON for machine consumers (``raft_tpu lint --json``, the bench.py
-provenance block). Severity semantics follow the CLI contract:
+JSON for machine consumers (``raft_tpu lint --json``).
+Severity semantics follow the CLI contract:
 
   error    a broken contract — ``lint`` exits 3 even without --strict
   warning  a drift/coverage gap — exits 3 only under --strict

@@ -5,7 +5,7 @@ everything else async dispatch — is what keeps the host out of the
 device's way (and keeps telemetry from perturbing what it measures: the
 observatory PR's first design cost a sync per chunk and skewed every
 stage it attributed). This pass walks the AST of the HOT loop bodies
-(``DeviceBFS.run`` / ``_run_timeline_wave`` / ``run_fleet``,
+(``DeviceBFS.run`` / ``run_fleet``,
 ``ShardedBFS.run`` / ``run_fleet``) and flags calls that force a
 host-device round trip inside a ``for``/``while`` body:
 
@@ -15,8 +15,7 @@ host-device round trip inside a ``for``/``while`` body:
     materialization (plain ``np.asarray(host_array)`` is not flagged)
 
 Blessed sites carry a ``lint: sync-ok(<why>)`` comment on the
-statement or the line above it: the once-per-wave snapshot, the
-sampled-wave stage attribution barriers (--timeline), and the
+statement or the line above it: the once-per-wave snapshot and the
 wave-start spill on shard loss. The analysis is intra-function —
 helpers called from the loop (checkpoint writers, abort paths) run
 once per EVENT, not per chunk, and are out of scope by design.
@@ -39,7 +38,7 @@ BLESS_MARK = "lint: sync-ok"
 # by policy — they ARE the host loop.
 HOT_SCOPES = {
     os.path.join("raft_tpu", "checker", "device_bfs.py"):
-        ("run", "_run_timeline_wave", "run_fleet"),
+        ("run", "run_fleet"),
     os.path.join("raft_tpu", "parallel", "sharded.py"):
         ("run", "run_fleet"),
 }

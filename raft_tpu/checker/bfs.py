@@ -247,16 +247,9 @@ class BFSChecker:
             )
         metrics: list[dict] | None = [] if collect_metrics else None
         last_ckpt = time.perf_counter()
-        # wave-timeline observatory: the host engine's stages are the
-        # numpy phases the chunk loop already runs in sequence, so the
-        # "sampled" split costs only perf_counter brackets — the wave
-        # math is untouched and trivially bit-identical to an unsampled
-        # run. device_s counts the jax-facing sections (expand/guards
-        # dispatch + fetches, fingerprinting); dedup/emit/merge are host
-        # bookkeeping and land in host_s.
-        tl_every = int(getattr(tel, "timeline_every", 0) or 0)
-        tl_wave_s: list[float] = []
-        fused_wave_s: list[float] = []
+        # the row's device_s counts the jax-facing sections of a chunk
+        # (expand/guards dispatch + fetches, fingerprinting); dedup, emit
+        # and the seen merge are host bookkeeping and land in host_s
         memwatch = (
             MemWatch(tel, device_budget(jax.devices()[0]))
             if tel.active else None
@@ -300,11 +293,6 @@ class BFSChecker:
                 exit_cause = "time_budget"
                 break
             tw = time.perf_counter()
-            tl_sample = tl_every > 0 and (depth + 1) % tl_every == 0
-            stage_s = {
-                "expand": 0.0, "canon": 0.0, "dedup": 0.0, "emit": 0.0,
-                "seen_merge": 0.0, "checkpoint": 0.0,
-            }
             dev_s = 0.0
             # contiguous cursor-append emit (mirrors the device engines'
             # emit_append): survivors append at a running cursor
@@ -359,7 +347,6 @@ class BFSChecker:
                         hit[np.arange(len(valid))[:, None], rk] = True
                         cov[:, 0] += hit[:, :K].sum(axis=0)
                     t_can = time.perf_counter()
-                    stage_s["expand"] += t_can - t_exp
                     if self._sparse:
                         # apply pass: construct rows for the enabled
                         # lanes only, then fan their fingerprints back
@@ -383,12 +370,10 @@ class BFSChecker:
                             dtype=np.uint64,
                         )
                         fps[~valid.reshape(-1)] = U64_MAX
-                    t_dd = time.perf_counter()
                     # the apply+fingerprint section mirrors the device
                     # program's canon stage, so it counts as device-facing
                     # time even on the sparse (host_apply) path
-                    stage_s["canon"] += t_dd - t_can
-                    dev_s += t_dd - t_can
+                    dev_s += time.perf_counter() - t_can
                     n_cand_total += int(valid.sum())
                     has_succ[off : off + nb] = valid[:nb].any(axis=1)
 
@@ -405,8 +390,6 @@ class BFSChecker:
                     if K:
                         cov[:, 2] += np.bincount(
                             flat_rk[idx], minlength=K + 1)[:K]
-                    t_em = time.perf_counter()
-                    stage_s["dedup"] += t_em - t_dd
                     if len(idx):
                         if self._sparse:
                             # idx lanes are all enabled (U64_MAX-masked
@@ -419,7 +402,6 @@ class BFSChecker:
                         wave_pb.append(base_gid + off + idx // model.A)
                         wave_cb.append((idx % model.A).astype(np.int32))
                         wave_fps = np.sort(np.concatenate([wave_fps, fps[idx]]))
-                    stage_s["emit"] += time.perf_counter() - t_em
 
             total += n_cand_total
             terminal += int((~has_succ).sum())
@@ -432,10 +414,8 @@ class BFSChecker:
             wave_cands = wave_cb.take()
             self._parents.append(wave_parents)
             self._cands.append(wave_cands)
-            t_sm = time.perf_counter()
             with tel.annotate("seen_merge"):
                 seen = _merge_sorted(seen, wave_fps)
-            stage_s["seen_merge"] += time.perf_counter() - t_sm
             depth += 1
             depth_counts.append(len(wave_states))
             violation = self._check_invariants(wave_states, next_gid, depth)
@@ -457,10 +437,7 @@ class BFSChecker:
                 )
                 last_ckpt = time.perf_counter()
                 ckpt_s = last_ckpt - t_ck
-                stage_s["checkpoint"] += ckpt_s
             wave_s_val = time.perf_counter() - tw
-            if tl_every:
-                (tl_wave_s if tl_sample else fused_wave_s).append(wave_s_val)
             if tel.active or metrics is not None or verbose:
                 el = time.perf_counter() - t0
                 hbm_frac = None
@@ -523,7 +500,6 @@ class BFSChecker:
                     "host_s": max(0.0, wave_s_val - dev_s - ckpt_s),
                     "ckpt_s": ckpt_s,
                     "tel_s": tel_s_last,
-                    "exchange_share": None,
                     "hbm_frac": hbm_frac,
                 }
                 t_tel = time.perf_counter()
@@ -531,16 +507,6 @@ class BFSChecker:
                 if tel.active:
                     tel.coverage(self._coverage_fields(
                         depth, cov, len(seen), depth_counts))
-                    if tl_sample:
-                        tel.event(
-                            "timeline", wave=depth, depth=depth,
-                            every=tl_every,
-                            stages={
-                                k: round(v, 5)
-                                for k, v in stage_s.items() if v > 0
-                            },
-                            wave_s=round(wave_s_val, 4),
-                        )
                 if metrics is not None:
                     metrics.append(wm)
                 if verbose:
@@ -571,22 +537,6 @@ class BFSChecker:
                 self._coverage_fields(depth, cov, len(seen), depth_counts),
                 final=True,
             )
-        tl_extras = {}
-        if tl_every:
-            mt = sum(tl_wave_s) / len(tl_wave_s) if tl_wave_s else None
-            mf = (
-                sum(fused_wave_s) / len(fused_wave_s)
-                if fused_wave_s else None
-            )
-            tl_extras = {
-                "timeline_every": tl_every,
-                "timeline_waves": len(tl_wave_s),
-                # per-wave extra cost of sampling, amortized over the
-                # stride (the host engine's stages are the same numpy
-                # code either way, so this should hover near zero)
-                "timeline_overhead": round((mt - mf) / (mf * tl_every), 4)
-                if mt is not None and mf else None,
-            }
         tel.close_run({
             "engine": "host",
             "ident": self._ckpt_ident(),
@@ -605,7 +555,6 @@ class BFSChecker:
             "canon_memo_hit_rate": 0.0,
             "canon_tier3_local": 0,
             "canon_tier3_full": 0,
-            **tl_extras,
             **(memwatch.summary_fields() if memwatch is not None else {}),
         })
         trace = self.reconstruct_trace(violation) if violation else None
@@ -899,7 +848,6 @@ class BFSChecker:
                     "host_s": wave_s_val,
                     "ckpt_s": 0.0,
                     "tel_s": 0.0,
-                    "exchange_share": None,
                     "hbm_frac": None,
                     "jobs_active": int(active.sum()),
                 })

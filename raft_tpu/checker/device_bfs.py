@@ -119,24 +119,14 @@ class DeviceBFS:
     N_STATS = 8
     STATS_KEEP = (0, 1, 1, 1, 0, 1, 1, 1)
 
-    # Donation contract for the wave/chunk programs: argument indices of
+    # Donation contract for the wave program: argument indices of
     # the capacity-shaped loop carries updated in place every dispatch
     # (next_buf, jparent, jcand, viol, stats, memo, cov). The frontier
     # (argnum 0) is deliberately NOT donated — the host swaps it with
     # next_buf between waves. analysis/donation.py verifies the lowered
-    # programs alias exactly these, so an edit that drops one is named
+    # program aliases exactly these, so an edit that drops one is named
     # before it costs a per-wave buffer copy.
     WAVE_DONATE = (1, 2, 3, 4, 5, 6, 7)
-    CHUNK_DONATE = (1, 2, 3, 4, 5, 6, 7)
-    # --timeline stage programs: memo in canon; the six state carries in
-    # finish; stats in the reset (expand/dedup carry nothing)
-    TL_DONATE = {
-        "expand": (),
-        "canon": (2,),
-        "dedup": (),
-        "finish": (0, 1, 2, 3, 4, 5),
-        "statreset": (0,),
-    }
 
     def __init__(
         self,
@@ -231,12 +221,9 @@ class DeviceBFS:
         self._memo = CanonMemo(canon_memo_cap if self._use_memo else 1)
         self.MCAP = self._memo.MCAP
         # donated: next_buf, jparent, jcand, viol, stats, memo, cov
-        # (seen read-only; the donation sets are class attributes so the
+        # (seen read-only; the donation set is a class attribute so the
         # static donation auditor — analysis/donation.py — can verify
         # the lowered aliasing against CARRY_NAMES independently)
-        self._chunk_fn = jax.jit(
-            self._chunk_step, donate_argnums=self.CHUNK_DONATE
-        )
         self._wave_fn = jax.jit(
             self._wave_step, donate_argnums=self.WAVE_DONATE
         )
@@ -247,10 +234,6 @@ class DeviceBFS:
         self._jparent = None
         self._jcand = None
         self._jcount = 0
-        # wave-timeline observatory programs, built on first sampled
-        # wave only (a run without --timeline never compiles them)
-        self._tl_fns: dict | None = None
-        self._tl_merge_cache: dict = {}
 
     # ---------------- seen-set adapters ----------------
 
@@ -348,14 +331,10 @@ class DeviceBFS:
     #
     # The chunk pipeline is factored into four stage methods
     # (_st_expand -> _st_canon -> _st_dedup -> _st_finish) that
-    # _chunk_step composes — the fused wave program traces the exact
-    # same (integer-only) ops, while the wave-timeline observatory
-    # (--timeline) dispatches the same stages as separate jits with
-    # block_until_ready between them to attribute a sampled wave's
-    # wall clock (obs/events.py TIMELINE_STAGES). Bit-identity of the
-    # two paths is parity-gated by tests/test_obs.py. Each stage method
-    # carries its obs.stage scope, so every program built from it names
-    # its ops by stage in a profile (obs/trace.py).
+    # _chunk_step composes and _wave_step loops over: one traced
+    # program, the only way this engine runs a wave. Each stage method
+    # carries its obs.stage scope, so a profile names its ops by stage
+    # (obs/trace.py): that is how a stage is timed.
 
     @stage("expand")
     def _st_expand(self, frontier, cursor, fcount):
@@ -493,11 +472,9 @@ class DeviceBFS:
         # better than sort-concat for merging sorted sets, but arbitrary-
         # index scatters serialize on this hardware while XLA's bitonic
         # sort is fast (scripts/emit_micro.py reproduces the scatter
-        # penalty on the current backend; EMIT_MICRO.json carries the
-        # measured numbers that used to live in this comment as
-        # folklore). All LSM merges therefore use sort-concat (as 2-key
-        # u32 sorts — hashing.py), and the per-chunk sort below is only
-        # R0 = 2^ceil(log2(VC)) lanes.
+        # penalty on the current backend). All LSM merges therefore use
+        # sort-concat (as 2-key u32 sorts — hashing.py), and the
+        # per-chunk sort below is only R0 = 2^ceil(log2(VC)) lanes.
         new_run = sort_u64(jnp.where(new, fps, U64_MAX))
         if self.R0 > VC:
             new_run = jnp.concatenate(
@@ -661,137 +638,6 @@ class DeviceBFS:
         )
         return out[1:]
 
-    # ---------------- wave-timeline observatory ----------------
-
-    def _tl_programs(self) -> dict:
-        """Separately jitted stage programs for sampled --timeline waves.
-        The loop-carried buffers donate exactly as in the fused program
-        (memo in canon; next_buf/journals/viol/stats/cov in finish) —
-        without donation every sampled chunk copies the full
-        capacity-shaped frontier + journal + memo through the stage
-        outputs, which dominates the sampled wave's wall clock on big
-        geometries and breaks the < 5% end-to-end overhead contract.
-        _run_timeline_wave rebinds every donated carry from the stage
-        return, so the dead inputs are never touched again."""
-        if self._tl_fns is None:
-            d = self.TL_DONATE
-            self._tl_fns = {
-                "expand": jax.jit(self._st_expand),
-                "canon": jax.jit(self._st_canon, donate_argnums=d["canon"]),
-                "dedup": jax.jit(self._st_dedup),
-                "finish": jax.jit(
-                    self._st_finish, donate_argnums=d["finish"]
-                ),
-                "statreset": jax.jit(
-                    lambda s: s * jnp.asarray(self.STATS_KEEP,
-                                              dtype=s.dtype),
-                    donate_argnums=d["statreset"],
-                ),
-            }
-        return self._tl_fns
-
-    def _tl_merge_fn(self, tt: int, K: int):
-        """Cascade merge program for chain length tt (tt == K truncates
-        at the top, mirroring _wave_step.cascade's absorb branch). No
-        donation here on purpose: the concatenated sort output can never
-        alias the smaller inputs (XLA would warn, not alias), and ladder
-        runs are KiB-scale — the buffers worth donating are the
-        capacity-shaped carries in the canon/finish stages."""
-        key = (tt, K)
-        fn = self._tl_merge_cache.get(key)
-        if fn is None:
-            topsz = self.R0 << K
-            if tt < K:
-                def merge(r, *lv):
-                    return sort_u64(jnp.concatenate([r, *lv]))
-            else:
-                def merge(r, *lv):
-                    return sort_u64(jnp.concatenate([r, *lv]))[:topsz]
-            fn = jax.jit(stage("seen_merge")(merge))
-            self._tl_merge_cache[key] = fn
-        return fn
-
-    def _run_timeline_wave(
-        self, frontier, next_buf, jparent, jcand, viol, stats, memo, cov,
-        fcount, base_gid, stage_s,
-    ):
-        """Host-driven mirror of _wave_step for a SAMPLED --timeline
-        wave: the same stage methods the fused program composes, each
-        dispatched as its own jit with block_until_ready between them,
-        so the wave's wall clock is attributed to TIMELINE_STAGES
-        (accumulated into ``stage_s``). Bit-identical to _wave_fn: the
-        stage math is shared (integer-only ops, no FP reassociation
-        risk) and the host cascade below replays the binary-counter
-        schedule exactly — the parity gate in tests/test_obs.py pins
-        it. Returns the same tuple as _wave_fn, so run() continues
-        unchanged (ladder shapes match the fused ladder, keeping the
-        _merge_seen signature cache warm)."""
-        C = self.chunk
-        K = self._wave_geom()
-        R0 = self.R0
-        fns = self._tl_programs()
-        pc = time.perf_counter
-
-        def reset_run(i):
-            # fresh arrays on purpose: _merge_seen donates the ladder at
-            # wave end, so a cached/shared reset template would be
-            # consumed by the first merge that receives it
-            return jnp.full((R0 << i,), U64_MAX, jnp.uint64)
-
-        stats = fns["statreset"](stats)
-        occ_all = jnp.concatenate([self._occ_one, jnp.ones((K + 1,), bool)])
-        ladder = [reset_run(i) for i in range(K + 1)]
-        n_chunks = -(-int(fcount) // C)
-        for k in range(n_chunks):
-            t = pc()
-            # lint: sync-ok(stage attribution on a sampled wave)
-            ex = jax.block_until_ready(
-                fns["expand"](frontier, np.int32(k * C), np.int32(fcount))
-            )
-            stage_s["expand"] += pc() - t
-            (flatc, sel, selv, valid, rank, n_gen, terminal, e_ovf,
-             c_ovf) = ex
-            t = pc()
-            # lint: sync-ok(stage attribution on a sampled wave)
-            fps, memo, canon_n = jax.block_until_ready(
-                fns["canon"](flatc, selv, memo)
-            )
-            stage_s["canon"] += pc() - t
-            t = pc()
-            # lint: sync-ok(stage attribution on a sampled wave)
-            new = jax.block_until_ready(
-                fns["dedup"](fps, occ_all, self._seen, *ladder)
-            )
-            stage_s["dedup"] += pc() - t
-            t = pc()
-            # lint: sync-ok(stage attribution on a sampled wave)
-            (next_buf, jparent, jcand, viol, stats, cov,
-             new_run) = jax.block_until_ready(fns["finish"](
-                next_buf, jparent, jcand, viol, stats, cov, flatc, fps,
-                sel, valid, rank, new, n_gen, terminal, e_ovf, c_ovf,
-                canon_n, np.int32(k * C), np.int32(base_gid),
-            ))
-            stage_s["emit"] += pc() - t
-            # binary-counter cascade, host-replayed: chain length =
-            # trailing zero bits of k+1, capped at K where the top
-            # absorbs by truncate-merge (same schedule as
-            # _wave_step.cascade, so ladder contents stay identical)
-            t = pc()
-            kp1 = k + 1
-            tt = 0
-            while tt < K and kp1 % (1 << (tt + 1)) == 0:
-                tt += 1
-            if tt < K:
-                merged = self._tl_merge_fn(tt, K)(new_run, *ladder[:tt])
-            else:
-                merged = self._tl_merge_fn(K, K)(new_run, *ladder)
-            for i in range(tt):
-                ladder[i] = reset_run(i)
-            ladder[tt] = merged
-            jax.block_until_ready(ladder)  # lint: sync-ok(stage attribution)
-            stage_s["seen_merge"] += pc() - t
-        return (next_buf, jparent, jcand, viol, stats, memo, cov, *ladder)
-
     # ---------------- precompile ----------------
 
     def precompile(self, telemetry=None) -> None:
@@ -865,7 +711,7 @@ class DeviceBFS:
         """Every device program this engine dispatches, as audit entries
         for the static donation auditor (analysis/donation.py):
 
-          name     program id (``wave`` / ``tl:<stage>`` / ``seen_merge``)
+          name     program id (``wave`` / ``seen_merge``)
           fn       a ``.lower()``-able jitted callable — the PRODUCTION
                    jit object where one exists
           args     abstract arguments for ``fn.lower(*args)``
@@ -880,7 +726,7 @@ class DeviceBFS:
         Yields entries without lowering or executing anything — tracing
         is the caller's cost, so passes choose their own coverage. The
         ``carries`` maps are written out independently of the
-        ``*_DONATE`` declarations on purpose: the auditor compares the
+        ``WAVE_DONATE`` declaration on purpose: the auditor compares the
         lowered aliasing against THIS list, so dropping an argnum from a
         donate tuple (the classic regression) diverges the two.
         """
@@ -915,51 +761,6 @@ class DeviceBFS:
             "carries": dict(wave_carries),
             "pinned": {0: "frontier"},
             "site": site(self._wave_step), "per_wave": 1,
-        }
-        # NOTE: _chunk_fn (the unfused per-chunk program) shares the
-        # donate set but has not been dispatched since the wave fusion
-        # (round 5); it is omitted here so the auditor's lowering budget
-        # goes to programs a run actually executes.
-
-        # --timeline stage programs: chain abstract shapes through the
-        # stage methods with eval_shape (no tracing of the jitted
-        # wrappers until the auditor lowers them)
-        fns = self._tl_programs()
-        ex_out = jax.eval_shape(self._st_expand, frontier, i32s, i32s)
-        flatc, sel, selv = ex_out[0], ex_out[1], ex_out[2]
-        valid, rank = ex_out[3], ex_out[4]
-        n_gen, terminal, e_ovf, c_ovf = ex_out[5:9]
-        canon_out = jax.eval_shape(self._st_canon, flatc, selv, memo)
-        fps = canon_out[0]
-        canon_n = canon_out[2]
-        occ_all = sds((K + 2,), jnp.bool_)
-        ladder = tuple(
-            sds((self.R0 << i,), jnp.uint64) for i in range(K + 1)
-        )
-        new = jax.eval_shape(
-            self._st_dedup, fps, occ_all, seen, *ladder
-        )
-        yield {
-            "name": "tl:canon", "fn": fns["canon"],
-            "args": (flatc, selv, memo),
-            "carries": {2: "memo"}, "pinned": {},
-            "site": site(self._st_canon), "per_wave": 1,
-        }
-        yield {
-            "name": "tl:finish", "fn": fns["finish"],
-            "args": (next_buf, jparent, jcand, viol, stats, cov, flatc,
-                     fps, sel, valid, rank, new, n_gen, terminal, e_ovf,
-                     c_ovf, canon_n, i32s, i32s),
-            "carries": {0: "next_buf", 1: "jparent", 2: "jcand",
-                        3: "viol", 4: "stats", 5: "cov"},
-            "pinned": {},
-            "site": site(self._st_finish), "per_wave": 1,
-        }
-        yield {
-            "name": "tl:statreset", "fn": fns["statreset"],
-            "args": (stats,),
-            "carries": {0: "stats"}, "pinned": {},
-            "site": site(self._tl_programs), "per_wave": 1,
         }
         # the per-wave seen merge, at the first (size, target) signature:
         # spec-built jit (production builds the same body and donation
@@ -1211,16 +1012,6 @@ class DeviceBFS:
         metrics: list[dict] | None = [] if collect_metrics else None
         last_ckpt = time.perf_counter()
 
-        # wave-timeline observatory state: sampling stride from the
-        # telemetry facade (0 = every wave stays fused), per-path wave
-        # seconds for the overhead estimate in the summary, HBM
-        # watermark tracker (analytic — no device reads), and the
-        # previous wave's telemetry-emission cost (tel_s is only known
-        # one wave late; 0.0 on wave 1)
-        tl_every = int(getattr(tel, "timeline_every", 0) or 0)
-        tl_waves = 0
-        tl_wave_s: list[float] = []
-        fused_wave_s: list[float] = []
         memwatch = (
             MemWatch(tel, device_budget(jax.devices()[0]))
             if tel.active else None
@@ -1287,33 +1078,18 @@ class DeviceBFS:
                     gen_prev, depth_counts, cov_h,
                 )
                 last_ckpt = time.perf_counter()
-            tl_sample = tl_every > 0 and (depth + 1) % tl_every == 0
-            stage_s = (
-                {s: 0.0 for s in ("expand", "canon", "dedup", "emit",
-                                  "seen_merge", "checkpoint")}
-                if tl_sample else None
-            )
             # ONE dispatch per wave: the chunk loop runs device-side
             # (_wave_step) and returns the wave's new fingerprints as a
             # binary-counter ladder, merged into the single seen run
             # below AFTER the overflow check (so an aborted wave leaves
             # the seen-set untouched and the run trivially resumable).
-            # A sampled --timeline wave runs the same stages host-driven
-            # with per-stage timing instead (bit-identical, parity-
-            # gated); untimed waves keep the fused program.
             with tel.wave_annotation(depth + 1):
                 with ph("dispatch"):
-                    if tl_sample:
-                        out = self._run_timeline_wave(
-                            frontier, next_buf, jparent, jcand, viol,
-                            stats, memo, cov, fcount, base_gid, stage_s,
-                        )
-                    else:
-                        out = self._wave_fn(
-                            frontier, next_buf, jparent, jcand, viol,
-                            stats, memo, cov, np.int32(fcount),
-                            np.int32(base_gid), self._occ_one, self._seen,
-                        )
+                    out = self._wave_fn(
+                        frontier, next_buf, jparent, jcand, viol,
+                        stats, memo, cov, np.int32(fcount),
+                        np.int32(base_gid), self._occ_one, self._seen,
+                    )
                 next_buf, jparent, jcand, viol, stats, memo, cov = out[:7]
                 ladder = out[7:]
                 # one host round-trip per wave: stats, the invariant
@@ -1380,8 +1156,6 @@ class DeviceBFS:
             # precompile)
             with ph("seen_merge"):
                 self._merge_seen(ladder, scount)
-            if stage_s is not None:
-                stage_s["seen_merge"] += ph.s["seen_merge"]
             depth += 1
             distinct += ncount
             depth_counts.append(ncount)
@@ -1416,8 +1190,6 @@ class DeviceBFS:
                     gen_prev, depth_counts, cov_h,
                 )
                 last_ckpt = time.perf_counter()
-                if stage_s is not None:
-                    stage_s["checkpoint"] += ph.s["checkpoint"]
             # the wave's canon counts (memo hits, tier-3 local and full
             # lanes), from the cumulative lanes of the same snapshot
             wave_memo, wave_t3l, wave_t3f = (
@@ -1437,9 +1209,6 @@ class DeviceBFS:
             device_s = dispatch_s + fetch_s + merge_s
             ckpt_s = ph_s.get("checkpoint", 0.0)
             comp_now = COMPILES.snapshot()
-            if tl_every:
-                (tl_wave_s if tl_sample else fused_wave_s).append(wave_s_val)
-                tl_waves += 1 if tl_sample else 0
             hbm_frac = None
             if memwatch is not None:
                 # analytic live-bytes: what the run's geometry holds in
@@ -1522,7 +1291,6 @@ class DeviceBFS:
                     # its wave (obs/compiles.py)
                     "compiles": comp_now[0] - comp_wave[0],
                     "compile_s": comp_now[1] - comp_wave[1],
-                    "exchange_share": None,
                     "hbm_frac": (
                         round(hbm_frac, 4) if hbm_frac is not None else None
                     ),
@@ -1532,16 +1300,6 @@ class DeviceBFS:
                     tel.coverage(self._coverage_fields(
                         depth, cov_h, scount, depth_counts,
                     ))
-                    if tl_sample:
-                        tel.event(
-                            "timeline",
-                            wave=depth, depth=depth, every=tl_every,
-                            stages={
-                                k: round(v, 5)
-                                for k, v in stage_s.items() if v > 0
-                            },
-                            wave_s=round(wave_s_val, 4),
-                        )
                 if metrics is not None:
                     metrics.append(wm)
                 if verbose:
@@ -1565,11 +1323,6 @@ class DeviceBFS:
         self._jparent = jparent
         self._jcand = jcand
         self._jcount = int(np.asarray(jax.device_get(stats))[1])
-        # keep the run-final memo resident: the donated input buffers are
-        # dead, but the last wave's OUTPUT table is live — the profiler
-        # times the memoized canon against this realistically-warmed
-        # table (checker/profile.py)
-        self._memo.table = memo
 
         # canon-memo fill ratio: ONE device reduction, at run end only
         # (mid-run it would add a per-wave sync), and computed whether or
@@ -1592,23 +1345,6 @@ class DeviceBFS:
             cf = self._coverage_fields(depth, cov_h, scount, depth_counts)
             cf["canon_memo_fill"] = memo_fill
             tel.coverage(cf, final=True)
-        # timeline overhead estimate: mean sampled vs mean fused wave
-        # seconds (null until both kinds of wave have run) — the
-        # "--timeline=N costs < 5% end-to-end" contract is checked from
-        # this summary field
-        tl_extras = {}
-        if tl_every:
-            overhead = None
-            if tl_wave_s and fused_wave_s:
-                mf = sum(fused_wave_s) / len(fused_wave_s)
-                mt = sum(tl_wave_s) / len(tl_wave_s)
-                if mf > 0:
-                    overhead = round((mt - mf) / (mf * tl_every), 4)
-            tl_extras = {
-                "timeline_every": tl_every,
-                "timeline_waves": tl_waves,
-                "timeline_overhead": overhead,
-            }
         run_stats = {
             **COMPILES.run_stats(comp_run), "dedup_plan": self._dedup_plan(),
             "canon_tier3_local": int(canon_prev[1]),
@@ -1632,7 +1368,6 @@ class DeviceBFS:
             "canon_memo_hit_rate": round(
                 int(canon_prev[0]) / max(1, gen_prev), 4),
             **run_stats,
-            **tl_extras,
             **(memwatch.summary_fields() if memwatch is not None else {}),
         })
         trace = self.reconstruct_trace(violation) if violation else None

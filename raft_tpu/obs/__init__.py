@@ -1,7 +1,6 @@
 """raft_tpu.obs — run-time telemetry for the BFS engines.
 
-Live counterpart of the offline stage profiler (checker/profile.py):
-per-wave JSONL metrics (events.py), a TLC-style progress line
+Per-wave JSONL metrics (events.py), a TLC-style progress line
 (progress.py), the tracing spine — device scopes, host spans, the
 profiler session (trace.py) — and compile counters (compiles.py), the
 collector/facade threading them through the engines (collector.py),
@@ -31,10 +30,8 @@ from .events import (
     PREEMPT_KEYS,
     RESUME_KEYS,
     RETRY_KEYS,
-    SHARD_WAVE_KEYS,
     STALL_KEYS,
     SUMMARY_KEYS,
-    TIMELINE_KEYS,
     TIMELINE_STAGES,
     WAVE_KEYS,
     hashv_of,
@@ -57,10 +54,8 @@ __all__ = [
     "PREEMPT_KEYS",
     "RESUME_KEYS",
     "RETRY_KEYS",
-    "SHARD_WAVE_KEYS",
     "STALL_KEYS",
     "SUMMARY_KEYS",
-    "TIMELINE_KEYS",
     "TIMELINE_STAGES",
     "WAVE_KEYS",
     "COMPILES",

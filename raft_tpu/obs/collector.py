@@ -214,13 +214,7 @@ class Telemetry:
         trace_dir: str | None = None,
         stall_factor: float = 4.0,
         keep_events: bool = True,
-        timeline_every: int = 0,
     ):
-        # timeline_every > 0 asks the engines to run every Nth wave as
-        # separately timed stage dispatches (`timeline` events); 0 = off
-        # and every wave keeps the fused program. See obs/events.py
-        # TIMELINE_STAGES and the engines' _run_timeline_wave.
-        self.timeline_every = int(timeline_every)
         self.collector = MetricsCollector(
             path=metrics_path, every=every, stall_factor=stall_factor,
             keep=keep_events,
@@ -302,10 +296,6 @@ class JobTaggedTelemetry:
     def active(self) -> bool:
         return self._inner.active
 
-    @property
-    def timeline_every(self) -> int:
-        return getattr(self._inner, "timeline_every", 0)
-
     def open_run(self, manifest: dict) -> None:
         self._inner.open_run({**manifest, "job": self.job})
 
@@ -346,7 +336,6 @@ class _NullTelemetry:
     active = False
     events = ()
     last_summary = None
-    timeline_every = 0
 
     def open_run(self, manifest: dict) -> None:
         pass

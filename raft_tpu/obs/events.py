@@ -1,10 +1,9 @@
-"""Event schema of the live telemetry stream (the run-time counterpart
-of checker/profile.py's DECLARED_STAGES).
+"""Event schema of the live telemetry stream.
 
 A run emits one JSON object per line (JSONL), in order:
 
-  manifest   once per run(), before the first wave: everything a BENCH /
-             PROFILE artifact needs to cite its provenance — engine,
+  manifest   once per run(), before the first wave: everything a record
+             of the run needs to cite its provenance — engine,
              fingerprint-formula identity (the checkpoint ident string),
              capacities, memo geometry, device/mesh topology.
   wave       one per BFS wave (at the collector's cadence): depth,
@@ -30,16 +29,11 @@ documented at their key tuples below; they interleave with the above
 (retry between attempts, resume/ckpt_generation right after a resumed
 run's manifest, preempt just before a "preempted" summary).
 
-The wave-timeline observatory adds three more: ``timeline`` (stage
-seconds of a sampled ``--timeline[=EVERY_N]`` wave, names drawn from
-``TIMELINE_STAGES``), ``memwatch`` (analytic HBM live-bytes watermarks
-from obs/memwatch.py, peak monotone within a run), and ``shard_wave``
-(per-shard critical-path rows of a sampled sharded wave: exchange vs
-compute seconds, emigrant lanes/bytes, work share). All three come
-before their run's summary.
+``memwatch`` carries the analytic HBM live-bytes watermarks of
+obs/memwatch.py (peak monotone within a run; before its run's summary).
 
-``DECLARED_EVENTS`` mirrors ``DECLARED_STAGES``: the tier-1 smoke test
-pins it, so the schema cannot silently rot when an engine's stats
+``DECLARED_EVENTS`` is pinned by the tier-1 smoke test,
+so the schema cannot silently rot when an engine's stats
 plumbing changes. Engines may add EXTRA keys (e.g. the sharded checker's
 all-to-all volume and per-shard skew); every DECLARED key must be
 present. This module is dependency-free (no jax/numpy) so schema
@@ -69,12 +63,9 @@ MANIFEST_KEYS = (
     "invariants", "action_names", "when",
 )
 
-# Stage names the wave-timeline observatory attributes seconds to.
-# Shared by all three engines; an engine reports the subset it can
-# split (e.g. "exchange" only exists on the sharded mesh, "dedup" folds
-# into "emit" where the fused program cannot separate them). The offline
-# counterpart is checker/profile.py DECLARED_STAGES — these are coarser
-# because they time real dispatches of a real run, not isolated re-runs.
+# Stage names: the first six are the device scopes ``obs.stage`` accepts
+# (obs/trace.py; "exchange" only on the sharded mesh), the last two host
+# time. The benchmark's scope_time reader is held to this tuple.
 TIMELINE_STAGES = (
     "expand",      # guard pass + budgeted sparse apply (or dense expand)
     "canon",       # canonical fingerprints (memoized symmetry reduction)
@@ -99,7 +90,7 @@ TIMELINE_STAGES = (
 # 0 on surviving waves; host engine: extra fixed-size apply blocks run
 # beyond one per chunk — it loops instead of aborting). Both derive
 # from counters the wave already fetched: zero extra device syncs.
-# device_s/host_s/ckpt_s/tel_s (wave-timeline observatory): the
+# device_s/host_s/ckpt_s/tel_s: the
 # host-side phase split of the wave's wall clock. device_s is the
 # HOST'S WAIT on the device, never device time (a profile has that):
 # the seconds its thread spent in the wave's dispatch, in the one
@@ -122,10 +113,8 @@ TIMELINE_STAGES = (
 # so together they never exceed generated - canon_memo_hits. 0 on the
 # host engines, which have no tiered canon. From the stats vector the
 # wave already fetched: zero extra device syncs.
-# exchange_share: sharded engine only, fraction of the sampled wave's
-# device seconds spent in the all-to-all (null on other engines and on
-# unsampled waves). hbm_frac: analytic live-bytes / budget from
-# obs/memwatch.py (null when memwatch is off).
+# hbm_frac: analytic live-bytes / budget from obs/memwatch.py (null when
+# memwatch is off).
 WAVE_KEYS = (
     "event", "wave", "depth", "frontier", "new", "distinct",
     "generated", "generated_total", "terminal", "dedup_hit_rate",
@@ -135,7 +124,7 @@ WAVE_KEYS = (
     "emit_rows", "emit_bytes", "frontier_fill",
     "enabled_density", "expand_budget_ovf",
     "device_s", "host_s", "ckpt_s", "tel_s",
-    "exchange_share", "hbm_frac",
+    "hbm_frac",
 )
 
 STALL_KEYS = (
@@ -225,15 +214,7 @@ SHARD_STALL_KEYS = (
     "event", "wave", "depth", "shard", "wave_s", "median_wave_s", "factor",
 )
 
-# wave-timeline observatory events (obs/memwatch.py + the engines'
-# sampled `--timeline[=EVERY_N]` mode):
-#   timeline    one per SAMPLED wave: the wave re-run as separately
-#               timed stage dispatches (block_until_ready between
-#               stages), bit-identical to the fused program by
-#               construction (integer-only wave math; parity-gated by
-#               tests). ``stages`` maps a TIMELINE_STAGES name to
-#               seconds; ``every`` is the sampling stride; ``wave_s``
-#               the sampled wave's total wall clock.
+# device-memory watermark (obs/memwatch.py):
 #   memwatch    analytic HBM live-bytes watermark, emitted when a wave
 #               sets a new peak (so the stream stays low-volume and
 #               peak_bytes is monotone within a run by construction).
@@ -241,25 +222,9 @@ SHARD_STALL_KEYS = (
 #               seen / journal / memo / ...) to live bytes; ``frac`` =
 #               total_bytes / budget_bytes (may exceed 1.0 — that is
 #               the out-of-core planning signal).
-#   shard_wave  per-shard critical-path row of a SAMPLED sharded wave:
-#               owner-side new states, routed (emigrant) lanes/bytes,
-#               this shard's share of the wave's work, and its
-#               estimated busy seconds (lockstep SPMD means wall time
-#               is shared; shard_s = compute_s * work_share * D is the
-#               analytic attribution, from which skew = max - median).
-TIMELINE_KEYS = (
-    "event", "wave", "depth", "every", "stages", "wave_s",
-)
-
 MEMWATCH_KEYS = (
     "event", "wave", "depth", "total_bytes", "peak_bytes",
     "budget_bytes", "frac", "breakdown",
-)
-
-SHARD_WAVE_KEYS = (
-    "event", "wave", "depth", "shard", "device_count", "new",
-    "routed_lanes", "routed_bytes", "work_share", "shard_s",
-    "exchange_s", "compute_s",
 )
 
 DECLARED_EVENTS = (
@@ -275,9 +240,7 @@ DECLARED_EVENTS = (
     ("shard_lost", SHARD_LOST_KEYS),
     ("reshard", RESHARD_KEYS),
     ("shard_stall", SHARD_STALL_KEYS),
-    ("timeline", TIMELINE_KEYS),
     ("memwatch", MEMWATCH_KEYS),
-    ("shard_wave", SHARD_WAVE_KEYS),
 )
 
 EVENT_KEYS = dict(DECLARED_EVENTS)
@@ -365,15 +328,6 @@ def validate_event(ev: object, lineno: int | None = None) -> list[str]:
                     f"{where}wave {key} {v!r} must be a non-negative "
                     f"number (seconds)"
                 )
-        share = ev.get("exchange_share")
-        if share is not None and (
-            isinstance(share, bool) or not isinstance(share, (int, float))
-            or not 0.0 <= share <= 1.0
-        ):
-            problems.append(
-                f"{where}wave exchange_share {share!r} must be null or a "
-                f"number in [0, 1]"
-            )
         frac = ev.get("hbm_frac")
         if frac is not None and (
             isinstance(frac, bool) or not isinstance(frac, (int, float))
@@ -382,37 +336,6 @@ def validate_event(ev: object, lineno: int | None = None) -> list[str]:
             problems.append(
                 f"{where}wave hbm_frac {frac!r} must be null or a "
                 f"non-negative number"
-            )
-    if etype == "timeline":
-        stages = ev.get("stages")
-        if not isinstance(stages, dict):
-            problems.append(
-                f"{where}timeline stages must be a dict of stage -> "
-                f"seconds, got {type(stages).__name__}"
-            )
-        else:
-            unknown = [s for s in stages if s not in TIMELINE_STAGES]
-            if unknown:
-                problems.append(
-                    f"{where}timeline stage names {unknown} not in the "
-                    f"declared stage set {TIMELINE_STAGES}"
-                )
-            bad = [
-                s for s, v in stages.items()
-                if isinstance(v, bool) or not isinstance(v, (int, float))
-                or v < 0
-            ]
-            if bad:
-                problems.append(
-                    f"{where}timeline stage seconds must be non-negative "
-                    f"numbers (bad: {bad})"
-                )
-        every = ev.get("every")
-        if isinstance(every, bool) or not isinstance(every, int) \
-                or every < 1:
-            problems.append(
-                f"{where}timeline every {every!r} must be an int >= 1 "
-                f"(the sampling stride)"
             )
     if etype == "memwatch":
         for key in ("total_bytes", "peak_bytes", "budget_bytes"):
@@ -440,35 +363,6 @@ def validate_event(ev: object, lineno: int | None = None) -> list[str]:
                 f"{where}memwatch breakdown must map buffer family "
                 f"names to non-negative int bytes"
             )
-    if etype == "shard_wave":
-        shard = ev.get("shard")
-        if isinstance(shard, bool) or not isinstance(shard, int) \
-                or shard < 0:
-            problems.append(
-                f"{where}shard_wave shard {shard!r} must be an int >= 0"
-            )
-        dc = ev.get("device_count")
-        if isinstance(dc, bool) or not isinstance(dc, int) or dc < 1:
-            problems.append(
-                f"{where}shard_wave device_count {dc!r} must be an "
-                f"int >= 1"
-            )
-        elif isinstance(shard, int) and not isinstance(shard, bool) \
-                and not 0 <= shard < dc:
-            problems.append(
-                f"{where}shard_wave shard {shard} out of range for "
-                f"device_count {dc}"
-            )
-        for key in ("shard_s", "exchange_s", "compute_s", "work_share"):
-            v = ev.get(key)
-            if v is not None and (
-                isinstance(v, bool) or not isinstance(v, (int, float))
-                or v < 0
-            ):
-                problems.append(
-                    f"{where}shard_wave {key} {v!r} must be a "
-                    f"non-negative number"
-                )
     if etype == "summary" and ev.get("exit_cause") not in EXIT_CAUSES:
         problems.append(
             f"{where}summary exit_cause {ev.get('exit_cause')!r} not in "
@@ -581,10 +475,9 @@ def validate_lines(lines) -> tuple[dict, list[str]]:
     manifest resets the expectation), and every job manifest must be
     matched by exactly one summary carrying the same job tag.
 
-    Wave-timeline observatory rules: ``timeline`` / ``memwatch`` /
-    ``shard_wave`` events must come before their run's summary, and
-    ``memwatch`` peak_bytes must be monotone non-decreasing within a
-    run (a new manifest resets the watermark).
+    ``memwatch`` events must come before their run's summary, and their
+    peak_bytes must be monotone non-decreasing within a run (a new
+    manifest resets the watermark).
     """
     counts: dict[str, int] = {}
     problems: list[str] = []
@@ -693,23 +586,22 @@ def validate_lines(lines) -> tuple[dict, list[str]]:
                     f"line {lineno}: {etype} wave index {w} behind the "
                     f"run's last completed wave {last_wave}"
                 )
-        elif etype in ("timeline", "memwatch", "shard_wave"):
+        elif etype == "memwatch":
             if summarized:
                 problems.append(
-                    f"line {lineno}: {etype} event after the run's summary"
+                    f"line {lineno}: memwatch event after the run's summary"
                 )
-            if etype == "memwatch":
-                peak = ev.get("peak_bytes")
-                if isinstance(peak, int) and not isinstance(peak, bool):
-                    if peak < last_memwatch_peak:
-                        problems.append(
-                            f"line {lineno}: memwatch peak_bytes {peak} "
-                            f"regressed below the run's watermark "
-                            f"{last_memwatch_peak} (peaks are monotone "
-                            f"within a run)"
-                        )
-                    else:
-                        last_memwatch_peak = peak
+            peak = ev.get("peak_bytes")
+            if isinstance(peak, int) and not isinstance(peak, bool):
+                if peak < last_memwatch_peak:
+                    problems.append(
+                        f"line {lineno}: memwatch peak_bytes {peak} "
+                        f"regressed below the run's watermark "
+                        f"{last_memwatch_peak} (peaks are monotone "
+                        f"within a run)"
+                    )
+                else:
+                    last_memwatch_peak = peak
         elif etype == "retry":
             att = ev.get("attempt")
             if isinstance(att, int) and not isinstance(att, bool):

@@ -38,7 +38,7 @@ class ProgressRenderer:
     # cannot drift apart
     CONSUMES = (
         "depth", "generated_total", "distinct", "distinct_per_s",
-        "canon_memo_hit_rate", "exchange_share", "hbm_frac",
+        "canon_memo_hit_rate", "hbm_frac",
         "generated", "canon_tier3_local", "canon_tier3_full",
     )
 
@@ -62,8 +62,6 @@ class ProgressRenderer:
             ev.get("canon_tier3_full") or 0)
         if tier3:
             line += f", tier3 {tier3 / max(1, ev['generated']):.0%}"
-        if ev.get("exchange_share"):
-            line += f", a2a {ev['exchange_share']:.0%}"
         if ev.get("hbm_frac"):
             line += f", hbm {ev['hbm_frac']:.0%}"
         return line

@@ -13,8 +13,8 @@ same spans into whatever session is open — ``--trace-dir``
                  pipeline, a member of ``events.TIMELINE_STAGES``
                  (expand, canon, dedup, emit, seen_merge, exchange).
                  Decorates the engines' stage methods, so the fused wave
-                 program, the chunk program and the ``--timeline`` stage
-                 programs all carry it: an op of the trace then reads
+                 program and the sharded chunk program carry it: an op
+                 of the trace then reads
                  ``jit(_wave_step)/.../canon/...`` where it read
                  ``fusion.1459``.
   span(name)     host side: one phase of the wave loop.

@@ -140,14 +140,7 @@ class ShardedBFS:
     # scalars-per-shard, and occ plus the LSM runs are reused across
     # chunks — none of those donate.
     #   chunk: next_buf, jps, jpl, jcand, jfp, viol, stats, memo, cov
-    CHUNK_DONATE = (2, 3, 4, 5, 6, 7, 8, 9, 10)
-    # timeline stages (--timeline sampled waves): memo through pre, the
-    # routed payloads through exchange, the state carries through post
-    TL_DONATE = {
-        "pre": (2,),
-        "exchange": (0, 1),
-        "post": (2, 3, 4, 5, 6, 7, 8, 9),
-    }
+    STEP_DONATE = (2, 3, 4, 5, 6, 7, 8, 9, 10)
 
     def __init__(
         self,
@@ -244,11 +237,6 @@ class ShardedBFS:
         self.MCAP = self._memo.MCAP
 
         self._chunk_fn_cache: dict[int, object] = {}
-        # wave-timeline observatory: separately dispatched pre / exchange
-        # / post programs for sampled waves (--timeline); the carries
-        # donate exactly as in the fused chunk program.
-        self._tl_pre_ex: tuple | None = None
-        self._tl_post_cache: dict[int, object] = {}
         self._occ_cache: dict[bytes, object] = {}
         self._journals = None  # (jps, jpl, jcand) per shard after run()
         self._init_by_shard = None
@@ -306,74 +294,10 @@ class ShardedBFS:
                     in_specs=(spec,) * 11 + (P(), P(), spec) + (spec,) * n_runs,
                     out_specs=(spec,) * 10,
                 ),
-                donate_argnums=self.CHUNK_DONATE,
+                donate_argnums=self.STEP_DONATE,
             )
             self._chunk_fn_cache[n_runs] = fn
         return fn
-
-    def _get_timeline_fns(self, n_runs: int):
-        """The sampled-wave (--timeline) programs: the SAME stage bodies
-        as the fused chunk program, dispatched as three shard_maps —
-        pre (expand..route), exchange (the all-to-all pair), post
-        (dedup..stats) — so the host can block_until_ready between them
-        and attribute real seconds per stage. The loop-carried buffers
-        donate exactly as in the fused program (memo in pre; the nine
-        state carries in post; the routed payloads through exchange):
-        without donation every sampled chunk copies the capacity-shaped
-        frontier/journal buffers through the stage outputs, which
-        dominates the sampled wave on big geometries. The wave loop
-        rebinds every donated carry from the stage returns. The cached
-        occ array and the LSM runs stay undonated (reused across
-        chunks), as does the frontier (read-only within a wave)."""
-        spec = P(AXIS)
-        if self._tl_pre_ex is None:
-            def pre_step(frontier, fcount, memo, cursor, base_lgid):
-                sp, sf, memo2, cg, ps = self._cs_pre(
-                    frontier[0], fcount[0, 0], memo[0], cursor,
-                    base_lgid[0, 0],
-                )
-                return sp[None], sf[None], memo2[None], cg[None], ps[None]
-
-            @stage("exchange")
-            def ex_step(send_pay, send_fps):
-                rp = lax.all_to_all(send_pay[0], AXIS, 0, 0, tiled=True)
-                rf = lax.all_to_all(send_fps[0], AXIS, 0, 0, tiled=True)
-                return rp[None], rf[None]
-
-            self._tl_pre_ex = (
-                jax.jit(self._shard_map(
-                    pre_step,
-                    in_specs=(spec, spec, spec, P(), spec),
-                    out_specs=(spec,) * 5,
-                ), donate_argnums=self.TL_DONATE["pre"]),
-                jax.jit(self._shard_map(
-                    ex_step,
-                    in_specs=(spec, spec), out_specs=(spec, spec),
-                ), donate_argnums=self.TL_DONATE["exchange"]),
-            )
-        post_fn = self._tl_post_cache.get(n_runs)
-        if post_fn is None:
-            def post_step(
-                recv_pay, recv_fps, next_buf, jps, jpl, jcand, jfp,
-                viol, stats, cov, cov_gen, pre_stats, occ, *runs,
-            ):
-                out = self._cs_post(
-                    recv_pay[0], recv_fps[0], next_buf[0], jps[0],
-                    jpl[0], jcand[0], jfp[0], viol[0], stats[0], cov[0],
-                    cov_gen[0], pre_stats[0], occ, [r[0] for r in runs],
-                )
-                return tuple(x[None] for x in out)
-
-            # donated: next_buf, jps, jpl, jcand, jfp, viol, stats, cov
-            # (recv_pay/recv_fps can't alias the outputs; occ and the
-            # LSM runs are reused across chunks)
-            post_fn = jax.jit(self._shard_map(
-                post_step,
-                in_specs=(spec,) * 12 + (P(),) + (spec,) * n_runs,
-                out_specs=(spec,) * 9,
-            ), donate_argnums=self.TL_DONATE["post"])
-            self._tl_post_cache[n_runs] = post_fn
-        return self._tl_pre_ex[0], self._tl_pre_ex[1], post_fn
 
     # ---------------- static audit surface ----------------
 
@@ -387,7 +311,7 @@ class ShardedBFS:
         compares against the lowered aliasing, ``site`` a (file, line)
         anchor, ``per_wave`` the dispatch count per wave. Nothing is
         lowered or executed here; the ``carries`` maps are deliberately
-        written out separately from ``CHUNK_DONATE``/``TL_DONATE`` so a
+        written out separately from ``STEP_DONATE`` so a
         dropped donate argnum diverges the two."""
         import inspect as _inspect
 
@@ -428,36 +352,6 @@ class ShardedBFS:
             "site": site(self._chunk_step), "per_wave": 1,
         }
 
-        # --timeline stage programs: chain abstract shapes through the
-        # jitted stages with eval_shape (free — no lowering happens
-        # until the auditor lowers an entry it chose to audit)
-        pre_fn, ex_fn, post_fn = self._get_timeline_fns(n_runs)
-        pre_out = jax.eval_shape(pre_fn, frontier, fc, memo, i32s, bl)
-        send_pay, send_fps, _memo2, cov_gen, pre_stats = pre_out
-        ex_out = jax.eval_shape(ex_fn, send_pay, send_fps)
-        recv_pay, recv_fps = ex_out
-        yield {
-            "name": "tl:pre", "fn": pre_fn,
-            "args": (frontier, fc, memo, i32s, bl),
-            "carries": {2: "memo"}, "pinned": {0: "frontier"},
-            "site": site(self._cs_pre), "per_wave": 1,
-        }
-        yield {
-            "name": "tl:exchange", "fn": ex_fn,
-            "args": (send_pay, send_fps),
-            "carries": {0: "send_pay", 1: "send_fps"}, "pinned": {},
-            "site": site(self._get_timeline_fns), "per_wave": 1,
-        }
-        yield {
-            "name": "tl:post", "fn": post_fn,
-            "args": (recv_pay, recv_fps, next_buf, jps, jpl, jcand, jfp,
-                     viol, stats, cov, cov_gen, pre_stats, occ, *runs),
-            "carries": {2: "next_buf", 3: "jps", 4: "jpl", 5: "jcand",
-                        6: "jfp", 7: "viol", 8: "stats", 9: "cov"},
-            "pinned": {},
-            "site": site(self._cs_post), "per_wave": 1,
-        }
-
     def _chunk_step(
         self, frontier, fcount, next_buf, jps, jpl, jcand, jfp, viol, stats,
         memo, cov, cursor, occ, base_lgid, *runs,
@@ -489,10 +383,6 @@ class ShardedBFS:
         memo = memo[0]
         cov = cov[0]
         runs = [r[0] for r in runs]
-        # composed from the same stage bodies the sampled --timeline
-        # waves dispatch separately (integer-only wave math, so the
-        # fused and staged programs are bit-identical — parity-gated by
-        # tests/test_obs.py)
         send_pay, send_fps, memo, cov_gen, pre_stats = self._cs_pre(
             frontier, fcount, memo, cursor, base_lgid
         )
@@ -1449,17 +1339,11 @@ class ShardedBFS:
         tiers_prev = np.zeros((2,), np.int64)
         per_shard_memo = np.zeros(D, np.int64)
         wave_times: list[float] = []  # stall-watchdog rolling window
-        # wave-timeline observatory (obs/): sampled waves dispatch the
-        # pre/exchange/post programs separately (bit-identical math);
         # every wave gets the phase split + analytic HBM watermark
-        tl_every = int(getattr(tel, "timeline_every", 0) or 0)
-        tl_wave_s: list[float] = []
-        fused_wave_s: list[float] = []
         memwatch = (
             MemWatch(tel, device_budget(self.mesh.devices.flat[0]))
             if tel.active else None
         )
-        routed_prev_d = np.zeros(D, np.int64)  # per-shard a2a cums
 
         while fcounts.sum() and violation is None:
             if preempt is not None and preempt.requested:
@@ -1509,72 +1393,26 @@ class ShardedBFS:
                 base_lgid.astype(np.int32).reshape(D, 1), self._sharding)
             max_fc = int(fcounts.max())
             chunks_done = 0
-            tl_sample = tl_every > 0 and (depth + 1) % tl_every == 0
-            stage_s = {
-                "expand": 0.0, "exchange": 0.0, "emit": 0.0,
-                "seen_merge": 0.0, "checkpoint": 0.0,
-            }
             with tel.wave_annotation(depth + 1):
                 for cursor in range(0, max_fc, C):
                     occ_dev = self._occ_dev()
-                    if tl_sample:
-                        with ph("dispatch"):
-                            pre_fn, ex_fn, post_fn = self._get_timeline_fns(
-                                len(self._lsm.runs))
-                            t1 = time.perf_counter()
-                            (send_pay, send_fps, state["memo"], cov_gen,
-                             pre_stats) = pre_fn(
-                                state["frontier"], fc_dev, state["memo"],
-                                np.int32(cursor), bl_dev,
-                            )
-                            # lint: sync-ok(stage attribution on a sampled wave)
-                            jax.block_until_ready(
-                                (send_pay, send_fps, state["memo"], cov_gen,
-                                 pre_stats))
-                            t2 = time.perf_counter()
-                            stage_s["expand"] += t2 - t1
-                            recv_pay, recv_fps = ex_fn(send_pay, send_fps)
-                            # lint: sync-ok(stage attribution on a sampled wave)
-                            jax.block_until_ready((recv_pay, recv_fps))
-                            t3 = time.perf_counter()
-                            stage_s["exchange"] += t3 - t2
-                            (state["next_buf"], state["jps"], state["jpl"],
-                             state["jcand"], state["jfp"], state["viol"],
-                             state["stats"], state["cov"], new_run,
-                             ) = post_fn(
-                                recv_pay, recv_fps, state["next_buf"],
-                                state["jps"], state["jpl"], state["jcand"],
-                                state["jfp"], state["viol"], state["stats"],
-                                state["cov"], cov_gen, pre_stats, occ_dev,
-                                *self._lsm.runs,
-                            )
-                            # lint: sync-ok(stage attribution on a sampled wave)
-                            jax.block_until_ready(new_run)
-                            t4 = time.perf_counter()
-                            stage_s["emit"] += t4 - t3
-                        with ph("seen_merge"):
-                            self._lsm.insert(new_run)
-                            # lint: sync-ok(stage attribution on a sampled wave)
-                            jax.block_until_ready(self._lsm.runs)
-                        stage_s["seen_merge"] += time.perf_counter() - t4
-                    else:
-                        with ph("dispatch"):
-                            chunk_fn = self._get_chunk_fn(len(self._lsm.runs))
-                            (state["next_buf"], state["jps"], state["jpl"],
-                             state["jcand"], state["jfp"], state["viol"],
-                             state["stats"], state["memo"], state["cov"],
-                             new_run,
-                             ) = chunk_fn(
-                                state["frontier"], fc_dev,
-                                state["next_buf"], state["jps"],
-                                state["jpl"], state["jcand"], state["jfp"],
-                                state["viol"], state["stats"],
-                                state["memo"], state["cov"],
-                                np.int32(cursor), occ_dev, bl_dev,
-                                *self._lsm.runs,
-                            )
-                        with ph("seen_merge"):
-                            self._lsm.insert(new_run)
+                    with ph("dispatch"):
+                        chunk_fn = self._get_chunk_fn(len(self._lsm.runs))
+                        (state["next_buf"], state["jps"], state["jpl"],
+                         state["jcand"], state["jfp"], state["viol"],
+                         state["stats"], state["memo"], state["cov"],
+                         new_run,
+                         ) = chunk_fn(
+                            state["frontier"], fc_dev,
+                            state["next_buf"], state["jps"],
+                            state["jpl"], state["jcand"], state["jfp"],
+                            state["viol"], state["stats"],
+                            state["memo"], state["cov"],
+                            np.int32(cursor), occ_dev, bl_dev,
+                            *self._lsm.runs,
+                        )
+                    with ph("seen_merge"):
+                        self._lsm.insert(new_run)
                     chunks_done += 1
                     if chaos is not None:
                         lost = chaos.shard_loss(depth + 1, D)
@@ -1686,8 +1524,6 @@ class ShardedBFS:
             terminal = int(stats_h[:, 3].sum())
             wave_routed = int(stats_h[:, 5].sum()) - routed_prev
             routed_prev = int(stats_h[:, 5].sum())
-            wave_routed_d = stats_h[:, 5] - routed_prev_d
-            routed_prev_d = stats_h[:, 5].copy()
             memo_hits = int(stats_h[:, 6].sum())
             wave_memo = memo_hits - memo_prev
             memo_prev = memo_hits
@@ -1745,7 +1581,6 @@ class ShardedBFS:
                         cov_hd,
                     )
                     last_ckpt = time.perf_counter()
-                    stage_s["checkpoint"] += ph.s["checkpoint"]
             wave_s_val = time.perf_counter() - tw
             # the wave's brackets, read once (DeviceBFS.run): device_s is
             # the host's wait on the device — every chunk's dispatch and
@@ -1759,8 +1594,6 @@ class ShardedBFS:
             device_s = dispatch_s + fetch_s + merge_s
             ckpt_s = ph_s.get("checkpoint", 0.0)
             comp_now = COMPILES.snapshot()
-            if tl_every:
-                (tl_wave_s if tl_sample else fused_wave_s).append(wave_s_val)
             if not (tel.active or metrics is not None or verbose):
                 continue
             with ph("telemetry"):
@@ -1780,10 +1613,6 @@ class ShardedBFS:
                         "memo": self.MCAP * 16 if self._use_memo else 0,
                     })
                     hbm_frac = round(frac, 6)
-                tl_dev = (
-                    stage_s["expand"] + stage_s["exchange"]
-                    + stage_s["emit"]
-                )
                 wm = {
                     "depth": depth,
                     "frontier": int(prev_fcounts.sum()),
@@ -1818,12 +1647,6 @@ class ShardedBFS:
                     # new LSM level count names its wave
                     "compiles": comp_now[0] - comp_wave[0],
                     "compile_s": comp_now[1] - comp_wave[1],
-                    # exchange share of the sampled wave's staged device
-                    # seconds; null on fused (unsampled) waves — the
-                    # fused program cannot separate the all-to-all
-                    "exchange_share": round(
-                        stage_s["exchange"] / tl_dev, 4)
-                    if tl_sample and tl_dev > 0 else None,
                     "hbm_frac": hbm_frac,
                     "a2a_lanes": wave_routed,
                     # payload widened to W+3 by the routed rank column
@@ -1855,35 +1678,6 @@ class ShardedBFS:
                 if tel.active:
                     tel.coverage(self._coverage_fields(
                         depth, cov_hd, scounts, depth_counts))
-                    if tl_sample:
-                        tel.event(
-                            "timeline", wave=depth, depth=depth,
-                            every=tl_every,
-                            stages={
-                                k: round(v, 5)
-                                for k, v in stage_s.items() if v > 0
-                            },
-                            wave_s=round(wave_s_val, 4),
-                        )
-                        # per-shard critical-path rows: lockstep SPMD
-                        # shares the wall clock, so shard_s is the
-                        # analytic attribution compute_s*work_share*D
-                        # (skew = max - median over shards)
-                        comp_s = stage_s["expand"] + stage_s["emit"]
-                        for d in range(D):
-                            ws = int(new_d[d]) / max(1, global_new)
-                            tel.event(
-                                "shard_wave", wave=depth, depth=depth,
-                                shard=d, device_count=D,
-                                new=int(new_d[d]),
-                                routed_lanes=int(wave_routed_d[d]),
-                                routed_bytes=int(wave_routed_d[d])
-                                * (4 * (W + 3) + 8),
-                                work_share=round(ws, 4),
-                                shard_s=round(comp_s * ws * D, 5),
-                                exchange_s=round(stage_s["exchange"], 5),
-                                compute_s=round(comp_s, 5),
-                            )
                 if metrics is not None:
                     metrics.append(wm)
                 if verbose:
@@ -1950,21 +1744,6 @@ class ShardedBFS:
             cf = self._coverage_fields(depth, cov_hd, scounts, depth_counts)
             cf["canon_memo_fill"] = memo_fill
             tel.coverage(cf, final=True)
-        tl_extras = {}
-        if tl_every:
-            mt = sum(tl_wave_s) / len(tl_wave_s) if tl_wave_s else None
-            mf = (
-                sum(fused_wave_s) / len(fused_wave_s)
-                if fused_wave_s else None
-            )
-            tl_extras = {
-                "timeline_every": tl_every,
-                "timeline_waves": len(tl_wave_s),
-                # per-wave extra cost of the staged dispatches,
-                # amortized over the stride
-                "timeline_overhead": round((mt - mf) / (mf * tl_every), 4)
-                if mt is not None and mf else None,
-            }
         tel.close_run({
             "engine": "sharded",
             "ident": self._ckpt_ident(),
@@ -1985,7 +1764,6 @@ class ShardedBFS:
             "shard_memo_hits": fleet_stats["shard_memo_hits"],
             "shard_skew": fleet_stats["shard_skew"],
             **run_stats,
-            **tl_extras,
             **(memwatch.summary_fields() if memwatch is not None else {}),
         })
         trace = init_trace

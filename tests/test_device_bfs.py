@@ -257,3 +257,17 @@ def test_wave_program_scatter_adds_nothing(make):
         and "expand/vmap()" not in str(e.source_info.name_stack)
     ]
     assert not adds, f"scatter-add in the {name} program under: {adds}"
+
+
+@pytest.mark.parametrize(
+    "make,names",
+    [(_device_wave, ["wave", "seen_merge"]), (_sharded_chunk, ["chunk"])],
+    ids=["device", "sharded"])
+def test_engine_declares_only_what_it_dispatches(make, names):
+    """An engine has one way to run a wave: its audit surface lists the
+    programs ``run()`` dispatches and no other. A second implementation
+    of the wave (a host-driven stage mirror, a per-chunk twin) would have
+    to declare its donations here to pass the lint, and then fails
+    this."""
+    eng, _ = make()
+    assert [p["name"] for p in eng.audit_programs()] == names

@@ -203,7 +203,7 @@ def test_schema_and_renderer_stay_in_sync():
         "manifest", "wave", "stall", "coverage", "summary",
         "retry", "resume", "ckpt_generation", "preempt",
         "shard_lost", "reshard", "shard_stall",
-        "timeline", "memwatch", "shard_wave",
+        "memwatch",
     )
     for _, keys in DECLARED_EVENTS:
         assert keys[0] == "event"
@@ -365,19 +365,29 @@ def test_sharded_stream_and_fleet_stats(tmp_path):
         "canon_memo_hit_rate"
     ]
 
+    # the offline digest: per-shard balance from the rows' shard_new,
+    # the analytic watermarks from the memwatch events
+    from scripts.obs_report import render_run, split_runs
 
-# ------------------------------------------ wave-timeline observatory
+    with open(path) as fh:
+        text = render_run(split_runs(fh)[-1])
+    assert "Shard balance" in text
+    assert "shard skew" in text
+    assert "Memory watermarks" in text
 
 
-def test_timeline_sampled_waves_bit_identical_device(tmp_path):
-    """The tentpole contract on the device engine: --timeline re-runs
-    every Nth wave as separately timed stage dispatches that compute
-    bit-identical counts, and the stream carries the new events."""
+# ------------------------------ phase split and memory watermarks
+
+
+def test_telemetry_run_carries_phase_split_and_watermarks(tmp_path):
+    """A telemetry run of the device engine counts what a bare one
+    counts, every wave row carries the host-side phase split, and the
+    stream and summary carry the analytic memory watermarks."""
     eng = _device()
     bare = eng.run(max_depth=5)
 
     path = tmp_path / "tl.jsonl"
-    with Telemetry(metrics_path=str(path), timeline_every=2) as tel:
+    with Telemetry(metrics_path=str(path)) as tel:
         res = eng.run(max_depth=5, telemetry=tel)
 
     assert res.distinct == bare.distinct
@@ -388,144 +398,27 @@ def test_timeline_sampled_waves_bit_identical_device(tmp_path):
     with open(path) as fh:
         counts, problems = validate_lines(fh)
     assert not problems, problems
-    assert counts["timeline"] >= 2
     assert counts["memwatch"] >= 1
 
-    tls = [e for e in tel.events if e["event"] == "timeline"]
-    for tl in tls:
-        assert tl["every"] == 2
-        assert set(tl["stages"]) <= set(TIMELINE_STAGES)
-        assert sum(tl["stages"].values()) > 0
-        assert tl["wave_s"] >= 0
-
-    # every wave (sampled or not) carries the host-side phase split
     for w in tel.wave_events():
         for k in ("device_s", "host_s", "ckpt_s", "tel_s"):
             assert isinstance(w[k], (int, float)), k
             assert w[k] >= 0, k
 
     s = tel.last_summary
-    assert s["timeline_every"] == 2
-    assert s["timeline_waves"] == len(tls)
     assert s["hbm_peak_bytes"] > 0
     assert 0 < s["hbm_peak_frac"] < 1
-
-
-def _small_kraft():
-    from raft_tpu.models.kraft import KRaftParams
-    from raft_tpu.models.kraft import cached_model as kraft_model
-
-    return kraft_model(KRaftParams(
-        n_servers=3, n_values=1, max_elections=1, max_restarts=0,
-        msg_slots=40,
-    ))
-
-
-@pytest.mark.slow
-@pytest.mark.parametrize("which", ["raft", "kraft"])
-def test_timeline_parity_all_engines(which):
-    """Sampled-wave bit-identity across the full engine matrix (2
-    models x host/device/sharded) — the staged dispatch must never
-    change what gets checked."""
-    import jax
-
-    from raft_tpu.checker.bfs import BFSChecker
-    from raft_tpu.checker.device_bfs import DeviceBFS
-    from raft_tpu.parallel.sharded import ShardedBFS
-
-    if which == "raft":
-        model, invs = cached_model(SMALL), INVS
-    else:
-        model = _small_kraft()
-        invs = ("LeaderHasAllAckedValues", "NoLogDivergence",
-                "NeverTwoLeadersInSameEpoch", "NoIllegalState")
-
-    factories = {
-        "host": lambda: BFSChecker(
-            model, invariants=invs, symmetry=True, chunk=256),
-        "device": lambda: DeviceBFS(
-            model, invariants=invs, symmetry=True, chunk=256,
-            frontier_cap=1 << 12, seen_cap=1 << 15, journal_cap=1 << 15),
-        "sharded": lambda: ShardedBFS(
-            model, invariants=invs, symmetry=True,
-            devices=jax.devices()[:2], chunk=512, frontier_cap=2048,
-            seen_cap=1 << 13),
-    }
-    for name, make in factories.items():
-        bare = make().run(max_depth=5)
-        tel = Telemetry(timeline_every=2)
-        res = make().run(max_depth=5, telemetry=tel)
-        assert res.distinct == bare.distinct, (which, name)
-        assert res.total == bare.total, (which, name)
-        assert res.depth_counts == bare.depth_counts, (which, name)
-        tls = [e for e in tel.events if e["event"] == "timeline"]
-        assert tls, (which, name)
-        assert all(set(t["stages"]) <= set(TIMELINE_STAGES) for t in tls)
-
-
-@pytest.mark.slow
-def test_sharded_timeline_shard_wave_events(tmp_path):
-    """Sharded D=2: sampled waves emit one shard_wave row per shard
-    with work shares in [0,1]; the exchange-share gauge lands on the
-    sampled wave events; obs_report renders the critical-path table."""
-    import jax
-
-    from raft_tpu.parallel.sharded import ShardedBFS
-
-    path = tmp_path / "sw.jsonl"
-    eng = ShardedBFS(
-        cached_model(SMALL), invariants=INVS, symmetry=True,
-        devices=jax.devices()[:2], chunk=512, frontier_cap=1024,
-        seen_cap=1 << 12,
-    )
-    with Telemetry(metrics_path=str(path), timeline_every=2) as tel:
-        eng.run(max_depth=6, telemetry=tel)
-
-    with open(path) as fh:
-        counts, problems = validate_lines(fh)
-    assert not problems, problems
-
-    tls = [e for e in tel.events if e["event"] == "timeline"]
-    sws = [e for e in tel.events if e["event"] == "shard_wave"]
-    assert tls and len(sws) == 2 * len(tls)  # one row per shard per sample
-    by_wave: dict[int, list[dict]] = {}
-    for sw in sws:
-        assert sw["device_count"] == 2
-        assert 0 <= sw["shard"] < 2
-        assert 0.0 <= sw["work_share"] <= 1.0
-        assert sw["routed_lanes"] >= 0 and sw["routed_bytes"] >= 0
-        by_wave.setdefault(sw["wave"], []).append(sw)
-    for wave, rows in by_wave.items():
-        assert sorted(r["shard"] for r in rows) == [0, 1]
-        if sum(r["new"] for r in rows) > 0:
-            assert sum(r["work_share"] for r in rows) == pytest.approx(
-                1.0, abs=0.01), wave
-
-    shares = [
-        w["exchange_share"] for w in tel.wave_events()
-        if w["exchange_share"] is not None
-    ]
-    assert shares and all(0.0 <= s <= 1.0 for s in shares)
-
-    from scripts.obs_report import render_run, split_runs
-
-    with open(path) as fh:
-        text = render_run(split_runs(fh)[-1])
-    assert "Shard critical path" in text
-    assert "shard skew" in text
-    assert "Wave timeline" in text
-    assert "Memory watermarks" in text
 
 
 def test_progress_renderer_observatory_gauges():
     ev = dict.fromkeys(WAVE_KEYS, 0)
     ev.update(event="wave", depth=7, generated_total=100, distinct=50,
               distinct_per_s=10.0, canon_memo_hit_rate=0.5,
-              exchange_share=0.25, hbm_frac=0.5)
+              hbm_frac=0.5)
     line = ProgressRenderer().render_wave(ev)
-    assert line.endswith(", a2a 25%, hbm 50%")
-    # null/zero gauges leave the pinned base line untouched
-    ev.update(exchange_share=None, hbm_frac=0)
+    assert line.endswith("memo 50%, hbm 50%")
+    # a null/zero gauge leaves the pinned base line untouched
+    ev.update(hbm_frac=0)
     assert ProgressRenderer().render_wave(ev).endswith("memo 50%")
     # lanes the canon routed to tier 3, as a share of the wave's lanes
     ev.update(generated=200, canon_tier3_local=30, canon_tier3_full=20)
@@ -550,18 +443,13 @@ def test_wave_tier_counters_schema_rule():
 
 
 def _observatory_stream(tmp_path, name="obs.jsonl"):
-    """One schema-clean stream exercising all three new events."""
+    """One schema-clean stream with two memwatch events."""
     path = tmp_path / name
     c = MetricsCollector(path=str(path))
     c.manifest(_fields(MANIFEST_KEYS, ident="x/hashv=5"))
     c.wave(_wave(0, 0.5))
-    c.event("timeline", wave=1, depth=0, every=2,
-            stages={"expand": 0.1, "emit": 0.05}, wave_s=0.5)
     c.event("memwatch", wave=1, depth=0, total_bytes=100, peak_bytes=100,
             budget_bytes=1000, frac=0.1, breakdown={"frontier": 60, "seen": 40})
-    c.event("shard_wave", wave=1, depth=0, shard=1, device_count=2, new=5,
-            routed_lanes=3, routed_bytes=120, work_share=0.5, shard_s=0.2,
-            exchange_s=0.01, compute_s=0.2)
     c.wave(_wave(1, 0.4))
     c.event("memwatch", wave=2, depth=1, total_bytes=150, peak_bytes=200,
             budget_bytes=1000, frac=0.2, breakdown={"frontier": 150})
@@ -586,18 +474,7 @@ def test_observatory_fixture_positive(tmp_path):
     good = _observatory_stream(tmp_path)
     counts, problems = validate_file(str(good))
     assert not problems, problems
-    assert counts["timeline"] == 1
-    assert counts["memwatch"] == 2
-    assert counts["shard_wave"] == 1
-
-
-def test_observatory_fixture_bad_stage_name(tmp_path):
-    from scripts.check_metrics_schema import validate_file
-
-    good = _observatory_stream(tmp_path)
-    bad = _perturb(good, tmp_path, '"expand"', '"quux"', "bad_stage.jsonl")
-    _, problems = validate_file(str(bad))
-    assert any("stage names" in p and "quux" in p for p in problems), problems
+    assert counts == {"manifest": 1, "wave": 2, "memwatch": 2, "summary": 1}
 
 
 def test_observatory_fixture_nonmonotone_peak(tmp_path):
@@ -612,16 +489,6 @@ def test_observatory_fixture_nonmonotone_peak(tmp_path):
                                            '"total_bytes": 50'))
     _, problems = validate_file(str(bad))
     assert any("monotone" in p for p in problems), problems
-
-
-def test_observatory_fixture_shard_out_of_range(tmp_path):
-    from scripts.check_metrics_schema import validate_file
-
-    good = _observatory_stream(tmp_path)
-    bad = _perturb(good, tmp_path, '"shard": 1', '"shard": 2',
-                   "bad_shard.jsonl")
-    _, problems = validate_file(str(bad))
-    assert any("out of range" in p for p in problems), problems
 
 
 # ------------------------------------------------------------ bench gate
@@ -780,9 +647,9 @@ CFG3 = CFG.replace("    v1 = v1", "    n3 = n3\n    v1 = v1").replace(
     "Server = { n1, n2 }", "Server = { n1, n2, n3 }")
 
 
-def test_cli_timeline_smoke_and_bench_gate(tmp_path, capsys):
-    """Tier-1 smoke of the whole observatory loop: a depth-4 3-server
-    Raft CLI check under --timeline=2 produces a schema-clean stream
+def test_cli_metrics_smoke_and_bench_gate(tmp_path, capsys):
+    """Tier-1 smoke of the whole telemetry loop: a depth-4 3-server
+    Raft CLI check with --metrics-out produces a schema-clean stream
     that PASSES the committed bench_gate baseline, while a 20%-tighter
     baseline fails with the strict-gate exit code 3."""
     from pathlib import Path
@@ -795,22 +662,18 @@ def test_cli_timeline_smoke_and_bench_gate(tmp_path, capsys):
     cfg.write_text(CFG3)
     mpath = tmp_path / "tl.jsonl"
 
-    rc = main([str(cfg), *CLI_BASE, "--timeline=2",
-               "--metrics-out", str(mpath)])
+    rc = main([str(cfg), *CLI_BASE, "--metrics-out", str(mpath)])
     cap = capsys.readouterr()
     assert rc == 0, cap.err
 
     counts, problems = validate_file(str(mpath))
     assert not problems, problems
     assert counts["wave"] == 4
-    assert counts["timeline"] == 2  # waves at depth 1 and 3
     assert counts["memwatch"] >= 1
 
     with open(mpath) as fh:
         summ = json.loads(fh.read().strip().splitlines()[-1])
     assert summ["event"] == "summary"
-    assert summ["timeline_every"] == 2
-    assert summ["timeline_waves"] == 2
     assert summ["hbm_peak_bytes"] > 0
 
     golden = Path(__file__).parent / "golden" / "raft3_depth4_gate.json"
@@ -929,6 +792,38 @@ def _host_spans(trace_dir):
                  dict(e.stats))
                 for e in line.events)
     return sorted(spans, key=lambda s: (s[0], -s[1]))
+
+
+def test_stage_split_reduces_a_recorded_trace(tmp_path, capsys):
+    """What an operator runs after ``python -m raft_tpu CFG --trace-dir
+    DIR``: ``scripts/stage_split.py --trace-dir DIR`` reduces the newest
+    trace under DIR, here one recorded on a v5e, to seconds by stage
+    scope. Every bucket is a device stage of TIMELINE_STAGES (one scope
+    deeper where there is one) or ``unscoped``, and they add up to the
+    seconds the device was busy."""
+    import gzip
+    import pathlib
+
+    from scripts import stage_split
+
+    recorded = (pathlib.Path(__file__).parents[1] / "benchmark" / "testdata"
+                / "scoped_v5e.xplane.pb.gz")
+    run_dir = tmp_path / "plugins" / "profile" / "2026_09_27_00_00_00"
+    run_dir.mkdir(parents=True)
+    (run_dir / "host.xplane.pb").write_bytes(
+        gzip.decompress(recorded.read_bytes()))
+
+    stage_split.main(["--trace-dir", str(tmp_path)])
+
+    table = capsys.readouterr().out
+    res = json.loads(table.strip().splitlines()[-1])
+    buckets = res["by_scope_s"]
+    device_stages = set(TIMELINE_STAGES) - {"checkpoint", "host"}
+    assert {b.split("/")[0] for b in buckets} <= device_stages | {"unscoped"}
+    assert {"expand", "canon", "dedup", "emit", "seen_merge"} <= set(buckets)
+    assert res["busy_s"] > 0
+    assert sum(buckets.values()) == pytest.approx(res["busy_s"], rel=1e-9)
+    assert all(f"\n{b} " in "\n" + table for b in [*buckets, "busy"])
 
 
 @pytest.mark.parametrize("facade", ["null", "wave_clock"])
