@@ -178,11 +178,19 @@ def _psum_last(p):
     return _reduce_pair(p[0], p[1], op="sum")
 
 
-def _pgather(p, idx):
-    return (
-        jnp.take_along_axis(p[0], idx, axis=1),
-        jnp.take_along_axis(p[1], idx, axis=1),
-    )
+def _lookup(t, idx):
+    """A [B, S] per-server table read at int [B, N] server indices in
+    0..S-1, as S compares and selects: a gather costs nanoseconds a lane
+    on the TPU however small its table, a select chain fuses into the
+    arithmetic on both sides of it."""
+    out = jnp.zeros(idx.shape, t.dtype)
+    for s in range(t.shape[1]):
+        out = jnp.where(idx == s, t[:, s : s + 1], out)
+    return out
+
+
+def _plookup(p, idx):
+    return _lookup(p[0], idx), _lookup(p[1], idx)
 
 
 def _adj_swap_products(S: int):
@@ -680,9 +688,11 @@ class Canonicalizer:
                 r0b = r0b ^ mix32(x * KB + wb)
             rec0 = (mix32(r0a), mix32(r0b))
             cnt32 = jnp.where(occ, cnt, 0).astype(jnp.uint32)
-            msg = (words, cnt32, occ, rec0)
-            for k, (fname, kind) in enumerate(self.msg_perm_spec):
-                val = self._unpack_key(words, fname)  # [B, M]
+            svals = [self._unpack_key(words, fname)  # [B, M] each
+                     for fname, _kind in self.msg_perm_spec]
+            msg = (svals, cnt32, occ, rec0)
+            for k, (val, (_f, kind)) in enumerate(
+                    zip(svals, self.msg_perm_spec)):
                 ck = _pfold(rec0, _salt(k, 8))
                 c = (cnt32 * ck[0], cnt32 * ck[1])  # [B, M]
                 acc = _padd(acc, self._scatter_by_server(c, val, kind, occ))
@@ -695,14 +705,9 @@ class Canonicalizer:
             acc1 = (jnp.zeros((B, S), jnp.uint32),
                     jnp.zeros((B, S), jnp.uint32))
             for off, vals in val_fields:
-                tgt = jnp.clip(vals - 1, 0, S - 1)
-                nsig = _pgather(sigp, tgt)
-                valid = (vals > 0) & (vals - 1 != srange)
-                sa, sb = _salt(off, 9 + rr)
-                acc1 = _padd(
-                    acc1,
-                    _pwhere(valid, (mix32(nsig[0] ^ sa), mix32(nsig[1] ^ sb))),
-                )
+                nsig = self._gather_sig_fold(
+                    sigp, vals, "server_nil", _salt(off, 9 + rr))
+                acc1 = _padd(acc1, _pwhere(vals - 1 != srange, nsig))
             for off, masks in bm_fields:
                 bits = ((masks[:, :, None] >> srange[None, None, :]) & 1) == 1
                 sa, sb = _salt(off, 10 + rr)
@@ -727,24 +732,21 @@ class Canonicalizer:
                 ecb = mix32(mt32 * KB + (sigp[1] ^ sb2)[:, None, :])
                 acc1 = _padd(acc1, _psum_last((eca, ecb)))
             if msg is not None:
-                words, cnt32, occ, rec0 = msg
+                svals, cnt32, occ, rec0 = msg
                 # per-slot fold of every referenced server's sig, then
                 # re-scatter: binds a record's endpoints together
-                svals = []
+                folds = [
+                    self._gather_sig_fold(sigp, val, kind, _salt(k, 13 + rr))
+                    for k, (val, (_f, kind)) in enumerate(
+                        zip(svals, self.msg_perm_spec))
+                ]
                 osum = (jnp.zeros_like(rec0[0]), jnp.zeros_like(rec0[1]))
-                for k, (fname, kind) in enumerate(self.msg_perm_spec):
-                    val = self._unpack_key(words, fname)
-                    svals.append(val)
-                    osum = _padd(
-                        osum,
-                        self._gather_sig_fold(sigp, val, kind,
-                                              _salt(k, 13 + rr)),
-                    )
-                for k, (fname, kind) in enumerate(self.msg_perm_spec):
+                for own in folds:
+                    osum = _padd(osum, own)
+                for k, (val, own, (_f, kind)) in enumerate(
+                        zip(svals, folds, self.msg_perm_spec)):
                     # exclude the target's own contribution so its fold
                     # is over the OTHER endpoints
-                    own = self._gather_sig_fold(sigp, svals[k], kind,
-                                                _salt(k, 13 + rr))
                     sa, sb = _salt(k, 14 + rr)
                     c = (
                         cnt32 * mix32(rec0[0] + (osum[0] - own[0]) + sa),
@@ -752,7 +754,7 @@ class Canonicalizer:
                     )
                     acc1 = _padd(
                         acc1,
-                        self._scatter_by_server(c, svals[k], kind, occ),
+                        self._scatter_by_server(c, val, kind, occ),
                     )
             return (mix32(sigp[0] + mix32(acc1[0])),
                     mix32(sigp[1] + mix32(acc1[1])))
@@ -785,23 +787,22 @@ class Canonicalizer:
         return _psum_last((pa, pb))
 
     def _gather_sig_fold(self, sig0, val, kind, salt):
-        """Fold the sig0 of servers referenced by a [B, M] message field
-        into a per-slot stream pair (multiset sum; 0 when Nil/absent)."""
+        """Fold the sig0 of servers referenced by a [B, N] field (message
+        slots or a votedFor row) into a per-lane stream pair (multiset
+        sum; 0 when Nil/absent). The salted mix is taken per server,
+        before the lookup: S mixes a row whatever N is."""
         S = self.S
         sa, sb = salt
+        e = (mix32(sig0[0] ^ sa), mix32(sig0[1] ^ sb))  # [B, S]
         if kind == "server":
-            nsig = _pgather(sig0, jnp.clip(val, 0, S - 1))
-            return mix32(nsig[0] ^ sa), mix32(nsig[1] ^ sb)
+            return _plookup(e, jnp.clip(val, 0, S - 1))
         if kind == "server_nil":
-            nsig = _pgather(sig0, jnp.clip(val - 1, 0, S - 1))
-            return _pwhere(val > 0, (mix32(nsig[0] ^ sa), mix32(nsig[1] ^ sb)))
+            return _pwhere(val > 0, _plookup(e, jnp.clip(val - 1, 0, S - 1)))
         if kind == "server_bitmask":
             srange = jnp.arange(S, dtype=jnp.int32)
             bits = ((val[:, :, None] >> srange[None, None, :]) & 1) == 1
-            ea = mix32(sig0[0] ^ sa)  # [B, S]
-            eb = mix32(sig0[1] ^ sb)
-            pa = jnp.where(bits, jnp.broadcast_to(ea[:, None, :], bits.shape), 0)
-            pb = jnp.where(bits, jnp.broadcast_to(eb[:, None, :], bits.shape), 0)
+            pa = jnp.where(bits, jnp.broadcast_to(e[0][:, None, :], bits.shape), 0)
+            pb = jnp.where(bits, jnp.broadcast_to(e[1][:, None, :], bits.shape), 0)
             return _psum_last((pa, pb))
         raise ValueError(f"unknown msg perm kind {kind}")
 
@@ -1130,7 +1131,7 @@ class Canonicalizer:
 
         # ---- tier 1: one dynamic permutation (the signature argsort) ----
         order = jnp.argsort(sig, axis=1).astype(jnp.int32)  # = inv
-        ssig = jnp.take_along_axis(sig, order, axis=1)
+        ssig = _lookup(sig, order)
         adj_eq = eq_u64(ssig[:, 1:], ssig[:, :-1])  # [B, S-1]
         sigma = jnp.argsort(order, axis=1).astype(jnp.int32)
         fp = self._hash_dyn(view, sigma)

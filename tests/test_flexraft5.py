@@ -8,6 +8,7 @@ test_flexible_raft.py::test_reference_flexible_cfg_loads, which needs a
 checkout with the reference beside it.
 """
 
+import collections
 import filecmp
 import os
 
@@ -189,6 +190,38 @@ def test_canon_scopes_nest_as_siblings_at_five_servers(
     assert f"/{scope}/" in memo_canon_lowered
     assert "memo/tier" not in memo_canon_lowered
     assert "/memo/while/" not in memo_canon_lowered
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs nested in it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+def test_tier12_looks_servers_up_without_a_gather(setup):
+    """A five-entry table is read by compares and selects (PR 29): a
+    gather costs the chip nanoseconds a lane however small its table,
+    and thirteen of them were 57 % of the device's time in
+    flexraft5-wide. The parent's jaxpr of what `canon/tier12` scopes had
+    31 gathers from a [B, S] operand: in `_signatures` 24 to [B, 32] and
+    6 to [B, 5], in `_tier_pre` the sorted signatures. Nothing is
+    compiled."""
+    from raft_tpu.ops.symmetry import Canonicalizer
+
+    canon = Canonicalizer.for_model(setup.model, symmetry=True)
+    B, S = 64, canon.S
+    assert S == 5
+    closed = jax.make_jaxpr(
+        lambda view: canon._tier_pre(view, canon._signatures(view)))(
+        jax.ShapeDtypeStruct((B, canon.VL), np.int32))
+    names = collections.Counter(e.primitive.name for e in _eqns(closed.jaxpr))
+    assert names["select_n"] > 0 and names["reduce_sum"] > 0  # the walk sees in
+    from_table = [e for e in _eqns(closed.jaxpr)
+                  if e.primitive.name == "gather"
+                  and e.invars[0].aval.shape == (B, S)]
+    assert not from_table, [str(e.outvars[0].aval) for e in from_table]
 
 
 def test_no_growth_after_the_wave_that_max_depth_ends():

@@ -189,6 +189,91 @@ def test_signature_equivariance(name):
         assert np.array_equal(psig[:, list(sigma)], sig), f"sigma={sigma}"
 
 
+def kraft3():
+    from raft_tpu.models.kraft import KRaftParams, cached_model
+    from raft_tpu.oracle.kraft_oracle import KRaftOracle
+
+    p = KRaftParams(n_servers=3, n_values=1, max_elections=2, max_restarts=0,
+                    msg_slots=56)
+    return cached_model(p), KRaftOracle(p.n_servers, p.n_values,
+                                        p.max_elections, p.max_restarts)
+
+
+def flexraft5():
+    from raft_tpu.models.registry import build_from_cfg, oracle_for_setup
+    from raft_tpu.utils.cfg import parse_cfg
+
+    from test_flexraft5 import CFG, MSG_SLOTS
+
+    setup = build_from_cfg(parse_cfg(CFG), msg_slots=MSG_SLOTS)
+    return setup.model, oracle_for_setup(setup)
+
+
+# name -> (builder, depth and cap of the sample); kraft3's mleader is the
+# tree's one server_nil message field (first not Nil at depth 6, state 382),
+# flexraft5 is tests/test_flexraft5.py's sample
+LOOKUP_CASES = {"raft3": (raft3, 4, 150), "pull3": (pull3, 4, 150),
+                "kraft3": (kraft3, 6, 700), "raft5": (raft5, 4, 150),
+                "flexraft5": (flexraft5, 6, 150)}
+
+
+def _lookup_by_gather(t, idx):
+    """The plain reference of ``symmetry._lookup``: the
+    ``take_along_axis`` gather it replaced (PR 29)."""
+    import jax.numpy as jnp
+
+    return jnp.take_along_axis(t, idx, axis=1)
+
+
+@pytest.fixture(scope="module", params=list(LOOKUP_CASES))
+def lookup_batch(request):
+    """A model and a view batch with Init (all tied, every votedFor Nil),
+    reachable rows (empty message slots: the index is out of range before
+    the clip) and full bags."""
+    build, depth, cap = LOOKUP_CASES[request.param]
+    model, oracle = build()
+    states = collect_states(oracle, max_depth=depth, cap=cap)
+    vecs = np.stack([model.encode(st) for st in states])
+    batch = np.concatenate([model.init_states(), vecs]).astype(np.int32)
+    # every looked-up field names servers and, where it can, Nil
+    auto = Canonicalizer.for_model(model, symmetry=True)
+    words = [batch[:, sl] for sl in auto._msg_word_sls]
+    for fname, kind in auto.msg_perm_spec:
+        val = np.asarray(auto._unpack_key(words, fname))
+        want = auto.S + (kind == "server_nil")
+        assert len(np.unique(val)) == want, (fname, kind)
+    return model, batch
+
+
+@pytest.mark.parametrize("rounds", [1, 2, 3])
+def test_signatures_bit_equal_to_gather_reference(lookup_batch, rounds,
+                                                  monkeypatch):
+    from raft_tpu.ops import symmetry
+
+    model, batch = lookup_batch
+    auto = Canonicalizer.for_model(model, symmetry=True, refine_rounds=rounds)
+    view = batch[:, : auto.VL]
+
+    def tier12():
+        """The signatures and, where the layout has tiers, what tier 1
+        makes of them: it sorts them through the same lookup."""
+        sig = auto._signatures(view)
+        return [np.asarray(x) for x in (
+            sig, *(auto._tier_pre(view, sig) if auto.prune else ()))]
+
+    got = tier12()
+    monkeypatch.setattr(symmetry, "_lookup", _lookup_by_gather)
+    want = tier12()
+    sig = got[0]
+    assert sig.dtype == np.uint64 and sig.shape == (len(batch), auto.S)
+    assert len(got) == len(want)
+    for a, b in zip(got, want):
+        assert np.array_equal(a, b)
+    # the sample is not degenerate: Init is all tied, later rows are not
+    assert len(set(sig[0].tolist())) == 1
+    assert any(len(set(row.tolist())) > 1 for row in sig)
+
+
 @pytest.mark.parametrize("name", ["raft3", "raft5"])
 def test_fp_equality_matches_oracle_canon(name):
     # fp equality <=> oracle canonical-view equality on a reachable sample
