@@ -18,6 +18,14 @@ count against the pure-Python oracle's golden
   leg C  the multi-chip engine (`--checker sharded --devices 4`) to
          depth 16, where four chips are visible; on a one-chip machine
          the result says the leg did not run.
+  leg D  configs/standard-raft/RaftWithReconfigJointConsensus.cfg (4
+         servers, 24 permutations, 1,042-lane rows; the lowering
+         models/config_common.py shares with the AddRemove spec) to
+         depth 8 at the registry's own bag width, against
+         tests/golden/joint_cfg_depth_counts.json: a second model file
+         through the same wave program. On its first contact with a v5e
+         (PR 30) this lowering lost writes in the sparse apply and every
+         count from depth 4 on was wrong, on the chip only.
 
 This process never imports jax or raft_tpu: a chip belongs to one process
 at a time, so every leg is a child of its own, one after the other, and
@@ -41,7 +49,11 @@ OUT = os.path.join(ROOT, ".smoke")
 GOLDEN = os.path.join(ROOT, "tests", "golden", "raft_cfg_depth_counts.json")
 TRACE_GOLDEN = os.path.join(
     ROOT, "tests", "golden", "flexible_unsafe_quorums_trace.txt")
+JOINT_GOLDEN = os.path.join(
+    ROOT, "tests", "golden", "joint_cfg_depth_counts.json")
 RAFT_CFG = os.path.join(ROOT, "configs", "standard-raft", "Raft.cfg")
+JOINT_CFG = os.path.join(
+    ROOT, "configs", "standard-raft", "RaftWithReconfigJointConsensus.cfg")
 UNSAFE_CFG = os.path.join(
     ROOT, "configs", "flexible-raft", "unsafe-quorums", "FlexibleRaft.cfg")
 SCHEMA_CHECK = os.path.join(ROOT, "scripts", "check_metrics_schema.py")
@@ -120,18 +132,21 @@ def read_events(path: str) -> list[dict]:
 
 
 def bfs_leg(name: str, dev: dict, golden: dict, extra: list[str],
-            max_depth: int, device_count: int) -> dict:
-    """One exhaustive-BFS child on Raft.cfg to ``max_depth``; every count
+            max_depth: int, device_count: int, cfg: str = RAFT_CFG,
+            chunk: int = 4096) -> dict:
+    """One exhaustive-BFS child on ``cfg`` to ``max_depth``; every count
     checked against the oracle golden's prefix. Returns the observations
     (wall, set-up to the end of the first wave)."""
     metrics = os.path.join(OUT, f"{name}.jsonl")
     if os.path.exists(metrics):
         os.remove(metrics)
     rc, out, wall = child(name, [
-        "-m", "raft_tpu", RAFT_CFG, "--platform", dev["platform"],
-        "--chunk", "4096", "--msg-slots", str(golden["msg_slots"]),
-        "--max-depth", str(max_depth), "--json", "--metrics-out", metrics,
-        *extra,
+        "-m", "raft_tpu", cfg, "--platform", dev["platform"],
+        "--chunk", str(chunk), "--max-depth", str(max_depth), "--json",
+        "--metrics-out", metrics, *extra,
+        # a golden with no bag width of its own: the registry's
+        *(["--msg-slots", str(golden["msg_slots"])]
+          if golden["msg_slots"] else []),
     ])
     check(rc == 0, f"{name}: exit code {rc}, expected 0:\n" + err_tail(name))
     summary = json.loads(out.strip().splitlines()[-1])
@@ -226,9 +241,20 @@ def leg_c(dev: dict, golden: dict) -> None:
           f"{res['distinct']} distinct (run wall {res['wall_s']} s)")
 
 
+def leg_d(dev: dict, golden: dict) -> None:
+    depth = golden["max_depth"]
+    res = bfs_leg("legD", dev, golden,
+                  ["--checker", "tpu", "--frontier-cap", "65536"], depth, 1,
+                  cfg=JOINT_CFG, chunk=1024)
+    print(f"leg D ok: RaftWithReconfigJointConsensus.cfg to depth {depth}, "
+          f"{res['distinct']} distinct / {res['total']} generated "
+          f"(run wall {res['wall_s']} s)")
+
+
 def main() -> int:
     try:
-        for path in (GOLDEN, TRACE_GOLDEN, RAFT_CFG, UNSAFE_CFG, SCHEMA_CHECK,
+        for path in (GOLDEN, JOINT_GOLDEN, TRACE_GOLDEN, RAFT_CFG, JOINT_CFG,
+                     UNSAFE_CFG, SCHEMA_CHECK,
                      os.path.join(ROOT, "raft_tpu", "__main__.py")):
             check(os.path.exists(path),
                   f"{os.path.relpath(path, ROOT)} is missing: chip_smoke.py "
@@ -240,6 +266,8 @@ def main() -> int:
         leg_a(dev, golden)
         leg_b(dev)
         leg_c(dev, golden)
+        with open(JOINT_GOLDEN) as f:
+            leg_d(dev, json.load(f)["depth_limited"])
     except SmokeFailure as e:
         print(f"chip_smoke: FAILED: {e}", file=sys.stderr)
         return 1

@@ -574,11 +574,18 @@ def onehot_set(arr, i, val):
 
 
 def onehot_set2(arr, i, j, val):
-    """``arr.at[i, j].set(val)`` on an [S, S] matrix via one-hot."""
-    S = arr.shape[0]
-    ohi = (jnp.arange(S, dtype=jnp.int32) == i)[:, None]
-    ohj = (jnp.arange(S, dtype=jnp.int32) == j)[None, :]
+    """``arr.at[i, j].set(val)`` on a matrix ([S, S], or a server's
+    log lanes [S, L]) via one-hot."""
+    ohi = (jnp.arange(arr.shape[0], dtype=jnp.int32) == i)[:, None]
+    ohj = (jnp.arange(arr.shape[1], dtype=jnp.int32) == j)[None, :]
     return jnp.where(ohi & ohj, val, arr)
+
+
+def onehot_add(arr, i, val):
+    """``arr.at[i].add(val)`` along axis 0 via a one-hot select."""
+    oh = jnp.arange(arr.shape[0], dtype=jnp.int32) == i
+    ohx = oh.reshape((arr.shape[0],) + (1,) * (arr.ndim - 1))
+    return arr + jnp.where(ohx, val, 0)
 
 
 def messages_are_valid_kernel(layout: Layout, packer):

@@ -29,7 +29,9 @@ import numpy as np
 
 from ..ops import bag
 from ..ops.packing import EMPTY
-from .base import ActionLabelMixin, SparseExpandMixin
+from .base import (
+    ActionLabelMixin, SparseExpandMixin, onehot_add, onehot_set, onehot_set2,
+)
 
 # enums shared by both variants (identical values in both specs' lowerings)
 FOLLOWER, CANDIDATE, LEADER, NOTMEMBER = range(4)
@@ -137,12 +139,12 @@ class ConfigRaftCommon(SparseExpandMixin, ActionLabelMixin):
         valid = d["restartCtr"] < p.max_restarts
         succ = self._asm(
             d,
-            state=d["state"].at[i].set(FOLLOWER),
-            votesGranted=d["votesGranted"].at[i].set(0),
-            nextIndex=d["nextIndex"].at[i].set(jnp.ones((S,), jnp.int32)),
-            matchIndex=d["matchIndex"].at[i].set(jnp.zeros((S,), jnp.int32)),
-            pendingResponse=d["pendingResponse"].at[i].set(0),
-            commitIndex=d["commitIndex"].at[i].set(0),
+            state=onehot_set(d["state"], i, FOLLOWER),
+            votesGranted=onehot_set(d["votesGranted"], i, 0),
+            nextIndex=onehot_set(d["nextIndex"], i, jnp.ones((S,), jnp.int32)),
+            matchIndex=onehot_set(d["matchIndex"], i, jnp.zeros((S,), jnp.int32)),
+            pendingResponse=onehot_set(d["pendingResponse"], i, 0),
+            commitIndex=onehot_set(d["commitIndex"], i, 0),
             restartCtr=d["restartCtr"] + 1,
         )
         return valid, succ, jnp.int32(R_RESTART), jnp.asarray(False)
@@ -183,10 +185,10 @@ class ConfigRaftCommon(SparseExpandMixin, ActionLabelMixin):
             cnt = jnp.where(is_member, c2, cnt)
         succ = self._asm(
             d,
-            state=d["state"].at[i].set(CANDIDATE),
-            currentTerm=d["currentTerm"].at[i].set(new_term),
-            votedFor=d["votedFor"].at[i].set(i + 1),
-            votesGranted=d["votesGranted"].at[i].set(jnp.int32(1) << i),
+            state=onehot_set(d["state"], i, CANDIDATE),
+            currentTerm=onehot_set(d["currentTerm"], i, new_term),
+            votedFor=onehot_set(d["votedFor"], i, i + 1),
+            votesGranted=onehot_set(d["votesGranted"], i, jnp.int32(1) << i),
             electionCtr=d["electionCtr"] + 1,
             **self._word_upd(words, cnt),
         )
@@ -209,12 +211,12 @@ class ConfigRaftCommon(SparseExpandMixin, ActionLabelMixin):
         posc = jnp.clip(pos, 0, L - 1)
         succ = self._asm(
             d,
-            log_term=d["log_term"].at[i, posc].set(term),
-            log_cmd=d["log_cmd"].at[i, posc].set(self.CMD_APPEND),
-            log_val=d["log_val"].at[i, posc].set(v + 1),
-            log_len=d["log_len"].at[i].add(1),
-            acked=d["acked"].at[v].set(ACK_FALSE),
-            valueCtr=d["valueCtr"].at[tpos].add(1),
+            log_term=onehot_set2(d["log_term"], i, posc, term),
+            log_cmd=onehot_set2(d["log_cmd"], i, posc, self.CMD_APPEND),
+            log_val=onehot_set2(d["log_val"], i, posc, v + 1),
+            log_len=onehot_add(d["log_len"], i, 1),
+            acked=onehot_set(d["acked"], v, ACK_FALSE),
+            valueCtr=onehot_add(d["valueCtr"], tpos, 1),
         )
         return valid, succ, jnp.int32(R_CLIENTREQUEST), ovf
 
@@ -257,7 +259,7 @@ class ConfigRaftCommon(SparseExpandMixin, ActionLabelMixin):
         valid &= (nent > 0) | ~existed  # empty AEReq is send-once
         succ = self._asm(
             d,
-            pendingResponse=d["pendingResponse"].at[i].set(
+            pendingResponse=onehot_set(d["pendingResponse"], i,
                 d["pendingResponse"][i] | (jnp.int32(1) << j)
             ),
             **self._word_upd(words, cnt),
@@ -292,7 +294,7 @@ class ConfigRaftCommon(SparseExpandMixin, ActionLabelMixin):
         words, cnt, _existed, ovf = self._bag_put(self._words(d), d["msg_cnt"], key)
         succ = self._asm(
             d,
-            nextIndex=d["nextIndex"].at[i, j].set(PENDING_SNAP_RESPONSE),
+            nextIndex=onehot_set2(d["nextIndex"], i, j, PENDING_SNAP_RESPONSE),
             **self._word_upd(words, cnt),
         )
         return valid, succ, jnp.int32(R_SENDSNAP), ovf & valid
@@ -412,7 +414,7 @@ class ConfigRaftCommon(SparseExpandMixin, ActionLabelMixin):
         b_rvresp = recv & (mtype == RVRESP) & eq_term & (st_dst == CANDIDATE)
         vg = jnp.where(
             u("mvoteGranted") > 0,
-            d["votesGranted"].at[dst].set(
+            onehot_set(d["votesGranted"], dst,
                 d["votesGranted"][dst] | (jnp.int32(1) << src)
             ),
             d["votesGranted"],
@@ -472,7 +474,7 @@ class ConfigRaftCommon(SparseExpandMixin, ActionLabelMixin):
         new_logs = {}
         for n in self.ENTRY_FIELDS:
             row = d[f"log_{n}"][dst]
-            nrow = jnp.where(keep, row, 0).at[app_pos].set(
+            nrow = onehot_set(jnp.where(keep, row, 0), app_pos,
                 jnp.where(appending, u(f"e_{n}"), 0)
             )
             new_logs[n] = jnp.where(appending, nrow, row)
@@ -554,43 +556,43 @@ class ConfigRaftCommon(SparseExpandMixin, ActionLabelMixin):
         # --- per-field combination (disjoint branches => order-free) ---
         upd = dict(
             currentTerm=jnp.where(
-                b_upd, d["currentTerm"].at[dst].set(mterm), d["currentTerm"]),
+                b_upd, onehot_set(d["currentTerm"], dst, mterm), d["currentTerm"]),
             state=jnp.where(
-                b_upd, d["state"].at[dst].set(FOLLOWER),
+                b_upd, onehot_set(d["state"], dst, FOLLOWER),
                 jnp.where(
                     b_accept,
-                    d["state"].at[dst].set(
+                    onehot_set(d["state"], dst,
                         jnp.where(in_new, FOLLOWER, NOTMEMBER)),
                     d["state"])),
             votedFor=jnp.where(
-                b_upd, d["votedFor"].at[dst].set(NIL),
+                b_upd, onehot_set(d["votedFor"], dst, NIL),
                 jnp.where(b_rvreq & grant,
-                          d["votedFor"].at[dst].set(src + 1), d["votedFor"])),
+                          onehot_set(d["votedFor"], dst, src + 1), d["votedFor"])),
             votesGranted=jnp.where(b_rvresp, vg, d["votesGranted"]),
             commitIndex=jnp.where(
-                b_accept, d["commitIndex"].at[dst].set(mci),
-                jnp.where(b_snapreq, d["commitIndex"].at[dst].set(sn_mci),
+                b_accept, onehot_set(d["commitIndex"], dst, mci),
+                jnp.where(b_snapreq, onehot_set(d["commitIndex"], dst, sn_mci),
                           d["commitIndex"])),
             log_len=jnp.where(
-                b_accept, d["log_len"].at[dst].set(new_ll),
-                jnp.where(b_snapreq, d["log_len"].at[dst].set(sn_ll),
+                b_accept, onehot_set(d["log_len"], dst, new_ll),
+                jnp.where(b_snapreq, onehot_set(d["log_len"], dst, sn_ll),
                           d["log_len"])),
             nextIndex=jnp.where(
-                b_aeresp, d["nextIndex"].at[dst, src].set(ni_new),
+                b_aeresp, onehot_set2(d["nextIndex"], dst, src, ni_new),
                 jnp.where(
                     b_snapresp,
-                    d["nextIndex"].at[dst, src].set(u("mmatchIndex") + 1),
+                    onehot_set2(d["nextIndex"], dst, src, u("mmatchIndex") + 1),
                     d["nextIndex"])),
             matchIndex=jnp.where(
                 b_aeresp & (res == RC_OK),
-                d["matchIndex"].at[dst, src].set(mmatch),
+                onehot_set2(d["matchIndex"], dst, src, mmatch),
                 jnp.where(
                     b_snapresp,
-                    d["matchIndex"].at[dst, src].set(u("mmatchIndex")),
+                    onehot_set2(d["matchIndex"], dst, src, u("mmatchIndex")),
                     d["matchIndex"])),
             pendingResponse=jnp.where(
                 b_aeresp,
-                d["pendingResponse"].at[dst].set(
+                onehot_set(d["pendingResponse"], dst,
                     d["pendingResponse"][dst] & ~(jnp.int32(1) << src)),
                 d["pendingResponse"]),
             msg_cnt=jnp.where(putb, pc, jnp.where(dropb, cnt_disc, cnt)),
@@ -599,8 +601,8 @@ class ConfigRaftCommon(SparseExpandMixin, ActionLabelMixin):
             upd[f"msg_w{k}"] = jnp.where(putb, w, words[k])
         for n in self.ENTRY_FIELDS:
             upd[f"log_{n}"] = jnp.where(
-                b_accept, d[f"log_{n}"].at[dst].set(new_logs[n]),
-                jnp.where(b_snapreq, d[f"log_{n}"].at[dst].set(sn_logs[n]),
+                b_accept, onehot_set(d[f"log_{n}"], dst, new_logs[n]),
+                jnp.where(b_snapreq, onehot_set(d[f"log_{n}"], dst, sn_logs[n]),
                           d[f"log_{n}"]))
         for k in cfg_upd:
             upd[k] = jnp.where(
@@ -774,24 +776,24 @@ class ConfigRaftCommon(SparseExpandMixin, ActionLabelMixin):
         upd["acked"] = acked
         removed = self._commit_removed(d, i, in_range)
         upd["state"] = jnp.where(
-            removed, d["state"].at[i].set(NOTMEMBER), d["state"])
+            removed, onehot_set(d["state"], i, NOTMEMBER), d["state"])
         upd["votesGranted"] = jnp.where(
-            removed, d["votesGranted"].at[i].set(0), d["votesGranted"]
+            removed, onehot_set(d["votesGranted"], i, 0), d["votesGranted"]
         )
         upd["nextIndex"] = jnp.where(
             removed,
-            d["nextIndex"].at[i].set(jnp.ones((S,), jnp.int32)),
+            onehot_set(d["nextIndex"], i, jnp.ones((S,), jnp.int32)),
             d["nextIndex"],
         )
         upd["matchIndex"] = jnp.where(
             removed,
-            d["matchIndex"].at[i].set(jnp.zeros((S,), jnp.int32)),
+            onehot_set(d["matchIndex"], i, jnp.zeros((S,), jnp.int32)),
             d["matchIndex"],
         )
         upd["commitIndex"] = jnp.where(
             removed,
-            d["commitIndex"].at[i].set(0),
-            d["commitIndex"].at[i].set(new_ci),
+            onehot_set(d["commitIndex"], i, 0),
+            onehot_set(d["commitIndex"], i, new_ci),
         )
         succ = self._asm(d, **upd)
         return valid, succ, jnp.int32(R_ADVANCECOMMIT), jnp.asarray(False)

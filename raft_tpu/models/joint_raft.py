@@ -37,7 +37,9 @@ import numpy as np
 
 from ..ops import bag
 from ..ops.packing import EMPTY, WidePacker, bits_for
-from .base import Layout, messages_are_valid_kernel
+from .base import (
+    Layout, messages_are_valid_kernel, onehot_add, onehot_set, onehot_set2,
+)
 
 from .config_common import (  # shared enums: single source of truth
     ACK_FALSE, ACK_NIL, ACK_TRUE, CANDIDATE, FOLLOWER, LEADER, NIL,
@@ -328,12 +330,12 @@ class JointRaftModel(ConfigRaftCommon):
         joint = (cmd == CMD_OLDNEW).astype(jnp.int32)
         z = jnp.int32(0)
         return dict(
-            config_id=d["config_id"].at[i].set(cid),
-            config_joint=d["config_joint"].at[i].set(joint),
-            config_members=d["config_members"].at[i].set(members),
-            config_old=d["config_old"].at[i].set(jnp.where(joint > 0, old, z)),
-            config_new=d["config_new"].at[i].set(jnp.where(joint > 0, new, z)),
-            config_committed=d["config_committed"].at[i].set(
+            config_id=onehot_set(d["config_id"], i, cid),
+            config_joint=onehot_set(d["config_joint"], i, joint),
+            config_members=onehot_set(d["config_members"], i, members),
+            config_old=onehot_set(d["config_old"], i, jnp.where(joint > 0, old, z)),
+            config_new=onehot_set(d["config_new"], i, jnp.where(joint > 0, new, z)),
+            config_committed=onehot_set(d["config_committed"], i,
                 (ci >= idx).astype(jnp.int32)
             ),
         )
@@ -359,12 +361,12 @@ class JointRaftModel(ConfigRaftCommon):
         )
         succ = self._asm(
             d,
-            state=d["state"].at[i].set(LEADER),
-            nextIndex=d["nextIndex"].at[i].set(
+            state=onehot_set(d["state"], i, LEADER),
+            nextIndex=onehot_set(d["nextIndex"], i,
                 jnp.full((S,), 1, jnp.int32) * (d["log_len"][i] + 1)
             ),
-            matchIndex=d["matchIndex"].at[i].set(jnp.zeros((S,), jnp.int32)),
-            pendingResponse=d["pendingResponse"].at[i].set(0),
+            matchIndex=onehot_set(d["matchIndex"], i, jnp.zeros((S,), jnp.int32)),
+            pendingResponse=onehot_set(d["pendingResponse"], i, 0),
         )
         return valid, succ, jnp.int32(J_BECOMELEADER), jnp.asarray(False)
 
@@ -430,23 +432,23 @@ class JointRaftModel(ConfigRaftCommon):
         )
         succ = self._asm(
             d,
-            log_term=d["log_term"].at[i, posc].set(d["currentTerm"][i]),
-            log_cmd=d["log_cmd"].at[i, posc].set(CMD_OLDNEW),
-            log_cid=d["log_cid"].at[i, posc].set(new_id),
-            log_old=d["log_old"].at[i, posc].set(old),
-            log_new=d["log_new"].at[i, posc].set(new),
-            log_members=d["log_members"].at[i, posc].set(joint_members),
-            log_len=d["log_len"].at[i].add(1),
-            config_id=d["config_id"].at[i].set(new_id),
-            config_joint=d["config_joint"].at[i].set(1),
-            config_members=d["config_members"].at[i].set(joint_members),
-            config_old=d["config_old"].at[i].set(old),
-            config_new=d["config_new"].at[i].set(new),
-            config_committed=d["config_committed"].at[i].set(
+            log_term=onehot_set2(d["log_term"], i, posc, d["currentTerm"][i]),
+            log_cmd=onehot_set2(d["log_cmd"], i, posc, CMD_OLDNEW),
+            log_cid=onehot_set2(d["log_cid"], i, posc, new_id),
+            log_old=onehot_set2(d["log_old"], i, posc, old),
+            log_new=onehot_set2(d["log_new"], i, posc, new),
+            log_members=onehot_set2(d["log_members"], i, posc, joint_members),
+            log_len=onehot_add(d["log_len"], i, 1),
+            config_id=onehot_set(d["config_id"], i, new_id),
+            config_joint=onehot_set(d["config_joint"], i, 1),
+            config_members=onehot_set(d["config_members"], i, joint_members),
+            config_old=onehot_set(d["config_old"], i, old),
+            config_new=onehot_set(d["config_new"], i, new),
+            config_committed=onehot_set(d["config_committed"], i,
                 (d["commitIndex"][i] >= pos + 1).astype(jnp.int32)
             ),
             reconfigCtr=d["reconfigCtr"] + 1,
-            nextIndex=d["nextIndex"].at[i].set(ni_row),
+            nextIndex=onehot_set(d["nextIndex"], i, ni_row),
         )
         return valid, succ, jnp.int32(J_APPEND_OLDNEW), ovf
 
@@ -478,17 +480,17 @@ class JointRaftModel(ConfigRaftCommon):
         posc = jnp.clip(pos, 0, L - 1)
         succ = self._asm(
             d,
-            log_term=d["log_term"].at[i, posc].set(d["currentTerm"][i]),
-            log_cmd=d["log_cmd"].at[i, posc].set(CMD_NEW),
-            log_cid=d["log_cid"].at[i, posc].set(new_id),
-            log_members=d["log_members"].at[i, posc].set(new_members),
-            log_len=d["log_len"].at[i].add(1),
-            config_id=d["config_id"].at[i].set(new_id),
-            config_joint=d["config_joint"].at[i].set(0),
-            config_members=d["config_members"].at[i].set(new_members),
-            config_old=d["config_old"].at[i].set(0),
-            config_new=d["config_new"].at[i].set(0),
-            config_committed=d["config_committed"].at[i].set(
+            log_term=onehot_set2(d["log_term"], i, posc, d["currentTerm"][i]),
+            log_cmd=onehot_set2(d["log_cmd"], i, posc, CMD_NEW),
+            log_cid=onehot_set2(d["log_cid"], i, posc, new_id),
+            log_members=onehot_set2(d["log_members"], i, posc, new_members),
+            log_len=onehot_add(d["log_len"], i, 1),
+            config_id=onehot_set(d["config_id"], i, new_id),
+            config_joint=onehot_set(d["config_joint"], i, 0),
+            config_members=onehot_set(d["config_members"], i, new_members),
+            config_old=onehot_set(d["config_old"], i, 0),
+            config_new=onehot_set(d["config_new"], i, 0),
+            config_committed=onehot_set(d["config_committed"], i,
                 (d["commitIndex"][i] >= pos + 1).astype(jnp.int32)
             ),
         )
@@ -510,16 +512,16 @@ class JointRaftModel(ConfigRaftCommon):
         cfg_joint = (cfg_cmd == CMD_OLDNEW).astype(jnp.int32)
         cfg_members = logs["members"][cfg_pos]
         upd = dict(
-            config_id=d["config_id"].at[dst].set(logs["cid"][cfg_pos]),
-            config_joint=d["config_joint"].at[dst].set(cfg_joint),
-            config_members=d["config_members"].at[dst].set(cfg_members),
-            config_old=d["config_old"].at[dst].set(
+            config_id=onehot_set(d["config_id"], dst, logs["cid"][cfg_pos]),
+            config_joint=onehot_set(d["config_joint"], dst, cfg_joint),
+            config_members=onehot_set(d["config_members"], dst, cfg_members),
+            config_old=onehot_set(d["config_old"], dst,
                 jnp.where(cfg_joint > 0, logs["old"][cfg_pos], z)
             ),
-            config_new=d["config_new"].at[dst].set(
+            config_new=onehot_set(d["config_new"], dst,
                 jnp.where(cfg_joint > 0, logs["new"][cfg_pos], z)
             ),
-            config_committed=d["config_committed"].at[dst].set(
+            config_committed=onehot_set(d["config_committed"], dst,
                 (mci >= cfg_idx).astype(jnp.int32)
             ),
         )

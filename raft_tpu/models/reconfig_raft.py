@@ -39,7 +39,9 @@ import numpy as np
 
 from ..ops import bag
 from ..ops.packing import EMPTY, WidePacker, bits_for
-from .base import Layout, messages_are_valid_kernel
+from .base import (
+    Layout, messages_are_valid_kernel, onehot_add, onehot_set, onehot_set2,
+)
 
 from .config_common import (  # shared enums: single source of truth
     ACK_FALSE, ACK_NIL, ACK_TRUE, CANDIDATE, FOLLOWER, LEADER, NIL,
@@ -312,12 +314,12 @@ class ReconfigRaftModel(ConfigRaftCommon):
         valid = (d["state"][i] == CANDIDATE) & subset & quorum
         succ = self._asm(
             d,
-            state=d["state"].at[i].set(LEADER),
-            nextIndex=d["nextIndex"].at[i].set(
+            state=onehot_set(d["state"], i, LEADER),
+            nextIndex=onehot_set(d["nextIndex"], i,
                 jnp.full((S,), 1, jnp.int32) * (d["log_len"][i] + 1)
             ),
-            matchIndex=d["matchIndex"].at[i].set(jnp.zeros((S,), jnp.int32)),
-            pendingResponse=d["pendingResponse"].at[i].set(0),
+            matchIndex=onehot_set(d["matchIndex"], i, jnp.zeros((S,), jnp.int32)),
+            pendingResponse=onehot_set(d["pendingResponse"], i, 0),
         )
         return valid, succ, jnp.int32(A_BECOMELEADER), jnp.asarray(False)
 
@@ -336,9 +338,9 @@ class ReconfigRaftModel(ConfigRaftCommon):
         cfg_idx, cfg_id, cfg_members = self._mrce(d, i)
         cfg_committed = (new_ci >= cfg_idx).astype(jnp.int32)
         return dict(
-            config_id=d["config_id"].at[i].set(cfg_id),
-            config_members=d["config_members"].at[i].set(cfg_members),
-            config_committed=d["config_committed"].at[i].set(cfg_committed),
+            config_id=onehot_set(d["config_id"], i, cfg_id),
+            config_members=onehot_set(d["config_members"], i, cfg_members),
+            config_committed=onehot_set(d["config_committed"], i, cfg_committed),
         )
 
     def _commit_removed(self, d, i, in_range):
@@ -377,20 +379,20 @@ class ReconfigRaftModel(ConfigRaftCommon):
         posc = jnp.clip(pos, 0, L - 1)
         succ = self._asm(
             d,
-            log_term=d["log_term"].at[i, posc].set(d["currentTerm"][i]),
-            log_cmd=d["log_cmd"].at[i, posc].set(CMD_ADD),
-            log_cid=d["log_cid"].at[i, posc].set(new_id),
-            log_cmem=d["log_cmem"].at[i, posc].set(a + 1),
-            log_cmembers=d["log_cmembers"].at[i, posc].set(new_members),
-            log_len=d["log_len"].at[i].add(1),
-            config_id=d["config_id"].at[i].set(new_id),
-            config_members=d["config_members"].at[i].set(new_members),
+            log_term=onehot_set2(d["log_term"], i, posc, d["currentTerm"][i]),
+            log_cmd=onehot_set2(d["log_cmd"], i, posc, CMD_ADD),
+            log_cid=onehot_set2(d["log_cid"], i, posc, new_id),
+            log_cmem=onehot_set2(d["log_cmem"], i, posc, a + 1),
+            log_cmembers=onehot_set2(d["log_cmembers"], i, posc, new_members),
+            log_len=onehot_add(d["log_len"], i, 1),
+            config_id=onehot_set(d["config_id"], i, new_id),
+            config_members=onehot_set(d["config_members"], i, new_members),
             # committed = ci >= Len(newLog) — always FALSE here (:814-816)
-            config_committed=d["config_committed"].at[i].set(
+            config_committed=onehot_set(d["config_committed"], i,
                 (d["commitIndex"][i] >= pos + 1).astype(jnp.int32)
             ),
             addReconfigCtr=d["addReconfigCtr"] + 1,
-            nextIndex=d["nextIndex"].at[i, a].set(PENDING_SNAP_REQUEST),
+            nextIndex=onehot_set2(d["nextIndex"], i, a, PENDING_SNAP_REQUEST),
         )
         return valid, succ, jnp.int32(A_APPEND_ADD), ovf
 
@@ -421,15 +423,15 @@ class ReconfigRaftModel(ConfigRaftCommon):
         posc = jnp.clip(pos, 0, L - 1)
         succ = self._asm(
             d,
-            log_term=d["log_term"].at[i, posc].set(d["currentTerm"][i]),
-            log_cmd=d["log_cmd"].at[i, posc].set(CMD_REMOVE),
-            log_cid=d["log_cid"].at[i, posc].set(new_id),
-            log_cmem=d["log_cmem"].at[i, posc].set(r + 1),
-            log_cmembers=d["log_cmembers"].at[i, posc].set(new_members),
-            log_len=d["log_len"].at[i].add(1),
-            config_id=d["config_id"].at[i].set(new_id),
-            config_members=d["config_members"].at[i].set(new_members),
-            config_committed=d["config_committed"].at[i].set(
+            log_term=onehot_set2(d["log_term"], i, posc, d["currentTerm"][i]),
+            log_cmd=onehot_set2(d["log_cmd"], i, posc, CMD_REMOVE),
+            log_cid=onehot_set2(d["log_cid"], i, posc, new_id),
+            log_cmem=onehot_set2(d["log_cmem"], i, posc, r + 1),
+            log_cmembers=onehot_set2(d["log_cmembers"], i, posc, new_members),
+            log_len=onehot_add(d["log_len"], i, 1),
+            config_id=onehot_set(d["config_id"], i, new_id),
+            config_members=onehot_set(d["config_members"], i, new_members),
+            config_committed=onehot_set(d["config_committed"], i,
                 (d["commitIndex"][i] >= pos + 1).astype(jnp.int32)
             ),
             removeReconfigCtr=d["removeReconfigCtr"] + 1,
@@ -457,24 +459,24 @@ class ReconfigRaftModel(ConfigRaftCommon):
         L = p.max_log
         succ = self._asm(
             d,
-            state=d["state"].at[i].set(NOTMEMBER),
-            config_id=d["config_id"].at[i].set(0),
-            config_members=d["config_members"].at[i].set(0),
-            config_committed=d["config_committed"].at[i].set(0),
-            currentTerm=d["currentTerm"].at[i].set(0),
-            votedFor=d["votedFor"].at[i].set(NIL),
-            votesGranted=d["votesGranted"].at[i].set(0),
-            nextIndex=d["nextIndex"].at[i].set(jnp.ones((S,), jnp.int32)),
-            matchIndex=d["matchIndex"].at[i].set(jnp.zeros((S,), jnp.int32)),
-            pendingResponse=d["pendingResponse"].at[i].set(0),
-            commitIndex=d["commitIndex"].at[i].set(0),
-            log_term=d["log_term"].at[i].set(jnp.zeros((L,), jnp.int32)),
-            log_cmd=d["log_cmd"].at[i].set(jnp.zeros((L,), jnp.int32)),
-            log_val=d["log_val"].at[i].set(jnp.zeros((L,), jnp.int32)),
-            log_cid=d["log_cid"].at[i].set(jnp.zeros((L,), jnp.int32)),
-            log_cmem=d["log_cmem"].at[i].set(jnp.zeros((L,), jnp.int32)),
-            log_cmembers=d["log_cmembers"].at[i].set(jnp.zeros((L,), jnp.int32)),
-            log_len=d["log_len"].at[i].set(0),
+            state=onehot_set(d["state"], i, NOTMEMBER),
+            config_id=onehot_set(d["config_id"], i, 0),
+            config_members=onehot_set(d["config_members"], i, 0),
+            config_committed=onehot_set(d["config_committed"], i, 0),
+            currentTerm=onehot_set(d["currentTerm"], i, 0),
+            votedFor=onehot_set(d["votedFor"], i, NIL),
+            votesGranted=onehot_set(d["votesGranted"], i, 0),
+            nextIndex=onehot_set(d["nextIndex"], i, jnp.ones((S,), jnp.int32)),
+            matchIndex=onehot_set(d["matchIndex"], i, jnp.zeros((S,), jnp.int32)),
+            pendingResponse=onehot_set(d["pendingResponse"], i, 0),
+            commitIndex=onehot_set(d["commitIndex"], i, 0),
+            log_term=onehot_set(d["log_term"], i, jnp.zeros((L,), jnp.int32)),
+            log_cmd=onehot_set(d["log_cmd"], i, jnp.zeros((L,), jnp.int32)),
+            log_val=onehot_set(d["log_val"], i, jnp.zeros((L,), jnp.int32)),
+            log_cid=onehot_set(d["log_cid"], i, jnp.zeros((L,), jnp.int32)),
+            log_cmem=onehot_set(d["log_cmem"], i, jnp.zeros((L,), jnp.int32)),
+            log_cmembers=onehot_set(d["log_cmembers"], i, jnp.zeros((L,), jnp.int32)),
+            log_len=onehot_set(d["log_len"], i, 0),
         )
         return valid, succ, jnp.int32(A_RESET_IDENTITY), jnp.asarray(False)
 
@@ -491,9 +493,9 @@ class ReconfigRaftModel(ConfigRaftCommon):
         in the installed member set."""
         cmembers = logs["cmembers"][cfg_pos]
         upd = dict(
-            config_id=d["config_id"].at[dst].set(logs["cid"][cfg_pos]),
-            config_members=d["config_members"].at[dst].set(cmembers),
-            config_committed=d["config_committed"].at[dst].set(
+            config_id=onehot_set(d["config_id"], dst, logs["cid"][cfg_pos]),
+            config_members=onehot_set(d["config_members"], dst, cmembers),
+            config_committed=onehot_set(d["config_committed"], dst,
                 (mci >= cfg_idx).astype(jnp.int32)
             ),
         )

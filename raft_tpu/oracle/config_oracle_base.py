@@ -115,6 +115,7 @@ class ConfigOracleBase:
         frontier = [init]
         total = 1
         distinct = 1
+        terminal = 0  # expanded states with no successor (`-deadlock`)
         depth_counts = [1]
         violation = None
         depth = 0
@@ -128,7 +129,9 @@ class ConfigOracleBase:
                 break
             next_frontier = []
             for st in frontier:
-                for _label, s2 in self.successors(st):
+                succs = self.successors(st)
+                terminal += not succs
+                for _label, s2 in succs:
                     total += 1
                     key = self.canon(s2, symmetry)
                     if key in seen:
@@ -162,6 +165,7 @@ class ConfigOracleBase:
             "distinct": distinct,
             "total": total,
             "depth_counts": depth_counts,
+            "terminal": terminal,
             "violation": violation,
         }
 

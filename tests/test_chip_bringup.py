@@ -181,10 +181,10 @@ def test_platform_tpu_without_a_chip_is_an_error_not_a_cpu_run():
     assert "distinct=" not in r.stdout  # nothing ran
 
 
-def test_chip_smoke_leg_holds_the_cli_to_the_golden(tmp_path, monkeypatch):
-    """The smoke's BFS leg at a tiny depth with the CPU standing in for
-    the device: it passes on the real golden and fails on a count that
-    differs (the probe that refuses a CPU is bypassed here, nothing else)."""
+@pytest.fixture
+def smoke(tmp_path, monkeypatch):
+    """chip_smoke with the CPU standing in for the device (the probe
+    that refuses a CPU is bypassed here, nothing else)."""
     sys.path.insert(0, str(ROOT))
     try:
         import chip_smoke
@@ -193,7 +193,13 @@ def test_chip_smoke_leg_holds_the_cli_to_the_golden(tmp_path, monkeypatch):
     monkeypatch.setattr(chip_smoke, "OUT", str(tmp_path))
     monkeypatch.setattr(chip_smoke, "T0", chip_smoke.time.monotonic())
     monkeypatch.setenv("JAX_PLATFORMS", "cpu")
-    dev = {"platform": "cpu", "kind": "cpu", "count": 1}
+    return chip_smoke, {"platform": "cpu", "kind": "cpu", "count": 1}
+
+
+def test_chip_smoke_leg_holds_the_cli_to_the_golden(smoke):
+    """The smoke's BFS leg at a tiny depth: it passes on the real golden
+    and fails on a count that differs."""
+    chip_smoke, dev = smoke
     golden = json.loads(GOLDEN.read_text())["depth_limited"]
     obs = chip_smoke.bfs_leg("ok", dev, golden, ["--checker", "tpu"], 5, 1)
     assert obs["distinct"] == sum(golden["depth_counts"][:6])
@@ -202,3 +208,23 @@ def test_chip_smoke_leg_holds_the_cli_to_the_golden(tmp_path, monkeypatch):
         chip_smoke.bfs_leg("bad", dev, wrong, ["--checker", "tpu"], 5, 1)
     with pytest.raises(chip_smoke.SmokeFailure, match="device_count 1 != 4"):
         chip_smoke.bfs_leg("cnt", dev, golden, ["--checker", "tpu"], 5, 4)
+
+
+def test_chip_smoke_joint_leg_runs_the_cfg_at_the_registrys_bag_width(smoke):
+    """Leg D's shape at a tiny depth: another cfg, its own chunk, no
+    --msg-slots (the golden names none), held to its own golden; the
+    count the v5e first got wrong is depth 4's (276 for 271)."""
+    chip_smoke, dev = smoke
+    golden = json.loads(
+        Path(chip_smoke.JOINT_GOLDEN).read_text())["depth_limited"]
+    assert golden["msg_slots"] is None
+    obs = chip_smoke.bfs_leg(
+        "joint", dev, golden, ["--checker", "tpu", "--frontier-cap", "4096"],
+        4, 1, cfg=chip_smoke.JOINT_CFG, chunk=256)
+    assert obs["distinct"] == 1 + 5 + 24 + 90 + 271
+    wrong = dict(golden, depth_counts=[1, 5, 24, 90, 276])
+    with pytest.raises(chip_smoke.SmokeFailure, match="per-depth counts differ"):
+        chip_smoke.bfs_leg(
+            "joint-bad", dev, wrong,
+            ["--checker", "tpu", "--frontier-cap", "4096"], 4, 1,
+            cfg=chip_smoke.JOINT_CFG, chunk=256)

@@ -19,7 +19,7 @@ import pytest
 from raft_tpu.models.registry import build_from_cfg, oracle_for_setup
 from raft_tpu.utils.cfg import parse_cfg
 
-from conftest import collect_states
+from conftest import collect_states, eqns, lower_memo_canon
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CFG = os.path.join(ROOT, "configs", "flexible-raft", "FlexibleRaft.cfg")
@@ -167,17 +167,8 @@ def test_tiered_canon_is_brute_force_over_120_permutations(setup, sample):
 
 @pytest.fixture(scope="module")
 def memo_canon_lowered(setup):
-    """Lowered text, with debug info, of the memoized canon at five
-    servers; nothing compiled or run."""
-    from raft_tpu.checker.lsm import CanonMemo
-    from raft_tpu.ops.symmetry import Canonicalizer
-
-    canon = Canonicalizer.for_model(setup.model, symmetry=True)
-    return jax.jit(canon.fingerprints_memo).lower(
-        jax.ShapeDtypeStruct((256, setup.model.layout.W), np.int32),
-        jax.ShapeDtypeStruct((256,), bool),
-        CanonMemo(1 << 8).reset(),
-    ).as_text(debug_info=True)
+    """Lowered text of the memoized canon at five servers."""
+    return lower_memo_canon(setup.model)
 
 
 @pytest.mark.parametrize(
@@ -190,14 +181,6 @@ def test_canon_scopes_nest_as_siblings_at_five_servers(
     assert f"/{scope}/" in memo_canon_lowered
     assert "memo/tier" not in memo_canon_lowered
     assert "/memo/while/" not in memo_canon_lowered
-
-
-def _eqns(jaxpr):
-    """Every equation of a jaxpr and of the jaxprs nested in it."""
-    for eqn in jaxpr.eqns:
-        yield eqn
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            yield from _eqns(sub)
 
 
 def test_tier12_looks_servers_up_without_a_gather(setup):
@@ -216,9 +199,9 @@ def test_tier12_looks_servers_up_without_a_gather(setup):
     closed = jax.make_jaxpr(
         lambda view: canon._tier_pre(view, canon._signatures(view)))(
         jax.ShapeDtypeStruct((B, canon.VL), np.int32))
-    names = collections.Counter(e.primitive.name for e in _eqns(closed.jaxpr))
+    names = collections.Counter(e.primitive.name for e in eqns(closed.jaxpr))
     assert names["select_n"] > 0 and names["reduce_sum"] > 0  # the walk sees in
-    from_table = [e for e in _eqns(closed.jaxpr)
+    from_table = [e for e in eqns(closed.jaxpr)
                   if e.primitive.name == "gather"
                   and e.invars[0].aval.shape == (B, S)]
     assert not from_table, [str(e.outvars[0].aval) for e in from_table]
