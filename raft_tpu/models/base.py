@@ -616,3 +616,13 @@ def messages_are_valid_kernel(layout: Layout, packer):
         return ~jnp.any(occ & (src == dst), axis=-1)
 
     return kernel
+
+
+def onehot_get2(arr, i, j):
+    """``arr[i, j]`` on a matrix ([S, S], or a server's log lanes
+    [S, L]) via one-hot: the read that mirrors onehot_set2. An index
+    outside its axis reads 0 where a gather would clamp, so callers
+    clip first."""
+    ohi = (jnp.arange(arr.shape[0], dtype=jnp.int32) == i)[:, None]
+    ohj = (jnp.arange(arr.shape[1], dtype=jnp.int32) == j)[None, :]
+    return jnp.sum(jnp.where(ohi & ohj, arr, 0))

@@ -30,7 +30,8 @@ import numpy as np
 from ..ops import bag
 from ..ops.packing import EMPTY
 from .base import (
-    ActionLabelMixin, SparseExpandMixin, onehot_add, onehot_set, onehot_set2,
+    ActionLabelMixin, SparseExpandMixin, onehot_add, onehot_get2, onehot_row,
+    onehot_set, onehot_set2,
 )
 
 # enums shared by both variants (identical values in both specs' lowerings)
@@ -122,8 +123,9 @@ class ConfigRaftCommon(SparseExpandMixin, ActionLabelMixin):
     @staticmethod
     def _last_term(d, i):
         """LastTerm — JointConsensus :252 / AddRemove :173."""
-        ll = d["log_len"][i]
-        return jnp.where(ll > 0, d["log_term"][i][jnp.clip(ll - 1, 0)], 0)
+        ll = onehot_row(d["log_len"], i)
+        return jnp.where(
+            ll > 0, onehot_get2(d["log_term"], i, jnp.clip(ll - 1, 0)), 0)
 
     @staticmethod
     def _popcount(x, S):
@@ -155,16 +157,16 @@ class ConfigRaftCommon(SparseExpandMixin, ActionLabelMixin):
         SendMultipleOnce."""
         p, S = self.p, self.p.n_servers
         d = self._dec(s)
-        st_i = d["state"][i]
-        members = d["config_members"][i]
+        st_i = onehot_row(d["state"], i)
+        members = onehot_row(d["config_members"], i)
         valid = (
             (d["electionCtr"] < p.max_elections)
             & ((st_i == FOLLOWER) | (st_i == CANDIDATE))
             & (((members >> i) & 1) > 0)
         )
-        new_term = d["currentTerm"][i] + 1
+        new_term = onehot_row(d["currentTerm"], i) + 1
         last_t = self._last_term(d, i)
-        ll_i = d["log_len"][i]
+        ll_i = onehot_row(d["log_len"], i)
         words, cnt = self._words(d), d["msg_cnt"]
         ovf = jnp.asarray(False)
         for delta in range(1, S):
@@ -199,14 +201,14 @@ class ConfigRaftCommon(SparseExpandMixin, ActionLabelMixin):
         :525-540 (acked gate + per-term valueCtr)."""
         p, L = self.p, self.p.max_log
         d = self._dec(s)
-        term = d["currentTerm"][i]
+        term = onehot_row(d["currentTerm"], i)
         tpos = jnp.clip(term - 1, 0, p.max_term - 1)
         valid = (
-            (d["state"][i] == LEADER)
-            & (d["acked"][v] == ACK_NIL)
-            & (d["valueCtr"][tpos] < p.max_values_per_term)
+            (onehot_row(d["state"], i) == LEADER)
+            & (onehot_row(d["acked"], v) == ACK_NIL)
+            & (onehot_row(d["valueCtr"], tpos) < p.max_values_per_term)
         )
-        pos = d["log_len"][i]
+        pos = onehot_row(d["log_len"], i)
         ovf = valid & (pos >= L)
         posc = jnp.clip(pos, 0, L - 1)
         succ = self._asm(
@@ -227,40 +229,45 @@ class ConfigRaftCommon(SparseExpandMixin, ActionLabelMixin):
         p = self.p
         L = p.max_log
         d = self._dec(s)
-        ni_ij = d["nextIndex"][i, j]
+        ni_ij = onehot_get2(d["nextIndex"], i, j)
+        pending_i = onehot_row(d["pendingResponse"], i)
         valid = (
-            (d["state"][i] == LEADER)
-            & (((d["config_members"][i] >> j) & 1) > 0)
+            (onehot_row(d["state"], i) == LEADER)
+            & (((onehot_row(d["config_members"], i) >> j) & 1) > 0)
             & (ni_ij >= 0)
-            & (((d["pendingResponse"][i] >> j) & 1) == 0)
+            & (((pending_i >> j) & 1) == 0)
         )
         prev_idx = ni_ij - 1
         prev_term = jnp.where(
-            prev_idx > 0, d["log_term"][i][jnp.clip(prev_idx - 1, 0, L - 1)], 0
+            prev_idx > 0,
+            onehot_get2(d["log_term"], i, jnp.clip(prev_idx - 1, 0, L - 1)),
+            0,
         )
-        last_entry = jnp.minimum(d["log_len"][i], ni_ij)
+        last_entry = jnp.minimum(onehot_row(d["log_len"], i), ni_ij)
         nent = (last_entry >= ni_ij).astype(jnp.int32)
         epos = jnp.clip(ni_ij - 1, 0, L - 1)
         z = jnp.int32(0)
         kw = dict(
             mtype=AEREQ,
-            mterm=d["currentTerm"][i],
+            mterm=onehot_row(d["currentTerm"], i),
             mprevLogIndex=jnp.clip(prev_idx, 0),
             mprevLogTerm=prev_term,
             nentries=nent,
-            mcommitIndex=jnp.clip(jnp.minimum(d["commitIndex"][i], last_entry), 0),
+            mcommitIndex=jnp.clip(
+                jnp.minimum(onehot_row(d["commitIndex"], i), last_entry), 0),
             msource=i,
             mdest=j,
         )
         for n in self.ENTRY_FIELDS:
-            kw[f"e_{n}"] = jnp.where(nent > 0, d[f"log_{n}"][i][epos], z)
+            kw[f"e_{n}"] = jnp.where(
+                nent > 0, onehot_get2(d[f"log_{n}"], i, epos), z)
         key = self._pack(**kw)
         words, cnt, existed, ovf = self._bag_put(self._words(d), d["msg_cnt"], key)
         valid &= (nent > 0) | ~existed  # empty AEReq is send-once
         succ = self._asm(
             d,
             pendingResponse=onehot_set(d["pendingResponse"], i,
-                d["pendingResponse"][i] | (jnp.int32(1) << j)
+                pending_i | (jnp.int32(1) << j)
             ),
             **self._word_upd(words, cnt),
         )
@@ -271,25 +278,28 @@ class ConfigRaftCommon(SparseExpandMixin, ActionLabelMixin):
         :862-878: embeds the whole log in the request."""
         p, L = self.p, self.p.max_log
         d = self._dec(s)
+        members = onehot_row(d["config_members"], i)
+        ll_i = onehot_row(d["log_len"], i)
         valid = (
-            (d["state"][i] == LEADER)
-            & (((d["config_members"][i] >> j) & 1) > 0)
-            & (d["nextIndex"][i, j] == PENDING_SNAP_REQUEST)
+            (onehot_row(d["state"], i) == LEADER)
+            & (((members >> j) & 1) > 0)
+            & (onehot_get2(d["nextIndex"], i, j) == PENDING_SNAP_REQUEST)
         )
         kw = dict(
             mtype=SNAPREQ,
-            mterm=d["currentTerm"][i],
-            mcommitIndex=d["commitIndex"][i],
-            mmembers=d["config_members"][i],
-            mloglen=d["log_len"][i],
+            mterm=onehot_row(d["currentTerm"], i),
+            mcommitIndex=onehot_row(d["commitIndex"], i),
+            mmembers=members,
+            mloglen=ll_i,
             msource=i,
             mdest=j,
         )
         lanes = jnp.arange(L, dtype=jnp.int32)
-        live = lanes < d["log_len"][i]
-        for k in range(L):
-            for n in self.ENTRY_FIELDS:
-                kw[f"l{k}_{n}"] = jnp.where(live[k], d[f"log_{n}"][i][k], 0)
+        live = lanes < ll_i
+        for n in self.ENTRY_FIELDS:
+            row = jnp.where(live, onehot_row(d[f"log_{n}"], i), 0)
+            for k in range(L):
+                kw[f"l{k}_{n}"] = row[k]
         key = self._pack(**kw)
         words, cnt, _existed, ovf = self._bag_put(self._words(d), d["msg_cnt"], key)
         succ = self._asm(
@@ -365,15 +375,17 @@ class ConfigRaftCommon(SparseExpandMixin, ActionLabelMixin):
         L = p.max_log
         d = self._dec(s)
         words, cnt = self._words(d), d["msg_cnt"]
-        key = [w[m] for w in words]
-        kcnt = cnt[m]
+        key = [onehot_row(w, m) for w in words]
+        kcnt = onehot_row(cnt, m)
         occupied = key[0] != EMPTY
         u = lambda n: self.packer.unpack(key, n)  # noqa: E731
         mtype, mterm = u("mtype"), u("mterm")
+        # in range for the one-hot reads below in every slot: an EMPTY
+        # word (bit WORD_BITS alone) decodes to 0 in every field
         src, dst = u("msource"), u("mdest")
-        cur = d["currentTerm"][dst]
-        st_dst = d["state"][dst]
-        member_dst = ((d["config_members"][dst] >> dst) & 1) > 0
+        cur = onehot_row(d["currentTerm"], dst)
+        st_dst = onehot_row(d["state"], dst)
+        member_dst = ((onehot_row(d["config_members"], dst) >> dst) & 1) > 0
         recv = occupied & (kcnt > 0)
         le_term = mterm <= cur
         eq_term = mterm == cur
@@ -392,15 +404,12 @@ class ConfigRaftCommon(SparseExpandMixin, ActionLabelMixin):
 
         # --- HandleRequestVoteRequest
         last_t = self._last_term(d, dst)
-        ll_dst = d["log_len"][dst]
+        ll_dst = onehot_row(d["log_len"], dst)
         rv_logok = (u("mlastLogTerm") > last_t) | (
             (u("mlastLogTerm") == last_t) & (u("mlastLogIndex") >= ll_dst)
         )
-        grant = (
-            eq_term
-            & rv_logok
-            & ((d["votedFor"][dst] == NIL) | (d["votedFor"][dst] == src + 1))
-        )
+        vf_dst = onehot_row(d["votedFor"], dst)
+        grant = eq_term & rv_logok & ((vf_dst == NIL) | (vf_dst == src + 1))
         b_rvreq = recv & (mtype == RVREQ) & le_term
         rv_key = self._pack(
             mtype=RVRESP,
@@ -415,7 +424,7 @@ class ConfigRaftCommon(SparseExpandMixin, ActionLabelMixin):
         vg = jnp.where(
             u("mvoteGranted") > 0,
             onehot_set(d["votesGranted"], dst,
-                d["votesGranted"][dst] | (jnp.int32(1) << src)
+                onehot_row(d["votesGranted"], dst) | (jnp.int32(1) << src)
             ),
             d["votesGranted"],
         )
@@ -425,8 +434,8 @@ class ConfigRaftCommon(SparseExpandMixin, ActionLabelMixin):
         prev_idx = u("mprevLogIndex")
         prev_term = u("mprevLogTerm")
         nent = u("nentries")
-        lt_row = d["log_term"][dst]
-        at_prev = lt_row[jnp.clip(prev_idx - 1, 0, L - 1)]
+        at_prev = onehot_get2(
+            d["log_term"], dst, jnp.clip(prev_idx - 1, 0, L - 1))
         ae_logok = jnp.where(
             nent > 0,
             (prev_idx > 0) & (prev_idx <= ll_dst) & (prev_term == at_prev),
@@ -473,7 +482,7 @@ class ConfigRaftCommon(SparseExpandMixin, ActionLabelMixin):
         app_pos = jnp.clip(prev_idx, 0, L - 1)
         new_logs = {}
         for n in self.ENTRY_FIELDS:
-            row = d[f"log_{n}"][dst]
+            row = onehot_row(d[f"log_{n}"], dst)
             nrow = onehot_set(jnp.where(keep, row, 0), app_pos,
                 jnp.where(appending, u(f"e_{n}"), 0)
             )
@@ -499,7 +508,7 @@ class ConfigRaftCommon(SparseExpandMixin, ActionLabelMixin):
         b_aeresp = recv & (mtype == AERESP) & eq_term & (st_dst == LEADER)
         res = u("mresult")
         mmatch = u("mmatchIndex")
-        ni_cur = d["nextIndex"][dst, src]
+        ni_cur = onehot_get2(d["nextIndex"], dst, src)
         ni_new = jnp.where(
             res == RC_OK,
             mmatch + 1,
@@ -538,7 +547,7 @@ class ConfigRaftCommon(SparseExpandMixin, ActionLabelMixin):
             recv
             & (mtype == SNAPRESP)
             & eq_term
-            & (d["nextIndex"][dst, src] == PENDING_SNAP_RESPONSE)
+            & (ni_cur == PENDING_SNAP_RESPONSE)
         )
 
         # --- shared Reply: put the branch-selected response once ---
@@ -593,7 +602,8 @@ class ConfigRaftCommon(SparseExpandMixin, ActionLabelMixin):
             pendingResponse=jnp.where(
                 b_aeresp,
                 onehot_set(d["pendingResponse"], dst,
-                    d["pendingResponse"][dst] & ~(jnp.int32(1) << src)),
+                    onehot_row(d["pendingResponse"], dst)
+                    & ~(jnp.int32(1) << src)),
                 d["pendingResponse"]),
             msg_cnt=jnp.where(putb, pc, jnp.where(dropb, cnt_disc, cnt)),
         )
@@ -748,24 +758,25 @@ class ConfigRaftCommon(SparseExpandMixin, ActionLabelMixin):
         p = self.p
         S, L, V = p.n_servers, p.max_log, p.n_values
         d = self._dec(s)
-        ll_i = d["log_len"][i]
-        ci_i = d["commitIndex"][i]
-        match_row = d["matchIndex"][i]
+        ll_i = onehot_row(d["log_len"], i)
+        ci_i = onehot_row(d["commitIndex"], i)
+        match_row = onehot_row(d["matchIndex"], i)
         idxs = jnp.arange(1, L + 1, dtype=jnp.int32)
         ks = jnp.arange(S, dtype=jnp.int32)
         quorum_ok = self._commit_quorum_ok(d, i, idxs, match_row, ks)
         is_agree = quorum_ok & (idxs <= ll_i)
         max_agree = jnp.max(jnp.where(is_agree, idxs, 0))
-        term_at = d["log_term"][i][jnp.clip(max_agree - 1, 0)]
+        term_at = onehot_get2(d["log_term"], i, jnp.clip(max_agree - 1, 0))
         new_ci = jnp.where(
-            (max_agree > 0) & (term_at == d["currentTerm"][i]), max_agree, ci_i
+            (max_agree > 0) & (term_at == onehot_row(d["currentTerm"], i)),
+            max_agree, ci_i,
         )
-        valid = (d["state"][i] == LEADER) & (ci_i < new_ci)
+        valid = (onehot_row(d["state"], i) == LEADER) & (ci_i < new_ci)
         lanes = jnp.arange(L, dtype=jnp.int32)
         in_range = (lanes + 1 > ci_i) & (lanes + 1 <= new_ci)
         # MayBeAckClient: only AppendCommand entries can ack a value
-        vals_row = jnp.where(d["log_cmd"][i] == self.CMD_APPEND,
-                             d["log_val"][i], 0)
+        vals_row = jnp.where(onehot_row(d["log_cmd"], i) == self.CMD_APPEND,
+                             onehot_row(d["log_val"], i), 0)
         committed = jnp.any(
             in_range[None, :]
             & (vals_row[None, :] == jnp.arange(1, V + 1, dtype=jnp.int32)[:, None]),

@@ -38,7 +38,8 @@ import numpy as np
 from ..ops import bag
 from ..ops.packing import EMPTY, WidePacker, bits_for
 from .base import (
-    Layout, messages_are_valid_kernel, onehot_add, onehot_set, onehot_set2,
+    Layout, messages_are_valid_kernel, onehot_add, onehot_get2, onehot_row,
+    onehot_set, onehot_set2,
 )
 
 from .config_common import (  # shared enums: single source of truth
@@ -311,18 +312,18 @@ class JointRaftModel(ConfigRaftCommon):
         old, new, members) of the latest config command."""
         L = self.p.max_log
         lanes = jnp.arange(L, dtype=jnp.int32)
-        cmd = d["log_cmd"][i]
+        cmd = onehot_row(d["log_cmd"], i)
         is_cfg = (cmd == CMD_OLDNEW) | (cmd == CMD_NEW)
-        mask = (lanes < d["log_len"][i]) & is_cfg
+        mask = (lanes < onehot_row(d["log_len"], i)) & is_cfg
         idx = jnp.max(jnp.where(mask, lanes + 1, 0))
         pos = jnp.clip(idx - 1, 0)
         return (
             idx,
-            cmd[pos],
-            d["log_cid"][i][pos],
-            d["log_old"][i][pos],
-            d["log_new"][i][pos],
-            d["log_members"][i][pos],
+            onehot_row(cmd, pos),
+            onehot_get2(d["log_cid"], i, pos),
+            onehot_get2(d["log_old"], i, pos),
+            onehot_get2(d["log_new"], i, pos),
+            onehot_get2(d["log_members"], i, pos),
         )
 
     def _config_for_upd(self, d, i, idx, cmd, cid, old, new, members, ci):
@@ -346,24 +347,25 @@ class JointRaftModel(ConfigRaftCommon):
         """BecomeLeader(i) — :511-528: dual quorums while joint."""
         S = self.p.n_servers
         d = self._dec(s)
-        vg = d["votesGranted"][i]
-        joint = d["config_joint"][i] > 0
-        members = d["config_members"][i]
-        old = d["config_old"][i]
-        new = d["config_new"][i]
+        vg = onehot_row(d["votesGranted"], i)
+        joint = onehot_row(d["config_joint"], i) > 0
+        members = onehot_row(d["config_members"], i)
+        old = onehot_row(d["config_old"], i)
+        new = onehot_row(d["config_new"], i)
         q_plain = ((vg & ~members) == 0) & (
             2 * self._popcount(vg, S) > self._popcount(members, S)
         )
         q_old = 2 * self._popcount(vg & old, S) > self._popcount(old, S)
         q_new = 2 * self._popcount(vg & new, S) > self._popcount(new, S)
-        valid = (d["state"][i] == CANDIDATE) & jnp.where(
+        valid = (onehot_row(d["state"], i) == CANDIDATE) & jnp.where(
             joint, q_old & q_new, q_plain
         )
         succ = self._asm(
             d,
             state=onehot_set(d["state"], i, LEADER),
             nextIndex=onehot_set(d["nextIndex"], i,
-                jnp.full((S,), 1, jnp.int32) * (d["log_len"][i] + 1)
+                jnp.full((S,), 1, jnp.int32)
+                * (onehot_row(d["log_len"], i) + 1)
             ),
             matchIndex=onehot_set(d["matchIndex"], i, jnp.zeros((S,), jnp.int32)),
             pendingResponse=onehot_set(d["pendingResponse"], i, 0),
@@ -373,7 +375,7 @@ class JointRaftModel(ConfigRaftCommon):
     def _commit_quorum_ok(self, d, i, idxs, match_row, ks):
         """Dual-quorum agreement while joint (:626-629)."""
         S = self.p.n_servers
-        joint = d["config_joint"][i] > 0
+        joint = onehot_row(d["config_joint"], i) > 0
 
         def quorum_over(member_mask):
             member_k = ((member_mask >> ks) & 1) > 0
@@ -382,8 +384,9 @@ class JointRaftModel(ConfigRaftCommon):
             )
             return 2 * jnp.sum(in_agree, axis=1) > self._popcount(member_mask, S)
 
-        q_plain = quorum_over(d["config_members"][i])
-        q_joint = quorum_over(d["config_old"][i]) & quorum_over(d["config_new"][i])
+        q_plain = quorum_over(onehot_row(d["config_members"], i))
+        q_joint = quorum_over(onehot_row(d["config_old"], i)) & quorum_over(
+            onehot_row(d["config_new"], i))
         return jnp.where(joint, q_joint, q_plain)
 
     def _commit_config_upd(self, d, i, new_ci) -> dict:
@@ -396,8 +399,8 @@ class JointRaftModel(ConfigRaftCommon):
         """IsRemovedFromCluster (:606-611): NewConfigCommand without i."""
         return jnp.any(
             in_range
-            & (d["log_cmd"][i] == CMD_NEW)
-            & (((d["log_members"][i] >> i) & 1) == 0)
+            & (onehot_row(d["log_cmd"], i) == CMD_NEW)
+            & (((onehot_row(d["log_members"], i) >> i) & 1) == 0)
         )
 
     def _append_old_new(self, s, i, add_mask, rem_mask):
@@ -405,13 +408,14 @@ class JointRaftModel(ConfigRaftCommon):
         pair — :827-856."""
         p, S, L = self.p, self.p.n_servers, self.p.max_log
         d = self._dec(s)
-        members = d["config_members"][i]
+        members = onehot_row(d["config_members"], i)
         add_m = jnp.int32(add_mask)
         rem_m = jnp.int32(rem_mask)
         # HasPendingConfigCommand (:246-248)
-        pending = (d["config_committed"][i] == 0) | (d["config_joint"][i] > 0)
+        pending = (onehot_row(d["config_committed"], i) == 0) | (
+            onehot_row(d["config_joint"], i) > 0)
         valid = (
-            (d["state"][i] == LEADER)
+            (onehot_row(d["state"], i) == LEADER)
             & (d["reconfigCtr"] < p.max_reconfigs)
             & ~pending
             & ((add_m & members) == 0)  # addMembers disjoint (:834)
@@ -421,18 +425,21 @@ class JointRaftModel(ConfigRaftCommon):
         new = (members & ~rem_m) | add_m
         joint_members = members | add_m
         new_id = d["reconfigCtr"] + 1  # id = reconfigCtr + 1 (:839)
-        pos = d["log_len"][i]
+        ci_i = onehot_row(d["commitIndex"], i)
+        pos = onehot_row(d["log_len"], i)
         ovf = valid & (pos >= L)
         posc = jnp.clip(pos, 0, L - 1)
         # nextIndex := PendingSnapshotRequest for s in new \ old (:849-853)
         ks = jnp.arange(S, dtype=jnp.int32)
         fresh = (((new >> ks) & 1) > 0) & (((old >> ks) & 1) == 0)
         ni_row = jnp.where(
-            fresh, jnp.int32(PENDING_SNAP_REQUEST), d["nextIndex"][i]
+            fresh, jnp.int32(PENDING_SNAP_REQUEST),
+            onehot_row(d["nextIndex"], i),
         )
         succ = self._asm(
             d,
-            log_term=onehot_set2(d["log_term"], i, posc, d["currentTerm"][i]),
+            log_term=onehot_set2(
+                d["log_term"], i, posc, onehot_row(d["currentTerm"], i)),
             log_cmd=onehot_set2(d["log_cmd"], i, posc, CMD_OLDNEW),
             log_cid=onehot_set2(d["log_cid"], i, posc, new_id),
             log_old=onehot_set2(d["log_old"], i, posc, old),
@@ -445,7 +452,7 @@ class JointRaftModel(ConfigRaftCommon):
             config_old=onehot_set(d["config_old"], i, old),
             config_new=onehot_set(d["config_new"], i, new),
             config_committed=onehot_set(d["config_committed"], i,
-                (d["commitIndex"][i] >= pos + 1).astype(jnp.int32)
+                (ci_i >= pos + 1).astype(jnp.int32)
             ),
             reconfigCtr=d["reconfigCtr"] + 1,
             nextIndex=onehot_set(d["nextIndex"], i, ni_row),
@@ -458,8 +465,9 @@ class JointRaftModel(ConfigRaftCommon):
         p, L = self.p, self.p.max_log
         d = self._dec(s)
         lanes = jnp.arange(L, dtype=jnp.int32)
-        cmd_row = d["log_cmd"][i]
-        ll_i = d["log_len"][i]
+        cmd_row = onehot_row(d["log_cmd"], i)
+        ll_i = onehot_row(d["log_len"], i)
+        ci_i = onehot_row(d["commitIndex"], i)
         in_log = lanes < ll_i
         is_oldnew = in_log & (cmd_row == CMD_OLDNEW)
         is_new = in_log & (cmd_row == CMD_NEW)
@@ -468,19 +476,20 @@ class JointRaftModel(ConfigRaftCommon):
         # CommittedOldNewWithoutNew (:232-242)
         qualifies = (
             (last_oldnew > 0)
-            & (d["commitIndex"][i] >= last_oldnew)
+            & (ci_i >= last_oldnew)
             & (last_new < last_oldnew)
         )
-        valid = (d["state"][i] == LEADER) & qualifies
+        valid = (onehot_row(d["state"], i) == LEADER) & qualifies
         tpos = jnp.clip(last_oldnew - 1, 0)
-        new_members = d["log_new"][i][tpos]
-        new_id = d["log_cid"][i][tpos]
+        new_members = onehot_get2(d["log_new"], i, tpos)
+        new_id = onehot_get2(d["log_cid"], i, tpos)
         pos = ll_i
         ovf = valid & (pos >= L)
         posc = jnp.clip(pos, 0, L - 1)
         succ = self._asm(
             d,
-            log_term=onehot_set2(d["log_term"], i, posc, d["currentTerm"][i]),
+            log_term=onehot_set2(
+                d["log_term"], i, posc, onehot_row(d["currentTerm"], i)),
             log_cmd=onehot_set2(d["log_cmd"], i, posc, CMD_NEW),
             log_cid=onehot_set2(d["log_cid"], i, posc, new_id),
             log_members=onehot_set2(d["log_members"], i, posc, new_members),
@@ -491,7 +500,7 @@ class JointRaftModel(ConfigRaftCommon):
             config_old=onehot_set(d["config_old"], i, 0),
             config_new=onehot_set(d["config_new"], i, 0),
             config_committed=onehot_set(d["config_committed"], i,
-                (d["commitIndex"][i] >= pos + 1).astype(jnp.int32)
+                (ci_i >= pos + 1).astype(jnp.int32)
             ),
         )
         return valid, succ, jnp.int32(J_APPEND_NEW), ovf
@@ -508,18 +517,19 @@ class JointRaftModel(ConfigRaftCommon):
         members, old/new sets, committed watermark; in_new = membership
         of dst in the installed config's member set."""
         z = jnp.int32(0)
-        cfg_cmd = logs["cmd"][cfg_pos]
+        cfg_cmd = onehot_row(logs["cmd"], cfg_pos)
         cfg_joint = (cfg_cmd == CMD_OLDNEW).astype(jnp.int32)
-        cfg_members = logs["members"][cfg_pos]
+        cfg_members = onehot_row(logs["members"], cfg_pos)
         upd = dict(
-            config_id=onehot_set(d["config_id"], dst, logs["cid"][cfg_pos]),
+            config_id=onehot_set(
+                d["config_id"], dst, onehot_row(logs["cid"], cfg_pos)),
             config_joint=onehot_set(d["config_joint"], dst, cfg_joint),
             config_members=onehot_set(d["config_members"], dst, cfg_members),
             config_old=onehot_set(d["config_old"], dst,
-                jnp.where(cfg_joint > 0, logs["old"][cfg_pos], z)
+                jnp.where(cfg_joint > 0, onehot_row(logs["old"], cfg_pos), z)
             ),
             config_new=onehot_set(d["config_new"], dst,
-                jnp.where(cfg_joint > 0, logs["new"][cfg_pos], z)
+                jnp.where(cfg_joint > 0, onehot_row(logs["new"], cfg_pos), z)
             ),
             config_committed=onehot_set(d["config_committed"], dst,
                 (mci >= cfg_idx).astype(jnp.int32)

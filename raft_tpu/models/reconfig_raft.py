@@ -40,7 +40,8 @@ import numpy as np
 from ..ops import bag
 from ..ops.packing import EMPTY, WidePacker, bits_for
 from .base import (
-    Layout, messages_are_valid_kernel, onehot_add, onehot_set, onehot_set2,
+    Layout, messages_are_valid_kernel, onehot_add, onehot_get2, onehot_row,
+    onehot_set, onehot_set2,
 )
 
 from .config_common import (  # shared enums: single source of truth
@@ -293,12 +294,16 @@ class ReconfigRaftModel(ConfigRaftCommon):
         on reachability, member logs always carry InitClusterCommand)."""
         L = self.p.max_log
         lanes = jnp.arange(L, dtype=jnp.int32)
-        cmd = d["log_cmd"][i]
+        cmd = onehot_row(d["log_cmd"], i)
         is_cfg = (cmd == CMD_INIT) | (cmd == CMD_ADD) | (cmd == CMD_REMOVE)
-        mask = (lanes < d["log_len"][i]) & is_cfg
+        mask = (lanes < onehot_row(d["log_len"], i)) & is_cfg
         idx = jnp.max(jnp.where(mask, lanes + 1, 0))
         pos = jnp.clip(idx - 1, 0)
-        return idx, d["log_cid"][i][pos], d["log_cmembers"][i][pos]
+        return (
+            idx,
+            onehot_get2(d["log_cid"], i, pos),
+            onehot_get2(d["log_cmembers"], i, pos),
+        )
 
     # ---------------- action kernels ----------------
 
@@ -307,16 +312,16 @@ class ReconfigRaftModel(ConfigRaftCommon):
         member set (subset + majority)."""
         S = self.p.n_servers
         d = self._dec(s)
-        members = d["config_members"][i]
-        vg = d["votesGranted"][i]
+        members = onehot_row(d["config_members"], i)
+        vg = onehot_row(d["votesGranted"], i)
         subset = (vg & ~members) == 0
         quorum = 2 * self._popcount(vg, S) > self._popcount(members, S)
-        valid = (d["state"][i] == CANDIDATE) & subset & quorum
+        valid = (onehot_row(d["state"], i) == CANDIDATE) & subset & quorum
         succ = self._asm(
             d,
             state=onehot_set(d["state"], i, LEADER),
             nextIndex=onehot_set(d["nextIndex"], i,
-                jnp.full((S,), 1, jnp.int32) * (d["log_len"][i] + 1)
+                jnp.full((S,), 1, jnp.int32) * (onehot_row(d["log_len"], i) + 1)
             ),
             matchIndex=onehot_set(d["matchIndex"], i, jnp.zeros((S,), jnp.int32)),
             pendingResponse=onehot_set(d["pendingResponse"], i, 0),
@@ -326,7 +331,7 @@ class ReconfigRaftModel(ConfigRaftCommon):
     def _commit_quorum_ok(self, d, i, idxs, match_row, ks):
         """Member-set quorum with leader self-inclusion (:612-618)."""
         S = self.p.n_servers
-        members = d["config_members"][i]
+        members = onehot_row(d["config_members"], i)
         member_k = ((members >> ks) & 1) > 0  # [S]
         in_agree = member_k[None, :] & (
             (match_row[None, :] >= idxs[:, None]) | (ks[None, :] == i)
@@ -347,39 +352,42 @@ class ReconfigRaftModel(ConfigRaftCommon):
         """IsRemovedFromCluster (:598-603)."""
         return jnp.any(
             in_range
-            & (d["log_cmd"][i] == CMD_REMOVE)
-            & (((d["log_cmembers"][i] >> i) & 1) == 0)
+            & (onehot_row(d["log_cmd"], i) == CMD_REMOVE)
+            & (((onehot_row(d["log_cmembers"], i) >> i) & 1) == 0)
         )
 
     def _append_add(self, s, i, a):
         """AppendAddServerCommandToLog(i, a) — :795-824."""
         p, S, L = self.p, self.p.n_servers, self.p.max_log
         d = self._dec(s)
-        members = d["config_members"][i]
+        members = onehot_row(d["config_members"], i)
+        term_i = onehot_row(d["currentTerm"], i)
+        ci_i = onehot_row(d["commitIndex"], i)
         valid = (
-            (d["state"][i] == LEADER)
+            (onehot_row(d["state"], i) == LEADER)
             & (d["addReconfigCtr"] < p.max_add_reconfigs)
             & (self._popcount(members, S) < p.max_cluster_size)
-            & (d["config_committed"][i] > 0)  # ~HasPendingConfigCommand (:248)
+            # ~HasPendingConfigCommand (:248)
+            & (onehot_row(d["config_committed"], i) > 0)
             & (((members >> a) & 1) == 0)
         )
         if not p.include_thesis_bug:
             # LeaderHasCommittedEntriesInCurrentTerm (:275-278)
             lanes = jnp.arange(L, dtype=jnp.int32)
             has_committed = jnp.any(
-                (lanes < d["log_len"][i])
-                & (d["log_term"][i] == d["currentTerm"][i])
-                & (d["commitIndex"][i] >= lanes + 1)
+                (lanes < onehot_row(d["log_len"], i))
+                & (onehot_row(d["log_term"], i) == term_i)
+                & (ci_i >= lanes + 1)
             )
             valid &= has_committed
         new_members = members | (jnp.int32(1) << a)
-        new_id = d["config_id"][i] + 1
-        pos = d["log_len"][i]
+        new_id = onehot_row(d["config_id"], i) + 1
+        pos = onehot_row(d["log_len"], i)
         ovf = valid & (pos >= L)
         posc = jnp.clip(pos, 0, L - 1)
         succ = self._asm(
             d,
-            log_term=onehot_set2(d["log_term"], i, posc, d["currentTerm"][i]),
+            log_term=onehot_set2(d["log_term"], i, posc, term_i),
             log_cmd=onehot_set2(d["log_cmd"], i, posc, CMD_ADD),
             log_cid=onehot_set2(d["log_cid"], i, posc, new_id),
             log_cmem=onehot_set2(d["log_cmem"], i, posc, a + 1),
@@ -389,7 +397,7 @@ class ReconfigRaftModel(ConfigRaftCommon):
             config_members=onehot_set(d["config_members"], i, new_members),
             # committed = ci >= Len(newLog) — always FALSE here (:814-816)
             config_committed=onehot_set(d["config_committed"], i,
-                (d["commitIndex"][i] >= pos + 1).astype(jnp.int32)
+                (ci_i >= pos + 1).astype(jnp.int32)
             ),
             addReconfigCtr=d["addReconfigCtr"] + 1,
             nextIndex=onehot_set2(d["nextIndex"], i, a, PENDING_SNAP_REQUEST),
@@ -400,30 +408,32 @@ class ReconfigRaftModel(ConfigRaftCommon):
         """AppendRemoveServerCommandToLog(i, r) — :828-853."""
         p, S, L = self.p, self.p.n_servers, self.p.max_log
         d = self._dec(s)
-        members = d["config_members"][i]
+        members = onehot_row(d["config_members"], i)
+        term_i = onehot_row(d["currentTerm"], i)
+        ci_i = onehot_row(d["commitIndex"], i)
         valid = (
-            (d["state"][i] == LEADER)
+            (onehot_row(d["state"], i) == LEADER)
             & (d["removeReconfigCtr"] < p.max_remove_reconfigs)
             & (self._popcount(members, S) > p.min_cluster_size)
-            & (d["config_committed"][i] > 0)
+            & (onehot_row(d["config_committed"], i) > 0)
             & (((members >> r) & 1) > 0)
         )
         if not p.include_thesis_bug:
             lanes = jnp.arange(L, dtype=jnp.int32)
             has_committed = jnp.any(
-                (lanes < d["log_len"][i])
-                & (d["log_term"][i] == d["currentTerm"][i])
-                & (d["commitIndex"][i] >= lanes + 1)
+                (lanes < onehot_row(d["log_len"], i))
+                & (onehot_row(d["log_term"], i) == term_i)
+                & (ci_i >= lanes + 1)
             )
             valid &= has_committed
         new_members = members & ~(jnp.int32(1) << r)
-        new_id = d["config_id"][i] + 1
-        pos = d["log_len"][i]
+        new_id = onehot_row(d["config_id"], i) + 1
+        pos = onehot_row(d["log_len"], i)
         ovf = valid & (pos >= L)
         posc = jnp.clip(pos, 0, L - 1)
         succ = self._asm(
             d,
-            log_term=onehot_set2(d["log_term"], i, posc, d["currentTerm"][i]),
+            log_term=onehot_set2(d["log_term"], i, posc, term_i),
             log_cmd=onehot_set2(d["log_cmd"], i, posc, CMD_REMOVE),
             log_cid=onehot_set2(d["log_cid"], i, posc, new_id),
             log_cmem=onehot_set2(d["log_cmem"], i, posc, r + 1),
@@ -432,7 +442,7 @@ class ReconfigRaftModel(ConfigRaftCommon):
             config_id=onehot_set(d["config_id"], i, new_id),
             config_members=onehot_set(d["config_members"], i, new_members),
             config_committed=onehot_set(d["config_committed"], i,
-                (d["commitIndex"][i] >= pos + 1).astype(jnp.int32)
+                (ci_i >= pos + 1).astype(jnp.int32)
             ),
             removeReconfigCtr=d["removeReconfigCtr"] + 1,
         )
@@ -450,11 +460,11 @@ class ReconfigRaftModel(ConfigRaftCommon):
         exists = jnp.any(is_cur_leader)
         leader = jnp.argmax(is_cur_leader)  # lowest index
         valid = (
-            (ct[i] > 0)
+            (onehot_row(ct, i) > 0)
             & exists
             & (leader != i)
-            & (((d["config_members"][leader] >> i) & 1) == 0)
-            & (d["config_committed"][leader] > 0)
+            & (((onehot_row(d["config_members"], leader) >> i) & 1) == 0)
+            & (onehot_row(d["config_committed"], leader) > 0)
         )
         L = p.max_log
         succ = self._asm(
@@ -491,9 +501,10 @@ class ReconfigRaftModel(ConfigRaftCommon):
         """Config cache from the most recent config entry (:734-739):
         id, member set, committed watermark; in_new = membership of dst
         in the installed member set."""
-        cmembers = logs["cmembers"][cfg_pos]
+        cmembers = onehot_row(logs["cmembers"], cfg_pos)
         upd = dict(
-            config_id=onehot_set(d["config_id"], dst, logs["cid"][cfg_pos]),
+            config_id=onehot_set(
+                d["config_id"], dst, onehot_row(logs["cid"], cfg_pos)),
             config_members=onehot_set(d["config_members"], dst, cmembers),
             config_committed=onehot_set(d["config_committed"], dst,
                 (mci >= cfg_idx).astype(jnp.int32)

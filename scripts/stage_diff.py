@@ -39,9 +39,15 @@ that is the reproducer of the dropped writes: the dense expand equal, the
 sparse expand's rows wrong in HandleMessage's log lanes (PERF.md section
 6, PR 30). Without it the same command reads ALL STAGES EQUAL.
 
+``--gather`` does the same for the reads PR 31 took out: ``onehot_row``
+and ``onehot_get2`` become ``arr[i]`` and ``arr[i, j]`` (``--gather
+get2`` swaps only that one), on both sides: the old reads against the
+CPU, and with ``scripts/stage_split.py --gather`` timed, from one tree.
+
     python scripts/stage_diff.py configs/standard-raft/RaftWithReconfigJointConsensus.cfg
         [--msg-slots N] [--chunk 1024] [--depth 3] [--cap 400]
-        [--scatter [set,set2,add]] [--platform cpu] [--out chiprun_out/stage_diff]
+        [--scatter [set,set2,add]] [--gather [row,get2]] [--platform cpu]
+        [--out chiprun_out/stage_diff]
 """
 
 import argparse
@@ -61,18 +67,34 @@ SCATTER_FORMS = {
 }
 
 
-def scatter_writes(which="set,set2,add"):
-    """Swap the named one-hot write helpers for the scatters they
-    replaced, in ``models/base.py`` and in every module that imported
-    them."""
+GATHER_FORMS = {
+    "row": ("onehot_row", lambda arr, i: arr[i]),
+    "get2": ("onehot_get2", lambda arr, i, j: arr[i, j]),
+}
+
+
+def _put_back(forms, which):
     import raft_tpu.models.registry  # noqa: F401  (loads every lowering)
 
     for name, mod in list(sys.modules.items()):
         if name.startswith("raft_tpu.models."):
             for key in which.split(","):
-                helper, form = SCATTER_FORMS[key]
+                helper, form = forms[key]
                 if hasattr(mod, helper):
                     setattr(mod, helper, form)
+
+
+def scatter_writes(which="set,set2,add"):
+    """Swap the named one-hot write helpers for the scatters they
+    replaced, in ``models/base.py`` and in every module that imported
+    them."""
+    _put_back(SCATTER_FORMS, which)
+
+
+def gather_reads(which="row,get2"):
+    """The same for the one-hot read helpers and the gathers they
+    replaced (``raft.py``'s HandleMessage reads by ``onehot_row`` too)."""
+    _put_back(GATHER_FORMS, which)
 
 
 def levels_of(oracle, depth, cap):
@@ -112,6 +134,8 @@ def stages(args, ref):
 
     if args.scatter:
         scatter_writes(args.scatter)
+    if args.gather:
+        gather_reads(args.gather)
     setup = build_from_cfg(parse_cfg(args.cfg), msg_slots=args.msg_slots)
     model = setup.model
     levels = levels_of(oracle_for_setup(setup), args.depth, args.cap)
@@ -248,6 +272,9 @@ def main(argv=None) -> int:
                     default=None, metavar="HELPERS",
                     help="set, set2, add or a comma list; all three "
                          "when given bare")
+    ap.add_argument("--gather", nargs="?", const="row,get2", default=None,
+                    metavar="HELPERS",
+                    help="row, get2 or a comma list; both when given bare")
     ap.add_argument("--platform", choices=("cpu",), default=None)
     ap.add_argument("--out", default=os.path.join(
         ROOT, "chiprun_out", "stage_diff"))

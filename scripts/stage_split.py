@@ -12,8 +12,13 @@ raft3, else the cell's own depth). Chip only in earnest; ``--platform
 cpu`` rehearses.
 
     python scripts/stage_split.py [--workload flexraft5-wide]
-        [--depth 14 20] [--platform cpu]
+        [--depth 14 20] [--top 16] [--gather] [--platform cpu]
         [--out chiprun_out/stage_split.json]
+
+``--top N`` keeps the N heaviest ops; ``--gather`` puts the per-lane
+reads back behind ``models/base.py``'s one-hot read helpers
+(``scripts/stage_diff.py``'s switch), so old reads and new are timed from
+one tree.
 
 ``--trace-dir DIR`` builds no engine: it reduces the newest trace under
 ``DIR`` (what ``python -m raft_tpu CFG --trace-dir DIR`` wrote) to the
@@ -35,9 +40,9 @@ sys.path.insert(0, ROOT)
 _CONTROL = ("while", "body", "cond", "closed_call")
 
 
-def split(path):
+def split(path, top=16):
     """Seconds of device self time by scope path two levels deep, and the
-    heaviest (op, name stack) pairs, of one .xplane.pb."""
+    ``top`` heaviest (op, name stack) pairs, of one .xplane.pb."""
     from benchmark import xplane, xspace
     from benchmark.readers import scope_time
 
@@ -74,17 +79,17 @@ def split(path):
     per = 1e9 * max(1, n_planes)
     return {
         "by_scope_s": {k: ns / per for k, ns in by_scope.most_common()},
-        "top_ops_s": [[*k, ns / per] for k, ns in by_op.most_common(16)],
+        "top_ops_s": [[*k, ns / per] for k, ns in by_op.most_common(top)],
     }
 
 
-def report(trace_dir):
+def report(trace_dir, top=16):
     """``split`` of the newest trace under ``trace_dir`` and the device's
     busy seconds, printed as a table."""
     from benchmark import xplane
 
     path = xplane.find_xplane(trace_dir)
-    res = split(path)
+    res = split(path, top)
     res["busy_s"] = busy = xplane.busy_s(xplane.load(path))
     for scope, s in [*res["by_scope_s"].items(), ("busy", busy)]:
         print(f"{scope:<22}{s:>12.6f} s{s / busy:>8.1%}")
@@ -98,12 +103,21 @@ def main(argv=None):
     ap.add_argument("--depth", type=int, nargs="*", default=None)
     ap.add_argument("--out", default=os.path.join(
         ROOT, "chiprun_out", "stage_split.json"))
+    ap.add_argument("--top", type=int, default=16,
+                    help="how many of the heaviest ops to keep")
+    ap.add_argument("--gather", action="store_true",
+                    help="time the reads as they were before PR 31 "
+                         "(stage_diff.gather_reads)")
     ap.add_argument("--platform", default=None)
     args = ap.parse_args(argv)
     if args.platform:
         os.environ["JAX_PLATFORMS"] = args.platform
     if args.trace_dir:
-        return print(json.dumps(report(args.trace_dir)))
+        return print(json.dumps(report(args.trace_dir, args.top)))
+    if args.gather:
+        from scripts import stage_diff
+
+        stage_diff.gather_reads()
 
     import jax
 
@@ -139,7 +153,7 @@ def main(argv=None):
         finally:
             jax.profiler.stop_trace()
         path = xplane.find_xplane(tdir)
-        res = split(path)
+        res = split(path, args.top)
         res["busy_s"] = xplane.busy_s(xplane.load(path))
         res["distinct"] = got["distinct"]
         res["dedup_plan"] = got["stats"].get("dedup_plan")

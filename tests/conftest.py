@@ -48,25 +48,56 @@ def eqns(jaxpr):
             yield from eqns(sub)
 
 
-def scatter_kernels(model):
-    """{binding name: scatter equations} of the per-action kernels that
-    write through a scatter, from their jaxprs as the sparse apply
-    calls them (one state row, scalar bindings); nothing compiled."""
+def _kernel_primitives(model, batch=None):
+    """(binding name, primitive names) of each per-action kernel, from
+    its jaxpr as the sparse apply calls it: one state row and scalar
+    bindings, or with ``batch`` under the worklist's vmap (where a read
+    by a traced index is a gather); nothing compiled."""
     import numpy as np
 
-    row = jax.ShapeDtypeStruct((model.layout.W,), np.int32)
-    scalar = jax.ShapeDtypeStruct((), np.int32)
+    lead = () if batch is None else (batch,)
+    row = jax.ShapeDtypeStruct(lead + (model.layout.W,), np.int32)
+    arg = jax.ShapeDtypeStruct(lead, np.int32)
     groups = model.sparse_groups()
     assert sum(g.n for g in groups) == model.A
-    found = {}
     for g in groups:
-        closed = jax.make_jaxpr(model.kernel_for(g.name))(
-            row, *[scalar] * g.params.shape[1])
+        kern = model.kernel_for(g.name)
+        closed = jax.make_jaxpr(kern if batch is None else jax.vmap(kern))(
+            row, *[arg] * g.params.shape[1])
         names = [e.primitive.name for e in eqns(closed.jaxpr)]
         assert "select_n" in names  # the walk sees in
+        yield g.name, names
+
+
+def scatter_kernels(model):
+    """{binding name: scatter equations} of the per-action kernels that
+    write through a scatter."""
+    found = {}
+    for kernel, names in _kernel_primitives(model):
         n = sum(name.startswith("scatter") for name in names)
         if n:
-            found[g.name] = n
+            found[kernel] = n
+    return found
+
+
+def gather_kernels(model, batch=8):
+    """{binding name: gather equations} of the per-action kernels that
+    read through a gather under the worklist's vmap, and under "guards"
+    those of the guard pass as the engines run it, ``vmap(guards1)``
+    over a chunk (a read by the inner vmap's iota is a gather there all
+    the same)."""
+    import numpy as np
+
+    found = {}
+    for kernel, names in _kernel_primitives(model, batch):
+        n = names.count("gather")
+        if n:
+            found[kernel] = n
+    closed = jax.make_jaxpr(jax.vmap(model.guards1))(
+        jax.ShapeDtypeStruct((batch, model.layout.W), np.int32))
+    n = [e.primitive.name for e in eqns(closed.jaxpr)].count("gather")
+    if n:
+        found["guards"] = n
     return found
 
 

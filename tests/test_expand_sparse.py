@@ -30,7 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import scatter_kernels
+from conftest import collect_states, gather_kernels, scatter_kernels
 from raft_tpu.checker.bfs import BFSChecker
 from raft_tpu.checker.device_bfs import DeviceBFS
 from raft_tpu.parallel.sharded import ShardedBFS
@@ -225,6 +225,74 @@ def test_no_kernel_writes_through_a_dynamic_index_scatter(family):
     assert scatter_kernels(FAMILIES[family]()) == {}
 
 
+# Kernels that still read `d[f][i]` with a binding, a decoded server or
+# a log position as the index: under the worklist's vmap, and in the
+# guard pass under the chunk's, a per-lane gather. On the v5e those were
+# 8-12 ns an index and most of `expand` on joint4 (PR 31); the two
+# config_common lowerings read by one-hot selects since
+# (`models/base.py::onehot_row`, `onehot_get2`). Counts at this file's
+# shapes, the guard pass's in brackets. strict, as above.
+GATHER_DEBT = {
+    "raft": "62 (29): RequestVote, BecomeLeader, ClientRequest, "
+            "AdvanceCommitIndex, AppendEntries, 3 in HandleMessage, "
+            "which reads by one-hot since round 5 (ROADMAP D14)",
+    "pull_raft": "86 (42), 21 of them in HandleMessage",
+    "kraft": "131 (58), 50 of them in HandleMessage",
+    "kraft_reconfig": "326 (127), 130 of them in HandleMessage",
+}
+
+
+@pytest.mark.parametrize("family", [
+    pytest.param(f, marks=pytest.mark.xfail(
+        strict=True, reason=GATHER_DEBT[f])) if f in GATHER_DEBT else f
+    for f in sorted(FAMILIES)])
+def test_no_kernel_reads_through_a_dynamic_index_gather(family):
+    """No per-action kernel of the family, traced under the worklist's
+    vmap, and no guard pass over a chunk holds a gather (`sparse_apply`'s
+    own row and binding gathers are outside the kernels; nothing
+    compiled)."""
+    assert gather_kernels(FAMILIES[family]()) == {}
+
+
+@pytest.mark.parametrize("family", ["joint_raft", "reconfig_raft"])
+def test_one_hot_reads_match_the_oracle_on_empty_slots_and_logs(family):
+    """A one-hot read of an index outside its axis yields 0 where the
+    gather it replaced clamped. The first levels from Init are where
+    such indices would come from: most bag slots EMPTY (every field of
+    the word decodes to 0), a server outside the cluster with an empty
+    log (`ll - 1` is -1). Per state, the enabled candidates' (action,
+    successor) pairs equal the oracle's, none overflows, and no
+    candidate on an EMPTY slot is enabled."""
+    from types import SimpleNamespace
+
+    from raft_tpu.models.registry import oracle_for_setup
+    from raft_tpu.ops.packing import EMPTY
+
+    model = FAMILIES[family]()
+    oracle = oracle_for_setup(SimpleNamespace(model=model))
+    states = collect_states(oracle, max_depth=3, cap=40)
+    vecs = np.stack([model.encode(st) for st in states]).astype(np.int32)
+    lay = model.layout
+    empty = lay.get(vecs, "msg_w0") == int(EMPTY)
+    assert empty.any(axis=1).all() and (~empty).any()
+    assert (lay.get(vecs, "log_len") == 0).any()
+    succs, valid, rank, ovf = jax.device_get(
+        jax.jit(jax.vmap(model._expand1))(vecs))
+    assert not np.any(valid & ovf)
+    slot0 = model.A - model.p.msg_slots  # HandleMessage(m) comes last
+    assert model.bindings[slot0] == ("HandleMessage", (0,))
+    assert not np.any(valid[:, slot0:] & empty)
+    assert np.all(rank[:, slot0:][empty] == -1)
+    for b, st in enumerate(states):
+        got = sorted(
+            (model.ACTION_NAMES[rank[b, a]],
+             oracle.serialize_full(model.decode(succs[b, a])))
+            for a in np.nonzero(valid[b])[0])
+        want = sorted((label.split("(")[0], oracle.serialize_full(s2))
+                      for label, s2 in oracle.successors(st))
+        assert got == want, f"state {b}"
+
+
 def _stage_diff(*argv):
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     return subprocess.run(
@@ -270,6 +338,21 @@ def test_stage_diff_scatter_puts_the_scatters_back():
     assert r.returncode == 0, r.stderr[-2000:]
     names, total = r.stdout.strip().rsplit(" ", 1)
     assert "HandleMessage" in names and int(total) > 40, r.stdout
+
+
+def test_stage_diff_gather_puts_the_gathers_back():
+    """--gather is the same switch for the reads: the one-hot read
+    helpers become `arr[i]` and `arr[i, j]` again, so old reads can be
+    timed and diffed against new ones on the chip from one tree."""
+    r = _stage_diff("-c", (
+        "import stage_diff, conftest, test_expand_sparse as t\n"
+        "stage_diff.gather_reads()\n"
+        "found = conftest.gather_kernels(t.FAMILIES['joint_raft']())\n"
+        "print(sorted(found), sum(found.values()))"))
+    assert r.returncode == 0, r.stderr[-2000:]
+    names, total = r.stdout.strip().rsplit(" ", 1)
+    assert "HandleMessage" in names and "guards" in names, r.stdout
+    assert int(total) > 100, r.stdout
 
 
 @pytest.mark.parametrize("family", sorted(FAMILIES))

@@ -94,3 +94,28 @@ def test_hash_lanes_sensitivity():
     assert h2[0] != h0[0] and h3[0] != h0[0] and h2[0] != h3[0]
     assert h2[1] == h0[1]
 
+
+
+@pytest.mark.parametrize("shape", [(4,), (4, 6), (4, 4)])
+def test_onehot_reads_index_inside_the_axis_and_read_zero_outside(shape):
+    """`onehot_row` and `onehot_get2` (models/base.py) equal `arr[i]`
+    and `arr[i, j]` for an index inside the axis, per lane under vmap
+    as the kernels call them; outside it they read 0 where a gather
+    clamps, which is why the kernels clip a computed position first."""
+    import jax
+
+    from raft_tpu.models.base import onehot_get2, onehot_row
+
+    arr = jnp.asarray(
+        np.random.default_rng(7).integers(1, 99, shape), jnp.int32)
+    idx = jnp.arange(-1, shape[0] + 1, dtype=jnp.int32)
+    rows = jax.vmap(lambda i: onehot_row(arr, i))(idx)
+    np.testing.assert_array_equal(rows[1:-1], arr)
+    assert not rows[0].any() and not rows[-1].any()
+    if len(shape) == 2:
+        jdx = jnp.arange(-1, shape[1] + 1, dtype=jnp.int32)
+        got = jax.vmap(lambda i: jax.vmap(
+            lambda j: onehot_get2(arr, i, j))(jdx))(idx)
+        np.testing.assert_array_equal(got[1:-1, 1:-1], arr)
+        assert not got[0].any() and not got[-1].any()
+        assert not got[:, 0].any() and not got[:, -1].any()
