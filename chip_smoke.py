@@ -26,6 +26,12 @@ count against the pure-Python oracle's golden
          through the same wave program. On its first contact with a v5e
          (PR 30) this lowering lost writes in the sparse apply and every
          count from depth 4 on was wrong, on the chip only.
+  leg E  configs/pull-raft/KRaft.cfg (Kafka's KIP-595 quorum: 3
+         servers, 6 permutations, 291-lane rows, 98 actions a state, 80
+         of them over the bag's slots; models/kraft.py) to depth 14
+         against tests/golden/kraft_cfg_depth_counts.json: a third model
+         file through the same wave program, its four invariants
+         evaluated on every state.
 
 This process never imports jax or raft_tpu: a chip belongs to one process
 at a time, so every leg is a child of its own, one after the other, and
@@ -51,9 +57,12 @@ TRACE_GOLDEN = os.path.join(
     ROOT, "tests", "golden", "flexible_unsafe_quorums_trace.txt")
 JOINT_GOLDEN = os.path.join(
     ROOT, "tests", "golden", "joint_cfg_depth_counts.json")
+KRAFT_GOLDEN = os.path.join(
+    ROOT, "tests", "golden", "kraft_cfg_depth_counts.json")
 RAFT_CFG = os.path.join(ROOT, "configs", "standard-raft", "Raft.cfg")
 JOINT_CFG = os.path.join(
     ROOT, "configs", "standard-raft", "RaftWithReconfigJointConsensus.cfg")
+KRAFT_CFG = os.path.join(ROOT, "configs", "pull-raft", "KRaft.cfg")
 UNSAFE_CFG = os.path.join(
     ROOT, "configs", "flexible-raft", "unsafe-quorums", "FlexibleRaft.cfg")
 SCHEMA_CHECK = os.path.join(ROOT, "scripts", "check_metrics_schema.py")
@@ -241,20 +250,22 @@ def leg_c(dev: dict, golden: dict) -> None:
           f"{res['distinct']} distinct (run wall {res['wall_s']} s)")
 
 
-def leg_d(dev: dict, golden: dict) -> None:
+def cfg_leg(letter: str, cfg: str, chunk: int, dev: dict, golden: dict) -> None:
+    """Legs D and E: another model file's cfg through the CLI to its
+    golden's depth, at its cell's chunk."""
     depth = golden["max_depth"]
-    res = bfs_leg("legD", dev, golden,
+    res = bfs_leg(f"leg{letter}", dev, golden,
                   ["--checker", "tpu", "--frontier-cap", "65536"], depth, 1,
-                  cfg=JOINT_CFG, chunk=1024)
-    print(f"leg D ok: RaftWithReconfigJointConsensus.cfg to depth {depth}, "
+                  cfg=cfg, chunk=chunk)
+    print(f"leg {letter} ok: {os.path.basename(cfg)} to depth {depth}, "
           f"{res['distinct']} distinct / {res['total']} generated "
           f"(run wall {res['wall_s']} s)")
 
 
 def main() -> int:
     try:
-        for path in (GOLDEN, JOINT_GOLDEN, TRACE_GOLDEN, RAFT_CFG, JOINT_CFG,
-                     UNSAFE_CFG, SCHEMA_CHECK,
+        for path in (GOLDEN, JOINT_GOLDEN, KRAFT_GOLDEN, TRACE_GOLDEN,
+                     RAFT_CFG, JOINT_CFG, KRAFT_CFG, UNSAFE_CFG, SCHEMA_CHECK,
                      os.path.join(ROOT, "raft_tpu", "__main__.py")):
             check(os.path.exists(path),
                   f"{os.path.relpath(path, ROOT)} is missing: chip_smoke.py "
@@ -266,8 +277,10 @@ def main() -> int:
         leg_a(dev, golden)
         leg_b(dev)
         leg_c(dev, golden)
-        with open(JOINT_GOLDEN) as f:
-            leg_d(dev, json.load(f)["depth_limited"])
+        for letter, cfg, chunk, path in (("D", JOINT_CFG, 1024, JOINT_GOLDEN),
+                                         ("E", KRAFT_CFG, 2048, KRAFT_GOLDEN)):
+            with open(path) as f:
+                cfg_leg(letter, cfg, chunk, dev, json.load(f)["depth_limited"])
     except SmokeFailure as e:
         print(f"chip_smoke: FAILED: {e}", file=sys.stderr)
         return 1

@@ -40,6 +40,10 @@ from .base import (
     Layout,
     SparseExpandMixin,
     messages_are_valid_kernel,
+    onehot_add,
+    onehot_row,
+    onehot_set,
+    onehot_set2,
 )
 
 # state[i] enum, shared with oracle/kraft_oracle.py (KRaft.tla:69,87)
@@ -271,11 +275,20 @@ class KRaftModel(SparseExpandMixin, ActionLabelMixin):
         hi, lo = self.packer.pack(**vals)
         return jnp.asarray(hi, jnp.int32), jnp.asarray(lo, jnp.int32)
 
+    # Every read and write through a traced index (a binding, a decoded
+    # server, a log position, the bag slot) is a one-hot select
+    # (models/base.py): under the worklist's vmap `arr[i]` is a per-lane
+    # gather and `arr.at[i].set` a batched scatter, which the v5e's
+    # compiler drops writes from at a wide worklist (PR 30). Positions
+    # are clipped into their axis first; an EMPTY bag word decodes to 0
+    # in every field, so no decoded server leaves its axis either.
+
     @staticmethod
     def _last_epoch(d, i):
         """LastEpoch(log[i]) — KRaft.tla:165."""
-        ll = d["log_len"][i]
-        return jnp.where(ll > 0, d["log_epoch"][i][jnp.clip(ll - 1, 0)], 0)
+        ll = onehot_row(d["log_len"], i)
+        row = onehot_row(d["log_epoch"], i)
+        return jnp.where(ll > 0, onehot_row(row, jnp.clip(ll - 1, 0)), 0)
 
     # ---------------- transition machine (KRaft.tla:312-392) ----------------
     # All helpers take/return (state, epoch, leader_enc) int32 triples with
@@ -283,9 +296,9 @@ class KRaftModel(SparseExpandMixin, ActionLabelMixin):
 
     def _maybe_transition(self, d, i, leader_enc, epoch):
         """MaybeTransition — KRaft.tla:351-367."""
-        st_i = d["state"][i]
-        cur = d["currentEpoch"][i]
-        led = d["leader"][i]
+        st_i = onehot_row(d["state"], i)
+        cur = onehot_row(d["currentEpoch"], i)
+        led = onehot_row(d["leader"], i)
         # HasConsistentLeader (KRaft.tla:316-327)
         hcl = jnp.where(
             leader_enc == i + 1,
@@ -324,9 +337,9 @@ class KRaftModel(SparseExpandMixin, ActionLabelMixin):
     def _maybe_handle_common(self, d, i, leader_enc, epoch, err):
         """MaybeHandleCommonResponse — KRaft.tla:369-392.
         Returns (state, epoch, leader_enc, handled)."""
-        st_i = d["state"][i]
-        cur = d["currentEpoch"][i]
-        led = d["leader"][i]
+        st_i = onehot_row(d["state"], i)
+        cur = onehot_row(d["currentEpoch"], i)
+        led = onehot_row(d["leader"], i)
         mt = self._maybe_transition(d, i, leader_enc, epoch)
         c_stale = epoch < cur
         c_trans = (epoch > cur) | (err != E_NONE)
@@ -355,10 +368,10 @@ class KRaftModel(SparseExpandMixin, ActionLabelMixin):
         highest entry with epoch <= last_fetched_epoch; (0,0) if none."""
         L = self.p.max_log
         lanes = jnp.arange(L, dtype=jnp.int32)
-        row = d["log_epoch"][i]
-        mask = (lanes < d["log_len"][i]) & (row <= last_fetched_epoch)
+        row = onehot_row(d["log_epoch"], i)
+        mask = (lanes < onehot_row(d["log_len"], i)) & (row <= last_fetched_epoch)
         off = jnp.max(jnp.where(mask, lanes + 1, 0))
-        ep = jnp.where(off > 0, row[jnp.clip(off - 1, 0)], 0)
+        ep = jnp.where(off > 0, onehot_row(row, jnp.clip(off - 1, 0)), 0)
         return off, ep
 
     def _highest_common_offset(self, d, i, end_off, epoch):
@@ -366,9 +379,9 @@ class KRaftModel(SparseExpandMixin, ActionLabelMixin):
         CompareEntries(offset, entry.epoch, end_off, epoch) <= 0."""
         L = self.p.max_log
         lanes = jnp.arange(L, dtype=jnp.int32)
-        row = d["log_epoch"][i]
+        row = onehot_row(d["log_epoch"], i)
         le = (row < epoch) | ((row == epoch) & (lanes + 1 <= end_off))
-        mask = (lanes < d["log_len"][i]) & le
+        mask = (lanes < onehot_row(d["log_len"], i)) & le
         return jnp.max(jnp.where(mask, lanes + 1, 0))
 
     def _valid_fetch_position(self, d, i, fetch_off, last_fetched_epoch):
@@ -379,6 +392,15 @@ class KRaftModel(SparseExpandMixin, ActionLabelMixin):
 
     # ---------------- action kernels ----------------
 
+    @staticmethod
+    def _pf_cleared(d, i, where=True):
+        """pendingFetch[i] := Nil where ``where`` holds: the four
+        decomposed lanes of server i zeroed."""
+        return {
+            pf: jnp.where(where, onehot_set(d[pf], i, 0), d[pf])
+            for pf in ("pf_epoch", "pf_offset", "pf_lastepoch", "pf_dest")
+        }
+
     def _restart(self, s, i):
         """Restart(i) — KRaft.tla:423-432: keeps currentEpoch, votedFor,
         log; loses leader belief, votes, endOffset, hwm, pendingFetch."""
@@ -387,16 +409,13 @@ class KRaftModel(SparseExpandMixin, ActionLabelMixin):
         valid = d["restartCtr"] < p.max_restarts
         succ = self._asm(
             d,
-            state=d["state"].at[i].set(FOLLOWER),
-            leader=d["leader"].at[i].set(NIL),
-            votesGranted=d["votesGranted"].at[i].set(0),
-            endOffset=d["endOffset"].at[i].set(jnp.zeros((S,), jnp.int32)),
-            highWatermark=d["highWatermark"].at[i].set(0),
-            pf_epoch=d["pf_epoch"].at[i].set(0),
-            pf_offset=d["pf_offset"].at[i].set(0),
-            pf_lastepoch=d["pf_lastepoch"].at[i].set(0),
-            pf_dest=d["pf_dest"].at[i].set(0),
+            state=onehot_set(d["state"], i, FOLLOWER),
+            leader=onehot_set(d["leader"], i, NIL),
+            votesGranted=onehot_set(d["votesGranted"], i, 0),
+            endOffset=onehot_set(d["endOffset"], i, jnp.zeros((S,), jnp.int32)),
+            highWatermark=onehot_set(d["highWatermark"], i, 0),
             restartCtr=d["restartCtr"] + 1,
+            **self._pf_cleared(d, i),
         )
         return valid, succ, jnp.int32(K_RESTART), jnp.asarray(False)
 
@@ -405,13 +424,13 @@ class KRaftModel(SparseExpandMixin, ActionLabelMixin):
         enabled from Follower, Candidate or Unattached)."""
         p, S = self.p, self.p.n_servers
         d = self._dec(s)
-        st_i = d["state"][i]
+        st_i = onehot_row(d["state"], i)
         valid = (d["electionCtr"] < p.max_elections) & (
             (st_i == FOLLOWER) | (st_i == CANDIDATE) | (st_i == UNATTACHED)
         )
-        new_epoch = d["currentEpoch"][i] + 1
+        new_epoch = onehot_row(d["currentEpoch"], i) + 1
         last_ep = self._last_epoch(d, i)
-        ll_i = d["log_len"][i]
+        ll_i = onehot_row(d["log_len"], i)
         hi, lo, cnt = d["msg_hi"], d["msg_lo"], d["msg_cnt"]
         ovf = jnp.asarray(False)
         for delta in range(1, S):
@@ -429,19 +448,16 @@ class KRaftModel(SparseExpandMixin, ActionLabelMixin):
             ovf |= o
         succ = self._asm(
             d,
-            state=d["state"].at[i].set(CANDIDATE),
-            currentEpoch=d["currentEpoch"].at[i].set(new_epoch),
-            leader=d["leader"].at[i].set(NIL),
-            votedFor=d["votedFor"].at[i].set(i + 1),
-            votesGranted=d["votesGranted"].at[i].set(jnp.int32(1) << i),
-            pf_epoch=d["pf_epoch"].at[i].set(0),
-            pf_offset=d["pf_offset"].at[i].set(0),
-            pf_lastepoch=d["pf_lastepoch"].at[i].set(0),
-            pf_dest=d["pf_dest"].at[i].set(0),
+            state=onehot_set(d["state"], i, CANDIDATE),
+            currentEpoch=onehot_set(d["currentEpoch"], i, new_epoch),
+            leader=onehot_set(d["leader"], i, NIL),
+            votedFor=onehot_set(d["votedFor"], i, i + 1),
+            votesGranted=onehot_set(d["votesGranted"], i, jnp.int32(1) << i),
             electionCtr=d["electionCtr"] + 1,
             msg_hi=hi,
             msg_lo=lo,
             msg_cnt=cnt,
+            **self._pf_cleared(d, i),
         )
         return valid, succ, jnp.int32(K_REQUESTVOTE), ovf & valid
 
@@ -449,23 +465,23 @@ class KRaftModel(SparseExpandMixin, ActionLabelMixin):
         """BecomeLeader(i) — KRaft.tla:546-558."""
         S = self.p.n_servers
         d = self._dec(s)
-        votes = jnp.sum((d["votesGranted"][i] >> jnp.arange(S, dtype=jnp.int32)) & 1)
-        valid = (d["state"][i] == CANDIDATE) & (2 * votes > S)
+        votes = jnp.sum(
+            (onehot_row(d["votesGranted"], i) >> jnp.arange(S, dtype=jnp.int32)) & 1)
+        valid = (onehot_row(d["state"], i) == CANDIDATE) & (2 * votes > S)
+        cur = onehot_row(d["currentEpoch"], i)
         hi, lo, cnt = d["msg_hi"], d["msg_lo"], d["msg_cnt"]
         ovf = jnp.asarray(False)
         for delta in range(1, S):
             j = jnp.mod(i + delta, S)
-            khi, klo = self._pack(
-                mtype=BQREQ, mepoch=d["currentEpoch"][i], msource=i, mdest=j
-            )
+            khi, klo = self._pack(mtype=BQREQ, mepoch=cur, msource=i, mdest=j)
             hi, lo, cnt, existed, o = bag.bag_put(hi, lo, cnt, khi, klo)
             valid &= ~existed  # SendMultipleOnce
             ovf |= o
         succ = self._asm(
             d,
-            state=d["state"].at[i].set(LEADER),
-            leader=d["leader"].at[i].set(i + 1),
-            endOffset=d["endOffset"].at[i].set(jnp.zeros((S,), jnp.int32)),
+            state=onehot_set(d["state"], i, LEADER),
+            leader=onehot_set(d["leader"], i, i + 1),
+            endOffset=onehot_set(d["endOffset"], i, jnp.zeros((S,), jnp.int32)),
             msg_hi=hi,
             msg_lo=lo,
             msg_cnt=cnt,
@@ -476,16 +492,18 @@ class KRaftModel(SparseExpandMixin, ActionLabelMixin):
         """ClientRequest(i, v) — KRaft.tla:594-603."""
         L = self.p.max_log
         d = self._dec(s)
-        valid = (d["state"][i] == LEADER) & (d["acked"][v] == ACK_NIL)
-        pos = d["log_len"][i]
+        valid = (onehot_row(d["state"], i) == LEADER) & (
+            onehot_row(d["acked"], v) == ACK_NIL)
+        pos = onehot_row(d["log_len"], i)
         ovf = valid & (pos >= L)
         posc = jnp.clip(pos, 0, L - 1)
         succ = self._asm(
             d,
-            log_epoch=d["log_epoch"].at[i, posc].set(d["currentEpoch"][i]),
-            log_value=d["log_value"].at[i, posc].set(v + 1),
-            log_len=d["log_len"].at[i].add(1),
-            acked=d["acked"].at[v].set(ACK_FALSE),
+            log_epoch=onehot_set2(
+                d["log_epoch"], i, posc, onehot_row(d["currentEpoch"], i)),
+            log_value=onehot_set2(d["log_value"], i, posc, v + 1),
+            log_len=onehot_add(d["log_len"], i, 1),
+            acked=onehot_set(d["acked"], v, ACK_FALSE),
         )
         return valid, succ, jnp.int32(K_CLIENTREQUEST), ovf
 
@@ -495,15 +513,16 @@ class KRaftModel(SparseExpandMixin, ActionLabelMixin):
         gate provides the flow control."""
         d = self._dec(s)
         valid = (
-            (d["state"][i] == FOLLOWER)
-            & (d["leader"][i] == j + 1)
-            & (d["pf_epoch"][i] == 0)
+            (onehot_row(d["state"], i) == FOLLOWER)
+            & (onehot_row(d["leader"], i) == j + 1)
+            & (onehot_row(d["pf_epoch"], i) == 0)
         )
-        ll_i = d["log_len"][i]
+        cur = onehot_row(d["currentEpoch"], i)
+        ll_i = onehot_row(d["log_len"], i)
         last_ep = self._last_epoch(d, i)
         khi, klo = self._pack(
             mtype=FETCHREQ,
-            mepoch=d["currentEpoch"][i],
+            mepoch=cur,
             mfetchOffset=ll_i,
             mlastFetchedEpoch=last_ep,
             msource=i,
@@ -514,10 +533,10 @@ class KRaftModel(SparseExpandMixin, ActionLabelMixin):
         )
         succ = self._asm(
             d,
-            pf_epoch=d["pf_epoch"].at[i].set(d["currentEpoch"][i]),
-            pf_offset=d["pf_offset"].at[i].set(ll_i),
-            pf_lastepoch=d["pf_lastepoch"].at[i].set(last_ep),
-            pf_dest=d["pf_dest"].at[i].set(j + 1),
+            pf_epoch=onehot_set(d["pf_epoch"], i, cur),
+            pf_offset=onehot_set(d["pf_offset"], i, ll_i),
+            pf_lastepoch=onehot_set(d["pf_lastepoch"], i, last_ep),
+            pf_dest=onehot_set(d["pf_dest"], i, j + 1),
             msg_hi=hi,
             msg_lo=lo,
             msg_cnt=cnt,
@@ -528,36 +547,31 @@ class KRaftModel(SparseExpandMixin, ActionLabelMixin):
     # The nine receipt disjuncts of Next (KRaft.tla:827-840) are mutually
     # exclusive for a fixed record (they partition on mtype, then on
     # error/validity/mresult), so one kernel per slot computes whichever
-    # fires; `rank` reports which for trace labels.
+    # fires; `rank` reports which for trace labels. Being exclusive, the
+    # five replying branches share ONE bag_put on the branch-selected
+    # response and the successor assembles once, field by field (the
+    # shape config_common.py's receipt kernel took in round 5).
 
     def _handle_message(self, s, m):
         p, packer = self.p, self.packer
         S, L = p.n_servers, p.max_log
         d = self._dec(s)
         hi, lo, cnt = d["msg_hi"], d["msg_lo"], d["msg_cnt"]
-        khi, klo, kcnt = hi[m], lo[m], cnt[m]
+        khi, klo, kcnt = onehot_row(hi, m), onehot_row(lo, m), onehot_row(cnt, m)
         occupied = khi != EMPTY
         u = partial(packer.unpack, khi, klo)
         mtype, mepoch = u("mtype"), u("mepoch")
         src, dst = u("msource"), u("mdest")
-        cur = d["currentEpoch"][dst]
-        st_dst = d["state"][dst]
-        led_dst = d["leader"][dst]
+        cur = onehot_row(d["currentEpoch"], dst)
+        st_dst = onehot_row(d["state"], dst)
+        led_dst = onehot_row(d["leader"], dst)
+        hwm_dst = onehot_row(d["highWatermark"], dst)
+        ll_dst = onehot_row(d["log_len"], dst)
+        ep_row = onehot_row(d["log_epoch"], dst)
+        val_row = onehot_row(d["log_value"], dst)
         recv = occupied & (kcnt > 0)  # ReceivableMessage (KRaft.tla:230-235)
         equal_epoch = mepoch == cur
-
-        def reply(resp_hi, resp_lo):
-            """Reply — KRaft.tla:220-227; caller enforces the FetchResponse
-            no-duplicate rule via the returned `existed`."""
-            c2 = bag.bag_discard_at(cnt, m)
-            return bag.bag_put(hi, lo, c2, resp_hi, resp_lo)
-
-        def clear_pf(upd):
-            upd["pf_epoch"] = d["pf_epoch"].at[dst].set(0)
-            upd["pf_offset"] = d["pf_offset"].at[dst].set(0)
-            upd["pf_lastepoch"] = d["pf_lastepoch"].at[dst].set(0)
-            upd["pf_dest"] = d["pf_dest"].at[dst].set(0)
-            return upd
+        cnt_disc = bag.bag_discard_at(cnt, m)
 
         # --- HandleRequestVoteRequest (KRaft.tla:464-513)
         b_rvreq = recv & (mtype == RVREQ)
@@ -567,13 +581,13 @@ class KRaftModel(SparseExpandMixin, ActionLabelMixin):
         s0_ep = jnp.where(mepoch > cur, mepoch, cur)
         s0_ld = jnp.where(mepoch > cur, NIL, led_dst)
         last_ep = self._last_epoch(d, dst)
-        ll_dst = d["log_len"][dst]
         # logOk: CompareEntries(mllo, mlle, Len, LastEpoch) >= 0 (:475-478)
         log_ok = (u("mlastLogEpoch") > last_ep) | (
             (u("mlastLogEpoch") == last_ep) & (u("mlastLogOffset") >= ll_dst)
         )
         grant = (
-            (s0_st == UNATTACHED) | ((s0_st == VOTED) & (d["votedFor"][dst] == src + 1))
+            (s0_st == UNATTACHED)
+            | ((s0_st == VOTED) & (onehot_row(d["votedFor"], dst) == src + 1))
         ) & log_ok
         # finalState: TransitionToVoted when grant from Unattached (:483-485);
         # the Unattached precondition makes the illegal arm unreachable.
@@ -582,78 +596,38 @@ class KRaftModel(SparseExpandMixin, ActionLabelMixin):
         f_ep = jnp.where(take_voted, mepoch, s0_ep)
         f_ld = jnp.where(take_voted, NIL, s0_ld)
         # error path replies with (cur, leader[i]); normal with (mepoch, final)
-        r_ep = jnp.where(rv_err, cur, mepoch)
-        r_ld = jnp.where(rv_err, led_dst, f_ld)
-        r_grant = jnp.where(rv_err, 0, grant.astype(jnp.int32))
-        r_err = jnp.where(rv_err, E_FENCED, E_NONE)
-        rhi, rlo = self._pack(
+        rv_key = self._pack(
             mtype=RVRESP,
-            mepoch=r_ep,
-            mleader=r_ld,
-            mvoteGranted=r_grant,
-            merror=r_err,
+            mepoch=jnp.where(rv_err, cur, mepoch),
+            mleader=jnp.where(rv_err, led_dst, f_ld),
+            mvoteGranted=jnp.where(rv_err, 0, grant.astype(jnp.int32)),
+            merror=jnp.where(rv_err, E_FENCED, E_NONE),
             msource=dst,
             mdest=src,
         )
-        hi1, lo1, cnt1, _ex1, ovf1 = reply(rhi, rlo)
-        upd1 = dict(msg_hi=hi1, msg_lo=lo1, msg_cnt=cnt1)
-        no_err = ~rv_err
-        upd1["state"] = jnp.where(no_err, d["state"].at[dst].set(f_st), d["state"])
-        upd1["currentEpoch"] = jnp.where(
-            no_err, d["currentEpoch"].at[dst].set(f_ep), d["currentEpoch"]
-        )
-        upd1["leader"] = jnp.where(no_err, d["leader"].at[dst].set(f_ld), d["leader"])
-        upd1["votedFor"] = jnp.where(
-            no_err & grant, d["votedFor"].at[dst].set(src + 1), d["votedFor"]
-        )
+        rv_ok = b_rvreq & ~rv_err
         # IF state # state' THEN reset pendingFetch (KRaft.tla:495-497)
-        pf_reset = no_err & (f_st != st_dst)
-        for pf in ("pf_epoch", "pf_offset", "pf_lastepoch", "pf_dest"):
-            upd1[pf] = jnp.where(pf_reset, d[pf].at[dst].set(0), d[pf])
-        s_rvreq = self._asm(d, **upd1)
+        rv_pf_reset = rv_ok & (f_st != st_dst)
 
         # --- HandleRequestVoteResponse (KRaft.tla:519-541)
         mh_st, mh_ep, mh_ld, handled = self._maybe_handle_common(
             d, dst, u("mleader"), mepoch, u("merror")
         )
         b_rvresp = recv & (mtype == RVRESP) & (handled | (st_dst == CANDIDATE))
-        cnt_disc = bag.bag_discard_at(cnt, m)
-        granted_bit = (u("mvoteGranted") > 0) & ~handled
-        upd2 = dict(
-            state=jnp.where(handled, d["state"].at[dst].set(mh_st), d["state"]),
-            currentEpoch=jnp.where(
-                handled, d["currentEpoch"].at[dst].set(mh_ep), d["currentEpoch"]
-            ),
-            leader=jnp.where(handled, d["leader"].at[dst].set(mh_ld), d["leader"]),
-            votesGranted=jnp.where(
-                granted_bit,
-                d["votesGranted"].at[dst].set(d["votesGranted"][dst] | (jnp.int32(1) << src)),
-                d["votesGranted"],
-            ),
-            msg_cnt=cnt_disc,
-        )
-        s_rvresp = self._asm(d, **upd2)
+        rvresp_grant = b_rvresp & (u("mvoteGranted") > 0) & ~handled
 
         # --- HandleBeginQuorumRequest (KRaft.tla:563-590)
         b_bqreq = recv & (mtype == BQREQ)
         bq_err = mepoch < cur
         bt_st, bt_ep, bt_ld = self._maybe_transition(d, dst, src + 1, mepoch)
-        bq_rep = jnp.where(bq_err, cur, mepoch)
-        bq_rerr = jnp.where(bq_err, E_FENCED, E_NONE)
-        bhi, blo = self._pack(
-            mtype=BQRESP, mepoch=bq_rep, msource=dst, mdest=src, merror=bq_rerr
+        bq_key = self._pack(
+            mtype=BQRESP,
+            mepoch=jnp.where(bq_err, cur, mepoch),
+            msource=dst,
+            mdest=src,
+            merror=jnp.where(bq_err, E_FENCED, E_NONE),
         )
-        hi3, lo3, cnt3, _ex3, ovf3 = reply(bhi, blo)
-        upd3 = dict(msg_hi=hi3, msg_lo=lo3, msg_cnt=cnt3)
-        ok3 = ~bq_err
-        upd3["state"] = jnp.where(ok3, d["state"].at[dst].set(bt_st), d["state"])
-        upd3["currentEpoch"] = jnp.where(
-            ok3, d["currentEpoch"].at[dst].set(bt_ep), d["currentEpoch"]
-        )
-        upd3["leader"] = jnp.where(ok3, d["leader"].at[dst].set(bt_ld), d["leader"])
-        for pf in ("pf_epoch", "pf_offset", "pf_lastepoch", "pf_dest"):
-            upd3[pf] = jnp.where(ok3, d[pf].at[dst].set(0), d[pf])
-        s_bqreq = self._asm(d, **upd3)
+        bq_ok = b_bqreq & ~bq_err
 
         # --- FetchRequest branches (KRaft.tla:631-736)
         is_fetchreq = recv & (mtype == FETCHREQ)
@@ -669,138 +643,111 @@ class KRaftModel(SparseExpandMixin, ActionLabelMixin):
         flep = u("mlastFetchedEpoch")
         valid_pos = self._valid_fetch_position(d, dst, foff, flep)
         eo_off, eo_ep = self._end_offset_for_epoch(d, dst, flep)
-
-        # RejectFetchRequest (KRaft.tla:631-651)
-        b_reject = is_fetchreq & (ferr != E_NONE)
-        rjhi, rjlo = self._pack(
+        # every FetchResponse embeds the request (KRaft.tla:649)
+        corr_kw = dict(
             mtype=FETCHRESP,
-            mresult=R_NOTOK,
-            merror=ferr,
             mleader=led_dst,
             mepoch=cur,
-            mhwm=d["highWatermark"][dst],
             msource=dst,
             mdest=src,
             cepoch=mepoch,
             cfetchOffset=foff,
             clastFetchedEpoch=flep,
         )
-        hi4, lo4, cnt4, ex4, ovf4 = reply(rjhi, rjlo)
-        b_reject &= ~ex4  # FetchResponse no-duplicate rule (KRaft.tla:224-227)
-        s_reject = self._asm(d, msg_hi=hi4, msg_lo=lo4, msg_cnt=cnt4)
+
+        # RejectFetchRequest (KRaft.tla:631-651)
+        b_reject = is_fetchreq & (ferr != E_NONE)
+        rj_key = self._pack(
+            mresult=R_NOTOK, merror=ferr, mhwm=hwm_dst, **corr_kw)
 
         # DivergingFetchRequest (KRaft.tla:658-679)
         b_div = is_fetchreq & equal_epoch & is_leader & ~valid_pos
-        dvhi, dvlo = self._pack(
-            mtype=FETCHRESP,
-            mepoch=cur,
+        dv_key = self._pack(
             mresult=R_DIVERGING,
             merror=E_NONE,
             mdivergingEpoch=eo_ep,
             mdivergingEndOffset=eo_off,
-            mleader=led_dst,
-            mhwm=d["highWatermark"][dst],
-            msource=dst,
-            mdest=src,
-            cepoch=mepoch,
-            cfetchOffset=foff,
-            clastFetchedEpoch=flep,
+            mhwm=hwm_dst,
+            **corr_kw,
         )
-        hi5, lo5, cnt5, ex5, ovf5 = reply(dvhi, dvlo)
-        b_div &= ~ex5
-        s_div = self._asm(d, msg_hi=hi5, msg_lo=lo5, msg_cnt=cnt5)
 
         # AcceptFetchRequest (KRaft.tla:703-736)
         b_accept = is_fetchreq & equal_epoch & is_leader & valid_pos
         offset = foff + 1
-        have_entry = offset <= d["log_len"][dst]
+        have_entry = offset <= ll_dst
         epos = jnp.clip(offset - 1, 0, L - 1)
-        ent_ep = jnp.where(have_entry, d["log_epoch"][dst][epos], 0)
-        ent_v = jnp.where(have_entry, d["log_value"][dst][epos], 0)
-        new_end = d["endOffset"][dst].at[src].set(foff)
+        ent_ep = jnp.where(have_entry, onehot_row(ep_row, epos), 0)
+        ent_v = jnp.where(have_entry, onehot_row(val_row, epos), 0)
+        new_end = onehot_set(onehot_row(d["endOffset"], dst), src, foff)
         # NewHighwaterMark (KRaft.tla:689-701)
         idxs = jnp.arange(1, L + 1, dtype=jnp.int32)
         self_in = jnp.arange(S, dtype=jnp.int32)[None, :] == dst
         agree = self_in | (new_end[None, :] >= idxs[:, None])
         quorum_ok = 2 * jnp.sum(agree, axis=1) > S
-        in_log = idxs <= d["log_len"][dst]
+        in_log = idxs <= ll_dst
         max_agree = jnp.max(jnp.where(quorum_ok & in_log, idxs, 0))
-        ep_at = d["log_epoch"][dst][jnp.clip(max_agree - 1, 0)]
-        hwm_old = d["highWatermark"][dst]
+        ep_at = onehot_row(ep_row, jnp.clip(max_agree - 1, 0))
         new_hwm = jnp.where(
-            (max_agree > 0) & (ep_at == cur), max_agree, hwm_old
+            (max_agree > 0) & (ep_at == cur), max_agree, hwm_dst
         )
         # acked: FALSE -> committed in (hwm_old, new_hwm] (KRaft.tla:721-724)
         lanes = jnp.arange(L, dtype=jnp.int32)
-        in_range = (lanes + 1 > hwm_old) & (lanes + 1 <= new_hwm)
-        vals_row = d["log_value"][dst]
+        in_range = (lanes + 1 > hwm_dst) & (lanes + 1 <= new_hwm)
         committed = jnp.any(
             in_range[None, :]
-            & (vals_row[None, :] == jnp.arange(1, p.n_values + 1, dtype=jnp.int32)[:, None]),
+            & (val_row[None, :] == jnp.arange(1, p.n_values + 1, dtype=jnp.int32)[:, None]),
             axis=1,
         )
         acked = jnp.where(
             (d["acked"] == ACK_FALSE) & committed, ACK_TRUE, d["acked"]
         )
-        achi, aclo = self._pack(
-            mtype=FETCHRESP,
-            mepoch=cur,
-            mleader=led_dst,
+        ac_key = self._pack(
             mresult=R_OK,
             merror=E_NONE,
             nentries=have_entry.astype(jnp.int32),
             eepoch=ent_ep,
             evalue=ent_v,
             mhwm=jnp.minimum(new_hwm, offset),
-            msource=dst,
-            mdest=src,
-            cepoch=mepoch,
-            cfetchOffset=foff,
-            clastFetchedEpoch=flep,
+            **corr_kw,
         )
-        hi6, lo6, cnt6, ex6, ovf6 = reply(achi, aclo)
-        b_accept &= ~ex6
-        s_accept = self._asm(
-            d,
-            endOffset=d["endOffset"].at[dst].set(new_end),
-            highWatermark=d["highWatermark"].at[dst].set(new_hwm),
-            acked=acked,
-            msg_hi=hi6,
-            msg_lo=lo6,
-            msg_cnt=cnt6,
-        )
+
+        # Reply — KRaft.tla:220-227: discard the request, send the
+        # branch's response; a FetchResponse may not be duplicated
+        # (:224-227), which disables the three fetch branches on `existed`
+        is_fresp_send = b_reject | b_div | b_accept
+        replying = b_rvreq | b_bqreq | is_fresp_send
+        resp_hi, resp_lo = rv_key
+        for b, (k_hi, k_lo) in (
+            (b_bqreq, bq_key), (b_reject, rj_key), (b_div, dv_key),
+            (b_accept, ac_key),
+        ):
+            resp_hi = jnp.where(b, k_hi, resp_hi)
+            resp_lo = jnp.where(b, k_lo, resp_lo)
+        hi_r, lo_r, cnt_r, existed, put_ovf = bag.bag_put(
+            hi, lo, cnt_disc, resp_hi, resp_lo)
+        b_reject &= ~existed
+        b_div &= ~existed
+        b_accept &= ~existed
 
         # --- FetchResponse branches (KRaft.tla:742-801)
         is_fresp = recv & (mtype == FETCHRESP)
         # correlation match: pendingFetch[dst] = m.correlation (:749); the
         # request's msource is dst (implied) and mdest is the responder src.
+        pf_ep_dst = onehot_row(d["pf_epoch"], dst)
         corr = (
-            (d["pf_epoch"][dst] > 0)
-            & (d["pf_epoch"][dst] == u("cepoch"))
-            & (d["pf_offset"][dst] == u("cfetchOffset"))
-            & (d["pf_lastepoch"][dst] == u("clastFetchedEpoch"))
-            & (d["pf_dest"][dst] == src + 1)
+            (pf_ep_dst > 0)
+            & (pf_ep_dst == u("cepoch"))
+            & (onehot_row(d["pf_offset"], dst) == u("cfetchOffset"))
+            & (onehot_row(d["pf_lastepoch"], dst) == u("clastFetchedEpoch"))
+            & (onehot_row(d["pf_dest"], dst) == src + 1)
         )
         mres = u("mresult")
 
         # HandleSuccessFetchResponse (KRaft.tla:742-757)
         b_ok = is_fresp & ~handled & corr & (mres == R_OK)
-        app = u("nentries") > 0
-        ll_dst2 = d["log_len"][dst]
-        apos = jnp.clip(ll_dst2, 0, L - 1)
-        ok_ovf = b_ok & app & (ll_dst2 >= L)
-        upd7 = dict(
-            highWatermark=d["highWatermark"].at[dst].set(u("mhwm")),
-            log_epoch=jnp.where(
-                app, d["log_epoch"].at[dst, apos].set(u("eepoch")), d["log_epoch"]
-            ),
-            log_value=jnp.where(
-                app, d["log_value"].at[dst, apos].set(u("evalue")), d["log_value"]
-            ),
-            log_len=jnp.where(app, d["log_len"].at[dst].add(1), d["log_len"]),
-            msg_cnt=cnt_disc,
-        )
-        s_ok = self._asm(d, **clear_pf(upd7))
+        app = b_ok & (u("nentries") > 0)
+        apos = jnp.clip(ll_dst, 0, L - 1)
+        ok_ovf = app & (ll_dst >= L)
 
         # HandleDivergingFetchResponse (KRaft.tla:766-780)
         b_divr = is_fresp & ~handled & corr & (mres == R_DIVERGING)
@@ -808,48 +755,89 @@ class KRaftModel(SparseExpandMixin, ActionLabelMixin):
             d, dst, u("mdivergingEndOffset"), u("mdivergingEpoch")
         )
         keep = jnp.arange(L, dtype=jnp.int32) < hco
-        upd8 = dict(
-            log_epoch=d["log_epoch"].at[dst].set(
-                jnp.where(keep, d["log_epoch"][dst], 0)
-            ),
-            log_value=d["log_value"].at[dst].set(
-                jnp.where(keep, d["log_value"][dst], 0)
-            ),
-            log_len=d["log_len"].at[dst].set(hco),
-            msg_cnt=cnt_disc,
-        )
-        s_divr = self._asm(d, **clear_pf(upd8))
 
         # HandleErrorFetchResponse (KRaft.tla:786-801)
         b_err = is_fresp & handled & corr
-        upd9 = dict(
-            state=d["state"].at[dst].set(mh_st),
-            currentEpoch=d["currentEpoch"].at[dst].set(mh_ep),
-            leader=d["leader"].at[dst].set(mh_ld),
-            msg_cnt=cnt_disc,
-        )
-        s_err = self._asm(d, **clear_pf(upd9))
 
-        branches = [
-            (b_rvreq, s_rvreq, K_HANDLE_RVREQ, ovf1),
-            (b_rvresp, s_rvresp, K_HANDLE_RVRESP, jnp.asarray(False)),
-            (b_reject, s_reject, K_REJECT_FETCH, ovf4),
-            (b_div, s_div, K_DIVERGING_FETCH, ovf5),
-            (b_accept, s_accept, K_ACCEPT_FETCH, ovf6),
-            (b_bqreq, s_bqreq, K_HANDLE_BQREQ, ovf3),
-            (b_ok, s_ok, K_HANDLE_FETCH_OK, ok_ovf),
-            (b_divr, s_divr, K_HANDLE_FETCH_DIV, jnp.asarray(False)),
-            (b_err, s_err, K_HANDLE_FETCH_ERR, jnp.asarray(False)),
-        ]
+        # ---- the successor, field by field ----
+        # (state, currentEpoch, leader) of dst: the vote request's final
+        # state, the transition machine's on a handled response or error,
+        # MaybeTransition's on BeginQuorum
+        common = (b_rvresp & handled) | b_err
+        upd = {}
+        for name, rv_v, mh_v, bt_v in (
+            ("state", f_st, mh_st, bt_st),
+            ("currentEpoch", f_ep, mh_ep, bt_ep),
+            ("leader", f_ld, mh_ld, bt_ld),
+        ):
+            v = jnp.where(rv_ok, rv_v, jnp.where(common, mh_v, bt_v))
+            upd[name] = jnp.where(
+                rv_ok | common | bq_ok, onehot_set(d[name], dst, v), d[name])
+        upd["votedFor"] = jnp.where(
+            rv_ok & grant, onehot_set(d["votedFor"], dst, src + 1), d["votedFor"])
+        upd["votesGranted"] = jnp.where(
+            rvresp_grant,
+            onehot_set(
+                d["votesGranted"], dst,
+                onehot_row(d["votesGranted"], dst) | (jnp.int32(1) << src)),
+            d["votesGranted"],
+        )
+        upd.update(self._pf_cleared(
+            d, dst, rv_pf_reset | bq_ok | b_ok | b_divr | b_err))
+        upd["endOffset"] = jnp.where(
+            b_accept, onehot_set(d["endOffset"], dst, new_end), d["endOffset"])
+        upd["highWatermark"] = jnp.where(
+            b_accept | b_ok,
+            onehot_set(
+                d["highWatermark"], dst, jnp.where(b_ok, u("mhwm"), new_hwm)),
+            d["highWatermark"],
+        )
+        upd["acked"] = jnp.where(b_accept, acked, d["acked"])
+        upd["log_epoch"] = jnp.where(
+            app,
+            onehot_set2(d["log_epoch"], dst, apos, u("eepoch")),
+            jnp.where(
+                b_divr,
+                onehot_set(d["log_epoch"], dst, jnp.where(keep, ep_row, 0)),
+                d["log_epoch"]),
+        )
+        upd["log_value"] = jnp.where(
+            app,
+            onehot_set2(d["log_value"], dst, apos, u("evalue")),
+            jnp.where(
+                b_divr,
+                onehot_set(d["log_value"], dst, jnp.where(keep, val_row, 0)),
+                d["log_value"]),
+        )
+        upd["log_len"] = jnp.where(
+            app,
+            onehot_add(d["log_len"], dst, 1),
+            jnp.where(b_divr, onehot_set(d["log_len"], dst, hco), d["log_len"]),
+        )
+        upd["msg_hi"] = jnp.where(replying, hi_r, hi)
+        upd["msg_lo"] = jnp.where(replying, lo_r, lo)
+        upd["msg_cnt"] = jnp.where(replying, cnt_r, cnt_disc)
+        succ = self._asm(d, **upd)
+
         valid = jnp.asarray(False)
-        succ = s
         rank = jnp.int32(-1)
-        ovf = jnp.asarray(False)
-        for b, sb, rk, ob in branches:
+        for b, rk in (
+            (b_rvreq, K_HANDLE_RVREQ),
+            (b_rvresp, K_HANDLE_RVRESP),
+            (b_reject, K_REJECT_FETCH),
+            (b_div, K_DIVERGING_FETCH),
+            (b_accept, K_ACCEPT_FETCH),
+            (b_bqreq, K_HANDLE_BQREQ),
+            (b_ok, K_HANDLE_FETCH_OK),
+            (b_divr, K_HANDLE_FETCH_DIV),
+            (b_err, K_HANDLE_FETCH_ERR),
+        ):
             valid = valid | b
-            succ = jnp.where(b, sb, succ)
             rank = jnp.where(b, jnp.int32(rk), rank)
-            ovf = ovf | (b & ob)
+        ovf = (
+            (b_rvreq | b_bqreq | b_reject | b_div | b_accept) & put_ovf
+        ) | ok_ovf
+        succ = jnp.where(valid, succ, s)
         return valid, succ, rank, ovf
 
     # ---------------- full expansion ----------------

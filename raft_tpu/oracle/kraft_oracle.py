@@ -998,6 +998,7 @@ class KRaftOracle:
         total = 1
         distinct = 1
         depth_counts = [1]
+        terminal = 0  # expanded states with no successor (`-deadlock`)
         violation = None
         depth = 0
         while frontier and violation is None:
@@ -1007,7 +1008,9 @@ class KRaftOracle:
                 break
             next_frontier = []
             for st in frontier:
-                for _label, s2 in self.successors(st):
+                succs = self.successors(st)
+                terminal += not succs
+                for _label, s2 in succs:
                     total += 1
                     key = self.canon(s2, symmetry)
                     if key in seen:
@@ -1041,5 +1044,6 @@ class KRaftOracle:
             "distinct": distinct,
             "total": total,
             "depth_counts": depth_counts,
+            "terminal": terminal,
             "violation": violation,
         }

@@ -1467,6 +1467,7 @@ class KRaftReconfigOracle(ConfigOracleBase):
         total = 1
         distinct = 1
         depth_counts = [1]
+        terminal = 0  # expanded states with no successor (`-deadlock`)
         violation = None
         depth = 0
         while frontier and violation is None:
@@ -1476,7 +1477,9 @@ class KRaftReconfigOracle(ConfigOracleBase):
                 break
             next_frontier = []
             for st in frontier:
-                for _label, s2 in self.successors(st):
+                succs = self.successors(st)
+                terminal += not succs
+                for _label, s2 in succs:
                     total += 1
                     key = self.canon(s2, symmetry)
                     if key in seen:
@@ -1510,6 +1513,7 @@ class KRaftReconfigOracle(ConfigOracleBase):
             "distinct": distinct,
             "total": total,
             "depth_counts": depth_counts,
+            "terminal": terminal,
             "violation": violation,
         }
 

@@ -210,21 +210,45 @@ def test_chip_smoke_leg_holds_the_cli_to_the_golden(smoke):
         chip_smoke.bfs_leg("cnt", dev, golden, ["--checker", "tpu"], 5, 4)
 
 
-def test_chip_smoke_joint_leg_runs_the_cfg_at_the_registrys_bag_width(smoke):
-    """Leg D's shape at a tiny depth: another cfg, its own chunk, no
-    --msg-slots (the golden names none), held to its own golden; the
-    count the v5e first got wrong is depth 4's (276 for 271)."""
+@pytest.mark.parametrize("leg,msg_slots,depth,counts,wrong,args", [
+    # leg D: no --msg-slots (the golden names none); the count the v5e
+    # first got wrong is depth 4's (276 for 271)
+    ("JOINT", None, 4, [1, 5, 24, 90, 271], [1, 5, 24, 90, 276],
+     ("D", 8, 1024)),
+    # leg E: the cell's bag width, the registry's own, by --msg-slots
+    ("KRAFT", 80, 6, [1, 1, 3, 6, 15, 29, 60], [1, 1, 3, 6, 15, 29, 61],
+     ("E", 14, 2048)),
+])
+def test_chip_smoke_second_lowerings_legs_run_their_own_cfg_and_golden(
+        smoke, monkeypatch, leg, msg_slots, depth, counts, wrong, args):
+    """Legs D and E at a tiny depth: another cfg, its own chunk, its own
+    golden and that golden's bag width; then the leg itself, its
+    arguments held: the frontier, the golden's depth, the chunk."""
     chip_smoke, dev = smoke
-    golden = json.loads(
-        Path(chip_smoke.JOINT_GOLDEN).read_text())["depth_limited"]
-    assert golden["msg_slots"] is None
+    cfg = getattr(chip_smoke, f"{leg}_CFG")
+    golden = json.loads(Path(
+        getattr(chip_smoke, f"{leg}_GOLDEN")).read_text())["depth_limited"]
+    assert golden["msg_slots"] == msg_slots
+    small = ["--checker", "tpu", "--frontier-cap", "4096"]
     obs = chip_smoke.bfs_leg(
-        "joint", dev, golden, ["--checker", "tpu", "--frontier-cap", "4096"],
-        4, 1, cfg=chip_smoke.JOINT_CFG, chunk=256)
-    assert obs["distinct"] == 1 + 5 + 24 + 90 + 271
-    wrong = dict(golden, depth_counts=[1, 5, 24, 90, 276])
+        leg, dev, golden, small, depth, 1, cfg=cfg, chunk=256)
+    assert obs["distinct"] == sum(counts)
     with pytest.raises(chip_smoke.SmokeFailure, match="per-depth counts differ"):
         chip_smoke.bfs_leg(
-            "joint-bad", dev, wrong,
-            ["--checker", "tpu", "--frontier-cap", "4096"], 4, 1,
-            cfg=chip_smoke.JOINT_CFG, chunk=256)
+            f"{leg}-bad", dev, dict(golden, depth_counts=wrong), small,
+            depth, 1, cfg=cfg, chunk=256)
+    letter, max_depth, chunk = args
+    calls = []
+    monkeypatch.setattr(
+        chip_smoke, "bfs_leg",
+        lambda *a, **kw: calls.append((a, kw)) or dict(obs))
+    monkeypatch.setattr(chip_smoke, "probe_device", lambda: dev)
+    monkeypatch.setattr(chip_smoke, "leg_a", lambda *a: None)
+    monkeypatch.setattr(chip_smoke, "leg_b", lambda *a: None)
+    monkeypatch.setattr(chip_smoke, "leg_c", lambda *a: None)
+    assert chip_smoke.main() == 0
+    (a, kw) = calls["DE".index(letter)]
+    assert a[:1] + a[2:] == (
+        f"leg{letter}", golden,
+        ["--checker", "tpu", "--frontier-cap", "65536"], max_depth, 1)
+    assert kw == {"cfg": cfg, "chunk": chunk}

@@ -200,7 +200,8 @@ def test_guard_jaxpr_writes_no_successor_blocks(family):
 # and the two config_common lowerings write by one-hot selects since.
 # `raft` runs on the chip with these and equals its goldens in every
 # benchmark run (none of them in HandleMessage, whose writes went
-# one-hot in round 5, for speed); `pull_raft`, `kraft` and
+# one-hot in round 5, for speed); `kraft` left the table with its first
+# cell (PR 32: 73, 44 of them in HandleMessage); `pull_raft` and
 # `kraft_reconfig` have not run there at their published constants:
 # convert each before its first cell (ROADMAP R3). strict: a family that
 # comes clean has to leave this table.
@@ -209,7 +210,6 @@ SCATTER_DEBT = {
             "AdvanceCommitIndex, AppendEntries; equal to the goldens on "
             "the v5e in every run of the three accepted cells",
     "pull_raft": "29, 15 of them in HandleMessage; never run on the chip",
-    "kraft": "73, 44 of them in HandleMessage; never run on the chip",
     "kraft_reconfig": "175, 104 of them in HandleMessage; never run on "
                       "the chip",
 }
@@ -230,14 +230,14 @@ def test_no_kernel_writes_through_a_dynamic_index_scatter(family):
 # guard pass under the chunk's, a per-lane gather. On the v5e those were
 # 8-12 ns an index and most of `expand` on joint4 (PR 31); the two
 # config_common lowerings read by one-hot selects since
-# (`models/base.py::onehot_row`, `onehot_get2`). Counts at this file's
+# (`models/base.py::onehot_row`, `onehot_get2`), and `kraft` since PR 32
+# (131 (58), 50 of them in HandleMessage). Counts at this file's
 # shapes, the guard pass's in brackets. strict, as above.
 GATHER_DEBT = {
     "raft": "62 (29): RequestVote, BecomeLeader, ClientRequest, "
             "AdvanceCommitIndex, AppendEntries, 3 in HandleMessage, "
             "which reads by one-hot since round 5 (ROADMAP D14)",
     "pull_raft": "86 (42), 21 of them in HandleMessage",
-    "kraft": "131 (58), 50 of them in HandleMessage",
     "kraft_reconfig": "326 (127), 130 of them in HandleMessage",
 }
 
@@ -254,8 +254,11 @@ def test_no_kernel_reads_through_a_dynamic_index_gather(family):
     assert gather_kernels(FAMILIES[family]()) == {}
 
 
-@pytest.mark.parametrize("family", ["joint_raft", "reconfig_raft"])
-def test_one_hot_reads_match_the_oracle_on_empty_slots_and_logs(family):
+@pytest.mark.parametrize("family,bag_word", [
+    ("joint_raft", "msg_w0"), ("reconfig_raft", "msg_w0"),
+    ("kraft", "msg_hi")])
+def test_one_hot_reads_match_the_oracle_on_empty_slots_and_logs(
+        family, bag_word):
     """A one-hot read of an index outside its axis yields 0 where the
     gather it replaced clamped. The first levels from Init are where
     such indices would come from: most bag slots EMPTY (every field of
@@ -273,7 +276,7 @@ def test_one_hot_reads_match_the_oracle_on_empty_slots_and_logs(family):
     states = collect_states(oracle, max_depth=3, cap=40)
     vecs = np.stack([model.encode(st) for st in states]).astype(np.int32)
     lay = model.layout
-    empty = lay.get(vecs, "msg_w0") == int(EMPTY)
+    empty = lay.get(vecs, bag_word) == int(EMPTY)
     assert empty.any(axis=1).all() and (~empty).any()
     assert (lay.get(vecs, "log_len") == 0).any()
     succs, valid, rank, ovf = jax.device_get(
