@@ -37,7 +37,7 @@ def undonated_carry():
     from ..checker.device_bfs import DeviceBFS
 
     orig = DeviceBFS.WAVE_DONATE
-    DeviceBFS.WAVE_DONATE = tuple(a for a in orig if a != 7)
+    DeviceBFS.WAVE_DONATE = tuple(a for a in orig if a != 6)
     try:
         yield {"families": ("raft",), "scopes": ("device",)}
     finally:

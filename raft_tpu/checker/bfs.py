@@ -466,10 +466,11 @@ class BFSChecker:
                     "terminal": terminal,
                     "dedup_hit_rate": round(
                         1.0 - len(wave_states) / max(1, n_cand_total), 4),
-                    # the host engine has no canon memo; the declared keys
-                    # still appear so one consumer reads all three engines
-                    "canon_memo_hits": 0,
-                    "canon_memo_hit_rate": 0.0,
+                    # the host engine has no in-chunk dedup; the declared
+                    # keys still appear so one consumer reads all three
+                    # engines
+                    "canon_dup_lanes": 0,
+                    "canon_dup_rate": 0.0,
                     "canon_tier3_local": 0,
                     "canon_tier3_full": 0,
                     "overflow_bits": 0,
@@ -552,7 +553,7 @@ class BFSChecker:
             "peak_frontier_cap": int(max(depth_counts)),
             "peak_journal_cap": int(next_gid - len(self._init_distinct)),
             "seen_lanes": int(len(seen)),
-            "canon_memo_hit_rate": 0.0,
+            "canon_dup_rate": 0.0,
             "canon_tier3_local": 0,
             "canon_tier3_full": 0,
             **(memwatch.summary_fields() if memwatch is not None else {}),
@@ -822,8 +823,8 @@ class BFSChecker:
                     "terminal": int(terminal_j.sum()),
                     "dedup_hit_rate": round(
                         1.0 - len(wave_states) / max(1, n_cand_total), 4),
-                    "canon_memo_hits": 0,
-                    "canon_memo_hit_rate": 0.0,
+                    "canon_dup_lanes": 0,
+                    "canon_dup_rate": 0.0,
                     "canon_tier3_local": 0,
                     "canon_tier3_full": 0,
                     "overflow_bits": 0,
@@ -917,7 +918,7 @@ class BFSChecker:
                 max(dc) for dc in depth_counts_j)),
             "peak_journal_cap": int(next_gid - len(self._init_distinct)),
             "seen_lanes": int(len(seen)),
-            "canon_memo_hit_rate": 0.0,
+            "canon_dup_rate": 0.0,
             "canon_tier3_local": 0,
             "canon_tier3_full": 0,
             "fleet_jobs": J,
@@ -956,7 +957,7 @@ class BFSChecker:
                     "peak_journal_cap": int(
                         next_gid - len(self._init_distinct)),
                     "seen_lanes": int(len(seen)),
-                    "canon_memo_hit_rate": 0.0,
+                    "canon_dup_rate": 0.0,
                     "canon_tier3_local": 0,
                     "canon_tier3_full": 0,
                     "job": name,
@@ -1016,8 +1017,7 @@ class BFSChecker:
     def _coverage_fields(self, depth, cov, seen_len, depth_counts) -> dict:
         """Coverage-event payload (events.COVERAGE_KEYS). The host engine
         keeps one flat sorted seen array (plus the in-wave probe set), so
-        the dedup-structure gauges are trivial and there is no canon
-        memo."""
+        the dedup-structure gauges are trivial."""
         return {
             "depth": depth,
             "actions": [[int(x) for x in row] for row in cov],
@@ -1028,7 +1028,6 @@ class BFSChecker:
             "seen_real": int(seen_len),
             "probe_runs": 2,  # global seen + current-wave fingerprints
             "frontier_hist": [int(x) for x in depth_counts],
-            "canon_memo_fill": None,  # host engine has no canon memo
         }
 
     def grow_for_overflow(self, bits: int) -> dict | None:
@@ -1111,7 +1110,6 @@ class BFSChecker:
             "journal_cap": 0,
             "max_seen_cap": 0,
             "valid_cap": 0,
-            "canon_memo_cap": 0,
             "symmetry": bool(self.canon.symmetry),
             "invariants": list(self.invariants),
             "action_names": list(getattr(self.model, "ACTION_NAMES", ())),

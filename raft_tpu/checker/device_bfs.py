@@ -62,12 +62,12 @@ from ..obs import (
     COMPILES, MemWatch, NULL_TELEMETRY, device_budget, stage, traced_run,
 )
 from ..obs.events import hashv_of
-from ..ops.hashing import U64_MAX, ne_u64, sort_u64
+from ..ops.hashing import U64_MAX, sort_u64
 from ..ops.symmetry import Canonicalizer, canon_chunk
 from ..resilience import ckpt as rckpt
 from ..resilience.errors import CapacityOverflow
 from .bfs import CheckResult, Violation
-from .lsm import CanonMemo, pow2_at_least
+from .lsm import pow2_at_least
 from .util import (
     GROWTH, HEADROOM, I32_MAX, dedup_plan, dense_prefix_sel, emit_append,
     first_new, jit_with_donation, next_cap, rank_counts, rank_onehot,
@@ -113,20 +113,20 @@ class DeviceBFS:
 
     # the in-program stats vector, i64[N_STATS]: [wave new count, journal
     # count, cumulative generated, cumulative terminal, overflow bits,
-    # then cumulative canon counts: memo hits, tier-3 local lanes,
-    # tier-3 full lanes]. STATS_KEEP is what a wave's first chunk keeps
-    # of it (the wave-new and overflow lanes reset in-program).
+    # then cumulative canon counts: in-chunk duplicates, tier-3 local
+    # lanes, tier-3 full lanes]. STATS_KEEP is what a wave's first chunk
+    # keeps of it (the wave-new and overflow lanes reset in-program).
     N_STATS = 8
     STATS_KEEP = (0, 1, 1, 1, 0, 1, 1, 1)
 
     # Donation contract for the wave program: argument indices of
     # the capacity-shaped loop carries updated in place every dispatch
-    # (next_buf, jparent, jcand, viol, stats, memo, cov). The frontier
+    # (next_buf, jparent, jcand, viol, stats, cov). The frontier
     # (argnum 0) is deliberately NOT donated — the host swaps it with
     # next_buf between waves. analysis/donation.py verifies the lowered
     # program aliases exactly these, so an edit that drops one is named
     # before it costs a per-wave buffer copy.
-    WAVE_DONATE = (1, 2, 3, 4, 5, 6, 7)
+    WAVE_DONATE = (1, 2, 3, 4, 5, 6)
 
     def __init__(
         self,
@@ -144,7 +144,6 @@ class DeviceBFS:
         max_seen_cap: int = 1 << 25,
         max_journal_cap: int = 1 << 25,
         fingerprint_seed: int = 0,
-        canon_memo_cap: int = 1 << 21,
     ):
         # constructor kwargs, for _rebuild (supervisor growth overrides)
         self._ctor_kw = {k: v for k, v in locals().items() if k != "self"}
@@ -208,19 +207,7 @@ class DeviceBFS:
         self.canon = Canonicalizer.for_model(
             model, symmetry=symmetry, seed=fingerprint_seed
         )
-        # canon memo (checker/lsm.py CanonMemo geometry): HBM-resident
-        # direct-mapped table caching raw-view-hash -> canonical
-        # fingerprint across the whole run; duplicate successors (the
-        # majority past the first waves) skip the tiered canon entirely.
-        # Custom canonicalizers (make_canonicalizer models) that predate
-        # the memo surface fall back to the unmemoized path.
-        self._use_memo = (
-            canon_memo_cap > 0
-            and hasattr(self.canon, "fingerprints_memo")
-        )
-        self._memo = CanonMemo(canon_memo_cap if self._use_memo else 1)
-        self.MCAP = self._memo.MCAP
-        # donated: next_buf, jparent, jcand, viol, stats, memo, cov
+        # donated: next_buf, jparent, jcand, viol, stats, cov
         # (seen read-only; the donation set is a class attribute so the
         # static donation auditor — analysis/donation.py — can verify
         # the lowered aliasing against CARRY_NAMES independently)
@@ -386,13 +373,13 @@ class DeviceBFS:
                 expand_ovf, compact_ovf)
 
     @stage("canon")
-    def _st_canon(self, flatc, selv, memo):
-        """Stage 3: canonical fingerprints on compacted lanes only,
-        through the raw-keyed canon memo (duplicate successors skip the
-        tiered canon; invalid lanes come back masked to U64_MAX either
-        way). ``canon_n`` is i32[3]: the chunk's memo hits and the lanes
-        its canon routed to tier 3's local and full buckets."""
-        return canon_chunk(self.canon, self._use_memo, flatc, selv, memo)
+    def _st_canon(self, flatc, selv):
+        """Stage 3: canonical fingerprints on compacted lanes only, one
+        tiered canon per distinct raw view of the chunk (duplicate
+        successors skip it; invalid lanes come back masked to U64_MAX).
+        ``canon_n`` is i32[3]: the chunk's in-chunk duplicate lanes and
+        the lanes its canon routed to tier 3's local and full buckets."""
+        return canon_chunk(self.canon, flatc, selv)
 
     @stage("dedup")
     def _st_dedup(self, fps, occ, *runs):
@@ -509,19 +496,17 @@ class DeviceBFS:
         return next_buf, jparent, jcand, viol, stats, cov, new_run
 
     def _chunk_step(
-        self, frontier, next_buf, jparent, jcand, viol, stats, memo, cov,
+        self, frontier, next_buf, jparent, jcand, viol, stats, cov,
         cursor, fcount, base_gid, occ, first, *runs,
     ):
         """One chunk of the current wave (the four stage methods above,
         composed — one traced program). stats is the i64[N_STATS]
-        vector the class comment lays out; memo is the [MCAP, 2] canon
-        memo table (threaded through
-        the wave loop, donated); cov is the i64[n_actions, 3] per-action
-        coverage accumulator — [enabled, fired, new-distinct] per Next-
-        disjunct rank, cumulative over the WHOLE run (never reset, so
-        host snapshots are monotone); occ is bool[n_levels] (the binary
-        search of an unoccupied level is skipped via lax.cond; a merged
-        level is sorted either way); first marks the
+        vector the class comment lays out; cov is the i64[n_actions, 3]
+        per-action coverage accumulator — [enabled, fired, new-distinct]
+        per Next-disjunct rank, cumulative over the WHOLE run (never
+        reset, so host snapshots are monotone); occ is bool[n_levels]
+        (the binary search of an unoccupied level is skipped via
+        lax.cond; a merged level is sorted either way); first marks the
         wave's first chunk (resets the wave-new and overflow lanes
         in-program, saving a per-wave host->device stats upload —
         dispatch latency dominates small configs). Returns
@@ -533,7 +518,7 @@ class DeviceBFS:
         )
         (flatc, sel, selv, valid, rank, n_gen, terminal, expand_ovf,
          compact_ovf) = self._st_expand(frontier, cursor, fcount)
-        fps, memo, canon_n = self._st_canon(flatc, selv, memo)
+        fps, canon_n = self._st_canon(flatc, selv)
         new = self._st_dedup(fps, occ, *runs)
         (next_buf, jparent, jcand, viol, stats, cov,
          new_run) = self._st_finish(
@@ -541,7 +526,7 @@ class DeviceBFS:
             valid, rank, new, n_gen, terminal, expand_ovf, compact_ovf,
             canon_n, cursor, base_gid,
         )
-        return next_buf, jparent, jcand, viol, stats, memo, cov, new_run
+        return next_buf, jparent, jcand, viol, stats, cov, new_run
 
     def _wave_geom(self) -> int:
         """Ladder depth K: levels R0<<0 .. R0<<K, top >= pow2(FCAP), so a
@@ -554,7 +539,7 @@ class DeviceBFS:
         return K
 
     def _wave_step(
-        self, frontier, next_buf, jparent, jcand, viol, stats, memo, cov,
+        self, frontier, next_buf, jparent, jcand, viol, stats, cov,
         fcount, base_gid, occ, *runs,
     ):
         """One WAVE as a single dispatched program (round 5, verdict Next
@@ -564,8 +549,8 @@ class DeviceBFS:
         and syncs once instead of once per chunk: a 170-chunk deep wave
         is one launch and one host round-trip, and the device never
         idles between chunks waiting for the host.
-        Returns (next_buf, jparent, jcand, viol, stats, memo, cov,
-        *ladder); the host inserts the occupied ladder levels into the
+        Returns (next_buf, jparent, jcand, viol, stats, cov, *ladder);
+        the host inserts the occupied ladder levels into the
         RunLSM."""
         C = self.chunk
         K = self._wave_geom()
@@ -615,26 +600,26 @@ class DeviceBFS:
             )
 
         def body(carry):
-            (k, next_buf, jparent, jcand, viol, stats, memo, cov,
+            (k, next_buf, jparent, jcand, viol, stats, cov,
              *ladder) = carry
-            (next_buf, jparent, jcand, viol, stats, memo, cov,
+            (next_buf, jparent, jcand, viol, stats, cov,
              new_run) = self._chunk_step(
-                frontier, next_buf, jparent, jcand, viol, stats, memo, cov,
+                frontier, next_buf, jparent, jcand, viol, stats, cov,
                 k * C, fcount, base_gid, occ_all, jnp.asarray(False),
                 *runs, *ladder,
             )
             with stage("seen_merge"), jax.named_scope("cascade"):
                 ladder = cascade(k, new_run, ladder)
-            return (k + 1, next_buf, jparent, jcand, viol, stats, memo,
-                    cov, *ladder)
+            return (k + 1, next_buf, jparent, jcand, viol, stats, cov,
+                    *ladder)
 
         def cond(carry):
             return carry[0] * C < fcount
 
         out = lax.while_loop(
             cond, body,
-            (jnp.int32(0), next_buf, jparent, jcand, viol, stats, memo,
-             cov, *ladder0),
+            (jnp.int32(0), next_buf, jparent, jcand, viol, stats, cov,
+             *ladder0),
         )
         return out[1:]
 
@@ -692,8 +677,7 @@ class DeviceBFS:
                 stats = jnp.zeros((self.N_STATS,), jnp.int64)
                 cov = jnp.zeros((self.n_actions, 3), jnp.int64)
                 self._wave_fn(
-                    frontier, next_buf, jparent, jcand, viol, stats,
-                    self._memo.reset(), cov,
+                    frontier, next_buf, jparent, jcand, viol, stats, cov,
                     np.int32(0), np.int32(0), self._occ_one, seen,
                 )
                 continue
@@ -741,13 +725,12 @@ class DeviceBFS:
         jcand = sds((self.JCAP + self.VC,), jnp.int32)
         viol = sds((max(1, len(self.invariants)),), jnp.int32)
         stats = sds((self.N_STATS,), jnp.int64)
-        memo = sds((self.MCAP, 2), jnp.uint64)
         cov = sds((self.n_actions, 3), jnp.int64)
         occ = sds((1,), jnp.bool_)
         seen = sds((self._seen_sizes[0],), jnp.uint64)
         wave_carries = {
             1: "next_buf", 2: "jparent", 3: "jcand", 4: "viol",
-            5: "stats", 6: "memo", 7: "cov",
+            5: "stats", 6: "cov",
         }
 
         def site(fn):
@@ -757,7 +740,7 @@ class DeviceBFS:
         yield {
             "name": "wave", "fn": self._wave_fn,
             "args": (frontier, next_buf, jparent, jcand, viol, stats,
-                     memo, cov, i32s, i32s, occ, seen),
+                     cov, i32s, i32s, occ, seen),
             "carries": dict(wave_carries),
             "pinned": {0: "frontier"},
             "site": site(self._wave_step), "per_wave": 1,
@@ -992,10 +975,6 @@ class DeviceBFS:
         viol = jnp.full((max(1, len(self.invariants)),), I32_MAX, jnp.int32)
         stats = jnp.asarray(stats0)
         cov = jnp.asarray(cov_h)  # i64[n_actions, 3], cumulative
-        # fresh memo per run: the table is a pure cache (its contents
-        # never change a fingerprint), but starting cold keeps
-        # back-to-back runs of one engine instance comparable
-        memo = self._memo.reset()
         canon_prev = np.zeros((3,), np.int64)
 
         tel.open_run(self._telemetry_manifest())
@@ -1087,11 +1066,11 @@ class DeviceBFS:
                 with ph("dispatch"):
                     out = self._wave_fn(
                         frontier, next_buf, jparent, jcand, viol,
-                        stats, memo, cov, np.int32(fcount),
+                        stats, cov, np.int32(fcount),
                         np.int32(base_gid), self._occ_one, self._seen,
                     )
-                next_buf, jparent, jcand, viol, stats, memo, cov = out[:7]
-                ladder = out[7:]
+                next_buf, jparent, jcand, viol, stats, cov = out[:6]
+                ladder = out[6:]
                 # one host round-trip per wave: stats, the invariant
                 # fold and the coverage block fetched together (two
                 # device_gets are two syncs on small configs, where
@@ -1190,9 +1169,10 @@ class DeviceBFS:
                     gen_prev, depth_counts, cov_h,
                 )
                 last_ckpt = time.perf_counter()
-            # the wave's canon counts (memo hits, tier-3 local and full
-            # lanes), from the cumulative lanes of the same snapshot
-            wave_memo, wave_t3l, wave_t3f = (
+            # the wave's canon counts (in-chunk duplicates, tier-3 local
+            # and full lanes), from the cumulative lanes of the same
+            # snapshot
+            wave_dup, wave_t3l, wave_t3f = (
                 int(x) for x in stats_h[5:8] - canon_prev)
             canon_prev = stats_h[5:8].copy()
             wave_s_val = time.perf_counter() - tw
@@ -1222,7 +1202,6 @@ class DeviceBFS:
                     "seen": int(self._seen.shape[0]) * 8,
                     "wave_ladder": ladder_bytes,
                     "chunk": self.VC * (4 * W + 8),
-                    "memo": self.MCAP * 16 if self._use_memo else 0,
                 })
             if not (tel.active or metrics is not None or verbose):
                 continue
@@ -1237,9 +1216,9 @@ class DeviceBFS:
                     "generated_total": total,
                     "terminal": terminal,
                     "dedup_hit_rate": round(1.0 - ncount / max(1, wave_gen), 4),
-                    "canon_memo_hits": wave_memo,
-                    "canon_memo_hit_rate": round(
-                        wave_memo / max(1, wave_gen), 4
+                    "canon_dup_lanes": wave_dup,
+                    "canon_dup_rate": round(
+                        wave_dup / max(1, wave_gen), 4
                     ),
                     "canon_tier3_local": wave_t3l,
                     "canon_tier3_full": wave_t3f,
@@ -1324,27 +1303,15 @@ class DeviceBFS:
         self._jcand = jcand
         self._jcount = int(np.asarray(jax.device_get(stats))[1])
 
-        # canon-memo fill ratio: ONE device reduction, at run end only
-        # (mid-run it would add a per-wave sync), and computed whether or
-        # not telemetry is attached so instrumented and bare runs keep
-        # identical jax.device_get call counts (tests/test_obs.py)
-        if self._use_memo:
-            filled = int(np.asarray(jax.device_get(
-                jnp.sum(ne_u64(memo[:, 0], U64_MAX))
-            )))
-            memo_fill = round(filled / max(1, self.MCAP), 4)
-        else:
-            memo_fill = None
-
         dt = time.perf_counter() - t0
         if violation is not None:
             exit_cause = "violation"
         elif exit_cause is None:
             exit_cause = "exhausted"
         if tel.active:
-            cf = self._coverage_fields(depth, cov_h, scount, depth_counts)
-            cf["canon_memo_fill"] = memo_fill
-            tel.coverage(cf, final=True)
+            tel.coverage(
+                self._coverage_fields(depth, cov_h, scount, depth_counts),
+                final=True)
         run_stats = {
             **COMPILES.run_stats(comp_run), "dedup_plan": self._dedup_plan(),
             "canon_tier3_local": int(canon_prev[1]),
@@ -1365,7 +1332,7 @@ class DeviceBFS:
             "peak_frontier_cap": self.FCAP,
             "peak_journal_cap": self.JCAP,
             "seen_lanes": int(self._seen.shape[0]),
-            "canon_memo_hit_rate": round(
+            "canon_dup_rate": round(
                 int(canon_prev[0]) / max(1, gen_prev), 4),
             **run_stats,
             **(memwatch.summary_fields() if memwatch is not None else {}),
@@ -1509,7 +1476,6 @@ class DeviceBFS:
             "seen_real": int(scount),
             "probe_runs": 1,  # single consolidated seen run (round 5)
             "frontier_hist": [int(x) for x in depth_counts],
-            "canon_memo_fill": None,  # final snapshot only
         }
 
     def _telemetry_manifest(self) -> dict:
@@ -1530,7 +1496,6 @@ class DeviceBFS:
             "journal_cap": self.JCAP,
             "max_seen_cap": self.MAX_SCAP,
             "valid_cap": self.VC,
-            "canon_memo_cap": self.MCAP if self._use_memo else 0,
             "symmetry": bool(self.canon.symmetry),
             "invariants": list(self.invariants),
             "action_names": list(getattr(self.model, "ACTION_NAMES", ())),
@@ -1560,9 +1525,9 @@ class DeviceBFS:
         # changes the admissible permutation set — and therefore the
         # canonical representative — of signature-tied states), so all
         # pre-v5 checkpoints are refused on load; the refinement depth
-        # is part of the formula and recorded alongside. The canon memo
-        # and the tie-group-local tier-3 are value-preserving and do
-        # NOT participate in the identity.
+        # is part of the formula and recorded alongside. The in-chunk
+        # dedup and the tie-group-local tier-3 are value-preserving and
+        # do NOT participate in the identity.
         wl = getattr(self.canon, "refine_rounds", 1)
         return (
             f"{self.model.name}/{self.model.p}/W={self.W}"

@@ -37,38 +37,6 @@ def pow2_at_least(n: int) -> int:
     return p
 
 
-class CanonMemo:
-    """Device residency of the canon memo table that lives alongside the
-    fingerprint runs: a direct-mapped [*lead, MCAP, 2] u64 array of
-    (raw view hash, canonical fingerprint) rows, empty rows keyed
-    U64_MAX. The probe/insert logic is pure and traced into the chunk
-    program (``Canonicalizer.fingerprints_memo``); this class only owns
-    allocation/placement so both engines share one geometry:
-    DeviceBFS uses lead (), ShardedBFS (D,) with a per-shard table —
-    raw keys are shard-local (successors are memoized where they are
-    GENERATED, before the all-to-all routes their canonical
-    fingerprints to their owners).
-
-    ``cap`` rounds up to a power of two (the slot mask requires it);
-    ``put`` pins placement (e.g. a sharded device_put)."""
-
-    def __init__(self, cap: int, lead_shape: tuple[int, ...] = (),
-                 put=None):
-        self.MCAP = pow2_at_least(max(1, cap))
-        self._lead = tuple(lead_shape)
-        self._put = put if put is not None else jnp.asarray
-        self.table = None
-
-    def reset(self):
-        """(Re)allocate the all-empty table and return it. Called at the
-        start of every run: memo contents are a pure cache, but a fresh
-        table keeps consecutive runs of one engine byte-reproducible."""
-        self.table = self._put(
-            np.full(self._lead + (self.MCAP, 2), np.uint64(U64_MAX))
-        )
-        return self.table
-
-
 class RunLSM:
     """``r0``: level-0 run lanes (a chunk's emission width, pow2);
     ``topsz``: top-level lane cap (>= the engine's max seen capacity);

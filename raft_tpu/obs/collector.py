@@ -138,8 +138,8 @@ class MetricsCollector:
     def coverage(self, fields: dict, final: bool = False) -> None:
         """Cumulative coverage snapshot for the wave just reported (call
         after ``wave()``; shares its cadence so the JSONL pairs up). The
-        ``final`` snapshot — the engine's end-of-run cumulative totals,
-        the only one carrying the canon-memo fill ratio — always writes
+        ``final`` snapshot — the engine's end-of-run cumulative totals —
+        always writes
         and supersedes any cadence-skipped snapshot."""
         ev = {
             "event": "coverage", "wave": self._wave, **fields,

@@ -5,11 +5,11 @@ A run emits one JSON object per line (JSONL), in order:
   manifest   once per run(), before the first wave: everything a record
              of the run needs to cite its provenance — engine,
              fingerprint-formula identity (the checkpoint ident string),
-             capacities, memo geometry, device/mesh topology.
+             capacities, device/mesh topology.
   wave       one per BFS wave (at the collector's cadence): depth,
              frontier lanes, per-wave and cumulative generated/distinct,
-             canon-memo hit rate, terminal count, overflow bits, LSM
-             occupancy, wall seconds, rolling distinct/s.
+             canon's in-chunk duplicate rate, terminal count, overflow
+             bits, LSM occupancy, wall seconds, rolling distinct/s.
   stall      emitted by the wall-clock watchdog when a wave exceeds
              stall_factor x the rolling median wave time.
   coverage   cumulative state-space cartography at the collector's
@@ -17,11 +17,9 @@ A run emits one JSON object per line (JSONL), in order:
              before the summary: per-action [enabled, fired,
              new-distinct] counters (index == the model's ACTION_NAMES
              rank), seen-set lane occupancy, fingerprint probe depth,
-             frontier depth histogram, canon-memo fill ratio (final
-             snapshot only; null mid-run — reading the memo table
-             mid-run would cost a device sync).
+             frontier depth histogram.
   summary    once per run(), after the last wave: final counts, exit
-             cause, peak buffer geometry, fleet memo hit rate.
+             cause, peak buffer geometry, fleet in-chunk duplicate rate.
 
 The self-healing runtime (raft_tpu/resilience/) adds four low-volume
 events — ``retry`` / ``resume`` / ``ckpt_generation`` / ``preempt`` —
@@ -59,7 +57,7 @@ import re
 MANIFEST_KEYS = (
     "event", "engine", "ident", "hashv", "model", "platform", "device",
     "device_count", "chunk", "frontier_cap", "journal_cap",
-    "max_seen_cap", "valid_cap", "canon_memo_cap", "symmetry",
+    "max_seen_cap", "valid_cap", "symmetry",
     "invariants", "action_names", "when",
 )
 
@@ -68,7 +66,7 @@ MANIFEST_KEYS = (
 # time. The benchmark's scope_time reader is held to this tuple.
 TIMELINE_STAGES = (
     "expand",      # guard pass + budgeted sparse apply (or dense expand)
-    "canon",       # canonical fingerprints (memoized symmetry reduction)
+    "canon",       # canonical fingerprints (one canon a raw view of a chunk)
     "dedup",       # seen-set probes + intra-wave first-occurrence
     "emit",        # cursor-append emit + coverage + invariants + stats
     "exchange",    # sharded only: the all-to-all pair on the ICI
@@ -106,11 +104,15 @@ TIMELINE_STAGES = (
 # that grew a buffer) — and compiles/compile_s: programs the iteration
 # loaded, compiled or read from the persistent cache, and the seconds
 # that took (obs/compiles.py).
+# canon_dup_lanes: valid lanes that shared an earlier lane's raw view in
+# their chunk-step and took its fingerprint instead of the permutations
+# (ops/symmetry.py fingerprints_dedup), summed over the wave's
+# chunk-steps; canon_dup_rate: the same over generated.
 # canon_tier3_local / canon_tier3_full: lanes the wave's canon routed to
 # tier 3's two buckets (ops/symmetry.py: the tie-group-local tables; the
 # S!-table masked min, which on a layout without tiers, S <= 4, is every
-# lane canonicalised). They count representatives that missed the memo,
-# so together they never exceed generated - canon_memo_hits. 0 on the
+# lane canonicalised). They count representatives of the in-chunk dedup,
+# so together they never exceed generated - canon_dup_lanes. 0 on the
 # host engines, which have no tiered canon. From the stats vector the
 # wave already fetched: zero extra device syncs.
 # hbm_frac: analytic live-bytes / budget from obs/memwatch.py (null when
@@ -118,7 +120,7 @@ TIMELINE_STAGES = (
 WAVE_KEYS = (
     "event", "wave", "depth", "frontier", "new", "distinct",
     "generated", "generated_total", "terminal", "dedup_hit_rate",
-    "canon_memo_hits", "canon_memo_hit_rate", "canon_tier3_local",
+    "canon_dup_lanes", "canon_dup_rate", "canon_tier3_local",
     "canon_tier3_full", "overflow_bits",
     "lsm_runs", "lsm_lanes", "wave_s", "elapsed_s", "distinct_per_s",
     "emit_rows", "emit_bytes", "frontier_fill",
@@ -137,20 +139,18 @@ STALL_KEYS = (
 # (occupancy histogram; the host engine reports one level); seen_real:
 # real (non-padding) fingerprints resident; probe_runs: sorted runs a
 # membership probe binary-searches (fingerprint probe length);
-# frontier_hist: distinct states first seen at each depth 0..d;
-# canon_memo_fill: filled/capacity of the canon memo, null until the
-# final snapshot (and when no memo is configured).
+# frontier_hist: distinct states first seen at each depth 0..d.
 COVERAGE_KEYS = (
     "event", "wave", "depth", "actions", "actions_total",
     "actions_fired", "seen_lanes", "seen_real", "probe_runs",
-    "frontier_hist", "canon_memo_fill", "final",
+    "frontier_hist", "final",
 )
 
 SUMMARY_KEYS = (
     "event", "engine", "ident", "exit_cause", "violation", "distinct",
     "total", "depth", "terminal", "seconds", "distinct_per_s",
     "exhausted", "waves", "stalls", "peak_frontier_cap",
-    "peak_journal_cap", "seen_lanes", "canon_memo_hit_rate",
+    "peak_journal_cap", "seen_lanes", "canon_dup_rate",
     "canon_tier3_local", "canon_tier3_full",
 )
 
@@ -219,7 +219,7 @@ SHARD_STALL_KEYS = (
 #               sets a new peak (so the stream stays low-volume and
 #               peak_bytes is monotone within a run by construction).
 #               ``breakdown`` maps a buffer family (frontier / chunk /
-#               seen / journal / memo / ...) to live bytes; ``frac`` =
+#               seen / journal / ...) to live bytes; ``frac`` =
 #               total_bytes / budget_bytes (may exceed 1.0 — that is
 #               the out-of-core planning signal).
 MEMWATCH_KEYS = (
@@ -310,12 +310,12 @@ def validate_event(ev: object, lineno: int | None = None) -> list[str]:
             )
         elif None not in tiers and all(
             isinstance(ev.get(k), int)
-            for k in ("generated", "canon_memo_hits")
-        ) and sum(tiers) > ev["generated"] - ev["canon_memo_hits"]:
+            for k in ("generated", "canon_dup_lanes")
+        ) and sum(tiers) > ev["generated"] - ev["canon_dup_lanes"]:
             problems.append(
                 f"{where}wave canon_tier3 lanes {tiers!r} exceed the "
-                f"lanes canonicalised less the memo's hits "
-                f"({ev['generated']} - {ev['canon_memo_hits']})"
+                f"lanes canonicalised less the in-chunk duplicates "
+                f"({ev['generated']} - {ev['canon_dup_lanes']})"
             )
         for key in ("device_s", "host_s", "ckpt_s", "tel_s", "dispatch_s",
                     "fetch_s", "merge_s", "grow_s", "compile_s"):

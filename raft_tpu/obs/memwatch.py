@@ -2,7 +2,7 @@
 
 The engines already know every buffer's geometry — frontier capacity
 and fill, the VC-wide chunk block, the seen-set ladder / LSM runs, the
-journal cursor, the canon memo table. ``MemWatch`` turns that geometry
+journal cursor. ``MemWatch`` turns that geometry
 into live-bytes per wave WITHOUT reading the device (no syncs, no
 allocator introspection — this is the planning model, not a profiler):
 each wave the engine hands it a ``{buffer family: live bytes}``

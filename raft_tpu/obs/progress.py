@@ -5,7 +5,7 @@ states generated, M distinct states, queue depth D") — the reference
 workflow assumes you watch it for hours. This renderer is the
 equivalent, fed from the telemetry wave-event stream:
 
-    Progress (depth 7): 1.2M generated, 310k distinct, 2,648/s, memo 71%
+    Progress (depth 7): 1.2M generated, 310k distinct, 2,648/s, dup 53%
 
 Throttled by wall clock (``every_s``); the first wave always prints so a
 short run is not silent. Stall events render immediately — a watchdog
@@ -38,7 +38,7 @@ class ProgressRenderer:
     # cannot drift apart
     CONSUMES = (
         "depth", "generated_total", "distinct", "distinct_per_s",
-        "canon_memo_hit_rate", "hbm_frac",
+        "canon_dup_rate", "hbm_frac",
         "generated", "canon_tier3_local", "canon_tier3_full",
     )
 
@@ -53,7 +53,7 @@ class ProgressRenderer:
             f"{format_count(ev['generated_total'])} generated, "
             f"{format_count(ev['distinct'])} distinct, "
             f"{ev['distinct_per_s']:,.0f}/s, "
-            f"memo {ev['canon_memo_hit_rate']:.0%}"
+            f"dup {ev['canon_dup_rate']:.0%}"
         )
         # observatory gauges render only when present and non-zero so
         # the base line (pinned by tests) is unchanged on engines /

@@ -195,13 +195,3 @@ def eq_u64(a, b):
     ah, al = split_u64(a)
     bh, bl = split_u64(b)
     return (ah == bh) & (al == bl)
-
-
-def memo_slot(fp, mcap: int):
-    """Direct-mapped slot of a u64 fingerprint in a table of ``mcap``
-    (power-of-two) rows: both u32 halves remixed through fmix32 — no
-    u64 arithmetic — so raw fingerprints that share a half still spread
-    across slots."""
-    hi, lo = split_u64(fp)
-    idx = mix32(lo ^ (mix32(hi + KB) + KA))
-    return (idx & np.uint32(mcap - 1)).astype(jnp.int32)

@@ -19,12 +19,14 @@ min lands on a different (equally canonical) orbit representative and
 tied-state fingerprints changed vs v4 (hashv=5 in the checkpoint
 identity, with the round count recorded alongside). Canonicalization
 itself is restructured around three compounding optimisations, all
-value-preserving given the signature: a direct-mapped canon memo table
-keyed by the raw (identity-permutation) view hash
-(``fingerprints_memo``), tie-group-LOCAL masked mins over per-pattern
-static tables for lanes whose tie groups stay small, and an adaptive
-blocked ``lax.while_loop`` budget replacing the old static ``B//8``
-compaction + whole-batch ``lax.cond`` fallback.
+value-preserving given the signature: one canon per distinct raw
+(identity-permutation) view hash of a chunk, by sorts alone
+(``fingerprints_dedup``; the cross-chunk memo table that stood beside it
+until PR 33 cost ten times what its hits saved, PERF.md section 6),
+tie-group-LOCAL masked mins over per-pattern static tables for lanes
+whose tie groups stay small, and an adaptive blocked ``lax.while_loop``
+budget replacing the old static ``B//8`` compaction + whole-batch
+``lax.cond`` fallback.
 
 Fingerprint formula v4 (round 5): identical STRUCTURE to v3 below, but
 all mixing arithmetic runs as two independent u32 streams combined into
@@ -107,11 +109,12 @@ from .hashing import (
     eq_u64,
     ge_u64,
     hash_lanes_pair,
-    memo_slot,
+    join_u64,
     mix32,
     ne_u64,
     seed_salts,
     sort_u64_with_idx,
+    split_u64,
 )
 from .packing import EMPTY, BitPacker, WidePacker
 from ..models.base import Layout
@@ -276,21 +279,18 @@ def _tie_pattern_tables(S: int):
     return tab, mask, local
 
 
-def canon_chunk(canon, use_memo: bool, states, valid, memo):
+def canon_chunk(canon, states, valid):
     """The engines' canon stage on one chunk's compacted lanes:
-    ``(fps, memo, canon_n)`` with invalid lanes masked to U64_MAX and
-    ``canon_n`` i32[3] = [memo hits, tier-3 local lanes, tier-3 full
-    lanes]. Through the raw-keyed memo when ``use_memo``; zeros from a
-    custom canonicalizer (``make_canonicalizer`` models), which has
-    neither a memo surface nor tiers to count."""
-    if use_memo:
-        fps, memo, n_hit, tiers = canon.fingerprints_memo(states, valid, memo)
-        return fps, memo, jnp.concatenate([n_hit[None], tiers])
-    if hasattr(canon, "fingerprints_tiers"):
-        fps, tiers = canon.fingerprints_tiers(states, valid)
-        return fps, memo, jnp.concatenate([jnp.zeros((1,), jnp.int32), tiers])
+    ``(fps, canon_n)`` with invalid lanes masked to U64_MAX and
+    ``canon_n`` i32[3] = [in-chunk duplicate lanes, tier-3 local lanes,
+    tier-3 full lanes]. Zeros from a custom canonicalizer
+    (``make_canonicalizer`` models), which has neither an in-chunk
+    dedup nor tiers to count."""
+    if hasattr(canon, "fingerprints_dedup"):
+        fps, n_dup, tiers = canon.fingerprints_dedup(states, valid)
+        return fps, jnp.concatenate([n_dup[None], tiers])
     fps = jnp.where(valid, canon._fingerprints(states), U64_MAX)
-    return fps, memo, jnp.zeros((3,), jnp.int32)
+    return fps, jnp.zeros((3,), jnp.int32)
 
 
 class Canonicalizer:
@@ -1085,12 +1085,6 @@ class Canonicalizer:
         (at 120+ perms the brute force is ~9x the whole chunk budget)."""
         return self._canon_view(states[:, : self.VL])[0]
 
-    def fingerprints_tiers(self, states, valid):
-        """``_fingerprints`` with invalid lanes masked to U64_MAX, and
-        the tier-3 lane counts of ``_canon_view`` over the valid ones."""
-        fps, tiers = self._canon_view(states[:, : self.VL], valid)
-        return jnp.where(valid, fps, U64_MAX), tiers
-
     def _canon_view(self, view, valid=None):
         """Tiered canonical hash of a [B, VL] view batch. Returns
         ``(fp, tiers)``; ``tiers`` is i32[2], the lanes among ``valid``
@@ -1264,77 +1258,70 @@ class Canonicalizer:
         )
         return fpp[:B]
 
-    # ---------------- raw-keyed canon memoization ----------------
+    # ---------------- in-chunk dedup by raw view ----------------
 
     def raw_fingerprints(self, states):
         """u64 [B] identity-permutation view hashes — the cheap raw key
-        the canon memo is indexed by (for symmetry=False this IS the
-        canonical fingerprint)."""
+        the in-chunk dedup groups lanes by (for symmetry=False this IS
+        the canonical fingerprint)."""
         return self._perm_hash(states[:, : self.VL])
 
-    def fingerprints_memo(self, states, valid, memo):
-        """Memoized canonical fingerprints of a [B, W] state batch.
+    def fingerprints_dedup(self, states, valid):
+        """Canonical fingerprints of a [B, W] state batch, one canon per
+        distinct raw view. Returns ``(fps, n_dup, tiers)`` with invalid
+        lanes masked to U64_MAX; ``n_dup`` is the valid lanes that
+        shared an earlier lane's raw view and so skipped the
+        permutations, ``tiers`` i32[2] the representatives that took
+        ``[tier3_local, tier3_full]`` (``_canon_view``): together at
+        most the valid lanes less ``n_dup``.
 
-        ``memo`` is a [MCAP, 2] u64 direct-mapped table (MCAP a power
-        of two): each row holds (raw view hash, canonical fingerprint),
-        empty rows keyed U64_MAX. Returns ``(fps, memo, n_hit, tiers)``
-        with invalid lanes masked to U64_MAX; ``tiers`` is i32[2], the
-        representatives that took ``[tier3_local, tier3_full]``
-        (``_canon_view``): together at most the valid lanes less the hits.
-
-        The miss path first dedups equal raw keys WITHIN the chunk
-        (sorted segments, one canon per distinct raw view — duplicate
-        successors inside a chunk are common), then drains the
-        representatives through the tiered canon in fixed-size blocks
-        of an adaptive-trip ``lax.while_loop``: a fully-memoized chunk
-        pays one probe, a cold chunk pays one canon per distinct raw
-        view. Insertion is always-overwrite, with key+value in ONE
-        row-atomic scatter so slot-colliding lanes can never interleave
-        one row's key with another's value; an evicted key simply
-        recomputes on its next miss. Memoization never changes a value
-        — the cached fingerprint was produced by the same tiered canon
-        under the same raw view."""
+        Sorts alone, no per-lane write: the raw keys are sorted (equal
+        views become segments — duplicate successors inside a chunk are
+        common), the segment heads drain through the tiered canon in
+        fixed-size blocks of an adaptive-trip ``lax.while_loop`` (a
+        chunk of one view pays one block, a chunk of distinct views one
+        canon a lane), the k-th head's fingerprint lands in slot k of a
+        dense buffer (the heads leave ``argsort`` in rising order), each
+        sorted lane reads its segment's slot, and one sort keyed on the
+        lanes' original indices brings the result back to lane order.
+        Deduplication never changes a value: a lane's fingerprint is
+        the tiered canon of its own raw view."""
         view = states[:, : self.VL]
         B = view.shape[0]
-        memo = jnp.asarray(memo)  # accept host tables (tests, tools)
         # derived from `view` for the loop carry's type under shard_map
         # (as _masked_min's init)
-        no_tiers = jnp.zeros((2,), jnp.int32) + (view[0, 0] & 0)
-        # the `memo` scope is the probe, the in-chunk dedup and the
-        # write; it is opened piecewise so that the tiers' scopes in the
-        # loop's body stay its siblings under `canon`, not its children
-        memo_scope = functools.partial(jax.named_scope, "memo")
-        with memo_scope():
+        zero = view[0, 0] & 0
+        no_tiers = jnp.zeros((2,), jnp.int32) + zero
+        # the `inchunk` scope is the raw hash, the sorts and the fill; it
+        # is opened piecewise so that the tiers' scopes in the loop's
+        # body stay its siblings under `canon`, not its children
+        inchunk = functools.partial(jax.named_scope, "inchunk")
+        with inchunk():
             raw = self._perm_hash(view)
         if not self.symmetry:
-            return (jnp.where(valid, raw, U64_MAX), memo,
-                    jnp.asarray(0, jnp.int32), no_tiers)
-        MCAP = memo.shape[0]
+            return jnp.where(valid, raw, U64_MAX), zero, no_tiers
         CB = min(B, max(64, B // 4))
-        with memo_scope():
-            slot = memo_slot(raw, MCAP)
-            row = memo[slot]  # [B, 2]
-            # a raw key equal to the empty sentinel (p = 2^-64) never
-            # hits: it recomputes every time rather than aliasing empty
-            # rows
-            hit = valid & eq_u64(row[:, 0], raw) & ne_u64(raw, U64_MAX)
-            need = valid & ~hit
-            n_hit = jnp.sum(hit).astype(jnp.int32)
-
-            # in-chunk dedup: sort the missed raw keys, canon only
-            # segment heads, forward-fill each segment from its head
-            sraw, order = sort_u64_with_idx(jnp.where(need, raw, U64_MAX))
-            is_head = jnp.concatenate(
+        with inchunk():
+            # a valid raw key equal to the sentinel (p = 2^-64) sorts
+            # with the padding and comes back masked, as an invalid lane
+            sraw, order = sort_u64_with_idx(jnp.where(valid, raw, U64_MAX))
+            real_s = ne_u64(sraw, U64_MAX)
+            head = real_s & jnp.concatenate(
                 [jnp.ones((1,), bool), ne_u64(sraw[1:], sraw[:-1])]
             )
-            head = is_head & ne_u64(sraw, U64_MAX)
             n_rep = jnp.sum(head)
-            psel = jnp.argsort(~head).astype(jnp.int32)  # head positions first
+            n_dup = (jnp.sum(valid) - n_rep).astype(jnp.int32)
+            # sorted lane -> its segment's representative, counted from 0
+            rank = jnp.maximum(jnp.cumsum(head.astype(jnp.int32)) - 1, 0)
+            # head positions first, in rising order: representative k
+            psel = jnp.argsort(~head, stable=True).astype(jnp.int32)
             psel = jnp.concatenate([psel, jnp.full((CB,), B, jnp.int32)])
             orderp = jnp.concatenate([order, jnp.full((1,), B, jnp.int32)])
             viewp = jnp.concatenate(
                 [view, jnp.zeros((1, self.VL), view.dtype)])
-            canon_sorted = jnp.full((B + 1,), U64_MAX, jnp.uint64)
+            # a whole number of blocks: dynamic_update_slice clamps a
+            # start that would run off the end
+            canon_rep = jnp.full((-(-B // CB) * CB,), U64_MAX, jnp.uint64)
             jcb = jnp.arange(CB, dtype=jnp.int32)
 
         def cond(c):
@@ -1342,30 +1329,22 @@ class Canonicalizer:
 
         def body(c):
             i, acc, tiers = c
-            with memo_scope():
+            with inchunk():
                 pos = lax.dynamic_slice(psel, (i * CB,), (CB,))
                 real = i * CB + jcb < n_rep
-                pos = jnp.where(real, pos, B)
-                block = viewp[orderp[pos]]
+                block = viewp[orderp[jnp.where(real, pos, B)]]
             cfp, t = self._canon_view(block, real)
-            with memo_scope():
-                return i + 1, acc.at[pos].set(cfp), tiers + t
+            with inchunk():
+                acc = lax.dynamic_update_slice(acc, cfp, (i * CB,))
+            return i + 1, acc, tiers + t
 
-        _, canon_sorted, tiers = lax.while_loop(
-            cond, body, (jnp.asarray(0, jnp.int32), canon_sorted, no_tiers)
+        _, canon_rep, tiers = lax.while_loop(
+            cond, body, (jnp.asarray(0, jnp.int32), canon_rep, no_tiers)
         )
-        with memo_scope():
-            hidx = lax.associative_scan(
-                jnp.maximum,
-                jnp.where(is_head, jnp.arange(B, dtype=jnp.int32), 0),
-            )
-            computed = (
-                jnp.zeros((B,), jnp.uint64)
-                .at[order]
-                .set(canon_sorted[:B][hidx])
-            )
-            fps = jnp.where(
-                hit, row[:, 1], jnp.where(need, computed, U64_MAX))
-            kv = jnp.stack([raw, fps], axis=1)
-            memo = memo.at[jnp.where(need, slot, MCAP)].set(kv, mode="drop")
-        return fps, memo, n_hit, tiers
+        with inchunk():
+            fh, fl = split_u64(jnp.where(real_s, canon_rep[rank], U64_MAX))
+            # `order` is a permutation of the lanes: sorting by it alone
+            # is the inverse permutation (util.first_new's return sort)
+            _, fh, fl = lax.sort((order, fh, fl), num_keys=1)
+            fps = join_u64(fh, fl)
+        return fps, n_dup, tiers

@@ -63,7 +63,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..checker.lsm import CanonMemo, RunLSM, pow2_at_least
+from ..checker.lsm import RunLSM, pow2_at_least
 from ..obs import (
     COMPILES, MemWatch, NULL_TELEMETRY, device_budget, stage, traced_run,
 )
@@ -73,7 +73,7 @@ from ..checker.util import (
     first_new, next_cap as _next_cap, rank_counts, rank_onehot,
 )
 from ..ops.hashing import (
-    U64_MAX, eq_u64, ne_u64, sort_u64, sort_u64_with_idx, split_u64,
+    U64_MAX, eq_u64, sort_u64, sort_u64_with_idx, split_u64,
 )
 from ..ops.symmetry import Canonicalizer, canon_chunk
 from ..resilience import ckpt as rckpt
@@ -95,8 +95,8 @@ class ShardedResult:
     exhausted: bool = True
     trace: list[tuple[str, dict]] | None = None
     metrics: list[dict] | None = None  # per-wave (SURVEY.md §5.5)
-    # fleet aggregates: canon-memo hits/rate summed over shards plus
-    # per-shard skew (always populated; cheap host arithmetic)
+    # fleet aggregates: canon's in-chunk duplicates and their rate over
+    # the shards, per-shard skew (always populated; cheap host arithmetic)
     stats: dict | None = None
     # fleet-summed per-action [enabled, fired, new-distinct] in
     # ACTION_NAMES rank order; None for models without the contract
@@ -139,8 +139,8 @@ class ShardedBFS:
     # (host-swapped with next_buf at the wave boundary), fc/bl/cursor are
     # scalars-per-shard, and occ plus the LSM runs are reused across
     # chunks — none of those donate.
-    #   chunk: next_buf, jps, jpl, jcand, jfp, viol, stats, memo, cov
-    STEP_DONATE = (2, 3, 4, 5, 6, 7, 8, 9, 10)
+    #   chunk: next_buf, jps, jpl, jcand, jfp, viol, stats, cov
+    STEP_DONATE = (2, 3, 4, 5, 6, 7, 8, 9)
 
     def __init__(
         self,
@@ -158,7 +158,6 @@ class ShardedBFS:
         max_frontier_cap: int = 1 << 20,
         max_seen_cap: int = 1 << 24,
         max_journal_cap: int = 1 << 24,
-        canon_memo_cap: int = 1 << 21,
     ):
         # constructor kwargs, captured before any normalization, so the
         # supervisor/fleet can rebuild this engine with overrides
@@ -220,21 +219,6 @@ class ShardedBFS:
             jit_kw={"out_shardings": self._sharding},
         )
         self.TOPSZ = self._lsm.TOPSZ
-        # canon memo is PER SHARD ([D, MCAP, 2]): successors are memoized
-        # on the chip that GENERATES them, keyed by the raw view hash,
-        # before the all-to-all routes canonical fps to their owners —
-        # so no memo state ever crosses ICI. Custom canonicalizers
-        # without the memo surface fall back to the unmemoized path.
-        self._use_memo = (
-            canon_memo_cap > 0
-            and hasattr(self.canon, "fingerprints_memo")
-        )
-        self._memo = CanonMemo(
-            canon_memo_cap if self._use_memo else 1,
-            lead_shape=(self.D,),
-            put=lambda h: jax.device_put(h, self._sharding),
-        )
-        self.MCAP = self._memo.MCAP
 
         self._chunk_fn_cache: dict[int, object] = {}
         self._occ_cache: dict[bytes, object] = {}
@@ -291,8 +275,8 @@ class ShardedBFS:
             fn = jax.jit(
                 self._shard_map(
                     self._chunk_step,
-                    in_specs=(spec,) * 11 + (P(), P(), spec) + (spec,) * n_runs,
-                    out_specs=(spec,) * 10,
+                    in_specs=(spec,) * 10 + (P(), P(), spec) + (spec,) * n_runs,
+                    out_specs=(spec,) * 9,
                 ),
                 donate_argnums=self.STEP_DONATE,
             )
@@ -329,7 +313,6 @@ class ShardedBFS:
         jfp = sds((D, self.JCAP + self.EPAD), jnp.uint64)
         viol = sds((D, max(1, len(self.invariants))), jnp.int32)
         stats = sds((D, self.N_STATS), jnp.int64)
-        memo = sds((D, self.MCAP, 2), jnp.uint64)
         cov = sds((D, self.n_actions, 3), jnp.int64)
         occ = sds((n_runs,), jnp.bool_)
         runs = tuple(
@@ -344,17 +327,16 @@ class ShardedBFS:
         yield {
             "name": "chunk", "fn": self._get_chunk_fn(n_runs),
             "args": (frontier, fc, next_buf, jps, jpl, jcand, jfp, viol,
-                     stats, memo, cov, i32s, occ, bl, *runs),
+                     stats, cov, i32s, occ, bl, *runs),
             "carries": {2: "next_buf", 3: "jps", 4: "jpl", 5: "jcand",
-                        6: "jfp", 7: "viol", 8: "stats", 9: "memo",
-                        10: "cov"},
+                        6: "jfp", 7: "viol", 8: "stats", 9: "cov"},
             "pinned": {0: "frontier"},
             "site": site(self._chunk_step), "per_wave": 1,
         }
 
     def _chunk_step(
         self, frontier, fcount, next_buf, jps, jpl, jcand, jfp, viol, stats,
-        memo, cov, cursor, occ, base_lgid, *runs,
+        cov, cursor, occ, base_lgid, *runs,
     ):
         """One chunk of the current wave on one chip.
 
@@ -364,15 +346,14 @@ class ShardedBFS:
         row's canonical fingerprint, the lane that makes the checkpoint
         mesh-portable (reshard routes rows by jfp mod D_new) and the
         wave-start LSM subtraction exact; viol [1,K]; occ bool[L]
-        (replicated);
-        runs: L sharded [1,lanes] sorted u64; memo [1,MCAP,2] shard-local
-        canon memo; cov [1,n_actions,3] i64 per-shard cumulative
+        (replicated); runs: L sharded [1,lanes] sorted u64;
+        cov [1,n_actions,3] i64 per-shard cumulative
         [enabled, fired, new] per action rank (enabled/fired tally on the
         GENERATING chip, new on the OWNER chip after the all-to-all);
         stats [1,S] i64 = [wave new, jcount, cum generated,
         cum terminal, ovf bits, routed lanes, then the cumulative canon
-        counts: memo hits, tier-3 local lanes, tier-3 full lanes]
-        (N_STATS lanes).
+        counts: in-chunk duplicate lanes, tier-3 local lanes, tier-3
+        full lanes] (N_STATS lanes).
         Returns (+ new_run [1,R0]).
         """
         # strip the leading local-block axis shard_map hands us
@@ -380,11 +361,10 @@ class ShardedBFS:
         next_buf = next_buf[0]
         jps, jpl, jcand, viol, stats = jps[0], jpl[0], jcand[0], viol[0], stats[0]
         jfp = jfp[0]
-        memo = memo[0]
         cov = cov[0]
         runs = [r[0] for r in runs]
-        send_pay, send_fps, memo, cov_gen, pre_stats = self._cs_pre(
-            frontier, fcount, memo, cursor, base_lgid
+        send_pay, send_fps, cov_gen, pre_stats = self._cs_pre(
+            frontier, fcount, cursor, base_lgid
         )
         # 5. ICI all-to-all: block d of my send goes to chip d; received
         # block d came from chip d (=> parent shard = recv row // RC)
@@ -398,10 +378,10 @@ class ShardedBFS:
         )
         return (
             next_buf[None], jps[None], jpl[None], jcand[None], jfp[None],
-            viol[None], stats[None], memo[None], cov[None], new_run[None],
+            viol[None], stats[None], cov[None], new_run[None],
         )
 
-    def _cs_pre(self, frontier, fcount, memo, cursor, base_lgid):
+    def _cs_pre(self, frontier, fcount, cursor, base_lgid):
         """Per-chip pre-exchange stages of one chunk (steps 1-4): expand,
         compact, canon, owner routing. Returns the all-to-all send blocks
         plus everything the post stage needs: ``cov_gen`` [K,2] =
@@ -409,8 +389,8 @@ class ShardedBFS:
         ([1,2] zeros when the model has no action ranks) and
         ``pre_stats`` [7] i64 = [n_gen, terminal, pre-exchange ovf bits
         (1=msg 2=valid 4=route), routed lanes, then the chunk's canon
-        counts as DeviceBFS._st_canon has them: memo hits, tier-3 local
-        lanes, tier-3 full lanes]."""
+        counts as DeviceBFS._st_canon has them: in-chunk duplicate lanes,
+        tier-3 local lanes, tier-3 full lanes]."""
         model, D, A, W = self.model, self.D, self.A, self.W
         C, VC, RC = self.chunk, self.VC, self.RC
         K = self.n_actions
@@ -465,11 +445,10 @@ class ShardedBFS:
             cand = sel % A
 
         with stage("canon"):
-            # 3. canonical fingerprints on the compacted lanes — memoized on
-            # the GENERATING chip (raw keys are shard-local; the all-to-all
-            # below only ever moves canonical fingerprints)
-            fps, memo, canon_n = canon_chunk(
-                self.canon, self._use_memo, flatc, selv, memo)
+            # 3. canonical fingerprints on the compacted lanes, one canon
+            # per distinct raw view, on the GENERATING chip (the
+            # all-to-all below only ever moves canonical fingerprints)
+            fps, canon_n = canon_chunk(self.canon, flatc, selv)
 
         with stage("exchange"), jax.named_scope("route"):
             # 4. route to owner chip = fp mod D: sort by owner, positional
@@ -517,7 +496,7 @@ class ShardedBFS:
             jnp.stack([enabled_k, fired_k], axis=1).astype(jnp.int64)
             if K else jnp.zeros((1, 2), jnp.int64)
         )
-        return send_pay, send_fps, memo, cov_gen, pre_stats
+        return send_pay, send_fps, cov_gen, pre_stats
 
     def _cs_post(
         self, recv_pay, recv_fps, next_buf, jps, jpl, jcand, jfp, viol,
@@ -707,8 +686,8 @@ class ShardedBFS:
     def _ckpt_ident(self) -> str:
         # hashv=5: k-round 1-WL refinement (ops/symmetry.py) changed the
         # canonical representative of signature-tied states; the
-        # refinement depth is part of the fingerprint formula. The canon
-        # memo is value-preserving and not part of the identity.
+        # refinement depth is part of the fingerprint formula. The in-chunk
+        # dedup is value-preserving and not part of the identity.
         # /D=<n>/ is PROVENANCE, not identity: resilience/ckpt.check_spec
         # strips it (mesh_neutral) when deciding reshardability, and the
         # resume path re-routes the payload when it differs.
@@ -1331,13 +1310,10 @@ class ShardedBFS:
                     depth=depth, distinct=distinct)
         metrics: list[dict] | None = [] if collect_metrics else None
         last_ckpt = time.perf_counter()
-        # fresh per-shard memo per run: a pure cache, but starting empty
-        # keeps consecutive runs of one engine byte-reproducible
-        state["memo"] = self._memo.reset()
         state["cov"] = jax.device_put(cov_hd, self._sharding)
-        memo_prev = 0
+        dup_prev = 0
         tiers_prev = np.zeros((2,), np.int64)
-        per_shard_memo = np.zeros(D, np.int64)
+        per_shard_dup = np.zeros(D, np.int64)
         wave_times: list[float] = []  # stall-watchdog rolling window
         # every wave gets the phase split + analytic HBM watermark
         memwatch = (
@@ -1400,14 +1376,12 @@ class ShardedBFS:
                         chunk_fn = self._get_chunk_fn(len(self._lsm.runs))
                         (state["next_buf"], state["jps"], state["jpl"],
                          state["jcand"], state["jfp"], state["viol"],
-                         state["stats"], state["memo"], state["cov"],
-                         new_run,
+                         state["stats"], state["cov"], new_run,
                          ) = chunk_fn(
                             state["frontier"], fc_dev,
                             state["next_buf"], state["jps"],
                             state["jpl"], state["jcand"], state["jfp"],
-                            state["viol"], state["stats"],
-                            state["memo"], state["cov"],
+                            state["viol"], state["stats"], state["cov"],
                             np.int32(cursor), occ_dev, bl_dev,
                             *self._lsm.runs,
                         )
@@ -1524,10 +1498,10 @@ class ShardedBFS:
             terminal = int(stats_h[:, 3].sum())
             wave_routed = int(stats_h[:, 5].sum()) - routed_prev
             routed_prev = int(stats_h[:, 5].sum())
-            memo_hits = int(stats_h[:, 6].sum())
-            wave_memo = memo_hits - memo_prev
-            memo_prev = memo_hits
-            per_shard_memo = stats_h[:, 6].copy()
+            dup_cum = int(stats_h[:, 6].sum())
+            wave_dup = dup_cum - dup_prev
+            dup_prev = dup_cum
+            per_shard_dup = stats_h[:, 6].copy()
             tiers_cum = stats_h[:, 7:9].sum(axis=0)
             wave_t3l, wave_t3f = (int(x) for x in tiers_cum - tiers_prev)
             tiers_prev = tiers_cum
@@ -1603,14 +1577,13 @@ class ShardedBFS:
                     # PER-CHIP analytic live bytes (the budget is one
                     # core's HBM): double-buffered frontier, 4-lane
                     # journal, this chip's LSM lanes, the chunk scratch
-                    # (payload + send/recv blocks), the canon memo
+                    # (payload + send/recv blocks)
                     frac = memwatch.update(depth, depth, {
                         "frontier": 2 * (self.FCAP + self.EPAD) * 4 * W,
                         "journal": (self.JCAP + self.EPAD) * (4 * 3 + 8),
                         "seen": int(self._lsm.lanes()) * 8,
                         "chunk": (self.VC + 2 * self.D * self.RC)
                         * (4 * (W + 3) + 8),
-                        "memo": self.MCAP * 16 if self._use_memo else 0,
                     })
                     hbm_frac = round(frac, 6)
                 wm = {
@@ -1622,9 +1595,9 @@ class ShardedBFS:
                     "generated_total": total,
                     "terminal": terminal + term_base,
                     "dedup_hit_rate": round(1.0 - global_new / max(1, wave_gen), 4),
-                    "canon_memo_hits": wave_memo,
-                    "canon_memo_hit_rate": round(
-                        wave_memo / max(1, wave_gen), 4
+                    "canon_dup_lanes": wave_dup,
+                    "canon_dup_rate": round(
+                        wave_dup / max(1, wave_gen), 4
                     ),
                     "canon_tier3_local": wave_t3l,
                     "canon_tier3_full": wave_t3f,
@@ -1709,10 +1682,10 @@ class ShardedBFS:
             exit_cause = "violation"
         elif exit_cause is None:
             exit_cause = "exhausted"
-        # fleet aggregates (satellite of the telemetry PR): memo hit
-        # totals + per-shard skew, from the SAME host stats the loop
-        # already fetched — also returned on ShardedResult.stats
-        fleet_rate = round(memo_prev / max(1, gen_prev), 4)
+        # fleet aggregates (satellite of the telemetry PR): in-chunk
+        # duplicate totals + per-shard skew, from the SAME host stats the
+        # loop already fetched — also returned on ShardedResult.stats
+        fleet_rate = round(dup_prev / max(1, gen_prev), 4)
         fleet_cov = cov_hd.sum(axis=0)
         run_stats = {
             **COMPILES.run_stats(comp_run), "dedup_plan": self._dedup_plan(),
@@ -1720,9 +1693,9 @@ class ShardedBFS:
             "canon_tier3_full": int(tiers_prev[1]),
         }
         fleet_stats = {
-            "canon_memo_hits": memo_prev,
-            "canon_memo_hit_rate": fleet_rate,
-            "shard_memo_hits": [int(x) for x in per_shard_memo],
+            "canon_dup_lanes": dup_prev,
+            "canon_dup_rate": fleet_rate,
+            "shard_dup_lanes": [int(x) for x in per_shard_dup],
             "shard_distinct": [int(x) for x in scounts],
             "shard_skew": round(
                 int(scounts.max()) / max(1, int(scounts.min())), 3),
@@ -1730,20 +1703,10 @@ class ShardedBFS:
             # what the run loaded into the process (obs/compiles.py)
             **run_stats,
         }
-        # final canon-memo fill ratio: one device reduction, done whether
-        # or not telemetry is attached so the zero-sync guarantee (equal
-        # device_get call counts) holds either way
-        if self._use_memo:
-            filled = int(np.asarray(jax.device_get(
-                jnp.sum(ne_u64(state["memo"][:, :, 0], U64_MAX))
-            )))
-            memo_fill = round(filled / max(1, self.D * self.MCAP), 4)
-        else:
-            memo_fill = None
         if tel.active:
-            cf = self._coverage_fields(depth, cov_hd, scounts, depth_counts)
-            cf["canon_memo_fill"] = memo_fill
-            tel.coverage(cf, final=True)
+            tel.coverage(
+                self._coverage_fields(depth, cov_hd, scounts, depth_counts),
+                final=True)
         tel.close_run({
             "engine": "sharded",
             "ident": self._ckpt_ident(),
@@ -1759,9 +1722,9 @@ class ShardedBFS:
             "peak_frontier_cap": self.FCAP,
             "peak_journal_cap": self.JCAP,
             "seen_lanes": int(self._lsm.lanes()),
-            "canon_memo_hit_rate": fleet_rate,
+            "canon_dup_rate": fleet_rate,
             # sharded extras (schema allows extra keys)
-            "shard_memo_hits": fleet_stats["shard_memo_hits"],
+            "shard_dup_lanes": fleet_stats["shard_dup_lanes"],
             "shard_skew": fleet_stats["shard_skew"],
             **run_stats,
             **(memwatch.summary_fields() if memwatch is not None else {}),
@@ -1907,7 +1870,6 @@ class ShardedBFS:
             "seen_real": int(scounts.sum()),
             "probe_runs": int(sum(occ)),
             "frontier_hist": [int(x) for x in depth_counts],
-            "canon_memo_fill": None,  # final snapshot only
         }
 
     def _telemetry_manifest(self) -> dict:
@@ -1927,7 +1889,6 @@ class ShardedBFS:
             "journal_cap": self.JCAP,
             "max_seen_cap": self.MAX_SCAP,
             "valid_cap": self.VC,
-            "canon_memo_cap": self.MCAP if self._use_memo else 0,
             "symmetry": bool(self.canon.symmetry),
             "invariants": list(self.invariants),
             "action_names": list(getattr(self.model, "ACTION_NAMES", ())),

@@ -34,7 +34,8 @@ mapping buffer families to non-negative byte counts.
 A `wave` event's canon_tier3_local and
 canon_tier3_full (lanes its canon routed to tier 3's buckets) must be
 non-negative ints that together do not exceed generated -
-canon_memo_hits. Job-tagged streams (the one
+canon_dup_lanes (the representatives its in-chunk dedup let through).
+Job-tagged streams (the one
 multiplexed file a `raft_tpu sweep --metrics-out` run writes) get the
 fleet rules: a `job` tag must be a non-empty string, each job's wave
 indices must be strictly increasing within its run, and every job

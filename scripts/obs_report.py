@@ -163,7 +163,7 @@ def render_run(events: list[dict]) -> str:
     else:
         for k in ("exit_cause", "violation", "distinct", "total", "depth",
                   "terminal", "seconds", "distinct_per_s", "exhausted",
-                  "waves", "stalls", "canon_memo_hit_rate",
+                  "waves", "stalls", "canon_dup_rate",
                   "hbm_peak_bytes", "hbm_peak_frac"):
             if k in summ:
                 out.append(f"- **{k}**: {_fmt(summ[k])}")
@@ -188,8 +188,6 @@ def render_run(events: list[dict]) -> str:
         out.append(
             f"{cov.get('actions_fired', 0)}/{cov.get('actions_total', 0)} "
             f"actions fired"
-            + (f"; canon memo fill {cov['canon_memo_fill']}"
-               if cov.get("canon_memo_fill") is not None else "")
         )
         for name in dead:
             out.append(f"- **WARNING**: action {name} never fired")

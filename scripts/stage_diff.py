@@ -13,9 +13,9 @@ action and state field that differ:
   guards         ``vmap(model.guards1)`` of the same rows
   sparse expand  ``DeviceBFS._st_expand``: guard pass, compaction and
                  the budgeted sparse apply, as the wave program runs them
-  canon          raw and canonical fingerprints and the memoized canon, on
-                 the CPU's rows of the sparse expand (so a fault upstream
-                 does not compound)
+  canon          raw and canonical fingerprints and the engines' canon
+                 (in-chunk dedup, then the tiers), on the CPU's rows of
+                 the sparse expand (so a fault upstream does not compound)
   invariants     each invariant kernel of the cfg, on the same rows
   wave           the fused wave program on the last level as its frontier,
                  the earlier levels in the seen run: stats, violations,
@@ -128,7 +128,6 @@ def stages(args, ref):
     import numpy as np
 
     from raft_tpu.checker.device_bfs import I32_MAX, U64_MAX, DeviceBFS
-    from raft_tpu.checker.lsm import CanonMemo
     from raft_tpu.models.registry import build_from_cfg, oracle_for_setup
     from raft_tpu.utils.cfg import parse_cfg
 
@@ -173,9 +172,9 @@ def stages(args, ref):
     canon = eng.canon
     out["canon_raw"] = np.asarray(canon.raw_fingerprints(flatc))
     out["canon_fp"] = np.where(selv, np.asarray(canon.fingerprints(flatc)), 0)
-    fps, _memo, _hit, tiers = jax.jit(canon.fingerprints_memo)(
-        flatc, selv, CanonMemo(1 << 21).reset())
-    out.update(canon_fp_memo=np.asarray(fps), canon_tiers=np.asarray(tiers))
+    fps, n_dup, tiers = jax.jit(canon.fingerprints_dedup)(flatc, selv)
+    out.update(canon_fp_dedup=np.asarray(fps),
+               canon_tiers=np.asarray([n_dup, *tiers]))
     for name in setup.invariants:
         holds = np.asarray(jax.jit(model.invariants[name])(flatc))
         out["invariant_" + name] = holds | ~selv
@@ -193,11 +192,11 @@ def stages(args, ref):
         jnp.zeros((eng.JCAP + eng.VC,), jnp.int32),
         jnp.zeros((eng.JCAP + eng.VC,), jnp.int32),
         jnp.full((max(1, len(eng.invariants)),), I32_MAX, jnp.int32),
-        jnp.zeros((eng.N_STATS,), jnp.int64), eng._memo.reset(),
+        jnp.zeros((eng.N_STATS,), jnp.int64),
         jnp.zeros((eng.n_actions, 3), jnp.int64),
         np.int32(len(frontier)), np.int32(0), eng._occ_one,
         jnp.asarray(seen))
-    nxt, jparent, jcand, viol, stats, _memo, cov, *ladder = jax.device_get(res)
+    nxt, jparent, jcand, viol, stats, cov, *ladder = jax.device_get(res)
     print(f"wave on {len(frontier)} rows: stats {stats.tolist()} "
           f"violations {viol.tolist()}", flush=True)
     keep = 4 * args.chunk

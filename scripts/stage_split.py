@@ -159,7 +159,7 @@ def main(argv=None):
         res["dedup_plan"] = got["stats"].get("dedup_plan")
         res["canon_lanes"] = {
             k: sum(w[k] for w in got["waves"])
-            for k in ("generated", "canon_memo_hits", "canon_tier3_local",
+            for k in ("generated", "canon_dup_lanes", "canon_tier3_local",
                       "canon_tier3_full") if k in got["waves"][0]}
         out[str(depth)] = res
         print(depth, json.dumps(res["by_scope_s"]), flush=True)

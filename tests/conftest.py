@@ -101,17 +101,16 @@ def gather_kernels(model, batch=8):
     return found
 
 
-def lower_memo_canon(model):
-    """Lowered text, with debug info, of the memoized canon of
-    ``model`` over a 256-lane batch; nothing compiled or run."""
+def lower_dedup_canon(model):
+    """Lowered text, with debug info, of the engines' canon (in-chunk
+    dedup, then the tiers) of ``model`` over a 256-lane batch; nothing
+    compiled or run."""
     import numpy as np
 
-    from raft_tpu.checker.lsm import CanonMemo
     from raft_tpu.ops.symmetry import Canonicalizer
 
     canon = Canonicalizer.for_model(model, symmetry=True)
-    return jax.jit(canon.fingerprints_memo).lower(
+    return jax.jit(canon.fingerprints_dedup).lower(
         jax.ShapeDtypeStruct((256, model.layout.W), np.int32),
         jax.ShapeDtypeStruct((256,), bool),
-        CanonMemo(1 << 8).reset(),
     ).as_text(debug_info=True)

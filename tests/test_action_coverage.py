@@ -128,10 +128,7 @@ def test_device_coverage_accumulation_invariants():
     assert covs[-1]["actions"] == res.coverage
     assert covs[-1]["actions_fired"] == K
     assert covs[-1]["frontier_hist"] == res.depth_counts
-    # memo fill is only read at the final snapshot (mid-run it would
-    # cost a device sync)
-    assert all(e["canon_memo_fill"] is None for e in covs[:-1])
-    assert covs[-1]["canon_memo_fill"] is not None
+    assert not any(e["final"] for e in covs[:-1])
 
 
 def _raft2_setup():
@@ -235,8 +232,7 @@ def _cov_event(wave, actions, final=False):
         "actions": actions, "actions_total": len(actions),
         "actions_fired": sum(1 for r in actions if r[1]),
         "seen_lanes": [8], "seen_real": 4, "probe_runs": 1,
-        "frontier_hist": [1] * (wave + 1), "canon_memo_fill": None,
-        "final": final,
+        "frontier_hist": [1] * (wave + 1), "final": final,
     }
 
 

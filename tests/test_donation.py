@@ -1,7 +1,7 @@
 """Buffer donation must actually stick (round 6).
 
 The wave program and the LSM merges declare donate_argnums so the big
-HBM carries (next frontier, journal, seen runs, memo) update in place.
+HBM carries (next frontier, journal, seen runs) update in place.
 Donation that silently fails is worse than none: XLA copies the buffer
 AND emits a UserWarning per dispatch. These tests pin:
 
@@ -75,8 +75,8 @@ def test_sharded_run_emits_no_donation_warning():
 
 
 def test_wave_program_consumes_donated_carries():
-    """The wave program donates next_buf/journal/viol/stats/memo/cov
-    (argnums 1..7): after a dispatch, those input buffers must be
+    """The wave program donates next_buf/journal/viol/stats/cov
+    (argnums 1..6): after a dispatch, those input buffers must be
     deleted — deleted means XLA aliased or freed them instead of keeping
     a live copy per wave."""
     dev = _device()
@@ -88,7 +88,6 @@ def test_wave_program_consumes_donated_carries():
         jcand=jnp.zeros((dev.JCAP + dev.VC,), jnp.int32),
         viol=jnp.full((len(INVS),), np.int32(2**31 - 1), jnp.int32),
         stats=jnp.zeros((dev.N_STATS,), jnp.int64),
-        memo=dev._memo.reset(),
         cov=jnp.zeros((dev.n_actions, 3), jnp.int64),
     )
     seen = jnp.full((dev._seen_sizes[0],), np.uint64(2**64 - 1), jnp.uint64)
@@ -127,7 +126,7 @@ def test_jit_with_donation_sticks_or_raises():
 @pytest.mark.slow
 def test_back_to_back_runs_identical():
     """One engine instance, two cold runs: donation must not leak the
-    first run's carries (or its memo/seen contents) into the second."""
+    first run's carries (or its seen contents) into the second."""
     dev = _device()
     r1 = dev.run(collect_metrics=True)
     r2 = dev.run(collect_metrics=True)

@@ -319,7 +319,7 @@ def test_stage_diff_rehearses_on_the_cpu(tmp_path):
     lines = r.stdout.splitlines()
     assert lines[-1] == "ALL STAGES EQUAL"
     stages = {ln.split()[0] for ln in lines if " differ" in ln}
-    assert {"dense_succs", "guards_valid", "sparse_rows", "canon_fp_memo",
+    assert {"dense_succs", "guards_valid", "sparse_rows", "canon_fp_dedup",
             "invariant_NoLogDivergence", "wave_rows"} <= stages
     # and without --platform cpu it refuses to call the CPU a chip
     r = _stage_diff(

@@ -19,7 +19,9 @@ import pytest
 from raft_tpu.models.registry import build_from_cfg, oracle_for_setup
 from raft_tpu.utils.cfg import parse_cfg
 
-from conftest import collect_states, eqns, lower_memo_canon
+from raft_tpu.ops.hashing import U64_MAX
+
+from conftest import collect_states, eqns, lower_dedup_canon
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CFG = os.path.join(ROOT, "configs", "flexible-raft", "FlexibleRaft.cfg")
@@ -92,11 +94,11 @@ def test_device_bfs_counts_match_oracle_to_depth_7(setup):
     assert [w["depth"] for w in rows] == list(range(1, depth + 1))
     assert not any(w["overflow_bits"] for w in rows)
     # the tier counters ride the wave row: lanes that took tier 3 are
-    # representatives that missed the memo, and this cfg has them from
+    # representatives of the in-chunk dedup, and this cfg has them from
     # wave 1 (a timed-out server beside four tied ones)
     for w in rows:
         t3 = w["canon_tier3_local"] + w["canon_tier3_full"]
-        assert 0 <= t3 <= w["generated"] - w["canon_memo_hits"], w
+        assert 0 <= t3 <= w["generated"] - w["canon_dup_lanes"], w
     assert rows[0]["canon_tier3_local"] == rows[0]["generated"]
     assert res.stats["canon_tier3_local"] == sum(
         w["canon_tier3_local"] for w in rows) > 0
@@ -141,7 +143,7 @@ def test_tiered_canon_is_brute_force_over_120_permutations(setup, sample):
     valid = np.ones(len(batch), bool)
     valid[-3:] = False  # lanes that are not to be counted
     sel = np.flatnonzero(valid)
-    fps, tiers = auto.fingerprints_tiers(batch, valid)
+    fps, tiers = auto._canon_view(batch[:, : auto.VL], valid)
     assert np.array_equal(np.asarray(fps)[sel], fa[sel])
     tiers = [int(x) for x in np.asarray(tiers)]
     assert tiers == [sum(3 <= largest[i] < 5 for i in sel),
@@ -150,37 +152,35 @@ def test_tiered_canon_is_brute_force_over_120_permutations(setup, sample):
     tier12_only = sum(largest[i] <= 2 for i in sel)
     assert tier12_only + sum(tiers) == len(sel)
 
-    # through the memo, cold: one canon per distinct raw view
-    from raft_tpu.checker.lsm import CanonMemo
-
-    fps_m, _memo, n_hit, tiers_m = auto.fingerprints_memo(
-        batch, valid, CanonMemo(1 << 12).reset())
-    assert int(n_hit) == 0
-    assert np.array_equal(np.asarray(fps_m)[sel], fa[sel])
+    # through the in-chunk dedup: one canon per distinct raw view
+    fps_d, n_dup, tiers_d = auto.fingerprints_dedup(batch, valid)
+    assert np.array_equal(np.asarray(fps_d)[sel], fa[sel])
+    assert np.all(np.asarray(fps_d)[~valid] == U64_MAX)
     raw = np.asarray(auto.raw_fingerprints(batch))
     _u, first = np.unique(raw[sel], return_index=True)
     reps = sel[first]
-    assert [int(x) for x in np.asarray(tiers_m)] == [
+    assert int(n_dup) == len(sel) - len(reps) >= 3
+    assert [int(x) for x in np.asarray(tiers_d)] == [
         sum(3 <= largest[i] < 5 for i in reps),
         sum(largest[i] == 5 for i in reps)]
 
 
 @pytest.fixture(scope="module")
-def memo_canon_lowered(setup):
-    """Lowered text of the memoized canon at five servers."""
-    return lower_memo_canon(setup.model)
+def dedup_canon_lowered(setup):
+    """Lowered text of the engines' canon at five servers."""
+    return lower_dedup_canon(setup.model)
 
 
 @pytest.mark.parametrize(
-    "scope", ["memo", "tier12", "tier3_local", "tier3_full"])
+    "scope", ["inchunk", "tier12", "tier3_local", "tier3_full"])
 def test_canon_scopes_nest_as_siblings_at_five_servers(
-        memo_canon_lowered, scope):
+        dedup_canon_lowered, scope):
     """What scripts/stage_split.py splits `canon` by: each scope is on
-    some op of the memoized canon, and the tiers, which run in the body
-    of the memo's loop, are not booked under `memo`."""
-    assert f"/{scope}/" in memo_canon_lowered
-    assert "memo/tier" not in memo_canon_lowered
-    assert "/memo/while/" not in memo_canon_lowered
+    some op of the engines' canon, and the tiers, which run in the body
+    of the in-chunk dedup's loop, are not booked under `inchunk`."""
+    assert f"/{scope}/" in dedup_canon_lowered
+    assert "inchunk/tier" not in dedup_canon_lowered
+    assert "/inchunk/while/" not in dedup_canon_lowered
 
 
 def test_tier12_looks_servers_up_without_a_gather(setup):

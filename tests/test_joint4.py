@@ -4,12 +4,12 @@ MaxValuesPerTerm 1, ReconfigType 2, 24 permutations), at the published
 constants and the registry's own bag width, against the pure-Python
 oracle: 1,042-lane rows, 224 candidate actions a state, and the canon
 branch a layout without tiers takes (S <= 4: the plain min over all S!
-tables on every lane that misses the memo), with the server-bitmask
+tables on every representative of a chunk's raw views), with the server-bitmask
 remaps of the configuration fields, the log entries and the N-word
 message keys.
 
 The cfg in the tree is reconstructed (its header says from what). One
-DeviceBFS verdict to depth 6 (memo on, as the CLI builds it) serves every
+DeviceBFS verdict to depth 6 (as the CLI builds it) serves every
 test of the engine here; the oracle's is its twin.
 """
 
@@ -28,7 +28,7 @@ import pytest
 from raft_tpu.models.registry import build_from_cfg, oracle_for_setup
 from raft_tpu.utils.cfg import parse_cfg
 
-from conftest import collect_states, lower_memo_canon, scatter_kernels
+from conftest import collect_states, lower_dedup_canon, scatter_kernels
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CFG = os.path.join(
@@ -220,23 +220,24 @@ def test_golden_prefix_is_what_the_oracle_and_the_engine_count(
 
 def test_every_canonicalised_lane_takes_the_full_table_at_four_servers(
         device_run):
-    """A layout without tiers: what misses the memo goes through the
-    24-table min, one lane a distinct raw view of a chunk (the memo's
-    in-chunk dedup lets one representative through), so the wave row's
-    `canon_tier3_full` is positive and at most generated - memo hits,
-    and `canon_tier3_local` is 0."""
+    """A layout without tiers: every representative of the in-chunk
+    dedup goes through the 24-table min, one lane a distinct raw view of
+    a chunk-step, so the wave row's `canon_tier3_full` is generated less
+    the in-chunk duplicates, and `canon_tier3_local` is 0."""
     _eng, res, _events, _path = device_run
     rows = res.metrics
     for w in rows:
         assert w["canon_tier3_local"] == 0, w
-        assert 0 < w["canon_tier3_full"] <= (
-            w["generated"] - w["canon_memo_hits"]), w
+        assert 0 < w["canon_tier3_full"] == (
+            w["generated"] - w["canon_dup_lanes"]), w
+        assert w["canon_dup_rate"] == round(
+            w["canon_dup_lanes"] / w["generated"], 4)
     # Init's eight successors are eight raw views
     assert rows[0]["canon_tier3_full"] == rows[0]["generated"] == 8
     assert res.stats["canon_tier3_local"] == 0
     assert res.stats["canon_tier3_full"] == sum(
         w["canon_tier3_full"] for w in rows)
-    assert sum(w["canon_memo_hits"] for w in rows) > 0
+    assert sum(w["canon_dup_lanes"] for w in rows) > 0
 
 
 def test_progress_line_and_summary_show_the_table_share(device_run):
@@ -248,9 +249,12 @@ def test_progress_line_and_summary_show_the_table_share(device_run):
     last = waves[-1]
     share = last["canon_tier3_full"] / last["generated"]
     line = ProgressRenderer(stream=io.StringIO()).render_wave(last)
-    assert f"tier3 {share:.0%}" in line and "memo " in line
+    assert f"tier3 {share:.0%}" in line
+    assert f"dup {last['canon_dup_rate']:.0%}" in line
     (summary,) = [ev for ev in events if ev["event"] == "summary"]
     assert summary["canon_tier3_local"] == 0
+    assert summary["canon_dup_rate"] == round(
+        sum(w["canon_dup_lanes"] for w in waves) / res.total, 4)
     assert summary["canon_tier3_full"] == res.stats["canon_tier3_full"] > 0
 
 
@@ -267,7 +271,7 @@ def test_metrics_schema_holds_the_tier_bound_at_four_servers(device_run):
         for ev in events:
             if ev["event"] == "wave" and ev["depth"] == DEPTH:
                 ev = dict(ev, canon_tier3_full=(
-                    ev["generated"] - ev["canon_memo_hits"] + 1))
+                    ev["generated"] - ev["canon_dup_lanes"] + 1))
             f.write(json.dumps(ev) + "\n")
     bad = subprocess.run([sys.executable, script, bad_path],
                          capture_output=True, text=True)
@@ -276,23 +280,23 @@ def test_metrics_schema_holds_the_tier_bound_at_four_servers(device_run):
 
 
 @pytest.fixture(scope="module")
-def memo_canon_lowered(setup):
-    """Lowered text of the memoized canon at four servers."""
-    return lower_memo_canon(setup.model)
+def dedup_canon_lowered(setup):
+    """Lowered text of the engines' canon at four servers."""
+    return lower_dedup_canon(setup.model)
 
 
-@pytest.mark.parametrize("scope", ["memo", "tier3_full"])
+@pytest.mark.parametrize("scope", ["inchunk", "tier3_full"])
 def test_canon_scopes_nest_as_siblings_at_four_servers(
-        memo_canon_lowered, scope):
-    """What scripts/stage_split.py splits `canon` by at S <= 4: the memo
-    and the S!-table min, which runs in the body of the memo's loop and
-    is not booked under it; the tiers a five-server layout has are not
-    traced at all."""
-    assert f"/{scope}/" in memo_canon_lowered
-    assert "memo/tier" not in memo_canon_lowered
-    assert "/memo/while/" not in memo_canon_lowered
-    assert "/tier12/" not in memo_canon_lowered
-    assert "/tier3_local/" not in memo_canon_lowered
+        dedup_canon_lowered, scope):
+    """What scripts/stage_split.py splits `canon` by at S <= 4: the
+    in-chunk dedup and the S!-table min, which runs in the body of the
+    dedup's loop and is not booked under it; the tiers a five-server
+    layout has are not traced at all."""
+    assert f"/{scope}/" in dedup_canon_lowered
+    assert "inchunk/tier" not in dedup_canon_lowered
+    assert "/inchunk/while/" not in dedup_canon_lowered
+    assert "/tier12/" not in dedup_canon_lowered
+    assert "/tier3_local/" not in dedup_canon_lowered
 
 
 def test_no_buffer_grows_after_the_wave_that_max_depth_ends(device_run):

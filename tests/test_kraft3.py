@@ -186,6 +186,48 @@ def test_device_bfs_counts_match_oracle_to_depth_10(device_run, oracle_run):
     assert not any(w["overflow_bits"] for w in rows)
 
 
+@pytest.fixture(scope="module")
+def sharded_run(setup):
+    """A one-device ShardedBFS verdict to depth 8: the other caller of
+    canon's in-chunk dedup."""
+    from raft_tpu.parallel.sharded import ShardedBFS
+
+    eng = ShardedBFS(setup.model, invariants=setup.invariants, symmetry=True,
+                     devices=jax.devices()[:1], chunk=256,
+                     frontier_cap=1 << 12, seen_cap=1 << 14)
+    return eng, eng.run(max_depth=8, collect_metrics=True)
+
+
+@pytest.mark.parametrize("engine", ["device", "sharded"])
+def test_every_representative_takes_the_table_and_the_rest_are_duplicates(
+        engine, request):
+    """Three servers have no tiers: a wave's lanes are its in-chunk
+    duplicates, which skip the permutations, and their representatives,
+    each of which takes the 6-table min. The counts are the golden's."""
+    _eng, res = request.getfixturevalue(f"{engine}_run")
+    with open(os.path.join(BENCH, "goldens", "kraft3.json")) as f:
+        golden = json.load(f)
+    depth = res.depth
+    assert [int(x) for x in res.depth_counts] == golden["depth_counts"][
+        : depth + 1]
+    if str(depth) in golden["totals"]:
+        assert {"total": res.total, "terminal": res.terminal} == golden[
+            "totals"][str(depth)]
+    rows = res.metrics
+    assert len(rows) == depth
+    for w in rows:
+        assert w["canon_tier3_local"] == 0
+        assert w["generated"] - w["canon_dup_lanes"] == w[
+            "canon_tier3_full"] > 0, w
+        assert w["canon_dup_rate"] == round(
+            w["canon_dup_lanes"] / w["generated"], 4)
+    # Init's three timeouts are three raw views; later waves repeat
+    assert rows[0]["canon_dup_lanes"] == 0
+    assert sum(w["canon_dup_lanes"] for w in rows) > res.total // 4
+    assert res.stats["canon_tier3_full"] == sum(
+        w["canon_tier3_full"] for w in rows)
+
+
 def test_golden_prefix_is_what_the_oracle_and_the_engine_count(
         device_run, oracle_run):
     """benchmark/goldens/kraft3.json, the pooled oracle run's record,

@@ -143,7 +143,7 @@ def test_dedup_lowering_follows_run_shapes(seen_lanes, searches):
         cached_model(RaftParams(
             n_servers=2, n_values=1, max_elections=1, max_restarts=0,
             msg_slots=16)),
-        chunk=4096, canon_memo_cap=0)
+        chunk=4096)
     assert (eng.VC, eng.R0, eng._wave_geom()) == (1 << 16, 1 << 16, 2)
     assert seen_lanes in eng._seen_sizes
     sds = jax.ShapeDtypeStruct

@@ -214,11 +214,11 @@ def test_schema_and_renderer_stay_in_sync():
     ev = dict.fromkeys(WAVE_KEYS, 0)
     ev.update(event="wave", depth=7, generated_total=1_200_000,
               distinct=310_000, distinct_per_s=2648.0,
-              canon_memo_hit_rate=0.71)
+              canon_dup_rate=0.53)
     line = ProgressRenderer().render_wave(ev)
     assert line == (
         "Progress (depth 7): 1.2M generated, 310k distinct, 2,648/s, "
-        "memo 71%"
+        "dup 53%"
     )
 
     out = io.StringIO()
@@ -336,24 +336,28 @@ def test_sharded_stream_and_fleet_stats(tmp_path):
     assert man["engine"] == "sharded" and man["device_count"] == 4
     assert tel.wave_events()[-1]["distinct"] == res.distinct
 
-    # satellite: aggregated fleet memo stats + per-shard skew on the
+    # satellite: aggregated fleet canon stats + per-shard skew on the
     # returned result
     assert res.stats is not None
-    for k in ("canon_memo_hits", "canon_memo_hit_rate", "shard_memo_hits",
+    for k in ("canon_dup_lanes", "canon_dup_rate", "shard_dup_lanes",
               "shard_distinct", "shard_skew", "coverage",
               "canon_tier3_local", "canon_tier3_full"):
         assert k in res.stats, k
-    # two servers have no tiers: every lane canonicalised takes the
-    # S!-table min, and the rows' lanes add up to the run's
+    # two servers have no tiers: every representative of the in-chunk
+    # dedup takes the S!-table min, and the rows' lanes add up to the
+    # run's
     waves = tel.wave_events()
     assert res.stats["canon_tier3_local"] == 0
     assert res.stats["canon_tier3_full"] == sum(
         w["canon_tier3_full"] for w in waves) > 0
-    assert all(w["canon_tier3_full"] <= w["generated"] - w["canon_memo_hits"]
+    assert all(w["canon_tier3_full"] == w["generated"] - w["canon_dup_lanes"]
                for w in waves)
+    assert res.stats["canon_dup_lanes"] == sum(
+        w["canon_dup_lanes"] for w in waves) == sum(
+        res.stats["shard_dup_lanes"]) > 0
     assert tel.last_summary["canon_tier3_full"] == res.stats[
         "canon_tier3_full"]
-    assert len(res.stats["shard_memo_hits"]) == 4
+    assert len(res.stats["shard_dup_lanes"]) == 4
     assert sum(res.stats["shard_distinct"]) == res.distinct
     # fleet-summed coverage: one row per action, new sums to distinct
     # beyond the inits
@@ -361,8 +365,8 @@ def test_sharded_stream_and_fleet_stats(tmp_path):
     assert len(res.coverage) == len(cached_model(SMALL).ACTION_NAMES)
     assert sum(r[2] for r in res.coverage) == res.distinct - res.depth_counts[0]
     assert res.stats["shard_skew"] >= 1.0
-    assert tel.last_summary["canon_memo_hit_rate"] == res.stats[
-        "canon_memo_hit_rate"
+    assert tel.last_summary["canon_dup_rate"] == res.stats[
+        "canon_dup_rate"
     ]
 
     # the offline digest: per-shard balance from the rows' shard_new,
@@ -413,26 +417,26 @@ def test_telemetry_run_carries_phase_split_and_watermarks(tmp_path):
 def test_progress_renderer_observatory_gauges():
     ev = dict.fromkeys(WAVE_KEYS, 0)
     ev.update(event="wave", depth=7, generated_total=100, distinct=50,
-              distinct_per_s=10.0, canon_memo_hit_rate=0.5,
+              distinct_per_s=10.0, canon_dup_rate=0.5,
               hbm_frac=0.5)
     line = ProgressRenderer().render_wave(ev)
-    assert line.endswith("memo 50%, hbm 50%")
+    assert line.endswith("dup 50%, hbm 50%")
     # a null/zero gauge leaves the pinned base line untouched
     ev.update(hbm_frac=0)
-    assert ProgressRenderer().render_wave(ev).endswith("memo 50%")
+    assert ProgressRenderer().render_wave(ev).endswith("dup 50%")
     # lanes the canon routed to tier 3, as a share of the wave's lanes
     ev.update(generated=200, canon_tier3_local=30, canon_tier3_full=20)
-    assert ProgressRenderer().render_wave(ev).endswith("memo 50%, tier3 25%")
+    assert ProgressRenderer().render_wave(ev).endswith("dup 50%, tier3 25%")
 
 
 def test_wave_tier_counters_schema_rule():
     from raft_tpu.obs.events import validate_event
 
     ev = dict.fromkeys(WAVE_KEYS, 0)
-    ev.update(event="wave", generated=100, canon_memo_hits=40,
+    ev.update(event="wave", generated=100, canon_dup_lanes=40,
               canon_tier3_local=35, canon_tier3_full=25)
     assert validate_event(ev) == []
-    # more tier-3 lanes than lanes that missed the memo
+    # more tier-3 lanes than representatives of the in-chunk dedup
     (problem,) = validate_event(dict(ev, canon_tier3_full=26))
     assert "exceed" in problem
     (problem,) = validate_event(dict(ev, canon_tier3_local=-1))
@@ -746,19 +750,38 @@ def _lowered_text(engine: str, program: str) -> str:
     ("sharded", "chunk", "emit"),
     # canon's nested scopes (a layout without tiers has these two; the
     # five-server ones are in test_flexraft5.py)
-    ("device", "wave", "canon/memo"),
+    ("device", "wave", "canon/inchunk"),
     ("device", "wave", "canon/tier3_full"),
-    ("sharded", "chunk", "canon/memo"),
+    ("sharded", "chunk", "canon/inchunk"),
     ("sharded", "chunk", "canon/tier3_full"),
 ])
 def test_stage_scope_in_lowered_program(engine, program, stage):
     assert stage.split("/")[0] in TIMELINE_STAGES
     # a location reads "jit(_wave_step)/while/body/canon/...", or, inside
     # a shard_map, starts at the scope: "canon/..."
-    # (a nested scope may sit below control flow: "canon/while/body/memo/")
+    # (a nested scope may sit below control flow: "canon/while/body/inchunk/")
     path = '/(?:[^"]*/)?'.join(stage.split("/"))
     assert re.search(rf'["/]{path}/', _lowered_text(engine, program)), (
         f"no op of {engine}:{program} carries the {stage!r} scope")
+
+
+@pytest.mark.parametrize("engine,program", [
+    ("device", "wave"), ("sharded", "chunk")])
+def test_inchunk_dedup_of_the_engines_programs_has_no_scatter_and_no_table(
+        engine, program):
+    """The program an engine dispatches, not the canon alone: nothing
+    under `canon/inchunk` writes by a scatter, and no two-column u64
+    table rides the program's arguments (the cross-chunk memo went in
+    PR 33; what is left of u64 there are the seen runs and, sharded,
+    the journal's fingerprints)."""
+    text = _lowered_text(engine, program)
+    under = [ln for ln in text.splitlines()
+             if re.search(r'["/]canon/(?:[^"]*/)?inchunk/', ln)]
+    assert any("/sort" in ln for ln in under)  # the walk sees in
+    assert not [ln for ln in under if "scatter" in ln]
+    (main,) = [ln for ln in text.splitlines()
+               if "func.func public @main" in ln]
+    assert not re.search(r"x2xui64>", main.split(") -> ")[0]), main
 
 
 def test_stage_scope_in_lsm_merge_program():

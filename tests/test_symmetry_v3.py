@@ -30,7 +30,7 @@ from raft_tpu.oracle.raft_oracle import RaftOracle
 from raft_tpu.ops.hashing import U64_MAX
 from raft_tpu.ops.symmetry import Canonicalizer
 
-from conftest import collect_states
+from conftest import collect_states, lower_dedup_canon
 
 
 def raft3():
@@ -325,57 +325,106 @@ def test_bag_multiset_hash_slot_order_free():
     assert np.array_equal(f1, f2)
 
 
-def _fresh_memo(cap):
-    return np.full((cap, 2), np.uint64(U64_MAX))
+def _dedup(auto, batch, valid):
+    """``fingerprints_dedup`` as numpy: (fps, n_dup, [local, full])."""
+    fps, n_dup, tiers = auto.fingerprints_dedup(batch, valid)
+    return np.asarray(fps), int(n_dup), [int(x) for x in np.asarray(tiers)]
+
+
+def _host_dups(auto, batch, valid):
+    """Valid lanes whose raw view an earlier valid lane has, on the host."""
+    raw = np.asarray(auto.raw_fingerprints(batch))[valid]
+    return len(raw) - len(np.unique(raw))
 
 
 @pytest.mark.parametrize("name", ["raft3", "raft5"])
-def test_memo_cold_equals_plain(name):
-    # a cold (all-empty) memo pass computes every fingerprint through
-    # the same tiered canon — bit-identical to the unmemoized entry
+def test_dedup_equals_plain(name):
+    # one canon per distinct raw view gives every lane, duplicated and
+    # Init-repeated rows among them, what the plain entry gives it
     model, _oracle, _states, vecs = states_of(name, depth=3, cap=80)
     reps = np.repeat(model.init_states(), 40, axis=0)
     batch = np.concatenate([vecs, reps, vecs], axis=0).astype(np.int32)
     auto, _ = canon_pair(model)
     valid = np.ones(len(batch), dtype=bool)
-    plain = np.asarray(auto.fingerprints(batch))
-    cold, memo1, n_hit, _tiers = auto.fingerprints_memo(
-        batch, valid, _fresh_memo(1 << 12))
-    assert np.array_equal(np.asarray(cold), plain)
-    assert int(n_hit) == 0
-
-    # warm pass over the same batch: hits must return the SAME values
-    warm, _memo2, n_hit2, _tiers = auto.fingerprints_memo(batch, valid, memo1)
-    assert np.array_equal(np.asarray(warm), plain)
-    assert int(n_hit2) > 0
+    fps, n_dup, tiers = _dedup(auto, batch, valid)
+    assert np.array_equal(fps, np.asarray(auto.fingerprints(batch)))
+    assert n_dup == _host_dups(auto, batch, valid) >= len(vecs) + 39
+    assert sum(tiers) <= len(batch) - n_dup
 
 
-def test_memo_invalid_lanes_masked():
+def test_dedup_invalid_lanes_masked():
     model, _oracle, _states, vecs = states_of("raft3", depth=3, cap=60)
     auto, _ = canon_pair(model)
-    valid = np.arange(len(vecs)) % 3 != 0
-    fps, _memo, _n, _tiers = auto.fingerprints_memo(
-        vecs.astype(np.int32), valid, _fresh_memo(1 << 10))
-    fps = np.asarray(fps)
+    batch = np.concatenate([vecs, vecs[:20]]).astype(np.int32)
+    valid = np.arange(len(batch)) % 3 != 0
+    fps, n_dup, _tiers = _dedup(auto, batch, valid)
     assert np.all(fps[~valid] == U64_MAX)
-    plain = np.asarray(auto.fingerprints(vecs))
-    assert np.array_equal(fps[valid], plain[valid])
-
-
-def test_memo_correct_across_eviction():
-    # a 2-slot table under a few hundred distinct keys evicts on nearly
-    # every insert; values must stay exactly the cold canon regardless —
-    # eviction only costs recomputation, never correctness
-    model, _oracle, _states, vecs = states_of("raft5", depth=3, cap=100)
-    reps = np.repeat(model.init_states(), 20, axis=0)
-    batch = np.concatenate([vecs, reps], axis=0).astype(np.int32)
-    auto, _ = canon_pair(model)
-    valid = np.ones(len(batch), dtype=bool)
     plain = np.asarray(auto.fingerprints(batch))
-    memo = _fresh_memo(2)
-    for _ in range(3):  # repeated passes churn the tiny table
-        fps, memo, _n, _tiers = auto.fingerprints_memo(batch, valid, memo)
-        assert np.array_equal(np.asarray(fps), plain)
+    assert np.array_equal(fps[valid], plain[valid])
+    # an invalid lane is nobody's duplicate and nobody's representative
+    assert n_dup == _host_dups(auto, batch, valid) > 0
+    none = np.zeros(len(batch), bool)
+    fps, n_dup, tiers = _dedup(auto, batch, none)
+    assert np.all(fps == U64_MAX) and n_dup == 0 and tiers == [0, 0]
+
+
+@pytest.mark.parametrize("name", ["raft3", "raft5"])
+def test_dedup_one_view_many_times(name):
+    # a chunk that is one raw view B times over is one representative:
+    # Init is all-tied, so that one lane takes the S!-table min, and the
+    # loop makes one trip
+    model, _oracle = CASES[name]()
+    B = 200
+    batch = np.repeat(model.init_states()[:1], B, axis=0).astype(np.int32)
+    auto, _ = canon_pair(model)
+    fps, n_dup, tiers = _dedup(auto, batch, np.ones(B, bool))
+    assert n_dup == B - 1 and tiers == [0, 1]
+    assert np.all(fps == np.asarray(auto.fingerprints(batch[:1]))[0])
+
+
+def test_dedup_more_representatives_than_a_block():
+    # 150 lanes drain in blocks of 64: three trips of the loop, the last
+    # block written at the end of a 192-slot buffer and part filled;
+    # shuffled, so the return sort has a permutation to undo
+    model, _oracle, _states, vecs = states_of("raft5", depth=4, cap=150)
+    rng = np.random.default_rng(33)
+    batch = vecs[rng.permutation(len(vecs))[:150]].astype(np.int32)
+    batch[100:110] = batch[:10]
+    auto, _ = canon_pair(model)
+    valid = np.ones(len(batch), bool)
+    assert len(batch) == 150
+    fps, n_dup, _tiers = _dedup(auto, batch, valid)
+    assert n_dup == _host_dups(auto, batch, valid) == 10
+    assert len(batch) - n_dup > 2 * 64
+    assert np.array_equal(fps, np.asarray(auto.fingerprints(batch)))
+
+
+def _joint4():
+    from raft_tpu.models.registry import build_from_cfg
+    from raft_tpu.utils.cfg import parse_cfg
+
+    from test_joint4 import CFG
+
+    return (build_from_cfg(parse_cfg(CFG)).model,)
+
+
+@pytest.mark.parametrize(
+    "build", [raft3, flexraft5, _joint4, kraft3],
+    ids=["raft3", "flexraft5", "joint4", "kraft3"])
+def test_inchunk_dedup_lowers_to_sorts_alone(build):
+    """Strict: a per-lane write costs the chip by the chunk's capacity
+    (the un-sort, the table's write and the loop's ``.at[pos].set`` were
+    28 % of raft3-wide's device time, PERF.md section 6, PR 33). Nothing
+    under ``canon/inchunk`` is a scatter, the program takes the rows and
+    their mask and no table, and the two sorts are there."""
+    text = lower_dedup_canon(build()[0])
+    under = [ln for ln in text.splitlines() if "/inchunk/" in ln]
+    assert any("/inchunk/sort" in ln for ln in under)  # the walk sees in
+    assert not [ln for ln in under if "scatter" in ln]
+    (main,) = [ln for ln in text.splitlines()
+               if "func.func public @main" in ln]
+    assert main.count("%arg") == 2 and "ui64" not in main.split("->")[0], main
+    assert text.count("stablehlo.sort") >= 2
 
 
 def test_seeded_family_differs():
