@@ -61,7 +61,7 @@ def build_engine(cfg_path: str, engine: str, params: dict, devices):
 
 def ident(engine) -> str:
     """The engine's identity string (model parameters, row width,
-    symmetry): what the harness's tests hold against BENCH_ROWS row2."""
+    symmetry), for the run's log."""
     return engine._ckpt_ident()
 
 

@@ -25,6 +25,7 @@ from __future__ import annotations
 import os
 import shutil
 import statistics
+import sys
 import time
 import traceback
 
@@ -97,6 +98,8 @@ def timed_verdict(engine, depth: int, golden: dict, env, tag) -> tuple:
     problems = compare(got, golden, depth)
     env.log({"event": "verdict", "n": tag, "seconds": took, "pieces": pieces,
              "distinct": got["distinct"], "problems": problems})
+    for problem in problems:  # what a run that is not correct leaves behind
+        print(f"benchmark: verdict {tag}: {problem[:300]}", file=sys.stderr)
     for row in got["waves"]:
         env.log({"event": "wave", "n": tag, **row})
     return took, pieces, got, problems
@@ -111,8 +114,9 @@ def memory_peak(devices) -> int:
 
 def wave_phases(waves, t0_ns: int, chunk: int) -> list:
     """The traced verdict's waves as spans on the trace's clock, from
-    the rows' own clocks (the program writes its wave annotations only
-    under --trace-dir): narrow where the frontier fits one chunk."""
+    the rows' own clocks (the program's own `wave` spans, in every trace
+    since PR 24, carry no such name): narrow where the frontier fits one
+    chunk."""
     return [(
         t0_ns + int((w["elapsed_s"] - w["wave_s"]) * 1e9),
         t0_ns + int(w["elapsed_s"] * 1e9),
@@ -197,6 +201,7 @@ def run(cell: dict, config: dict, traffic: dict, golden: dict, env) -> dict:
                 engine, depth, golden, env, "traced")
         finally:
             jax.profiler.stop_trace()
+        out["traced_at"] = clock()
         attempted += 1
         failed += int(bool(problems))
         if got is not None:
@@ -231,6 +236,14 @@ def run(cell: dict, config: dict, traffic: dict, golden: dict, env) -> dict:
         "failed": failed,
         "correct": (failed == 0 and attempted > 1
                     and window_entries == 0 and window_compiles == 0),
+        # what `correct` compares, each [number, limit]: every count is
+        # compared exactly, so a verdict is off the golden or it is not
+        "compared": {
+            "verdicts_off_golden": [failed, 0],
+            "verdicts_short_of_2": [max(0, 2 - attempted), 0],
+            "window_compiles": [window_compiles, 0],
+            "window_cache_entries": [window_entries, 0],
+        },
         "memory_peak_bytes": peak,
     })
     return out

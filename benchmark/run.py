@@ -7,14 +7,17 @@ Finds the cell by name, benchmark/workloads/<cell>.json, from there its
 configuration, benchmark/configs/<config>/, the configuration's golden,
 benchmark/goldens/<config>.json, its traffic mix,
 benchmark/traffic/<traffic>.json, and the mix's mode,
-benchmark/modes/<mode>.py; with --trace 1 each per-layer metric the cell
-lists, from benchmark/layer_metrics/<name>.json, and that metric's reader,
+benchmark/modes/<mode>.py; with --trace 1 each per-layer metric,
+benchmark/layer_metrics/<name>.json, that the cell's file lists or whose
+own file lists the cell, and that metric's reader,
 benchmark/readers/<kind>.py. A new cell, configuration, golden, traffic
 mix, mode, per-layer metric or reader is a new file, found by its name.
 
 The last line of standard output is one JSON object: correct, attempted,
-failed, metrics, device and, traced, breakdown. Everything else a run
-tells goes to standard error and to benchmark/out/<cell>-<seed>.jsonl.
+failed, metrics, device, traced also breakdown, and last compared: what
+`correct` rests on, each number beside its limit, which are the last
+lines of standard error too. Everything else a run tells goes to
+standard error and to benchmark/out/<cell>-<seed>.jsonl.
 
 No chip, no number: on the CPU backend, or with fewer chips than the
 cell asks for, the command exits non-zero and prints no result.
@@ -101,18 +104,27 @@ def resolve(bench_dir: str, name: str) -> tuple:
 
 
 def layer_metrics(bench_dir: str, cell: dict, out: dict, peaks: dict) -> dict:
+    """The per-layer metrics of a traced run: those the cell's file lists
+    under ``per_layer``, then those whose own file lists the cell under
+    ``workloads``. So a new cell names what it reports, and a new metric
+    names the cells that report it: neither edits a file that is there."""
     from benchmark import readers
 
     ctx = {"scalars": out["scalars"], "waves": out["waves"],
            "stats": out["stats"], "trace": out["trace"],
            "trace_path": out["trace_path"],
            "params": cell["engine_params"], "peaks": peaks}
+    folder = os.path.join(bench_dir, "layer_metrics")
+    specs = {f[:-5]: load_json(folder, f)
+             for f in sorted(os.listdir(folder)) if f.endswith(".json")}
+    names = list(cell["per_layer"])
+    names += [name for name, spec in specs.items() if name not in names
+              and cell["name"] in spec.get("workloads", ())]
     metrics = {}
-    for name in cell["per_layer"]:
-        spec = load_json(bench_dir, "layer_metrics", f"{name}.json")
-        value = readers.read(spec, ctx)
+    for name in names:
+        value = readers.read(specs[name], ctx)
         if value is not None:
-            metrics[name] = {"value": value, "unit": spec["unit"]}
+            metrics[name] = {"value": value, "unit": specs[name]["unit"]}
     return metrics
 
 
@@ -195,6 +207,12 @@ def main(argv=None) -> int:
         device["window_s"] = out["scalars"].get("trace_window_s")
         if "breakdown" in out:
             result["breakdown"] = out["breakdown"]
+        if "traced_at" in out:
+            print(f"benchmark: {time.perf_counter() - out['traced_at']:.3f} s "
+                  "from stop_trace to the result line", file=sys.stderr)
+    result["compared"] = out["compared"]
+    for name, (value, limit) in out["compared"].items():
+        print(f"benchmark: {name} {value} (limit {limit})", file=sys.stderr)
     print(json.dumps(result), flush=True)
     return 0
 

@@ -27,7 +27,7 @@ sys.path.insert(0, ROOT)
 from benchmark import readers, xplane  # noqa: E402
 from benchmark.modes import bfs  # noqa: E402
 
-RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device", "compared"}
 DEVICE_KEYS = {"platform", "kind", "count", "memory_peak_bytes"}
 
 
@@ -39,6 +39,11 @@ def load(*parts):
 def names(d):
     return sorted(f[:-5] for f in os.listdir(os.path.join(BENCH, d))
                   if f.endswith(".json"))
+
+
+def layer_metric_files():
+    return {name: load(BENCH, "layer_metrics", f"{name}.json")
+            for name in names("layer_metrics")}
 
 
 def run_cell(*argv, devices=1, allow_cpu=True):
@@ -90,7 +95,11 @@ def test_cell_resolves_and_agrees_with_benchmark_json(cell):
         assert depth <= golden["independent_to_depth"]
     assert os.path.exists(os.path.join(BENCH, "modes", f"{traffic['mode']}.py"))
     assert golden["msg_slots"] == spec["engine_params"]["msg_slots"]
-    layer = {name: load(BENCH, "layer_metrics", f"{name}.json") for name in spec["per_layer"]}
+    # what the cell reports: what its own file lists, and what lists it
+    files = layer_metric_files()
+    assert set(spec["per_layer"]) <= set(files)
+    layer = {name: metric for name, metric in files.items()
+             if name in spec["per_layer"] or cell in metric.get("workloads", ())}
     for name, metric in layer.items():
         assert metric["moves"] in spec["end_to_end"], (name, "moves a metric the cell does not report")
         assert os.path.exists(os.path.join(BENCH, "readers", f"{metric['reduce']['kind']}.py"))
@@ -103,9 +112,9 @@ def test_cell_resolves_and_agrees_with_benchmark_json(cell):
     def cells_of(metric):
         return metric.get("workloads", [w["name"] for w in bench["workloads"]])
 
-    for kind in ("end_to_end", "per_layer"):
+    for kind, reported in (("end_to_end", spec["end_to_end"]), ("per_layer", layer)):
         listed = {m["name"]: m for m in bench[kind]}
-        assert set(spec[kind]) == {n for n, m in listed.items() if cell in cells_of(m)}
+        assert set(reported) == {n for n, m in listed.items() if cell in cells_of(m)}
     for name, metric in layer.items():
         assert {k: metric[k] for k in ("layer", "unit", "moves", "source", "better")} == {
             k: listed[name][k] for k in ("layer", "unit", "moves", "source", "better")}
@@ -156,7 +165,7 @@ def test_traced_run_reports_the_cells_layer_metrics(spare_bench):
     proc, res = run_cell("--bench-dir", bench_dir, "--workload", "small-d6",
                          "--seed", "3", "--seconds", "1", "--trace", "1")
     assert proc.returncode == 0, proc.stderr[-2000:]
-    assert set(res) == RESULT_KEYS | {"breakdown"}
+    assert set(res) == RESULT_KEYS | {"breakdown"} and list(res)[-1] == "compared"
     assert set(res["device"]) == DEVICE_KEYS | {"busy_s", "window_s"}
     assert 0 < res["device"]["busy_s"] <= res["device"]["window_s"]
     cell = load(BENCH, "workloads", "raft3-small.json")
@@ -177,6 +186,42 @@ def test_wrong_golden_is_not_correct(spare_bench, tmp_path):
                          "--seed", "3", "--seconds", "1", "--trace", "0")
     assert proc.returncode == 0, proc.stderr[-2000:]
     assert res["correct"] is False and res["failed"] >= 1
+    # the numbers `correct` rests on, beside their limits: on the line,
+    # and the last lines of standard error
+    assert res["compared"]["verdicts_off_golden"] == [res["failed"], 0]
+    assert all(limit == 0 for _value, limit in res["compared"].values())
+    assert proc.stderr.strip().splitlines()[-len(res["compared"])].startswith(
+        f"benchmark: verdicts_off_golden {res['failed']} (limit 0)")
+    assert "benchmark: verdict warmup: total" in proc.stderr
+
+
+def test_a_metric_arrives_as_one_file_that_names_its_cells(spare_bench):
+    """The other way in: a per-layer metric whose own file lists the
+    cells that report it. One file is added to the bench dir and nothing
+    in it is edited; the named cell reports the metric beside those its
+    own file lists. (A cell that a file does not name does not report
+    it: the traced run above, beside the nine files that name the
+    repository's cells.)"""
+    bench_dir = spare_bench("small-d6", "raft3-small", 6, 6)
+
+    def contents():
+        return {os.path.join(base, f): open(os.path.join(base, f)).read()
+                for base, _dirs, files in os.walk(bench_dir) for f in files}
+
+    before = contents()
+    added = os.path.join(bench_dir, "layer_metrics", "programs_again.json")
+    with open(added, "w") as f:
+        json.dump({"name": "programs_again", "unit": "count", "workloads": ["small-d6"],
+                   "reduce": {"kind": "stat", "name": "programs_loaded"}}, f)
+    assert {k: v for k, v in contents().items() if k != added} == before
+    proc, res = run_cell("--bench-dir", bench_dir, "--workload", "small-d6",
+                         "--seed", "7", "--seconds", "1", "--trace", "1")
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    cell = load(BENCH, "workloads", "raft3-small.json")
+    assert res["correct"] is True
+    assert set(res["metrics"]) == {*cell["per_layer"], "programs_again"}
+    assert res["metrics"]["programs_again"]["unit"] == "count"
+    assert res["metrics"]["programs_again"]["value"] >= 1
 
 
 def test_a_sharded_cell_is_files_only_and_needs_its_chips(spare_bench):
@@ -267,7 +312,6 @@ def test_readers_read_what_the_files_say():
     want = {"build_s": 2.0, "warmup_s": 8.0, "cache_new_entries": 0,
             "narrow_wave_ms": 200.0, "device_busy_s_per_mstate": 6.0,
             "device_idle_share": 25.0}
-    assert sorted(want) == names("layer_metrics")
     for name, value in want.items():
         got = readers.read(load(BENCH, "layer_metrics", f"{name}.json"), ctx)
         assert got == pytest.approx(value), name

@@ -4,8 +4,8 @@ counters. On the CPU, with --allow-cpu; nothing here is a timing.
 
     python -m pytest benchmark/tests -q
 
-The metrics they serve are in tracing_overlay.py, beside this file, and
-not yet in BENCHMARK.json (why: that module's docstring).
+The nine metrics they serve are files of benchmark/layer_metrics/, each
+listing the cells that report it (BENCHMARK.json since PR 34).
 """
 
 from __future__ import annotations
@@ -23,10 +23,14 @@ ROOT = os.path.dirname(BENCH)
 sys.path.insert(0, ROOT)
 sys.path.insert(0, HERE)
 
-import tracing_overlay  # noqa: E402
-from test_benchmark import load, run_cell  # noqa: E402
+from test_benchmark import layer_metric_files, load, run_cell, spare_bench  # noqa: E402, F401
 from benchmark import adapter, readers, xplane, xspace  # noqa: E402
 from benchmark.readers import scope_time  # noqa: E402
+
+METRICS = layer_metric_files()
+STAGE_METRICS = [n for n, m in METRICS.items() if m["reduce"]["kind"] == "scope_time"]
+# the metrics that read the program's own scopes, spans and counters
+TRACING = [*STAGE_METRICS, "wave_idle_ms", "host_share", "programs_loaded"]
 
 
 def unpacked(tmp_path, name):
@@ -49,54 +53,89 @@ def ctx_of(path, pinned):
 
 def test_the_metrics_name_readers_cells_and_layers_that_exist():
     bench = load(ROOT, "BENCHMARK.json")
-    layers = {m["layer"] for m in bench["per_layer"]} | {
-        "Stages in a chunk", "Host wave loop"}  # PERF.md section 3's rows
-    listed = {m["name"] for m in bench["per_layer"]}
-    for name, spec in tracing_overlay.METRICS.items():
-        assert spec["name"] == name and name not in listed
-        assert spec["layer"] in layers
+    listed = {m["name"]: m for m in bench["per_layer"]}
+    cells = {w["name"] for w in bench["workloads"]}
+    assert len(TRACING) == 9
+    for name in TRACING:
+        spec = METRICS[name]
+        assert spec["name"] == name
         assert os.path.exists(os.path.join(
             BENCH, "readers", f"{spec['reduce']['kind']}.py"))
-    for cell, names in tracing_overlay.CELLS.items():
-        end_to_end = load(BENCH, "workloads", f"{cell}.json")["end_to_end"]
-        for name in names:
-            assert tracing_overlay.METRICS[name]["moves"] in end_to_end, (cell, name)
+        # the file's cells are BENCHMARK.json's, and each reports what
+        # the metric moves
+        assert spec["workloads"] == listed[name]["workloads"]
+        assert set(spec["workloads"]) <= cells
+        for cell in spec["workloads"]:
+            assert spec["moves"] in load(BENCH, "workloads", f"{cell}.json")["end_to_end"], (cell, name)
+    assert {METRICS[n]["layer"] for n in TRACING} == {
+        "Stages in a chunk", "Host wave loop", "Compile + cache"}  # PERF.md section 3's rows
 
 
 def test_the_readers_stage_list_is_the_programs():
     from raft_tpu.obs.events import TIMELINE_STAGES  # jax-free
 
-    assert set(scope_time.STAGES) == set(TIMELINE_STAGES) - {"checkpoint", "host"}
-    want = {f"{s}_s_per_mstate" for s in scope_time.STAGES if s != "exchange"}
-    assert want | {"unscoped_s_per_mstate"} == set(tracing_overlay.STAGE_METRICS)
+    assert scope_time.STAGES is xplane.STAGES
+    assert set(xplane.STAGES) == set(TIMELINE_STAGES) - {"checkpoint", "host"}
+    want = {f"{s}_s_per_mstate" for s in xplane.STAGES if s != "exchange"}
+    assert want | {"unscoped_s_per_mstate"} == set(STAGE_METRICS)
 
 
 # ---------------- the wire format ----------------
 
 def test_xspace_reads_the_ops_that_jax_reads(tmp_path):
-    """The standard-library walk of the file and jax's own reader agree
-    on every op's interval, to the nanosecond."""
+    """The standard-library walk of the file, which ``xplane.load`` takes
+    the device ops from, and jax's own reader agree on every op's
+    interval and name, to the nanosecond."""
+    from jax.profiler import ProfileData
+
     path = unpacked(tmp_path, "tiny_v5e")
-    trace = xplane.load(path)
     got = xspace.device_ops(path)
-    assert sorted(got) == sorted(trace.devices)
-    for name, (events, tf_ops) in got.items():
-        assert [(s, e) for s, e, _ in events] == [
-            (s, e) for s, e, _ in trace.devices[name]]
+    jaxs = {p.name: sorted(
+        (int(e.start_ns), int(e.start_ns + e.duration_ns), e.name)
+        for line in p.lines if xspace.OP_LINE.match(line.name) for e in line.events)
+        for p in ProfileData.from_file(path).planes if xspace.DEVICE_PLANE.match(p.name)}
+    assert sorted(got) == sorted(jaxs) and got
+    for name, (events, names, tf_ops) in got.items():
+        assert sorted((s, e, names[meta]) for s, e, meta in events) == jaxs[name]
         assert tf_ops and all(isinstance(v, str) for v in tf_ops.values())
+    trace = xplane.load(path)
+    assert {k: [(s, e) for s, e, _ in v] for k, v in trace.devices.items()} == {
+        k: [(s, e) for s, e, _ in v] for k, v in jaxs.items()}
 
 
 def test_a_trace_without_scopes_reads_as_nothing(tmp_path):
     """PR 23's recorded trace: the program had no stage scope then, as
-    executables from a stale compile cache have none."""
-    path = unpacked(tmp_path, "tiny_v5e")
-    assert scope_time.seconds_by_scope(path) is None
-    ctx = {"scalars": {"mstates": 1.0}, "params": {}, "trace_path": path}
-    for name in tracing_overlay.STAGE_METRICS:
-        assert readers.read(tracing_overlay.METRICS[name], ctx) is None, name
+    executables from a stale compile cache have none. Its ops keep their
+    bare names, and no stage metric reads it."""
+    trace = xplane.load(unpacked(tmp_path, "tiny_v5e"))
+    assert not trace.scoped
+    assert not any("/" in name for ops in trace.devices.values() for _s, _e, name in ops)
+    assert scope_time.seconds_by_scope(trace) is None
+    ctx = {"scalars": {"mstates": 1.0}, "params": {}, "trace": trace}
+    for name in STAGE_METRICS:
+        assert readers.read(METRICS[name], ctx) is None, name
+    assert readers.read(METRICS["expand_s_per_mstate"], dict(ctx, trace=None)) is None
 
 
 # ---------------- the readers, on made-up input ----------------
+
+def test_scope_path_takes_the_outermost_stage_and_the_named_scopes_under_it():
+    path = xplane.scope_path
+    assert path("jit(_wave_step)/while/body/dedup/merge/sort:") == ("dedup", "merge")
+    assert path("jit(_wave_step)/while/body/canon/while/body/closed_call/inchunk/jit(argsort)/sort:") == ("canon", "inchunk")
+    assert path("jit(_wave_step)/while/body/expand/vmap(vmap())/gather:") == ("expand",)
+    assert path("jit(_wave_step)/while/body/expand/jit(cumsum)/DeviceBFS._st_expand/add:") == ("expand",)
+    assert path("jit(_wave_step)/while/body/dedup/cond/branch_1_fun/search/jit(searchsorted)/lt:") == ("dedup", "search")
+    assert path("jit(_wave_step)/while/body/emit/invariants/dedup/eq:") == ("emit", "invariants")
+    assert path("jit(_wave_step)/while/body/emit/coverage/inner/add:", levels=3) == ("emit", "coverage", "inner")
+    assert path("jit(_wave_step)/while/body/emit/coverage/add:", levels=1) == ("emit",)
+    assert path("jit(_wave_step)/while/cond/lt:") == () and path(None) == ()
+    # a name in the trace is the path, then the instruction and a fusion's kind
+    assert xplane.scoped_name(
+        "jit(_wave_step)/while/body/canon/inchunk/sort:",
+        "%fusion.7 = u32[8]{0} fusion(u32[8]{0} %p), kind=kCustom, calls=%c") == "canon/inchunk/fusion.7[Custom]"
+    assert xplane.scoped_name(None, "%copy.12 = u32[8]{0} copy(u32[8]{0} %p)") == "-/copy.12"
+
 
 def test_stage_of_takes_the_outermost_stage():
     assert scope_time.stage_of("jit(_wave_step)/while/body/canon/eq:") == "canon"
@@ -119,7 +158,7 @@ def test_span_idle_wave_sum_ratio_and_stat():
                      {"frontier": 4096, "host_s": 0.25, "wave_s": 2.0},
                      {"frontier": 9000, "host_s": 0.5, "wave_s": 5.0}],
            "stats": {"programs_loaded": 57}}
-    idle = tracing_overlay.METRICS["wave_idle_ms"]
+    idle = METRICS["wave_idle_ms"]
     # narrow waves: 20 ns and 20 ns idle; the wide one (220 ns) is left out
     assert readers.read(idle, ctx) == pytest.approx(20 / 1e9 * 1000)
     every = {"reduce": {"kind": "span_idle", "span": "wave"}}
@@ -128,12 +167,12 @@ def test_span_idle_wave_sum_ratio_and_stat():
     # spans that do not pair with the rows read as nothing
     assert readers.read(idle, dict(ctx, waves=ctx["waves"][:2])) is None
     assert readers.read(idle, dict(ctx, trace=None)) is None
-    assert readers.read(tracing_overlay.METRICS["host_share"], ctx) == pytest.approx(12.5)
+    assert readers.read(METRICS["host_share"], ctx) == pytest.approx(12.5)
     rounded = [{"frontier": 7, "wave_s": 1.0}]  # a program without host_s
-    assert readers.read(tracing_overlay.METRICS["host_share"], dict(ctx, waves=rounded)) is None
-    assert readers.read(tracing_overlay.METRICS["host_share"], dict(ctx, waves=[])) is None
-    assert readers.read(tracing_overlay.METRICS["programs_loaded"], ctx) == 57
-    assert readers.read(tracing_overlay.METRICS["programs_loaded"], dict(ctx, stats={})) is None
+    assert readers.read(METRICS["host_share"], dict(ctx, waves=rounded)) is None
+    assert readers.read(METRICS["host_share"], dict(ctx, waves=[])) is None
+    assert readers.read(METRICS["programs_loaded"], ctx) == 57
+    assert readers.read(METRICS["programs_loaded"], dict(ctx, stats={})) is None
 
 
 # ---------------- the recorded trace, with scopes ----------------
@@ -146,7 +185,7 @@ def test_scoped_trace_reduces_to_the_pinned_numbers(tmp_path):
     ctx = ctx_of(path, pinned)
     busy = xplane.busy_s(ctx["trace"])
     assert busy == pytest.approx(pinned["busy_s"], rel=1e-9)
-    seconds = scope_time.seconds_by_scope(path)
+    seconds = scope_time.seconds_by_scope(ctx["trace"])
     assert seconds == {
         (None if k == "unscoped" else k): pytest.approx(v, rel=1e-9)
         for k, v in pinned["scope_s"].items()}
@@ -155,9 +194,20 @@ def test_scoped_trace_reduces_to_the_pinned_numbers(tmp_path):
     assert sum(seconds.values()) == pytest.approx(busy, rel=1e-9)
     assert seconds["exchange"] == 0  # one chip
     for name, want in pinned["metrics"].items():
-        got = readers.read(tracing_overlay.METRICS[name], ctx)
+        got = readers.read(METRICS[name], ctx)
         assert got == pytest.approx(want, rel=1e-9), name
-    assert set(pinned["metrics"]) == set(tracing_overlay.METRICS)
+    assert set(pinned["metrics"]) == set(TRACING)
+    # time by op name is time by stage too: every name starts with its
+    # scope path, `-` under no stage, and the names of a stage add up to
+    # its bucket
+    assert ctx["trace"].scoped
+    by_name = dict(xplane.op_time_by_name(ctx["trace"], top=10**6))
+    heads = {name.split("/")[0] for name in by_name}
+    assert heads <= {*xplane.STAGES, xplane.UNSCOPED} and xplane.UNSCOPED in heads
+    for stage, want in seconds.items():
+        head = stage or xplane.UNSCOPED
+        assert sum(s for name, s in by_name.items() if name.split("/")[0] == head) == pytest.approx(want, rel=1e-9)
+    assert [n for n, _s in xplane.op_time_by_name(ctx["trace"], top=3)] == pinned["top_op_names"]
 
 
 def test_scoped_trace_holds_the_programs_spans_beside_the_verdict(tmp_path):
@@ -188,21 +238,14 @@ def test_scoped_trace_holds_the_programs_spans_beside_the_verdict(tmp_path):
 
 # ---------------- the command ----------------
 
-def test_a_cell_that_lists_the_new_metrics_runs_to_a_result_line(tmp_path):
-    """A throw-away cell (raft3-small cut to depth 6) in a bench dir that
-    lists every new metric: the rehearsal runs to a result line, the
-    metrics that need no device plane are on it beside PR 23's, and those
-    that need one are left out, not zero."""
-    bench_dir = tracing_overlay.build(str(tmp_path / "bench"))
-    cell = load(bench_dir, "workloads", "raft3-small.json")
-    traffic = load(bench_dir, "traffic", f"{cell['traffic']}.json")
-    traffic.update(name="tracing-d6", max_depth=6, warmup_depth=6)
-    cell.update(name="tracing-d6", traffic="tracing-d6",
-                per_layer=[*load(BENCH, "workloads", "raft3-small.json")["per_layer"],
-                           *tracing_overlay.METRICS])
-    for d, spec in (("traffic", traffic), ("workloads", cell)):
-        with open(os.path.join(bench_dir, d, "tracing-d6.json"), "w") as f:
-            json.dump(spec, f)
+def test_a_cell_that_lists_the_new_metrics_runs_to_a_result_line(spare_bench):
+    """A throw-away cell (raft3-small cut to depth 6) whose own file lists
+    the nine metrics under ``per_layer``, the first way in: the rehearsal
+    runs to a result line, the metrics that need no device plane are on
+    it beside PR 23's, and those that need one are left out, not zero."""
+    mine = load(BENCH, "workloads", "raft3-small.json")["per_layer"]
+    bench_dir = spare_bench("tracing-d6", "raft3-small", 6, 6,
+                            per_layer=[*mine, *TRACING])
     proc, res = run_cell("--bench-dir", bench_dir, "--workload", "tracing-d6",
                          "--seed", str(2**31 + 11), "--seconds", "1", "--trace", "1")
     assert proc.returncode == 0, proc.stderr[-2000:]
@@ -214,6 +257,7 @@ def test_a_cell_that_lists_the_new_metrics_runs_to_a_result_line(tmp_path):
     assert 0 < got["host_share"]["value"] < 100
     assert got["wave_idle_ms"]["value"] >= 0
     assert got["host_share"]["unit"] == "%"
+    assert "from stop_trace to the result line" in proc.stderr
 
 
 def test_a_benchmark_verdict_writes_the_programs_spans(tmp_path):

@@ -6,8 +6,14 @@ collective runs beside other work ("Async XLA Ops" is a line of its own)
 is not reduced yet, for want of a trace of more than one chip to pin it
 on.
 
+A device op is named by the program's stage scope it was traced under
+and its instruction, ``dedup/merge/sort.279`` (``scope_path``), so that
+time by op name is time by stage too. The host planes are read with
+jax's own reader; the device planes, whose ops' metadata it does not
+reach, through benchmark/xspace.py: one walk of the file a trace.
+
 All times are seconds, all timestamps nanoseconds on the trace's clock.
-Pinned on a small recorded trace by benchmark/tests.
+Pinned on small recorded traces by benchmark/tests.
 """
 
 from __future__ import annotations
@@ -19,26 +25,56 @@ import re
 
 import numpy as np
 
+from benchmark import xspace
+from benchmark.xspace import DEVICE_PLANE
+
 # gaps shorter than this are the device's own pauses between two ops of
 # one program, not the host's doing
 SHORT_GAP_NS = 50_000
 
-# On a device plane the ops are on this line; the other lines ("Steps",
-# "XLA Modules", "XLA TraceMe", ...) repeat the same time at a coarser
-# grain and would count it twice.
-OP_LINE = re.compile(r"^XLA Ops")
-DEVICE_PLANE = re.compile(r"^/device:(TPU|GPU):\d+$")
 HOST_PLANE = re.compile(r"^/host:CPU$")
+
+# the program's stage scopes (``obs.stage``, a ``jax.named_scope``): the
+# device members of raft_tpu.obs.events.TIMELINE_STAGES (the tests hold
+# the two lists together)
+STAGES = ("expand", "canon", "dedup", "emit", "exchange", "seen_merge")
+# what an op under no stage is booked to
+UNSCOPED = "-"
+# elements of a name stack that are control flow, not a scope
+_CONTROL = ("while", "body", "cond", "closed_call")
 
 
 @dataclasses.dataclass
 class Trace:
     """devices: plane name -> [(start_ns, end_ns, op name)], sorted by
     start, nested ops included (a `while` holds its body's ops).
-    host: [(start_ns, end_ns, name)] of every host span."""
+    host: [(start_ns, end_ns, name)] of every host span.
+    scoped: some op carries a stage scope, and so every op's name starts
+    with its scope path (``-`` under no stage)."""
 
     devices: dict
     host: list
+    scoped: bool = False
+
+
+def scope_path(tf_op, levels: int = 2) -> tuple:
+    """The stage scope an op was traced under, ``levels`` deep: ("dedup",
+    "merge"), ("expand",), () under no stage. ``tf_op`` is the op's JAX
+    name stack (``jit(_wave_step)/while/body/canon/inchunk/sort``; a
+    fusion carries that of its root op). The stage is the outermost
+    element that is one of ``STAGES``; below it count the named scopes
+    alone: not a transform (``vmap()``, ``jit(f)``), a function's name
+    (``DeviceBFS._st_expand``), control flow, a closed call's repeat of
+    the prefix, nor the last element, which is the op itself."""
+    parts = tf_op.split("/") if isinstance(tf_op, str) else []
+    for i, part in enumerate(parts):
+        if part in STAGES:
+            inner = [p for p in parts[i + 1:-1]
+                     if "(" not in p and "." not in p
+                     and p not in _CONTROL + STAGES
+                     and not p.startswith("branch_")]
+            return (part, *inner[:levels - 1])
+    return ()
 
 
 def op_name(text: str) -> str:
@@ -50,6 +86,12 @@ def op_name(text: str) -> str:
     return name.lstrip("%") + (f"[{kind.group(1)}]" if kind else "")
 
 
+def scoped_name(tf_op, text: str) -> str:
+    """``canon/inchunk/fusion.1240[Custom]``, ``-/copy.12``: an op's
+    scope path, then its name."""
+    return "/".join((*(scope_path(tf_op) or (UNSCOPED,)), op_name(text)))
+
+
 def find_xplane(trace_dir: str) -> str:
     found = sorted(glob.glob(
         os.path.join(trace_dir, "plugins", "profile", "*", "*.xplane.pb")))
@@ -59,39 +101,39 @@ def find_xplane(trace_dir: str) -> str:
 
 
 def load(path: str) -> Trace:
-    """Read an .xplane.pb with jax's own reader. On the CPU backend there
-    is no device plane: the ops are host events that carry an `hlo_op`
-    stat, and they stand in for one device (rehearsal only)."""
+    """Read an .xplane.pb. On the CPU backend there is no device plane:
+    the ops are host events that carry an `hlo_op` stat, and they stand
+    in, bare-named, for one device (rehearsal only)."""
     from jax.profiler import ProfileData
 
-    planes = list(ProfileData.from_file(path).planes)
-    on_cpu = not any(DEVICE_PLANE.match(p.name) for p in planes)
+    walked = xspace.device_ops(path)
+    # a trace of a program without scopes keeps its bare names
+    scoped = any(scope_path(tf_op) for _events, _names, tf_ops
+                 in walked.values() for tf_op in tf_ops.values())
     devices: dict = {}
+    for plane, (events, names, tf_ops) in walked.items():
+        label = {meta: scoped_name(tf_ops.get(meta), name) if scoped
+                 else op_name(name) for meta, name in names.items()}
+        devices[plane] = [(s, e, label[meta]) for s, e, meta in events]
     host: list = []
-    for plane in planes:
-        if DEVICE_PLANE.match(plane.name):
-            ops = devices.setdefault(plane.name, [])
-            for line in plane.lines:
-                if OP_LINE.match(line.name):
-                    ops.extend(
-                        (int(e.start_ns), int(e.start_ns + e.duration_ns),
-                         op_name(e.name)) for e in line.events)
-        elif HOST_PLANE.match(plane.name):
-            for line in plane.lines:
-                for e in line.events:
-                    if e.name.startswith("$"):  # python tracer frames
-                        continue
-                    span = (int(e.start_ns),
-                            int(e.start_ns + e.duration_ns), e.name)
-                    # the stats are read only where they decide anything:
-                    # a chip's trace has hundreds of thousands of host events
-                    if on_cpu and any(k == "hlo_op" for k, _ in e.stats):
-                        devices.setdefault(plane.name, []).append(span)
-                    else:
-                        host.append(span)
+    for plane in ProfileData.from_file(path).planes:
+        if not HOST_PLANE.match(plane.name):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("$"):  # python tracer frames
+                    continue
+                span = (int(e.start_ns),
+                        int(e.start_ns + e.duration_ns), e.name)
+                # the stats are read only where they decide anything:
+                # a chip's trace has hundreds of thousands of host events
+                if not walked and any(k == "hlo_op" for k, _ in e.stats):
+                    devices.setdefault(plane.name, []).append(span)
+                else:
+                    host.append(span)
     for ops in devices.values():
         ops.sort()
-    return Trace(devices=devices, host=host)
+    return Trace(devices=devices, host=host, scoped=scoped)
 
 
 def union(intervals) -> list:

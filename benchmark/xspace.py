@@ -28,7 +28,13 @@ The schema (tsl/profiler/protobuf/xplane.proto), field numbers:
 
 from __future__ import annotations
 
-from benchmark.xplane import DEVICE_PLANE, OP_LINE
+import re
+
+# On a device plane the ops are on this line; the other lines ("Steps",
+# "XLA Modules", "XLA TraceMe", ...) repeat the same time at a coarser
+# grain and would count it twice.
+OP_LINE = re.compile(r"^XLA Ops")
+DEVICE_PLANE = re.compile(r"^/device:(TPU|GPU):\d+$")
 
 
 def fields(buf):
@@ -168,12 +174,22 @@ def op_events(plane):
     return out
 
 
+def op_names(plane) -> dict:
+    """event metadata id -> the op's name as the profiler wrote it (its
+    whole HLO line on a TPU)."""
+    out = {}
+    for key, meta in map_entries(plane, 4):
+        name = first(meta, 2)
+        out[key] = "" if name is None else text(name)
+    return out
+
+
 def device_ops(path: str) -> dict:
     """plane name -> ([(start_ns, end_ns, metadata id)], {metadata id:
-    tf_op}) for each device plane of the file."""
+    name}, {metadata id: tf_op}) for each device plane of the file."""
     with open(path, "rb") as f:
         buf = memoryview(f.read())
     return {
-        name: (op_events(plane), op_stat(plane, "tf_op"))
+        name: (op_events(plane), op_names(plane), op_stat(plane, "tf_op"))
         for name, plane in planes(buf) if DEVICE_PLANE.match(name)
     }
