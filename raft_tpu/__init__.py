@@ -22,13 +22,65 @@ Layout of the package:
 """
 
 import os
-import re
+import time
 
-import jax
+_T_FIRST = time.perf_counter()  # the program's first line
+
+
+def _process_age() -> float | None:
+    """Wall seconds since this process started: the machine's uptime
+    less the process's start time, which ``/proc/self/stat`` gives in
+    clock ticks since boot (field 22; good to 10 ms. ``/proc/stat``'s
+    ``btime`` is whole seconds and will not do). None where the platform
+    has no ``/proc``."""
+    try:
+        with open("/proc/uptime") as f:
+            uptime = float(f.read().split()[0])
+        with open("/proc/self/stat") as f:
+            # the command's name, field 2, may hold spaces and brackets
+            start = int(f.read().rsplit(")", 1)[1].split()[19])
+        return uptime - start / os.sysconf("SC_CLK_TCK")
+    except (OSError, ValueError, IndexError):
+        return None
+
+
+# The process's record of its set-up, in seconds (obs/trace.py
+# ``setup_phase`` adds to it; obs/compiles.py ``run_stats`` puts it on
+# every result as ``setup_<phase>_s``). ``pre`` is what no bracket can
+# give: process start to the line above — the interpreter and whatever
+# the caller imported and started first (in the benchmark ``import
+# jax``, the backend and its own imports; in the CLI almost nothing).
+# ``import`` is this file's own imports, stamped below.
+SETUP_S: dict = {
+    "pre": _process_age(), "import": 0.0, "backend": 0.0, "cfg": 0.0,
+    "model": 0.0, "engine": 0.0,
+}
+
+import re  # noqa: E402
+
+import jax  # noqa: E402
 
 # 64-bit fingerprints (TLC uses 64-bit state fingerprints; parity requires
 # the same collision budget). Must run before any jax arrays are created.
 jax.config.update("jax_enable_x64", True)
+
+SETUP_S["import"] = time.perf_counter() - _T_FIRST
+
+
+def start_backend() -> None:
+    """The first touch of the backend, as the set-up phase ``backend``:
+    once a process, from ``enable_compcache`` or, earlier, from a caller
+    that wants its profiler session open before the cfg is read (the
+    CLI). Reads ~0 where the caller had started the backend already (the
+    benchmark), and says so by being ~0. The process's compile records
+    (obs/compiles.py) are switched on here, before the first program."""
+    from .obs.compiles import COMPILES
+    from .obs.trace import setup_phase
+
+    if COMPILES.install():
+        with setup_phase("backend"):
+            jax.devices()
+
 
 def enable_compcache() -> None:
     """Persistent compilation cache, placeable from outside.
@@ -48,8 +100,9 @@ def enable_compcache() -> None:
     features the host lacks), which buries a run's stderr. Called once
     the backend is known, from Canonicalizer.for_model/__init__,
     Simulator and LivenessChecker — the chokepoints every checker and
-    simulation path goes through — so the process's compile counters
-    (obs/compiles.py) are switched on here too, cache or no cache.
+    simulation path goes through — so the backend's start is bracketed
+    and the process's compile records are switched on from here too
+    (``start_backend``), cache or no cache.
 
     The cache key takes the programs' metadata in: the stage scopes of
     obs/trace.py are metadata and nothing else, and a key that strips it
@@ -58,9 +111,7 @@ def enable_compcache() -> None:
     names. The metadata names source files, so the checkout's own path
     is cut from them: the same commit in another directory (a cache
     placed from outside, shared by two checkouts) still hits."""
-    from .obs.compiles import COMPILES
-
-    COMPILES.install()
+    start_backend()
     checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         if jax.default_backend() == "cpu":

@@ -258,6 +258,27 @@ def main(argv=None):
     if rc:
         return rc
 
+    trace = None
+    if args.checker != "oracle":
+        # the backend starts here, as the set-up phase `backend`, and
+        # with it up the --trace-dir session opens before the cfg is
+        # read: the trace then holds setup/cfg, setup/model,
+        # setup/engine and the first run's init on the device's clock
+        from . import start_backend
+        from .obs import TraceSession
+
+        start_backend()
+        trace = TraceSession(args.trace_dir)
+    try:
+        return _check(args, chaos_spec, trace)
+    finally:
+        if trace is not None:
+            trace.stop()  # whichever way _check returned; once
+
+
+def _check(args, chaos_spec, trace) -> int:
+    """Everything after the flags are read and the backend is up: cfg,
+    model, engine, run, report. Returns the exit code."""
     from .utils.cfg import CfgError, parse_cfg
     from .models.registry import build_from_cfg
 
@@ -566,7 +587,7 @@ def main(argv=None):
             metrics_path=args.metrics_out,
             every=args.metrics_every,
             progress_every=args.progress,
-            trace_dir=args.trace_dir,
+            trace=trace,
         )
 
     def _finish(rc: int) -> int:

@@ -22,7 +22,10 @@ from dataclasses import dataclass, field
 import jax
 import numpy as np
 
-from ..obs import MemWatch, NULL_TELEMETRY, device_budget
+from ..obs import (
+    COMPILES, MemWatch, NULL_TELEMETRY, device_budget, setup_phase, span,
+    traced_run,
+)
 from ..obs.events import hashv_of
 from ..ops.hashing import U64_MAX
 from ..ops.symmetry import Canonicalizer
@@ -122,6 +125,7 @@ class CheckResult:
 
 
 class BFSChecker:
+    @setup_phase("engine")
     def __init__(
         self,
         model,
@@ -154,6 +158,7 @@ class BFSChecker:
 
     # ---------------- main loop ----------------
 
+    @traced_run("host")
     def run(
         self,
         max_depth: int | None = None,
@@ -171,6 +176,11 @@ class BFSChecker:
         model = self.model
         B = self.chunk
         t0 = time.perf_counter()
+        # the run's top-level host spans, as the device engines have
+        # them (obs/trace.py): init, a wave an iteration, finish
+        ph = self._ph
+        ph.top("init")
+        comp_run = COMPILES.snapshot()
         exhausted = True
         exit_cause = None
         tel = telemetry if telemetry is not None else NULL_TELEMETRY
@@ -292,6 +302,7 @@ class BFSChecker:
                 exhausted = False
                 exit_cause = "time_budget"
                 break
+            ph.wave(self._run_id, depth + 1, len(frontier))
             tw = time.perf_counter()
             dev_s = 0.0
             # contiguous cursor-append emit (mirrors the device engines'
@@ -414,7 +425,7 @@ class BFSChecker:
             wave_cands = wave_cb.take()
             self._parents.append(wave_parents)
             self._cands.append(wave_cands)
-            with tel.annotate("seen_merge"):
+            with ph("seen_merge"):
                 seen = _merge_sorted(seen, wave_fps)
             depth += 1
             depth_counts.append(len(wave_states))
@@ -519,6 +530,7 @@ class BFSChecker:
                     )
                 tel_s_last = time.perf_counter() - t_tel
 
+        ph.top("finish")
         if checkpoint_path is not None and violation is None and not exhausted:
             # budget/depth/preemption exit at a wave boundary: save a
             # final resumable snapshot (the periodic timer alone can
@@ -529,6 +541,9 @@ class BFSChecker:
             )
 
         dt = time.perf_counter() - t0
+        # what the run loaded into the process, and its top-level spans'
+        # seconds, read beside dt: they add up to it
+        run_stats = {**COMPILES.run_stats(comp_run), **ph.top_seconds()}
         if violation is not None:
             exit_cause = "violation"
         elif exit_cause is None:
@@ -556,6 +571,8 @@ class BFSChecker:
             "canon_dup_rate": 0.0,
             "canon_tier3_local": 0,
             "canon_tier3_full": 0,
+            **run_stats,
+            "programs": COMPILES.programs(comp_run),
             **(memwatch.summary_fields() if memwatch is not None else {}),
         })
         trace = self.reconstruct_trace(violation) if violation else None
@@ -573,6 +590,7 @@ class BFSChecker:
             metrics=metrics,
             coverage=[[int(x) for x in row] for row in cov] if K else None,
             exit_cause=exit_cause,
+            stats=run_stats,
         )
 
     # ---------------- fleet (packed co-resident jobs) ----------------
@@ -618,6 +636,7 @@ class BFSChecker:
             raise ValueError(f"{len(names)} job names for {J} jobs")
         tel = telemetry if telemetry is not None else NULL_TELEMETRY
         t0 = time.perf_counter()
+        comp_run = COMPILES.snapshot()
         K = self.n_actions
 
         model.fleet_select(None)
@@ -788,7 +807,7 @@ class BFSChecker:
             wave_jobs = wave_jb.take()
             self._parents.append(wave_parents)
             self._cands.append(wave_cands)
-            with tel.annotate("seen_merge"):
+            with span("seen_merge"):
                 seen = _merge_sorted(seen, wave_fps)
             depth += 1
             new_by_job = np.bincount(wave_jobs, minlength=J)
@@ -900,6 +919,9 @@ class BFSChecker:
                 final=True,
             )
         first_viol = next((v for v in violation_j if v is not None), None)
+        # what the group loaded into the process (obs/compiles.py); the
+        # per-job summaries below repeat it, the jobs being co-resident
+        run_stats = COMPILES.run_stats(comp_run)
         tel.close_run({
             "engine": "host",
             "ident": self._ckpt_ident(),
@@ -922,6 +944,7 @@ class BFSChecker:
             "canon_tier3_local": 0,
             "canon_tier3_full": 0,
             "fleet_jobs": J,
+            **run_stats,
         })
         # per-job synthesized runs: one manifest/coverage/summary triple
         # per job so obs_report and the schema checker see per-job
@@ -960,6 +983,7 @@ class BFSChecker:
                     "canon_dup_rate": 0.0,
                     "canon_tier3_local": 0,
                     "canon_tier3_full": 0,
+                    **run_stats,
                     "job": name,
                 })
         return results

@@ -59,7 +59,8 @@ import numpy as np
 from jax import lax
 
 from ..obs import (
-    COMPILES, MemWatch, NULL_TELEMETRY, device_budget, stage, traced_run,
+    COMPILES, MemWatch, NULL_TELEMETRY, device_budget, setup_phase, span,
+    stage, traced_run,
 )
 from ..obs.events import hashv_of
 from ..ops.hashing import U64_MAX, sort_u64
@@ -128,6 +129,7 @@ class DeviceBFS:
     # before it costs a per-wave buffer copy.
     WAVE_DONATE = (1, 2, 3, 4, 5, 6)
 
+    @setup_phase("engine")
     def __init__(
         self,
         model,
@@ -625,7 +627,7 @@ class DeviceBFS:
 
     # ---------------- precompile ----------------
 
-    def precompile(self, telemetry=None) -> None:
+    def precompile(self) -> None:
         """Compile (and execute once, on zero/sentinel buffers) every
         device program a run at the CURRENT capacities can need: the
         chunk program and the full LSM merge ladder. A mid-run compile
@@ -633,10 +635,9 @@ class DeviceBFS:
         warmup — which the persistent compile cache turns into disk
         reads in later processes — the timed region never compiles.
         Growth steps still retrace, so benchmark callers should start
-        at their final capacities. ``telemetry``: a --trace-dir run
-        brackets the whole warmup in a named "precompile" span."""
-        tel = telemetry if telemetry is not None else NULL_TELEMETRY
-        with tel.annotate("precompile"):
+        at their final capacities. The whole warmup is one host span,
+        "precompile"."""
+        with span("precompile"):
             self._precompile_programs()
 
     def signature_inventory(self):
@@ -1304,6 +1305,7 @@ class DeviceBFS:
         self._jcount = int(np.asarray(jax.device_get(stats))[1])
 
         dt = time.perf_counter() - t0
+        top_s = ph.top_seconds()  # read beside dt: they add up to it
         if violation is not None:
             exit_cause = "violation"
         elif exit_cause is None:
@@ -1313,7 +1315,8 @@ class DeviceBFS:
                 self._coverage_fields(depth, cov_h, scount, depth_counts),
                 final=True)
         run_stats = {
-            **COMPILES.run_stats(comp_run), "dedup_plan": self._dedup_plan(),
+            **COMPILES.run_stats(comp_run), **top_s,
+            "dedup_plan": self._dedup_plan(),
             "canon_tier3_local": int(canon_prev[1]),
             "canon_tier3_full": int(canon_prev[2]),
         }
@@ -1335,6 +1338,7 @@ class DeviceBFS:
             "canon_dup_rate": round(
                 int(canon_prev[0]) / max(1, gen_prev), 4),
             **run_stats,
+            "programs": COMPILES.programs(comp_run),
             **(memwatch.summary_fields() if memwatch is not None else {}),
         })
         trace = self.reconstruct_trace(violation) if violation else None

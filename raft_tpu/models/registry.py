@@ -9,6 +9,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 
+from ..obs.trace import setup_phase
 from ..utils.cfg import Cfg, CfgError
 from .kraft import KRaftModel, KRaftParams
 from .pull_raft import PullRaftModel, PullRaftParams
@@ -391,6 +392,7 @@ def oracle_for_setup(setup: CheckSetup):
 NET_FAULT_SPECS = ("Raft", "FlexibleRaft", "RaftFsync")
 
 
+@setup_phase("model")
 def build_from_cfg(
     cfg: Cfg,
     spec: str | None = None,

@@ -2,7 +2,8 @@
 
 Per-wave JSONL metrics (events.py), a TLC-style progress line
 (progress.py), the tracing spine — device scopes, host spans, the
-profiler session (trace.py) — and compile counters (compiles.py), the
+profiler session, the set-up phases (trace.py) — and a record of every
+program the process traced, lowered and loaded (compiles.py), the
 collector/facade threading them through the engines (collector.py),
 and TLC-style per-action coverage rendering (coverage.py).
 
@@ -18,7 +19,7 @@ from .collector import (
     NULL_TELEMETRY,
     Telemetry,
 )
-from .coverage import coverage_digest, dead_actions, render_coverage_table
+from .coverage import dead_actions, render_coverage_table
 from .events import (
     CKPT_GENERATION_KEYS,
     COVERAGE_KEYS,
@@ -41,7 +42,9 @@ from .events import (
 from .memwatch import MemWatch, budget_from_env, device_budget
 from .progress import ProgressRenderer, format_count
 from .compiles import COMPILES
-from .trace import Phases, TraceSession, span, stage, traced_run
+from .trace import (
+    HERE, Phases, TraceSession, setup_phase, span, stage, traced_run,
+)
 
 __all__ = [
     "CKPT_GENERATION_KEYS",
@@ -59,6 +62,7 @@ __all__ = [
     "TIMELINE_STAGES",
     "WAVE_KEYS",
     "COMPILES",
+    "HERE",
     "JobTaggedTelemetry",
     "MemWatch",
     "MetricsCollector",
@@ -68,12 +72,12 @@ __all__ = [
     "Telemetry",
     "TraceSession",
     "budget_from_env",
-    "coverage_digest",
     "dead_actions",
     "device_budget",
     "format_count",
     "hashv_of",
     "render_coverage_table",
+    "setup_phase",
     "span",
     "stage",
     "traced_run",

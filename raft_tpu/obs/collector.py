@@ -20,10 +20,10 @@ checkpoint spill on a slow disk, or a preempted device.
 object bundling the collector, the optional TLC-style progress renderer
 and the ``--trace-dir`` profiler session. ``NULL_TELEMETRY`` is the
 do-nothing instance engines default to, so the hot loop never branches
-on None. The host spans do not depend on either: every facade's
-``annotate`` is ``trace.span`` (obs/trace.py), and ``wave_annotation``
-is a hook that does nothing here — it marks where a wave's dispatch and
-fetch are, and the benchmark's adapter hangs its clock on it.
+on None. The host spans do not depend on either (obs/trace.py opens
+them whatever the facade); ``wave_annotation`` is a hook that does
+nothing here — it marks where a wave's dispatch and fetch are, and the
+benchmark's adapter hangs its clock on it.
 """
 
 from __future__ import annotations
@@ -33,9 +33,10 @@ import statistics
 import time
 from contextlib import nullcontext
 
+from .compiles import COMPILES
 from .events import EVENT_KEYS
 from .progress import ProgressRenderer
-from .trace import TraceSession, span
+from .trace import TraceSession
 
 _NO_SPAN = nullcontext()
 
@@ -214,6 +215,7 @@ class Telemetry:
         trace_dir: str | None = None,
         stall_factor: float = 4.0,
         keep_events: bool = True,
+        trace: TraceSession | None = None,
     ):
         self.collector = MetricsCollector(
             path=metrics_path, every=every, stall_factor=stall_factor,
@@ -225,7 +227,12 @@ class Telemetry:
                 every_s=progress_every, stream=progress_stream
             )
             self.collector.add_listener(self.progress)
-        self.trace = TraceSession(trace_dir)
+            # a cold start is minutes of compiling before the first
+            # wave's line: say which program, as each slow load ends
+            COMPILES.watchers.append(self.progress.loaded)
+        # ``trace``: a session the caller opened earlier (the CLI, before
+        # it reads the cfg, so the set-up phases are in it); closed here
+        self.trace = trace if trace is not None else TraceSession(trace_dir)
 
     # -- engine-facing --
 
@@ -247,9 +254,6 @@ class Telemetry:
     def wave_annotation(self, depth: int):
         return _NO_SPAN
 
-    def annotate(self, name: str):
-        return span(name)
-
     # -- caller-facing --
 
     @property
@@ -269,6 +273,9 @@ class Telemetry:
     def close(self) -> None:
         self.collector.close()
         self.trace.stop()
+        if self.progress is not None \
+                and self.progress.loaded in COMPILES.watchers:
+            COMPILES.watchers.remove(self.progress.loaded)
 
     def __enter__(self):
         return self
@@ -314,9 +321,6 @@ class JobTaggedTelemetry:
     def wave_annotation(self, depth: int):
         return self._inner.wave_annotation(depth)
 
-    def annotate(self, name: str):
-        return self._inner.annotate(name)
-
     @property
     def events(self):
         return self._inner.events
@@ -354,9 +358,6 @@ class _NullTelemetry:
 
     def wave_annotation(self, depth: int):
         return _NO_SPAN
-
-    def annotate(self, name: str):
-        return span(name)
 
     def close(self) -> None:
         pass

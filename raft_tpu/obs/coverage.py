@@ -1,5 +1,4 @@
-"""TLC-style action coverage: table rendering, dead-action detection
-and the digest attached to BENCH provenance.
+"""TLC-style action coverage: table rendering and dead-action detection.
 
 The input everywhere is the cumulative per-action counter block the
 engines accumulate on device — ``actions[rank] = [enabled, fired,
@@ -66,19 +65,3 @@ def render_coverage_table(action_names, actions, title: str | None = None) -> st
     for name in dead_actions(action_names, actions):
         lines.append(f"WARNING: action {name} never fired")
     return "\n".join(lines)
-
-
-def coverage_digest(action_names, actions) -> dict:
-    """Provenance block for BENCH rows: exploration completeness in four
-    scalars, so rows stay comparable on coverage, not just throughput."""
-    rows = _rows(action_names, actions)
-    if not rows:
-        return {"actions_total": 0, "actions_fired": 0,
-                "min_fire_action": None, "min_fire_count": None}
-    least = min(rows, key=lambda r: r[2])
-    return {
-        "actions_total": len(rows),
-        "actions_fired": sum(1 for r in rows if r[2] > 0),
-        "min_fire_action": least[0],
-        "min_fire_count": least[2],
-    }

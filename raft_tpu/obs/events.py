@@ -146,13 +146,34 @@ COVERAGE_KEYS = (
     "frontier_hist", "final",
 )
 
+# what the process has spent on set-up so far, on every summary
+# (obs/compiles.py ``run_stats``): seconds from process start to the
+# package's first line and of each set-up phase (obs/trace.py
+# ``setup_phase``), and the seconds it spent getting programs ready, by
+# kind and as the union of all three; cumulative, as programs_loaded is.
+# setup_pre_s is null where the platform has no /proc.
+SETUP_KEYS = (
+    "setup_pre_s", "setup_import_s", "setup_backend_s", "setup_cfg_s",
+    "setup_model_s", "setup_engine_s", "load_trace_s", "load_lower_s",
+    "load_compile_s", "load_cache_read_s", "load_union_s",
+)
+# with the two counts beside them: what validate_event holds to numbers
+PROCESS_KEYS = ("programs_loaded", "programs_traced", *SETUP_KEYS)
+
 SUMMARY_KEYS = (
     "event", "engine", "ident", "exit_cause", "violation", "distinct",
     "total", "depth", "terminal", "seconds", "distinct_per_s",
     "exhausted", "waves", "stalls", "peak_frontier_cap",
     "peak_journal_cap", "seen_lanes", "canon_dup_rate",
     "canon_tier3_local", "canon_tier3_full",
+    *PROCESS_KEYS,
 )
+
+# a summary's ``programs``: the run's top-level program records
+# (obs/compiles.py), each with the span that caused it
+PROGRAM_KINDS = ("trace", "lower", "load")
+PROGRAM_KEYS = ("kind", "fun_name", "seconds", "nesting", "cause")
+CAUSE_KEYS = ("run", "top", "depth", "bracket")
 
 # resilience events (self-healing runtime): the supervisor and the
 # engines narrate recovery in the same stream the waves go to, so a
@@ -260,6 +281,41 @@ def hashv_of(ident: str) -> int:
     return int(m.group(1)) if m else 0
 
 
+def _negative_or_no_number(v) -> bool:
+    return isinstance(v, bool) or not isinstance(v, (int, float)) or v < 0
+
+
+def _program_problems(programs) -> list[tuple]:
+    """(index, problem) for each record of a summary's ``programs`` that
+    is not one: a dict with PROGRAM_KEYS, a known kind, non-negative
+    seconds, a cause with CAUSE_KEYS, and on a load its cache_hit."""
+    if not isinstance(programs, list):
+        return [("", f"{type(programs).__name__}, not a list of records")]
+    found = []
+    for i, rec in enumerate(programs):
+        if not isinstance(rec, dict):
+            found.append((i, "not an object"))
+            continue
+        missing = [k for k in PROGRAM_KEYS if k not in rec]
+        if missing:
+            found.append((i, f"missing {missing}"))
+            continue
+        if rec["kind"] not in PROGRAM_KINDS:
+            found.append(
+                (i, f"kind {rec['kind']!r} not in {PROGRAM_KINDS}"))
+        if _negative_or_no_number(rec["seconds"]):
+            found.append((i, f"seconds {rec['seconds']!r} must be a "
+                             f"non-negative number"))
+        cause = rec["cause"]
+        if not isinstance(cause, dict) or any(
+                k not in cause for k in CAUSE_KEYS):
+            found.append((i, f"cause {cause!r} must carry {CAUSE_KEYS}"))
+        if rec["kind"] == "load" and not isinstance(
+                rec.get("cache_hit"), bool):
+            found.append((i, "a load record says cache_hit true or false"))
+    return found
+
+
 def validate_event(ev: object, lineno: int | None = None) -> list[str]:
     """Problems with one decoded event (empty list = valid). Extra keys
     are allowed — engines extend the schema; they never shrink it."""
@@ -363,11 +419,23 @@ def validate_event(ev: object, lineno: int | None = None) -> list[str]:
                 f"{where}memwatch breakdown must map buffer family "
                 f"names to non-negative int bytes"
             )
-    if etype == "summary" and ev.get("exit_cause") not in EXIT_CAUSES:
-        problems.append(
-            f"{where}summary exit_cause {ev.get('exit_cause')!r} not in "
-            f"{EXIT_CAUSES}"
-        )
+    if etype == "summary":
+        if ev.get("exit_cause") not in EXIT_CAUSES:
+            problems.append(
+                f"{where}summary exit_cause {ev.get('exit_cause')!r} not "
+                f"in {EXIT_CAUSES}"
+            )
+        for key in PROCESS_KEYS:
+            v = ev.get(key)
+            if v is not None and _negative_or_no_number(v):
+                problems.append(
+                    f"{where}summary {key} {v!r} must be a non-negative "
+                    f"number"
+                )
+        problems += [
+            f"{where}summary programs[{i}]: {p}"
+            for i, p in _program_problems(ev.get("programs", []))
+        ]
     if etype == "retry":
         att = ev.get("attempt")
         if isinstance(att, bool) or not isinstance(att, int) or att < 1:

@@ -66,6 +66,23 @@ class ProgressRenderer:
             line += f", hbm {ev['hbm_frac']:.0%}"
         return line
 
+    def loaded(self, rec: dict) -> None:
+        """One line for a program that took long to load (a ``load``
+        record of obs/compiles.py): which, how, and the span it was
+        loaded in."""
+        cause = rec["cause"]
+        where = [cause["top"] or "outside a run"]
+        if cause["depth"] is not None:
+            where[0] += f" {cause['depth']}"
+        if cause["bracket"]:
+            where.append(cause["bracket"])
+        how = "read from the cache" if rec["cache_hit"] else "compiled"
+        print(
+            f"loading {rec['fun_name']}: {how} in {rec['seconds']:.1f} s "
+            f"({', '.join(where)})",
+            file=self.stream, flush=True,
+        )
+
     def __call__(self, ev: dict) -> None:
         etype = ev.get("event")
         if etype == "stall":

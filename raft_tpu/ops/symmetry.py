@@ -118,6 +118,7 @@ from .hashing import (
 )
 from .packing import EMPTY, BitPacker, WidePacker
 from ..models.base import Layout
+from ..obs.trace import setup_phase
 
 _C1 = np.uint64(0x9E3779B97F4A7C15)
 _C2 = np.uint64(0xC2B2AE3D27D4EB4F)
@@ -295,6 +296,7 @@ def canon_chunk(canon, states, valid):
 
 class Canonicalizer:
     @classmethod
+    @setup_phase("engine/canon")
     def for_model(cls, model, symmetry: bool = True, seed: int = 0,
                   mode: str = "auto",
                   refine_rounds: int = 3) -> "Canonicalizer":

@@ -65,7 +65,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..checker.lsm import RunLSM, pow2_at_least
 from ..obs import (
-    COMPILES, MemWatch, NULL_TELEMETRY, device_budget, stage, traced_run,
+    COMPILES, MemWatch, NULL_TELEMETRY, device_budget, setup_phase, span,
+    stage, traced_run,
 )
 from ..obs.events import hashv_of
 from ..checker.util import (
@@ -142,6 +143,7 @@ class ShardedBFS:
     #   chunk: next_buf, jps, jpl, jcand, jfp, viol, stats, cov
     STEP_DONATE = (2, 3, 4, 5, 6, 7, 8, 9)
 
+    @setup_phase("engine")
     def __init__(
         self,
         model,
@@ -1540,7 +1542,7 @@ class ShardedBFS:
                 # per-chip floor is smaller than DeviceBFS's (1<<21):
                 # each chip holds ~1/D of the space
                 if self._lsm.lanes() > max(4 * int(scounts.max()), 1 << 20):
-                    with tel.annotate("consolidate"):
+                    with span("consolidate"):
                         self._lsm.consolidate(int(scounts.max()))
                 if (
                     checkpoint_path is not None
@@ -1678,6 +1680,7 @@ class ShardedBFS:
         self._journals = (jps_h, jpl_h, jcand_h, jcounts.copy(), n0.copy())
 
         dt = time.perf_counter() - t0
+        top_s = ph.top_seconds()  # read beside dt: they add up to it
         if violation is not None:
             exit_cause = "violation"
         elif exit_cause is None:
@@ -1688,7 +1691,8 @@ class ShardedBFS:
         fleet_rate = round(dup_prev / max(1, gen_prev), 4)
         fleet_cov = cov_hd.sum(axis=0)
         run_stats = {
-            **COMPILES.run_stats(comp_run), "dedup_plan": self._dedup_plan(),
+            **COMPILES.run_stats(comp_run), **top_s,
+            "dedup_plan": self._dedup_plan(),
             "canon_tier3_local": int(tiers_prev[0]),
             "canon_tier3_full": int(tiers_prev[1]),
         }
@@ -1727,6 +1731,7 @@ class ShardedBFS:
             "shard_dup_lanes": fleet_stats["shard_dup_lanes"],
             "shard_skew": fleet_stats["shard_skew"],
             **run_stats,
+            "programs": COMPILES.programs(comp_run),
             **(memwatch.summary_fields() if memwatch is not None else {}),
         })
         trace = init_trace

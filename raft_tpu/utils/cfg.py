@@ -16,6 +16,8 @@ from __future__ import annotations
 import re
 from dataclasses import dataclass, field
 
+from ..obs.trace import setup_phase
+
 
 class CfgError(Exception):
     pass
@@ -82,6 +84,7 @@ def _strip_comment(line: str) -> str:
     return line[:i] if i >= 0 else line
 
 
+@setup_phase("cfg")
 def parse_cfg(path: str, text: str | None = None, lenient: bool = False) -> Cfg:
     """Parse a TLC cfg. ``lenient=True`` downgrades recoverable cfg bugs
     (see Cfg.diagnostics) from errors to recorded diagnostics, applying the

@@ -35,6 +35,12 @@ A `wave` event's canon_tier3_local and
 canon_tier3_full (lanes its canon routed to tier 3's buckets) must be
 non-negative ints that together do not exceed generated -
 canon_dup_lanes (the representatives its in-chunk dedup let through).
+A `summary` event's set-up keys (programs_loaded, programs_traced,
+setup_*_s, load_*_s: obs/compiles.py) must be non-negative numbers or
+null, and its `programs`, where it has them, a list of records with a
+kind of trace / lower / load, a fun_name, non-negative seconds, a
+nesting, a cause naming run, top, depth and bracket, and on a load
+cache_hit true or false.
 Job-tagged streams (the one
 multiplexed file a `raft_tpu sweep --metrics-out` run writes) get the
 fleet rules: a `job` tag must be a non-empty string, each job's wave

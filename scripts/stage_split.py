@@ -37,14 +37,12 @@ import time
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
-_CONTROL = ("while", "body", "cond", "closed_call")
-
 
 def split(path, top=16):
-    """Seconds of device self time by scope path two levels deep, and the
-    ``top`` heaviest (op, name stack) pairs, of one .xplane.pb."""
+    """Seconds of device self time by scope path two levels deep (the
+    benchmark's own rule, ``xplane.scope_path``), and the ``top``
+    heaviest (op, name stack) pairs, of one .xplane.pb."""
     from benchmark import xplane, xspace
-    from benchmark.readers import scope_time
 
     with open(path, "rb") as f:
         buf = memoryview(f.read())
@@ -60,20 +58,7 @@ def split(path, top=16):
               for key, meta in xspace.map_entries(plane, 4)}
         for start, end, meta in xplane.self_pieces(xspace.op_events(plane)):
             stack = tf_ops.get(meta) or ""
-            stage = scope_time.stage_of(stack)
-            parts = stack.split("/")
-            if stage is None:
-                scope = "unscoped"
-            else:
-                # the next named scope under the stage: not a transform
-                # (``vmap()``, ``jit(f)``), a function's name, control flow
-                # or a closed call's repeat of the prefix, and not the
-                # last element, which is the op itself
-                inner = [p for p in parts[parts.index(stage) + 1:-1]
-                         if "(" not in p and "." not in p
-                         and p not in _CONTROL + scope_time.STAGES
-                         and not p.startswith("branch_")]
-                scope = "/".join([stage, *inner[:1]])
+            scope = "/".join(xplane.scope_path(stack)) or "unscoped"
             by_scope[scope] += end - start
             by_op[(scope, op[meta], stack[-80:])] += end - start
     per = 1e9 * max(1, n_planes)
