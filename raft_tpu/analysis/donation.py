@@ -2,7 +2,7 @@
 output of the lowered program that rebinds it.
 
 The engines declare their dispatch surface via ``audit_programs()``
-(DeviceBFS: the fused wave program + the seen-ladder merge;
+(DeviceBFS: the fused wave program + the end-of-wave seen merge;
 ShardedBFS: the shard_map chunk program; RunLSM: the
 cascade merge closure). Each entry carries an INDEPENDENT ``carries``
 map — written out separately from the ``*_DONATE`` tuples the jits
@@ -15,10 +15,10 @@ The proof reads the LOWERED computation, not the python: jax marks
 input-output aliasing in the StableHLO ``@main`` signature as
 ``{tf.aliasing_output = K}`` arg attributes. A carry must carry that
 attribute whenever a shape/dtype-compatible output slot exists for it
-(a carry whose shape matches no remaining output — e.g. a ladder run
-folded into the seen merge — cannot alias anything and is exempt: the
-engines build such inputs undonated by declaration, see
-checker/util.py jit_with_donation).
+(a carry whose shape matches no remaining output — e.g. the wave's
+fingerprint buffer folded into the seen merge — cannot alias anything
+and is exempt: the engines build such inputs undonated by declaration,
+see checker/util.py jit_with_donation).
 
 Coverage vs budget: the full device + sharded + LSM surface is lowered
 for one family (raft); for the other five families the fused wave
@@ -107,8 +107,8 @@ def audit_entry(entry: dict, scope: str, findings: list) -> None:
             continue  # aliased: the contract holds
         if avail.get(ty, 0) <= 0:
             # no compatible output slot remains — aliasing is
-            # impossible for this carry (e.g. ladder runs folded into
-            # the seen merge), declared undonated by the engine
+            # impossible for this carry (e.g. the wave's buffer folded
+            # into the seen merge), declared undonated by the engine
             continue
         avail[ty] -= 1
         per_wave = entry.get("per_wave", 1)

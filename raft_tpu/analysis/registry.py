@@ -11,7 +11,7 @@ wave/chunk jit wrappers.
 Lint engine geometry: capacities small enough that program LOWERING (the
 only cost a pass pays) stays in the tier-1 smoke budget, while keeping
 every structural element real — a multi-size seen ladder, VC pad rows,
-the binary-counter wave ladder.
+the wave's fingerprint buffer and its prefix switch.
 """
 
 from __future__ import annotations

@@ -16,7 +16,8 @@ two are equal:
     first-size-at-least member the ladder implies, always inside the
     precompiled wave set, and overflow past TOPSZ must raise;
   * merge closure — the precompiled merge keys must cover every
-    (size, target >= size) pair at the wave-ladder shapes;
+    (size, target >= size) pair at the shape of the wave's fingerprint
+    buffer (FCAP lanes: what the wave program hands the merge);
   * pad-up proof — ``eval_shape`` of every merge spec body returns
     EXACTLY ``(target,)`` u64 (the shape invariant whose violation
     caused the cliff);
@@ -116,11 +117,8 @@ def _check_device(fam: str, eng, findings: list) -> int:
     except OverflowError:
         pass
 
-    # merge closure at the wave-ladder shapes
-    K = 0
-    while (eng.R0 << K) < _pow2_at_least(eng.FCAP):
-        K += 1
-    lshapes = tuple(eng.R0 << i for i in range(K + 1))
+    # merge closure at the wave buffer's shape
+    lshapes = (eng.FCAP,)
     expect_merges = {
         (s, lshapes, t) for si, s in enumerate(sizes)
         for t in sizes[si:]
@@ -131,8 +129,8 @@ def _check_device(fam: str, eng, findings: list) -> int:
         findings.append(Finding(
             PASS_ID, "error", mpath, mline,
             f"device:{fam}: precompiled merge signatures differ from "
-            f"the reachable (size, target>=size) closure at ladder "
-            f"shapes {lshapes}",
+            f"the reachable (size, target>=size) closure at the wave "
+            f"buffer's shape {lshapes}",
             {"missing": sorted(
                 str(k) for k in expect_merges - merge_set),
              "extra": sorted(str(k) for k in merge_set - expect_merges)},
@@ -194,13 +192,6 @@ def _check_device(fam: str, eng, findings: list) -> int:
             f"tail signature",
         ))
     return checked
-
-
-def _pow2_at_least(n: int) -> int:
-    p = 1
-    while p < n:
-        p <<= 1
-    return p
 
 
 def _check_sharded(fam: str, sh, findings: list) -> int:

@@ -10,9 +10,10 @@ Pipeline per chunk (one jitted program, all device):
   2. compact the valid successor lanes (typically <20% of chunk*A) so
      canonicalization/hashing only runs on real candidates
   3. canonical fingerprints (VIEW + SYMMETRY, ops/symmetry.py)
-  4. dedup: one merged sort of the seen-set runs with the chunk's
-     fingerprints gives membership and first-occurrence within the
-     chunk at once (checker/util.py first_new)
+  4. dedup: one merged sort of the seen run, what the wave has written
+     of its new fingerprints so far and the chunk's fingerprints gives
+     membership and first-occurrence within the chunk at once
+     (checker/util.py first_new)
   5. compact survivors to a dense prefix block and APPEND it at the
      running cursor of the device next-frontier buffer — and their
      (parent gid, candidate) rows at the journal cursor — with one
@@ -20,28 +21,30 @@ Pipeline per chunk (one jitted program, all device):
      redesign retired the full-capacity scatters this step used to do)
   6. evaluate invariants on the compacted candidates, folding the first
      violating gid per invariant into a device accumulator
-  7. emit the chunk's new fingerprints as one small sorted run
+  7. append the chunk's new fingerprints at the wave's running count
+     of the wave's fingerprint buffer, as the rows are in step 5
 
-The seen-set is an LSM of SORTED RUNS (round-4 redesign): level i holds
-at most one sorted u64 run of R0<<i lanes (R0 = the chunk's successor
-budget rounded to a power of two). Each chunk's new fingerprints enter
-at level 0; two runs at the same level merge (sort-concat — measured
-faster than scatter-merges on this TPU, see the note in _chunk_step)
-into the next level, exactly a binary counter. Membership is by merging,
-not searching: on the v5e every step of a searchsorted is a serial
-gather, 467.5 us for 65,536 queries whatever they hold, and four runs
-of 17-19 steps were 92 ms of a 152 ms chunk-step, where a 720,896-lane
-2-key sort takes 1.187 ms (the recorded trace benchmark/testdata/
-scoped_v5e, PR 24). So a chunk-step sorts its fingerprints together
-with every run short enough (util.first_new; the rule reads shapes
-alone) and binary-searches only a seen run past that crossover, whose
-cost is O(VC log) and INDEPENDENT of the total state count — the
-round-3 design re-sorted an FCAP-lane buffer per chunk and SCAP+FCAP
-lanes per wave, which dominated small and deep runs alike (round-3
-verdict Weak #2, Next #4). The cascade is deterministic
-(occupancy-driven), so the host enqueues merges without ever syncing on
-a chunk's result; padding waste is bounded by wave-boundary
-consolidation.
+The seen-set is ONE SORTED RUN between waves and, inside a wave, that
+run and an APPEND BUFFER: a u64 buffer of the frontier's capacity,
+U64_MAX from the wave's start, to which every chunk-step appends its
+new fingerprints at the wave's running count, so its real lanes are a
+dense prefix of known length (not sorted: nothing needs it sorted). At
+the wave's end one sort-concat (measured faster than scatter-merges on
+this TPU, see the note in _st_finish) folds the buffer into the seen
+run. Membership is by merging, not searching: on the v5e every step of
+a searchsorted is a serial gather, 467.5 us for 65,536 queries whatever
+they hold, and four runs of 17-19 steps were 92 ms of a 152 ms
+chunk-step, where a 720,896-lane 2-key sort takes 1.187 ms (the
+recorded trace benchmark/testdata/scoped_v5e, PR 24). So a chunk-step
+sorts its fingerprints together with the seen run, while that is short
+enough (util.first_new; the rule reads shapes alone), and with the
+smallest of a few static prefixes of the buffer that holds the wave's
+count: the sort costs what the wave has written, not what it could
+hold. Only a seen run past the crossover is binary-searched, at a cost
+of O(VC log) that is INDEPENDENT of the total state count — the round-3
+design re-sorted an FCAP-lane buffer per chunk and SCAP+FCAP lanes per
+wave, which dominated small and deep runs alike (round-3 verdict Weak
+#2, Next #4).
 
 This replaces TLC's shared fingerprint set + BFS queue (SURVEY.md §3.1
 hot loop); `-deadlock` semantics are preserved (terminal states counted,
@@ -72,6 +75,7 @@ from .lsm import pow2_at_least
 from .util import (
     GROWTH, HEADROOM, I32_MAX, dedup_plan, dense_prefix_sel, emit_append,
     first_new, jit_with_donation, next_cap, rank_counts, rank_onehot,
+    wave_prefix_sizes,
 )
 
 
@@ -115,10 +119,12 @@ class DeviceBFS:
     # the in-program stats vector, i64[N_STATS]: [wave new count, journal
     # count, cumulative generated, cumulative terminal, overflow bits,
     # then cumulative canon counts: in-chunk duplicates, tier-3 local
-    # lanes, tier-3 full lanes]. STATS_KEEP is what a wave's first chunk
-    # keeps of it (the wave-new and overflow lanes reset in-program).
-    N_STATS = 8
-    STATS_KEEP = (0, 1, 1, 1, 0, 1, 1, 1)
+    # lanes, tier-3 full lanes; last the lanes the dedup stage's merged
+    # sort sorted, summed over the wave's chunk-steps]. STATS_KEEP is
+    # what a wave's start keeps of it (the wave-new, overflow and
+    # sorted-lanes lanes reset in-program).
+    N_STATS = 9
+    STATS_KEEP = (0, 1, 1, 1, 0, 1, 1, 1, 0)
 
     # Donation contract for the wave program: argument indices of
     # the capacity-shaped loop carries updated in place every dispatch
@@ -187,7 +193,7 @@ class DeviceBFS:
         assert frontier_cap % chunk == 0, "frontier_cap must be a multiple of chunk"
         # seen-set geometry (round 5): ONE device-resident sorted run,
         # sized from a small pow2 ladder and merged with the wave's
-        # fingerprint ladder ON DEVICE once per wave. Every extra
+        # fingerprint buffer ON DEVICE once per wave. Every extra
         # multi-million-lane run is one more searchsorted per CHUNK
         # (the old binary-counter LSM probed up to 3 on deep waves),
         # and a host-side repack moves the whole set over PCIe and
@@ -248,8 +254,8 @@ class DeviceBFS:
         self._seen = jnp.asarray(host)
         self._seen_real = n
 
-    def _merge_seen(self, ladder, new_real: int) -> None:
-        """seen <- sort(concat(seen, *ladder)) resized to EXACTLY the
+    def _merge_seen(self, wave_new, new_real: int) -> None:
+        """seen <- sort(concat(seen, wave_new)) resized to EXACTLY the
         ladder size `target` on device. Truncation only drops U64_MAX
         padding (new_real <= target by construction); when the concat is
         SHORTER than target the result is padded back up with U64_MAX —
@@ -261,26 +267,26 @@ class DeviceBFS:
         mid-run compile was round 5's unexplained final-wave cliff at
         depth 32 (most of that wave's wall time)."""
         target = self._seen_size_for(new_real)
-        key = (self._seen.shape[0], tuple(l.shape[0] for l in ladder), target)
+        key = (self._seen.shape[0], (wave_new.shape[0],), target)
         fn = self._merge_cache.get(key)
         if fn is None:
             fn = self._make_seen_merge(key)
             self._merge_cache[key] = fn
-        self._seen = fn(self._seen, *ladder)
+        self._seen = fn(self._seen, wave_new)
         self._seen_real = new_real
 
     @staticmethod
     def _seen_merge_spec(key):
         """(body, donate_argnums) of the merge program for one
-        (seen size, ladder shapes, target) signature — the single source
-        both the production wrapper below and the static donation /
-        signature auditors build from. The old seen run is donated
+        (seen size, (wave buffer lanes,), target) signature — the single
+        source both the production wrapper below and the static donation
+        / signature auditors build from. The old seen run is donated
         where the output can alias it (size == target: the steady-state
         merge sorts into the dead run's HBM instead of holding old + new
         live); a merge that steps the seen ladder up (size < target)
-        and the ladder runs (never the output's shape) cannot alias
-        anything and are undonated by declaration. The pad-up branch
-        keeps the output EXACTLY ``target`` lanes even when the concat
+        cannot alias it, and the wave's buffer is never donated (it is
+        the output's shape only by an accident of capacities). The
+        pad-up branch keeps the output EXACTLY ``target`` lanes even when the concat
         total falls short — the signature-closure invariant
         (_merge_seen) depends on it."""
         size, lshapes, target = key
@@ -384,25 +390,28 @@ class DeviceBFS:
         return canon_chunk(self.canon, flatc, selv)
 
     @stage("dedup")
-    def _st_dedup(self, fps, occ, *runs):
-        """Stage 4: new = not in any LSM run and first occurrence in the
-        chunk (lowest lane), by one merged sort (util.first_new; a run
-        past its crossover is still searched, under ``occ``). Runs
-        inserted by earlier chunks of this wave are in ``runs`` already
-        (the cascade is enqueued before the next chunk call), so
-        cross-chunk in-wave dedup falls out of the same lookup."""
-        return first_new(fps, occ, runs)
+    def _st_dedup(self, fps, occ, wave_new, ncount, *runs):
+        """Stage 4: new = not in the seen run, not among the ``ncount``
+        fingerprints earlier chunks of this wave appended to
+        ``wave_new``, and first occurrence in the chunk (lowest lane),
+        by one merged sort of the seen run, the smallest static prefix
+        of ``wave_new`` that holds ``ncount`` lanes and the chunk
+        (util.first_new; a seen run past its crossover is still
+        searched, under ``occ``). A fingerprint chunk k appended is a
+        run lane for chunk k + 1, so cross-chunk in-wave dedup falls out
+        of the same lookup. Returns (new, lanes that sort sorted)."""
+        return first_new(
+            fps, occ, runs, wave=(wave_new, ncount, self._wave_prefix()))
 
     @stage("emit")
     def _st_finish(
-        self, next_buf, jparent, jcand, viol, stats, cov, flatc, fps,
-        sel, valid, rank, new, n_gen, terminal, expand_ovf, compact_ovf,
-        canon_n, cursor, base_gid,
+        self, next_buf, jparent, jcand, viol, stats, cov, wave_new,
+        flatc, fps, sel, valid, rank, new, n_gen, terminal, expand_ovf,
+        compact_ovf, canon_n, sort_lanes, cursor, base_gid,
     ):
-        """Stages 4b-6: per-action coverage, the cursor-append emit,
-        invariants on the new states and the stats fold. Returns the
-        updated carries plus the chunk's new fingerprints as a sorted
-        R0-lane run."""
+        """Stages 4b-6: per-action coverage, the cursor-append emit of
+        rows, journal and new fingerprints, invariants on the new states
+        and the stats fold. Returns the updated carries."""
         model = self.model
         C, A, W, VC = self.chunk, self.A, self.W, self.VC
         FCAP, JCAP = self.FCAP, self.JCAP
@@ -461,14 +470,14 @@ class DeviceBFS:
         # better than sort-concat for merging sorted sets, but arbitrary-
         # index scatters serialize on this hardware while XLA's bitonic
         # sort is fast (scripts/emit_micro.py reproduces the scatter
-        # penalty on the current backend). All LSM merges therefore use
+        # penalty on the current backend). All seen merges therefore use
         # sort-concat (as 2-key u32 sorts — hashing.py), and the
-        # per-chunk sort below is only R0 = 2^ceil(log2(VC)) lanes.
+        # per-chunk sort below, VC lanes, is the compaction: the chunk's
+        # new fingerprints first and padding after, a dense block to
+        # append at the wave's count like the rows above. Its padding
+        # tail lands on padding, and the next append overwrites it.
         new_run = sort_u64(jnp.where(new, fps, U64_MAX))
-        if self.R0 > VC:
-            new_run = jnp.concatenate(
-                [new_run, jnp.full((self.R0 - VC,), U64_MAX, jnp.uint64)]
-            )
+        wave_new, _ = emit_append(wave_new, new_run, ncount, n_new, FCAP)
 
         # 6. invariants on the compacted candidates; fold first-bad gid
         jidx = jnp.where(new, jcount + npos, I32_MAX)
@@ -492,53 +501,45 @@ class DeviceBFS:
                 stats[2] + n_gen,
                 stats[3] + terminal,
                 stats[4] | ovf_bits,
-                *(stats[5:] + canon_n),
+                *(stats[5:8] + canon_n),
+                stats[8] + sort_lanes,
             ]
         )
-        return next_buf, jparent, jcand, viol, stats, cov, new_run
+        return next_buf, jparent, jcand, viol, stats, cov, wave_new
 
     def _chunk_step(
         self, frontier, next_buf, jparent, jcand, viol, stats, cov,
-        cursor, fcount, base_gid, occ, first, *runs,
+        wave_new, cursor, fcount, base_gid, occ, *runs,
     ):
         """One chunk of the current wave (the four stage methods above,
         composed — one traced program). stats is the i64[N_STATS]
         vector the class comment lays out; cov is the i64[n_actions, 3]
         per-action coverage accumulator — [enabled, fired, new-distinct]
         per Next-disjunct rank, cumulative over the WHOLE run (never
-        reset, so host snapshots are monotone); occ is bool[n_levels]
-        (the binary search of an unoccupied level is skipped via
-        lax.cond; a merged level is sorted either way); first marks the
-        wave's first chunk (resets the wave-new and overflow lanes
-        in-program, saving a per-wave host->device stats upload —
-        dispatch latency dominates small configs). Returns
-        the chunk's new fingerprints as a sorted R0-lane run."""
-        stats = jnp.where(
-            first,
-            stats * jnp.asarray(self.STATS_KEEP, dtype=stats.dtype),
-            stats,
-        )
+        reset, so host snapshots are monotone); wave_new is the wave's
+        fingerprint buffer, u64[FCAP + VC], whose first stats[0] lanes
+        are the fingerprints the wave's earlier chunks found new and
+        whose rest is U64_MAX; occ is bool[n_runs] (the binary search of
+        an unoccupied run is skipped via lax.cond; a merged run is
+        sorted either way). Returns the carries, wave_new with the
+        chunk's new fingerprints appended."""
         (flatc, sel, selv, valid, rank, n_gen, terminal, expand_ovf,
          compact_ovf) = self._st_expand(frontier, cursor, fcount)
         fps, canon_n = self._st_canon(flatc, selv)
-        new = self._st_dedup(fps, occ, *runs)
-        (next_buf, jparent, jcand, viol, stats, cov,
-         new_run) = self._st_finish(
-            next_buf, jparent, jcand, viol, stats, cov, flatc, fps, sel,
-            valid, rank, new, n_gen, terminal, expand_ovf, compact_ovf,
-            canon_n, cursor, base_gid,
+        new, sort_lanes = self._st_dedup(
+            fps, occ, wave_new, stats[0].astype(jnp.int32), *runs)
+        return self._st_finish(
+            next_buf, jparent, jcand, viol, stats, cov, wave_new, flatc,
+            fps, sel, valid, rank, new, n_gen, terminal, expand_ovf,
+            compact_ovf, canon_n, sort_lanes, cursor, base_gid,
         )
-        return next_buf, jparent, jcand, viol, stats, cov, new_run
 
-    def _wave_geom(self) -> int:
-        """Ladder depth K: levels R0<<0 .. R0<<K, top >= pow2(FCAP), so a
-        whole wave's new fingerprints fit in-program (the top absorbs by
-        truncate-merge, sound while the wave's real new count <= FCAP —
-        the frontier overflow bit aborts the run otherwise)."""
-        K = 0
-        while (self.R0 << K) < pow2_at_least(self.FCAP):
-            K += 1
-        return K
+    def _wave_prefix(self) -> tuple[int, ...]:
+        """The prefixes of the wave's fingerprint buffer the dedup stage
+        can sort (util.wave_prefix_sizes): 0, R0, 4 * R0, ... under
+        FCAP, then FCAP, which holds a whole wave's new fingerprints
+        (the frontier overflow bit aborts the run otherwise)."""
+        return wave_prefix_sizes(self.R0, self.FCAP)
 
     def _wave_step(
         self, frontier, next_buf, jparent, jcand, viol, stats, cov,
@@ -546,74 +547,28 @@ class DeviceBFS:
     ):
         """One WAVE as a single dispatched program (round 5, verdict Next
         #1): a lax.while_loop drives the chunk pipeline over the frontier,
-        deduplicating in-wave against an in-program binary-counter ladder
-        of sorted fingerprint runs — so the host dispatches ONCE per wave
+        deduplicating in-wave against the fingerprints the wave's
+        earlier chunks appended to one in-program buffer (wave_new,
+        U64_MAX at the wave's start; _st_dedup sorts the prefix of it
+        the wave has written) — so the host dispatches ONCE per wave
         and syncs once instead of once per chunk: a 170-chunk deep wave
         is one launch and one host round-trip, and the device never
         idles between chunks waiting for the host.
-        Returns (next_buf, jparent, jcand, viol, stats, cov, *ladder);
-        the host inserts the occupied ladder levels into the
-        RunLSM."""
+        Returns (next_buf, jparent, jcand, viol, stats, cov,
+        wave_new[:FCAP]); the host merges the last into the seen run."""
         C = self.chunk
-        K = self._wave_geom()
-        R0 = self.R0
-
+        # the wave-new, overflow and sorted-lanes lanes reset in-program:
+        # no host->device stats upload a wave (dispatch latency dominates
+        # small configs)
         stats = stats * jnp.asarray(self.STATS_KEEP, dtype=stats.dtype)
-        occ_all = jnp.concatenate(
-            [occ, jnp.ones((K + 1,), bool)]
-        )  # ladder levels always looked up (empties hold U64_MAX padding)
-        ladder0 = tuple(
-            jnp.full((R0 << i,), U64_MAX, jnp.uint64) for i in range(K + 1)
-        )
-        topsz = R0 << K
-
-        def cascade(k, new_run, ladder):
-            """Binary-counter insert of the chunk's R0-run: after chunk k,
-            the ladder encodes counter k+1. The merge chain length is the
-            number of trailing one-bits of k (capped at K, where the top
-            absorbs by truncate-merge)."""
-            kp1 = k + 1
-            t = jnp.int32(0)
-            for i in range(1, K + 1):
-                t = t + (kp1 & ((1 << i) - 1) == 0).astype(jnp.int32)
-
-            def make_branch(tt):
-                def branch(r, *lv):
-                    out = list(lv)
-                    if tt < K:
-                        merged = sort_u64(
-                            jnp.concatenate([r, *lv[:tt]])
-                        )  # R0 * 2^tt lanes
-                        for i in range(tt):
-                            out[i] = jnp.full((R0 << i,), U64_MAX, jnp.uint64)
-                        out[tt] = merged
-                    else:
-                        merged = sort_u64(jnp.concatenate([r, *lv]))[:topsz]
-                        for i in range(K):
-                            out[i] = jnp.full((R0 << i,), U64_MAX, jnp.uint64)
-                        out[K] = merged
-                    return tuple(out)
-
-                return branch
-
-            return lax.switch(
-                jnp.clip(t, 0, K), [make_branch(tt) for tt in range(K + 1)],
-                new_run, *ladder,
-            )
+        # rows [FCAP, FCAP + VC) are the append's drop region
+        # (util.emit_append), as next_buf's are
+        wave_new = jnp.full((self.FCAP + self.VC,), U64_MAX, jnp.uint64)
 
         def body(carry):
-            (k, next_buf, jparent, jcand, viol, stats, cov,
-             *ladder) = carry
-            (next_buf, jparent, jcand, viol, stats, cov,
-             new_run) = self._chunk_step(
-                frontier, next_buf, jparent, jcand, viol, stats, cov,
-                k * C, fcount, base_gid, occ_all, jnp.asarray(False),
-                *runs, *ladder,
-            )
-            with stage("seen_merge"), jax.named_scope("cascade"):
-                ladder = cascade(k, new_run, ladder)
-            return (k + 1, next_buf, jparent, jcand, viol, stats, cov,
-                    *ladder)
+            k, *carries = carry
+            return (k + 1, *self._chunk_step(
+                frontier, *carries, k * C, fcount, base_gid, occ, *runs))
 
         def cond(carry):
             return carry[0] * C < fcount
@@ -621,9 +576,9 @@ class DeviceBFS:
         out = lax.while_loop(
             cond, body,
             (jnp.int32(0), next_buf, jparent, jcand, viol, stats, cov,
-             *ladder0),
+             wave_new),
         )
-        return out[1:]
+        return (*out[1:-1], out[-1][:self.FCAP])
 
     # ---------------- precompile ----------------
 
@@ -644,15 +599,15 @@ class DeviceBFS:
         """The FINITE signature universe a run at the CURRENT capacities
         dispatches, in precompile order: a ``("wave", seen_size)`` per
         seen-ladder size, each followed by the per-wave seen merges that
-        size can need — ``("merge", size, lshapes, target)`` for every
-        ladder target >= size. ``_precompile_programs`` warms exactly
+        size can need — ``("merge", size, (FCAP,), target)``, FCAP the
+        lanes of the wave's fingerprint buffer, for every ladder target
+        >= size. ``_precompile_programs`` warms exactly
         this set; analysis/signatures.py independently recomputes the
         reachable set from the geometry primitives (_seen_size_for, the
-        wave ladder, the pad-up merge contract) and proves the two are
+        wave buffer, the pad-up merge contract) and proves the two are
         equal — round 5's retrace-cliff class, checked symbolically.
         """
-        K = self._wave_geom()
-        lshapes = tuple((self.R0 << i) for i in range(K + 1))
+        lshapes = (self.FCAP,)
         for si, size in enumerate(self._seen_sizes):
             yield ("wave", size)
             # targets >= size only: one wave adds at most pow2(FCAP)
@@ -718,7 +673,7 @@ class DeviceBFS:
         import inspect as _inspect
 
         sds = jax.ShapeDtypeStruct
-        W, K = self.W, self._wave_geom()
+        W = self.W
         i32s = sds((), np.int32)
         frontier = sds((self.FCAP + self.VC, W), jnp.int32)
         next_buf = sds((self.FCAP + self.VC, W), jnp.int32)
@@ -749,9 +704,7 @@ class DeviceBFS:
         # the per-wave seen merge, at the first (size, target) signature:
         # spec-built jit (production builds the same body and donation
         # through jit_with_donation)
-        key = (self._seen_sizes[0],
-               tuple((self.R0 << i) for i in range(K + 1)),
-               self._seen_sizes[0])
+        key = (self._seen_sizes[0], (self.FCAP,), self._seen_sizes[0])
         body, donate = self._seen_merge_spec(key)
         merge_args = tuple(
             sds((n,), jnp.uint64) for n in (key[0], *key[1])
@@ -760,8 +713,7 @@ class DeviceBFS:
             "name": "seen_merge",
             "fn": jax.jit(body, donate_argnums=donate),
             "args": merge_args,
-            "carries": {0: "seen",
-                        **{1 + i: f"ladder[{i}]" for i in range(K + 1)}},
+            "carries": {0: "seen", 1: "wave_new"},
             "pinned": {},
             "site": site(self._seen_merge_spec), "per_wave": 1,
         }
@@ -996,9 +948,7 @@ class DeviceBFS:
             MemWatch(tel, device_budget(jax.devices()[0]))
             if tel.active else None
         )
-        ladder_bytes = sum(
-            (self.R0 << i) * 8 for i in range(self._wave_geom() + 1)
-        )
+        sort_lanes_run = 0
 
         while fcount and violation is None:
             if preempt is not None and preempt.requested:
@@ -1059,8 +1009,8 @@ class DeviceBFS:
                 )
                 last_ckpt = time.perf_counter()
             # ONE dispatch per wave: the chunk loop runs device-side
-            # (_wave_step) and returns the wave's new fingerprints as a
-            # binary-counter ladder, merged into the single seen run
+            # (_wave_step) and returns the wave's new fingerprints in
+            # one buffer, merged into the single seen run
             # below AFTER the overflow check (so an aborted wave leaves
             # the seen-set untouched and the run trivially resumable).
             with tel.wave_annotation(depth + 1):
@@ -1070,8 +1020,8 @@ class DeviceBFS:
                         stats, cov, np.int32(fcount),
                         np.int32(base_gid), self._occ_one, self._seen,
                     )
-                next_buf, jparent, jcand, viol, stats, cov = out[:6]
-                ladder = out[6:]
+                (next_buf, jparent, jcand, viol, stats, cov,
+                 wave_new) = out
                 # one host round-trip per wave: stats, the invariant
                 # fold and the coverage block fetched together (two
                 # device_gets are two syncs on small configs, where
@@ -1095,7 +1045,7 @@ class DeviceBFS:
                 saved = ""
                 if checkpoint_path is not None:
                     # the aborted wave never touched the seen run (its
-                    # fingerprints live in the discarded ladder), and the
+                    # fingerprints live in the discarded buffer), and the
                     # frontier buffer and journal[:jcount] are untouched
                     # (only next_buf and journal rows past jcount were
                     # written), so the wave-start state is exactly
@@ -1120,7 +1070,7 @@ class DeviceBFS:
                 )
             # the wave completed: adopt its cumulative coverage (the
             # aborted-wave path above deliberately keeps the wave-start
-            # cov_h, matching the discarded ladder/journal rows)
+            # cov_h, matching the discarded buffer/journal rows)
             cov_h = np.asarray(cov_w, dtype=np.int64)
             n_gen = int(stats_h[2])
             wave_gen = n_gen - gen_prev
@@ -1131,11 +1081,11 @@ class DeviceBFS:
                 exit_cause = "exhausted"
                 break
             scount += ncount
-            # fold the wave ladder into the single seen run (device-side
+            # fold the wave's buffer into the single seen run (device-side
             # sort-concat; the merge-program signature set is warmed by
             # precompile)
             with ph("seen_merge"):
-                self._merge_seen(ladder, scount)
+                self._merge_seen(wave_new, scount)
             depth += 1
             distinct += ncount
             depth_counts.append(ncount)
@@ -1176,6 +1126,7 @@ class DeviceBFS:
             wave_dup, wave_t3l, wave_t3f = (
                 int(x) for x in stats_h[5:8] - canon_prev)
             canon_prev = stats_h[5:8].copy()
+            sort_lanes_run += int(stats_h[8])
             wave_s_val = time.perf_counter() - tw
             # the wave's brackets, read once: each phase's seconds are
             # those of its span. device_s is the host's WAIT on the
@@ -1201,7 +1152,7 @@ class DeviceBFS:
                     "frontier": 2 * (self.FCAP + self.VC) * 4 * W,
                     "journal": 2 * (self.JCAP + self.VC) * 4,
                     "seen": int(self._seen.shape[0]) * 8,
-                    "wave_ladder": ladder_bytes,
+                    "wave_new": (self.FCAP + self.VC) * 8,
                     "chunk": self.VC * (4 * W + 8),
                 })
             if not (tel.active or metrics is not None or verbose):
@@ -1223,6 +1174,11 @@ class DeviceBFS:
                     ),
                     "canon_tier3_local": wave_t3l,
                     "canon_tier3_full": wave_t3f,
+                    # lanes the dedup stage's merged sort sorted, summed
+                    # over the wave's chunk-steps (lane 8 of the stats
+                    # the wave already fetched): the seen run, the
+                    # prefix of the wave's buffer each step chose and VC
+                    "dedup_sort_lanes": int(stats_h[8]),
                     "overflow_bits": ovf_bits,
                     "wave_s": wave_s_val,
                     "elapsed_s": el,
@@ -1319,6 +1275,7 @@ class DeviceBFS:
             "dedup_plan": self._dedup_plan(),
             "canon_tier3_local": int(canon_prev[1]),
             "canon_tier3_full": int(canon_prev[2]),
+            "dedup_sort_lanes": sort_lanes_run,
         }
         tel.close_run({
             "engine": "device",
@@ -1509,14 +1466,11 @@ class DeviceBFS:
 
     def _dedup_plan(self) -> dict:
         """util.dedup_plan of the wave program as it stands: the seen
-        run and the in-wave ladder levels against VC query lanes (the
-        manifest has it at the run's first seen size, ``stats`` and the
-        summary at its last)."""
+        run and the prefixes of the wave's fingerprint buffer against VC
+        query lanes (the manifest has it at the run's first seen size,
+        ``stats`` and the summary at its last)."""
         return dedup_plan(
-            [self._seen.shape[0],
-             *(self.R0 << i for i in range(self._wave_geom() + 1))],
-            self.VC,
-        )
+            [self._seen.shape[0]], self.VC, self._wave_prefix())
 
     def _ckpt_ident(self) -> str:
         """Everything the saved run's soundness depends on: symmetry mode

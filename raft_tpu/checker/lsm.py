@@ -1,5 +1,8 @@
-"""LSM of sorted fingerprint runs — the round-4 seen-set shared by the
-single-device (DeviceBFS) and sharded (ShardedBFS) checkers.
+"""LSM of sorted fingerprint runs — the seen-set of the sharded checker
+(ShardedBFS). DeviceBFS has its own: one sorted run, and a wave's new
+fingerprints in one append buffer whose written prefix is sorted with
+the chunk (device_bfs.py), where every level here is sorted at its
+capacity.
 
 Level i holds at most one sorted u64 run of ``min(R0 << i, TOPSZ)`` lanes
 (tail-padded with U64_MAX). Each chunk's new fingerprints enter at level
@@ -12,9 +15,9 @@ to sort once a chunk, occupied or not, and binary-searches only the
 OCCUPIED levels above that crossover (checker/util.py first_new); per-
 chunk dedup cost is therefore independent of the total state count.
 
-Lanes live on the LAST axis: DeviceBFS uses [lanes] arrays, ShardedBFS
-[D, lanes] sharded arrays — the per-row sorts/concats are identical code,
-ShardedBFS just pins shardings via ``jit_kw``/``put``. The cascade is
+Lanes live on the LAST axis: a single device would use [lanes] arrays,
+ShardedBFS uses [D, lanes] sharded arrays — the per-row sorts/concats are
+identical code, ShardedBFS just pins shardings via ``jit_kw``/``put``. The cascade is
 deterministic (occupancy-driven), so hosts can enqueue merges without
 syncing on run contents.
 """
@@ -216,8 +219,7 @@ class RunLSM:
 
     def insert_at(self, run, level: int) -> None:
         """Insert a sorted run whose lane count equals ``lv_size(level)``
-        starting the cascade at that level (the wave-fused engine emits
-        one pre-merged ladder per wave rather than per-chunk runs)."""
+        starting the cascade at that level."""
         assert run.shape[-1] == self.lv_size(level), (
             run.shape, self.lv_size(level))
         lv = level

@@ -42,63 +42,121 @@ def probe_sorted(sorted_arr, vals):
 
 
 def merges(run_lanes: int, n_queries: int) -> bool:
-    """Whether ``first_new`` merges a run of ``run_lanes`` lanes with
-    ``n_queries`` queries or searches it: the static choice, from shapes
-    alone."""
+    """Whether ``first_new`` merges a sorted run of ``run_lanes`` lanes
+    with ``n_queries`` queries or searches it: the static choice, from
+    shapes alone. It is asked of the seen run and of the sharded
+    engine's ``RunLSM`` levels. The wave's append buffer is not asked:
+    it is not sorted, so it cannot be searched, and what it costs
+    follows the wave's count and not its capacity (at the default
+    ``max_frontier_cap`` of 2^22 its largest prefix costs what the table
+    above gives a merged run of 2^22 lanes, 21.8 ms against 23.3
+    searched)."""
     return run_lanes <= MERGE_LANES_PER_QUERY * n_queries
 
 
-def dedup_plan(run_lanes, n_queries: int) -> dict:
-    """``first_new``'s choice for runs of ``run_lanes`` lanes, as the
-    engines' run records carry it: the sizes merged, the sizes searched
-    and the lanes sorted a chunk-step."""
+def wave_prefix_sizes(r0: int, cap: int) -> tuple[int, ...]:
+    """The prefixes of a wave's append buffer that ``first_new`` can
+    sort: none of it, then ``r0`` lanes and four times as many again
+    while that is under ``cap``, then all ``cap`` lanes (four apart, as
+    the seen run's own sizes are)."""
+    sizes = [0]
+    s = r0
+    while s < cap:
+        sizes.append(s)
+        s <<= 2
+    return (*sizes, cap)
+
+
+def dedup_plan(run_lanes, n_queries: int, wave_prefix=()) -> dict:
+    """``first_new``'s choice for sorted runs of ``run_lanes`` lanes and
+    an append buffer it sorts a prefix of (``wave_prefix``: the sizes;
+    empty where the engine has no such buffer), as the engines' run
+    records carry it: the run sizes merged, the run sizes searched, the
+    prefix sizes, and the most lanes a chunk-step sorts."""
     merge = [int(n) for n in run_lanes if merges(n, n_queries)]
+    prefix = [int(p) for p in wave_prefix]
     return {
         "merge": merge,
         "search": [int(n) for n in run_lanes if not merges(n, n_queries)],
-        "sort_lanes": sum(merge) + int(n_queries),
+        "wave_prefix": prefix,
+        "sort_lanes": sum(merge) + max(prefix, default=0) + int(n_queries),
     }
 
 
-def first_new(vals, occ, runs):
+def _merged_new(vals, merged):
+    """``first_new``'s merged sort: bool[n] in lane order, lane i of
+    ``vals`` is not U64_MAX, is in none of the arrays ``merged`` (sorted
+    or not) and in no lower lane."""
+    n = vals.shape[0]
+    n_run = sum(r.shape[0] for r in merged)
+    hi, lo = split_u64(jnp.concatenate([*merged, vals]))
+    tag = jnp.concatenate([
+        jnp.zeros((n_run,), jnp.uint32),
+        jnp.arange(1, n + 1, dtype=jnp.uint32),
+    ])
+    hi, lo, tag = lax.sort((hi, lo, tag), num_keys=2, is_stable=True)
+    differs = jnp.concatenate([
+        jnp.ones((1,), bool),
+        (hi[1:] != hi[:-1]) | (lo[1:] != lo[:-1]),
+    ])
+    query = tag > 0
+    new = query & differs & ~((hi == _U32_MAX) & (lo == _U32_MAX))
+    back = jnp.where(
+        query, (tag - 1) << 1 | new.astype(jnp.uint32), _U32_MAX)
+    return (lax.sort(back)[:n] & 1).astype(bool)
+
+
+def first_new(vals, occ, runs, wave=None):
     """bool[n] in lane order: lane i holds a value that is not U64_MAX,
-    is in none of the sorted U64_MAX-padded ``runs``, and is in no lower
-    lane of ``vals`` — the seen-set probe and first-occurrence-in-chunk
-    as one rule.
+    is in none of the sorted U64_MAX-padded ``runs``, is not among the
+    first ``count`` lanes of the wave's append buffer, and is in no
+    lower lane of ``vals`` — the seen-set probe, the in-wave probe and
+    first-occurrence-in-chunk as one rule.
 
     Membership by merging, not searching: the runs that ``merges``
-    says to merge, then ``vals``, are sorted together once as u32 pairs,
-    stably, with a payload that is 0 on a run's lane and lane + 1 on a
-    query's. A run's element therefore comes before an equal query and
-    equal queries keep lane order, and a lane is new iff it is a query,
-    is not U64_MAX and differs from its predecessor. A second,
-    single-operand sort of (lane << 1 | new) brings the bits back to
-    lane order: no gather, no scatter. A merged run is sorted whether
-    ``occ`` says it is occupied or not (an unoccupied run is all
-    padding, which sorts after every real query); a run above the
-    crossover keeps the binary search under its ``lax.cond`` and its
-    hits are and-ed out."""
+    says to merge, then the buffer's prefix, then ``vals``, are sorted
+    together once as u32 pairs, stably, with a payload that is 0 on a
+    run's or the buffer's lane and lane + 1 on a query's. Such a lane
+    therefore comes before an equal query and equal queries keep lane
+    order, and a lane is new iff it is a query, is not U64_MAX and
+    differs from its predecessor. A second, single-operand sort of
+    (lane << 1 | new) brings the bits back to lane order: no gather, no
+    scatter. A merged run is sorted whether ``occ`` says it is occupied
+    or not (an unoccupied run is all padding, which sorts after every
+    real query); a run above the crossover keeps the binary search
+    under its ``lax.cond`` and its hits are and-ed out.
+
+    ``wave`` is ``(buf, count, sizes)``: a U64_MAX-padded u64 buffer the
+    wave's earlier chunk-steps appended their new values to (in any
+    order: the sort never needs its operands sorted, so the buffer is
+    always merged and never searched), the traced i32 count of its real
+    lanes, which are its first, and the static prefix sizes
+    (``wave_prefix_sizes``; the last covers every lane that can be
+    real). What is sorted is the smallest prefix that holds ``count``
+    lanes, by a ``lax.switch`` over the sizes, so the sorts cost what
+    the wave has written and not what it could hold. With ``wave`` the
+    result is ``(new, lanes)``, lanes the i32 number of lanes the
+    merged sort sorted; without, the program is the one it always
+    was."""
     n = vals.shape[0]
     assert n < 1 << 31
     merged = [r for r in runs if merges(r.shape[0], n)]
     searched = [(i, r) for i, r in enumerate(runs) if not merges(r.shape[0], n)]
+    lanes = None
     with jax.named_scope("merge"):
-        n_run = sum(r.shape[0] for r in merged)
-        hi, lo = split_u64(jnp.concatenate([*merged, vals]))
-        tag = jnp.concatenate([
-            jnp.zeros((n_run,), jnp.uint32),
-            jnp.arange(1, n + 1, dtype=jnp.uint32),
-        ])
-        hi, lo, tag = lax.sort((hi, lo, tag), num_keys=2, is_stable=True)
-        differs = jnp.concatenate([
-            jnp.ones((1,), bool),
-            (hi[1:] != hi[:-1]) | (lo[1:] != lo[:-1]),
-        ])
-        query = tag > 0
-        new = query & differs & ~((hi == _U32_MAX) & (lo == _U32_MAX))
-        back = jnp.where(
-            query, (tag - 1) << 1 | new.astype(jnp.uint32), _U32_MAX)
-        new = (lax.sort(back)[:n] & 1).astype(bool)
+        if wave is None:
+            new = _merged_new(vals, merged)
+        else:
+            buf, count, sizes = wave
+            fixed = sum(r.shape[0] for r in merged) + n
+
+            def prefix(p):
+                return lambda b, v, *m: (
+                    _merged_new(v, [*m, b[:p]]), jnp.int32(fixed + p))
+
+            case = sum((count > p).astype(jnp.int32) for p in sizes[:-1])
+            new, lanes = lax.switch(
+                case, [prefix(p) for p in sizes], buf, vals, *merged)
     with jax.named_scope("search"):
         for i, r in searched:
             hit = lax.cond(
@@ -111,7 +169,7 @@ def first_new(vals, occ, runs):
                 r,
             )
             new = new & ~hit
-    return new
+    return new if wave is None else (new, lanes)
 
 
 def rank_onehot(rank, mask, n_ranks: int):

@@ -70,7 +70,7 @@ TIMELINE_STAGES = (
     "dedup",       # seen-set probes + intra-wave first-occurrence
     "emit",        # cursor-append emit + coverage + invariants + stats
     "exchange",    # sharded only: the all-to-all pair on the ICI
-    "seen_merge",  # LSM ladder cascade + end-of-wave seen merge
+    "seen_merge",  # end-of-wave seen merge (sharded: + the LSM cascade)
     "checkpoint",  # wave-boundary checkpoint I/O
     "host",        # host bookkeeping not covered by a device stage
 )
@@ -115,6 +115,11 @@ TIMELINE_STAGES = (
 # so together they never exceed generated - canon_dup_lanes. 0 on the
 # host engines, which have no tiered canon. From the stats vector the
 # wave already fetched: zero extra device syncs.
+# dedup_sort_lanes (an extra key of the device engine's rows): the lanes
+# its dedup stage's merged sort sorted, summed over the wave's
+# chunk-steps: each step the seen run while it is merged, the prefix of
+# the wave's fingerprint buffer the step chose (checker/util.py
+# first_new) and the chunk's queries. Lane 8 of the same stats vector.
 # hbm_frac: analytic live-bytes / budget from obs/memwatch.py (null when
 # memwatch is off).
 WAVE_KEYS = (
@@ -285,6 +290,39 @@ def _negative_or_no_number(v) -> bool:
     return isinstance(v, bool) or not isinstance(v, (int, float)) or v < 0
 
 
+def _is_count(v) -> bool:
+    return not isinstance(v, bool) and isinstance(v, int) and v >= 0
+
+
+DEDUP_PLAN_KEYS = ("merge", "search", "wave_prefix", "sort_lanes")
+
+
+def _dedup_plan_problems(plan) -> list[str]:
+    """What is wrong with a manifest's or summary's ``dedup_plan``
+    (checker/util.py dedup_plan): the sizes of the runs the dedup stage
+    merges and searches, the prefix sizes of the wave's append buffer
+    it can sort (none on the sharded engine; else from 0 up) and the
+    most lanes a chunk-step sorts, which are the merged runs and the
+    largest prefix at least."""
+    if not isinstance(plan, dict) or any(
+            k not in plan for k in DEDUP_PLAN_KEYS):
+        return [f"{plan!r} must carry {DEDUP_PLAN_KEYS}"]
+    lists = [plan[k] for k in DEDUP_PLAN_KEYS[:3]]
+    if not all(isinstance(v, list) and all(map(_is_count, v))
+               for v in lists) or not _is_count(plan["sort_lanes"]):
+        return [f"{plan!r}: sizes are non-negative ints (lanes)"]
+    prefix = plan["wave_prefix"]
+    found = []
+    if prefix and (prefix[0] != 0 or any(
+            a >= b for a, b in zip(prefix, prefix[1:]))):
+        found.append(f"wave_prefix {prefix!r} must rise strictly from 0")
+    if plan["sort_lanes"] < sum(plan["merge"]) + max(prefix, default=0):
+        found.append(
+            f"sort_lanes {plan['sort_lanes']} is under the merged runs "
+            f"and the largest prefix together")
+    return found
+
+
 def _program_problems(programs) -> list[tuple]:
     """(index, problem) for each record of a summary's ``programs`` that
     is not one: a dict with PROGRAM_KEYS, a known kind, non-negative
@@ -338,7 +376,16 @@ def validate_event(ev: object, lineno: int | None = None) -> list[str]:
         problems.append(
             f"{where}job tag {ev['job']!r} must be a non-empty string"
         )
+    if etype in ("manifest", "summary") and "dedup_plan" in ev:
+        problems += [f"{where}{etype} dedup_plan: {p}"
+                     for p in _dedup_plan_problems(ev["dedup_plan"])]
     if etype == "wave":
+        lanes = ev.get("dedup_sort_lanes")
+        if lanes is not None and not _is_count(lanes):
+            problems.append(
+                f"{where}wave dedup_sort_lanes {lanes!r} must be a "
+                f"non-negative int (lanes the dedup stage sorted)"
+            )
         dens = ev.get("enabled_density")
         if dens is not None and (
             isinstance(dens, bool) or not isinstance(dens, (int, float))

@@ -1,9 +1,9 @@
 """Analytic HBM watermark accounting (the out-of-core planning input).
 
 The engines already know every buffer's geometry — frontier capacity
-and fill, the VC-wide chunk block, the seen-set ladder / LSM runs, the
-journal cursor. ``MemWatch`` turns that geometry
-into live-bytes per wave WITHOUT reading the device (no syncs, no
+and fill, the VC-wide chunk block, the seen run and the wave's
+fingerprint buffer / LSM runs, the journal cursor. ``MemWatch`` turns
+that geometry into live-bytes per wave WITHOUT reading the device (no syncs, no
 allocator introspection — this is the planning model, not a profiler):
 each wave the engine hands it a ``{buffer family: live bytes}``
 breakdown, it tracks the running peak, and it emits a ``memwatch``

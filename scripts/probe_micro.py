@@ -22,16 +22,28 @@ device JAX has (a chip, or ``--platform cpu`` to rehearse):
                  (S+VC)-update scatter and a u64 argsort)
 
 (the last two at ``--few`` sizes only: every sort is a minute of compile)
-and, at the benchmark cells' shape (a 2^18-lane seen run and the three
-ladder levels 2^16..2^18), all four runs merged against all four
-searched. Every variant's answer is checked against numpy before it is
-timed (that first call's seconds are the compile's). Milliseconds are the
-host's clock round ``block_until_ready``, median and fastest of ``--reps``
-calls.
+and, at the shape the raft3 cells had until PR 36 (a 2^18-lane seen run
+and the three levels, 2^16..2^18 lanes, of the wave's ladder of sorted
+runs), all four runs merged against all four searched. Every variant's
+answer is checked against numpy before it is timed (that first call's
+seconds are the compile's). Milliseconds are the host's clock round
+``block_until_ready``, median and fastest of ``--reps`` calls.
+
+``--cells`` times instead what the dedup stage's lookup costs a
+chunk-step in the benchmark's five cells (``benchmark/workloads``; four
+shapes, and a fifth for the 2^20-lane seen run of ``kraft3-wide``'s last
+wave): ``util.first_new`` with the seen run, the wave's append buffer and
+VC queries, one program a shape, called with a count in each of its
+prefix sizes, so every branch of its switch is timed in place, with the
+lanes it sorted and the nanoseconds a lane; and beside it ``ladder``, the
+lookup as it was, the seen run and every level of the ladder sorted
+whatever they held.
 
     python scripts/probe_micro.py [--vc 65536] [--lo 16] [--hi 25]
         [--few 18 22 24] [--reps 10] [--out chiprun_out/probe_micro.json]
         [--platform cpu]
+    python scripts/probe_micro.py --cells [--reps 10]
+        [--out chiprun_out/probe_cells.json] [--platform cpu]
 """
 
 import argparse
@@ -64,6 +76,111 @@ def _time(fn, args, reps, want):
             "first_call_s": first_s}
 
 
+def cell_shapes():
+    """[(cells, seen lanes, VC, FCAP)]: the shapes the dedup stage's
+    lookup has in the benchmark's cells, by DeviceBFS's own rules (16
+    valid successors a state; the seen run's first size, and its second
+    where the cell's job outgrows the first)."""
+    import inspect
+
+    from raft_tpu.checker.device_bfs import DeviceBFS
+
+    default = inspect.signature(DeviceBFS).parameters
+    shapes: dict = {}
+    bench = os.path.join(ROOT, "benchmark", "workloads")
+    for name in sorted(os.listdir(bench)):
+        with open(os.path.join(bench, name)) as f:
+            cell = json.load(f)
+        params = cell["engine_params"]
+        vc = params["chunk"] * default["valid_per_state"].default
+        fcap = params.get("frontier_cap", default["frontier_cap"].default)
+        shapes.setdefault((1 << 18, vc, fcap), []).append(cell["name"])
+        if cell["name"] == "kraft3-wide":  # wave 20: 322,004 seen
+            shapes.setdefault((1 << 20, vc, fcap), []).append(
+                cell["name"] + " wave 20")
+    return [(cells, *shape) for shape, cells in sorted(shapes.items())]
+
+
+def time_cells(args):
+    """The ``--cells`` mode: see the module docstring."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    import raft_tpu  # noqa: F401  (x64 on, as the engines run)
+    from raft_tpu.checker import util
+    from raft_tpu.checker.lsm import pow2_at_least
+    from raft_tpu.ops.hashing import U64_MAX
+
+    pad = np.uint64(U64_MAX)
+    rng = np.random.default_rng(36)
+    dev = jax.devices()[0]
+    rows = []
+    for cells, seen_lanes, vc, fcap in cell_shapes():
+        sizes = util.wave_prefix_sizes(pow2_at_least(vc), fcap)
+        seen_h = np.full((seen_lanes,), pad)
+        seen_h[: seen_lanes // 2] = np.sort(rng.integers(
+            0, 1 << 63, size=seen_lanes // 2, dtype=np.uint64))
+        buf_h = rng.integers(0, 1 << 63, size=fcap + vc, dtype=np.uint64)
+        v_h = rng.integers(0, 1 << 63, size=vc, dtype=np.uint64)
+        v_h[0::16] = seen_h[rng.integers(0, seen_lanes // 2, size=vc // 16)]
+        v_h[5::16] = v_h[4::16]  # duplicates inside the chunk
+        v_h[7::16] = pad
+        first = np.zeros(v_h.shape, bool)
+        first[np.unique(v_h, return_index=True)[1]] = True
+        fresh = first & (v_h != pad) & ~np.isin(v_h, seen_h)
+        occ = jnp.ones((1,), bool)
+        seen, v = jnp.asarray(seen_h), jnp.asarray(v_h)
+
+        def buffer_of(count):
+            """``count`` lanes written, the first of them among the
+            queries; (buffer, what is new then)."""
+            b = buf_h.copy()
+            b[count:] = pad
+            b[: min(count, vc // 16)] = v_h[1::16][: min(count, vc // 16)]
+            return jnp.asarray(b), fresh & ~np.isin(v_h, b[:count])
+
+        prefix_fn = jax.jit(lambda v, s, b, c: util.first_new(
+            v, occ, (s,), wave=(b, c, sizes))[0])
+        row = {"cells": cells, "seen_lanes": seen_lanes, "queries": vc,
+               "wave_lanes": fcap, "prefix": []}
+        lo = 0
+        for p in sizes:
+            count = (lo + p + 1) // 2  # inside (previous size, p]
+            lo = p
+            b, want = buffer_of(count)
+            t = _time(prefix_fn, (v, seen, b, np.int32(count)),
+                      args.reps, want)
+            lanes = seen_lanes + p + vc
+            row["prefix"].append({
+                "prefix_lanes": p, "count": count, "sort_lanes": lanes,
+                "ns_per_lane": 1e6 * t["median_ms"] / lanes, **t})
+        # the lookup as it was: every level of the ladder, sorted
+        levels, n = [], pow2_at_least(vc)
+        while n < pow2_at_least(fcap):
+            levels.append(n)
+            n <<= 1
+        levels.append(n)
+        b, want = buffer_of(levels[0] // 2)
+        runs = [jnp.asarray(np.sort(np.asarray(b)[:levels[0]])),
+                *(jnp.full((n,), pad, jnp.uint64) for n in levels[1:])]
+        lanes = seen_lanes + sum(levels) + vc
+        ladder_fn = jax.jit(lambda v, *r: util.first_new(
+            v, jnp.ones((len(r),), bool), r))
+        t = _time(ladder_fn, (v, seen, *runs), args.reps, want)
+        row["ladder"] = {"levels": levels, "sort_lanes": lanes,
+                         "ns_per_lane": 1e6 * t["median_ms"] / lanes, **t}
+        rows.append(row)
+        print(json.dumps(row), flush=True)
+    out = {"platform": dev.platform,
+           "device": str(getattr(dev, "device_kind", dev.platform)),
+           "jax": jax.__version__, "reps": args.reps, "cells": rows}
+    os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)
+    with open(args.out, "w") as f:
+        json.dump(out, f, indent=1)
+    print(f"wrote {args.out}")
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--vc", type=int, default=65536)
@@ -71,12 +188,18 @@ def main(argv=None):
     ap.add_argument("--hi", type=int, default=25)
     ap.add_argument("--few", type=int, nargs="*", default=[18, 22, 24])
     ap.add_argument("--reps", type=int, default=10)
-    ap.add_argument("--out", default=os.path.join(
-        ROOT, "chiprun_out", "probe_micro.json"))
+    ap.add_argument("--cells", action="store_true",
+                    help="time first_new at the benchmark cells' shapes")
+    ap.add_argument("--out", default=None)
     ap.add_argument("--platform", default=None)
     args = ap.parse_args(argv)
     if args.platform:
         os.environ["JAX_PLATFORMS"] = args.platform
+    args.out = args.out or os.path.join(
+        ROOT, "chiprun_out",
+        "probe_cells.json" if args.cells else "probe_micro.json")
+    if args.cells:
+        return time_cells(args)
 
     import jax
     import jax.numpy as jnp

@@ -19,7 +19,8 @@ action and state field that differ:
   invariants     each invariant kernel of the cfg, on the same rows
   wave           the fused wave program on the last level as its frontier,
                  the earlier levels in the seen run: stats, violations,
-                 emitted rows, journal, coverage, the ladder's first run
+                 emitted rows, journal, coverage, the wave's buffer of
+                 new fingerprints
 
 One command does both sides: before this process imports JAX it starts
 itself as a child held to the CPU backend (``JAX_PLATFORMS=cpu``) that
@@ -196,7 +197,7 @@ def stages(args, ref):
         jnp.zeros((eng.n_actions, 3), jnp.int64),
         np.int32(len(frontier)), np.int32(0), eng._occ_one,
         jnp.asarray(seen))
-    nxt, jparent, jcand, viol, stats, cov, *ladder = jax.device_get(res)
+    nxt, jparent, jcand, viol, stats, cov, wave_new = jax.device_get(res)
     print(f"wave on {len(frontier)} rows: stats {stats.tolist()} "
           f"violations {viol.tolist()}", flush=True)
     keep = 4 * args.chunk
@@ -204,7 +205,7 @@ def stages(args, ref):
                wave_rows=np.asarray(nxt[:keep]), wave_cov=np.asarray(cov),
                wave_jcand=np.asarray(jcand[:keep]),
                wave_jparent=np.asarray(jparent[:keep]),
-               wave_ladder0=np.asarray(ladder[0]))
+               wave_new=np.asarray(wave_new[:keep]))
     return out, model
 
 

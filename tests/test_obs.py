@@ -88,6 +88,14 @@ def test_device_metrics_stream_valid_and_count_accurate(tmp_path):
     # wave index strictly increasing from 1
     assert [w["wave"] for w in waves] == list(range(1, len(waves) + 1))
 
+    # the dedup stage's counter: on every row, the run's total on the
+    # summary, and both ends say what the stage can choose from
+    assert all(w["dedup_sort_lanes"] > 0 for w in waves)
+    assert summ["dedup_sort_lanes"] == sum(
+        w["dedup_sort_lanes"] for w in waves) == res.stats["dedup_sort_lanes"]
+    assert man["dedup_plan"]["wave_prefix"] == [0, 4096]
+    assert summ["dedup_plan"] == res.stats["dedup_plan"]
+
     # manifest provenance: ident carries the fingerprint revision
     assert man["engine"] == "device"
     assert man["hashv"] == hashv_of(man["ident"]) > 0
@@ -443,6 +451,50 @@ def test_wave_tier_counters_schema_rule():
     assert "non-negative" in problem
 
 
+@pytest.mark.parametrize("key,value,says", [
+    ("dedup_sort_lanes", -1, "non-negative int"),
+    ("dedup_sort_lanes", 1.5, "non-negative int"),
+    ("dedup_sort_lanes", True, "non-negative int"),
+])
+def test_wave_dedup_sort_lanes_schema_rule(key, value, says):
+    from raft_tpu.obs.events import validate_event
+
+    ev = dict.fromkeys(WAVE_KEYS, 0)
+    ev.update(event="wave", dedup_sort_lanes=327680)
+    assert validate_event(ev) == []
+    (problem,) = validate_event({**ev, key: value})
+    assert says in problem
+
+
+@pytest.mark.parametrize("etype,keys", [
+    ("manifest", MANIFEST_KEYS), ("summary", SUMMARY_KEYS)])
+def test_dedup_plan_schema_rule(etype, keys):
+    """`dedup_plan` on a manifest and a summary: the sizes merged and
+    searched, the wave buffer's prefix sizes from 0 up (none on the
+    sharded engine) and the most lanes a chunk-step sorts."""
+    from raft_tpu.checker.util import dedup_plan
+    from raft_tpu.obs.events import validate_event
+
+    ev = _fields(keys, ident="x/hashv=5", exit_cause="exhausted")
+    ev["event"] = etype
+    plan = dedup_plan([1 << 18], 1 << 16, (0, 1 << 16, 1 << 18))
+    assert plan["sort_lanes"] == (1 << 18) + (1 << 18) + (1 << 16)
+    assert validate_event({**ev, "dedup_plan": plan}) == []
+    sharded = dedup_plan([1 << 16, 1 << 17, 1 << 23], 1 << 16)
+    assert sharded["wave_prefix"] == []
+    assert validate_event({**ev, "dedup_plan": sharded}) == []
+    for bad, says in (
+        ({**plan, "wave_prefix": [65536, 262144]}, "strictly from 0"),
+        ({**plan, "wave_prefix": [0, 262144, 65536]}, "strictly from 0"),
+        ({**plan, "sort_lanes": 1 << 18}, "under the merged runs"),
+        ({**plan, "merge": [-1]}, "non-negative ints"),
+        ({k: v for k, v in plan.items() if k != "wave_prefix"},
+         "must carry"),
+    ):
+        (problem,) = validate_event({**ev, "dedup_plan": bad})
+        assert "dedup_plan" in problem and says in problem
+
+
 # ---------------------------------------- observatory schema fixtures
 
 
@@ -741,7 +793,8 @@ def _lowered_text(engine: str, program: str) -> str:
     ("device", "wave", "canon"),
     ("device", "wave", "dedup"),
     ("device", "wave", "emit"),
-    ("device", "wave", "seen_merge"),
+    # the prefix switch keeps the merged sort's scope (PR 36)
+    ("device", "wave", "dedup/merge"),
     ("device", "seen_merge", "seen_merge"),
     ("sharded", "chunk", "expand"),
     ("sharded", "chunk", "canon"),
@@ -763,6 +816,16 @@ def test_stage_scope_in_lowered_program(engine, program, stage):
     path = '/(?:[^"]*/)?'.join(stage.split("/"))
     assert re.search(rf'["/]{path}/', _lowered_text(engine, program)), (
         f"no op of {engine}:{program} carries the {stage!r} scope")
+
+
+def test_the_wave_program_merges_nothing_into_the_seen_set():
+    """The wave's new fingerprints are appended to one buffer, not
+    merged up a ladder of sorted runs (PR 36): no op of the wave program
+    is under `seen_merge`, which is the end-of-wave program alone, and
+    every 2-key sort inside the loop is the dedup stage's or canon's."""
+    text = _lowered_text("device", "wave")
+    assert not re.search(r'["/]seen_merge/', text)
+    assert re.search(r'["/]emit/(?:[^"]*/)?dynamic_update_slice', text)
 
 
 @pytest.mark.parametrize("engine,program", [
