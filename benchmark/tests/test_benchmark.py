@@ -20,30 +20,15 @@ import sys
 import pytest
 
 HERE = os.path.dirname(os.path.abspath(__file__))
-BENCH = os.path.dirname(HERE)
-ROOT = os.path.dirname(BENCH)
-sys.path.insert(0, ROOT)
+sys.path.insert(0, HERE)
 
+import files  # noqa: E402
+from files import BENCH, ROOT, load, names  # noqa: E402
 from benchmark import readers, xplane  # noqa: E402
 from benchmark.modes import bfs  # noqa: E402
 
 RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device", "compared"}
 DEVICE_KEYS = {"platform", "kind", "count", "memory_peak_bytes"}
-
-
-def load(*parts):
-    with open(os.path.join(*parts)) as f:
-        return json.load(f)
-
-
-def names(d):
-    return sorted(f[:-5] for f in os.listdir(os.path.join(BENCH, d))
-                  if f.endswith(".json"))
-
-
-def layer_metric_files():
-    return {name: load(BENCH, "layer_metrics", f"{name}.json")
-            for name in names("layer_metrics")}
 
 
 def run_cell(*argv, devices=1, allow_cpu=True):
@@ -82,57 +67,21 @@ def spare_bench(tmp_path):
 
 
 # ---------------- the files ----------------
+# The checks are functions of a root, in files.py; test_additions.py makes
+# them again on a copy of the repository that has gained a cell.
 
 @pytest.mark.parametrize("cell", names("workloads"))
 def test_cell_resolves_and_agrees_with_benchmark_json(cell):
-    spec = load(BENCH, "workloads", f"{cell}.json")
-    config = load(BENCH, "configs", spec["config"], "config.json")
-    assert os.path.exists(os.path.join(BENCH, "configs", spec["config"], config["cfg"]))
-    traffic = load(BENCH, "traffic", f"{spec['traffic']}.json")
-    golden = load(BENCH, "goldens", f"{spec['config']}.json")
-    for depth in (traffic["max_depth"], traffic["warmup_depth"]):
-        assert str(depth) in golden["totals"] and len(golden["depth_counts"]) > depth
-        assert depth <= golden["independent_to_depth"]
-    assert os.path.exists(os.path.join(BENCH, "modes", f"{traffic['mode']}.py"))
-    assert golden["msg_slots"] == spec["engine_params"]["msg_slots"]
-    # what the cell reports: what its own file lists, and what lists it
-    files = layer_metric_files()
-    assert set(spec["per_layer"]) <= set(files)
-    layer = {name: metric for name, metric in files.items()
-             if name in spec["per_layer"] or cell in metric.get("workloads", ())}
-    for name, metric in layer.items():
-        assert metric["moves"] in spec["end_to_end"], (name, "moves a metric the cell does not report")
-        assert os.path.exists(os.path.join(BENCH, "readers", f"{metric['reduce']['kind']}.py"))
+    files.check_cell(ROOT, cell)
 
-    bench = load(ROOT, "BENCHMARK.json")
-    (entry,) = [w for w in bench["workloads"] if w["name"] == cell]
-    assert {k: spec[k] for k in ("config", "traffic", "chips", "why")} == {
-        k: entry[k] for k in ("config", "traffic", "chips", "why")}
 
-    def cells_of(metric):
-        return metric.get("workloads", [w["name"] for w in bench["workloads"]])
-
-    for kind, reported in (("end_to_end", spec["end_to_end"]), ("per_layer", layer)):
-        listed = {m["name"]: m for m in bench[kind]}
-        assert set(reported) == {n for n, m in listed.items() if cell in cells_of(m)}
-    for name, metric in layer.items():
-        assert {k: metric[k] for k in ("layer", "unit", "moves", "source", "better")} == {
-            k: listed[name][k] for k in ("layer", "unit", "moves", "source", "better")}
+@pytest.mark.parametrize("metric", names("layer_metrics"))
+def test_metric_file_agrees_with_benchmark_json_and_names_its_cells(metric):
+    files.check_metric(ROOT, metric)
 
 
 def test_benchmark_json_and_the_files_name_each_other():
-    """Nothing prepared and unlisted: every cell, traffic mix,
-    configuration and per-layer metric file is one BENCHMARK.json names."""
-    bench = load(ROOT, "BENCHMARK.json")
-    assert sorted(w["name"] for w in bench["workloads"]) == names("workloads")
-    assert sorted({w["traffic"] for w in bench["workloads"]}) == names("traffic")
-    assert sorted(m["name"] for m in bench["per_layer"]) == names("layer_metrics")
-    assert sorted(c["name"] for c in bench["configs"]) == sorted(os.listdir(os.path.join(BENCH, "configs")))
-    assert sorted(c["name"] for c in bench["configs"]) == names("goldens")
-    for c in bench["configs"]:
-        config = load(ROOT, c["file"])
-        assert (config["source"], config["reduced"]) == (c["source"], c["reduced"])
-        assert config["guarantees"]
+    files.check_listing(ROOT)
 
 
 # ---------------- the command ----------------

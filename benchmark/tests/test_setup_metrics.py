@@ -1,7 +1,8 @@
 """The six per-layer metrics that split `setup_s` (PR 35): what the
 program's own records say of the seconds before the window. Each is one
-file of benchmark/layer_metrics/ read by `stat`, listing the five cells.
-On the CPU, with --allow-cpu; nothing here is a timing.
+file of benchmark/layer_metrics/ read by `stat`, and every cell reports
+each, by whichever of the two ways. On the CPU, with --allow-cpu; nothing
+here is a timing.
 
     python -m pytest benchmark/tests -q
 """
@@ -13,35 +14,16 @@ import os
 import sys
 
 HERE = os.path.dirname(os.path.abspath(__file__))
-BENCH = os.path.dirname(HERE)
-ROOT = os.path.dirname(BENCH)
-sys.path.insert(0, ROOT)
 sys.path.insert(0, HERE)
 
-from test_benchmark import layer_metric_files, load, run_cell, spare_bench  # noqa: E402, F401
+import files  # noqa: E402
+from files import BENCH, ROOT, SETUP, layer_metric_files, load  # noqa: E402
+from test_benchmark import run_cell, spare_bench  # noqa: E402, F401
 from benchmark import readers  # noqa: E402
-
-SETUP = {"setup_pre_s": "Process + backend", "load_trace_s": "Compile + cache",
-         "load_lower_s": "Compile + cache", "load_cache_read_s": "Compile + cache",
-         "load_compile_s": "Compile + cache", "load_union_s": "Compile + cache"}
 
 
 def test_the_files_benchmark_json_and_the_cells_name_each_other():
-    bench = load(ROOT, "BENCHMARK.json")
-    files = layer_metric_files()
-    listed = {m["name"]: m for m in bench["per_layer"]}
-    cells = [w["name"] for w in bench["workloads"]]
-    # additions, at the end of the list, in the files' order
-    assert [m["name"] for m in bench["per_layer"]][-len(SETUP):] == list(SETUP)
-    for name, layer in SETUP.items():
-        spec = files[name]
-        assert spec["reduce"] == {"kind": "stat", "name": name} and spec["name"] == name
-        want = {"layer": layer, "unit": "s", "better": "lower", "moves": "setup_s",
-                "source": "program_counter", "workloads": cells}
-        assert {k: spec[k] for k in want} == want == {k: listed[name][k] for k in want}
-        assert set(listed[name]) == {"name", *want}  # and no other key
-        for cell in cells:  # every cell reports what they move
-            assert "setup_s" in load(BENCH, "workloads", f"{cell}.json")["end_to_end"]
+    files.check_setup_metrics(ROOT)
 
 
 def test_a_program_without_the_records_reports_none_of_them():

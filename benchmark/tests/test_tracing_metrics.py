@@ -4,8 +4,9 @@ counters. On the CPU, with --allow-cpu; nothing here is a timing.
 
     python -m pytest benchmark/tests -q
 
-The nine metrics they serve are files of benchmark/layer_metrics/, each
-listing the cells that report it (BENCHMARK.json since PR 34).
+The nine metrics they serve (files.TRACING) are files of
+benchmark/layer_metrics/, each listing the cells that report it
+(BENCHMARK.json since PR 34).
 """
 
 from __future__ import annotations
@@ -18,19 +19,15 @@ import sys
 import pytest
 
 HERE = os.path.dirname(os.path.abspath(__file__))
-BENCH = os.path.dirname(HERE)
-ROOT = os.path.dirname(BENCH)
-sys.path.insert(0, ROOT)
 sys.path.insert(0, HERE)
 
-from test_benchmark import layer_metric_files, load, run_cell, spare_bench  # noqa: E402, F401
+import files  # noqa: E402
+from files import BENCH, ROOT, STAGE_METRICS, TRACING, layer_metric_files, load  # noqa: E402
+from test_benchmark import run_cell, spare_bench  # noqa: E402, F401
 from benchmark import adapter, readers, xplane, xspace  # noqa: E402
 from benchmark.readers import scope_time  # noqa: E402
 
 METRICS = layer_metric_files()
-STAGE_METRICS = [n for n, m in METRICS.items() if m["reduce"]["kind"] == "scope_time"]
-# the metrics that read the program's own scopes, spans and counters
-TRACING = [*STAGE_METRICS, "wave_idle_ms", "host_share", "programs_loaded"]
 
 
 def unpacked(tmp_path, name):
@@ -52,23 +49,7 @@ def ctx_of(path, pinned):
 # ---------------- the files ----------------
 
 def test_the_metrics_name_readers_cells_and_layers_that_exist():
-    bench = load(ROOT, "BENCHMARK.json")
-    listed = {m["name"]: m for m in bench["per_layer"]}
-    cells = {w["name"] for w in bench["workloads"]}
-    assert len(TRACING) == 9
-    for name in TRACING:
-        spec = METRICS[name]
-        assert spec["name"] == name
-        assert os.path.exists(os.path.join(
-            BENCH, "readers", f"{spec['reduce']['kind']}.py"))
-        # the file's cells are BENCHMARK.json's, and each reports what
-        # the metric moves
-        assert spec["workloads"] == listed[name]["workloads"]
-        assert set(spec["workloads"]) <= cells
-        for cell in spec["workloads"]:
-            assert spec["moves"] in load(BENCH, "workloads", f"{cell}.json")["end_to_end"], (cell, name)
-    assert {METRICS[n]["layer"] for n in TRACING} == {
-        "Stages in a chunk", "Host wave loop", "Compile + cache"}  # PERF.md section 3's rows
+    files.check_tracing_metrics(ROOT)
 
 
 def test_the_readers_stage_list_is_the_programs():
@@ -76,8 +57,7 @@ def test_the_readers_stage_list_is_the_programs():
 
     assert scope_time.STAGES is xplane.STAGES
     assert set(xplane.STAGES) == set(TIMELINE_STAGES) - {"checkpoint", "host"}
-    want = {f"{s}_s_per_mstate" for s in xplane.STAGES if s != "exchange"}
-    assert want | {"unscoped_s_per_mstate"} == set(STAGE_METRICS)
+    files.check_stage_metrics(ROOT)
 
 
 # ---------------- the wire format ----------------
