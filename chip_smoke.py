@@ -32,6 +32,14 @@ count against the pure-Python oracle's golden
          against tests/golden/kraft_cfg_depth_counts.json: a third model
          file through the same wave program, its four invariants
          evaluated on every state.
+  leg F  configs/pull-raft/KRaftWithReconfig.cfg under --lenient (KRaft
+         with membership change: 3 hosts, up to 5 servers, 12
+         permutations, 479-lane rows, 145 actions a state;
+         models/kraft_reconfig.py and its own SlotCanonicalizer) to
+         depth 5 against tests/golden/kraftrc_cfg_depth_counts.json: a
+         fourth model file through the same wave program, the one whose
+         canonical fingerprints are not ops/symmetry.py's, its five
+         invariants evaluated on every state.
 
 This process never imports jax or raft_tpu: a chip belongs to one process
 at a time, so every leg is a child of its own, one after the other, and
@@ -59,10 +67,14 @@ JOINT_GOLDEN = os.path.join(
     ROOT, "tests", "golden", "joint_cfg_depth_counts.json")
 KRAFT_GOLDEN = os.path.join(
     ROOT, "tests", "golden", "kraft_cfg_depth_counts.json")
+KRAFTRC_GOLDEN = os.path.join(
+    ROOT, "tests", "golden", "kraftrc_cfg_depth_counts.json")
 RAFT_CFG = os.path.join(ROOT, "configs", "standard-raft", "Raft.cfg")
 JOINT_CFG = os.path.join(
     ROOT, "configs", "standard-raft", "RaftWithReconfigJointConsensus.cfg")
 KRAFT_CFG = os.path.join(ROOT, "configs", "pull-raft", "KRaft.cfg")
+KRAFTRC_CFG = os.path.join(
+    ROOT, "configs", "pull-raft", "KRaftWithReconfig.cfg")
 UNSAFE_CFG = os.path.join(
     ROOT, "configs", "flexible-raft", "unsafe-quorums", "FlexibleRaft.cfg")
 SCHEMA_CHECK = os.path.join(ROOT, "scripts", "check_metrics_schema.py")
@@ -250,13 +262,14 @@ def leg_c(dev: dict, golden: dict) -> None:
           f"{res['distinct']} distinct (run wall {res['wall_s']} s)")
 
 
-def cfg_leg(letter: str, cfg: str, chunk: int, dev: dict, golden: dict) -> None:
-    """Legs D and E: another model file's cfg through the CLI to its
-    golden's depth, at its cell's chunk."""
+def cfg_leg(letter: str, cfg: str, chunk: int, dev: dict, golden: dict,
+            flags: tuple = ()) -> None:
+    """Legs D, E and F: another model file's cfg through the CLI to its
+    golden's depth, at its cell's chunk, with the flags the cfg needs."""
     depth = golden["max_depth"]
     res = bfs_leg(f"leg{letter}", dev, golden,
-                  ["--checker", "tpu", "--frontier-cap", "65536"], depth, 1,
-                  cfg=cfg, chunk=chunk)
+                  ["--checker", "tpu", "--frontier-cap", "65536", *flags],
+                  depth, 1, cfg=cfg, chunk=chunk)
     print(f"leg {letter} ok: {os.path.basename(cfg)} to depth {depth}, "
           f"{res['distinct']} distinct / {res['total']} generated "
           f"(run wall {res['wall_s']} s)")
@@ -264,8 +277,9 @@ def cfg_leg(letter: str, cfg: str, chunk: int, dev: dict, golden: dict) -> None:
 
 def main() -> int:
     try:
-        for path in (GOLDEN, JOINT_GOLDEN, KRAFT_GOLDEN, TRACE_GOLDEN,
-                     RAFT_CFG, JOINT_CFG, KRAFT_CFG, UNSAFE_CFG, SCHEMA_CHECK,
+        for path in (GOLDEN, JOINT_GOLDEN, KRAFT_GOLDEN, KRAFTRC_GOLDEN,
+                     TRACE_GOLDEN, RAFT_CFG, JOINT_CFG, KRAFT_CFG, KRAFTRC_CFG,
+                     UNSAFE_CFG, SCHEMA_CHECK,
                      os.path.join(ROOT, "raft_tpu", "__main__.py")):
             check(os.path.exists(path),
                   f"{os.path.relpath(path, ROOT)} is missing: chip_smoke.py "
@@ -277,10 +291,14 @@ def main() -> int:
         leg_a(dev, golden)
         leg_b(dev)
         leg_c(dev, golden)
-        for letter, cfg, chunk, path in (("D", JOINT_CFG, 1024, JOINT_GOLDEN),
-                                         ("E", KRAFT_CFG, 2048, KRAFT_GOLDEN)):
+        for letter, cfg, chunk, path, flags in (
+                ("D", JOINT_CFG, 1024, JOINT_GOLDEN, ()),
+                ("E", KRAFT_CFG, 2048, KRAFT_GOLDEN, ()),
+                # upstream's cfg declares v1 and uses v2
+                ("F", KRAFTRC_CFG, 1024, KRAFTRC_GOLDEN, ("--lenient",))):
             with open(path) as f:
-                cfg_leg(letter, cfg, chunk, dev, json.load(f)["depth_limited"])
+                cfg_leg(letter, cfg, chunk, dev,
+                        json.load(f)["depth_limited"], flags)
     except SmokeFailure as e:
         print(f"chip_smoke: FAILED: {e}", file=sys.stderr)
         return 1

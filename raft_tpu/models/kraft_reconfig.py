@@ -51,9 +51,17 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops import bag
-from ..ops.hashing import hash_lanes
+from ..ops.hashing import U64_MAX, hash_lanes
 from ..ops.packing import EMPTY, WidePacker, bits_for
-from .base import ActionLabelMixin, Layout, SparseExpandMixin
+from .base import (
+    ActionLabelMixin,
+    Layout,
+    SparseExpandMixin,
+    onehot_add,
+    onehot_row,
+    onehot_set,
+    onehot_set2,
+)
 
 # server states (KRaftWithReconfig.tla:354-360). UNATTACHED = 0 doubles as
 # the all-zero unused-slot filler; every kernel gates on `used`.
@@ -72,6 +80,10 @@ E_NONE, E_FENCED, E_NOTLEADER, E_UNKNOWN_LEADER, E_UNKNOWN_MEMBER, E_ALREADY_MEM
 R_RESULT_NONE, R_OK, R_NOTOK, R_DIVERGING = range(4)
 # log entry commands (:363-366); 0 = empty lane
 C_NONE, C_INIT, C_APPEND, C_ADD, C_REMOVE = range(5)
+
+# the six lanes of a log entry (command, epoch, value parts)
+LOG_FIELDS = ("log_cmd", "log_epoch", "log_val", "log_cfgid", "log_who",
+              "log_members")
 
 # Next-disjunct order (:1730-1756) for trace labels
 (
@@ -388,18 +400,20 @@ class KRaftReconfigModel(SparseExpandMixin, ActionLabelMixin):
     @staticmethod
     def _last_epoch(d, i):
         """LastEpoch(log[i]) — :498."""
-        ll = d["log_len"][i]
-        return jnp.where(ll > 0, d["log_epoch"][i][jnp.clip(ll - 1, 0)], 0)
+        ll = onehot_row(d["log_len"], i)
+        row = onehot_row(d["log_epoch"], i)
+        return jnp.where(ll > 0, onehot_row(row, jnp.clip(ll - 1, 0)), 0)
 
     # -------- transition machine (:599-715) --------
     # Triples are (state, epoch, leader_enc) int32 with leader_enc 0..NS.
 
     def _has_consistent_leader(self, d, i, leader_enc, epoch):
         """HasConsistentLeader — :599-616 (resigned/observer carve-outs)."""
-        cur, st_i, led = d["currentEpoch"][i], d["state"][i], d["leader"][i]
+        cur = onehot_row(d["currentEpoch"], i)
+        st_i, led = onehot_row(d["state"], i), onehot_row(d["leader"], i)
         self_case = jnp.where(
             (cur == epoch)
-            & ((d["role"][i] == R_OBSERVER) | (st_i == RESIGNED)),
+            & ((onehot_row(d["role"], i) == R_OBSERVER) | (st_i == RESIGNED)),
             True,
             st_i == LEADER,
         )
@@ -411,8 +425,9 @@ class KRaftReconfigModel(SparseExpandMixin, ActionLabelMixin):
 
     def _to_follower(self, d, i, leader_enc, epoch):
         """TransitionToFollower — :645-653 (illegal arm folded in)."""
-        ill = (d["currentEpoch"][i] == epoch) & (
-            (d["state"][i] == FOLLOWER) | (d["state"][i] == LEADER)
+        st_i = onehot_row(d["state"], i)
+        ill = (onehot_row(d["currentEpoch"], i) == epoch) & (
+            (st_i == FOLLOWER) | (st_i == LEADER)
         )
         return (
             jnp.where(ill, ILLEGAL, FOLLOWER),
@@ -422,7 +437,8 @@ class KRaftReconfigModel(SparseExpandMixin, ActionLabelMixin):
 
     def _maybe_transition(self, d, i, leader_enc, epoch):
         """MaybeTransition — :656-675 (case 3 adds leaderId # i)."""
-        cur, st_i, led = d["currentEpoch"][i], d["state"][i], d["leader"][i]
+        cur = onehot_row(d["currentEpoch"], i)
+        st_i, led = onehot_row(d["state"], i), onehot_row(d["leader"], i)
         hcl = self._has_consistent_leader(d, i, leader_enc, epoch)
         tf = self._to_follower(d, i, leader_enc, epoch)
         una = (jnp.int32(UNATTACHED), epoch, jnp.int32(NIL))
@@ -445,7 +461,8 @@ class KRaftReconfigModel(SparseExpandMixin, ActionLabelMixin):
     def _mhcr(self, d, i, leader_enc, epoch, err):
         """MaybeHandleCommonResponse — :683-715.
         Returns (state, epoch, leader_enc, handled)."""
-        cur, st_i, led = d["currentEpoch"][i], d["state"][i], d["leader"][i]
+        cur = onehot_row(d["currentEpoch"], i)
+        st_i, led = onehot_row(d["state"], i), onehot_row(d["leader"], i)
         mt = self._maybe_transition(d, i, leader_enc, epoch)
         c_stale = epoch < cur
         c_trans = (epoch > cur) | (err == E_FENCED) | (err == E_NOTLEADER)
@@ -467,8 +484,8 @@ class KRaftReconfigModel(SparseExpandMixin, ActionLabelMixin):
         return out[0], out[1], out[2], handled
 
     def _handle_message_part2(
-        self, s, d, m, u, recv, mtype, mepoch, src, dst, cnt_disc, handled,
-        mh_st, mh_ep, mh_ld, branches,
+        self, s, d, m, u, recv, mtype, mepoch, src, dst, log_dst, cnt_disc,
+        handled, mh_st, mh_ep, mh_ld, branches,
     ):
         """FetchResponse + Join receipt branches and the final select."""
         p, NS, L = self.p, self.NS, self.p.max_log
@@ -476,12 +493,12 @@ class KRaftReconfigModel(SparseExpandMixin, ActionLabelMixin):
         # correlation match: pendingFetch[dst] = m.correlation (:1390); the
         # request's msource is dst (implied) and mdest is the responder src
         corr = (
-            (d["pf_active"][dst] > 0)
-            & (d["pf_epoch"][dst] == u("cepoch"))
-            & (d["pf_offset"][dst] == u("cfetchOffset"))
-            & (d["pf_lastepoch"][dst] == u("clastFetchedEpoch"))
-            & (d["pf_observer"][dst] == u("cobserver"))
-            & (d["pf_dest"][dst] == src + 1)
+            (onehot_row(d["pf_active"], dst) > 0)
+            & (onehot_row(d["pf_epoch"], dst) == u("cepoch"))
+            & (onehot_row(d["pf_offset"], dst) == u("cfetchOffset"))
+            & (onehot_row(d["pf_lastepoch"], dst) == u("clastFetchedEpoch"))
+            & (onehot_row(d["pf_observer"], dst) == u("cobserver"))
+            & (onehot_row(d["pf_dest"], dst) == src + 1)
         )
         mres = u("mresult")
         mhwm = u("mhwm")
@@ -495,67 +512,60 @@ class KRaftReconfigModel(SparseExpandMixin, ActionLabelMixin):
             padded to all servers. Applies to row `dst`; the new-state
             (from _mhcr) supplies leader and the default state."""
             member = ((cfg_members_v >> dst) & 1) > 0
-            was_voter = d["role"][dst] == R_VOTER
-            was_obs = d["role"][dst] == R_OBSERVER
+            role_dst = onehot_row(d["role"], dst)
+            was_voter = role_dst == R_VOTER
+            was_obs = role_dst == R_OBSERVER
             demote = was_voter & ~member
             promote = was_obs & member
             new_role = jnp.where(
-                demote, R_OBSERVER, jnp.where(promote, R_VOTER, d["role"][dst])
+                demote, R_OBSERVER, jnp.where(promote, R_VOTER, role_dst)
             )
             new_state = jnp.where(demote | promote, FOLLOWER, mh_st)
-            upd["leader"] = d["leader"].at[dst].set(mh_ld)
-            upd["cfg_id"] = d["cfg_id"].at[dst].set(cfg_id_v)
-            upd["cfg_members"] = d["cfg_members"].at[dst].set(cfg_members_v)
-            upd["cfg_committed"] = d["cfg_committed"].at[dst].set(cfg_committed_v)
-            upd["role"] = d["role"].at[dst].set(new_role)
-            upd["state"] = d["state"].at[dst].set(new_state)
-            upd["eo_dom"] = d["eo_dom"].at[dst].set(d["eo_dom"][dst] | used_mask)
-            upd["log_cmd"] = d["log_cmd"].at[dst].set(log_cmd_v)
-            upd["log_epoch"] = d["log_epoch"].at[dst].set(log_epoch_v)
-            upd["log_val"] = d["log_val"].at[dst].set(log_val_v)
-            upd["log_cfgid"] = d["log_cfgid"].at[dst].set(log_cfgid_v)
-            upd["log_who"] = d["log_who"].at[dst].set(log_who_v)
-            upd["log_members"] = d["log_members"].at[dst].set(log_members_v)
-            upd["log_len"] = d["log_len"].at[dst].set(log_len_v)
+            upd["leader"] = onehot_set(d["leader"], dst, mh_ld)
+            upd["cfg_id"] = onehot_set(d["cfg_id"], dst, cfg_id_v)
+            upd["cfg_members"] = onehot_set(d["cfg_members"], dst, cfg_members_v)
+            upd["cfg_committed"] = onehot_set(d["cfg_committed"], dst, cfg_committed_v)
+            upd["role"] = onehot_set(d["role"], dst, new_role)
+            upd["state"] = onehot_set(d["state"], dst, new_state)
+            upd["eo_dom"] = onehot_set(
+                d["eo_dom"], dst, onehot_row(d["eo_dom"], dst) | used_mask)
+            upd["log_cmd"] = onehot_set(d["log_cmd"], dst, log_cmd_v)
+            upd["log_epoch"] = onehot_set(d["log_epoch"], dst, log_epoch_v)
+            upd["log_val"] = onehot_set(d["log_val"], dst, log_val_v)
+            upd["log_cfgid"] = onehot_set(d["log_cfgid"], dst, log_cfgid_v)
+            upd["log_who"] = onehot_set(d["log_who"], dst, log_who_v)
+            upd["log_members"] = onehot_set(d["log_members"], dst, log_members_v)
+            upd["log_len"] = onehot_set(d["log_len"], dst, log_len_v)
             return upd
 
         # --- HandleSuccessFetchResponse (:1383-1409)
         b_ok = is_fresp & ~handled & corr & (mres == R_OK)
         app = u("nentries") > 0
-        ll_dst = d["log_len"][dst]
+        ll_dst = onehot_row(d["log_len"], dst)
         apos = jnp.clip(ll_dst, 0, L - 1)
         ok_ovf = b_ok & app & (ll_dst >= L)
-        nl_cmd = jnp.where(
-            app, d["log_cmd"][dst].at[apos].set(u("e_cmd")), d["log_cmd"][dst]
-        )
-        nl_ep = jnp.where(
-            app, d["log_epoch"][dst].at[apos].set(u("e_epoch")), d["log_epoch"][dst]
-        )
-        nl_val = jnp.where(
-            app, d["log_val"][dst].at[apos].set(u("e_val")), d["log_val"][dst]
-        )
-        nl_cfgid = jnp.where(
-            app, d["log_cfgid"][dst].at[apos].set(u("e_cfgid")), d["log_cfgid"][dst]
-        )
-        nl_who = jnp.where(
-            app, d["log_who"][dst].at[apos].set(u("e_who")), d["log_who"][dst]
-        )
-        nl_members = jnp.where(
-            app,
-            d["log_members"][dst].at[apos].set(u("e_members")),
-            d["log_members"][dst],
-        )
+
+        def appended(f, e):  # log[dst]'s lanes of f, the entry's at apos
+            return jnp.where(
+                app, onehot_set(log_dst[f], apos, u(e)), log_dst[f])
+
+        nl_cmd = appended("log_cmd", "e_cmd")
+        nl_ep = appended("log_epoch", "e_epoch")
+        nl_val = appended("log_val", "e_val")
+        nl_cfgid = appended("log_cfgid", "e_cfgid")
+        nl_who = appended("log_who", "e_who")
+        nl_members = appended("log_members", "e_members")
         nl_len = ll_dst + app.astype(jnp.int32)
         ok_cfg_off = self._most_recent_reconfig(d, nl_cmd, nl_len)
         b_ok &= ok_cfg_off > 0  # log always has a config cmd when reachable
         ok_lane = jnp.clip(ok_cfg_off - 1, 0, L - 1)
         upd8 = maybe_switch(
             dict(msg_cnt=cnt_disc),
-            nl_cfgid[ok_lane], nl_members[ok_lane],
+            onehot_row(nl_cfgid, ok_lane), onehot_row(nl_members, ok_lane),
             (mhwm >= ok_cfg_off).astype(jnp.int32),
             nl_cmd, nl_ep, nl_val, nl_cfgid, nl_who, nl_members, nl_len,
         )
-        upd8["highWatermark"] = d["highWatermark"].at[dst].set(mhwm)
+        upd8["highWatermark"] = onehot_set(d["highWatermark"], dst, mhwm)
         upd8 = {**upd8, **self._pf_clear_upd(d, dst)}
         s_ok = self._asm(d, **upd8)
 
@@ -566,18 +576,14 @@ class KRaftReconfigModel(SparseExpandMixin, ActionLabelMixin):
             d, dst, u("mdivergingEndOffset"), u("mdivergingEpoch")
         )
         keep = jnp.arange(L, dtype=jnp.int32) < hco
-        tl_cmd = jnp.where(keep, d["log_cmd"][dst], 0)
-        tl_ep = jnp.where(keep, d["log_epoch"][dst], 0)
-        tl_val = jnp.where(keep, d["log_val"][dst], 0)
-        tl_cfgid = jnp.where(keep, d["log_cfgid"][dst], 0)
-        tl_who = jnp.where(keep, d["log_who"][dst], 0)
-        tl_members = jnp.where(keep, d["log_members"][dst], 0)
+        tl_cmd, tl_ep, tl_val, tl_cfgid, tl_who, tl_members = (
+            jnp.where(keep, log_dst[f], 0) for f in LOG_FIELDS)
         dv_cfg_off = self._most_recent_reconfig(d, tl_cmd, hco)
         b_divr &= dv_cfg_off > 0
         dv_lane = jnp.clip(dv_cfg_off - 1, 0, L - 1)
         upd9 = maybe_switch(
             dict(msg_cnt=cnt_disc),
-            tl_cfgid[dv_lane], tl_members[dv_lane],
+            onehot_row(tl_cfgid, dv_lane), onehot_row(tl_members, dv_lane),
             (mhwm >= dv_cfg_off).astype(jnp.int32),
             tl_cmd, tl_ep, tl_val, tl_cfgid, tl_who, tl_members, hco,
         )
@@ -587,12 +593,12 @@ class KRaftReconfigModel(SparseExpandMixin, ActionLabelMixin):
         # --- HandleNonSuccessFetchResponse (:1459-1483)
         b_err = is_fresp & handled & corr
         upd10 = dict(
-            state=d["state"].at[dst].set(mh_st),
-            currentEpoch=d["currentEpoch"].at[dst].set(mh_ep),
-            leader=d["leader"].at[dst].set(mh_ld),
+            state=onehot_set(d["state"], dst, mh_st),
+            currentEpoch=onehot_set(d["currentEpoch"], dst, mh_ep),
+            leader=onehot_set(d["leader"], dst, mh_ld),
             role=jnp.where(
                 u("merror") == E_UNKNOWN_MEMBER,
-                d["role"].at[dst].set(R_OBSERVER),
+                onehot_set(d["role"], dst, R_OBSERVER),
                 d["role"],
             ),
             msg_cnt=cnt_disc,
@@ -602,42 +608,46 @@ class KRaftReconfigModel(SparseExpandMixin, ActionLabelMixin):
 
         # --- Join flow (:1524-1674)
         is_joinreq = recv & (mtype == JOINREQ)
-        members = d["cfg_members"][dst]
+        members = onehot_row(d["cfg_members"], dst)
         msize = self._popcount(members)
         # JoinCheck (:1551-1556)
-        jc_notleader = d["state"][dst] != LEADER
+        jc_notleader = onehot_row(d["state"], dst) != LEADER
         jc_already = ((members >> src) & 1) > 0
-        jc_pending = d["cfg_committed"][dst] == 0
+        jc_pending = onehot_row(d["cfg_committed"], dst) == 0
         jc_notready = ~self._leader_committed_in_epoch(d, dst)
         jc_ok = ~jc_notleader & ~jc_already & ~jc_pending & ~jc_notready
 
         # AcceptJoinRequest (:1558-1590)
         b_jacc = is_joinreq & (msize < p.max_cluster_size) & jc_ok
-        pos = d["log_len"][dst]
+        pos = onehot_row(d["log_len"], dst)
         ja_ovf = b_jacc & (pos >= L)
         posc = jnp.clip(pos, 0, L - 1)
         new_len = pos + 1
         add_members = members | (jnp.int32(1) << src)
         jakey = self._pack(
-            mtype=JOINRESP, mepoch=d["currentEpoch"][dst],
-            mleader=d["leader"][dst], mresult=R_OK, merror=E_NONE,
+            mtype=JOINRESP, mepoch=onehot_row(d["currentEpoch"], dst),
+            mleader=onehot_row(d["leader"], dst), mresult=R_OK, merror=E_NONE,
             mdest=src, msource=dst,
         )
         wj, cj, _exj, ovfj = self._reply(d, m, jakey)
         updj = dict(
-            log_cmd=d["log_cmd"].at[dst, posc].set(C_ADD),
-            log_epoch=d["log_epoch"].at[dst, posc].set(d["currentEpoch"][dst]),
-            log_cfgid=d["log_cfgid"].at[dst, posc].set(d["cfg_id"][dst] + 1),
-            log_who=d["log_who"].at[dst, posc].set(src + 1),
-            log_members=d["log_members"].at[dst, posc].set(add_members),
-            log_len=d["log_len"].at[dst].set(new_len),
-            cfg_id=d["cfg_id"].at[dst].set(d["cfg_id"][dst] + 1),
-            cfg_members=d["cfg_members"].at[dst].set(add_members),
-            cfg_committed=d["cfg_committed"].at[dst].set(
-                (d["highWatermark"][dst] >= new_len).astype(jnp.int32)
+            log_cmd=onehot_set2(d["log_cmd"], dst, posc, C_ADD),
+            log_epoch=onehot_set2(
+                d["log_epoch"], dst, posc, onehot_row(d["currentEpoch"], dst)),
+            log_cfgid=onehot_set2(
+                d["log_cfgid"], dst, posc, onehot_row(d["cfg_id"], dst) + 1),
+            log_who=onehot_set2(d["log_who"], dst, posc, src + 1),
+            log_members=onehot_set2(d["log_members"], dst, posc, add_members),
+            log_len=onehot_set(d["log_len"], dst, new_len),
+            cfg_id=onehot_set(d["cfg_id"], dst, onehot_row(d["cfg_id"], dst) + 1),
+            cfg_members=onehot_set(d["cfg_members"], dst, add_members),
+            cfg_committed=onehot_set(
+                d["cfg_committed"], dst,
+                (onehot_row(d["highWatermark"], dst) >= new_len).astype(jnp.int32),
             ),
-            eo_dom=d["eo_dom"].at[dst].set(
-                d["eo_dom"][dst] | (jnp.int32(1) << src)
+            eo_dom=onehot_set(
+                d["eo_dom"], dst,
+                onehot_row(d["eo_dom"], dst) | (jnp.int32(1) << src),
             ),
             **self._wupd(wj, cj),
         )
@@ -648,8 +658,8 @@ class KRaftReconfigModel(SparseExpandMixin, ActionLabelMixin):
         b_jrej = is_joinreq & (jc_notleader | (~jc_notleader & jc_already))
         jr_err = jnp.where(jc_notleader, E_NOTLEADER, E_ALREADY_MEMBER)
         jrkey = self._pack(
-            mtype=JOINRESP, mepoch=d["currentEpoch"][dst],
-            mleader=d["leader"][dst], mresult=R_NOTOK, merror=jr_err,
+            mtype=JOINRESP, mepoch=onehot_row(d["currentEpoch"], dst),
+            mleader=onehot_row(d["leader"], dst), mresult=R_NOTOK, merror=jr_err,
             mdest=src, msource=dst,
         )
         wr, cr, _exr, ovfr = self._reply(d, m, jrkey)
@@ -658,7 +668,7 @@ class KRaftReconfigModel(SparseExpandMixin, ActionLabelMixin):
         # HandleRejectJoinResponse (:1643-1674): only the Discard arm is
         # reachable (the CASE tests mresult against ERROR values)
         b_jrr = (
-            recv & (mtype == JOINRESP) & (d["role"][dst] == R_OBSERVER)
+            recv & (mtype == JOINRESP) & (onehot_row(d["role"], dst) == R_OBSERVER)
             & (mres == R_NOTOK)
         )
         s_jrr = self._asm(d, msg_cnt=cnt_disc)
@@ -688,19 +698,19 @@ class KRaftReconfigModel(SparseExpandMixin, ActionLabelMixin):
         """EndOffsetForEpoch — :551-567."""
         L = self.p.max_log
         lanes = jnp.arange(L, dtype=jnp.int32)
-        row = d["log_epoch"][i]
-        mask = (lanes < d["log_len"][i]) & (row <= lfe)
+        row = onehot_row(d["log_epoch"], i)
+        mask = (lanes < onehot_row(d["log_len"], i)) & (row <= lfe)
         off = jnp.max(jnp.where(mask, lanes + 1, 0))
-        ep = jnp.where(off > 0, row[jnp.clip(off - 1, 0)], 0)
+        ep = jnp.where(off > 0, onehot_row(row, jnp.clip(off - 1, 0)), 0)
         return off, ep
 
     def _highest_common_offset(self, d, i, end_off, epoch):
         """HighestCommonOffset — :521-539."""
         L = self.p.max_log
         lanes = jnp.arange(L, dtype=jnp.int32)
-        row = d["log_epoch"][i]
+        row = onehot_row(d["log_epoch"], i)
         le = (row < epoch) | ((row == epoch) & (lanes + 1 <= end_off))
-        mask = (lanes < d["log_len"][i]) & le
+        mask = (lanes < onehot_row(d["log_len"], i)) & le
         return jnp.max(jnp.where(mask, lanes + 1, 0))
 
     def _valid_fetch_position(self, d, i, fetch_off, lfe):
@@ -728,9 +738,9 @@ class KRaftReconfigModel(SparseExpandMixin, ActionLabelMixin):
         L = self.p.max_log
         lanes = jnp.arange(L, dtype=jnp.int32)
         return jnp.any(
-            (lanes < d["log_len"][i])
-            & (d["log_epoch"][i] == d["currentEpoch"][i])
-            & (d["highWatermark"][i] >= lanes + 1)
+            (lanes < onehot_row(d["log_len"], i))
+            & (onehot_row(d["log_epoch"], i) == onehot_row(d["currentEpoch"], i))
+            & (onehot_row(d["highWatermark"], i) >= lanes + 1)
         )
 
     # -------- send helpers (MessagePassing.tla) --------
@@ -758,26 +768,27 @@ class KRaftReconfigModel(SparseExpandMixin, ActionLabelMixin):
         d = self._dec(s)
         valid = (
             (d["restartCtr"] < p.max_restarts)
-            & (d["used"][i] > 0)
-            & (d["state"][i] != DEAD)
+            & (onehot_row(d["used"], i) > 0)
+            & (onehot_row(d["state"], i) != DEAD)
         )
-        was_leader = d["state"][i] == LEADER
+        was_leader = onehot_row(d["state"], i) == LEADER
         new_state = jnp.where(
             was_leader,
-            jnp.where(d["role"][i] == R_VOTER, RESIGNED, UNATTACHED),
-            d["state"][i],
+            jnp.where(onehot_row(d["role"], i) == R_VOTER, RESIGNED, UNATTACHED),
+            onehot_row(d["state"], i),
         )
         used_mask = self._used_mask(d)
         succ = self._asm(
             d,
-            state=d["state"].at[i].set(new_state),
-            leader=d["leader"].at[i].set(
-                jnp.where(was_leader, NIL, d["leader"][i])
+            state=onehot_set(d["state"], i, new_state),
+            leader=onehot_set(
+                d["leader"], i,
+                jnp.where(was_leader, NIL, onehot_row(d["leader"], i)),
             ),
-            votesGranted=d["votesGranted"].at[i].set(0),
-            eo_dom=d["eo_dom"].at[i].set(used_mask),
-            endOffset=d["endOffset"].at[i].set(jnp.zeros((NS,), jnp.int32)),
-            highWatermark=d["highWatermark"].at[i].set(0),
+            votesGranted=onehot_set(d["votesGranted"], i, 0),
+            eo_dom=onehot_set(d["eo_dom"], i, used_mask),
+            endOffset=onehot_set(d["endOffset"], i, jnp.zeros((NS,), jnp.int32)),
+            highWatermark=onehot_set(d["highWatermark"], i, 0),
             **self._pf_clear_upd(d, i),
             restartCtr=d["restartCtr"] + 1,
         )
@@ -791,12 +802,12 @@ class KRaftReconfigModel(SparseExpandMixin, ActionLabelMixin):
 
     def _pf_clear_upd(self, d, i):
         return dict(
-            pf_active=d["pf_active"].at[i].set(0),
-            pf_epoch=d["pf_epoch"].at[i].set(0),
-            pf_offset=d["pf_offset"].at[i].set(0),
-            pf_lastepoch=d["pf_lastepoch"].at[i].set(0),
-            pf_dest=d["pf_dest"].at[i].set(0),
-            pf_observer=d["pf_observer"].at[i].set(0),
+            pf_active=onehot_set(d["pf_active"], i, 0),
+            pf_epoch=onehot_set(d["pf_epoch"], i, 0),
+            pf_offset=onehot_set(d["pf_offset"], i, 0),
+            pf_lastepoch=onehot_set(d["pf_lastepoch"], i, 0),
+            pf_dest=onehot_set(d["pf_dest"], i, 0),
+            pf_observer=onehot_set(d["pf_observer"], i, 0),
         )
 
     def _request_vote(self, s, i):
@@ -804,23 +815,23 @@ class KRaftReconfigModel(SparseExpandMixin, ActionLabelMixin):
         RequestVoteRequests to the config members via SendMultipleOnce."""
         p, NS = self.p, self.NS
         d = self._dec(s)
-        st_i = d["state"][i]
-        member = ((d["cfg_members"][i] >> i) & 1) > 0
+        st_i = onehot_row(d["state"], i)
+        member = ((onehot_row(d["cfg_members"], i) >> i) & 1) > 0
         valid = (
             (d["electionCtr"] < p.max_elections)
-            & (d["used"][i] > 0)
-            & (d["role"][i] == R_VOTER)
+            & (onehot_row(d["used"], i) > 0)
+            & (onehot_row(d["role"], i) == R_VOTER)
             & ((st_i == FOLLOWER) | (st_i == CANDIDATE) | (st_i == UNATTACHED))
             & member
         )
-        new_epoch = d["currentEpoch"][i] + 1
+        new_epoch = onehot_row(d["currentEpoch"], i) + 1
         last_ep = self._last_epoch(d, i)
-        ll_i = d["log_len"][i]
+        ll_i = onehot_row(d["log_len"], i)
         words, cnt = self._words(d), d["msg_cnt"]
         ovf = jnp.asarray(False)
         for delta in range(1, NS):
             j = jnp.mod(i + delta, NS)
-            is_member = ((d["cfg_members"][i] >> j) & 1) > 0
+            is_member = ((onehot_row(d["cfg_members"], i) >> j) & 1) > 0
             key = self._pack(
                 mtype=RVREQ, mepoch=new_epoch, mlastLogEpoch=last_ep,
                 mlastLogOffset=ll_i, msource=i, mdest=j,
@@ -830,11 +841,11 @@ class KRaftReconfigModel(SparseExpandMixin, ActionLabelMixin):
             ovf |= o
         succ = self._asm(
             d,
-            state=d["state"].at[i].set(CANDIDATE),
-            currentEpoch=d["currentEpoch"].at[i].set(new_epoch),
-            leader=d["leader"].at[i].set(NIL),
-            votedFor=d["votedFor"].at[i].set(i + 1),
-            votesGranted=d["votesGranted"].at[i].set(jnp.int32(1) << i),
+            state=onehot_set(d["state"], i, CANDIDATE),
+            currentEpoch=onehot_set(d["currentEpoch"], i, new_epoch),
+            leader=onehot_set(d["leader"], i, NIL),
+            votedFor=onehot_set(d["votedFor"], i, i + 1),
+            votesGranted=onehot_set(d["votesGranted"], i, jnp.int32(1) << i),
             **self._pf_clear_upd(d, i),
             electionCtr=d["electionCtr"] + 1,
             **self._wupd(words, cnt),
@@ -847,14 +858,14 @@ class KRaftReconfigModel(SparseExpandMixin, ActionLabelMixin):
         servers."""
         NS = self.NS
         d = self._dec(s)
-        members = d["cfg_members"][i]
-        vg = d["votesGranted"][i]
+        members = onehot_row(d["cfg_members"], i)
+        vg = onehot_row(d["votesGranted"], i)
         votes = self._popcount(vg)
         msize = self._popcount(members)
         vg_subset = (vg & ~members) == 0
         valid = (
-            (d["used"][i] > 0)
-            & (d["state"][i] == CANDIDATE)
+            (onehot_row(d["used"], i) > 0)
+            & (onehot_row(d["state"], i) == CANDIDATE)
             & vg_subset
             & (2 * votes > msize)
         )
@@ -864,7 +875,7 @@ class KRaftReconfigModel(SparseExpandMixin, ActionLabelMixin):
             j = jnp.mod(i + delta, NS)
             is_member = ((members >> j) & 1) > 0
             key = self._pack(
-                mtype=BQREQ, mepoch=d["currentEpoch"][i], msource=i, mdest=j
+                mtype=BQREQ, mepoch=onehot_row(d["currentEpoch"], i), msource=i, mdest=j
             )
             words, cnt, existed, o = self._cond_put(words, cnt, key, is_member)
             valid &= ~existed
@@ -872,10 +883,10 @@ class KRaftReconfigModel(SparseExpandMixin, ActionLabelMixin):
         used_mask = self._used_mask(d)
         succ = self._asm(
             d,
-            state=d["state"].at[i].set(LEADER),
-            leader=d["leader"].at[i].set(i + 1),
-            eo_dom=d["eo_dom"].at[i].set(used_mask),
-            endOffset=d["endOffset"].at[i].set(jnp.zeros((NS,), jnp.int32)),
+            state=onehot_set(d["state"], i, LEADER),
+            leader=onehot_set(d["leader"], i, i + 1),
+            eo_dom=onehot_set(d["eo_dom"], i, used_mask),
+            endOffset=onehot_set(d["endOffset"], i, jnp.zeros((NS,), jnp.int32)),
             **self._wupd(words, cnt),
         )
         return valid, succ, jnp.int32(KR_BECOMELEADER), ovf & valid
@@ -884,25 +895,25 @@ class KRaftReconfigModel(SparseExpandMixin, ActionLabelMixin):
         """ClientRequest — :1110-1126: bounded per-epoch by valueCtr."""
         p, L = self.p, self.p.max_log
         d = self._dec(s)
-        ep = d["currentEpoch"][i]
+        ep = onehot_row(d["currentEpoch"], i)
         epc = jnp.clip(ep - 1, 0, p.max_epoch - 1)
         valid = (
-            (d["used"][i] > 0)
-            & (d["state"][i] == LEADER)
-            & (d["acked"][v] == ACK_NIL)
-            & (d["valueCtr"][epc] < p.max_values_per_epoch)
+            (onehot_row(d["used"], i) > 0)
+            & (onehot_row(d["state"], i) == LEADER)
+            & (onehot_row(d["acked"], v) == ACK_NIL)
+            & (onehot_row(d["valueCtr"], epc) < p.max_values_per_epoch)
         )
-        pos = d["log_len"][i]
+        pos = onehot_row(d["log_len"], i)
         ovf = valid & (pos >= L)
         posc = jnp.clip(pos, 0, L - 1)
         succ = self._asm(
             d,
-            log_cmd=d["log_cmd"].at[i, posc].set(C_APPEND),
-            log_epoch=d["log_epoch"].at[i, posc].set(ep),
-            log_val=d["log_val"].at[i, posc].set(v + 1),
-            log_len=d["log_len"].at[i].add(1),
-            acked=d["acked"].at[v].set(ACK_FALSE),
-            valueCtr=d["valueCtr"].at[epc].add(1),
+            log_cmd=onehot_set2(d["log_cmd"], i, posc, C_APPEND),
+            log_epoch=onehot_set2(d["log_epoch"], i, posc, ep),
+            log_val=onehot_set2(d["log_val"], i, posc, v + 1),
+            log_len=onehot_add(d["log_len"], i, 1),
+            acked=onehot_set(d["acked"], v, ACK_FALSE),
+            valueCtr=onehot_add(d["valueCtr"], epc, 1),
         )
         return valid, succ, jnp.int32(KR_CLIENTREQUEST), ovf
 
@@ -910,22 +921,23 @@ class KRaftReconfigModel(SparseExpandMixin, ActionLabelMixin):
         """SendFetchRequest — :1137-1169: known-leader follower fetch, or
         an Unattached observer probing a voter of its config."""
         d = self._dec(s)
-        path_a = (d["leader"][i] == j + 1) & (d["state"][i] == FOLLOWER)
+        path_a = (onehot_row(d["leader"], i) == j + 1) & (
+            onehot_row(d["state"], i) == FOLLOWER)
         path_b = (
-            (d["role"][i] == R_OBSERVER)
-            & (d["state"][i] == UNATTACHED)
-            & (((d["cfg_members"][i] >> j) & 1) > 0)
+            (onehot_row(d["role"], i) == R_OBSERVER)
+            & (onehot_row(d["state"], i) == UNATTACHED)
+            & (((onehot_row(d["cfg_members"], i) >> j) & 1) > 0)
         )
         valid = (
-            (d["used"][i] > 0) & (d["used"][j] > 0)
-            & (d["pf_active"][i] == 0)
+            (onehot_row(d["used"], i) > 0) & (onehot_row(d["used"], j) > 0)
+            & (onehot_row(d["pf_active"], i) == 0)
             & (path_a | path_b)
         )
-        ll_i = d["log_len"][i]
+        ll_i = onehot_row(d["log_len"], i)
         last_ep = self._last_epoch(d, i)
-        is_obs = (d["role"][i] == R_OBSERVER).astype(jnp.int32)
+        is_obs = (onehot_row(d["role"], i) == R_OBSERVER).astype(jnp.int32)
         key = self._pack(
-            mtype=FETCHREQ, mepoch=d["currentEpoch"][i], mfetchOffset=ll_i,
+            mtype=FETCHREQ, mepoch=onehot_row(d["currentEpoch"], i), mfetchOffset=ll_i,
             mlastFetchedEpoch=last_ep, mobserver=is_obs, msource=i, mdest=j,
         )
         words, cnt, _existed, ovf = bag.wide_bag_put(
@@ -933,12 +945,12 @@ class KRaftReconfigModel(SparseExpandMixin, ActionLabelMixin):
         )
         succ = self._asm(
             d,
-            pf_active=d["pf_active"].at[i].set(1),
-            pf_epoch=d["pf_epoch"].at[i].set(d["currentEpoch"][i]),
-            pf_offset=d["pf_offset"].at[i].set(ll_i),
-            pf_lastepoch=d["pf_lastepoch"].at[i].set(last_ep),
-            pf_dest=d["pf_dest"].at[i].set(j + 1),
-            pf_observer=d["pf_observer"].at[i].set(is_obs),
+            pf_active=onehot_set(d["pf_active"], i, 1),
+            pf_epoch=onehot_set(d["pf_epoch"], i, onehot_row(d["currentEpoch"], i)),
+            pf_offset=onehot_set(d["pf_offset"], i, ll_i),
+            pf_lastepoch=onehot_set(d["pf_lastepoch"], i, last_ep),
+            pf_dest=onehot_set(d["pf_dest"], i, j + 1),
+            pf_observer=onehot_set(d["pf_observer"], i, is_obs),
             **self._wupd(words, cnt),
         )
         return valid, succ, jnp.int32(KR_SENDFETCH), ovf & valid
@@ -950,7 +962,11 @@ class KRaftReconfigModel(SparseExpandMixin, ActionLabelMixin):
         NS = self.NS
         d = self._dec(s)
         n_used = jnp.sum((d["used"] > 0).astype(jnp.int32))
-        valid = (n_used < NS) & (d["used"][j] > 0) & (d["state"][j] == LEADER)
+        valid = (
+            (n_used < NS)
+            & (onehot_row(d["used"], j) > 0)
+            & (onehot_row(d["state"], j) == LEADER)
+        )
         slot = jnp.clip(n_used, 0, NS - 1)
         disk_id = d["diskIdGen"] + 1
         old_mask = self._used_mask(d)
@@ -963,28 +979,28 @@ class KRaftReconfigModel(SparseExpandMixin, ActionLabelMixin):
         )
         succ = self._asm(
             d,
-            used=d["used"].at[slot].set(1),
-            host=d["host"].at[slot].set(h),
-            diskId=d["diskId"].at[slot].set(disk_id),
-            role=d["role"].at[slot].set(R_OBSERVER),
-            state=d["state"].at[slot].set(UNATTACHED),
-            currentEpoch=d["currentEpoch"].at[slot].set(0),
-            leader=d["leader"].at[slot].set(NIL),
-            votedFor=d["votedFor"].at[slot].set(NIL),
-            votesGranted=d["votesGranted"].at[slot].set(0),
-            cfg_id=d["cfg_id"].at[slot].set(0),
-            cfg_members=d["cfg_members"].at[slot].set(0),
-            cfg_committed=d["cfg_committed"].at[slot].set(0),
-            eo_dom=d["eo_dom"].at[slot].set(old_mask),
-            endOffset=d["endOffset"].at[slot].set(jnp.zeros((NS,), jnp.int32)),
-            log_len=d["log_len"].at[slot].set(0),
-            highWatermark=d["highWatermark"].at[slot].set(0),
-            pf_active=d["pf_active"].at[slot].set(1),
-            pf_epoch=d["pf_epoch"].at[slot].set(0),
-            pf_offset=d["pf_offset"].at[slot].set(0),
-            pf_lastepoch=d["pf_lastepoch"].at[slot].set(0),
-            pf_dest=d["pf_dest"].at[slot].set(j + 1),
-            pf_observer=d["pf_observer"].at[slot].set(1),
+            used=onehot_set(d["used"], slot, 1),
+            host=onehot_set(d["host"], slot, h),
+            diskId=onehot_set(d["diskId"], slot, disk_id),
+            role=onehot_set(d["role"], slot, R_OBSERVER),
+            state=onehot_set(d["state"], slot, UNATTACHED),
+            currentEpoch=onehot_set(d["currentEpoch"], slot, 0),
+            leader=onehot_set(d["leader"], slot, NIL),
+            votedFor=onehot_set(d["votedFor"], slot, NIL),
+            votesGranted=onehot_set(d["votesGranted"], slot, 0),
+            cfg_id=onehot_set(d["cfg_id"], slot, 0),
+            cfg_members=onehot_set(d["cfg_members"], slot, 0),
+            cfg_committed=onehot_set(d["cfg_committed"], slot, 0),
+            eo_dom=onehot_set(d["eo_dom"], slot, old_mask),
+            endOffset=onehot_set(d["endOffset"], slot, jnp.zeros((NS,), jnp.int32)),
+            log_len=onehot_set(d["log_len"], slot, 0),
+            highWatermark=onehot_set(d["highWatermark"], slot, 0),
+            pf_active=onehot_set(d["pf_active"], slot, 1),
+            pf_epoch=onehot_set(d["pf_epoch"], slot, 0),
+            pf_offset=onehot_set(d["pf_offset"], slot, 0),
+            pf_lastepoch=onehot_set(d["pf_lastepoch"], slot, 0),
+            pf_dest=onehot_set(d["pf_dest"], slot, j + 1),
+            pf_observer=onehot_set(d["pf_observer"], slot, 1),
             diskIdGen=disk_id,
             **self._wupd(words, cnt),
         )
@@ -997,13 +1013,13 @@ class KRaftReconfigModel(SparseExpandMixin, ActionLabelMixin):
         d = self._dec(s)
         valid = (
             jnp.asarray(self.p.max_add_reconfigs > 0)
-            & (d["used"][i] > 0) & (d["used"][j] > 0)
-            & (d["role"][i] == R_OBSERVER)
-            & (((d["cfg_members"][i] >> i) & 1) == 0)
-            & (d["leader"][i] == j + 1)
+            & (onehot_row(d["used"], i) > 0) & (onehot_row(d["used"], j) > 0)
+            & (onehot_row(d["role"], i) == R_OBSERVER)
+            & (((onehot_row(d["cfg_members"], i) >> i) & 1) == 0)
+            & (onehot_row(d["leader"], i) == j + 1)
         )
         key = self._pack(
-            mtype=JOINREQ, mepoch=d["currentEpoch"][i], mdest=j, msource=i
+            mtype=JOINREQ, mepoch=onehot_row(d["currentEpoch"], i), mdest=j, msource=i
         )
         words, cnt, existed, ovf = bag.wide_bag_put(
             self._words(d), d["msg_cnt"], key
@@ -1018,41 +1034,45 @@ class KRaftReconfigModel(SparseExpandMixin, ActionLabelMixin):
         but stays leader."""
         p, L = self.p, self.p.max_log
         d = self._dec(s)
-        members = d["cfg_members"][i]
+        members = onehot_row(d["cfg_members"], i)
         msize = self._popcount(members)
         # RemoveCheck (:1692-1697) = Ok
         check_ok = (
-            (d["state"][i] == LEADER)
+            (onehot_row(d["state"], i) == LEADER)
             & (((members >> r) & 1) > 0)
-            & (d["cfg_committed"][i] > 0)  # no pending config
+            & (onehot_row(d["cfg_committed"], i) > 0)  # no pending config
             & self._leader_committed_in_epoch(d, i)
         )
         valid = (
-            (d["used"][i] > 0) & (d["used"][r] > 0)
+            (onehot_row(d["used"], i) > 0) & (onehot_row(d["used"], r) > 0)
             & (d["removeCtr"] < p.max_remove_reconfigs)
             & check_ok
             & (msize > p.min_cluster_size)
         )
         new_members = members & ~(jnp.int32(1) << r)
-        pos = d["log_len"][i]
+        pos = onehot_row(d["log_len"], i)
         ovf = valid & (pos >= L)
         posc = jnp.clip(pos, 0, L - 1)
         new_len = pos + 1
         succ = self._asm(
             d,
-            log_cmd=d["log_cmd"].at[i, posc].set(C_REMOVE),
-            log_epoch=d["log_epoch"].at[i, posc].set(d["currentEpoch"][i]),
-            log_cfgid=d["log_cfgid"].at[i, posc].set(d["cfg_id"][i] + 1),
-            log_who=d["log_who"].at[i, posc].set(r + 1),
-            log_members=d["log_members"].at[i, posc].set(new_members),
-            log_len=d["log_len"].at[i].set(new_len),
-            cfg_id=d["cfg_id"].at[i].set(d["cfg_id"][i] + 1),
-            cfg_members=d["cfg_members"].at[i].set(new_members),
-            cfg_committed=d["cfg_committed"].at[i].set(
-                (d["highWatermark"][i] >= new_len).astype(jnp.int32)
+            log_cmd=onehot_set2(d["log_cmd"], i, posc, C_REMOVE),
+            log_epoch=onehot_set2(
+                d["log_epoch"], i, posc, onehot_row(d["currentEpoch"], i)),
+            log_cfgid=onehot_set2(
+                d["log_cfgid"], i, posc, onehot_row(d["cfg_id"], i) + 1),
+            log_who=onehot_set2(d["log_who"], i, posc, r + 1),
+            log_members=onehot_set2(d["log_members"], i, posc, new_members),
+            log_len=onehot_set(d["log_len"], i, new_len),
+            cfg_id=onehot_set(d["cfg_id"], i, onehot_row(d["cfg_id"], i) + 1),
+            cfg_members=onehot_set(d["cfg_members"], i, new_members),
+            cfg_committed=onehot_set(
+                d["cfg_committed"], i,
+                (onehot_row(d["highWatermark"], i) >= new_len).astype(jnp.int32),
             ),
-            role=d["role"].at[i].set(
-                jnp.where(i == r, R_OBSERVER, d["role"][i])
+            role=onehot_set(
+                d["role"], i,
+                jnp.where(i == r, R_OBSERVER, onehot_row(d["role"], i)),
             ),
             removeCtr=d["removeCtr"] + 1,
         )
@@ -1068,17 +1088,21 @@ class KRaftReconfigModel(SparseExpandMixin, ActionLabelMixin):
         p, NS, L = self.p, self.NS, self.p.max_log
         d = self._dec(s)
         words, cnt = self._words(d), d["msg_cnt"]
-        key = tuple(w[m] for w in words)
+        key = tuple(onehot_row(w, m) for w in words)
         occupied = key[0] != EMPTY
         u = partial(self.packer.unpack, key)
         mtype, mepoch = u("mtype"), u("mepoch")
         src, dst = u("msource"), u("mdest")
-        cur = d["currentEpoch"][dst]
-        st_dst = d["state"][dst]
-        led_dst = d["leader"][dst]
-        role_dst = d["role"][dst]
+        cur = onehot_row(d["currentEpoch"], dst)
+        st_dst = onehot_row(d["state"], dst)
+        led_dst = onehot_row(d["leader"], dst)
+        role_dst = onehot_row(d["role"], dst)
+        log_dst = {f: onehot_row(d[f], dst) for f in LOG_FIELDS}
         # ReceivableMessage (:471-477): count > 0 and dest not DeadNoState
-        recv = occupied & (cnt[m] > 0) & (d["used"][dst] > 0) & (st_dst != DEAD)
+        recv = (
+            occupied & (onehot_row(cnt, m) > 0)
+            & (onehot_row(d["used"], dst) > 0) & (st_dst != DEAD)
+        )
         equal_epoch = mepoch == cur
 
         def pf_clear(upd):
@@ -1093,13 +1117,13 @@ class KRaftReconfigModel(SparseExpandMixin, ActionLabelMixin):
         s0_ep = jnp.where(mepoch > cur, mepoch, cur)
         s0_ld = jnp.where(mepoch > cur, NIL, led_dst)
         last_ep = self._last_epoch(d, dst)
-        ll_dst = d["log_len"][dst]
+        ll_dst = onehot_row(d["log_len"], dst)
         log_ok = (u("mlastLogEpoch") > last_ep) | (
             (u("mlastLogEpoch") == last_ep) & (u("mlastLogOffset") >= ll_dst)
         )
         grant = (
             (s0_st == UNATTACHED)
-            | ((s0_st == VOTED) & (d["votedFor"][dst] == src + 1))
+            | ((s0_st == VOTED) & (onehot_row(d["votedFor"], dst) == src + 1))
         ) & log_ok
         # TransitionToVoted (:630-637) when granting from Unattached; the
         # Unattached precondition makes its illegal arm unreachable
@@ -1118,18 +1142,20 @@ class KRaftReconfigModel(SparseExpandMixin, ActionLabelMixin):
         w1, c1, _ex1, ovf1 = self._reply(d, m, rkey)
         no_err = ~rv_err
         upd1 = self._wupd(w1, c1)
-        upd1["state"] = jnp.where(no_err, d["state"].at[dst].set(f_st), d["state"])
+        upd1["state"] = jnp.where(no_err, onehot_set(d["state"], dst, f_st), d["state"])
         upd1["currentEpoch"] = jnp.where(
-            no_err, d["currentEpoch"].at[dst].set(f_ep), d["currentEpoch"]
+            no_err, onehot_set(d["currentEpoch"], dst, f_ep), d["currentEpoch"]
         )
-        upd1["leader"] = jnp.where(no_err, d["leader"].at[dst].set(f_ld), d["leader"])
+        upd1["leader"] = jnp.where(
+            no_err, onehot_set(d["leader"], dst, f_ld), d["leader"]
+        )
         upd1["votedFor"] = jnp.where(
-            no_err & grant, d["votedFor"].at[dst].set(src + 1), d["votedFor"]
+            no_err & grant, onehot_set(d["votedFor"], dst, src + 1), d["votedFor"]
         )
         pf_reset = no_err & (f_st != st_dst)
         for pf in ("pf_active", "pf_epoch", "pf_offset", "pf_lastepoch",
                    "pf_dest", "pf_observer"):
-            upd1[pf] = jnp.where(pf_reset, d[pf].at[dst].set(0), d[pf])
+            upd1[pf] = jnp.where(pf_reset, onehot_set(d[pf], dst, 0), d[pf])
         s_rvreq = self._asm(d, **upd1)
 
         # --- HandleRequestVoteResponse (:1025-1050; adds the Voter gate)
@@ -1142,15 +1168,16 @@ class KRaftReconfigModel(SparseExpandMixin, ActionLabelMixin):
         )
         granted_bit = (u("mvoteGranted") > 0) & ~handled
         upd2 = dict(
-            state=jnp.where(handled, d["state"].at[dst].set(mh_st), d["state"]),
+            state=jnp.where(handled, onehot_set(d["state"], dst, mh_st), d["state"]),
             currentEpoch=jnp.where(
-                handled, d["currentEpoch"].at[dst].set(mh_ep), d["currentEpoch"]
+                handled, onehot_set(d["currentEpoch"], dst, mh_ep), d["currentEpoch"]
             ),
-            leader=jnp.where(handled, d["leader"].at[dst].set(mh_ld), d["leader"]),
+            leader=jnp.where(handled, onehot_set(d["leader"], dst, mh_ld), d["leader"]),
             votesGranted=jnp.where(
                 granted_bit,
-                d["votesGranted"].at[dst].set(
-                    d["votesGranted"][dst] | (jnp.int32(1) << src)
+                onehot_set(
+                    d["votesGranted"], dst,
+                    onehot_row(d["votesGranted"], dst) | (jnp.int32(1) << src),
                 ),
                 d["votesGranted"],
             ),
@@ -1165,9 +1192,9 @@ class KRaftReconfigModel(SparseExpandMixin, ActionLabelMixin):
         )
         bt_st, bt_ep, bt_ld = self._maybe_transition(d, dst, src + 1, mepoch)
         upd3 = pf_clear(dict(
-            state=d["state"].at[dst].set(bt_st),
-            currentEpoch=d["currentEpoch"].at[dst].set(bt_ep),
-            leader=d["leader"].at[dst].set(bt_ld),
+            state=onehot_set(d["state"], dst, bt_st),
+            currentEpoch=onehot_set(d["currentEpoch"], dst, bt_ep),
+            leader=onehot_set(d["leader"], dst, bt_ld),
             msg_cnt=cnt_disc,
         ))
         s_bqreq = self._asm(d, **upd3)
@@ -1194,7 +1221,8 @@ class KRaftReconfigModel(SparseExpandMixin, ActionLabelMixin):
         b_reject = is_fetchreq & (ferr != E_NONE)
         rjkey = self._pack(
             mtype=FETCHRESP, mresult=R_NOTOK, merror=ferr, mleader=led_dst,
-            mepoch=cur, mhwm=d["highWatermark"][dst], msource=dst, mdest=src,
+            mepoch=cur, mhwm=onehot_row(d["highWatermark"], dst),
+            msource=dst, mdest=src,
             **corr_kw,
         )
         w4, c4, ex4, ovf4 = self._reply(d, m, rjkey)
@@ -1206,7 +1234,7 @@ class KRaftReconfigModel(SparseExpandMixin, ActionLabelMixin):
         dvkey = self._pack(
             mtype=FETCHRESP, mepoch=cur, mresult=R_DIVERGING, merror=E_NONE,
             mdivergingEpoch=eo_ep, mdivergingEndOffset=eo_off,
-            mleader=led_dst, mhwm=d["highWatermark"][dst],
+            mleader=led_dst, mhwm=onehot_row(d["highWatermark"], dst),
             msource=dst, mdest=src, **corr_kw,
         )
         w5, c5, ex5, ovf5 = self._reply(d, m, dvkey)
@@ -1218,9 +1246,8 @@ class KRaftReconfigModel(SparseExpandMixin, ActionLabelMixin):
         have_entry = offset <= ll_dst
         epos = jnp.clip(offset - 1, 0, L - 1)
         ent = {
-            f: jnp.where(have_entry, d[f][dst][epos], 0)
-            for f in ("log_cmd", "log_epoch", "log_val", "log_cfgid",
-                      "log_who", "log_members")
+            f: jnp.where(have_entry, onehot_row(log_dst[f], epos), 0)
+            for f in LOG_FIELDS
         }
         ent_kw = dict(
             nentries=have_entry.astype(jnp.int32), e_cmd=ent["log_cmd"],
@@ -1231,11 +1258,11 @@ class KRaftReconfigModel(SparseExpandMixin, ActionLabelMixin):
 
         # AcceptFetchRequestFromVoter (:1286-1342)
         b_acc_v = is_fetchreq & equal_epoch & is_leader & valid_pos & (fobs == 0)
-        new_end = d["endOffset"][dst].at[src].set(foff)
-        new_eo_dom = d["eo_dom"].at[dst].set(
-            d["eo_dom"][dst] | (jnp.int32(1) << src)
+        new_end = onehot_set(onehot_row(d["endOffset"], dst), src, foff)
+        new_eo_dom = onehot_set(
+            d["eo_dom"], dst, onehot_row(d["eo_dom"], dst) | (jnp.int32(1) << src)
         )
-        members = d["cfg_members"][dst]
+        members = onehot_row(d["cfg_members"], dst)
         msize = self._popcount(members)
         # NewHighwaterMark (:1266-1284): leader self-exclusion when removed
         idxs = jnp.arange(1, L + 1, dtype=jnp.int32)
@@ -1247,8 +1274,8 @@ class KRaftReconfigModel(SparseExpandMixin, ActionLabelMixin):
         quorum_ok = 2 * jnp.sum(agree, axis=1) > msize
         in_log = idxs <= ll_dst
         best = jnp.max(jnp.where(quorum_ok & in_log, idxs, 0))
-        ep_at = d["log_epoch"][dst][jnp.clip(best - 1, 0)]
-        hwm_old = d["highWatermark"][dst]
+        ep_at = onehot_row(log_dst["log_epoch"], jnp.clip(best - 1, 0))
+        hwm_old = onehot_row(d["highWatermark"], dst)
         new_hwm = jnp.where((best > 0) & (ep_at == cur), best, hwm_old)
         advanced = new_hwm > hwm_old
         # IsRemovedFromCluster (:1259-1264) over (hwm_old, new_hwm]
@@ -1256,18 +1283,18 @@ class KRaftReconfigModel(SparseExpandMixin, ActionLabelMixin):
         in_range = (lanes + 1 > hwm_old) & (lanes + 1 <= new_hwm)
         leaves = advanced & jnp.any(
             in_range
-            & (d["log_cmd"][dst] == C_REMOVE)
-            & (((d["log_members"][dst] >> dst) & 1) == 0)
+            & (log_dst["log_cmd"] == C_REMOVE)
+            & (((log_dst["log_members"] >> dst) & 1) == 0)
         )
         # config refresh from the most recent reconfig entry (ci = new_hwm)
-        cfg_off = self._most_recent_reconfig(d, d["log_cmd"][dst], ll_dst)
+        cfg_off = self._most_recent_reconfig(d, log_dst["log_cmd"], ll_dst)
         cfg_lane = jnp.clip(cfg_off - 1, 0, L - 1)
         # acked: in-flight values committed in (hwm_old, new_hwm] (:1331-1338)
         committed = jnp.any(
             in_range[None, :]
-            & (d["log_cmd"][dst][None, :] == C_APPEND)
+            & (log_dst["log_cmd"][None, :] == C_APPEND)
             & (
-                d["log_val"][dst][None, :]
+                log_dst["log_val"][None, :]
                 == jnp.arange(1, p.n_values + 1, dtype=jnp.int32)[:, None]
             ),
             axis=1,
@@ -1280,47 +1307,52 @@ class KRaftReconfigModel(SparseExpandMixin, ActionLabelMixin):
             acked=acked_v,
             cfg_id=jnp.where(
                 advanced,
-                d["cfg_id"].at[dst].set(d["log_cfgid"][dst][cfg_lane]),
+                onehot_set(
+                    d["cfg_id"], dst, onehot_row(log_dst["log_cfgid"], cfg_lane)
+                ),
                 d["cfg_id"],
             ),
             cfg_members=jnp.where(
                 advanced,
-                d["cfg_members"].at[dst].set(d["log_members"][dst][cfg_lane]),
+                onehot_set(
+                    d["cfg_members"], dst,
+                    onehot_row(log_dst["log_members"], cfg_lane),
+                ),
                 d["cfg_members"],
             ),
             cfg_committed=jnp.where(
                 advanced,
-                d["cfg_committed"].at[dst].set(
-                    (new_hwm >= cfg_off).astype(jnp.int32)
+                onehot_set(
+                    d["cfg_committed"], dst, (new_hwm >= cfg_off).astype(jnp.int32)
                 ),
                 d["cfg_committed"],
             ),
             role=jnp.where(
-                leaves, d["role"].at[dst].set(R_OBSERVER), d["role"]
+                leaves, onehot_set(d["role"], dst, R_OBSERVER), d["role"]
             ),
             state=jnp.where(
-                leaves, d["state"].at[dst].set(UNATTACHED), d["state"]
+                leaves, onehot_set(d["state"], dst, UNATTACHED), d["state"]
             ),
-            leader=jnp.where(leaves, d["leader"].at[dst].set(NIL), d["leader"]),
+            leader=jnp.where(leaves, onehot_set(d["leader"], dst, NIL), d["leader"]),
             votesGranted=jnp.where(
-                leaves, d["votesGranted"].at[dst].set(0), d["votesGranted"]
+                leaves, onehot_set(d["votesGranted"], dst, 0), d["votesGranted"]
             ),
             eo_dom=jnp.where(
                 leaves,
-                d["eo_dom"].at[dst].set(used_mask),
+                onehot_set(d["eo_dom"], dst, used_mask),
                 new_eo_dom,
             ),
             endOffset=jnp.where(
                 leaves,
-                d["endOffset"].at[dst].set(jnp.zeros((NS,), jnp.int32)),
-                d["endOffset"].at[dst].set(new_end),
+                onehot_set(d["endOffset"], dst, jnp.zeros((NS,), jnp.int32)),
+                onehot_set(d["endOffset"], dst, new_end),
             ),
             highWatermark=jnp.where(
                 leaves,
-                d["highWatermark"].at[dst].set(0),
+                onehot_set(d["highWatermark"], dst, 0),
                 jnp.where(
                     advanced,
-                    d["highWatermark"].at[dst].set(new_hwm),
+                    onehot_set(d["highWatermark"], dst, new_hwm),
                     d["highWatermark"],
                 ),
             ),
@@ -1348,8 +1380,8 @@ class KRaftReconfigModel(SparseExpandMixin, ActionLabelMixin):
 
         # Part 4 (fetch responses, join handling, branch select) below.
         return self._handle_message_part2(
-            s, d, m, u, recv, mtype, mepoch, src, dst, cnt_disc, handled,
-            mh_st, mh_ep, mh_ld,
+            s, d, m, u, recv, mtype, mepoch, src, dst, log_dst, cnt_disc,
+            handled, mh_st, mh_ep, mh_ld,
             [
                 (b_rvreq, s_rvreq, KR_HANDLE_RVREQ, ovf1),
                 (b_rvresp, s_rvresp, KR_HANDLE_RVRESP, jnp.asarray(False)),
@@ -1567,8 +1599,7 @@ class KRaftReconfigModel(SparseExpandMixin, ActionLabelMixin):
         lanes = jnp.arange(1, L + 1, dtype=jnp.int32)
         in_common = lanes[None, None, None, :] <= mh[..., None]
         eq = jnp.ones_like(in_common)
-        for f in ("log_cmd", "log_epoch", "log_val", "log_cfgid",
-                  "log_who", "log_members"):
+        for f in LOG_FIELDS:
             v = lay.get(states, f)
             eq &= v[:, :, None, :] == v[:, None, :, :]
         both = used[:, :, None] & used[:, None, :]
@@ -1685,11 +1716,7 @@ class KRaftReconfigModel(SparseExpandMixin, ActionLabelMixin):
         }
         role_names = {R_VOTER: KO.VOTER, R_OBSERVER: KO.OBSERVER, R_DEAD: KO.DEAD}
 
-        lt = {
-            f: g(f).reshape(NS, p.max_log)
-            for f in ("log_cmd", "log_epoch", "log_val", "log_cfgid",
-                      "log_who", "log_members")
-        }
+        lt = {f: g(f).reshape(NS, p.max_log) for f in LOG_FIELDS}
         ll = g("log_len")
 
         def entry(i, k):
@@ -2014,6 +2041,15 @@ class KRaftReconfigModel(SparseExpandMixin, ActionLabelMixin):
         return vec
 
 
+def _lookup(table, idx):
+    """``table[idx]`` of a tiny 1-D table at an int array of indices, as
+    compares and selects (base.onehot_row for an array of indices): a
+    per-lane gather is serial on the TPU however small its table. An
+    index outside the table reads 0."""
+    k = jnp.arange(table.shape[0], dtype=jnp.int32)
+    return jnp.sum(jnp.where(idx[..., None] == k, table, 0), axis=-1)
+
+
 class SlotCanonicalizer:
     """Canonical fingerprints for the slot encoding under
     ``symmHostsAndValues`` (:462-463).
@@ -2021,18 +2057,26 @@ class SlotCanonicalizer:
     A host permutation sigma maps identity (h, d) -> (sigma(h), d); slots
     do NOT move (they are creation-order), but the oracle's view serializes
     servers in sorted-identity order, so canonicalization is data-dependent:
-    for each (sigma, tau) (1) remap host values, (2) argsort slots by the
-    permuted (host, diskId) key — used slots first, creation order as the
-    stable tie-break for unused — (3) remap every slot reference (leader/
-    votedFor/pf_dest/bitmasks/endOffset axes/message source/dest/leader/
-    e_who/e_members) through the sort, (4) remap values through tau
-    (log_val/e_val/acked lanes), (5) re-sort the message bag, (6) hash the
-    VIEW prefix. The fingerprint is the min over all permutations —
-    exactly the oracle's ``canon`` equivalence, hashed.
+    for each (sigma, tau) (1) remap host values, (2) rank slots by the
+    permuted (host, diskId) key — used slots first, creation order for the
+    unused — (3) remap every slot reference (leader/votedFor/pf_dest/
+    bitmasks/endOffset axes/message source/dest/leader/e_who/e_members)
+    through the ranking, (4) remap values through tau (log_val/e_val/acked
+    lanes), (5) re-sort the message bag, (6) hash the VIEW prefix. The
+    fingerprint is the min over all permutations — exactly the oracle's
+    ``canon`` equivalence, hashed.
 
-    With symmetry off only the identity permutation runs; the slot sort is
-    then a no-op by construction (device slot order IS sorted-identity
-    order for unpermuted states), kept for uniformity.
+    Every index set is tiny (NS slots, H hosts, V values), so each read
+    and write through one is compares and selects; the only sort is the
+    bag's. The four steps run under the scopes ``slot_sort``,
+    ``slot_remap``, ``slot_bag`` and ``slot_hash`` (``canon/slot_*`` in an
+    engine's trace), each opened OUTSIDE the vmaps over lanes and
+    permutations: a scope opened under a vmap reaches the trace as
+    ``vmap(slot_bag)``, which the trace's reduction does not read.
+
+    With symmetry off only the identity permutation runs; the slot ranking
+    is then the identity by construction (device slot order IS
+    sorted-identity order for unpermuted states), kept for uniformity.
     """
 
     def __init__(self, model: KRaftReconfigModel, symmetry: bool = True,
@@ -2050,68 +2094,113 @@ class SlotCanonicalizer:
         pairs = [(s, t) for s in sigmas for t in taus]
         self._sigmas = jnp.asarray([p0 for p0, _ in pairs], jnp.int32)
         self._taus = jnp.asarray([t for _, t in pairs], jnp.int32)
+        # the VIEW is the per-server fields and acked, then the bag
+        fields = list(model.layout.fields)
+        self._row_fields = fields[: fields.index("msg_w0")]
         self.fingerprints = jax.jit(self._fingerprints)
 
     def _fingerprints(self, states):
         states = jnp.asarray(states, jnp.int32)
-        return jax.vmap(self._fp1)(states)
 
-    def _fp1(self, vec):
-        hashes = jax.vmap(lambda sg, tu: self._canon_hash(vec, sg, tu))(
-            self._sigmas, self._taus
-        )
-        return jnp.min(hashes)
+        def pairs(f, *per_pair):
+            """``f(vec, sigma, tau, *x)`` on every (lane, permutation)."""
+            n = len(per_pair)
+            g = jax.vmap(f, in_axes=(None, 0, 0) + (0,) * n)
+            g = jax.vmap(g, in_axes=(0, None, None) + (0,) * n)
+            return g(states, self._sigmas, self._taus, *per_pair)
 
-    def _canon_hash(self, vec, sigma, tau):
+        with jax.named_scope("slot_sort"):
+            host2, inv = pairs(self._slot_sort)
+        with jax.named_scope("slot_remap"):
+            rows, words, cnt = pairs(self._slot_remap, host2, inv)
+        with jax.named_scope("slot_bag"):
+            words, cnt = bag.wide_bag_sort(words, cnt)  # along the slots
+        with jax.named_scope("slot_hash"):
+            view = jnp.concatenate([rows, *words, cnt], axis=-1)
+            assert view.shape[-1] == self.model.layout.view_len
+            return jnp.min(hash_lanes(view, seed=self.seed), axis=-1)
+
+    def fingerprints_dedup(self, states, valid):
+        """The surface ``ops.symmetry.canon_chunk`` prefers: ``(fps,
+        n_dup, tiers)`` with invalid lanes masked to U64_MAX. There is no
+        in-chunk dedup and there are no tiers here: every valid lane runs
+        every permutation, so ``n_dup`` is 0 and ``tiers`` is [0, valid
+        lanes] (``canon_tier3_full``: the full table wherever it runs)."""
+        fps = jnp.where(valid, self._fingerprints(states), U64_MAX)
+        n_valid = jnp.sum(valid).astype(jnp.int32)
+        zero = n_valid * 0  # of the lanes' type under shard_map
+        full = n_valid if self.symmetry else zero
+        return fps, zero, jnp.stack([zero, full])
+
+    def _slot_sort(self, vec, sigma, _tau):
+        """The permuted host of each slot and the slot's rank under the
+        permuted identity (old slot -> new row; keys are unique, so a
+        rank is a count of smaller keys and no sort runs)."""
+        model = self.model
+        lay, NS, H = model.layout, model.NS, model.p.n_hosts
+        iota = jnp.arange(NS, dtype=jnp.int32)
+        used = lay.get(vec, "used") > 0
+        host2 = _lookup(sigma, jnp.clip(lay.get(vec, "host"), 0, H - 1))
+        BIG = jnp.int32(max(NS, H) + 2)  # > any diskId/host
+        # unused slots last, in creation order
+        key = jnp.where(
+            used, host2 * BIG + lay.get(vec, "diskId"), BIG * BIG + iota)
+        inv = jnp.sum((key[None, :] < key[:, None]).astype(jnp.int32), axis=1)
+        return host2, inv
+
+    def _slot_remap(self, vec, _sigma, tau, host2, inv):
+        """The VIEW's per-server fields and acked in the new slot order,
+        flat, and the bag's words and counts with their slot and value
+        fields remapped, not yet re-sorted."""
         model = self.model
         d = model._dec(vec)
-        NS, L = model.NS, model.p.max_log
+        NS = model.NS
         iota = jnp.arange(NS, dtype=jnp.int32)
         used = d["used"] > 0
+        take = inv[None, :] == iota[:, None]  # new row r <- old slot i
 
-        # 1. permuted identity sort key; unused slots last in stable order
-        host2 = sigma[jnp.clip(d["host"], 0, model.p.n_hosts - 1)]
-        BIG = jnp.int32(max(NS, model.p.n_hosts) + 2)  # > any diskId/host
-        key = jnp.where(used, host2 * BIG + d["diskId"], BIG * BIG + iota)
-        order = jnp.argsort(key, stable=True)  # new row r <- old slot order[r]
-        inv = jnp.zeros((NS,), jnp.int32).at[order].set(iota)  # old -> new
+        def gather(x):  # per-slot rows into the new order
+            tk = take.reshape(take.shape + (1,) * (x.ndim - 1))
+            return jnp.sum(jnp.where(tk, x[None], 0), axis=1)
 
-        def gather(x):  # per-slot rows
-            return x[order]
+        ref = jnp.concatenate([jnp.zeros((1,), jnp.int32), inv + 1])
 
         def refmap(x):  # slot+1 valued (0 = Nil)
-            return jnp.where(x > 0, inv[jnp.clip(x - 1, 0)] + 1, 0)
+            return _lookup(ref, x)
 
-        def maskmap(mask):  # bitmask over slots; mask shape [...]
-            bits = (mask[..., None] >> order) & 1  # new bit r from old order[r]
-            return jnp.sum(bits << iota, axis=-1).astype(jnp.int32)
+        def maskmap(mask):  # bitmask over slots: old bit i -> new bit inv[i]
+            bits = (mask[..., None] >> iota) & 1
+            return jnp.sum(bits << inv, axis=-1).astype(jnp.int32)
+
+        def valmap(cmd, val):  # APPEND entries only carry a value
+            return jnp.where(
+                (cmd == C_APPEND) & (val > 0),
+                _lookup(tau, jnp.clip(val - 1, 0)) + 1,
+                val,
+            )
 
         upd = {}
-        upd["host"] = jnp.where(used, host2, 0)[order]
+        upd["host"] = gather(jnp.where(used, host2, 0))
         for f in ("diskId", "used", "role", "state", "currentEpoch",
                   "pf_active", "pf_epoch", "pf_offset", "pf_lastepoch",
                   "pf_observer", "cfg_id", "cfg_committed", "log_cmd",
                   "log_epoch", "log_cfgid", "log_len", "highWatermark"):
             upd[f] = gather(d[f])
-        for f in ("leader", "votedFor", "pf_dest"):
+        for f in ("leader", "votedFor", "pf_dest", "log_who"):
             upd[f] = gather(refmap(d[f]))
         for f in ("votesGranted", "cfg_members", "eo_dom", "log_members"):
             upd[f] = gather(maskmap(d[f]))
-        upd["log_who"] = gather(refmap(d["log_who"]))
-        upd["endOffset"] = d["endOffset"][order][:, order]
-        # value permutation tau: log_val lanes (APPEND entries only carry a
-        # value) + acked reorder (acked'[tau[v]] = acked[v])
-        lv = d["log_val"]
-        lv2 = jnp.where(
-            (d["log_cmd"] == C_APPEND) & (lv > 0),
-            tau[jnp.clip(lv - 1, 0)] + 1,
-            lv,
-        )
-        upd["log_val"] = gather(lv2)
-        upd["acked"] = jnp.zeros_like(d["acked"]).at[tau].set(d["acked"])
+        upd["endOffset"] = gather(gather(d["endOffset"]).T).T
+        upd["log_val"] = gather(valmap(d["log_cmd"], d["log_val"]))
+        # acked'[tau[v]] = acked[v]
+        upd["acked"] = jnp.sum(
+            jnp.where(tau[None, :] == jnp.arange(tau.shape[0])[:, None],
+                      d["acked"][None, :], 0), axis=1)
+        rows = jnp.concatenate(
+            [upd[f].reshape(-1) for f in self._row_fields])
 
         # message bag: remap slot/value fields inside the packed keys of
-        # occupied slots, then re-sort
+        # occupied slots
         words = model._words(d)
         occ = words[0] != EMPTY
         pk = model.packer
@@ -2121,29 +2210,14 @@ class SlotCanonicalizer:
             return [jnp.where(occ, o, w) for o, w in zip(out, ws)]
 
         u = partial(pk.unpack, tuple(words))
-        src, dst = u("msource"), u("mdest")
         ws = list(words)
-        ws = wreplace(ws, "msource", inv[jnp.clip(src, 0, NS - 1)])
-        ws = wreplace(ws, "mdest", inv[jnp.clip(dst, 0, NS - 1)])
+        ws = wreplace(ws, "msource", _lookup(inv, u("msource")))
+        ws = wreplace(ws, "mdest", _lookup(inv, u("mdest")))
         ws = wreplace(ws, "mleader", refmap(u("mleader")))
         ws = wreplace(ws, "e_who", refmap(u("e_who")))
         ws = wreplace(ws, "e_members", maskmap(u("e_members")))
-        ev = u("e_val")
-        ws = wreplace(
-            ws, "e_val",
-            jnp.where(
-                (u("e_cmd") == C_APPEND) & (ev > 0),
-                tau[jnp.clip(ev - 1, 0)] + 1,
-                ev,
-            ),
-        )
-        sw, scnt = bag.wide_bag_sort(ws, d["msg_cnt"])
-        for k in range(pk.n_words):
-            upd[f"msg_w{k}"] = sw[k]
-        upd["msg_cnt"] = scnt
-
-        out = model._asm(d, **upd)
-        return hash_lanes(out[: model.layout.view_len], seed=self.seed)
+        ws = wreplace(ws, "e_val", valmap(u("e_cmd"), u("e_val")))
+        return rows, ws, d["msg_cnt"]
 
 
 @lru_cache(maxsize=None)

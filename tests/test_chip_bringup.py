@@ -214,22 +214,27 @@ def test_chip_smoke_leg_holds_the_cli_to_the_golden(smoke):
     # leg D: no --msg-slots (the golden names none); the count the v5e
     # first got wrong is depth 4's (276 for 271)
     ("JOINT", None, 4, [1, 5, 24, 90, 271], [1, 5, 24, 90, 276],
-     ("D", 8, 1024)),
+     ("D", 8, 1024, [])),
     # leg E: the cell's bag width, the registry's own, by --msg-slots
     ("KRAFT", 80, 6, [1, 1, 3, 6, 15, 29, 60], [1, 1, 3, 6, 15, 29, 61],
-     ("E", 14, 2048)),
+     ("E", 14, 2048, [])),
+    # leg F: upstream's cfg declares v1 and uses v2, so --lenient
+    ("KRAFTRC", 40, 3, [1, 9, 65, 406], [1, 9, 65, 407],
+     ("F", 5, 1024, ["--lenient"])),
 ])
 def test_chip_smoke_second_lowerings_legs_run_their_own_cfg_and_golden(
         smoke, monkeypatch, leg, msg_slots, depth, counts, wrong, args):
-    """Legs D and E at a tiny depth: another cfg, its own chunk, its own
-    golden and that golden's bag width; then the leg itself, its
-    arguments held: the frontier, the golden's depth, the chunk."""
+    """Legs D, E and F at a tiny depth: another cfg, its own chunk, its
+    own golden and that golden's bag width; then the leg itself, its
+    arguments held: the frontier, the golden's depth, the chunk, the
+    flags the cfg needs."""
     chip_smoke, dev = smoke
     cfg = getattr(chip_smoke, f"{leg}_CFG")
     golden = json.loads(Path(
         getattr(chip_smoke, f"{leg}_GOLDEN")).read_text())["depth_limited"]
     assert golden["msg_slots"] == msg_slots
-    small = ["--checker", "tpu", "--frontier-cap", "4096"]
+    letter, max_depth, chunk, flags = args
+    small = ["--checker", "tpu", "--frontier-cap", "4096", *flags]
     obs = chip_smoke.bfs_leg(
         leg, dev, golden, small, depth, 1, cfg=cfg, chunk=256)
     assert obs["distinct"] == sum(counts)
@@ -237,7 +242,6 @@ def test_chip_smoke_second_lowerings_legs_run_their_own_cfg_and_golden(
         chip_smoke.bfs_leg(
             f"{leg}-bad", dev, dict(golden, depth_counts=wrong), small,
             depth, 1, cfg=cfg, chunk=256)
-    letter, max_depth, chunk = args
     calls = []
     monkeypatch.setattr(
         chip_smoke, "bfs_leg",
@@ -247,8 +251,8 @@ def test_chip_smoke_second_lowerings_legs_run_their_own_cfg_and_golden(
     monkeypatch.setattr(chip_smoke, "leg_b", lambda *a: None)
     monkeypatch.setattr(chip_smoke, "leg_c", lambda *a: None)
     assert chip_smoke.main() == 0
-    (a, kw) = calls["DE".index(letter)]
+    (a, kw) = calls["DEF".index(letter)]
     assert a[:1] + a[2:] == (
         f"leg{letter}", golden,
-        ["--checker", "tpu", "--frontier-cap", "65536"], max_depth, 1)
+        ["--checker", "tpu", "--frontier-cap", "65536", *flags], max_depth, 1)
     assert kw == {"cfg": cfg, "chunk": chunk}

@@ -201,17 +201,16 @@ def test_guard_jaxpr_writes_no_successor_blocks(family):
 # `raft` runs on the chip with these and equals its goldens in every
 # benchmark run (none of them in HandleMessage, whose writes went
 # one-hot in round 5, for speed); `kraft` left the table with its first
-# cell (PR 32: 73, 44 of them in HandleMessage); `pull_raft` and
-# `kraft_reconfig` have not run there at their published constants:
-# convert each before its first cell (ROADMAP R3). strict: a family that
-# comes clean has to leave this table.
+# cell (PR 32: 73, 44 of them in HandleMessage) and `kraft_reconfig`
+# with its (PR 40: 175, 104 of them in HandleMessage); `pull_raft` has
+# not run there at its published constants: convert it before its first
+# cell (ROADMAP R3). strict: a family that comes clean has to leave this
+# table.
 SCATTER_DEBT = {
     "raft": "20 in Restart, RequestVote, BecomeLeader, ClientRequest, "
             "AdvanceCommitIndex, AppendEntries; equal to the goldens on "
             "the v5e in every run of the three accepted cells",
     "pull_raft": "29, 15 of them in HandleMessage; never run on the chip",
-    "kraft_reconfig": "175, 104 of them in HandleMessage; never run on "
-                      "the chip",
 }
 
 
@@ -230,15 +229,15 @@ def test_no_kernel_writes_through_a_dynamic_index_scatter(family):
 # guard pass under the chunk's, a per-lane gather. On the v5e those were
 # 8-12 ns an index and most of `expand` on joint4 (PR 31); the two
 # config_common lowerings read by one-hot selects since
-# (`models/base.py::onehot_row`, `onehot_get2`), and `kraft` since PR 32
-# (131 (58), 50 of them in HandleMessage). Counts at this file's
-# shapes, the guard pass's in brackets. strict, as above.
+# (`models/base.py::onehot_row`, `onehot_get2`), `kraft` since PR 32
+# (131 (58), 50 of them in HandleMessage) and `kraft_reconfig` since
+# PR 40 (326 (127), 130 of them in HandleMessage). Counts at this
+# file's shapes, the guard pass's in brackets. strict, as above.
 GATHER_DEBT = {
     "raft": "62 (29): RequestVote, BecomeLeader, ClientRequest, "
             "AdvanceCommitIndex, AppendEntries, 3 in HandleMessage, "
             "which reads by one-hot since round 5 (ROADMAP D14)",
     "pull_raft": "86 (42), 21 of them in HandleMessage",
-    "kraft_reconfig": "326 (127), 130 of them in HandleMessage",
 }
 
 
@@ -256,7 +255,7 @@ def test_no_kernel_reads_through_a_dynamic_index_gather(family):
 
 @pytest.mark.parametrize("family,bag_word", [
     ("joint_raft", "msg_w0"), ("reconfig_raft", "msg_w0"),
-    ("kraft", "msg_hi")])
+    ("kraft", "msg_hi"), ("kraft_reconfig", "msg_w0")])
 def test_one_hot_reads_match_the_oracle_on_empty_slots_and_logs(
         family, bag_word):
     """A one-hot read of an index outside its axis yields 0 where the

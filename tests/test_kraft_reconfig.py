@@ -1,7 +1,22 @@
 """KRaftWithReconfig oracle tests: join/remove reconfiguration flows over
 the dynamic server universe (pull-raft/KRaftWithReconfig.tla, 1,918
 lines), invariants, bounded BFS sanity, simulation mode, and
-reference-cfg loading with the documented v2 repair."""
+reference-cfg loading with the documented v2 repair.
+
+The four device tests at the end are `slow` and stay so: tier-1 has the
+same ground in tests/test_kraftrc3.py (PR 40). Successor sets against
+the oracle: `test_successor_sets_match_oracle_on_states_that_take_every_
+action` (published constants, walked states that take all 21 actions,
+where `test_device_successor_sets_match_oracle` here samples BFS order
+at SMALLP). BFS counts with symmetry on and off, through
+`SlotCanonicalizer`: `test_device_bfs_counts_match_oracle_and_a_second_
+verdict_compiles_nothing` (SMALLP, depth 4, with `total`, `terminal` and
+the canon counters). Symmetric collapse with InitClusterSize = H:
+`test_fingerprints_are_equal_iff_the_oracles_canon_is_under_all_12` and
+`test_cli_refuses_the_cfg_and_under_lenient_counts_the_goldens_prefix`
+(2,600 distinct to depth 4 at the published constants, which is also
+what `test_device_cli_dispatch_tpu_checker` pins at depth 2 where
+/root/reference exists). Unmarking a slow test here buys no coverage."""
 
 import pytest
 

@@ -1,0 +1,356 @@
+"""kraftrc3: upstream's KRaftWithReconfig.cfg (KRaft with one-at-a-time
+membership change over a growing universe of servers: 3 hosts, up to 5
+servers [host, diskId], 2 values, 12 permutations, five invariants)
+against the pure-Python oracle, `KRaftReconfigOracle`: 479-lane rows,
+145 candidate actions a state, 40 of them HandleMessage over the bag's
+slots, and a canonicalizer of the model's own.
+
+The cfg in the tree is reconstructed, latent bug and all (its header
+says from what); the benchmark's copy has the bug repaired in the file.
+What runs at the published constants: the cfg, the successor sets, the
+fingerprint classes (a permuted state only encodes where every host
+holds an initial server, InitClusterSize = 3) and one verdict to depth 4
+through the CLI. The BFS counts with symmetry on and off run at
+`test_kraft_reconfig.SMALLP`, where a wave program compiles in half the
+time.
+"""
+
+import contextlib
+import io
+import itertools
+import json
+import os
+import random
+
+import jax
+import numpy as np
+import pytest
+
+from conftest import eqns
+from test_kraft_reconfig import SMALLP, small_oracle
+from raft_tpu.models import kraft_reconfig
+from raft_tpu.models.registry import build_from_cfg, oracle_for_setup
+from raft_tpu.utils.cfg import CfgError, parse_cfg
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+CFG = os.path.join(ROOT, "configs", "pull-raft", "KRaftWithReconfig.cfg")
+BENCH = os.path.join(ROOT, "benchmark")
+BENCH_CFG = os.path.join(BENCH, "configs", "kraftrc3", "KRaftWithReconfig.cfg")
+DEPTH = 4
+INVARIANTS = (
+    "LeaderHasAllAckedValues",
+    "NoLogDivergence",
+    "NeverTwoLeadersInSameEpoch",
+    "NoIllegalState",
+    "StatesMatchRoles",
+)
+
+
+@pytest.fixture(scope="module")
+def setup():
+    # lenient parsing, the registry's own bag width: the CLI's path
+    return build_from_cfg(parse_cfg(CFG, lenient=True))
+
+
+@pytest.fixture(scope="module")
+def oracle(setup):
+    return oracle_for_setup(setup)
+
+
+@pytest.fixture(scope="module")
+def golden():
+    with open(os.path.join(BENCH, "goldens", "kraftrc3.json")) as f:
+        return json.load(f)
+
+
+@pytest.fixture(scope="module")
+def walked(setup, oracle):
+    """{action: states it was taken from} on seeded random walks of 50
+    steps at the published constants, at most 6 states an action, each
+    with room in its bag for what one more action sends. A walk reaches
+    a diverging log and a committed removal in a second; BFS order would
+    stop twenty steps short of them."""
+    rng = random.Random(40)
+    room = setup.model.p.msg_slots - setup.model.NS
+    taken = {}
+    for _ in range(120):
+        st = oracle.init_state()
+        for _step in range(50):
+            succs = oracle.successors(st)
+            if not succs:
+                break
+            label, nxt = rng.choice(succs)
+            if len(st["messages"]) <= room:
+                taken.setdefault(label.split("(")[0], []).append(st)
+            st = nxt
+    return {name: sts[:: max(1, len(sts) // 6)][:6]
+            for name, sts in taken.items()}
+
+
+@pytest.fixture(scope="module")
+def sample(walked):
+    return [st for name in sorted(walked) for st in walked[name]]
+
+
+def test_in_tree_cfg_is_refused_strictly_and_builds_the_published_constants(
+        setup):
+    with pytest.raises(CfgError, match="undeclared model value 'v2'"):
+        parse_cfg(CFG)
+    p = setup.model.p
+    assert (p.n_hosts, p.n_values) == (3, 2)  # v2 after the repair
+    assert (p.init_cluster_size, p.min_cluster_size, p.max_cluster_size) == (
+        3, 2, 4)
+    assert (p.max_spawned_servers, p.max_restarts) == (5, 1)
+    assert (p.max_values_per_epoch, p.max_add_reconfigs,
+            p.max_remove_reconfigs) == (1, 1, 1)
+    assert p.max_elections == 2  # assumed: config.json says why
+    assert p.msg_slots == 40  # the registry's own
+    assert setup.model.name == "KRaftWithReconfig"
+    assert setup.symmetry and setup.invariants == INVARIANTS
+    # the row the cell is named for
+    assert (setup.model.layout.W, setup.model.A) == (479, 145)
+    groups = {g.name: g.n for g in setup.model.sparse_groups()}
+    assert groups["HandleMessage"] == 40
+    canon = setup.model.make_canonicalizer(True)
+    assert canon._sigmas.shape == (12, 3) and canon._taus.shape == (12, 2)
+
+
+def test_the_two_cfg_copies_differ_in_one_line_and_build_one_model():
+    """The benchmark's copy is the in-tree cfg with `v2` declared, which
+    is all --lenient does to it; the adapter, which parses strictly,
+    builds from it the engine the CLI builds from the in-tree file."""
+    from benchmark import adapter
+    from raft_tpu.checker.device_bfs import DeviceBFS
+
+    with open(CFG) as f:
+        tree = f.read().splitlines()
+    with open(BENCH_CFG) as f:
+        bench = f.read().splitlines()
+    added = [line for line in bench if line not in tree]
+    assert [line.split() for line in added] == [["v2", "=", "v2"]]
+    assert [line for line in bench if line not in added] == tree
+    lenient, strict = parse_cfg(CFG, lenient=True), parse_cfg(BENCH_CFG)
+    assert lenient.constants == strict.constants
+    assert lenient.invariants == strict.invariants
+    assert lenient.symmetry == strict.symmetry
+    with open(os.path.join(BENCH, "workloads", "kraftrc3-wide.json")) as f:
+        cell = json.load(f)
+    params = dict(cell["engine_params"], chunk=64, frontier_cap=1 << 12)
+    bench_eng = adapter.build_engine(BENCH_CFG, cell["engine"], params, None)
+    cli = build_from_cfg(lenient, msg_slots=params["msg_slots"])
+    cli_eng = DeviceBFS(cli.model, invariants=cli.invariants,
+                        symmetry=cli.symmetry, chunk=64, frontier_cap=1 << 12)
+    assert adapter.ident(bench_eng) == cli_eng._ckpt_ident()
+    assert "StatesMatchRoles" in adapter.ident(bench_eng)
+
+
+def test_successor_sets_match_oracle_on_states_that_take_every_action(
+        setup, oracle, walked, sample):
+    """Every action the spec can take was taken from some sampled state:
+    HandleMessage's thirteen kinds, a spawn, a join accepted and
+    rejected, a removal, a restart (RestartWithoutState is never enabled
+    upstream, :913, and the oracle never offers it); per state the
+    (action, successor) pairs equal the oracle's."""
+    model = setup.model
+    assert set(walked) == set(model.ACTION_NAMES)
+    assert len(model.ACTION_NAMES) == 21
+    vecs = np.stack([model.encode(st) for st in sample]).astype(np.int32)
+    succs, valid, rank, ovf = jax.device_get(model.expand(vecs))
+    assert not np.any(valid & ovf)
+    for b, st in enumerate(sample):
+        got = sorted(
+            (model.ACTION_NAMES[rank[b, a]],
+             oracle.serialize_full(model.decode(succs[b, a])))
+            for a in np.nonzero(valid[b])[0])
+        want = sorted((label.split("(")[0], oracle.serialize_full(s2))
+                      for label, s2 in oracle.successors(st))
+        assert got == want, f"successor mismatch at state {b}"
+
+
+def test_no_gather_and_no_scatter_in_the_canonicalizer_and_its_scopes(setup):
+    """`SlotCanonicalizer._fingerprints` reads and writes through its
+    tiny index sets by compares and selects (the one sort is the bag's),
+    and its four steps reach a trace as `canon/slot_*`: each scope is
+    opened outside the vmaps it covers."""
+    from raft_tpu.obs import stage
+
+    model = setup.model
+    canon = model.make_canonicalizer(True)
+    rows = jax.ShapeDtypeStruct((16, model.layout.W), np.int32)
+    names = [e.primitive.name
+             for e in eqns(jax.make_jaxpr(canon._fingerprints)(rows).jaxpr)]
+    assert not [n for n in names if n == "gather" or n.startswith("scatter")]
+    assert names.count("sort") == 1
+    lowered = jax.jit(stage("canon")(canon.fingerprints_dedup)).lower(
+        rows, jax.ShapeDtypeStruct((16,), bool)).as_text(debug_info=True)
+    for scope in ("slot_sort", "slot_remap", "slot_bag", "slot_hash"):
+        assert f"/canon/{scope}/" in lowered
+        assert f"({scope})" not in lowered  # not vmap(slot_bag)
+    assert "canon/slot_bag/sort" in lowered
+
+
+def test_fingerprints_are_equal_iff_the_oracles_canon_is_under_all_12(
+        setup, oracle, sample):
+    """On the sampled states and their images under every host and value
+    permutation: an image's fingerprint is its state's, and two states
+    share one iff the oracle's brute-force `canon` (the least serialised
+    view over the 12 permuted states) is equal."""
+    model = setup.model
+    canon = model.make_canonicalizer(True)
+    perms = [(list(s), list(t))
+             for s in itertools.permutations(range(3))
+             for t in itertools.permutations(range(2))]
+    assert len(perms) == 12
+    states = sample[::3]
+    # a state and a proper image of a later one: equal keys that are not
+    # one state's rows
+    states = states + [oracle.permute(states[-1], [1, 2, 0], [1, 0])]
+    rows = np.stack([
+        model.encode(oracle.permute(st, sigma, tau))
+        for st in states for sigma, tau in perms
+    ]).astype(np.int32)
+    got = np.asarray(canon.fingerprints(rows)).reshape(len(states), 12)
+    assert np.array_equal(got, np.broadcast_to(got[:, :1], got.shape))
+    keys = [oracle.canon(st, True) for st in states]
+    assert keys[-1] == keys[-2]
+    fps = got[:, 0].tolist()
+    for a, b in itertools.combinations(range(len(states)), 2):
+        assert (fps[a] == fps[b]) == (keys[a] == keys[b]), (a, b)
+    # with symmetry off a permuted state is another state
+    plain = model.make_canonicalizer(False)
+    off = np.asarray(plain.fingerprints(rows)).reshape(len(states), 12)
+    assert len(set(off[0].tolist())) > 1
+
+
+def _break_roles(st):
+    """An Unattached server that believes in a leader."""
+    i, j = sorted(st["servers"])[:2]
+    return dict(st, state={**st["state"], i: "Unattached"},
+                leader={**st["leader"], i: j})
+
+
+def test_states_match_roles_kernel_equals_the_oracles_predicate(
+        setup, oracle, sample):
+    """The invariant no other cell evaluates: the kernel and the oracle's
+    predicate agree on reachable states (where both hold) and on the same
+    states broken by hand (where neither does)."""
+    model = setup.model
+    states = sample[::3]
+    states = states + [_break_roles(st) for st in states]
+    name = "StatesMatchRoles"
+    want = np.array([oracle.INVARIANTS[name](oracle, st) for st in states])
+    vecs = np.stack([model.encode(st) for st in states]).astype(np.int32)
+    got = np.asarray(model.invariants[name](vecs))
+    assert np.array_equal(got, want)
+    half = len(states) // 2
+    assert want[:half].all() and not want[half:].any()
+
+
+@pytest.fixture(scope="module")
+def cli_run(tmp_path_factory):
+    """One verdict to depth 4 at the published constants, as a user gets
+    it: the in-tree cfg through `python -m raft_tpu`'s main path."""
+    from raft_tpu.__main__ import main
+
+    metrics = tmp_path_factory.mktemp("kraftrc3") / "m.jsonl"
+    out, err = io.StringIO(), io.StringIO()
+    argv = [CFG, "--platform", "cpu", "--checker", "tpu", "--chunk", "256",
+            "--msg-slots", "40", "--frontier-cap", "4096",
+            "--max-depth", str(DEPTH), "--json", "--metrics-out", str(metrics)]
+    with contextlib.redirect_stdout(out), contextlib.redirect_stderr(err):
+        refused = main(argv)
+        rc = main(argv + ["--lenient"])
+    events = [json.loads(x) for x in metrics.read_text().splitlines()]
+    return refused, rc, out.getvalue(), err.getvalue(), events
+
+
+def test_cli_refuses_the_cfg_and_under_lenient_counts_the_goldens_prefix(
+        cli_run, golden):
+    """2,600 distinct states to depth 4, all five invariants on each,
+    equal to the pooled oracle run's record."""
+    refused, rc, out, err, events = cli_run
+    assert refused == 64 and "undeclared model value 'v2'" in err
+    assert rc == 0, err
+    summary = json.loads(out.strip().splitlines()[-1])
+    waves = [e for e in events if e["event"] == "wave"]
+    want = golden["depth_counts"][: DEPTH + 1]
+    assert want == [1, 9, 65, 406, 2119]
+    assert [1] + [w["new"] for w in waves] == want
+    assert (summary["distinct"], summary["violation"]) == (2600, None)
+    assert {"total": summary["total"], "terminal": summary["terminal"]} == (
+        golden["totals"][str(DEPTH)])
+    assert summary["exit_cause"] == "max_depth"
+    assert all(w["overflow_bits"] == 0 for w in waves)
+
+
+def test_every_valid_lane_runs_every_permutation_and_the_rows_say_so(cli_run):
+    """`SlotCanonicalizer.fingerprints_dedup` has no in-chunk dedup and no
+    tiers: a wave's `canon_tier3_full` is its valid successor lanes, its
+    duplicates are 0, and the run's total is the golden's generated less
+    Init."""
+    _refused, _rc, out, _err, events = cli_run
+    waves = [e for e in events if e["event"] == "wave"]
+    assert len(waves) == DEPTH
+    for w in waves:
+        assert w["canon_tier3_full"] == w["generated"] > 0
+        assert (w["canon_dup_lanes"], w["canon_tier3_local"]) == (0, 0)
+    summary = json.loads(out.strip().splitlines()[-1])
+    assert summary["canon_tier3_full"] == summary["total"] - 1 == sum(
+        w["generated"] for w in waves)
+
+
+def test_golden_is_the_oracles_and_covers_the_cell(golden):
+    """benchmark/goldens/kraftrc3.json is the pooled oracle run's record
+    at the bag width the cell runs, its totals cover the cell's depth,
+    and what chip_smoke.py's leg F holds the CLI to is its prefix."""
+    assert golden["msg_slots"] == 40
+    assert golden["independent_to_depth"] >= 7
+    assert "oracle" in golden["source"] and "oracle_golden.py" in golden["command"]
+    with open(os.path.join(BENCH, "traffic", "init-d7-warm7.json")) as f:
+        traffic = json.load(f)
+    assert traffic["warmup_depth"] == traffic["max_depth"] == 7
+    for depth in (4, 7):
+        assert str(depth) in golden["totals"]
+    assert sum(golden["depth_counts"][:8]) == 188494
+    assert golden["totals"]["7"] == {"total": 490155, "terminal": 0}
+    with open(os.path.join(
+            ROOT, "tests", "golden", "kraftrc_cfg_depth_counts.json")) as f:
+        smoke = json.load(f)["depth_limited"]
+    depth = smoke["max_depth"]
+    assert smoke["msg_slots"] == 40
+    assert smoke["depth_counts"] == golden["depth_counts"][: depth + 1]
+    assert smoke["distinct"] == sum(smoke["depth_counts"])
+    assert {k: smoke[k] for k in ("total", "terminal")} == golden[
+        "totals"][str(depth)]
+
+
+@pytest.mark.parametrize("sym", [True, False])
+def test_device_bfs_counts_match_oracle_and_a_second_verdict_compiles_nothing(
+        sym):
+    """Per-depth counts, generated and terminal equal the oracle's through
+    the slot canonicalizer, symmetry on and off; the engine's next
+    verdict finds every program, the canonicalizer's among them."""
+    from raft_tpu.checker.device_bfs import DeviceBFS
+
+    model = kraft_reconfig.cached_model(SMALLP)
+    eng = DeviceBFS(model, invariants=INVARIANTS, symmetry=sym, chunk=256,
+                    frontier_cap=1 << 12, seen_cap=1 << 15,
+                    journal_cap=1 << 15)
+    first = eng.run(max_depth=DEPTH, collect_metrics=True)
+    want = small_oracle().bfs(invariants=INVARIANTS, symmetry=sym,
+                              max_depth=DEPTH)
+    assert first.violation is None and want["violation"] is None
+    assert [int(x) for x in first.depth_counts] == want["depth_counts"]
+    assert (first.distinct, first.total, first.terminal) == (
+        want["distinct"], want["total"], want["terminal"])
+    assert not any(w["overflow_bits"] for w in first.metrics)
+    full = [w["canon_tier3_full"] for w in first.metrics]
+    assert full == ([w["generated"] for w in first.metrics] if sym
+                    else [0] * DEPTH)
+    assert first.stats["run_compiles"] >= 1
+    again = eng.run(max_depth=DEPTH, collect_metrics=True)
+    assert again.stats["run_compiles"] == 0
+    assert not any(w["compiles"] for w in again.metrics)
+    assert again.stats["programs_loaded"] == first.stats["programs_loaded"]
+    assert again.depth_counts == first.depth_counts
+    assert (again.distinct, again.total) == (first.distinct, first.total)

@@ -213,8 +213,12 @@ def test_second_run_adds_no_record():
 def test_growth_records_are_booked_to_their_wave_and_bracket():
     """Capacities so tiny that the journal outgrows them mid-run (as
     test_obs.test_growth_compile_is_booked_to_its_wave): the programs
-    that re-shape the buffers say `grow` and the wave that grew."""
-    eng = _device(chunk=32, frontier_cap=32, journal_cap=32)
+    that re-shape the buffers say `grow` and the wave that grew. The
+    journal starts at 40 rows, not that test's 32: the re-shaping
+    programs are eager ops a process compiles once a shape, so with its
+    shapes the later of the two in a worker would find no `grow`
+    record."""
+    eng = _device(chunk=32, frontier_cap=32, journal_cap=40)
     n0 = len(COMPILES.records)
     rows = eng.run(collect_metrics=True).metrics
     grew = [r["depth"] for r in rows if r["grow_s"] > 0]
