@@ -53,6 +53,7 @@ import numpy as np
 from ..ops import bag
 from ..ops.hashing import U64_MAX, hash_lanes
 from ..ops.packing import EMPTY, WidePacker, bits_for
+from ..ops.symmetry import fingerprints_by_raw_view
 from .base import (
     ActionLabelMixin,
     Layout,
@@ -2079,6 +2080,13 @@ class SlotCanonicalizer:
     sorted-identity order for unpermuted states), kept for uniformity.
     """
 
+    # fingerprints_dedup runs the permutations on blocks of a BLOCKS-th
+    # of the batch's lanes: a lane costs microseconds here, a loop trip
+    # nothing beside it, and a smaller block leaves fewer padding lanes
+    # after the last representative (PERF.md section 6, PR 42: B // 4 to
+    # B // 64 measured in kraftrc3-wide; B // 64 reads as this one does)
+    BLOCKS = 32
+
     def __init__(self, model: KRaftReconfigModel, symmetry: bool = True,
                  seed: int = 0):
         self.model = model
@@ -2120,17 +2128,36 @@ class SlotCanonicalizer:
             assert view.shape[-1] == self.model.layout.view_len
             return jnp.min(hash_lanes(view, seed=self.seed), axis=-1)
 
+    def raw_fingerprints(self, states):
+        """u64 [B] hashes of the unpermuted view prefix, all that
+        ``_fingerprints`` reads of a row: the raw key the in-chunk dedup
+        groups lanes by."""
+        view = jnp.asarray(states, jnp.int32)[:, : self.model.layout.view_len]
+        return hash_lanes(view, seed=self.seed)
+
     def fingerprints_dedup(self, states, valid):
-        """The surface ``ops.symmetry.canon_chunk`` prefers: ``(fps,
-        n_dup, tiers)`` with invalid lanes masked to U64_MAX. There is no
-        in-chunk dedup and there are no tiers here: every valid lane runs
-        every permutation, so ``n_dup`` is 0 and ``tiers`` is [0, valid
-        lanes] (``canon_tier3_full``: the full table wherever it runs)."""
-        fps = jnp.where(valid, self._fingerprints(states), U64_MAX)
-        n_valid = jnp.sum(valid).astype(jnp.int32)
-        zero = n_valid * 0  # of the lanes' type under shard_map
-        full = n_valid if self.symmetry else zero
-        return fps, zero, jnp.stack([zero, full])
+        """The engines' canon stage (``ops.symmetry.canon_chunk``):
+        ``(fps, n_dup, tiers)`` with invalid lanes masked to U64_MAX. The
+        permutations run once per distinct raw view of the batch
+        (``fingerprints_by_raw_view``, the in-chunk dedup
+        ``Canonicalizer`` uses): ``n_dup`` is the valid lanes that shared
+        an earlier lane's view and skipped them, and there are no tiers,
+        so ``tiers`` is [0, representatives] (``canon_tier3_full``: the
+        full table wherever it runs). With symmetry off one permutation
+        runs on every lane and nothing is counted."""
+        if not self.symmetry:
+            fps = jnp.where(valid, self._fingerprints(states), U64_MAX)
+            zero = jnp.sum(valid).astype(jnp.int32) * 0  # typed as the lanes
+            return fps, zero, jnp.stack([zero, zero])
+
+        def canon_block(rows, real):
+            n = jnp.sum(real).astype(jnp.int32)
+            return self._fingerprints(rows), jnp.stack([n * 0, n])
+
+        B = states.shape[0]
+        return fingerprints_by_raw_view(
+            states, valid, self.raw_fingerprints, canon_block,
+            min(B, max(64, B // self.BLOCKS)))
 
     def _slot_sort(self, vec, sigma, _tau):
         """The permuted host of each slot and the slot's rank under the

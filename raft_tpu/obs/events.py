@@ -106,13 +106,16 @@ TIMELINE_STAGES = (
 # that took (obs/compiles.py).
 # canon_dup_lanes: valid lanes that shared an earlier lane's raw view in
 # their chunk-step and took its fingerprint instead of the permutations
-# (ops/symmetry.py fingerprints_dedup), summed over the wave's
-# chunk-steps; canon_dup_rate: the same over generated.
+# (ops/symmetry.py fingerprints_by_raw_view, under either canon), summed
+# over the wave's chunk-steps; canon_dup_rate: the same over generated.
 # canon_tier3_local / canon_tier3_full: lanes the wave's canon routed to
 # tier 3's two buckets (ops/symmetry.py: the tie-group-local tables; the
 # S!-table masked min, which on a layout without tiers, S <= 4, is every
 # lane canonicalised). They count representatives of the in-chunk dedup,
-# so together they never exceed generated - canon_dup_lanes. 0 on the
+# so together they never exceed generated - canon_dup_lanes. On
+# KRaftWithReconfig (the model's own SlotCanonicalizer, no tiers)
+# canon_tier3_full is every representative: the lanes its 12
+# permutations ran on, generated - canon_dup_lanes. 0 on the
 # host engines, which have no tiered canon. From the stats vector the
 # wave already fetched: zero extra device syncs.
 # dedup_sort_lanes (an extra key of the device engine's rows): the lanes

@@ -21,7 +21,8 @@ identity, with the round count recorded alongside). Canonicalization
 itself is restructured around three compounding optimisations, all
 value-preserving given the signature: one canon per distinct raw
 (identity-permutation) view hash of a chunk, by sorts alone
-(``fingerprints_dedup``; the cross-chunk memo table that stood beside it
+(``fingerprints_by_raw_view``, which a model's own canonicalizer calls
+too; the cross-chunk memo table that stood beside it
 until PR 33 cost ten times what its hits saved, PERF.md section 6),
 tie-group-LOCAL masked mins over per-pattern static tables for lanes
 whose tie groups stay small, and an adaptive blocked ``lax.while_loop``
@@ -284,14 +285,11 @@ def canon_chunk(canon, states, valid):
     """The engines' canon stage on one chunk's compacted lanes:
     ``(fps, canon_n)`` with invalid lanes masked to U64_MAX and
     ``canon_n`` i32[3] = [in-chunk duplicate lanes, tier-3 local lanes,
-    tier-3 full lanes]. Zeros from a custom canonicalizer
-    (``make_canonicalizer`` models), which has neither an in-chunk
-    dedup nor tiers to count."""
-    if hasattr(canon, "fingerprints_dedup"):
-        fps, n_dup, tiers = canon.fingerprints_dedup(states, valid)
-        return fps, jnp.concatenate([n_dup[None], tiers])
-    fps = jnp.where(valid, canon._fingerprints(states), U64_MAX)
-    return fps, jnp.zeros((3,), jnp.int32)
+    tier-3 full lanes], as the canon's ``fingerprints_dedup`` counts
+    them (``Canonicalizer``'s, or a ``make_canonicalizer`` model's
+    own)."""
+    fps, n_dup, tiers = canon.fingerprints_dedup(states, valid)
+    return fps, jnp.concatenate([n_dup[None], tiers])
 
 
 class Canonicalizer:
@@ -1269,84 +1267,109 @@ class Canonicalizer:
         return self._perm_hash(states[:, : self.VL])
 
     def fingerprints_dedup(self, states, valid):
-        """Canonical fingerprints of a [B, W] state batch, one canon per
-        distinct raw view. Returns ``(fps, n_dup, tiers)`` with invalid
-        lanes masked to U64_MAX; ``n_dup`` is the valid lanes that
-        shared an earlier lane's raw view and so skipped the
-        permutations, ``tiers`` i32[2] the representatives that took
+        """Canonical fingerprints of a [B, W] state batch, one tiered
+        canon per distinct raw view (``fingerprints_by_raw_view``).
+        Returns ``(fps, n_dup, tiers)`` with invalid lanes masked to
+        U64_MAX; ``tiers`` i32[2] is the representatives that took
         ``[tier3_local, tier3_full]`` (``_canon_view``): together at
-        most the valid lanes less ``n_dup``.
-
-        Sorts alone, no per-lane write: the raw keys are sorted (equal
-        views become segments — duplicate successors inside a chunk are
-        common), the segment heads drain through the tiered canon in
-        fixed-size blocks of an adaptive-trip ``lax.while_loop`` (a
-        chunk of one view pays one block, a chunk of distinct views one
-        canon a lane), the k-th head's fingerprint lands in slot k of a
-        dense buffer (the heads leave ``argsort`` in rising order), each
-        sorted lane reads its segment's slot, and one sort keyed on the
-        lanes' original indices brings the result back to lane order.
-        Deduplication never changes a value: a lane's fingerprint is
-        the tiered canon of its own raw view."""
+        most the valid lanes less ``n_dup``."""
         view = states[:, : self.VL]
-        B = view.shape[0]
-        # derived from `view` for the loop carry's type under shard_map
-        # (as _masked_min's init)
-        zero = view[0, 0] & 0
-        no_tiers = jnp.zeros((2,), jnp.int32) + zero
-        # the `inchunk` scope is the raw hash, the sorts and the fill; it
-        # is opened piecewise so that the tiers' scopes in the loop's
-        # body stay its siblings under `canon`, not its children
-        inchunk = functools.partial(jax.named_scope, "inchunk")
-        with inchunk():
-            raw = self._perm_hash(view)
         if not self.symmetry:
+            zero, no_tiers = _zero_counts(view)
+            with _inchunk():
+                raw = self._perm_hash(view)
             return jnp.where(valid, raw, U64_MAX), zero, no_tiers
-        CB = min(B, max(64, B // 4))
-        with inchunk():
-            # a valid raw key equal to the sentinel (p = 2^-64) sorts
-            # with the padding and comes back masked, as an invalid lane
-            sraw, order = sort_u64_with_idx(jnp.where(valid, raw, U64_MAX))
-            real_s = ne_u64(sraw, U64_MAX)
-            head = real_s & jnp.concatenate(
-                [jnp.ones((1,), bool), ne_u64(sraw[1:], sraw[:-1])]
-            )
-            n_rep = jnp.sum(head)
-            n_dup = (jnp.sum(valid) - n_rep).astype(jnp.int32)
-            # sorted lane -> its segment's representative, counted from 0
-            rank = jnp.maximum(jnp.cumsum(head.astype(jnp.int32)) - 1, 0)
-            # head positions first, in rising order: representative k
-            psel = jnp.argsort(~head, stable=True).astype(jnp.int32)
-            psel = jnp.concatenate([psel, jnp.full((CB,), B, jnp.int32)])
-            orderp = jnp.concatenate([order, jnp.full((1,), B, jnp.int32)])
-            viewp = jnp.concatenate(
-                [view, jnp.zeros((1, self.VL), view.dtype)])
-            # a whole number of blocks: dynamic_update_slice clamps a
-            # start that would run off the end
-            canon_rep = jnp.full((-(-B // CB) * CB,), U64_MAX, jnp.uint64)
-            jcb = jnp.arange(CB, dtype=jnp.int32)
+        B = view.shape[0]
+        return fingerprints_by_raw_view(
+            view, valid, self._perm_hash, self._canon_view,
+            min(B, max(64, B // 4)))
 
-        def cond(c):
-            return c[0] * CB < n_rep
 
-        def body(c):
-            i, acc, tiers = c
-            with inchunk():
-                pos = lax.dynamic_slice(psel, (i * CB,), (CB,))
-                real = i * CB + jcb < n_rep
-                block = viewp[orderp[jnp.where(real, pos, B)]]
-            cfp, t = self._canon_view(block, real)
-            with inchunk():
-                acc = lax.dynamic_update_slice(acc, cfp, (i * CB,))
-            return i + 1, acc, tiers + t
+# the `inchunk` scope is the raw hash, the sorts and the fill; it is
+# opened piecewise so that the scopes a canon opens in the loop's body
+# stay its siblings under `canon`, not its children
+_inchunk = functools.partial(jax.named_scope, "inchunk")
 
-        _, canon_rep, tiers = lax.while_loop(
-            cond, body, (jnp.asarray(0, jnp.int32), canon_rep, no_tiers)
+
+def _zero_counts(rows):
+    """An i32 zero and an i32[2] of zeros derived from ``rows``, so that
+    a loop carry built on them has the lanes' type under shard_map (as
+    _masked_min's init)."""
+    zero = rows[0, 0] & 0
+    return zero, jnp.zeros((2,), jnp.int32) + zero
+
+
+def fingerprints_by_raw_view(rows, valid, raw_key, canon_block, block):
+    """The in-chunk dedup both canons share: canonical fingerprints of a
+    [B, L] batch of rows, the canon run once per distinct raw key.
+    ``raw_key(rows)`` is u64 [B], equal on two lanes only if the canon
+    would be (a canon's hash of what it reads, unpermuted);
+    ``canon_block(block_rows, real)`` canonicalizes a [CB, L] block of
+    rows, ``real`` marking the lanes that hold a representative, and
+    returns ``(fps u64 [CB], counts i32 [2])``; ``block`` is CB, the
+    block's lanes, the caller's function of the shape. Returns ``(fps,
+    n_dup, counts)`` with invalid lanes masked to U64_MAX; ``n_dup`` is
+    the valid lanes that shared an earlier lane's raw key and so
+    skipped the permutations, ``counts`` the blocks' sum.
+
+    Sorts alone, no per-lane write: the raw keys are sorted (equal
+    views become segments — duplicate successors inside a chunk are
+    common), the segment heads drain through the canon in fixed-size
+    blocks of an adaptive-trip ``lax.while_loop`` (a chunk of one view
+    pays one block, a chunk of distinct views one canon a lane), the
+    k-th head's fingerprint lands in slot k of a dense buffer (the
+    heads leave ``argsort`` in rising order), each sorted lane reads
+    its segment's slot, and one sort keyed on the lanes' original
+    indices brings the result back to lane order. Deduplication never
+    changes a value: a lane's fingerprint is the canon of its own raw
+    view."""
+    B, CB = rows.shape[0], block
+    _zero, no_counts = _zero_counts(rows)
+    with _inchunk():
+        raw = raw_key(rows)
+        # a valid raw key equal to the sentinel (p = 2^-64) sorts
+        # with the padding and comes back masked, as an invalid lane
+        sraw, order = sort_u64_with_idx(jnp.where(valid, raw, U64_MAX))
+        real_s = ne_u64(sraw, U64_MAX)
+        head = real_s & jnp.concatenate(
+            [jnp.ones((1,), bool), ne_u64(sraw[1:], sraw[:-1])]
         )
-        with inchunk():
-            fh, fl = split_u64(jnp.where(real_s, canon_rep[rank], U64_MAX))
-            # `order` is a permutation of the lanes: sorting by it alone
-            # is the inverse permutation (util.first_new's return sort)
-            _, fh, fl = lax.sort((order, fh, fl), num_keys=1)
-            fps = join_u64(fh, fl)
-        return fps, n_dup, tiers
+        n_rep = jnp.sum(head)
+        n_dup = (jnp.sum(valid) - n_rep).astype(jnp.int32)
+        # sorted lane -> its segment's representative, counted from 0
+        rank = jnp.maximum(jnp.cumsum(head.astype(jnp.int32)) - 1, 0)
+        # head positions first, in rising order: representative k
+        psel = jnp.argsort(~head, stable=True).astype(jnp.int32)
+        psel = jnp.concatenate([psel, jnp.full((CB,), B, jnp.int32)])
+        orderp = jnp.concatenate([order, jnp.full((1,), B, jnp.int32)])
+        rowsp = jnp.concatenate(
+            [rows, jnp.zeros((1, rows.shape[1]), rows.dtype)])
+        # a whole number of blocks: dynamic_update_slice clamps a
+        # start that would run off the end
+        canon_rep = jnp.full((-(-B // CB) * CB,), U64_MAX, jnp.uint64)
+        jcb = jnp.arange(CB, dtype=jnp.int32)
+
+    def cond(c):
+        return c[0] * CB < n_rep
+
+    def body(c):
+        i, acc, counts = c
+        with _inchunk():
+            pos = lax.dynamic_slice(psel, (i * CB,), (CB,))
+            real = i * CB + jcb < n_rep
+            heads = rowsp[orderp[jnp.where(real, pos, B)]]
+        cfp, n = canon_block(heads, real)
+        with _inchunk():
+            acc = lax.dynamic_update_slice(acc, cfp, (i * CB,))
+        return i + 1, acc, counts + n
+
+    _, canon_rep, counts = lax.while_loop(
+        cond, body, (jnp.asarray(0, jnp.int32), canon_rep, no_counts)
+    )
+    with _inchunk():
+        fh, fl = split_u64(jnp.where(real_s, canon_rep[rank], U64_MAX))
+        # `order` is a permutation of the lanes: sorting by it alone
+        # is the inverse permutation (util.first_new's return sort)
+        _, fh, fl = lax.sort((order, fh, fl), num_keys=1)
+        fps = join_u64(fh, fl)
+    return fps, n_dup, counts

@@ -171,8 +171,7 @@ def stages(args, ref):
         flatc, selv = ref["sparse_rows"], ref["sparse_selv"]
 
     canon = eng.canon
-    if hasattr(canon, "raw_fingerprints"):  # not on a model's own canon
-        out["canon_raw"] = np.asarray(canon.raw_fingerprints(flatc))
+    out["canon_raw"] = np.asarray(canon.raw_fingerprints(flatc))
     out["canon_fp"] = np.where(selv, np.asarray(canon.fingerprints(flatc)), 0)
     fps, n_dup, tiers = jax.jit(canon.fingerprints_dedup)(flatc, selv)
     out.update(canon_fp_dedup=np.asarray(fps),

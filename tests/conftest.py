@@ -101,6 +101,33 @@ def gather_kernels(model, batch=8):
     return found
 
 
+def scope_paths(lowered):
+    """The stage scope paths (``benchmark.xplane.scope_path``: what a
+    trace's reduction names an op by, control flow and transforms left
+    out) of every name stack in a lowered text with debug info."""
+    import re
+
+    from benchmark.xplane import scope_path
+
+    return {scope_path(stack)
+            for stack in re.findall(r'"(jit\([^"]*)"', lowered)}
+
+
+def jaxpr_digest(closed):
+    """(equations, sha256 prefix) of a closed jaxpr's equations, nested
+    ones included, in order: each one's primitive name and the dtypes
+    and shapes of what it puts out."""
+    import hashlib
+
+    digest, n = hashlib.sha256(), 0
+    for eqn in eqns(closed.jaxpr):
+        outs = ",".join(f"{v.aval.dtype}{list(v.aval.shape)}"
+                        for v in eqn.outvars)
+        digest.update(f"{eqn.primitive.name}:{outs};".encode())
+        n += 1
+    return n, digest.hexdigest()[:16]
+
+
 def lower_dedup_canon(model):
     """Lowered text, with debug info, of the engines' canon (in-chunk
     dedup, then the tiers) of ``model`` over a 256-lane batch; nothing

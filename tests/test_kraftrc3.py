@@ -26,7 +26,7 @@ import jax
 import numpy as np
 import pytest
 
-from conftest import eqns
+from conftest import eqns, scope_paths
 from test_kraft_reconfig import SMALLP, small_oracle
 from raft_tpu.models import kraft_reconfig
 from raft_tpu.models.registry import build_from_cfg, oracle_for_setup
@@ -183,10 +183,11 @@ def test_no_gather_and_no_scatter_in_the_canonicalizer_and_its_scopes(setup):
     assert names.count("sort") == 1
     lowered = jax.jit(stage("canon")(canon.fingerprints_dedup)).lower(
         rows, jax.ShapeDtypeStruct((16,), bool)).as_text(debug_info=True)
+    paths = scope_paths(lowered)
     for scope in ("slot_sort", "slot_remap", "slot_bag", "slot_hash"):
-        assert f"/canon/{scope}/" in lowered
+        assert ("canon", scope) in paths  # in the in-chunk dedup's loop
         assert f"({scope})" not in lowered  # not vmap(slot_bag)
-    assert "canon/slot_bag/sort" in lowered
+    assert "/slot_bag/sort" in lowered
 
 
 def test_fingerprints_are_equal_iff_the_oracles_canon_is_under_all_12(
@@ -283,20 +284,158 @@ def test_cli_refuses_the_cfg_and_under_lenient_counts_the_goldens_prefix(
     assert all(w["overflow_bits"] == 0 for w in waves)
 
 
-def test_every_valid_lane_runs_every_permutation_and_the_rows_say_so(cli_run):
-    """`SlotCanonicalizer.fingerprints_dedup` has no in-chunk dedup and no
-    tiers: a wave's `canon_tier3_full` is its valid successor lanes, its
-    duplicates are 0, and the run's total is the golden's generated less
-    Init."""
+def test_permutations_run_once_a_distinct_raw_view_and_the_rows_say_so(
+        cli_run):
+    """`SlotCanonicalizer.fingerprints_dedup` runs the 12 permutations on
+    one lane of each distinct raw view of a chunk-step: a wave's
+    `canon_tier3_full` (the representatives) and `canon_dup_lanes` (the
+    lanes that skipped) add up to its valid successor lanes, duplicates
+    appear from the first wave that has any (depth 2: two servers'
+    timeouts commute) and grow, and there are no tiers."""
     _refused, _rc, out, _err, events = cli_run
     waves = [e for e in events if e["event"] == "wave"]
     assert len(waves) == DEPTH
     for w in waves:
-        assert w["canon_tier3_full"] == w["generated"] > 0
-        assert (w["canon_dup_lanes"], w["canon_tier3_local"]) == (0, 0)
+        assert w["canon_tier3_full"] + w["canon_dup_lanes"] == w["generated"]
+        assert w["canon_tier3_full"] > 0 and w["canon_tier3_local"] == 0
+        assert w["canon_dup_rate"] == pytest.approx(
+            w["canon_dup_lanes"] / w["generated"], abs=1e-4)
+    dups = [w["canon_dup_lanes"] for w in waves]
+    assert dups[0] == 0 and all(d > 0 for d in dups[1:])
+    assert dups == sorted(dups)
     summary = json.loads(out.strip().splitlines()[-1])
-    assert summary["canon_tier3_full"] == summary["total"] - 1 == sum(
-        w["generated"] for w in waves)
+    assert summary["canon_tier3_full"] == summary["total"] - 1 - sum(dups)
+
+
+B_EDGE = 256  # one shape, one program: 4 blocks of 64 lanes
+
+
+@pytest.fixture(scope="module")
+def views(setup, oracle, sample):
+    """A batch's worth of rows of distinct raw views: the sampled states
+    and their images under two host and value permutations."""
+    model = setup.model
+    rows = np.stack([
+        model.encode(oracle.permute(st, sigma, tau))
+        for sigma, tau in (([0, 1, 2], [0, 1]), ([1, 2, 0], [1, 0]),
+                           ([2, 0, 1], [0, 1]))
+        for st in sample]).astype(np.int32)
+    VL = model.layout.view_len
+    _u, first = np.unique(rows[:, :VL], axis=0, return_index=True)
+    rows = rows[np.sort(first)]
+    assert len(rows) >= B_EDGE
+    return rows[:B_EDGE]
+
+
+@pytest.fixture(scope="module")
+def slot_canon(setup):
+    canon = setup.model.make_canonicalizer(True)
+    assert min(B_EDGE, max(64, B_EDGE // canon.BLOCKS)) == 64
+    return canon, jax.jit(canon.fingerprints_dedup)
+
+
+# the edges of the in-chunk dedup -> the distinct raw views among the
+# valid lanes of the batch built for each
+EDGES = {
+    "duplicates_far_apart": 216, "invalid_interleaved": 128,
+    "all_invalid": 0, "one_view_everywhere": 1,
+    "more_representatives_than_a_block": 100,
+    "representatives_fill_one_block_exactly": 64,
+    "representatives_fill_three_blocks_exactly": 192,
+    "every_lane_its_own_view": 256,
+    "duplicates_differ_in_the_aux_lanes": 50,
+}
+
+
+@pytest.fixture(scope="module")
+def edge_batches(views, setup):
+    """name -> (rows [B_EDGE, W], valid [B_EDGE])."""
+    B = B_EDGE
+    VL, W = setup.model.layout.view_len, setup.model.layout.W
+    rng = np.random.default_rng(42)
+    lanes, on = np.arange(B), np.ones(B, bool)
+    out = {}
+    # 20 views at both ends of the batch and in the middle
+    idx = lanes.copy()
+    idx[-20:] = idx[100:120] = idx[:20]
+    out["duplicates_far_apart"] = (views[idx], on)
+    # every other lane invalid, and the invalid lanes hold rows whose
+    # view a valid lane holds too: they must not count as its duplicate
+    out["invalid_interleaved"] = (views[lanes // 2], lanes % 2 == 0)
+    out["all_invalid"] = (views, ~on)
+    out["one_view_everywhere"] = (views[np.zeros(B, int)], on)
+    out["more_representatives_than_a_block"] = (views[lanes % 100], on)
+    out["representatives_fill_one_block_exactly"] = (views[lanes % 64], on)
+    out["representatives_fill_three_blocks_exactly"] = (
+        views[rng.permutation(lanes % 192)], on)
+    out["every_lane_its_own_view"] = (views, on)
+    # raw duplicates that differ past the view: the aux lanes
+    rows = views[lanes % 50].copy()
+    rows[50:, VL:] = rng.integers(0, 7, size=(B - 50, W - VL))
+    out["duplicates_differ_in_the_aux_lanes"] = (rows, on)
+    assert set(out) == set(EDGES)
+    return out
+
+
+@pytest.mark.parametrize("edge", sorted(EDGES))
+def test_inchunk_dedup_is_the_canon_of_every_lane_bit_for_bit(
+        setup, slot_canon, edge_batches, edge):
+    """Dedup decides where the 12 permutations run, never their value:
+    `fingerprints_dedup` is `_fingerprints` on every valid lane, U64_MAX
+    elsewhere, `n_dup` the valid lanes less their distinct raw views and
+    `tiers` [0, representatives]."""
+    from raft_tpu.ops.hashing import U64_MAX
+
+    canon, dedup = slot_canon
+    rows, valid = edge_batches[edge]
+    VL = setup.model.layout.view_len
+    fps, n_dup, tiers = jax.device_get(dedup(rows, valid))
+    want = np.where(valid, np.asarray(canon.fingerprints(rows)),
+                    np.uint64(U64_MAX))
+    assert np.array_equal(fps, want)
+    distinct = len(np.unique(rows[valid][:, :VL], axis=0))
+    assert distinct == EDGES[edge]
+    assert int(n_dup) == int(valid.sum()) - distinct
+    assert [int(t) for t in tiers] == [0, distinct]
+
+
+def test_a_permuted_image_is_no_raw_duplicate_and_both_lanes_run(
+        setup, oracle, sample, slot_canon):
+    """A lane that holds a permuted image of another lane's state shares
+    its fingerprint, not its raw view: the dedup runs the permutations on
+    both, and both come back with the one value."""
+    canon, dedup = slot_canon
+    model = setup.model
+    st = sample[-1]
+    image = oracle.permute(st, [1, 2, 0], [1, 0])
+    pair = np.stack([model.encode(st), model.encode(image)]).astype(np.int32)
+    assert not np.array_equal(pair[0], pair[1])
+    rows = np.tile(pair, (B_EDGE // 2, 1))
+    valid = np.arange(B_EDGE) < 6
+    fps, n_dup, tiers = jax.device_get(dedup(rows, valid))
+    assert len(set(fps[:6].tolist())) == 1
+    assert fps[0] == np.asarray(canon.fingerprints(pair))[0]
+    assert (int(n_dup), [int(t) for t in tiers]) == (4, [0, 2])
+
+
+def test_the_canon_reads_nothing_past_the_view(setup, views):
+    """What makes the hash of the view prefix a sound raw key: rows that
+    differ only in the aux lanes (the counters VIEW leaves out) have one
+    fingerprint, and the raw key is theirs too."""
+    model = setup.model
+    canon = model.make_canonicalizer(True)
+    VL, W = model.layout.view_len, model.layout.W
+    assert W - VL == 7  # four counters and valueCtr, one an epoch
+    rows = views[:64]
+    other = rows.copy()
+    other[:, VL:] = np.random.default_rng(7).integers(
+        -3, 1 << 20, size=(len(rows), W - VL))
+    assert not np.array_equal(rows, other)
+    assert np.array_equal(np.asarray(canon.fingerprints(rows)),
+                          np.asarray(canon.fingerprints(other)))
+    raw = np.asarray(canon.raw_fingerprints(rows))
+    assert np.array_equal(raw, np.asarray(canon.raw_fingerprints(other)))
+    assert len(set(raw.tolist())) == len(rows)
 
 
 def test_golden_is_the_oracles_and_covers_the_cell(golden):
@@ -345,8 +484,10 @@ def test_device_bfs_counts_match_oracle_and_a_second_verdict_compiles_nothing(
         want["distinct"], want["total"], want["terminal"])
     assert not any(w["overflow_bits"] for w in first.metrics)
     full = [w["canon_tier3_full"] for w in first.metrics]
-    assert full == ([w["generated"] for w in first.metrics] if sym
-                    else [0] * DEPTH)
+    dup = [w["canon_dup_lanes"] for w in first.metrics]
+    assert [f + d for f, d in zip(full, dup)] == (
+        [w["generated"] for w in first.metrics] if sym else [0] * DEPTH)
+    assert (sum(dup) > 0) == sym
     assert first.stats["run_compiles"] >= 1
     again = eng.run(max_depth=DEPTH, collect_metrics=True)
     assert again.stats["run_compiles"] == 0

@@ -20,6 +20,7 @@ The correctness contract (module docstring there):
 
 import itertools
 
+import jax
 import numpy as np
 import pytest
 
@@ -30,7 +31,8 @@ from raft_tpu.oracle.raft_oracle import RaftOracle
 from raft_tpu.ops.hashing import U64_MAX
 from raft_tpu.ops.symmetry import Canonicalizer
 
-from conftest import collect_states, lower_dedup_canon
+from conftest import (
+    collect_states, jaxpr_digest, lower_dedup_canon, scope_paths)
 
 
 def raft3():
@@ -425,6 +427,60 @@ def test_inchunk_dedup_lowers_to_sorts_alone(build):
                if "func.func public @main" in ln]
     assert main.count("%arg") == 2 and "ui64" not in main.split("->")[0], main
     assert text.count("stablehlo.sort") >= 2
+
+
+def _kraftrc_small():
+    from raft_tpu.models import kraft_reconfig
+
+    from test_kraft_reconfig import SMALLP
+
+    return (kraft_reconfig.cached_model(SMALLP),)
+
+
+# (equations, digest) of `Canonicalizer.fingerprints_dedup`'s jaxpr over a
+# 256-lane batch at PR 40's tree (ad5b664), where the in-chunk dedup was
+# that method's own lines: conftest.jaxpr_digest
+PARENT_JAXPR = {"raft3": (718, "e7ba52e369f44441"),
+                "flexraft5": (5536, "1aa1dbe5d3cebea0")}
+
+
+@pytest.mark.parametrize("name, build", [
+    ("raft3", raft3), ("flexraft5", flexraft5),
+    ("kraftrc_slot_canon", _kraftrc_small)])
+def test_both_canons_run_their_permutations_under_the_one_inchunk_dedup(
+        name, build):
+    """`ops.symmetry.fingerprints_by_raw_view` is the tree's one in-chunk
+    dedup: `Canonicalizer` and the slot canon of `KRaftWithReconfig` both
+    lower to it (`canon/inchunk` beside the canon's own scopes, which run
+    in its loop's body and stay its siblings), with no per-lane write of
+    its own; and what `Canonicalizer` traces is what it traced when the
+    lines were its method's, equation for equation."""
+    from raft_tpu.obs import stage
+
+    model = build()[0]
+    canon = Canonicalizer.for_model(model, symmetry=True)
+    args = (jax.ShapeDtypeStruct((256, model.layout.W), np.int32),
+            jax.ShapeDtypeStruct((256,), bool))
+    text = jax.jit(stage("canon")(canon.fingerprints_dedup)).lower(
+        *args).as_text(debug_info=True)
+    paths = scope_paths(text)
+    assert ("canon", "inchunk") in paths
+    assert "/inchunk/sort" in text and "/inchunk/while/" not in text
+    assert not [ln for ln in text.splitlines()
+                if "/inchunk/" in ln and "scatter" in ln]
+    if name in PARENT_JAXPR:
+        assert type(canon) is Canonicalizer
+        # three servers have no tiers: the full table on every lane
+        own = {"tier3_full"} | (
+            {"tier12", "tier3_local"} if name == "flexraft5" else set())
+        assert jaxpr_digest(jax.make_jaxpr(canon.fingerprints_dedup)(
+            *args)) == PARENT_JAXPR[name]
+    else:
+        assert type(canon).__name__ == "SlotCanonicalizer"
+        own = {"slot_sort", "slot_remap", "slot_bag", "slot_hash"}
+        assert "scatter" not in text
+        assert "(slot_" not in text  # no scope opened under a vmap
+    assert {p[1] for p in paths if len(p) > 1} == own | {"inchunk"}
 
 
 def test_seeded_family_differs():
