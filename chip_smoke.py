@@ -40,6 +40,14 @@ count against the pure-Python oracle's golden
          fourth model file through the same wave program, the one whose
          canonical fingerprints are not ops/symmetry.py's, its five
          invariants evaluated on every state.
+  leg G  configs/pull-raft/PullRaft.cfg under --lenient (follower-pull
+         replication: 3 servers, 2 values, 6 permutations, 259-lane
+         rows, 85 actions a state, 64 of them over the bag's slots;
+         models/pull_raft.py) to depth 14 against
+         tests/golden/pull3_cfg_depth_counts.json: a fifth model file
+         through the same wave program, at its cell's chunk (a
+         32,768-lane worklist, twice the size at which leg D's lowering
+         lost writes).
 
 This process never imports jax or raft_tpu: a chip belongs to one process
 at a time, so every leg is a child of its own, one after the other, and
@@ -75,6 +83,9 @@ JOINT_CFG = os.path.join(
 KRAFT_CFG = os.path.join(ROOT, "configs", "pull-raft", "KRaft.cfg")
 KRAFTRC_CFG = os.path.join(
     ROOT, "configs", "pull-raft", "KRaftWithReconfig.cfg")
+PULL_GOLDEN = os.path.join(
+    ROOT, "tests", "golden", "pull3_cfg_depth_counts.json")
+PULL_CFG = os.path.join(ROOT, "configs", "pull-raft", "PullRaft.cfg")
 UNSAFE_CFG = os.path.join(
     ROOT, "configs", "flexible-raft", "unsafe-quorums", "FlexibleRaft.cfg")
 SCHEMA_CHECK = os.path.join(ROOT, "scripts", "check_metrics_schema.py")
@@ -264,7 +275,7 @@ def leg_c(dev: dict, golden: dict) -> None:
 
 def cfg_leg(letter: str, cfg: str, chunk: int, dev: dict, golden: dict,
             flags: tuple = ()) -> None:
-    """Legs D, E and F: another model file's cfg through the CLI to its
+    """Legs D to G: another model file's cfg through the CLI to its
     golden's depth, at its cell's chunk, with the flags the cfg needs."""
     depth = golden["max_depth"]
     res = bfs_leg(f"leg{letter}", dev, golden,
@@ -278,8 +289,8 @@ def cfg_leg(letter: str, cfg: str, chunk: int, dev: dict, golden: dict,
 def main() -> int:
     try:
         for path in (GOLDEN, JOINT_GOLDEN, KRAFT_GOLDEN, KRAFTRC_GOLDEN,
-                     TRACE_GOLDEN, RAFT_CFG, JOINT_CFG, KRAFT_CFG, KRAFTRC_CFG,
-                     UNSAFE_CFG, SCHEMA_CHECK,
+                     PULL_GOLDEN, TRACE_GOLDEN, RAFT_CFG, JOINT_CFG, KRAFT_CFG,
+                     KRAFTRC_CFG, PULL_CFG, UNSAFE_CFG, SCHEMA_CHECK,
                      os.path.join(ROOT, "raft_tpu", "__main__.py")):
             check(os.path.exists(path),
                   f"{os.path.relpath(path, ROOT)} is missing: chip_smoke.py "
@@ -295,7 +306,8 @@ def main() -> int:
                 ("D", JOINT_CFG, 1024, JOINT_GOLDEN, ()),
                 ("E", KRAFT_CFG, 2048, KRAFT_GOLDEN, ()),
                 # upstream's cfg declares v1 and uses v2
-                ("F", KRAFTRC_CFG, 1024, KRAFTRC_GOLDEN, ("--lenient",))):
+                ("F", KRAFTRC_CFG, 1024, KRAFTRC_GOLDEN, ("--lenient",)),
+                ("G", PULL_CFG, 2048, PULL_GOLDEN, ("--lenient",))):
             with open(path) as f:
                 cfg_leg(letter, cfg, chunk, dev,
                         json.load(f)["depth_limited"], flags)

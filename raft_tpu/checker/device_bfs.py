@@ -119,12 +119,13 @@ class DeviceBFS:
     # the in-program stats vector, i64[N_STATS]: [wave new count, journal
     # count, cumulative generated, cumulative terminal, overflow bits,
     # then cumulative canon counts: in-chunk duplicates, tier-3 local
-    # lanes, tier-3 full lanes; last the lanes the dedup stage's merged
-    # sort sorted, summed over the wave's chunk-steps]. STATS_KEEP is
-    # what a wave's start keeps of it (the wave-new, overflow and
-    # sorted-lanes lanes reset in-program).
-    N_STATS = 9
-    STATS_KEEP = (0, 1, 1, 1, 0, 1, 1, 1, 0)
+    # lanes, tier-3 full lanes; last the dedup stage's two counts, each
+    # summed over the wave's chunk-steps: the lanes its merged sort
+    # sorted and the query lanes it searched an occupied run with].
+    # STATS_KEEP is what a wave's start keeps of it (the wave-new,
+    # overflow and dedup lanes reset in-program).
+    N_STATS = 10
+    STATS_KEEP = (0, 1, 1, 1, 0, 1, 1, 1, 0, 0)
 
     # Donation contract for the wave program: argument indices of
     # the capacity-shaped loop carries updated in place every dispatch
@@ -399,15 +400,17 @@ class DeviceBFS:
         (util.first_new; a seen run past its crossover is still
         searched, under ``occ``). A fingerprint chunk k appended is a
         run lane for chunk k + 1, so cross-chunk in-wave dedup falls out
-        of the same lookup. Returns (new, lanes that sort sorted)."""
-        return first_new(
+        of the same lookup. Returns (new, i32[2]: the lanes that sort
+        sorted and the query lanes searched against an occupied run)."""
+        new, lanes, queries = first_new(
             fps, occ, runs, wave=(wave_new, ncount, self._wave_prefix()))
+        return new, jnp.stack([lanes, queries])
 
     @stage("emit")
     def _st_finish(
         self, next_buf, jparent, jcand, viol, stats, cov, wave_new,
         flatc, fps, sel, valid, rank, new, n_gen, terminal, expand_ovf,
-        compact_ovf, canon_n, sort_lanes, cursor, base_gid,
+        compact_ovf, canon_n, dedup_n, cursor, base_gid,
     ):
         """Stages 4b-6: per-action coverage, the cursor-append emit of
         rows, journal and new fingerprints, invariants on the new states
@@ -502,7 +505,7 @@ class DeviceBFS:
                 stats[3] + terminal,
                 stats[4] | ovf_bits,
                 *(stats[5:8] + canon_n),
-                stats[8] + sort_lanes,
+                *(stats[8:10] + dedup_n),
             ]
         )
         return next_buf, jparent, jcand, viol, stats, cov, wave_new
@@ -526,12 +529,12 @@ class DeviceBFS:
         (flatc, sel, selv, valid, rank, n_gen, terminal, expand_ovf,
          compact_ovf) = self._st_expand(frontier, cursor, fcount)
         fps, canon_n = self._st_canon(flatc, selv)
-        new, sort_lanes = self._st_dedup(
+        new, dedup_n = self._st_dedup(
             fps, occ, wave_new, stats[0].astype(jnp.int32), *runs)
         return self._st_finish(
             next_buf, jparent, jcand, viol, stats, cov, wave_new, flatc,
             fps, sel, valid, rank, new, n_gen, terminal, expand_ovf,
-            compact_ovf, canon_n, sort_lanes, cursor, base_gid,
+            compact_ovf, canon_n, dedup_n, cursor, base_gid,
         )
 
     def _wave_prefix(self) -> tuple[int, ...]:
@@ -948,7 +951,7 @@ class DeviceBFS:
             MemWatch(tel, device_budget(jax.devices()[0]))
             if tel.active else None
         )
-        sort_lanes_run = 0
+        sort_lanes_run = search_queries_run = 0
 
         while fcount and violation is None:
             if preempt is not None and preempt.requested:
@@ -1013,6 +1016,7 @@ class DeviceBFS:
             # one buffer, merged into the single seen run
             # below AFTER the overflow check (so an aborted wave leaves
             # the seen-set untouched and the run trivially resumable).
+            seen_lanes = int(self._seen.shape[0])  # before this wave's merge
             with tel.wave_annotation(depth + 1):
                 with ph("dispatch"):
                     out = self._wave_fn(
@@ -1127,6 +1131,7 @@ class DeviceBFS:
                 int(x) for x in stats_h[5:8] - canon_prev)
             canon_prev = stats_h[5:8].copy()
             sort_lanes_run += int(stats_h[8])
+            search_queries_run += int(stats_h[9])
             wave_s_val = time.perf_counter() - tw
             # the wave's brackets, read once: each phase's seconds are
             # those of its span. device_s is the host's WAIT on the
@@ -1179,6 +1184,11 @@ class DeviceBFS:
                     # the wave already fetched): the seen run, the
                     # prefix of the wave's buffer each step chose and VC
                     "dedup_sort_lanes": int(stats_h[8]),
+                    # query lanes those steps searched the seen run with
+                    # (lane 9; 0 while the run is merged), and the run's
+                    # size as the wave met it
+                    "dedup_search_queries": int(stats_h[9]),
+                    "seen_lanes": seen_lanes,
                     "overflow_bits": ovf_bits,
                     "wave_s": wave_s_val,
                     "elapsed_s": el,
@@ -1276,6 +1286,7 @@ class DeviceBFS:
             "canon_tier3_local": int(canon_prev[1]),
             "canon_tier3_full": int(canon_prev[2]),
             "dedup_sort_lanes": sort_lanes_run,
+            "dedup_search_queries": search_queries_run,
         }
         tel.close_run({
             "engine": "device",

@@ -135,9 +135,11 @@ def first_new(vals, occ, runs, wave=None):
     real). What is sorted is the smallest prefix that holds ``count``
     lanes, by a ``lax.switch`` over the sizes, so the sorts cost what
     the wave has written and not what it could hold. With ``wave`` the
-    result is ``(new, lanes)``, lanes the i32 number of lanes the
-    merged sort sorted; without, the program is the one it always
-    was."""
+    result is ``(new, lanes, queries)``: lanes the i32 number of lanes
+    the merged sort sorted, queries the i32 number of query lanes
+    handed to ``probe_sorted`` (all ``n`` for each searched run that
+    ``occ`` says is occupied; 0 while every run is merged); without,
+    the program is the one it always was."""
     n = vals.shape[0]
     assert n < 1 << 31
     merged = [r for r in runs if merges(r.shape[0], n)]
@@ -157,8 +159,10 @@ def first_new(vals, occ, runs, wave=None):
             case = sum((count > p).astype(jnp.int32) for p in sizes[:-1])
             new, lanes = lax.switch(
                 case, [prefix(p) for p in sizes], buf, vals, *merged)
+    queries = jnp.int32(0)
     with jax.named_scope("search"):
         for i, r in searched:
+            queries = queries + jnp.where(occ[i], jnp.int32(n), 0)
             hit = lax.cond(
                 occ[i],
                 lambda rr: probe_sorted(rr, vals),
@@ -169,7 +173,7 @@ def first_new(vals, occ, runs, wave=None):
                 r,
             )
             new = new & ~hit
-    return new if wave is None else (new, lanes)
+    return new if wave is None else (new, lanes, queries)
 
 
 def rank_onehot(rank, mask, n_ranks: int):

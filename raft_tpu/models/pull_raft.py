@@ -37,6 +37,11 @@ from .base import (
     Layout,
     SparseExpandMixin,
     messages_are_valid_kernel,
+    onehot_add,
+    onehot_get2,
+    onehot_row,
+    onehot_set,
+    onehot_set2,
 )
 
 FOLLOWER, CANDIDATE, LEADER = 0, 1, 2
@@ -238,11 +243,20 @@ class PullRaftModel(SparseExpandMixin, FleetConstMixin, ActionLabelMixin):
         hi, lo = self.packer.pack(**vals)
         return jnp.asarray(hi, jnp.int32), jnp.asarray(lo, jnp.int32)
 
+    # Every read and write through a traced index (a binding, a decoded
+    # server, a log position, the bag slot) is a one-hot select
+    # (models/base.py): under the worklist's vmap `arr[i]` is a per-lane
+    # gather and `arr.at[i].set` a batched scatter, which the v5e's
+    # compiler drops writes from at a wide worklist (PR 30). Positions
+    # are clipped into their axis first; an EMPTY bag word decodes to 0
+    # in every field, so no decoded server leaves its axis either.
+
     @staticmethod
     def _last_term(d, i):
         """LastTerm(log[i]) — PullRaft.tla:134."""
-        ll = d["log_len"][i]
-        return jnp.where(ll > 0, d["log_term"][i][jnp.clip(ll - 1, 0)], 0)
+        ll = onehot_row(d["log_len"], i)
+        row = onehot_row(d["log_term"], i)
+        return jnp.where(ll > 0, onehot_row(row, jnp.clip(ll - 1, 0)), 0)
 
     def _last_common(self, lt_row, ll, last_idx, last_term):
         """LastCommonEntry — PullRaft.tla:211-226. Highest index k in
@@ -255,7 +269,8 @@ class PullRaftModel(SparseExpandMixin, FleetConstMixin, ActionLabelMixin):
             (lt_row < last_term) | ((lt_row == last_term) & (lanes <= last_idx))
         )
         idx = jnp.max(jnp.where(ok, lanes, 0))
-        term = jnp.where(idx > 0, lt_row[jnp.clip(idx - 1, 0, L - 1)], 0)
+        term = jnp.where(
+            idx > 0, onehot_row(lt_row, jnp.clip(idx - 1, 0, L - 1)), 0)
         return idx, term
 
     # ---------------- action kernels ----------------
@@ -267,18 +282,18 @@ class PullRaftModel(SparseExpandMixin, FleetConstMixin, ActionLabelMixin):
         p, S = self.p, self.p.n_servers
         d = self._dec(s)
         valid = d["restartCtr"] < self._cv(d, "max_restarts")
+        zeros = jnp.zeros((S,), jnp.int32)
         upd = dict(
-            state=d["state"].at[i].set(FOLLOWER),
-            votesGranted=d["votesGranted"].at[i].set(0),
-            matchIndex=d["matchIndex"].at[i].set(jnp.zeros((S,), jnp.int32)),
-            commitIndex=d["commitIndex"].at[i].set(0),
+            state=onehot_set(d["state"], i, FOLLOWER),
+            votesGranted=onehot_set(d["votesGranted"], i, 0),
+            matchIndex=onehot_set(d["matchIndex"], i, zeros),
+            commitIndex=onehot_set(d["commitIndex"], i, 0),
             restartCtr=d["restartCtr"] + 1,
         )
         if p.variant2:
-            upd["leader"] = d["leader"].at[i].set(NIL)
-            upd["vle_has"] = d["vle_has"].at[i].set(jnp.zeros((S,), jnp.int32))
-            upd["vle_idx"] = d["vle_idx"].at[i].set(jnp.zeros((S,), jnp.int32))
-            upd["vle_term"] = d["vle_term"].at[i].set(jnp.zeros((S,), jnp.int32))
+            upd["leader"] = onehot_set(d["leader"], i, NIL)
+            for f in ("vle_has", "vle_idx", "vle_term"):
+                upd[f] = onehot_set(d[f], i, zeros)
         succ = self._asm(d, **upd)
         return valid, succ, jnp.int32(R_RESTART), jnp.asarray(False)
 
@@ -287,13 +302,13 @@ class PullRaftModel(SparseExpandMixin, FleetConstMixin, ActionLabelMixin):
         Variant2 (PullRaftVariant2.tla:279-295): votedFor := i, leader := Nil."""
         p, S = self.p, self.p.n_servers
         d = self._dec(s)
-        st_i = d["state"][i]
+        st_i = onehot_row(d["state"], i)
         valid = (d["electionCtr"] < self._cv(d, "max_elections")) & (
             (st_i == FOLLOWER) | (st_i == CANDIDATE)
         )
-        new_term = d["currentTerm"][i] + 1
+        new_term = onehot_row(d["currentTerm"], i) + 1
         last_t = self._last_term(d, i)
-        ll_i = d["log_len"][i]
+        ll_i = onehot_row(d["log_len"], i)
         hi, lo, cnt = d["msg_hi"], d["msg_lo"], d["msg_cnt"]
         ovf = jnp.asarray(False)
         for delta in range(1, S):
@@ -310,19 +325,19 @@ class PullRaftModel(SparseExpandMixin, FleetConstMixin, ActionLabelMixin):
             valid &= ~existed  # SendMultiple (PullRaft.tla:141-143)
             ovf |= o
         upd = dict(
-            state=d["state"].at[i].set(CANDIDATE),
-            currentTerm=d["currentTerm"].at[i].set(new_term),
-            votesGranted=d["votesGranted"].at[i].set(jnp.int32(1) << i),
+            state=onehot_set(d["state"], i, CANDIDATE),
+            currentTerm=onehot_set(d["currentTerm"], i, new_term),
+            votesGranted=onehot_set(d["votesGranted"], i, jnp.int32(1) << i),
             electionCtr=d["electionCtr"] + 1,
             msg_hi=hi,
             msg_lo=lo,
             msg_cnt=cnt,
         )
         if p.variant2:
-            upd["votedFor"] = d["votedFor"].at[i].set(i + 1)
-            upd["leader"] = d["leader"].at[i].set(NIL)
+            upd["votedFor"] = onehot_set(d["votedFor"], i, i + 1)
+            upd["leader"] = onehot_set(d["leader"], i, NIL)
         else:
-            upd["leader"] = d["leader"].at[i].set(i + 1)
+            upd["leader"] = onehot_set(d["leader"], i, i + 1)
         succ = self._asm(d, **upd)
         return valid, succ, jnp.int32(R_REQUESTVOTE), ovf & valid
 
@@ -332,24 +347,26 @@ class PullRaftModel(SparseExpandMixin, FleetConstMixin, ActionLabelMixin):
         notify ALL peers with embedded mlastCommonEntry, leader[i] := i."""
         p, S = self.p, self.p.n_servers
         d = self._dec(s)
-        votes = jnp.sum((d["votesGranted"][i] >> jnp.arange(S, dtype=jnp.int32)) & 1)
-        valid = (d["state"][i] == CANDIDATE) & (2 * votes > S)
+        vg_i = onehot_row(d["votesGranted"], i)
+        votes = jnp.sum((vg_i >> jnp.arange(S, dtype=jnp.int32)) & 1)
+        valid = (onehot_row(d["state"], i) == CANDIDATE) & (2 * votes > S)
+        ct_i = onehot_row(d["currentTerm"], i)
         hi, lo, cnt = d["msg_hi"], d["msg_lo"], d["msg_cnt"]
         ovf = jnp.asarray(False)
         for delta in range(1, S):
             j = jnp.mod(i + delta, S)
             if p.variant2:
                 send_j = jnp.asarray(True)
-                has = d["vle_has"][i, j] > 0
+                has = onehot_get2(d["vle_has"], i, j) > 0
                 lce_i, lce_t = self._last_common(
-                    d["log_term"][i],
-                    d["log_len"][i],
-                    d["vle_idx"][i, j],
-                    d["vle_term"][i, j],
+                    onehot_row(d["log_term"], i),
+                    onehot_row(d["log_len"], i),
+                    onehot_get2(d["vle_idx"], i, j),
+                    onehot_get2(d["vle_term"], i, j),
                 )
                 khi, klo = self._pack(
                     mtype=NOTIFY,
-                    mterm=d["currentTerm"][i],
+                    mterm=ct_i,
                     mlcHas=has.astype(jnp.int32),
                     mlcIndex=jnp.where(has, lce_i, 0),
                     mlcTerm=jnp.where(has, lce_t, 0),
@@ -358,9 +375,9 @@ class PullRaftModel(SparseExpandMixin, FleetConstMixin, ActionLabelMixin):
                 )
             else:
                 # only peers that did NOT vote for i (PullRaft.tla:364)
-                send_j = ((d["votesGranted"][i] >> j) & 1) == 0
+                send_j = ((vg_i >> j) & 1) == 0
                 khi, klo = self._pack(
-                    mtype=NOTIFY, mterm=d["currentTerm"][i], msource=i, mdest=j
+                    mtype=NOTIFY, mterm=ct_i, msource=i, mdest=j
                 )
             nhi, nlo, ncnt, existed, o = bag.bag_put(hi, lo, cnt, khi, klo)
             valid &= ~(existed & send_j)
@@ -369,14 +386,15 @@ class PullRaftModel(SparseExpandMixin, FleetConstMixin, ActionLabelMixin):
             lo = jnp.where(send_j, nlo, lo)
             cnt = jnp.where(send_j, ncnt, cnt)
         upd = dict(
-            state=d["state"].at[i].set(LEADER),
-            matchIndex=d["matchIndex"].at[i].set(jnp.zeros((S,), jnp.int32)),
+            state=onehot_set(d["state"], i, LEADER),
+            matchIndex=onehot_set(
+                d["matchIndex"], i, jnp.zeros((S,), jnp.int32)),
             msg_hi=hi,
             msg_lo=lo,
             msg_cnt=cnt,
         )
         if p.variant2:
-            upd["leader"] = d["leader"].at[i].set(i + 1)
+            upd["leader"] = onehot_set(d["leader"], i, i + 1)
         succ = self._asm(d, **upd)
         return valid, succ, jnp.int32(R_BECOMELEADER), ovf & valid
 
@@ -384,27 +402,30 @@ class PullRaftModel(SparseExpandMixin, FleetConstMixin, ActionLabelMixin):
         """ClientRequest(i, v) — PullRaft.tla:370-379."""
         L = self.p.max_log
         d = self._dec(s)
-        valid = (d["state"][i] == LEADER) & (d["acked"][v] == ACK_NIL)
-        pos = d["log_len"][i]
+        valid = (onehot_row(d["state"], i) == LEADER) & (
+            onehot_row(d["acked"], v) == ACK_NIL)
+        pos = onehot_row(d["log_len"], i)
         ovf = valid & (pos >= L)
         posc = jnp.clip(pos, 0, L - 1)
         succ = self._asm(
             d,
-            log_term=d["log_term"].at[i, posc].set(d["currentTerm"][i]),
-            log_value=d["log_value"].at[i, posc].set(v + 1),
-            log_len=d["log_len"].at[i].add(1),
-            acked=d["acked"].at[v].set(ACK_FALSE),
+            log_term=onehot_set2(
+                d["log_term"], i, posc, onehot_row(d["currentTerm"], i)),
+            log_value=onehot_set2(d["log_value"], i, posc, v + 1),
+            log_len=onehot_add(d["log_len"], i, 1),
+            acked=onehot_set(d["acked"], v, ACK_FALSE),
         )
         return valid, succ, jnp.int32(R_CLIENTREQUEST), ovf
 
     def _send_pull(self, s, i, j):
         """SendPullEntriesRequest(i, j) — PullRaft.tla:396-411."""
         d = self._dec(s)
-        valid = (d["state"][i] == FOLLOWER) & (d["leader"][i] == j + 1)
+        valid = (onehot_row(d["state"], i) == FOLLOWER) & (
+            onehot_row(d["leader"], i) == j + 1)
         khi, klo = self._pack(
             mtype=PULLREQ,
-            mterm=d["currentTerm"][i],
-            mlastLogIndex=d["log_len"][i],
+            mterm=onehot_row(d["currentTerm"], i),
+            mlastLogIndex=onehot_row(d["log_len"], i),
             mlastLogTerm=self._last_term(d, i),
             msource=i,
             mdest=j,
@@ -420,40 +441,34 @@ class PullRaftModel(SparseExpandMixin, FleetConstMixin, ActionLabelMixin):
     # The eight receipt disjuncts (UpdateTerm, HandleRVReq, HandleRVResp,
     # RejectPull, AcceptPull, LearnOfLeader, HandleSuccessPull,
     # HandleFailPull) are mutually exclusive per record: they partition on
-    # mtype, the term comparison, ValidPullPosition and msuccess.
+    # mtype, the term comparison, ValidPullPosition and msuccess. Being
+    # exclusive, the three replying branches share ONE bag_put on the
+    # branch-selected response and the successor assembles once, field
+    # by field (the shape kraft.py's receipt kernel has).
 
     def _handle_message(self, s, m):
         p, packer = self.p, self.packer
         S, L, V = p.n_servers, p.max_log, p.n_values
         d = self._dec(s)
         hi, lo, cnt = d["msg_hi"], d["msg_lo"], d["msg_cnt"]
-        khi, klo, kcnt = hi[m], lo[m], cnt[m]
+        khi, klo, kcnt = onehot_row(hi, m), onehot_row(lo, m), onehot_row(cnt, m)
         occupied = khi != EMPTY
         u = partial(packer.unpack, khi, klo)
         mtype, mterm = u("mtype"), u("mterm")
         src, dst = u("msource"), u("mdest")
-        ct_dst = d["currentTerm"][dst]
-        st_dst = d["state"][dst]
+        ct_dst = onehot_row(d["currentTerm"], dst)
+        st_dst = onehot_row(d["state"], dst)
         recv = occupied & (kcnt > 0)  # ReceivableMessage (PullRaft.tla:166-172)
-        ll_dst = d["log_len"][dst]
-        lt_dst = d["log_term"][dst]
-        lv_dst = d["log_value"][dst]
+        ll_dst = onehot_row(d["log_len"], dst)
+        lt_dst = onehot_row(d["log_term"], dst)
+        lv_dst = onehot_row(d["log_value"], dst)
+        ci_dst = onehot_row(d["commitIndex"], dst)
+        cnt_disc = bag.bag_discard_at(cnt, m)
+        lanes0 = jnp.arange(L, dtype=jnp.int32)
 
-        def reply(resp_hi, resp_lo):
-            """Reply — PullRaft.tla:158-161 (response must be absent)."""
-            c2 = bag.bag_discard_at(cnt, m)
-            return bag.bag_put(hi, lo, c2, resp_hi, resp_lo)
-
-        # --- UpdateTerm (PullRaft.tla:269-276): count-0 records included.
+        # --- UpdateTerm (PullRaft.tla:269-276): count-0 records included;
+        # the message stays in the bag.
         b_upd = occupied & (mterm > ct_dst)
-        upd_u = dict(
-            currentTerm=d["currentTerm"].at[dst].set(mterm),
-            state=d["state"].at[dst].set(FOLLOWER),
-            leader=d["leader"].at[dst].set(NIL),
-        )
-        if p.variant2:
-            upd_u["votedFor"] = d["votedFor"].at[dst].set(NIL)
-        s_upd = self._asm(d, **upd_u)
 
         # --- HandleRequestVoteRequest (PullRaft.tla:306-330;
         # PullRaftVariant2.tla:303-326)
@@ -461,11 +476,12 @@ class PullRaftModel(SparseExpandMixin, FleetConstMixin, ActionLabelMixin):
         rv_logok = (u("mlastLogTerm") > last_t) | (
             (u("mlastLogTerm") == last_t) & (u("mlastLogIndex") >= ll_dst)
         )
-        vote_var = d["votedFor"] if p.variant2 else d["leader"]
+        vote_name = "votedFor" if p.variant2 else "leader"
+        vote_dst = onehot_row(d[vote_name], dst)
         grant = (
             (mterm == ct_dst)
             & rv_logok
-            & ((vote_var[dst] == NIL) | (vote_var[dst] == src + 1))
+            & ((vote_dst == NIL) | (vote_dst == src + 1))
         )
         b_rvreq = recv & (mtype == RVREQ) & (mterm <= ct_dst)
         resp_kw = dict(
@@ -478,38 +494,12 @@ class PullRaftModel(SparseExpandMixin, FleetConstMixin, ActionLabelMixin):
         if p.variant2:  # response carries last entry (PullRaftVariant2.tla:320-321)
             resp_kw["mlastLogIndex"] = ll_dst
             resp_kw["mlastLogTerm"] = last_t
-        rhi, rlo = self._pack(**resp_kw)
-        hi1, lo1, cnt1, ex1, ovf1 = reply(rhi, rlo)
-        b_rvreq &= ~ex1
-        upd_rv = dict(msg_hi=hi1, msg_lo=lo1, msg_cnt=cnt1)
-        granted_var = jnp.where(grant, vote_var.at[dst].set(src + 1), vote_var)
-        if p.variant2:
-            upd_rv["votedFor"] = granted_var
-        else:
-            upd_rv["leader"] = granted_var
-        s_rvreq = self._asm(d, **upd_rv)
+        rv_key = self._pack(**resp_kw)
 
         # --- HandleRequestVoteResponse (PullRaft.tla:335-350;
         # Variant2 also records votesLastEntry, PullRaftVariant2.tla:339-344)
         b_rvresp = recv & (mtype == RVRESP) & (mterm == ct_dst)
-        g = u("mvoteGranted") > 0
-        vg = jnp.where(
-            g,
-            d["votesGranted"].at[dst].set(d["votesGranted"][dst] | (jnp.int32(1) << src)),
-            d["votesGranted"],
-        )
-        upd_rvr = dict(votesGranted=vg, msg_cnt=bag.bag_discard_at(cnt, m))
-        if p.variant2:
-            upd_rvr["vle_has"] = jnp.where(
-                g, d["vle_has"].at[dst, src].set(1), d["vle_has"]
-            )
-            upd_rvr["vle_idx"] = jnp.where(
-                g, d["vle_idx"].at[dst, src].set(u("mlastLogIndex")), d["vle_idx"]
-            )
-            upd_rvr["vle_term"] = jnp.where(
-                g, d["vle_term"].at[dst, src].set(u("mlastLogTerm")), d["vle_term"]
-            )
-        s_rvresp = self._asm(d, **upd_rvr)
+        rvresp_grant = b_rvresp & (u("mvoteGranted") > 0)
 
         # --- pull-request handling: ValidPullPosition (PullRaft.tla:192-196)
         pull_idx = u("mlastLogIndex")
@@ -517,14 +507,14 @@ class PullRaftModel(SparseExpandMixin, FleetConstMixin, ActionLabelMixin):
         valid_pos = (pull_idx == 0) | (
             (pull_idx > 0)
             & (pull_idx <= ll_dst)
-            & (pull_term == lt_dst[jnp.clip(pull_idx - 1, 0, L - 1)])
+            & (pull_term == onehot_row(lt_dst, jnp.clip(pull_idx - 1, 0, L - 1)))
         )
         is_pullreq = recv & (mtype == PULLREQ) & (mterm == ct_dst) & (st_dst == LEADER)
 
         # --- RejectPullEntriesRequest (PullRaft.tla:418-436)
         b_reject = is_pullreq & ~valid_pos
         lce_i, lce_t = self._last_common(lt_dst, ll_dst, pull_idx, pull_term)
-        rjhi, rjlo = self._pack(
+        rj_key = self._pack(
             mtype=PULLRESP,
             mterm=ct_dst,
             msuccess=0,
@@ -534,28 +524,23 @@ class PullRaftModel(SparseExpandMixin, FleetConstMixin, ActionLabelMixin):
             msource=dst,
             mdest=src,
         )
-        hi2, lo2, cnt2, ex2, ovf2 = reply(rjhi, rjlo)
-        b_reject &= ~ex2
-        s_reject = self._asm(d, msg_hi=hi2, msg_lo=lo2, msg_cnt=cnt2)
 
         # --- AcceptPullEntriesRequest (PullRaft.tla:460-488)
         index = pull_idx + 1
         b_accept = is_pullreq & valid_pos & (index <= ll_dst)
-        new_match = d["matchIndex"].at[dst, src].set(pull_idx)
+        new_match = onehot_set(onehot_row(d["matchIndex"], dst), src, pull_idx)
         # NewCommitIndex (PullRaft.tla:446-458)
         idxs = jnp.arange(1, L + 1, dtype=jnp.int32)
         self_in = jnp.arange(S, dtype=jnp.int32)[None, :] == dst
-        agree = self_in | (new_match[dst][None, :] >= idxs[:, None])
+        agree = self_in | (new_match[None, :] >= idxs[:, None])
         quorum_ok = 2 * jnp.sum(agree, axis=1) > S
         is_agree = quorum_ok & (idxs <= ll_dst)
         max_agree = jnp.max(jnp.where(is_agree, idxs, 0))
-        term_at = lt_dst[jnp.clip(max_agree - 1, 0, L - 1)]
-        ci_dst = d["commitIndex"][dst]
+        term_at = onehot_row(lt_dst, jnp.clip(max_agree - 1, 0, L - 1))
         new_ci = jnp.where(
             (max_agree > 0) & (term_at == ct_dst), max_agree, ci_dst
         )
         # acked[v]: FALSE -> v committed in (ci, new_ci] (PullRaft.tla:476-479)
-        lanes0 = jnp.arange(L, dtype=jnp.int32)
         in_range = (lanes0 + 1 > ci_dst) & (lanes0 + 1 <= new_ci)
         committed = jnp.any(
             in_range[None, :]
@@ -564,99 +549,120 @@ class PullRaftModel(SparseExpandMixin, FleetConstMixin, ActionLabelMixin):
         )
         acked2 = jnp.where((d["acked"] == ACK_FALSE) & committed, ACK_TRUE, d["acked"])
         epos = jnp.clip(index - 1, 0, L - 1)
-        achi, aclo = self._pack(
+        ac_key = self._pack(
             mtype=PULLRESP,
             mterm=ct_dst,
             msuccess=1,
             nentries=1,
-            eterm=lt_dst[epos],
-            evalue=lv_dst[epos],
+            eterm=onehot_row(lt_dst, epos),
+            evalue=onehot_row(lv_dst, epos),
             mcommitIndex=jnp.minimum(new_ci, index),
             msource=dst,
             mdest=src,
         )
-        hi3, lo3, cnt3, ex3, ovf3 = reply(achi, aclo)
-        b_accept &= ~ex3
-        s_accept = self._asm(
-            d,
-            matchIndex=new_match,
-            commitIndex=d["commitIndex"].at[dst].set(new_ci),
-            acked=acked2,
-            msg_hi=hi3,
-            msg_lo=lo3,
-            msg_cnt=cnt3,
-        )
+
+        # Reply — PullRaft.tla:158-161: discard the request, send the
+        # branch's response, which must be absent
+        replying = b_rvreq | b_reject | b_accept
+        resp_hi, resp_lo = rv_key
+        for b, (k_hi, k_lo) in ((b_reject, rj_key), (b_accept, ac_key)):
+            resp_hi = jnp.where(b, k_hi, resp_hi)
+            resp_lo = jnp.where(b, k_lo, resp_lo)
+        hi_r, lo_r, cnt_r, existed, put_ovf = bag.bag_put(
+            hi, lo, cnt_disc, resp_hi, resp_lo)
+        b_rvreq &= ~existed
+        b_reject &= ~existed
+        b_accept &= ~existed
 
         # --- LearnOfLeader (PullRaft.tla:383-391; Variant2 may truncate,
         # PullRaftVariant2.tla:398-410)
         b_learn = recv & (mtype == NOTIFY) & (mterm == ct_dst)
-        upd_learn = dict(
-            leader=d["leader"].at[dst].set(src + 1),
-            msg_cnt=bag.bag_discard_at(cnt, m),
-        )
-        if p.variant2:
-            # NeedsTruncation (PullRaftVariant2.tla:171-173): mlcHas and
-            # Len(log) >= index; TruncateLog to the index (:176-179).
-            mlc_has = u("mlcHas") > 0
-            mlc_idx = u("mlcIndex")
-            do_trunc = mlc_has & (ll_dst >= mlc_idx)
-            new_ll_l = jnp.where(do_trunc, mlc_idx, ll_dst)
-            keep = lanes0 < new_ll_l
-            upd_learn["log_term"] = d["log_term"].at[dst].set(
-                jnp.where(keep, lt_dst, 0)
-            )
-            upd_learn["log_value"] = d["log_value"].at[dst].set(
-                jnp.where(keep, lv_dst, 0)
-            )
-            upd_learn["log_len"] = d["log_len"].at[dst].set(new_ll_l)
-        s_learn = self._asm(d, **upd_learn)
 
         # --- HandleSuccessPullEntriesResponse (PullRaft.tla:493-503)
         is_pullresp = recv & (mtype == PULLRESP) & (mterm == ct_dst)
         b_succ = is_pullresp & (u("msuccess") > 0)
         app_pos = jnp.clip(ll_dst, 0, L - 1)
         suc_ovf = b_succ & (ll_dst >= L)
-        s_succ = self._asm(
-            d,
-            commitIndex=d["commitIndex"].at[dst].set(u("mcommitIndex")),
-            log_term=d["log_term"].at[dst, app_pos].set(u("eterm")),
-            log_value=d["log_value"].at[dst, app_pos].set(u("evalue")),
-            log_len=d["log_len"].at[dst].add(1),
-            msg_cnt=bag.bag_discard_at(cnt, m),
-        )
 
         # --- HandleFailPullEntriesResponse (PullRaft.tla:510-520):
         # TruncateLog to mlastCommonEntry.index (clamped to Len).
         b_fail = is_pullresp & (u("msuccess") == 0)
-        new_ll_f = jnp.minimum(u("mlcIndex"), ll_dst)
-        keep_f = lanes0 < new_ll_f
-        s_fail = self._asm(
-            d,
-            log_term=d["log_term"].at[dst].set(jnp.where(keep_f, lt_dst, 0)),
-            log_value=d["log_value"].at[dst].set(jnp.where(keep_f, lv_dst, 0)),
-            log_len=d["log_len"].at[dst].set(new_ll_f),
-            msg_cnt=bag.bag_discard_at(cnt, m),
-        )
+        trunc, new_ll = b_fail, jnp.minimum(u("mlcIndex"), ll_dst)
+        if p.variant2:
+            # NeedsTruncation (PullRaftVariant2.tla:171-173): mlcHas and
+            # Len(log) >= index; TruncateLog to the index (:176-179).
+            do_trunc = (u("mlcHas") > 0) & (ll_dst >= u("mlcIndex"))
+            new_ll = jnp.where(
+                b_learn, jnp.where(do_trunc, u("mlcIndex"), ll_dst), new_ll)
+            trunc = trunc | b_learn
+        keep = lanes0 < new_ll
 
-        branches = [
-            (b_upd, s_upd, R_UPDATETERM, jnp.asarray(False)),
-            (b_rvreq, s_rvreq, R_HANDLE_RVREQ, ovf1),
-            (b_rvresp, s_rvresp, R_HANDLE_RVRESP, jnp.asarray(False)),
-            (b_reject, s_reject, R_REJECT_PULL, ovf2),
-            (b_accept, s_accept, R_ACCEPT_PULL, ovf3),
-            (b_learn, s_learn, R_LEARNOFLEADER, jnp.asarray(False)),
-            (b_succ, s_succ, R_HANDLE_SUCCESS_PULL, suc_ovf),
-            (b_fail, s_fail, R_HANDLE_FAIL_PULL, jnp.asarray(False)),
-        ]
+        # ---- the successor, field by field ----
+        def where_set(b, arr, val):
+            return jnp.where(b, onehot_set(arr, dst, val), arr)
+
+        upd = dict(
+            currentTerm=where_set(b_upd, d["currentTerm"], mterm),
+            state=where_set(b_upd, d["state"], FOLLOWER),
+            votesGranted=where_set(
+                rvresp_grant, d["votesGranted"],
+                onehot_row(d["votesGranted"], dst) | (jnp.int32(1) << src)),
+            log_term=jnp.where(
+                b_succ,
+                onehot_set2(d["log_term"], dst, app_pos, u("eterm")),
+                where_set(trunc, d["log_term"], jnp.where(keep, lt_dst, 0))),
+            log_value=jnp.where(
+                b_succ,
+                onehot_set2(d["log_value"], dst, app_pos, u("evalue")),
+                where_set(trunc, d["log_value"], jnp.where(keep, lv_dst, 0))),
+            log_len=jnp.where(
+                b_succ,
+                onehot_add(d["log_len"], dst, 1),
+                where_set(trunc, d["log_len"], new_ll)),
+            commitIndex=where_set(
+                b_accept | b_succ, d["commitIndex"],
+                jnp.where(b_succ, u("mcommitIndex"), new_ci)),
+            matchIndex=where_set(b_accept, d["matchIndex"], new_match),
+            acked=jnp.where(b_accept, acked2, d["acked"]),
+            msg_hi=jnp.where(replying, hi_r, hi),
+            msg_lo=jnp.where(replying, lo_r, lo),
+            msg_cnt=jnp.where(
+                replying, cnt_r, jnp.where(b_upd, cnt, cnt_disc)),
+        )
+        # the vote (leader, or Variant2's votedFor): Nil on UpdateTerm,
+        # the requester on a granted vote; leader also the notifier's
+        vote_to = b_rvreq & grant
+        if p.variant2:
+            upd["votedFor"] = where_set(
+                b_upd | vote_to, d["votedFor"], jnp.where(b_upd, NIL, src + 1))
+            upd["leader"] = where_set(
+                b_upd | b_learn, d["leader"], jnp.where(b_upd, NIL, src + 1))
+            for f, val in (("vle_has", 1), ("vle_idx", u("mlastLogIndex")),
+                           ("vle_term", u("mlastLogTerm"))):
+                upd[f] = jnp.where(
+                    rvresp_grant, onehot_set2(d[f], dst, src, val), d[f])
+        else:
+            upd["leader"] = where_set(
+                b_upd | vote_to | b_learn, d["leader"],
+                jnp.where(b_upd, NIL, src + 1))
+        succ = self._asm(d, **upd)
+
         valid = jnp.asarray(False)
-        succ = s
         rank = jnp.int32(-1)
-        ovf = jnp.asarray(False)
-        for b, sb, rk, ob in branches:
+        for b, rk in (
+            (b_upd, R_UPDATETERM),
+            (b_rvreq, R_HANDLE_RVREQ),
+            (b_rvresp, R_HANDLE_RVRESP),
+            (b_reject, R_REJECT_PULL),
+            (b_accept, R_ACCEPT_PULL),
+            (b_learn, R_LEARNOFLEADER),
+            (b_succ, R_HANDLE_SUCCESS_PULL),
+            (b_fail, R_HANDLE_FAIL_PULL),
+        ):
             valid = valid | b
-            succ = jnp.where(b, sb, succ)
             rank = jnp.where(b, jnp.int32(rk), rank)
-            ovf = ovf | (b & ob)
+        ovf = ((b_rvreq | b_reject | b_accept) & put_ovf) | suc_ovf
+        succ = jnp.where(valid, succ, s)
         return valid, succ, rank, ovf
 
     # ---------------- full expansion ----------------

@@ -123,6 +123,13 @@ TIMELINE_STAGES = (
 # chunk-steps: each step the seen run while it is merged, the prefix of
 # the wave's fingerprint buffer the step chose (checker/util.py
 # first_new) and the chunk's queries. Lane 8 of the same stats vector.
+# dedup_search_queries (beside it, lane 9): the query lanes the dedup
+# stage handed to the binary search (util.probe_sorted), summed over the
+# wave's chunk-steps: a step's VC lanes once the occupied seen run is
+# past the merge-or-search crossover (util.merges), 0 while it is merged.
+# seen_lanes: the lanes of the seen run the wave ran against (its size
+# before the wave's own merge, which may step it up), so a trace says
+# which waves merged and which searched.
 # hbm_frac: analytic live-bytes / budget from obs/memwatch.py (null when
 # memwatch is off).
 WAVE_KEYS = (
@@ -389,6 +396,16 @@ def validate_event(ev: object, lineno: int | None = None) -> list[str]:
                 f"{where}wave dedup_sort_lanes {lanes!r} must be a "
                 f"non-negative int (lanes the dedup stage sorted)"
             )
+        for key, what in (
+            ("dedup_search_queries", "query lanes the dedup stage searched"),
+            ("seen_lanes", "lanes of the seen run the wave ran against"),
+        ):
+            val = ev.get(key)
+            if val is not None and not _is_count(val):
+                problems.append(
+                    f"{where}wave {key} {val!r} must be a non-negative "
+                    f"int ({what})"
+                )
         dens = ev.get("enabled_density")
         if dens is not None and (
             isinstance(dens, bool) or not isinstance(dens, (int, float))

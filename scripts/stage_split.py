@@ -143,6 +143,7 @@ def main(argv=None):
         res["distinct"] = got["distinct"]
         res["dedup_plan"] = got["stats"].get("dedup_plan")
         res["dedup_sort_lanes"] = got["stats"].get("dedup_sort_lanes")
+        res["dedup_search_queries"] = got["stats"].get("dedup_search_queries")
         res["canon_lanes"] = {
             k: sum(w[k] for w in got["waves"])
             for k in ("generated", "canon_dup_lanes", "canon_tier3_local",

@@ -221,10 +221,13 @@ def test_chip_smoke_leg_holds_the_cli_to_the_golden(smoke):
     # leg F: upstream's cfg declares v1 and uses v2, so --lenient
     ("KRAFTRC", 40, 3, [1, 9, 65, 406], [1, 9, 65, 407],
      ("F", 5, 1024, ["--lenient"])),
+    # leg G: the same latent bug in PullRaft.cfg, the cell's chunk
+    ("PULL", 64, 6, [1, 1, 3, 7, 18, 40, 86], [1, 1, 3, 7, 18, 40, 87],
+     ("G", 14, 2048, ["--lenient"])),
 ])
 def test_chip_smoke_second_lowerings_legs_run_their_own_cfg_and_golden(
         smoke, monkeypatch, leg, msg_slots, depth, counts, wrong, args):
-    """Legs D, E and F at a tiny depth: another cfg, its own chunk, its
+    """Legs D to G at a tiny depth: another cfg, its own chunk, its
     own golden and that golden's bag width; then the leg itself, its
     arguments held: the frontier, the golden's depth, the chunk, the
     flags the cfg needs."""
@@ -251,7 +254,7 @@ def test_chip_smoke_second_lowerings_legs_run_their_own_cfg_and_golden(
     monkeypatch.setattr(chip_smoke, "leg_b", lambda *a: None)
     monkeypatch.setattr(chip_smoke, "leg_c", lambda *a: None)
     assert chip_smoke.main() == 0
-    (a, kw) = calls["DEF".index(letter)]
+    (a, kw) = calls["DEFG".index(letter)]
     assert a[:1] + a[2:] == (
         f"leg{letter}", golden,
         ["--checker", "tpu", "--frontier-cap", "65536", *flags], max_depth, 1)

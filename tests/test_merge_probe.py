@@ -168,7 +168,7 @@ def test_first_new_sorts_the_prefix_the_wave_has_written(count):
     vals[5::16] = vals[0::16]  # a duplicate of a buffer lane, twice
     vals[7::16] = PAD
     occ = np.ones((1,), bool)
-    got, lanes = jax.jit(
+    got, lanes, queries = jax.jit(
         lambda v, o, s, b, c: util.first_new(v, o, (s,), wave=(b, c, PREFIX))
     )(jnp.asarray(vals), jnp.asarray(occ), jnp.asarray(seen),
       jnp.asarray(buf), np.int32(count))
@@ -183,6 +183,7 @@ def test_first_new_sorts_the_prefix_the_wave_has_written(count):
     assert 0 < want.sum() < (vals != PAD).sum()
     prefix = min(p for p in PREFIX if p >= count)
     assert int(lanes) == 256 + prefix + N
+    assert int(queries) == 0  # a merged run is never searched
 
 
 def test_first_new_never_searches_the_wave_buffer():

@@ -93,6 +93,11 @@ def test_device_metrics_stream_valid_and_count_accurate(tmp_path):
     assert all(w["dedup_sort_lanes"] > 0 for w in waves)
     assert summ["dedup_sort_lanes"] == sum(
         w["dedup_sort_lanes"] for w in waves) == res.stats["dedup_sort_lanes"]
+    # the run is merged, so nothing was searched, and every row says
+    # which run its wave met
+    assert summ["dedup_search_queries"] == 0 == sum(
+        w["dedup_search_queries"] for w in waves)
+    assert {w["seen_lanes"] for w in waves} == {summ["seen_lanes"]}
     assert man["dedup_plan"]["wave_prefix"] == [0, 4096]
     assert summ["dedup_plan"] == res.stats["dedup_plan"]
 
@@ -455,12 +460,17 @@ def test_wave_tier_counters_schema_rule():
     ("dedup_sort_lanes", -1, "non-negative int"),
     ("dedup_sort_lanes", 1.5, "non-negative int"),
     ("dedup_sort_lanes", True, "non-negative int"),
+    ("dedup_search_queries", -1, "non-negative int"),
+    ("dedup_search_queries", 1.5, "non-negative int"),
+    ("seen_lanes", -1, "non-negative int"),
+    ("seen_lanes", True, "non-negative int"),
 ])
 def test_wave_dedup_sort_lanes_schema_rule(key, value, says):
     from raft_tpu.obs.events import validate_event
 
     ev = dict.fromkeys(WAVE_KEYS, 0)
-    ev.update(event="wave", dedup_sort_lanes=327680)
+    ev.update(event="wave", dedup_sort_lanes=327680,
+              dedup_search_queries=65536, seen_lanes=1 << 22)
     assert validate_event(ev) == []
     (problem,) = validate_event({**ev, key: value})
     assert says in problem
