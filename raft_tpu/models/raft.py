@@ -16,6 +16,16 @@ Derived bounds that make the encoding tight:
   - the message-bag DOMAIN grows monotonically (see ops/bag.py), so a
     behavior's distinct-message count bounds the slot table; overflow is a
     hard error surfaced to the driver, never silent.
+
+Every read and write whose index is a binding, a decoded server, a log
+position or a bag slot goes through models/base.py's one-hot helpers
+(``onehot_row``, ``onehot_get2``, ``onehot_set``, ``onehot_set2``,
+``onehot_add``): the axes are a handful of servers, log lanes, values and
+bag slots, and under the worklist's vmap or the guard pass's an ``arr[i]``
+is a per-lane gather and an ``arr.at[i].set`` a scatter the TPU serializes
+(and, at some batch sizes, has dropped). A one-hot read of an index outside
+its axis yields 0 where a gather would clamp, so log positions are clipped
+before they are read.
 """
 
 from __future__ import annotations
@@ -35,6 +45,8 @@ from .base import (
     Layout,
     SparseExpandMixin,
     messages_are_valid_kernel,
+    onehot_add,
+    onehot_get2,
     onehot_row,
     onehot_set,
     onehot_set2,
@@ -315,8 +327,8 @@ class RaftModel(SparseExpandMixin, FleetConstMixin, ActionLabelMixin):
 
     @staticmethod
     def _last_term(d, i):
-        """LastTerm(log[i]) — Raft.tla:126 (one-hot row selects: dynamic
-        row gathers serialize on scattered indices, models/base.py)."""
+        """LastTerm(log[i]) — Raft.tla:126, by one-hot selects; ``ll - 1``
+        is clipped for the empty log."""
         ll = onehot_row(d["log_len"], i)
         lt = onehot_row(d["log_term"], i)
         return jnp.where(ll > 0, onehot_row(lt, jnp.clip(ll - 1, 0)), 0)
@@ -333,25 +345,27 @@ class RaftModel(SparseExpandMixin, FleetConstMixin, ActionLabelMixin):
         d = self._dec(s)
         valid = d["restartCtr"] < self._cv(d, "max_restarts")
         upd = dict(
-            state=d["state"].at[i].set(FOLLOWER),
-            votesGranted=d["votesGranted"].at[i].set(0),
-            nextIndex=d["nextIndex"].at[i].set(jnp.ones((S,), jnp.int32)),
-            matchIndex=d["matchIndex"].at[i].set(jnp.zeros((S,), jnp.int32)),
-            commitIndex=d["commitIndex"].at[i].set(0),
+            state=onehot_set(d["state"], i, FOLLOWER),
+            votesGranted=onehot_set(d["votesGranted"], i, 0),
+            nextIndex=onehot_set(d["nextIndex"], i, jnp.ones((S,), jnp.int32)),
+            matchIndex=onehot_set(d["matchIndex"], i, jnp.zeros((S,), jnp.int32)),
+            commitIndex=onehot_set(d["commitIndex"], i, 0),
             restartCtr=d["restartCtr"] + 1,
         )
         if p.has_pending_response:
-            upd["pendingResponse"] = d["pendingResponse"].at[i].set(0)
+            upd["pendingResponse"] = onehot_set(d["pendingResponse"], i, 0)
         if p.has_fsync:
-            new_ll = jnp.minimum(d["log_len"][i], d["fsyncIndex"][i])
+            new_ll = jnp.minimum(
+                onehot_row(d["log_len"], i), onehot_row(d["fsyncIndex"], i)
+            )
             keep = jnp.arange(p.max_log, dtype=jnp.int32) < new_ll
-            upd["log_term"] = d["log_term"].at[i].set(
-                jnp.where(keep, d["log_term"][i], 0)
+            upd["log_term"] = onehot_set(
+                d["log_term"], i, jnp.where(keep, onehot_row(d["log_term"], i), 0)
             )
-            upd["log_value"] = d["log_value"].at[i].set(
-                jnp.where(keep, d["log_value"][i], 0)
+            upd["log_value"] = onehot_set(
+                d["log_value"], i, jnp.where(keep, onehot_row(d["log_value"], i), 0)
             )
-            upd["log_len"] = d["log_len"].at[i].set(new_ll)
+            upd["log_len"] = onehot_set(d["log_len"], i, new_ll)
         succ = self._asm(d, **upd)
         return valid, succ, jnp.int32(R_RESTART), jnp.asarray(False)
 
@@ -360,16 +374,16 @@ class RaftModel(SparseExpandMixin, FleetConstMixin, ActionLabelMixin):
         sending (RequestVote(i,j) sends per peer separately)."""
         p = self.p
         d = self._dec(s)
-        st_i = d["state"][i]
+        st_i = onehot_row(d["state"], i)
         valid = (d["electionCtr"] < self._cv(d, "max_elections")) & (
             (st_i == FOLLOWER) | (st_i == CANDIDATE)
         )
         succ = self._asm(
             d,
-            state=d["state"].at[i].set(CANDIDATE),
-            currentTerm=d["currentTerm"].at[i].set(d["currentTerm"][i] + 1),
-            votedFor=d["votedFor"].at[i].set(i + 1),
-            votesGranted=d["votesGranted"].at[i].set(jnp.int32(1) << i),
+            state=onehot_set(d["state"], i, CANDIDATE),
+            currentTerm=onehot_add(d["currentTerm"], i, 1),
+            votedFor=onehot_set(d["votedFor"], i, i + 1),
+            votesGranted=onehot_set(d["votesGranted"], i, jnp.int32(1) << i),
             electionCtr=d["electionCtr"] + 1,
         )
         return valid, succ, jnp.int32(R_TIMEOUT), jnp.asarray(False)
@@ -378,12 +392,12 @@ class RaftModel(SparseExpandMixin, FleetConstMixin, ActionLabelMixin):
         """RequestVote(i, j) — RaftFsync.tla:234-243: candidate i sends one
         send-once RequestVoteRequest (at its current term) to peer j."""
         d = self._dec(s)
-        valid = d["state"][i] == CANDIDATE
+        valid = onehot_row(d["state"], i) == CANDIDATE
         khi, klo = self._pack(
             mtype=RVREQ,
-            mterm=d["currentTerm"][i],
+            mterm=onehot_row(d["currentTerm"], i),
             mlastLogTerm=self._last_term(d, i),
-            mlastLogIndex=d["log_len"][i],
+            mlastLogIndex=onehot_row(d["log_len"], i),
             msource=i,
             mdest=j,
         )
@@ -397,21 +411,21 @@ class RaftModel(SparseExpandMixin, FleetConstMixin, ActionLabelMixin):
     def _advance_fsync_index(self, s, i):
         """AdvanceFsyncIndex(i) — RaftFsync.tla:339-343."""
         d = self._dec(s)
-        valid = d["fsyncIndex"][i] < d["log_len"][i]
-        succ = self._asm(d, fsyncIndex=d["fsyncIndex"].at[i].add(1))
+        valid = onehot_row(d["fsyncIndex"], i) < onehot_row(d["log_len"], i)
+        succ = self._asm(d, fsyncIndex=onehot_add(d["fsyncIndex"], i, 1))
         return valid, succ, jnp.int32(R_ADVANCEFSYNC), jnp.asarray(False)
 
     def _request_vote(self, s, i):
         """RequestVote(i) — Raft.tla:242-257 (fused Timeout+RequestVote)."""
         p, S = self.p, self.p.n_servers
         d = self._dec(s)
-        st_i = d["state"][i]
+        st_i = onehot_row(d["state"], i)
         valid = (d["electionCtr"] < self._cv(d, "max_elections")) & (
             (st_i == FOLLOWER) | (st_i == CANDIDATE)
         )
-        new_term = d["currentTerm"][i] + 1
+        new_term = onehot_row(d["currentTerm"], i) + 1
         last_t = self._last_term(d, i)
-        ll_i = d["log_len"][i]
+        ll_i = onehot_row(d["log_len"], i)
         hi, lo, cnt = d["msg_hi"], d["msg_lo"], d["msg_cnt"]
         ovf = jnp.asarray(False)
         # SendMultipleOnce of RequestVoteRequest to all peers (Raft.tla:250-256):
@@ -431,10 +445,10 @@ class RaftModel(SparseExpandMixin, FleetConstMixin, ActionLabelMixin):
             ovf |= o
         succ = self._asm(
             d,
-            state=d["state"].at[i].set(CANDIDATE),
-            currentTerm=d["currentTerm"].at[i].set(new_term),
-            votedFor=d["votedFor"].at[i].set(i + 1),
-            votesGranted=d["votesGranted"].at[i].set(jnp.int32(1) << i),
+            state=onehot_set(d["state"], i, CANDIDATE),
+            currentTerm=onehot_set(d["currentTerm"], i, new_term),
+            votedFor=onehot_set(d["votedFor"], i, i + 1),
+            votesGranted=onehot_set(d["votesGranted"], i, jnp.int32(1) << i),
             electionCtr=d["electionCtr"] + 1,
             msg_hi=hi,
             msg_lo=lo,
@@ -449,21 +463,24 @@ class RaftModel(SparseExpandMixin, FleetConstMixin, ActionLabelMixin):
         (FlexibleRaft.tla:260-269)."""
         p, S = self.p, self.p.n_servers
         d = self._dec(s)
-        votes = jnp.sum((d["votesGranted"][i] >> jnp.arange(S, dtype=jnp.int32)) & 1)
+        votes = jnp.sum(
+            (onehot_row(d["votesGranted"], i) >> jnp.arange(S, dtype=jnp.int32)) & 1
+        )
         if p.election_quorum is not None:
             quorum = votes >= p.election_quorum
         else:
             quorum = 2 * votes > S
-        valid = (d["state"][i] == CANDIDATE) & quorum
+        valid = (onehot_row(d["state"], i) == CANDIDATE) & quorum
         upd = dict(
-            state=d["state"].at[i].set(LEADER),
-            nextIndex=d["nextIndex"].at[i].set(
-                jnp.full((S,), 1, jnp.int32) * (d["log_len"][i] + 1)
+            state=onehot_set(d["state"], i, LEADER),
+            nextIndex=onehot_set(
+                d["nextIndex"], i,
+                jnp.full((S,), 1, jnp.int32) * (onehot_row(d["log_len"], i) + 1),
             ),
-            matchIndex=d["matchIndex"].at[i].set(jnp.zeros((S,), jnp.int32)),
+            matchIndex=onehot_set(d["matchIndex"], i, jnp.zeros((S,), jnp.int32)),
         )
         if p.has_pending_response:
-            upd["pendingResponse"] = d["pendingResponse"].at[i].set(0)
+            upd["pendingResponse"] = onehot_set(d["pendingResponse"], i, 0)
         succ = self._asm(d, **upd)
         return valid, succ, jnp.int32(R_BECOMELEADER), jnp.asarray(False)
 
@@ -471,16 +488,20 @@ class RaftModel(SparseExpandMixin, FleetConstMixin, ActionLabelMixin):
         """ClientRequest(i, v) — Raft.tla:304-313."""
         L = self.p.max_log
         d = self._dec(s)
-        valid = (d["state"][i] == LEADER) & (d["acked"][v] == ACK_NIL)
-        pos = d["log_len"][i]
+        valid = (onehot_row(d["state"], i) == LEADER) & (
+            onehot_row(d["acked"], v) == ACK_NIL
+        )
+        pos = onehot_row(d["log_len"], i)
         ovf = valid & (pos >= L)
         posc = jnp.clip(pos, 0, L - 1)
         succ = self._asm(
             d,
-            log_term=d["log_term"].at[i, posc].set(d["currentTerm"][i]),
-            log_value=d["log_value"].at[i, posc].set(v + 1),
-            log_len=d["log_len"].at[i].add(1),
-            acked=d["acked"].at[v].set(ACK_FALSE),
+            log_term=onehot_set2(
+                d["log_term"], i, posc, onehot_row(d["currentTerm"], i)
+            ),
+            log_value=onehot_set2(d["log_value"], i, posc, v + 1),
+            log_len=onehot_add(d["log_len"], i, 1),
+            acked=onehot_set(d["acked"], v, ACK_FALSE),
         )
         return valid, succ, jnp.int32(R_CLIENTREQUEST), ovf
 
@@ -489,16 +510,17 @@ class RaftModel(SparseExpandMixin, FleetConstMixin, ActionLabelMixin):
         p = self.p
         S, L, V = p.n_servers, p.max_log, p.n_values
         d = self._dec(s)
-        ll_i = d["log_len"][i]
-        ci_i = d["commitIndex"][i]
-        match_row = d["matchIndex"][i]  # [S]
+        ll_i = onehot_row(d["log_len"], i)
+        ci_i = onehot_row(d["commitIndex"], i)
+        ct_i = onehot_row(d["currentTerm"], i)
+        match_row = onehot_row(d["matchIndex"], i)  # [S]
         idxs = jnp.arange(1, L + 1, dtype=jnp.int32)  # candidate indexes
         # Agree(index) = {i} u {k : matchIndex[i][k] >= index} (Raft.tla:323-324).
         # RaftFsync (RaftFsync.tla:313-315): when LeaderFsyncBeforeIncludeInQuorum
         # and index > fsyncIndex[i], the leader excludes itself.
         self_in = jnp.arange(S, dtype=jnp.int32)[None, :] == i
         if p.has_fsync and p.fsync_leader_quorum:
-            self_in = self_in & (idxs[:, None] <= d["fsyncIndex"][i])
+            self_in = self_in & (idxs[:, None] <= onehot_row(d["fsyncIndex"], i))
         agree = self_in | (match_row[None, :] >= idxs[:, None])
         agree_cnt = jnp.sum(agree, axis=1)
         if p.replication_quorum is not None:
@@ -508,21 +530,24 @@ class RaftModel(SparseExpandMixin, FleetConstMixin, ActionLabelMixin):
             quorum_ok = 2 * agree_cnt > S
         is_agree = quorum_ok & (idxs <= ll_i)  # quorum + in-log
         max_agree = jnp.max(jnp.where(is_agree, idxs, 0))  # Max (Raft.tla:333)
-        term_at = d["log_term"][i][jnp.clip(max_agree - 1, 0)]
+        # max_agree <= L, so the clip's floor is the only bound the read needs
+        term_at = onehot_row(
+            onehot_row(d["log_term"], i), jnp.clip(max_agree - 1, 0)
+        )
         # current-term gate (Raft.tla:330-335)
-        new_ci = jnp.where((max_agree > 0) & (term_at == d["currentTerm"][i]), max_agree, ci_i)
-        valid = (d["state"][i] == LEADER) & (ci_i < new_ci)
+        new_ci = jnp.where((max_agree > 0) & (term_at == ct_i), max_agree, ci_i)
+        valid = (onehot_row(d["state"], i) == LEADER) & (ci_i < new_ci)
         # acked[v]: FALSE -> (v committed in (ci, new_ci]) (Raft.tla:339-342)
         lanes = jnp.arange(L, dtype=jnp.int32)
         in_range = (lanes + 1 > ci_i) & (lanes + 1 <= new_ci)
-        vals_row = d["log_value"][i]
+        vals_row = onehot_row(d["log_value"], i)
         committed = jnp.any(
             in_range[None, :] & (vals_row[None, :] == jnp.arange(1, V + 1, dtype=jnp.int32)[:, None]),
             axis=1,
         )
         acked = jnp.where((d["acked"] == ACK_FALSE) & committed, ACK_TRUE, d["acked"])
         succ = self._asm(
-            d, commitIndex=d["commitIndex"].at[i].set(new_ci), acked=acked
+            d, commitIndex=onehot_set(d["commitIndex"], i, new_ci), acked=acked
         )
         return valid, succ, jnp.int32(R_ADVANCECOMMIT), jnp.asarray(False)
 
@@ -532,32 +557,34 @@ class RaftModel(SparseExpandMixin, FleetConstMixin, ActionLabelMixin):
         p = self.p
         L = p.max_log
         d = self._dec(s)
-        valid = d["state"][i] == LEADER
+        valid = onehot_row(d["state"], i) == LEADER
         if p.has_pending_response:
-            pending = (d["pendingResponse"][i] >> j) & 1
-            valid &= pending == 0
-        ni_ij = d["nextIndex"][i, j]
+            pend_i = onehot_row(d["pendingResponse"], i)
+            valid &= ((pend_i >> j) & 1) == 0
+        ni_ij = onehot_get2(d["nextIndex"], i, j)
         prev_idx = ni_ij - 1
-        lt_row = d["log_term"][i]
-        lv_row = d["log_value"][i]
-        prev_term = jnp.where(prev_idx > 0, lt_row[jnp.clip(prev_idx - 1, 0, L - 1)], 0)
-        last_entry = jnp.minimum(d["log_len"][i], ni_ij)  # Min (Raft.tla:273)
+        lt_row = onehot_row(d["log_term"], i)
+        lv_row = onehot_row(d["log_value"], i)
+        prev_term = jnp.where(
+            prev_idx > 0, onehot_row(lt_row, jnp.clip(prev_idx - 1, 0, L - 1)), 0
+        )
+        last_entry = jnp.minimum(onehot_row(d["log_len"], i), ni_ij)  # Min (Raft.tla:273)
         if p.has_fsync and p.fsync_leader_before_ae:
             # LeaderFsyncBeforeAppendEntries gate (RaftFsync.tla:261-263)
-            valid &= d["fsyncIndex"][i] >= last_entry
+            valid &= onehot_row(d["fsyncIndex"], i) >= last_entry
         nent = (last_entry >= ni_ij).astype(jnp.int32)  # <=1 entry
         epos = jnp.clip(ni_ij - 1, 0, L - 1)
-        eterm = jnp.where(nent > 0, lt_row[epos], 0)
-        evalue = jnp.where(nent > 0, lv_row[epos], 0)
+        eterm = jnp.where(nent > 0, onehot_row(lt_row, epos), 0)
+        evalue = jnp.where(nent > 0, onehot_row(lv_row, epos), 0)
         khi, klo = self._pack(
             mtype=AEREQ,
-            mterm=d["currentTerm"][i],
+            mterm=onehot_row(d["currentTerm"], i),
             mprevLogIndex=prev_idx,
             mprevLogTerm=prev_term,
             nentries=nent,
             eterm=eterm,
             evalue=evalue,
-            mcommitIndex=jnp.minimum(d["commitIndex"][i], last_entry),
+            mcommitIndex=jnp.minimum(onehot_row(d["commitIndex"], i), last_entry),
             msource=i,
             mdest=j,
         )
@@ -572,8 +599,8 @@ class RaftModel(SparseExpandMixin, FleetConstMixin, ActionLabelMixin):
             valid &= (nent > 0) | ~existed
         upd = dict(msg_hi=hi, msg_lo=lo, msg_cnt=cnt)
         if p.has_pending_response:
-            upd["pendingResponse"] = d["pendingResponse"].at[i].set(
-                d["pendingResponse"][i] | (jnp.int32(1) << j)
+            upd["pendingResponse"] = onehot_set(
+                d["pendingResponse"], i, pend_i | (jnp.int32(1) << j)
             )
         succ = self._asm(d, **upd)
         return valid, succ, jnp.int32(R_APPENDENTRIES), ovf & valid
@@ -588,10 +615,10 @@ class RaftModel(SparseExpandMixin, FleetConstMixin, ActionLabelMixin):
         p = self.p
         d = self._dec(s)
         cnt = d["msg_cnt"]
-        occupied = d["msg_hi"][m] != EMPTY
-        valid = occupied & (cnt[m] >= 1) & (cnt[m] < p.max_msg_copies)
-        oh = (jnp.arange(p.msg_slots, dtype=jnp.int32) == m).astype(jnp.int32)
-        succ = self._asm(d, msg_cnt=cnt + oh)
+        kcnt = onehot_row(cnt, m)
+        occupied = onehot_row(d["msg_hi"], m) != EMPTY
+        valid = occupied & (kcnt >= 1) & (kcnt < p.max_msg_copies)
+        succ = self._asm(d, msg_cnt=onehot_add(cnt, m, 1))
         return valid, succ, jnp.int32(self._r_dup), jnp.asarray(False)
 
     def _drop_message(self, s, m):
@@ -600,8 +627,8 @@ class RaftModel(SparseExpandMixin, FleetConstMixin, ActionLabelMixin):
         like the receipt kernels' bag_discard (ops/bag.py)."""
         d = self._dec(s)
         cnt = d["msg_cnt"]
-        occupied = d["msg_hi"][m] != EMPTY
-        valid = occupied & (cnt[m] >= 1)
+        occupied = onehot_row(d["msg_hi"], m) != EMPTY
+        valid = occupied & (onehot_row(cnt, m) >= 1)
         succ = self._asm(d, msg_cnt=bag.bag_discard_at(cnt, m))
         return valid, succ, jnp.int32(self._r_drop), jnp.asarray(False)
 
@@ -617,7 +644,7 @@ class RaftModel(SparseExpandMixin, FleetConstMixin, ActionLabelMixin):
         L = p.max_log
         d = self._dec(s)
         hi, lo, cnt = d["msg_hi"], d["msg_lo"], d["msg_cnt"]
-        khi, klo, kcnt = hi[m], lo[m], cnt[m]
+        khi, klo, kcnt = onehot_row(hi, m), onehot_row(lo, m), onehot_row(cnt, m)
         occupied = khi != EMPTY
         u = partial(packer.unpack, khi, klo)
         mtype, mterm = u("mtype"), u("mterm")

@@ -94,7 +94,8 @@ def scatter_writes(which="set,set2,add"):
 
 def gather_reads(which="row,get2"):
     """The same for the one-hot read helpers and the gathers they
-    replaced (``raft.py``'s HandleMessage reads by ``onehot_row`` too)."""
+    replaced (every lowering reads by them, ``raft.py`` in all its
+    kernels since PR 44)."""
     _put_back(GATHER_FORMS, which)
 
 

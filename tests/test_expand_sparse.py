@@ -46,6 +46,30 @@ def _raft():
     ))
 
 
+def _raft_fsync():
+    """RaftFsync (the policy of test_device_smoke.py's fsync model):
+    Timeout, RequestVotePair, AdvanceFsyncIndex and Restart's fsync arm."""
+    from raft_tpu.models.raft import RaftParams, cached_model
+
+    return cached_model(RaftParams(
+        n_servers=3, n_values=1, max_elections=1, max_restarts=1,
+        msg_slots=24, strict_send_once=True, has_pending_response=False,
+        trunc_term_mismatch=True, has_fsync=True,
+        fsync_leader_before_ae=False, fsync_leader_quorum=True,
+        fsync_follower_reply=True,
+    ))
+
+
+def _raft_net_faults():
+    """Raft with DuplicateMessage and DropMessage over every slot."""
+    from raft_tpu.models.raft import RaftParams, cached_model
+
+    return cached_model(RaftParams(
+        n_servers=2, n_values=1, max_elections=1, max_restarts=0,
+        msg_slots=12, net_faults=True,
+    ))
+
+
 def _pull_raft():
     from raft_tpu.models.pull_raft import PullRaftParams, cached_model
 
@@ -102,6 +126,8 @@ def _kraft_reconfig():
 
 FAMILIES = {
     "raft": _raft,
+    "raft_fsync": _raft_fsync,
+    "raft_net_faults": _raft_net_faults,
     "pull_raft": _pull_raft,
     "kraft": _kraft,
     "joint_raft": _joint_raft,
@@ -194,56 +220,26 @@ def test_guard_jaxpr_writes_no_successor_blocks(family):
     assert not findings, [f.render() for f in findings]
 
 
-# Kernels that still write `.at[i].set` with a binding or a decoded
-# server as the index: under the worklist's vmap a batched scatter. On
-# the v5e such writes were DROPPED in the joint-consensus lowering's
-# sparse apply (PR 30; scripts/stage_diff.py --scatter reproduces it),
-# and the two config_common lowerings write by one-hot selects since.
-# `raft` runs on the chip with these and equals its goldens in every
-# benchmark run (none of them in HandleMessage, whose writes went
-# one-hot in round 5, for speed); `kraft` left the table with its first
-# cell (PR 32: 73, 44 of them in HandleMessage), `kraft_reconfig` with
-# its (PR 40: 175, 104 of them in HandleMessage) and `pull_raft` with
-# its (PR 43: 29, 15 of them in HandleMessage, `variant2` with it).
-# strict: a family that comes clean has to leave this table.
-SCATTER_DEBT = {
-    "raft": "20 in Restart, RequestVote, BecomeLeader, ClientRequest, "
-            "AdvanceCommitIndex, AppendEntries; equal to the goldens on "
-            "the v5e in every run of the three accepted cells",
-}
-
-
-@pytest.mark.parametrize("family", [
-    pytest.param(f, marks=pytest.mark.xfail(
-        strict=True, reason=SCATTER_DEBT[f])) if f in SCATTER_DEBT else f
-    for f in sorted(FAMILIES)])
+# A kernel that writes `.at[i].set` with a binding or a decoded server as
+# the index is a batched scatter under the worklist's vmap. On the v5e
+# such writes were DROPPED in the joint-consensus lowering's sparse apply
+# at a batch size no golden covered (PR 30; scripts/stage_diff.py
+# --scatter reproduces it), so every lowering writes by models/base.py's
+# one-hot selects, and a small run on the chip is no guard: this is.
+@pytest.mark.parametrize("family", sorted(FAMILIES))
 def test_no_kernel_writes_through_a_dynamic_index_scatter(family):
     """No per-action kernel of the family holds a scatter (the jaxpr
     of each kernel as the sparse apply calls it; nothing compiled)."""
     assert scatter_kernels(FAMILIES[family]()) == {}
 
 
-# Kernels that still read `d[f][i]` with a binding, a decoded server or
-# a log position as the index: under the worklist's vmap, and in the
-# guard pass under the chunk's, a per-lane gather. On the v5e those were
-# 8-12 ns an index and most of `expand` on joint4 (PR 31); the two
-# config_common lowerings read by one-hot selects since
-# (`models/base.py::onehot_row`, `onehot_get2`), `kraft` since PR 32
-# (131 (58), 50 of them in HandleMessage), `kraft_reconfig` since
-# PR 40 (326 (127), 130 of them in HandleMessage) and `pull_raft` since
-# PR 43 (86 (42), 21 of them in HandleMessage). Counts at this
-# file's shapes, the guard pass's in brackets. strict, as above.
-GATHER_DEBT = {
-    "raft": "62 (29): RequestVote, BecomeLeader, ClientRequest, "
-            "AdvanceCommitIndex, AppendEntries, 3 in HandleMessage, "
-            "which reads by one-hot since round 5 (ROADMAP D14)",
-}
-
-
-@pytest.mark.parametrize("family", [
-    pytest.param(f, marks=pytest.mark.xfail(
-        strict=True, reason=GATHER_DEBT[f])) if f in GATHER_DEBT else f
-    for f in sorted(FAMILIES)])
+# A kernel that reads `d[f][i]` with a binding, a decoded server, a log
+# position or a bag slot as the index is a per-lane gather under the
+# worklist's vmap, and in the guard pass under the chunk's (a read by the
+# inner vmap's own iota included). On the v5e those cost 6.5-12 ns an
+# index and were most of `expand` (PRs 31-44), so every lowering reads by
+# `models/base.py::onehot_row` / `onehot_get2`.
+@pytest.mark.parametrize("family", sorted(FAMILIES))
 def test_no_kernel_reads_through_a_dynamic_index_gather(family):
     """No per-action kernel of the family, traced under the worklist's
     vmap, and no guard pass over a chunk holds a gather (`sparse_apply`'s
@@ -255,7 +251,7 @@ def test_no_kernel_reads_through_a_dynamic_index_gather(family):
 @pytest.mark.parametrize("family,bag_word", [
     ("joint_raft", "msg_w0"), ("reconfig_raft", "msg_w0"),
     ("kraft", "msg_hi"), ("kraft_reconfig", "msg_w0"),
-    ("pull_raft", "msg_hi")])
+    ("pull_raft", "msg_hi"), ("raft", "msg_hi")])
 def test_one_hot_reads_match_the_oracle_on_empty_slots_and_logs(
         family, bag_word):
     """A one-hot read of an index outside its axis yields 0 where the
