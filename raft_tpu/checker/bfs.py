@@ -1111,7 +1111,7 @@ class BFSChecker:
         wl = getattr(self.canon, "refine_rounds", 1)
         return (
             f"host/{self.model.name}/{self.model.p}/W={self.model.layout.W}"
-            f"/sym={self.canon.symmetry}/hashv=5/wl={wl}"
+            f"/sym={self.canon.symmetry}/hashv={self.canon.hashv}/wl={wl}"
             f"/inv={','.join(self.invariants)}"
         )
 

@@ -1489,19 +1489,19 @@ class DeviceBFS:
         match too — states explored before the checkpoint (including Init)
         were only checked against the original run's invariants, so a
         resume with different invariants would silently skip them."""
-        # hashv marks fingerprint-formula revisions. v5 (round 6: the
-        # 1-WL signature refinement iterates to a bounded depth, which
-        # changes the admissible permutation set — and therefore the
-        # canonical representative — of signature-tied states), so all
-        # pre-v5 checkpoints are refused on load; the refinement depth
-        # is part of the formula and recorded alongside. The in-chunk
-        # dedup and the tie-group-local tier-3 are value-preserving and
-        # do NOT participate in the identity.
+        # hashv marks fingerprint-formula revisions and is the canon's
+        # own (Canonicalizer 5, round 6: the 1-WL refinement iterates to
+        # a bounded depth, which changes the canonical representative of
+        # signature-tied states; KRaftWithReconfig's SlotCanonicalizer 6:
+        # the bag hashed as a multiset), so a checkpoint of another
+        # formula is refused on load; the refinement depth is part of
+        # the formula and recorded alongside. The in-chunk dedup and the
+        # tie-group-local tier-3 preserve values and are NOT identity.
         wl = getattr(self.canon, "refine_rounds", 1)
         return (
             f"{self.model.name}/{self.model.p}/W={self.W}"
             f"/sym={self.canon.symmetry}/seed={self.canon.seed}"
-            f"/hashv=5/wl={wl}/inv={','.join(self.invariants)}"
+            f"/hashv={self.canon.hashv}/wl={wl}/inv={','.join(self.invariants)}"
         )
 
     def _save_checkpoint(

@@ -29,8 +29,9 @@ Lowering strategy (SURVEY.md §7.2 "dynamic server universe"):
     so the canonical fingerprint is data-dependent: for each (sigma, tau)
     remap host/value fields, re-sort slots by permuted identity
     (reproducing the oracle's sorted-identity view order), remap slot
-    references through the sort, re-sort the message bag, hash, and take
-    the min (``SlotCanonicalizer``).
+    references through the sort, hash the rows by position and the
+    remapped message bag as a multiset, and take the min
+    (``SlotCanonicalizer``).
 
 Faithfully-reproduced reference quirks (same as the oracle):
   - ``RestartWithoutState:906-924`` is never enabled (its guard :913
@@ -51,9 +52,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops import bag
-from ..ops.hashing import U64_MAX, hash_lanes
+from ..ops.hashing import U64_MAX, combine_pair, hash_lanes, hash_lanes_pair
 from ..ops.packing import EMPTY, WidePacker, bits_for
-from ..ops.symmetry import fingerprints_by_raw_view
+from ..ops.symmetry import bag_hash_pair, fingerprints_by_raw_view
 from .base import (
     ActionLabelMixin,
     Layout,
@@ -2063,17 +2064,23 @@ class SlotCanonicalizer:
     unused — (3) remap every slot reference (leader/votedFor/pf_dest/
     bitmasks/endOffset axes/message source/dest/leader/e_who/e_members)
     through the ranking, (4) remap values through tau (log_val/e_val/acked
-    lanes), (5) re-sort the message bag, (6) hash the VIEW prefix. The
-    fingerprint is the min over all permutations — exactly the oracle's
-    ``canon`` equivalence, hashed.
+    lanes), (5) hash the remapped bag as a MULTISET, its slots as they
+    lie (``ops.symmetry.bag_hash_pair``, the bag hash of every other
+    canon since formula v3: the oracle serialises the bag sorted, and a
+    hash that takes no notice of slot order names the same classes),
+    (6) hash the per-server rows and acked by position and XOR the two.
+    The fingerprint is the min over all permutations — exactly the
+    oracle's ``canon`` equivalence, hashed.
 
     Every index set is tiny (NS slots, H hosts, V values), so each read
-    and write through one is compares and selects; the only sort is the
-    bag's. The four steps run under the scopes ``slot_sort``,
-    ``slot_remap``, ``slot_bag`` and ``slot_hash`` (``canon/slot_*`` in an
-    engine's trace), each opened OUTSIDE the vmaps over lanes and
-    permutations: a scope opened under a vmap reaches the trace as
-    ``vmap(slot_bag)``, which the trace's reduction does not read.
+    and write through one is compares and selects, and nothing sorts
+    (a re-sort of the bag under every permutation was 40 % of
+    kraftrc3-wide's wall, PERF.md section 6, PR 45). The four steps run
+    under the scopes ``slot_sort``, ``slot_remap``, ``slot_bag`` and
+    ``slot_hash`` (``canon/slot_*`` in an engine's trace), each opened
+    OUTSIDE the vmaps over lanes and permutations: a scope opened under
+    a vmap reaches the trace as ``vmap(slot_bag)``, which the trace's
+    reduction does not read.
 
     With symmetry off only the identity permutation runs; the slot ranking
     is then the identity by construction (device slot order IS
@@ -2086,6 +2093,11 @@ class SlotCanonicalizer:
     # after the last representative (PERF.md section 6, PR 42: B // 4 to
     # B // 64 measured in kraftrc3-wide; B // 64 reads as this one does)
     BLOCKS = 32
+
+    # fingerprint-formula revision, as ``Canonicalizer.hashv``: 6 = the
+    # bag hashed as a multiset. A checkpoint of the sorted-bag formula
+    # carries 5 and is refused on load
+    hashv = 6
 
     def __init__(self, model: KRaftReconfigModel, symmetry: bool = True,
                  seed: int = 0):
@@ -2122,11 +2134,10 @@ class SlotCanonicalizer:
         with jax.named_scope("slot_remap"):
             rows, words, cnt = pairs(self._slot_remap, host2, inv)
         with jax.named_scope("slot_bag"):
-            words, cnt = bag.wide_bag_sort(words, cnt)  # along the slots
+            ba, bb = bag_hash_pair(words, cnt, self.seed)  # no slot order
         with jax.named_scope("slot_hash"):
-            view = jnp.concatenate([rows, *words, cnt], axis=-1)
-            assert view.shape[-1] == self.model.layout.view_len
-            return jnp.min(hash_lanes(view, seed=self.seed), axis=-1)
+            ra, rb = hash_lanes_pair(rows, seed=self.seed)
+            return jnp.min(combine_pair(ra ^ ba, rb ^ bb), axis=-1)
 
     def raw_fingerprints(self, states):
         """u64 [B] hashes of the unpermuted view prefix, all that
@@ -2178,7 +2189,7 @@ class SlotCanonicalizer:
     def _slot_remap(self, vec, _sigma, tau, host2, inv):
         """The VIEW's per-server fields and acked in the new slot order,
         flat, and the bag's words and counts with their slot and value
-        fields remapped, not yet re-sorted."""
+        fields remapped, in the slots they lay in."""
         model = self.model
         d = model._dec(vec)
         NS = model.NS

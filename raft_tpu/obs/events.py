@@ -115,9 +115,9 @@ TIMELINE_STAGES = (
 # so together they never exceed generated - canon_dup_lanes. On
 # KRaftWithReconfig (the model's own SlotCanonicalizer, no tiers)
 # canon_tier3_full is every representative: the lanes its 12
-# permutations ran on, generated - canon_dup_lanes. 0 on the
-# host engines, which have no tiered canon. From the stats vector the
-# wave already fetched: zero extra device syncs.
+# permutations (rank, remap, hash; no sort) ran on, generated -
+# canon_dup_lanes. 0 on the host engines, which have no tiered canon.
+# From the stats vector the wave already fetched: no extra device sync.
 # dedup_sort_lanes (an extra key of the device engine's rows): the lanes
 # its dedup stage's merged sort sorted, summed over the wave's
 # chunk-steps: each step the seen run while it is merged, the prefix of

@@ -47,7 +47,11 @@ the slot-sorted view):
      hold DISTINCT keys by construction (bag canonicalization,
      ops/packing.py), so XOR cannot cancel duplicates; the collision
      budget stays 2^-64-class. This removes the M-lane ``lax.sort``
-     that every permutation previously paid.
+     that every permutation previously paid. ``bag_hash_pair`` is that
+     hash (v4's two streams and addition); the slot canon of
+     KRaftWithReconfig hashes its remapped bag with it too
+     (``SlotCanonicalizer``, whose formula revision is its own
+     ``hashv``), so no canon in the tree sorts a bag.
 
   2. **Signature-pruned permutation set.** A permutation-EQUIVARIANT
      per-server signature (1-WL style: per-server invariant content +
@@ -183,6 +187,35 @@ def _psum_last(p):
     return _reduce_pair(p[0], p[1], op="sum")
 
 
+def bag_hash_pair(words, cnt, seed: int = 0):
+    """Multiset hash of a message bag as a (u32, u32) stream pair: key
+    words [..., M] each (word 0 EMPTY on a free slot) and delivery counts
+    [..., M]. Occupied slots' position-independent record hashes combine
+    by ADDITION mod 2^32 (nonlinear carries — a slightly better multiset
+    structure than the round-4 XOR, which was linear over GF(2); slots
+    hold distinct keys by construction either way, so neither combine
+    can cancel duplicates), so the bag's slot order never enters and no
+    permutation re-sorts it. A nonzero seed XORs a per-word constant in
+    before the multiply (the audit's independent family). The one
+    definition: ``Canonicalizer._bag_hash_pair`` and the slot canon of
+    KRaftWithReconfig (models/kraft_reconfig.py) both call it."""
+    occ = words[0] != EMPTY
+    ha = jnp.zeros_like(words[0], dtype=jnp.uint32)
+    hb = jnp.zeros_like(words[0], dtype=jnp.uint32)
+    for w_i, w in enumerate([*words, cnt]):
+        x = w.astype(jnp.uint32)
+        if seed:
+            sw = _host_mix64(w_i * int(_C2) + seed)
+            x = x ^ np.uint32(sw & 0xFFFFFFFF)
+        wa, wb = _salt(w_i, 20)
+        ha = ha ^ mix32(x * KA + wa)
+        hb = hb ^ mix32(x * KB + wb)
+    # per-slot finalize, then a single stacked multiset-sum reduce
+    ha = mix32(ha + KB)
+    hb = mix32(hb + KA)
+    return _psum_last(_pwhere(occ, (ha, hb)))
+
+
 def _lookup(t, idx):
     """A [B, S] per-server table read at int [B, N] server indices in
     0..S-1, as S compares and selects: a gather costs nanoseconds a lane
@@ -293,6 +326,11 @@ def canon_chunk(canon, states, valid):
 
 
 class Canonicalizer:
+    # fingerprint-formula revision, the checkpoint identity's hashv (the
+    # engines' _ckpt_ident): a canon whose formula changes takes the next
+    # number, and checkpoints of the old one are refused on load
+    hashv = 5
+
     @classmethod
     @setup_phase("engine/canon")
     def for_model(cls, model, symmetry: bool = True, seed: int = 0,
@@ -567,32 +605,13 @@ class Canonicalizer:
     # ---------------- the v3 hash ----------------
 
     def _bag_hash_pair(self, v):
-        """Multiset hash of the message bag region of [B, VL] views as a
-        (u32, u32) stream pair: occupied slots' position-independent
-        record hashes combine by ADDITION mod 2^32 (nonlinear carries —
-        a slightly better multiset structure than the round-4 XOR, which
-        was linear over GF(2); slots hold distinct keys by construction
-        either way, so neither combine can cancel duplicates)."""
+        """``bag_hash_pair`` of the message bag region of [B, VL] views
+        (zeros for a layout without a bag)."""
         if not self._msg_word_sls:
             z = jnp.zeros(v.shape[:-1], jnp.uint32)
             return z, z
         words = [v[..., sl] for sl in self._msg_word_sls]  # each [B, M]
-        cnt = v[..., self._msg_cnt_sl]
-        occ = words[0] != EMPTY
-        ha = jnp.zeros_like(words[0], dtype=jnp.uint32)
-        hb = jnp.zeros_like(words[0], dtype=jnp.uint32)
-        for w_i, w in enumerate([*words, cnt]):
-            x = w.astype(jnp.uint32)
-            if self.seed:
-                sw = _host_mix64(w_i * int(_C2) + self.seed)
-                x = x ^ np.uint32(sw & 0xFFFFFFFF)
-            wa, wb = _salt(w_i, 20)
-            ha = ha ^ mix32(x * KA + wa)
-            hb = hb ^ mix32(x * KB + wb)
-        # per-slot finalize, then a single stacked multiset-sum reduce
-        ha = mix32(ha + KB)
-        hb = mix32(hb + KA)
-        return _psum_last(_pwhere(occ, (ha, hb)))
+        return bag_hash_pair(words, v[..., self._msg_cnt_sl], self.seed)
 
     def _perm_hash(self, v):
         """u64 hash of a permuted [B, VL] view: positional over the

@@ -686,17 +686,17 @@ class ShardedBFS:
     # ---------------- checkpoint ----------------
 
     def _ckpt_ident(self) -> str:
-        # hashv=5: k-round 1-WL refinement (ops/symmetry.py) changed the
-        # canonical representative of signature-tied states; the
-        # refinement depth is part of the fingerprint formula. The in-chunk
-        # dedup is value-preserving and not part of the identity.
+        # hashv: the canon's own formula revision (DeviceBFS._ckpt_ident;
+        # 5 = ops/symmetry.py's k-round 1-WL refinement, whose depth is
+        # part of the fingerprint formula; 6 = the slot canon's multiset
+        # bag). The in-chunk dedup preserves values and is not identity.
         # /D=<n>/ is PROVENANCE, not identity: resilience/ckpt.check_spec
         # strips it (mesh_neutral) when deciding reshardability, and the
         # resume path re-routes the payload when it differs.
         wl = getattr(self.canon, "refine_rounds", 1)
         return (
             f"sharded/{self.model.name}/{self.model.p}/W={self.W}"
-            f"/D={self.D}/sym={self.canon.symmetry}/hashv=5/wl={wl}"
+            f"/D={self.D}/sym={self.canon.symmetry}/hashv={self.canon.hashv}/wl={wl}"
             f"/inv={','.join(self.invariants)}"
         )
 

@@ -22,7 +22,10 @@ import json
 import os
 import random
 
+from functools import partial
+
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -30,6 +33,7 @@ from conftest import eqns, scope_paths
 from test_kraft_reconfig import SMALLP, small_oracle
 from raft_tpu.models import kraft_reconfig
 from raft_tpu.models.registry import build_from_cfg, oracle_for_setup
+from raft_tpu.ops.packing import EMPTY
 from raft_tpu.utils.cfg import CfgError, parse_cfg
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -169,9 +173,11 @@ def test_successor_sets_match_oracle_on_states_that_take_every_action(
 
 def test_no_gather_and_no_scatter_in_the_canonicalizer_and_its_scopes(setup):
     """`SlotCanonicalizer._fingerprints` reads and writes through its
-    tiny index sets by compares and selects (the one sort is the bag's),
-    and its four steps reach a trace as `canon/slot_*`: each scope is
-    opened outside the vmaps it covers."""
+    tiny index sets by compares and selects and sorts nothing (the bag
+    is hashed as a multiset since PR 45; its re-sort under each of the
+    12 permutations was 40 % of kraftrc3-wide's wall), and its four
+    steps reach a trace as `canon/slot_*`: each scope is opened outside
+    the vmaps it covers."""
     from raft_tpu.obs import stage
 
     model = setup.model
@@ -180,14 +186,17 @@ def test_no_gather_and_no_scatter_in_the_canonicalizer_and_its_scopes(setup):
     names = [e.primitive.name
              for e in eqns(jax.make_jaxpr(canon._fingerprints)(rows).jaxpr)]
     assert not [n for n in names if n == "gather" or n.startswith("scatter")]
-    assert names.count("sort") == 1
+    assert names.count("sort") == 0
     lowered = jax.jit(stage("canon")(canon.fingerprints_dedup)).lower(
         rows, jax.ShapeDtypeStruct((16,), bool)).as_text(debug_info=True)
     paths = scope_paths(lowered)
     for scope in ("slot_sort", "slot_remap", "slot_bag", "slot_hash"):
         assert ("canon", scope) in paths  # in the in-chunk dedup's loop
         assert f"({scope})" not in lowered  # not vmap(slot_bag)
-    assert "/slot_bag/sort" in lowered
+    # the only sorts left in the canon stage are the in-chunk dedup's
+    assert "/slot_bag/" in lowered and "/inchunk/sort" in lowered
+    assert not [ln for ln in lowered.splitlines()
+                if "/slot_" in ln and ln.split('"')[1].endswith("/sort")]
 
 
 def test_fingerprints_are_equal_iff_the_oracles_canon_is_under_all_12(
@@ -221,6 +230,234 @@ def test_fingerprints_are_equal_iff_the_oracles_canon_is_under_all_12(
     plain = model.make_canonicalizer(False)
     off = np.asarray(plain.fingerprints(rows)).reshape(len(states), 12)
     assert len(set(off[0].tolist())) > 1
+
+
+def _remapped_views(canon, rows, sort_bag=False):
+    """[B, 12, view_len]: every row's view under every permutation as
+    `_slot_sort` and `_slot_remap` leave it, the bag as it lies or, as
+    the canon did until PR 45, re-sorted."""
+    from raft_tpu.ops import bag
+
+    def one(vec, sigma, tau):
+        host2, inv = canon._slot_sort(vec, sigma, tau)
+        r, ws, cnt = canon._slot_remap(vec, sigma, tau, host2, inv)
+        if sort_bag:
+            ws, cnt = bag.wide_bag_sort(ws, cnt)
+        return jnp.concatenate([r, *ws, cnt])
+
+    f = jax.vmap(jax.vmap(one, (None, 0, 0)), (0, None, None))
+    return f(jnp.asarray(rows, jnp.int32), canon._sigmas, canon._taus)
+
+
+def _sorted_bag_fingerprints(canon, rows):
+    """The slot canon's formula until PR 45, kept as the reference: the
+    positional hash of the whole 472-lane view, its bag re-sorted under
+    every permutation, then the min."""
+    from raft_tpu.ops.hashing import hash_lanes
+
+    views = _remapped_views(canon, rows, sort_bag=True)
+    return jnp.min(hash_lanes(views, seed=canon.seed), axis=-1)
+
+
+def _same_partition(a, b):
+    a, b = np.asarray(a).tolist(), np.asarray(b).tolist()
+    return len(set(a)) == len(set(b)) == len(set(zip(a, b)))
+
+
+def test_multiset_bag_fingerprints_partition_the_sample_as_the_sorted_bag_did(
+        setup, oracle, sample, views):
+    """Which 64-bit number names a class changed with PR 45, the classes
+    did not: on the walked sample, its images under two permutations and
+    a block of exact repeats, two rows share a new fingerprint iff they
+    shared one under the sorted-bag formula, and no number carried over."""
+    canon = setup.model.make_canonicalizer(True)
+    rows = np.concatenate([
+        views,
+        np.stack([setup.model.encode(st) for st in sample]).astype(np.int32),
+        views[:40]])
+    new = np.asarray(canon.fingerprints(rows))
+    old = np.asarray(jax.jit(partial(_sorted_bag_fingerprints, canon))(rows))
+    assert _same_partition(new, old)
+    classes = len(set(new.tolist()))
+    assert 40 < classes < len(rows)  # images and repeats collapsed
+    assert not set(new.tolist()) & set(old.tolist())
+
+
+def _bag_lanes(model, w):
+    """The lanes of bag word ``w`` (``len(words)`` = the counts)."""
+    names = [f"msg_w{i}" for i in range(model.packer.n_words)] + ["msg_cnt"]
+    sl = model.layout.sl(names[w])
+    return np.arange(sl.start, sl.stop)
+
+
+@pytest.fixture(scope="module")
+def busy_rows(setup, sample):
+    """Sampled rows with at least three messages in flight."""
+    model = setup.model
+    rows = np.stack([model.encode(st) for st in sample
+                     if len(st["messages"]) >= 3]).astype(np.int32)
+    assert len(rows) >= 16
+    return rows[:32]
+
+
+@pytest.mark.parametrize(
+    "edit", ["shuffle_occupied", "rotate_all_slots", "count", "key_word"])
+def test_the_bags_slot_order_is_no_part_of_a_fingerprint_and_its_content_is(
+        setup, busy_rows, edit):
+    """The bag enters as a multiset: the same records in other slots,
+    the free slots among them or not, are the same fingerprint (a
+    remapped bag is hashed as it lies, unsorted); one delivery count or
+    one key word changed is another."""
+    model = setup.model
+    canon = model.make_canonicalizer(True)
+    nw = model.packer.n_words
+    M = model.p.msg_slots
+    rng = np.random.default_rng(45)
+    out = busy_rows.copy()
+    for b, row in enumerate(busy_rows):
+        occ = np.nonzero(row[_bag_lanes(model, 0)] != int(EMPTY))[0]
+        assert len(occ) >= 3
+        if edit == "shuffle_occupied":
+            src = np.arange(M)
+            src[occ] = rng.permutation(occ)
+            while np.array_equal(src, np.arange(M)):
+                src[occ] = rng.permutation(occ)
+        elif edit == "rotate_all_slots":
+            src = np.roll(np.arange(M), 1 + b)  # free slots in the middle
+        if edit in ("shuffle_occupied", "rotate_all_slots"):
+            for w in range(nw + 1):
+                lanes = _bag_lanes(model, w)
+                out[b, lanes] = row[lanes][src]
+        elif edit == "count":
+            out[b, _bag_lanes(model, nw)[occ[-1]]] += 1
+        else:
+            out[b, _bag_lanes(model, nw - 1)[occ[0]]] ^= 1
+    assert not (out == busy_rows).all(axis=1).any()
+    before = np.asarray(canon.fingerprints(busy_rows))
+    after = np.asarray(canon.fingerprints(out))
+    if edit in ("shuffle_occupied", "rotate_all_slots"):
+        assert np.array_equal(after, before)
+        # the raw key of the in-chunk dedup is positional: other lanes
+        assert (np.asarray(canon.raw_fingerprints(out))
+                != np.asarray(canon.raw_fingerprints(busy_rows))).all()
+    else:
+        assert (after != before).all()
+
+
+@pytest.mark.parametrize("seed", [0, 0x5EED])
+def test_both_canons_hash_a_bag_through_the_one_helper(setup, views, seed):
+    """`ops.symmetry.bag_hash_pair` is the tree's one multiset hash of a
+    bag: a plain `Canonicalizer` over the same layout and packer gives
+    the same pair for the same words, counts and seed, and the slot
+    canon's fingerprint is the least of `Canonicalizer`'s own hash (the
+    312 non-bag lanes by position XOR that pair) over the 12 remapped
+    views, their bags as they lie, bit for bit under either seed."""
+    from raft_tpu.ops import symmetry
+
+    model = setup.model
+    assert kraft_reconfig.bag_hash_pair is symmetry.bag_hash_pair
+    slot = model.make_canonicalizer(True, seed=seed)
+    plain = symmetry.Canonicalizer(
+        model.layout, model.packer, symmetry=False, seed=seed)
+    nb = len(plain._nonbag_lanes)
+    assert nb == 312 and np.array_equal(plain._nonbag_lanes, np.arange(nb))
+    rows = views[:64]
+    words = [rows[:, _bag_lanes(model, w)]
+             for w in range(model.packer.n_words)]
+    cnt = rows[:, _bag_lanes(model, len(words))]
+    ba, bb = symmetry.bag_hash_pair(words, cnt, seed)
+    pa, pb = plain._bag_hash_pair(jnp.asarray(rows[:, :model.layout.view_len]))
+    assert np.array_equal(ba, pa) and np.array_equal(bb, pb)
+    images = _remapped_views(slot, rows)
+    assert images.shape == (64, 12, model.layout.view_len)
+    want = jnp.min(plain._perm_hash(images), axis=-1)
+    assert np.array_equal(np.asarray(slot.fingerprints(rows)),
+                          np.asarray(want))
+
+
+def test_seeded_slot_canon_is_another_family_over_the_same_classes(
+        setup, oracle, views):
+    """The collision audit's second family: another seed gives other
+    fingerprints to the same rows (the rows' hash and the bag's hash both
+    take the seed: rows with an empty bag and a bag alone both move) and
+    the same partition."""
+    model = setup.model
+    a0 = model.make_canonicalizer(True, seed=0)
+    a1 = model.make_canonicalizer(True, seed=0x5EED)
+    init = model.encode(oracle.init_state()).astype(np.int32)
+    assert (init[_bag_lanes(model, 0)] == int(EMPTY)).all()
+    rows = np.concatenate([views[:96], views[:32], init[None]])
+    f0, f1 = (np.asarray(a.fingerprints(rows)) for a in (a0, a1))
+    assert (f0 != f1).all()
+    assert _same_partition(f0, f1)
+    words = [rows[:96, _bag_lanes(model, w)]
+             for w in range(model.packer.n_words)]
+    cnt = rows[:96, _bag_lanes(model, len(words))]
+    p0 = np.stack(kraft_reconfig.bag_hash_pair(words, cnt, 0))
+    p1 = np.stack(kraft_reconfig.bag_hash_pair(words, cnt, 0x5EED))
+    busy = (words[0] != int(EMPTY)).any(axis=1)
+    assert busy.sum() > 48 and (p0 != p1).all(axis=0)[busy].all()
+
+
+def test_the_formula_revision_is_in_the_checkpoint_identity(tmp_path):
+    """The slot canon's fingerprints changed with PR 45 and no other
+    canon's did: a KRaftWithReconfig engine's ident carries the canon's
+    own `hashv`, a checkpoint written under the sorted-bag formula
+    (`hashv=5`) is refused on load, and a Raft engine's ident is the
+    string it was."""
+    from raft_tpu.checker.bfs import BFSChecker
+    from raft_tpu.checker.device_bfs import DeviceBFS
+    from raft_tpu.models.raft import RaftParams, cached_model
+    from raft_tpu.obs import hashv_of
+    from raft_tpu.ops.symmetry import Canonicalizer
+    from raft_tpu.parallel.sharded import ShardedBFS
+    from raft_tpu.resilience import CheckpointMismatch
+    from raft_tpu.resilience import ckpt as rckpt
+
+    model = kraft_reconfig.cached_model(SMALLP)
+    invs = INVARIANTS[:2]
+    assert (Canonicalizer.hashv, kraft_reconfig.SlotCanonicalizer.hashv) == (
+        5, 6)
+    host = BFSChecker(model, invariants=invs, symmetry=True)
+    dev = DeviceBFS(model, invariants=invs, symmetry=True, chunk=64,
+                    frontier_cap=1 << 8, seen_cap=1 << 10,
+                    journal_cap=1 << 10)
+    mesh = ShardedBFS(model, invariants=invs, symmetry=True,
+                      devices=jax.devices()[:2], chunk=64)
+    for eng in (host, dev, mesh):
+        ident = eng._ckpt_ident()
+        assert "/hashv=6/wl=1/" in ident and hashv_of(ident) == 6
+    assert host._telemetry_manifest()["hashv"] == 6
+
+    path = str(tmp_path / "run.npz")
+    first = host.run(max_depth=2, checkpoint_path=path, checkpoint_every_s=0)
+    ck, _gen, _skipped = rckpt.load_npz(path)
+    assert str(ck["spec"]) == host._ckpt_ident()
+    resumed = host.run(max_depth=3, resume=path)
+    assert resumed.depth_counts[: len(first.depth_counts)] == (
+        first.depth_counts)
+    old = str(tmp_path / "sorted_bag.npz")
+    rckpt.save_npz(old, dict(
+        ck, spec=host._ckpt_ident().replace("/hashv=6/", "/hashv=5/")))
+    with pytest.raises(CheckpointMismatch, match="checkpoint is for spec"):
+        host.run(max_depth=3, resume=old)
+
+    raft = DeviceBFS(cached_model(RaftParams(
+        n_servers=3, n_values=1, max_elections=2, max_restarts=0,
+        msg_slots=32)), invariants=("LeaderHasAllAckedValues",
+                                    "NoLogDivergence"),
+        symmetry=True, chunk=64, frontier_cap=1 << 8, seen_cap=1 << 10,
+        journal_cap=1 << 10)
+    assert raft._ckpt_ident() == (
+        "Raft/RaftParams(n_servers=3, n_values=1, max_elections=2, "
+        "max_restarts=0, msg_slots=32, election_quorum=None, "
+        "replication_quorum=None, strict_send_once=False, "
+        "has_pending_response=True, trunc_term_mismatch=False, "
+        "has_fsync=False, fsync_leader_before_ae=False, "
+        "fsync_leader_quorum=False, fsync_follower_reply=False, "
+        "net_faults=False, max_msg_copies=2, dyn_consts=(), fleet=False)"
+        "/W=144/sym=True/seed=0/hashv=5/wl=3"
+        "/inv=LeaderHasAllAckedValues,NoLogDivergence")
 
 
 def _break_roles(st):
