@@ -538,7 +538,7 @@ def _check(args, chaos_spec, trace) -> int:
     checker = make_checker({})
 
     if args.resume is not None:
-        # fail fast, BEFORE the multi-second precompile: prove the
+        # fail fast, BEFORE the run's first compiles (many seconds): prove the
         # checkpoint exists, loads (falling back through generations)
         # and matches this exact model/capacity identity
         from .resilience import ckpt as rckpt

@@ -3,7 +3,7 @@
 The survey's north star — every variant's ``Next`` relation hand-lowered
 to fused, donated, fixed-signature device programs — rests on contracts
 no type system sees: wave programs must alias their capacity-shaped
-carries, deep runs must stay on a closed set of precompiled signatures,
+carries, deep runs must stay on a closed, declared set of signatures,
 guard passes must write no W-wide successor rows, wave loops must stay
 zero-extra-sync, and fleet-packable guards must reach dynamic constants
 through the ``_cv`` lane indirection. Each pass in this package proves

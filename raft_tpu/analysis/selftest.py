@@ -9,7 +9,7 @@ The acceptance contract: every mutation exits 3 with a finding naming
 the pass and a ``file:line``.
 
   undonated-carry   drop the cov carry from DeviceBFS.WAVE_DONATE
-  open-signature    skew _seen_size_for off the precompiled ladder
+  open-signature    skew _seen_size_for off the seen ladder
   wide-guard-write  leak a W-wide block into a kept guard output
   injected-sync     insert a jax.device_get inside the wave loop
   raw-const-read    read a FLEET_DYN constant around the _cv lane
@@ -46,8 +46,8 @@ def undonated_carry():
 
 @contextlib.contextmanager
 def open_signature():
-    """Skew the runtime merge-target chooser off the precompiled
-    ladder — the round-5 retrace cliff, reintroduced."""
+    """Skew the runtime merge-target chooser off the seen ladder —
+    the round-5 retrace cliff, reintroduced."""
     from ..checker.device_bfs import DeviceBFS
 
     orig = DeviceBFS._seen_size_for

@@ -1,21 +1,22 @@
-"""Signature-closure auditor: prove a deep run dispatches only
-precompiled program signatures — the retrace-cliff class, symbolically.
+"""Signature-closure auditor: prove a deep run dispatches only the
+program signatures its engine declares — the retrace-cliff class,
+symbolically.
 
 Round 5's depth-32 wave-time cliff was one mid-run compile: a seen
 merge whose target outgrew the concat total left a non-ladder-size run,
-and the next wave retraced the whole wave program at a
-never-precompiled shape (most of that wave's wall time). The engine now precompiles exactly
-``DeviceBFS.signature_inventory()``; this pass independently recomputes
-the REACHABLE signature set from the geometry primitives and proves the
-two are equal:
+and the next wave retraced the whole wave program at a shape off the
+seen ladder (most of that wave's wall time). The engine declares the
+set a run can dispatch, ``DeviceBFS.signature_inventory()``; this pass
+independently recomputes the REACHABLE signature set from the geometry
+primitives and proves the two are equal:
 
   * ladder well-formedness — ``_seen_sizes`` strictly increasing powers
     of two ending at TOPSZ (= pow2 ceiling of max_seen_cap);
   * dispatch closure — ``_seen_size_for`` (the runtime target chooser)
     probed at every ladder boundary +/-1 must return exactly the
     first-size-at-least member the ladder implies, always inside the
-    precompiled wave set, and overflow past TOPSZ must raise;
-  * merge closure — the precompiled merge keys must cover every
+    declared wave set, and overflow past TOPSZ must raise;
+  * merge closure — the declared merge keys must cover every
     (size, target >= size) pair at the shape of the wave's fingerprint
     buffer (FCAP lanes: what the wave program hands the merge);
   * pad-up proof — ``eval_shape`` of every merge spec body returns
@@ -23,7 +24,7 @@ two are equal:
     caused the cliff);
   * growth chain — ``next_cap`` frontier/journal growth from the
     current capacity terminates at the cap ceiling in finitely many
-    chunk-aligned steps (growth retraces are bounded and precompilable);
+    chunk-aligned steps (growth retraces are bounded);
   * sharded arity — RunLSM pre-creates its full ladder, so the chunk
     program's run-tuple arity can never change mid-run;
   * fleet grouping — FLEET_DYN names resolve to real params fields
@@ -77,19 +78,19 @@ def _check_device(fam: str, eng, findings: list) -> int:
     wave_set = [s for tag, *rest in inv if tag == "wave" for s in rest]
     merge_set = {tuple(sig[1:]) for sig in inv if sig[0] == "merge"}
 
-    # precompiled wave set == the ladder, exactly
+    # declared wave set == the ladder, exactly
     checked += 1
     if wave_set != list(sizes):
         findings.append(Finding(
             PASS_ID, "error", path, line,
-            f"device:{fam}: precompiled wave signatures {wave_set} != "
+            f"device:{fam}: declared wave signatures {wave_set} != "
             f"seen ladder {list(sizes)}",
             {"inventory": wave_set, "ladder": list(sizes)},
         ))
 
     # dispatch closure: probe the runtime target chooser at every
     # boundary; it must agree with the independent first-geq rule and
-    # stay inside the precompiled set
+    # stay inside the declared set
     probes = {1}
     for s in sizes:
         probes.update(x for x in (s - 1, s, s + 1) if 1 <= x <= eng.TOPSZ)
@@ -101,10 +102,10 @@ def _check_device(fam: str, eng, findings: list) -> int:
             findings.append(Finding(
                 PASS_ID, "error", path, line,
                 f"device:{fam}: _seen_size_for({n}) -> {got}, outside "
-                f"the precompiled set (expected {want}) — a deep run "
-                f"dispatching this target retraces mid-run",
+                f"the set a run can dispatch (expected {want}) — a deep "
+                f"run dispatching this target retraces mid-run",
                 {"n": n, "got": got, "expected": want,
-                 "precompiled": wave_set},
+                 "declared": wave_set},
             ))
     checked += 1
     try:
@@ -112,7 +113,7 @@ def _check_device(fam: str, eng, findings: list) -> int:
         findings.append(Finding(
             PASS_ID, "error", path, line,
             f"device:{fam}: _seen_size_for(TOPSZ+1) did not raise — the "
-            f"capacity guard would dispatch an unprecompiled signature",
+            f"capacity guard would dispatch an undeclared signature",
         ))
     except OverflowError:
         pass
@@ -128,7 +129,7 @@ def _check_device(fam: str, eng, findings: list) -> int:
         mpath, mline = site_of(cls.signature_inventory)
         findings.append(Finding(
             PASS_ID, "error", mpath, mline,
-            f"device:{fam}: precompiled merge signatures differ from "
+            f"device:{fam}: declared merge signatures differ from "
             f"the reachable (size, target>=size) closure at the wave "
             f"buffer's shape {lshapes}",
             {"missing": sorted(
@@ -152,7 +153,7 @@ def _check_device(fam: str, eng, findings: list) -> int:
                 PASS_ID, "error", spath, sline,
                 f"device:{fam}: merge {key} produces shape {out.shape} "
                 f"instead of exactly ({target},) — the next wave would "
-                f"retrace at a never-precompiled seen size",
+                f"retrace at a seen size off the ladder",
                 {"key": str(key), "out_shape": list(out.shape)},
             ))
 

@@ -5,8 +5,8 @@ everything else async dispatch — is what keeps the host out of the
 device's way (and keeps telemetry from perturbing what it measures: the
 observatory PR's first design cost a sync per chunk and skewed every
 stage it attributed). This pass walks the AST of the HOT loop bodies
-(``DeviceBFS.run`` / ``run_fleet``,
-``ShardedBFS.run`` / ``run_fleet``) and flags calls that force a
+(``DeviceBFS.run``, ``ShardedBFS.run`` and the ``run_fleet`` they share,
+``checker/engine.py`` ``FleetQueue``) and flags calls that force a
 host-device round trip inside a ``for``/``while`` body:
 
   * ``jax.device_get(...)`` / ``jax.block_until_ready(...)``
@@ -37,10 +37,9 @@ BLESS_MARK = "lint: sync-ok"
 # sync-free; host-side modules (checker/bfs.py, simulate) are excluded
 # by policy — they ARE the host loop.
 HOT_SCOPES = {
-    os.path.join("raft_tpu", "checker", "device_bfs.py"):
-        ("run", "run_fleet"),
-    os.path.join("raft_tpu", "parallel", "sharded.py"):
-        ("run", "run_fleet"),
+    os.path.join("raft_tpu", "checker", "device_bfs.py"): ("run",),
+    os.path.join("raft_tpu", "parallel", "sharded.py"): ("run",),
+    os.path.join("raft_tpu", "checker", "engine.py"): ("run_fleet",),
 }
 
 # the hook the mutation self-test overrides: {rel_path: source_text}

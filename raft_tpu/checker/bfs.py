@@ -26,11 +26,14 @@ from ..obs import (
     COMPILES, MemWatch, NULL_TELEMETRY, device_budget, setup_phase, span,
     traced_run,
 )
-from ..obs.events import hashv_of
 from ..ops.hashing import U64_MAX
 from ..ops.symmetry import Canonicalizer
 from ..resilience import ckpt as rckpt
 from ..resilience.errors import CapacityOverflow
+from .engine import (
+    canon_ident, manifest_fields, resume_events, run_stats, summary_fields,
+    wave_row,
+)
 
 
 def _in_sorted(sorted_arr: np.ndarray, vals: np.ndarray) -> np.ndarray:
@@ -246,15 +249,7 @@ class BFSChecker:
 
         tel.open_run(self._telemetry_manifest())
         if resume is not None:
-            if ck_skipped:
-                tel.event(
-                    "ckpt_generation", path=resume, generation=ck_gen,
-                    skipped=list(ck_skipped),
-                )
-            tel.event(
-                "resume", path=resume, generation=ck_gen, depth=depth,
-                distinct=distinct,
-            )
+            resume_events(tel, resume, ck_gen, ck_skipped, depth, distinct)
         metrics: list[dict] | None = [] if collect_metrics else None
         last_ckpt = time.perf_counter()
         # the row's device_s counts the jax-facing sections of a chunk
@@ -467,53 +462,22 @@ class BFSChecker:
                         "wave_emit": int(emit_bytes),
                     })
                     hbm_frac = round(frac, 6)
-                wm = {
-                    "depth": depth,
-                    "frontier": prev_frontier,
-                    "new": len(wave_states),
-                    "distinct": distinct,
-                    "generated": n_cand_total,
-                    "generated_total": total,
-                    "terminal": terminal,
-                    "dedup_hit_rate": round(
-                        1.0 - len(wave_states) / max(1, n_cand_total), 4),
-                    # the host engine has no in-chunk dedup; the declared
-                    # keys still appear so one consumer reads all three
-                    # engines
-                    "canon_dup_lanes": 0,
-                    "canon_dup_rate": 0.0,
-                    "canon_tier3_local": 0,
-                    "canon_tier3_full": 0,
-                    "overflow_bits": 0,
-                    "lsm_runs": 1,
-                    "lsm_lanes": int(len(seen)),
-                    # emit gauges (round 6): rows/bytes the cursor-append
-                    # emit wrote this wave; the host engine has no fixed-
-                    # capacity frontier buffer, so fill is reported as 0
-                    "emit_rows": len(wave_states),
-                    "emit_bytes": emit_bytes,
-                    "frontier_fill": 0.0,
-                    # sparse-expand gauges: enabled fraction of the
-                    # dense candidate grid this wave, and how many
-                    # extra fixed-size apply blocks the host path ran
-                    # beyond one per chunk (the host analog of the
-                    # device engines' budget-overflow bit — it loops
-                    # instead of aborting)
-                    "enabled_density": round(
-                        n_cand_total / max(1, prev_frontier * model.A), 4
-                    ),
-                    "expand_budget_ovf": wave_extra,
-                    # clocks unrounded: device_s + host_s + ckpt_s ==
-                    # wave_s
-                    "wave_s": wave_s_val,
-                    "elapsed_s": el,
-                    "distinct_per_s": round(distinct / el, 1),
-                    "device_s": dev_s,
-                    "host_s": max(0.0, wave_s_val - dev_s - ckpt_s),
-                    "ckpt_s": ckpt_s,
-                    "tel_s": tel_s_last,
-                    "hbm_frac": hbm_frac,
-                }
+                wm = wave_row(
+                    depth=depth, frontier=prev_frontier,
+                    new=len(wave_states), distinct=distinct,
+                    generated=n_cand_total, generated_total=total,
+                    terminal=terminal, canon=(0, 0, 0), overflow_bits=0,
+                    lsm_runs=1, lsm_lanes=int(len(seen)),
+                    wave_s=wave_s_val, elapsed_s=el,
+                    # no fixed-capacity frontier buffer: fill is 0
+                    emit_bytes=emit_bytes, frontier_fill=0.0,
+                    # extra fixed-size apply blocks past one per chunk:
+                    # the host analog of the device engines' budget
+                    # overflow bit (it loops instead of aborting)
+                    A=model.A, expand_budget_ovf=wave_extra,
+                    device_s=dev_s, ckpt_s=ckpt_s, tel_s=tel_s_last,
+                    hbm_frac=hbm_frac,
+                )
                 t_tel = time.perf_counter()
                 tel.wave(wm)
                 if tel.active:
@@ -541,9 +505,7 @@ class BFSChecker:
             )
 
         dt = time.perf_counter() - t0
-        # what the run loaded into the process, and its top-level spans'
-        # seconds, read beside dt: they add up to it
-        run_stats = {**COMPILES.run_stats(comp_run), **ph.top_seconds()}
+        stats_run = run_stats(comp_run, ph)
         if violation is not None:
             exit_cause = "violation"
         elif exit_cause is None:
@@ -553,28 +515,20 @@ class BFSChecker:
                 self._coverage_fields(depth, cov, len(seen), depth_counts),
                 final=True,
             )
-        tel.close_run({
-            "engine": "host",
-            "ident": self._ckpt_ident(),
-            "exit_cause": exit_cause,
-            "violation": violation.invariant if violation else None,
-            "distinct": distinct,
-            "total": total,
-            "depth": depth,
-            "terminal": terminal,
-            "seconds": round(dt, 3),
-            "distinct_per_s": round(distinct / dt, 1) if dt > 0 else 0.0,
-            "exhausted": exhausted and violation is None,
-            "peak_frontier_cap": int(max(depth_counts)),
-            "peak_journal_cap": int(next_gid - len(self._init_distinct)),
-            "seen_lanes": int(len(seen)),
-            "canon_dup_rate": 0.0,
-            "canon_tier3_local": 0,
-            "canon_tier3_full": 0,
-            **run_stats,
-            "programs": COMPILES.programs(comp_run),
-            **(memwatch.summary_fields() if memwatch is not None else {}),
-        })
+        tel.close_run(summary_fields(
+            self, "host",
+            exit_cause=exit_cause,
+            violation=violation.invariant if violation else None,
+            distinct=distinct, total=total, depth=depth,
+            terminal=terminal, seconds=dt,
+            exhausted=exhausted and violation is None,
+            peak_frontier_cap=int(max(depth_counts)),
+            peak_journal_cap=int(next_gid - len(self._init_distinct)),
+            seen_lanes=int(len(seen)), canon_dup_rate=0.0,
+            stats=stats_run, programs=COMPILES.programs(comp_run),
+            memwatch=memwatch,
+            canon_tier3_local=0, canon_tier3_full=0,
+        ))
         trace = self.reconstruct_trace(violation) if violation else None
         return CheckResult(
             distinct=distinct,
@@ -590,7 +544,7 @@ class BFSChecker:
             metrics=metrics,
             coverage=[[int(x) for x in row] for row in cov] if K else None,
             exit_cause=exit_cause,
-            stats=run_stats,
+            stats=stats_run,
         )
 
     # ---------------- fleet (packed co-resident jobs) ----------------
@@ -832,45 +786,22 @@ class BFSChecker:
                 distinct = int(distinct_j.sum())
                 total = int(total_j.sum())
                 n_cand_total = int(cand_by_job.sum())
-                tel.wave({
-                    "depth": depth,
-                    "frontier": prev_frontier,
-                    "new": len(wave_states),
-                    "distinct": distinct,
-                    "generated": n_cand_total,
-                    "generated_total": total,
-                    "terminal": int(terminal_j.sum()),
-                    "dedup_hit_rate": round(
-                        1.0 - len(wave_states) / max(1, n_cand_total), 4),
-                    "canon_dup_lanes": 0,
-                    "canon_dup_rate": 0.0,
-                    "canon_tier3_local": 0,
-                    "canon_tier3_full": 0,
-                    "overflow_bits": 0,
-                    "lsm_runs": 1,
-                    "lsm_lanes": int(len(seen)),
-                    "emit_rows": len(wave_states),
-                    "emit_bytes": wave_sb.nbytes + wave_pb.nbytes
+                # packed-fleet waves are not phase-split (the shared
+                # group run is throughput-oriented): all of a wave is
+                # host_s
+                tel.wave(wave_row(
+                    depth=depth, frontier=prev_frontier,
+                    new=len(wave_states), distinct=distinct,
+                    generated=n_cand_total, generated_total=total,
+                    terminal=int(terminal_j.sum()), canon=(0, 0, 0),
+                    overflow_bits=0, lsm_runs=1, lsm_lanes=int(len(seen)),
+                    wave_s=wave_s_val, elapsed_s=el,
+                    emit_bytes=wave_sb.nbytes + wave_pb.nbytes
                     + wave_cb.nbytes,
-                    "frontier_fill": 0.0,
-                    "enabled_density": round(
-                        n_cand_total / max(1, prev_frontier * model.A), 4
-                    ),
-                    "expand_budget_ovf": 0,
-                    "wave_s": wave_s_val,
-                    "elapsed_s": el,
-                    "distinct_per_s": round(distinct / el, 1),
-                    # packed-fleet waves are not phase-split (the shared
-                    # group run is throughput-oriented); the declared
-                    # observatory keys still appear so one consumer
-                    # reads every engine's stream
-                    "device_s": 0.0,
-                    "host_s": wave_s_val,
-                    "ckpt_s": 0.0,
-                    "tel_s": 0.0,
-                    "hbm_frac": None,
-                    "jobs_active": int(active.sum()),
-                })
+                    frontier_fill=0.0, A=model.A, expand_budget_ovf=0,
+                    device_s=0.0, ckpt_s=0.0, tel_s=0.0, hbm_frac=None,
+                    jobs_active=int(active.sum()),
+                ))
                 if verbose:
                     print(
                         f"fleet depth {depth}: frontier {len(frontier)}, "
@@ -921,31 +852,23 @@ class BFSChecker:
         first_viol = next((v for v in violation_j if v is not None), None)
         # what the group loaded into the process (obs/compiles.py); the
         # per-job summaries below repeat it, the jobs being co-resident
-        run_stats = COMPILES.run_stats(comp_run)
-        tel.close_run({
-            "engine": "host",
-            "ident": self._ckpt_ident(),
-            "exit_cause": "violation" if first_viol is not None
+        stats_run = COMPILES.run_stats(comp_run)
+        journal_rows = int(next_gid - len(self._init_distinct))
+        tel.close_run(summary_fields(
+            self, "host",
+            exit_cause="violation" if first_viol is not None
             else (exit_cause_global or "exhausted"),
-            "violation": first_viol.invariant if first_viol else None,
-            "distinct": int(distinct_j.sum()),
-            "total": int(total_j.sum()),
-            "depth": depth,
-            "terminal": int(terminal_j.sum()),
-            "seconds": round(dt, 3),
-            "distinct_per_s": round(int(distinct_j.sum()) / dt, 1)
-            if dt > 0 else 0.0,
-            "exhausted": all(r.exhausted for r in results),
-            "peak_frontier_cap": int(max(
+            violation=first_viol.invariant if first_viol else None,
+            distinct=int(distinct_j.sum()), total=int(total_j.sum()),
+            depth=depth, terminal=int(terminal_j.sum()), seconds=dt,
+            exhausted=all(r.exhausted for r in results),
+            peak_frontier_cap=int(max(
                 max(dc) for dc in depth_counts_j)),
-            "peak_journal_cap": int(next_gid - len(self._init_distinct)),
-            "seen_lanes": int(len(seen)),
-            "canon_dup_rate": 0.0,
-            "canon_tier3_local": 0,
-            "canon_tier3_full": 0,
-            "fleet_jobs": J,
-            **run_stats,
-        })
+            peak_journal_cap=journal_rows,
+            seen_lanes=int(len(seen)), canon_dup_rate=0.0,
+            stats=stats_run,
+            canon_tier3_local=0, canon_tier3_full=0, fleet_jobs=J,
+        ))
         # per-job synthesized runs: one manifest/coverage/summary triple
         # per job so obs_report and the schema checker see per-job
         # digests in the one multiplexed stream
@@ -962,30 +885,20 @@ class BFSChecker:
                     },
                     final=True,
                 )
-                tel.close_run({
-                    "engine": "host",
-                    "ident": self._ckpt_ident(),
-                    "exit_cause": r.exit_cause,
-                    "violation": r.violation.invariant
+                tel.close_run(summary_fields(
+                    self, "host",
+                    exit_cause=r.exit_cause,
+                    violation=r.violation.invariant
                     if r.violation else None,
-                    "distinct": r.distinct,
-                    "total": r.total,
-                    "depth": r.depth,
-                    "terminal": r.terminal,
-                    "seconds": round(dt, 3),
-                    "distinct_per_s": round(r.distinct / dt, 1)
-                    if dt > 0 else 0.0,
-                    "exhausted": r.exhausted,
-                    "peak_frontier_cap": int(max(r.depth_counts)),
-                    "peak_journal_cap": int(
-                        next_gid - len(self._init_distinct)),
-                    "seen_lanes": int(len(seen)),
-                    "canon_dup_rate": 0.0,
-                    "canon_tier3_local": 0,
-                    "canon_tier3_full": 0,
-                    **run_stats,
-                    "job": name,
-                })
+                    distinct=r.distinct, total=r.total, depth=r.depth,
+                    terminal=r.terminal, seconds=dt,
+                    exhausted=r.exhausted,
+                    peak_frontier_cap=int(max(r.depth_counts)),
+                    peak_journal_cap=journal_rows,
+                    seen_lanes=int(len(seen)), canon_dup_rate=0.0,
+                    stats=stats_run,
+                    canon_tier3_local=0, canon_tier3_full=0, job=name,
+                ))
         return results
 
     def _fleet_check_invariants(
@@ -1106,12 +1019,12 @@ class BFSChecker:
         )
 
     def _ckpt_ident(self) -> str:
-        """Same identity grammar as the device engines (hashv marks the
-        fingerprint formula revision; see DeviceBFS._ckpt_ident)."""
-        wl = getattr(self.canon, "refine_rounds", 1)
+        """Same identity grammar as the device engines (what it must
+        match is in ``DeviceBFS._ckpt_ident``; the formula part is
+        ``engine.canon_ident``)."""
         return (
             f"host/{self.model.name}/{self.model.p}/W={self.model.layout.W}"
-            f"/sym={self.canon.symmetry}/hashv={self.canon.hashv}/wl={wl}"
+            f"/{canon_ident(self.canon)}"
             f"/inv={','.join(self.invariants)}"
         )
 
@@ -1119,26 +1032,7 @@ class BFSChecker:
         """Run-provenance fields of the telemetry manifest event. The
         host engine's arrays are unbounded python/numpy buffers, so the
         capacity fields are 0 (= not capacity-limited)."""
-        dev = jax.devices()[0]
-        ident = self._ckpt_ident()
-        return {
-            "engine": "host",
-            "ident": ident,
-            "hashv": hashv_of(ident),
-            "model": self.model.name,
-            "platform": dev.platform,
-            "device": str(getattr(dev, "device_kind", dev.platform)),
-            "device_count": 1,
-            "chunk": self.chunk,
-            "frontier_cap": 0,
-            "journal_cap": 0,
-            "max_seen_cap": 0,
-            "valid_cap": 0,
-            "symmetry": bool(self.canon.symmetry),
-            "invariants": list(self.invariants),
-            "action_names": list(getattr(self.model, "ACTION_NAMES", ())),
-            "when": time.strftime("%Y-%m-%dT%H:%M:%S"),
-        }
+        return manifest_fields(self, "host", jax.devices()[0])
 
     def _check_invariants(self, states: np.ndarray, base_gid: int, depth: int):
         """Batched invariant evaluation; returns the first (in exploration

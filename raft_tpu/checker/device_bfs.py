@@ -62,15 +62,19 @@ import numpy as np
 from jax import lax
 
 from ..obs import (
-    COMPILES, MemWatch, NULL_TELEMETRY, device_budget, setup_phase, span,
-    stage, traced_run,
+    COMPILES, MemWatch, NULL_TELEMETRY, device_budget, setup_phase, stage,
+    traced_run,
 )
-from ..obs.events import hashv_of
 from ..ops.hashing import U64_MAX, sort_u64
 from ..ops.symmetry import Canonicalizer, canon_chunk
 from ..resilience import ckpt as rckpt
 from ..resilience.errors import CapacityOverflow
 from .bfs import CheckResult, Violation
+from .engine import (
+    FleetQueue, canon_ident, compact_chunk, expand_chunk, loop_exit,
+    manifest_fields, phase_clocks, resume_events, run_stats,
+    summary_fields, wave_row,
+)
 from .lsm import pow2_at_least
 from .util import (
     GROWTH, HEADROOM, I32_MAX, dedup_plan, dense_prefix_sel, emit_append,
@@ -79,7 +83,7 @@ from .util import (
 )
 
 
-class DeviceBFS:
+class DeviceBFS(FleetQueue):
     """Single-device BFS with device-resident frontier/seen-runs/journal.
 
     Capacities are static (XLA shapes). The frontier/journal GROW between
@@ -199,7 +203,8 @@ class DeviceBFS:
         # (the old binary-counter LSM probed up to 3 on deep waves),
         # and a host-side repack moves the whole set over PCIe and
         # back; the single-run design probes once and never leaves
-        # HBM. The few (size -> size) merge signatures precompile.
+        # HBM. The (size -> size) merge signatures are few and finite
+        # (signature_inventory).
         self.R0 = pow2_at_least(self.VC)
         self.SCAP = self.MAX_SCAP  # capacity bound (kept for callers)
         self.TOPSZ = pow2_at_least(self.MAX_SCAP)
@@ -264,7 +269,7 @@ class DeviceBFS:
         and _lsm_export / probe_sorted are padding-blind. Without the
         pad-up, a merge whose target outgrew the concat total left a
         non-ladder-size seen run, and the NEXT wave retraced + recompiled
-        the whole wave program at a never-precompiled shape: that one
+        the whole wave program at a shape off the seen ladder: that one
         mid-run compile was round 5's unexplained final-wave cliff at
         depth 32 (most of that wave's wall time)."""
         target = self._seen_size_for(new_real)
@@ -337,47 +342,10 @@ class DeviceBFS:
         """Stages 1-2: guard/dense expand + compaction (+ the budgeted
         sparse apply). Returns the compacted successor block and every
         lane the later stages consume."""
-        model = self.model
-        C, A, W, VC = self.chunk, self.A, self.W, self.VC
-        batch = lax.dynamic_slice(frontier, (cursor, jnp.int32(0)), (C, W))
-        live = (jnp.arange(C, dtype=jnp.int32) + cursor) < fcount
-        if self._sparse:
-            # guard pass only: valid/rank/ovf over the dense [C, A]
-            # grid without materializing any W-wide successor rows
-            # (DCE-derived from _expand1, bit-identical by construction)
-            valid, rank, ovf = jax.vmap(model.guards1)(batch)
-        else:
-            succs, valid, rank, ovf = jax.vmap(model._expand1)(batch)
-        valid = valid & live[:, None]
-        expand_ovf = jnp.any(valid & ovf)
-        n_gen = jnp.sum(valid)
-        terminal = jnp.sum(live & ~jnp.any(valid, axis=1))
-
-        # 2. compact valid lanes: sel[j] = flat lane of the j-th valid succ
-        vflat = valid.reshape(-1)
-        vpos = jnp.cumsum(vflat) - 1
-        compact_ovf = n_gen > VC
-        sdst = jnp.where(vflat, jnp.minimum(vpos, VC), VC)
-        sel = (
-            jnp.full((VC + 1,), C * A, jnp.int32)
-            .at[sdst]
-            .set(jnp.arange(C * A, dtype=jnp.int32))[:VC]
-        )
-        selv = sel < C * A
-        if self._sparse:
-            # apply pass: construct successors ONLY for the compacted
-            # worklist lanes, vmapped per group over the static budget
-            # plan (precompiled signatures). Budget overflow folds into
-            # the compaction bit: both mean "a static worklist bound
-            # was exceeded, raise the knob".
-            flatc, apply_ovf = model.sparse_apply(batch, sel, selv, self._plan)
-            compact_ovf = compact_ovf | apply_ovf
-        else:
-            flatp = jnp.concatenate(
-                [succs.reshape(C * A, W), jnp.zeros((1, W), jnp.int32)],
-                axis=0,
-            )
-            flatc = flatp[sel]  # [VC, W]
+        batch, succs, valid, rank, n_gen, terminal, expand_ovf = expand_chunk(
+            self.model, self._sparse, frontier, cursor, fcount, self.chunk)
+        flatc, sel, selv, compact_ovf = compact_chunk(
+            self.model, self._plan, batch, succs, valid, n_gen, self.VC)
         return (flatc, sel, selv, valid, rank, n_gen, terminal,
                 expand_ovf, compact_ovf)
 
@@ -583,70 +551,30 @@ class DeviceBFS:
         )
         return (*out[1:-1], out[-1][:self.FCAP])
 
-    # ---------------- precompile ----------------
-
-    def precompile(self) -> None:
-        """Compile (and execute once, on zero/sentinel buffers) every
-        device program a run at the CURRENT capacities can need: the
-        chunk program and the full LSM merge ladder. A mid-run compile
-        lands in one wave's wall time and reads as a stall; after this
-        warmup — which the persistent compile cache turns into disk
-        reads in later processes — the timed region never compiles.
-        Growth steps still retrace, so benchmark callers should start
-        at their final capacities. The whole warmup is one host span,
-        "precompile"."""
-        with span("precompile"):
-            self._precompile_programs()
+    # ---------------- the signatures a run can dispatch ----------------
 
     def signature_inventory(self):
         """The FINITE signature universe a run at the CURRENT capacities
-        dispatches, in precompile order: a ``("wave", seen_size)`` per
+        can dispatch, in ladder order: a ``("wave", seen_size)`` per
         seen-ladder size, each followed by the per-wave seen merges that
         size can need — ``("merge", size, (FCAP,), target)``, FCAP the
         lanes of the wave's fingerprint buffer, for every ladder target
-        >= size. ``_precompile_programs`` warms exactly
-        this set; analysis/signatures.py independently recomputes the
-        reachable set from the geometry primitives (_seen_size_for, the
-        wave buffer, the pad-up merge contract) and proves the two are
-        equal — round 5's retrace-cliff class, checked symbolically.
+        >= size. Nothing compiles them ahead of a run: each compiles in
+        the wave that first dispatches it (the row's ``compiles``).
+        analysis/signatures.py independently recomputes the reachable
+        set from the geometry primitives (_seen_size_for, the wave
+        buffer, the pad-up merge contract) and proves the two are equal
+        — round 5's retrace-cliff class, checked symbolically.
         """
         lshapes = (self.FCAP,)
         for si, size in enumerate(self._seen_sizes):
             yield ("wave", size)
             # targets >= size only: one wave adds at most pow2(FCAP)
             # real lanes, so targets further than two ladder steps up
-            # are unreachable — but warming the whole upper triangle is
-            # cheap and keeps the closure argument one-sided
+            # are unreachable — but naming the whole upper triangle
+            # keeps the closure argument one-sided
             for target in self._seen_sizes[si:]:
                 yield ("merge", size, lshapes, target)
-
-    def _precompile_programs(self) -> None:
-        W = self.W
-        frontier = jnp.zeros((self.FCAP + self.VC, W), jnp.int32)
-        for sig in self.signature_inventory():
-            if sig[0] == "wave":
-                size = sig[1]
-                seen = jnp.full((size,), U64_MAX, jnp.uint64)
-                next_buf = jnp.zeros((self.FCAP + self.VC, W), jnp.int32)
-                jparent = jnp.zeros((self.JCAP + self.VC,), jnp.int32)
-                jcand = jnp.zeros((self.JCAP + self.VC,), jnp.int32)
-                viol = jnp.full(
-                    (max(1, len(self.invariants)),), I32_MAX, jnp.int32
-                )
-                stats = jnp.zeros((self.N_STATS,), jnp.int64)
-                cov = jnp.zeros((self.n_actions, 3), jnp.int64)
-                self._wave_fn(
-                    frontier, next_buf, jparent, jcand, viol, stats, cov,
-                    np.int32(0), np.int32(0), self._occ_one, seen,
-                )
-                continue
-            # _make_seen_merge compiles AND executes each program once
-            # on fresh throwaway buffers — the cached merges must never
-            # be handed shared arrays, since a donation consumes its
-            # input.
-            key = sig[1:]
-            if key not in self._merge_cache:
-                self._merge_cache[key] = self._make_seen_merge(key)
 
     # ---------------- static audit surface ----------------
 
@@ -794,11 +722,6 @@ class DeviceBFS:
             g["max_seen_cap"] = self.MAX_SCAP * 4
         return g
 
-    def _rebuild(self, overrides: dict) -> "DeviceBFS":
-        """A fresh engine with this one's constructor kwargs plus
-        ``overrides`` (the supervisor's growth dicts)."""
-        return type(self)(**{**self._ctor_kw, **overrides})
-
     # ---------------- host driver ----------------
 
     @traced_run("device")
@@ -935,15 +858,7 @@ class DeviceBFS:
 
         tel.open_run(self._telemetry_manifest())
         if resume is not None:
-            if ck_skipped:
-                tel.event(
-                    "ckpt_generation", path=resume, generation=ck_gen,
-                    skipped=list(ck_skipped),
-                )
-            tel.event(
-                "resume", path=resume, generation=ck_gen, depth=depth,
-                distinct=distinct,
-            )
+            resume_events(tel, resume, ck_gen, ck_skipped, depth, distinct)
         metrics: list[dict] | None = [] if collect_metrics else None
         last_ckpt = time.perf_counter()
 
@@ -954,26 +869,11 @@ class DeviceBFS:
         sort_lanes_run = search_queries_run = 0
 
         while fcount and violation is None:
-            if preempt is not None and preempt.requested:
-                # SIGTERM/SIGINT honored at the wave boundary: the final
-                # snapshot block below writes the checkpoint, the CLI
-                # maps exit_cause "preempted" to rc 4
+            exit_cause = loop_exit(
+                tel, preempt, chaos, depth, checkpoint_path, max_depth,
+                time_budget_s, t0)
+            if exit_cause is not None:
                 exhausted = False
-                exit_cause = "preempted"
-                tel.event(
-                    "preempt", signame=preempt.signame, depth=depth,
-                    checkpoint=checkpoint_path,
-                )
-                break
-            if chaos is not None:
-                chaos.wave_start(depth + 1)
-            if max_depth is not None and depth >= max_depth:
-                exhausted = False
-                exit_cause = "max_depth"
-                break
-            if time_budget_s is not None and time.perf_counter() - t0 > time_budget_s:
-                exhausted = False
-                exit_cause = "time_budget"
                 break
             ph.wave(self._run_id, depth + 1, fcount)
             tw = time.perf_counter()
@@ -1086,8 +986,8 @@ class DeviceBFS:
                 break
             scount += ncount
             # fold the wave's buffer into the single seen run (device-side
-            # sort-concat; the merge-program signature set is warmed by
-            # precompile)
+            # sort-concat; a merge signature's first use compiles inside
+            # this bracket)
             with ph("seen_merge"):
                 self._merge_seen(wave_new, scount)
             depth += 1
@@ -1133,18 +1033,10 @@ class DeviceBFS:
             sort_lanes_run += int(stats_h[8])
             search_queries_run += int(stats_h[9])
             wave_s_val = time.perf_counter() - tw
-            # the wave's brackets, read once: each phase's seconds are
-            # those of its span. device_s is the host's WAIT on the
-            # device (dispatch, the one blocking fetch, the seen merge's
-            # dispatch), never device time; what the brackets leave of
-            # the wave is host_s. `telemetry` is the previous wave's
-            # bracket (Phases.take)
+            # the wave's brackets, read once a wave whoever listens
+            # (engine.phase_clocks makes the row's clocks of them):
+            # `telemetry` is the previous wave's bracket (Phases.take)
             ph_s = ph.take()
-            dispatch_s = ph_s.get("dispatch", 0.0)
-            fetch_s = ph_s.get("fetch", 0.0)
-            merge_s = ph_s.get("seen_merge", 0.0)
-            device_s = dispatch_s + fetch_s + merge_s
-            ckpt_s = ph_s.get("checkpoint", 0.0)
             comp_now = COMPILES.snapshot()
             hbm_frac = None
             if memwatch is not None:
@@ -1164,83 +1056,38 @@ class DeviceBFS:
                 continue
             with ph("telemetry"):
                 el = time.perf_counter() - t0
-                wm = {
-                    "depth": depth,
-                    "frontier": prev_fcount,
-                    "new": ncount,
-                    "distinct": distinct,
-                    "generated": wave_gen,
-                    "generated_total": total,
-                    "terminal": terminal,
-                    "dedup_hit_rate": round(1.0 - ncount / max(1, wave_gen), 4),
-                    "canon_dup_lanes": wave_dup,
-                    "canon_dup_rate": round(
-                        wave_dup / max(1, wave_gen), 4
-                    ),
-                    "canon_tier3_local": wave_t3l,
-                    "canon_tier3_full": wave_t3f,
-                    # lanes the dedup stage's merged sort sorted, summed
-                    # over the wave's chunk-steps (lane 8 of the stats
-                    # the wave already fetched): the seen run, the
-                    # prefix of the wave's buffer each step chose and VC
-                    "dedup_sort_lanes": int(stats_h[8]),
-                    # query lanes those steps searched the seen run with
-                    # (lane 9; 0 while the run is merged), and the run's
-                    # size as the wave met it
-                    "dedup_search_queries": int(stats_h[9]),
-                    "seen_lanes": seen_lanes,
-                    "overflow_bits": ovf_bits,
-                    "wave_s": wave_s_val,
-                    "elapsed_s": el,
-                    "distinct_per_s": round(distinct / el, 1),
-                    "lsm_runs": 1,
-                    "lsm_lanes": int(self._seen.shape[0]),
-                    # emit gauges (round 6): rows appended this wave,
-                    # bytes the emit WROTE (one [VC, W] i32 block + two
-                    # VC i32 journal lanes per chunk — vs the retired
-                    # scatter's full-capacity touch), and how full the
-                    # frontier buffer got — the stall watchdog reads
-                    # these to attribute growth/cliff waves
-                    "emit_rows": ncount,
-                    "emit_bytes": (
+                wm = wave_row(
+                    depth=depth, frontier=prev_fcount, new=ncount,
+                    distinct=distinct, generated=wave_gen,
+                    generated_total=total, terminal=terminal,
+                    canon=(wave_dup, wave_t3l, wave_t3f),
+                    overflow_bits=ovf_bits,
+                    lsm_runs=1, lsm_lanes=int(self._seen.shape[0]),
+                    wave_s=wave_s_val, elapsed_s=el,
+                    # one [VC, W] i32 block + two VC i32 journal lanes
+                    # per chunk, vs the retired scatter's full-capacity
+                    # touch
+                    emit_bytes=(
                         (prev_fcount + C - 1) // C
                     ) * self.VC * (4 * W + 8),
-                    "frontier_fill": round(ncount / self.FCAP, 4),
-                    # sparse-expand gauges (derived from stats the wave
-                    # already fetched — zero extra device syncs):
-                    # enabled fraction of the dense [chunk, A] candidate
-                    # grid this wave (the guard-first win scales with
-                    # its inverse), and whether the apply budget plan
-                    # overflowed (always 0 on surviving waves — the
-                    # abort above fires first; host BFS reports real
-                    # extra-batch counts here)
-                    "enabled_density": round(
-                        wave_gen / max(1, prev_fcount * self.A), 4
-                    ),
-                    "expand_budget_ovf": (ovf_bits >> 1) & 1,
-                    # host-side phase split, unrounded (zero extra
-                    # device syncs): device_s + host_s + ckpt_s ==
-                    # wave_s, and device_s == dispatch_s + fetch_s +
-                    # merge_s; tel_s is the PREVIOUS wave's telemetry
-                    # bracket (only known one wave late)
-                    "device_s": device_s,
-                    "host_s": max(0.0, wave_s_val - device_s - ckpt_s),
-                    "ckpt_s": ckpt_s,
-                    "tel_s": ph_s.get("telemetry", 0.0),
-                    "dispatch_s": dispatch_s,
-                    "fetch_s": fetch_s,
-                    "merge_s": merge_s,
-                    "grow_s": ph_s.get("grow", 0.0),
-                    # programs this iteration loaded (compiled, or read
-                    # from the persistent cache) and the seconds that
-                    # took: a growth or ladder-step compile is booked to
-                    # its wave (obs/compiles.py)
-                    "compiles": comp_now[0] - comp_wave[0],
-                    "compile_s": comp_now[1] - comp_wave[1],
-                    "hbm_frac": (
+                    frontier_fill=round(ncount / self.FCAP, 4),
+                    A=self.A, expand_budget_ovf=(ovf_bits >> 1) & 1,
+                    hbm_frac=(
                         round(hbm_frac, 4) if hbm_frac is not None else None
                     ),
-                }
+                    **phase_clocks(ph_s, comp_wave, comp_now),
+                    # this engine's own: the lanes the dedup stage's
+                    # merged sort sorted, summed over the wave's
+                    # chunk-steps (lane 8 of the stats the wave already
+                    # fetched): the seen run, the prefix of the wave's
+                    # buffer each step chose and VC; the query lanes
+                    # those steps searched the seen run with (lane 9; 0
+                    # while the run is merged); and the run's size as
+                    # the wave met it
+                    dedup_sort_lanes=int(stats_h[8]),
+                    dedup_search_queries=int(stats_h[9]),
+                    seen_lanes=seen_lanes,
+                )
                 tel.wave(wm)
                 if tel.active:
                     tel.coverage(self._coverage_fields(
@@ -1271,7 +1118,14 @@ class DeviceBFS:
         self._jcount = int(np.asarray(jax.device_get(stats))[1])
 
         dt = time.perf_counter() - t0
-        top_s = ph.top_seconds()  # read beside dt: they add up to it
+        stats_run = run_stats(
+            comp_run, ph,
+            dedup_plan=self._dedup_plan(),
+            canon_tier3_local=int(canon_prev[1]),
+            canon_tier3_full=int(canon_prev[2]),
+            dedup_sort_lanes=sort_lanes_run,
+            dedup_search_queries=search_queries_run,
+        )
         if violation is not None:
             exit_cause = "violation"
         elif exit_cause is None:
@@ -1280,35 +1134,20 @@ class DeviceBFS:
             tel.coverage(
                 self._coverage_fields(depth, cov_h, scount, depth_counts),
                 final=True)
-        run_stats = {
-            **COMPILES.run_stats(comp_run), **top_s,
-            "dedup_plan": self._dedup_plan(),
-            "canon_tier3_local": int(canon_prev[1]),
-            "canon_tier3_full": int(canon_prev[2]),
-            "dedup_sort_lanes": sort_lanes_run,
-            "dedup_search_queries": search_queries_run,
-        }
-        tel.close_run({
-            "engine": "device",
-            "ident": self._ckpt_ident(),
-            "exit_cause": exit_cause,
-            "violation": violation.invariant if violation else None,
-            "distinct": distinct,
-            "total": total,
-            "depth": depth,
-            "terminal": terminal,
-            "seconds": round(dt, 3),
-            "distinct_per_s": round(distinct / dt, 1) if dt > 0 else 0.0,
-            "exhausted": exhausted and violation is None,
-            "peak_frontier_cap": self.FCAP,
-            "peak_journal_cap": self.JCAP,
-            "seen_lanes": int(self._seen.shape[0]),
-            "canon_dup_rate": round(
+        tel.close_run(summary_fields(
+            self, "device",
+            exit_cause=exit_cause,
+            violation=violation.invariant if violation else None,
+            distinct=distinct, total=total, depth=depth,
+            terminal=terminal, seconds=dt,
+            exhausted=exhausted and violation is None,
+            peak_frontier_cap=self.FCAP, peak_journal_cap=self.JCAP,
+            seen_lanes=int(self._seen.shape[0]),
+            canon_dup_rate=round(
                 int(canon_prev[0]) / max(1, gen_prev), 4),
-            **run_stats,
-            "programs": COMPILES.programs(comp_run),
-            **(memwatch.summary_fields() if memwatch is not None else {}),
-        })
+            stats=stats_run, programs=COMPILES.programs(comp_run),
+            memwatch=memwatch,
+        ))
         trace = self.reconstruct_trace(violation) if violation else None
         res = CheckResult(
             distinct=distinct,
@@ -1327,112 +1166,8 @@ class DeviceBFS:
                 if self.n_actions else None
             ),
             exit_cause=exit_cause,
-            stats=run_stats,
+            stats=stats_run,
         )
-        return res
-
-    def run_fleet(
-        self,
-        job_names: list[str] | None = None,
-        telemetry=None,
-        checkpoint_dir: str | None = None,
-        checkpoint_every_s: float = 300.0,
-        checkpoint_keep: int = rckpt.DEFAULT_KEEP,
-        resume: bool = False,
-        skip: tuple[str, ...] = (),
-        supervise: int | None = None,
-        chaos_by_job: dict | None = None,
-        recovery_stats: dict | None = None,
-        **run_kw,
-    ) -> list:
-        """Fleet queue arm: run a fleet-bound model's jobs one at a time
-        through THIS engine instance. ``fleet_select(j)`` changes only
-        which job's constants get stamped into the init states — the
-        compiled programs are shared, so every job after the first is a
-        jit-cache hit (one precompile per layout group). Telemetry is
-        job-tagged into one multiplexed stream (obs.JobTaggedTelemetry);
-        each job checkpoints to its OWN lineage file under
-        ``checkpoint_dir`` (resilience/ckpt.py generations, named by
-        ``resilience.lineage_name`` so sanitizer collisions between job
-        names cannot alias two lineages), so the supervisor restarts /
-        resumes only the failed job. Jobs named in ``skip`` (fleet-level
-        resume) yield None in the result list.
-
-        ``supervise``: when set, each job runs under the resilience
-        supervisor with that per-job recovery budget; empty-override
-        recoveries reuse this instance's compiled programs (zero
-        recompiles), and a job whose budget is spent contributes its
-        terminal exception to the results list instead of killing the
-        fleet. ``chaos_by_job`` maps job name -> ChaosInjector for that
-        job only; ``recovery_stats`` is filled in place with job name ->
-        recovery count."""
-        import os
-
-        from ..obs.collector import JobTaggedTelemetry
-
-        model = self.model
-        J = model.fleet_jobs
-        if J == 0:
-            raise ValueError(
-                "run_fleet needs a fleet-bound model (fleet_bind)"
-            )
-        names = list(job_names) if job_names else [f"job{j}" for j in range(J)]
-        if len(names) != J:
-            raise ValueError(f"{len(names)} job names for {J} jobs")
-        results = []
-        try:
-            for j, name in enumerate(names):
-                if name in skip:
-                    results.append(None)
-                    continue
-                model.fleet_select(j)
-                kw = dict(run_kw)
-                if telemetry is not None:
-                    kw["telemetry"] = JobTaggedTelemetry(telemetry, name)
-                if chaos_by_job and name in chaos_by_job:
-                    kw["chaos"] = chaos_by_job[name]
-                if checkpoint_dir is not None:
-                    ck = os.path.join(
-                        checkpoint_dir, rckpt.lineage_name(name, j))
-                    kw.setdefault("checkpoint_path", ck)
-                    kw.setdefault("checkpoint_every_s", checkpoint_every_s)
-                    kw.setdefault("checkpoint_keep", checkpoint_keep)
-                    if resume and os.path.exists(ck):
-                        kw.setdefault("resume", ck)
-                if supervise is None:
-                    results.append(self.run(**kw))
-                    continue
-                results.append(self._run_supervised(
-                    kw, int(supervise), j, name, recovery_stats))
-        finally:
-            model.fleet_select(None)
-        return results
-
-    def _run_supervised(self, kw, budget, job_index, name, recovery_stats):
-        """One fleet job under the resilience supervisor. Returns the
-        run result, or the terminal exception object when the job's
-        recovery budget is spent (the fleet driver maps it to an
-        ``unrecoverable`` JobResult)."""
-        from ..resilience import (
-            CheckpointMismatch,
-            UnrecoverableError,
-            supervise as _supervise,
-        )
-
-        def factory(overrides):
-            return self if not overrides else self._rebuild(overrides)
-
-        stats: dict = {}
-        try:
-            res = _supervise(
-                factory, kw, max_retries=budget, backoff_base=0.0,
-                seed=job_index, telemetry=kw.get("telemetry"),
-                stats_out=stats,
-            )
-        except (UnrecoverableError, CheckpointMismatch) as exc:
-            res = exc
-        if recovery_stats is not None:
-            recovery_stats[name] = int(stats.get("recoveries", 0))
         return res
 
     def _coverage_fields(self, depth, cov_h, scount, depth_counts) -> dict:
@@ -1453,27 +1188,12 @@ class DeviceBFS:
     def _telemetry_manifest(self) -> dict:
         """Run-provenance fields of the telemetry manifest event (all
         MANIFEST_KEYS except the auto-added "event")."""
-        dev = jax.devices()[0]
-        ident = self._ckpt_ident()
-        return {
-            "engine": "device",
-            "ident": ident,
-            "hashv": hashv_of(ident),
-            "model": self.model.name,
-            "platform": dev.platform,
-            "device": str(getattr(dev, "device_kind", dev.platform)),
-            "device_count": 1,
-            "chunk": self.chunk,
-            "frontier_cap": self.FCAP,
-            "journal_cap": self.JCAP,
-            "max_seen_cap": self.MAX_SCAP,
-            "valid_cap": self.VC,
-            "symmetry": bool(self.canon.symmetry),
-            "invariants": list(self.invariants),
-            "action_names": list(getattr(self.model, "ACTION_NAMES", ())),
-            "when": time.strftime("%Y-%m-%dT%H:%M:%S"),
-            "dedup_plan": self._dedup_plan(),
-        }
+        return manifest_fields(
+            self, "device", jax.devices()[0],
+            frontier_cap=self.FCAP, journal_cap=self.JCAP,
+            max_seen_cap=self.MAX_SCAP, valid_cap=self.VC,
+            dedup_plan=self._dedup_plan(),
+        )
 
     def _dedup_plan(self) -> dict:
         """util.dedup_plan of the wave program as it stands: the seen
@@ -1489,19 +1209,10 @@ class DeviceBFS:
         match too — states explored before the checkpoint (including Init)
         were only checked against the original run's invariants, so a
         resume with different invariants would silently skip them."""
-        # hashv marks fingerprint-formula revisions and is the canon's
-        # own (Canonicalizer 5, round 6: the 1-WL refinement iterates to
-        # a bounded depth, which changes the canonical representative of
-        # signature-tied states; KRaftWithReconfig's SlotCanonicalizer 6:
-        # the bag hashed as a multiset), so a checkpoint of another
-        # formula is refused on load; the refinement depth is part of
-        # the formula and recorded alongside. The in-chunk dedup and the
-        # tie-group-local tier-3 preserve values and are NOT identity.
-        wl = getattr(self.canon, "refine_rounds", 1)
         return (
             f"{self.model.name}/{self.model.p}/W={self.W}"
-            f"/sym={self.canon.symmetry}/seed={self.canon.seed}"
-            f"/hashv={self.canon.hashv}/wl={wl}/inv={','.join(self.invariants)}"
+            f"/{canon_ident(self.canon, seed=True)}"
+            f"/inv={','.join(self.invariants)}"
         )
 
     def _save_checkpoint(

@@ -16,8 +16,9 @@ chunk is one ``shard_map``-ed program per chip:
     journal index per invariant -> emit the chip's new fingerprints as
     one sorted run.
 
-The per-chip seen-set is the same LSM of sorted runs as DeviceBFS
-(round-4 redesign, see checker/device_bfs.py): runs live as [D, lanes]
+The per-chip seen-set is an LSM of sorted runs (checker/lsm.py RunLSM;
+DeviceBFS has kept one run and a wave buffer instead since round 5, and
+sorts them with the chunk since PR 36): runs live as [D, lanes]
 sharded arrays so every merge/consolidation is a batched per-chip sort
 with no collectives; the binary-counter cascade is identical on every
 chip (all chips insert one run per chunk), so one host-side occupancy
@@ -63,12 +64,16 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..checker.engine import (
+    FleetQueue, canon_ident, compact_chunk, expand_chunk, loop_exit,
+    manifest_fields, phase_clocks, resume_events, run_stats,
+    summary_fields, wave_row,
+)
 from ..checker.lsm import RunLSM, pow2_at_least
 from ..obs import (
     COMPILES, MemWatch, NULL_TELEMETRY, device_budget, setup_phase, span,
     stage, traced_run,
 )
-from ..obs.events import hashv_of
 from ..checker.util import (
     GROWTH, HEADROOM, I32_MAX, dedup_plan, dense_prefix_sel, emit_append,
     first_new, next_cap as _next_cap, rank_counts, rank_onehot,
@@ -107,7 +112,7 @@ class ShardedResult:
     exit_cause: str | None = None
 
 
-class ShardedBFS:
+class ShardedBFS(FleetQueue):
     """Multi-chip exhaustive BFS with per-chip frontier/seen-runs/journal.
 
     Capacities (all per device):
@@ -232,8 +237,7 @@ class ShardedBFS:
     def _occ_dev(self):
         """Occupancy flags as a device array, uploaded once per distinct
         pattern (a fresh upload per chunk is a host-to-device transfer
-        on the chunk loop's critical path — same cache as
-        DeviceBFS._occ_dev)."""
+        on the chunk loop's critical path)."""
         key = bytes(self._lsm.occ)
         arr = self._occ_cache.get(key)
         if arr is None:
@@ -391,7 +395,7 @@ class ShardedBFS:
         ([1,2] zeros when the model has no action ranks) and
         ``pre_stats`` [7] i64 = [n_gen, terminal, pre-exchange ovf bits
         (1=msg 2=valid 4=route), routed lanes, then the chunk's canon
-        counts as DeviceBFS._st_canon has them: in-chunk duplicate lanes,
+        counts (ops/symmetry.canon_chunk): in-chunk duplicate lanes,
         tier-3 local lanes, tier-3 full lanes]."""
         model, D, A, W = self.model, self.D, self.A, self.W
         C, VC, RC = self.chunk, self.VC, self.RC
@@ -399,50 +403,21 @@ class ShardedBFS:
 
         with stage("expand"):
             # 1. expand `chunk` rows starting at the wave cursor
-            batch = lax.dynamic_slice(frontier, (cursor, jnp.int32(0)), (C, W))
-            live = (jnp.arange(C, dtype=jnp.int32) + cursor) < fcount
-            if self._sparse:
-                # guard pass: valid/rank/ovf only — no W-wide successor
-                # rows (DCE-derived from _expand1, bit-identical)
-                valid, rank, ovf = jax.vmap(model.guards1)(batch)
-            else:
-                succs, valid, rank, ovf = jax.vmap(model._expand1)(batch)
-            valid = valid & live[:, None]
-            expand_ovf = jnp.any(valid & ovf)
-            n_gen = jnp.sum(valid)
-            term = jnp.sum(live & ~jnp.any(valid, axis=1))
+            (batch, succs, valid, rank, n_gen, term,
+             expand_ovf) = expand_chunk(
+                model, self._sparse, frontier, cursor, fcount, C)
 
             # 1b. enabled/fired per action rank, tallied where the lanes are
             # generated (numpy mirror in checker/bfs.py), by compare and
-            # sum: util.rank_counts, as DeviceBFS._st_finish
+            # sum (util.rank_counts)
             if K:
                 en = rank_onehot(rank, valid, K)  # [C, A, K]
                 enabled_k = jnp.sum(jnp.any(en, axis=1), axis=0, dtype=jnp.int32)
                 fired_k = rank_counts(rank, valid, K)
 
-            # 2. compact the valid lanes (sel[j] = flat lane of the j-th valid)
-            vflat = valid.reshape(-1)
-            vpos = jnp.cumsum(vflat) - 1
-            compact_ovf = n_gen > VC
-            sdst = jnp.where(vflat, jnp.minimum(vpos, VC), VC)
-            sel = (
-                jnp.full((VC + 1,), C * A, jnp.int32)
-                .at[sdst]
-                .set(jnp.arange(C * A, dtype=jnp.int32))[:VC]
-            )
-            selv = sel < C * A
-            if self._sparse:
-                # apply pass over the compacted worklist only; budget
-                # overflow folds into the compaction bit (same remedy:
-                # raise the static budget knob)
-                flatc, apply_ovf = model.sparse_apply(batch, sel, selv, self._plan)
-                compact_ovf = compact_ovf | apply_ovf
-            else:
-                flatp = jnp.concatenate(
-                    [succs.reshape(C * A, W), jnp.zeros((1, W), jnp.int32)],
-                    axis=0,
-                )
-                flatc = flatp[sel]  # [VC, W]
+            # 2. compact the valid lanes into the [VC, W] successor block
+            flatc, sel, selv, compact_ovf = compact_chunk(
+                model, self._plan, batch, succs, valid, n_gen, VC)
             parent_lgid = base_lgid + cursor + sel // A
             cand = sel % A
 
@@ -527,8 +502,7 @@ class ShardedBFS:
             # 7. emit survivors: compact to a dense prefix of a [D*RC, W]
             # block, then ONE dynamic_update_slice per buffer appends at the
             # running cursor (rows [F, F+D*RC) / [JC, JC+D*RC) are the drop
-            # region — checker/util.py emit_append; same redesign as
-            # DeviceBFS._chunk_step step 5, retiring full-capacity scatters)
+            # region — checker/util.py emit_append)
             ncount = stats[0].astype(jnp.int32)
             jcount = stats[1].astype(jnp.int32)
             npos = (jnp.cumsum(new) - 1).astype(jnp.int32)
@@ -621,7 +595,7 @@ class ShardedBFS:
             state[key] = jax.device_put(out, self._sharding)
 
         # the `grow` span and the row's grow_s exist only on a wave that
-        # grows (as in DeviceBFS._maybe_grow)
+        # grows
         with self._ph("grow"):
             if grow_f:
                 new = _next_cap(ncount * self.HEADROOM, self.FCAP,
@@ -678,25 +652,18 @@ class ShardedBFS:
         devs.pop(int(shard) % len(devs))
         return {"devices": devs}
 
-    def _rebuild(self, overrides: dict) -> "ShardedBFS":
-        """A fresh engine with this one's constructor kwargs plus
-        ``overrides`` (the supervisor's growth / shrunk-mesh dicts)."""
-        return type(self)(**{**self._ctor_kw, **overrides})
-
     # ---------------- checkpoint ----------------
 
     def _ckpt_ident(self) -> str:
-        # hashv: the canon's own formula revision (DeviceBFS._ckpt_ident;
-        # 5 = ops/symmetry.py's k-round 1-WL refinement, whose depth is
-        # part of the fingerprint formula; 6 = the slot canon's multiset
-        # bag). The in-chunk dedup preserves values and is not identity.
-        # /D=<n>/ is PROVENANCE, not identity: resilience/ckpt.check_spec
-        # strips it (mesh_neutral) when deciding reshardability, and the
-        # resume path re-routes the payload when it differs.
-        wl = getattr(self.canon, "refine_rounds", 1)
+        """The checkpoint identity (what it must match is in
+        ``DeviceBFS._ckpt_ident``; the formula part is
+        ``engine.canon_ident``). ``/D=<n>/`` is PROVENANCE, not
+        identity: resilience/ckpt.check_spec strips it (mesh_neutral)
+        when deciding reshardability, and the resume path re-routes the
+        payload when it differs."""
         return (
             f"sharded/{self.model.name}/{self.model.p}/W={self.W}"
-            f"/D={self.D}/sym={self.canon.symmetry}/hashv={self.canon.hashv}/wl={wl}"
+            f"/D={self.D}/{canon_ident(self.canon)}"
             f"/inv={','.join(self.invariants)}"
         )
 
@@ -1127,7 +1094,7 @@ class ShardedBFS:
     ) -> ShardedResult:
         model, D, W, C = self.model, self.D, self.W, self.chunk
         t0 = time.perf_counter()
-        # host spans (obs/trace.py), as in DeviceBFS.run: `init`, one
+        # host spans (obs/trace.py): `init`, one
         # `wave` per loop iteration, `finish`; a wave's phases are
         # bracketed once, for the trace and the row — here `dispatch`,
         # `seen_merge` and (mid-wave, on shard loss) `fetch` once a chunk
@@ -1299,13 +1266,7 @@ class ShardedBFS:
 
         tel.open_run(self._telemetry_manifest())
         if resume is not None:
-            if ck_skipped:
-                tel.event(
-                    "ckpt_generation", path=resume, generation=ck_gen,
-                    skipped=list(ck_skipped))
-            tel.event(
-                "resume", path=resume, generation=ck_gen, depth=depth,
-                distinct=distinct)
+            resume_events(tel, resume, ck_gen, ck_skipped, depth, distinct)
             if reshard_from is not None:
                 tel.event(
                     "reshard", path=resume, from_d=reshard_from, to_d=D,
@@ -1324,24 +1285,11 @@ class ShardedBFS:
         )
 
         while fcounts.sum() and violation is None:
-            if preempt is not None and preempt.requested:
-                # the final-save block below writes the (single)
-                # wave-boundary checkpoint for this exit path
+            exit_cause = loop_exit(
+                tel, preempt, chaos, depth, checkpoint_path, max_depth,
+                time_budget_s, t0)
+            if exit_cause is not None:
                 exhausted = False
-                exit_cause = "preempted"
-                tel.event(
-                    "preempt", signame=preempt.signame, depth=depth,
-                    checkpoint=checkpoint_path)
-                break
-            if chaos is not None:
-                chaos.wave_start(depth + 1)
-            if max_depth is not None and depth >= max_depth:
-                exhausted = False
-                exit_cause = "max_depth"
-                break
-            if time_budget_s is not None and time.perf_counter() - t0 > time_budget_s:
-                exhausted = False
-                exit_cause = "time_budget"
                 break
             ph.wave(self._run_id, depth + 1, int(fcounts.sum()))
             tw = time.perf_counter()
@@ -1535,12 +1483,11 @@ class ShardedBFS:
             prev_fcounts = fcounts
             fcounts = new_d.copy()
             if violation is None:
-                # not after the wave that max_depth ends
-                # (as DeviceBFS.run)
+                # not after the wave that max_depth ends: no wave
+                # would use the larger buffers
                 if max_depth is None or depth < max_depth:
                     state = self._maybe_grow(state, fcounts, jcounts)
-                # per-chip floor is smaller than DeviceBFS's (1<<21):
-                # each chip holds ~1/D of the space
+                # the floor is per chip: each holds ~1/D of the space
                 if self._lsm.lanes() > max(4 * int(scounts.max()), 1 << 20):
                     with span("consolidate"):
                         self._lsm.consolidate(int(scounts.max()))
@@ -1558,17 +1505,12 @@ class ShardedBFS:
                     )
                     last_ckpt = time.perf_counter()
             wave_s_val = time.perf_counter() - tw
-            # the wave's brackets, read once (DeviceBFS.run): device_s is
-            # the host's wait on the device — every chunk's dispatch and
-            # LSM insert plus the blocking fetch — and what the brackets
-            # leave of the wave (growth, consolidation, loop bookkeeping)
-            # is host_s; `telemetry` is the previous wave's bracket
+            # the wave's brackets, read once a wave whoever listens
+            # (engine.phase_clocks): here every chunk's dispatch and LSM
+            # insert plus the blocking fetch are the wait on the device,
+            # and growth, consolidation and the loop's bookkeeping are
+            # what they leave of the wave, host_s
             ph_s = ph.take()
-            dispatch_s = ph_s.get("dispatch", 0.0)
-            fetch_s = ph_s.get("fetch", 0.0)
-            merge_s = ph_s.get("seen_merge", 0.0)
-            device_s = dispatch_s + fetch_s + merge_s
-            ckpt_s = ph_s.get("checkpoint", 0.0)
             comp_now = COMPILES.snapshot()
             if not (tel.active or metrics is not None or verbose):
                 continue
@@ -1588,67 +1530,34 @@ class ShardedBFS:
                         * (4 * (W + 3) + 8),
                     })
                     hbm_frac = round(frac, 6)
-                wm = {
-                    "depth": depth,
-                    "frontier": int(prev_fcounts.sum()),
-                    "new": global_new,
-                    "distinct": distinct,
-                    "generated": wave_gen,
-                    "generated_total": total,
-                    "terminal": terminal + term_base,
-                    "dedup_hit_rate": round(1.0 - global_new / max(1, wave_gen), 4),
-                    "canon_dup_lanes": wave_dup,
-                    "canon_dup_rate": round(
-                        wave_dup / max(1, wave_gen), 4
-                    ),
-                    "canon_tier3_local": wave_t3l,
-                    "canon_tier3_full": wave_t3f,
-                    "overflow_bits": ovf_bits,
-                    "wave_s": wave_s_val,
-                    "elapsed_s": el,
-                    "distinct_per_s": round(distinct / el, 1),
-                    # unrounded: device_s + host_s + ckpt_s == wave_s,
-                    # device_s == dispatch_s + fetch_s + merge_s
-                    "device_s": device_s,
-                    "host_s": max(0.0, wave_s_val - device_s - ckpt_s),
-                    "ckpt_s": ckpt_s,
-                    "tel_s": ph_s.get("telemetry", 0.0),
-                    "dispatch_s": dispatch_s,
-                    "fetch_s": fetch_s,
-                    "merge_s": merge_s,
-                    "grow_s": ph_s.get("grow", 0.0),
-                    # programs this iteration loaded and the seconds
-                    # that took (obs/compiles.py): a chunk program for a
-                    # new LSM level count names its wave
-                    "compiles": comp_now[0] - comp_wave[0],
-                    "compile_s": comp_now[1] - comp_wave[1],
-                    "hbm_frac": hbm_frac,
-                    "a2a_lanes": wave_routed,
-                    # payload widened to W+3 by the routed rank column
-                    "a2a_bytes": wave_routed * (4 * (W + 3) + 8),
-                    "shard_new": [int(x) for x in new_d],
-                    "shard_new_min": int(new_d.min()),
-                    "shard_new_max": int(new_d.max()),
-                    "lsm_runs": sum(self._lsm.occ),
-                    "lsm_lanes": int(self._lsm.lanes()),
-                    # emit gauges (round 6): fleet rows appended, bytes
-                    # the append path WROTE (one [D*RC, W] block + three
-                    # journal lanes per chip per chunk), and the worst
-                    # chip's frontier occupancy — frontier_fill nearing
-                    # 1.0 flags an imminent growth/overflow wave for the
-                    # stall watchdog
-                    "emit_rows": global_new,
-                    "emit_bytes": chunks_done * D * (D * self.RC)
+                wm = wave_row(
+                    depth=depth, frontier=int(prev_fcounts.sum()),
+                    new=global_new, distinct=distinct, generated=wave_gen,
+                    generated_total=total, terminal=terminal + term_base,
+                    canon=(wave_dup, wave_t3l, wave_t3f),
+                    overflow_bits=ovf_bits,
+                    lsm_runs=sum(self._lsm.occ),
+                    lsm_lanes=int(self._lsm.lanes()),
+                    wave_s=wave_s_val, elapsed_s=el,
+                    # one [D*RC, W] block + three journal lanes per chip
+                    # per chunk
+                    emit_bytes=chunks_done * D * (D * self.RC)
                     * (4 * W + 12),
-                    "frontier_fill": round(int(new_d.max()) / self.FCAP, 4),
-                    # sparse-expand gauges (checker/device_bfs.py): both
-                    # derive from counters this wave already fetched
-                    "enabled_density": round(
-                        wave_gen / max(1, int(prev_fcounts.sum()) * self.A),
-                        4,
-                    ),
-                    "expand_budget_ovf": (ovf_bits >> 1) & 1,
-                }
+                    # the worst chip's: nearing 1.0 flags an imminent
+                    # growth/overflow wave for the stall watchdog
+                    frontier_fill=round(int(new_d.max()) / self.FCAP, 4),
+                    A=self.A, expand_budget_ovf=(ovf_bits >> 1) & 1,
+                    hbm_frac=hbm_frac,
+                    **phase_clocks(ph_s, comp_wave, comp_now),
+                    # this engine's own: the all-to-all's lanes and bytes
+                    # (payload widened to W+3 by the routed rank column)
+                    # and the per-shard balance
+                    a2a_lanes=wave_routed,
+                    a2a_bytes=wave_routed * (4 * (W + 3) + 8),
+                    shard_new=[int(x) for x in new_d],
+                    shard_new_min=int(new_d.min()),
+                    shard_new_max=int(new_d.max()),
+                )
                 tel.wave(wm)
                 if tel.active:
                     tel.coverage(self._coverage_fields(
@@ -1680,7 +1589,12 @@ class ShardedBFS:
         self._journals = (jps_h, jpl_h, jcand_h, jcounts.copy(), n0.copy())
 
         dt = time.perf_counter() - t0
-        top_s = ph.top_seconds()  # read beside dt: they add up to it
+        stats_run = run_stats(
+            comp_run, ph,
+            dedup_plan=self._dedup_plan(),
+            canon_tier3_local=int(tiers_prev[0]),
+            canon_tier3_full=int(tiers_prev[1]),
+        )
         if violation is not None:
             exit_cause = "violation"
         elif exit_cause is None:
@@ -1690,12 +1604,6 @@ class ShardedBFS:
         # loop already fetched — also returned on ShardedResult.stats
         fleet_rate = round(dup_prev / max(1, gen_prev), 4)
         fleet_cov = cov_hd.sum(axis=0)
-        run_stats = {
-            **COMPILES.run_stats(comp_run), **top_s,
-            "dedup_plan": self._dedup_plan(),
-            "canon_tier3_local": int(tiers_prev[0]),
-            "canon_tier3_full": int(tiers_prev[1]),
-        }
         fleet_stats = {
             "canon_dup_lanes": dup_prev,
             "canon_dup_rate": fleet_rate,
@@ -1705,35 +1613,27 @@ class ShardedBFS:
                 int(scounts.max()) / max(1, int(scounts.min())), 3),
             "coverage": [[int(x) for x in row] for row in fleet_cov],
             # what the run loaded into the process (obs/compiles.py)
-            **run_stats,
+            **stats_run,
         }
         if tel.active:
             tel.coverage(
                 self._coverage_fields(depth, cov_hd, scounts, depth_counts),
                 final=True)
-        tel.close_run({
-            "engine": "sharded",
-            "ident": self._ckpt_ident(),
-            "exit_cause": exit_cause,
-            "violation": violation,
-            "distinct": distinct,
-            "total": total,
-            "depth": depth,
-            "terminal": terminal + term_base,
-            "seconds": round(dt, 3),
-            "distinct_per_s": round(distinct / dt, 1) if dt > 0 else 0.0,
-            "exhausted": exhausted and violation is None,
-            "peak_frontier_cap": self.FCAP,
-            "peak_journal_cap": self.JCAP,
-            "seen_lanes": int(self._lsm.lanes()),
-            "canon_dup_rate": fleet_rate,
-            # sharded extras (schema allows extra keys)
-            "shard_dup_lanes": fleet_stats["shard_dup_lanes"],
-            "shard_skew": fleet_stats["shard_skew"],
-            **run_stats,
-            "programs": COMPILES.programs(comp_run),
-            **(memwatch.summary_fields() if memwatch is not None else {}),
-        })
+        tel.close_run(summary_fields(
+            self, "sharded",
+            exit_cause=exit_cause, violation=violation,
+            distinct=distinct, total=total, depth=depth,
+            terminal=terminal + term_base, seconds=dt,
+            exhausted=exhausted and violation is None,
+            peak_frontier_cap=self.FCAP, peak_journal_cap=self.JCAP,
+            seen_lanes=int(self._lsm.lanes()),
+            canon_dup_rate=fleet_rate,
+            stats=stats_run, programs=COMPILES.programs(comp_run),
+            memwatch=memwatch,
+            # this engine's own (the schema allows extra keys)
+            shard_dup_lanes=fleet_stats["shard_dup_lanes"],
+            shard_skew=fleet_stats["shard_skew"],
+        ))
         trace = init_trace
         if violation is not None and viol_site is not None:
             trace = self.reconstruct_trace(viol_site)
@@ -1753,109 +1653,6 @@ class ShardedBFS:
             coverage=(fleet_stats["coverage"] if self.n_actions else None),
             exit_cause=exit_cause,
         )
-
-    def run_fleet(
-        self,
-        job_names: list[str] | None = None,
-        telemetry=None,
-        checkpoint_dir: str | None = None,
-        checkpoint_every_s: float = 300.0,
-        checkpoint_keep: int = rckpt.DEFAULT_KEEP,
-        resume: bool = False,
-        skip: tuple[str, ...] = (),
-        supervise: int | None = None,
-        chaos_by_job: dict | None = None,
-        recovery_stats: dict | None = None,
-        **run_kw,
-    ) -> list:
-        """Fleet queue arm over all shards: same contract as
-        DeviceBFS.run_fleet — sequential jobs through one engine
-        instance (``fleet_select`` swaps only the stamped init states,
-        so the sharded programs compile once per group), job-tagged
-        telemetry, and one checkpoint lineage per job under
-        ``checkpoint_dir`` (named by ``resilience.lineage_name``, which
-        disambiguates sanitizer collisions with the job index).
-
-        ``supervise``: when set, each job runs under the resilience
-        supervisor with that per-job recovery budget; the engine factory
-        returns THIS instance for empty overrides, so recoveries that
-        need no growth/reshard reuse the compiled programs (zero
-        recompiles). A job whose budget is spent (or whose failure has
-        no recovery policy) contributes its UnrecoverableError /
-        CheckpointMismatch to the results list instead of killing the
-        rest of the fleet. ``chaos_by_job`` maps job name -> a
-        ChaosInjector for that job only. ``recovery_stats`` (a dict) is
-        filled in place with job name -> recovery count."""
-        import os
-
-        from ..obs.collector import JobTaggedTelemetry
-
-        model = self.model
-        J = model.fleet_jobs
-        if J == 0:
-            raise ValueError(
-                "run_fleet needs a fleet-bound model (fleet_bind)"
-            )
-        names = list(job_names) if job_names else [f"job{j}" for j in range(J)]
-        if len(names) != J:
-            raise ValueError(f"{len(names)} job names for {J} jobs")
-        results = []
-        try:
-            for j, name in enumerate(names):
-                if name in skip:
-                    results.append(None)
-                    continue
-                model.fleet_select(j)
-                kw = dict(run_kw)
-                if telemetry is not None:
-                    kw["telemetry"] = JobTaggedTelemetry(telemetry, name)
-                if chaos_by_job and name in chaos_by_job:
-                    kw["chaos"] = chaos_by_job[name]
-                if checkpoint_dir is not None:
-                    ck = os.path.join(
-                        checkpoint_dir, rckpt.lineage_name(name, j))
-                    kw.setdefault("checkpoint_path", ck)
-                    kw.setdefault("checkpoint_every_s", checkpoint_every_s)
-                    kw.setdefault("checkpoint_keep", checkpoint_keep)
-                    if resume and os.path.exists(ck):
-                        kw.setdefault("resume", ck)
-                if supervise is None:
-                    results.append(self.run(**kw))
-                    continue
-                results.append(self._run_supervised(
-                    kw, int(supervise), j, name, recovery_stats))
-        finally:
-            model.fleet_select(None)
-        return results
-
-    def _run_supervised(self, kw, budget, job_index, name, recovery_stats):
-        """One fleet job under the resilience supervisor. Returns the
-        run result, or the terminal exception object when the job's
-        recovery budget is spent (the fleet driver maps it to an
-        ``unrecoverable`` JobResult)."""
-        from ..resilience import (
-            CheckpointMismatch,
-            UnrecoverableError,
-            supervise as _supervise,
-        )
-
-        def factory(overrides):
-            # empty overrides -> the cached engine: recoveries that need
-            # neither growth nor a shrunk mesh stay recompile-free
-            return self if not overrides else self._rebuild(overrides)
-
-        stats: dict = {}
-        try:
-            res = _supervise(
-                factory, kw, max_retries=budget, backoff_base=0.0,
-                seed=job_index, telemetry=kw.get("telemetry"),
-                stats_out=stats,
-            )
-        except (UnrecoverableError, CheckpointMismatch) as exc:
-            res = exc
-        if recovery_stats is not None:
-            recovery_stats[name] = int(stats.get("recoveries", 0))
-        return res
 
     def _coverage_fields(self, depth, cov_hd, scounts, depth_counts) -> dict:
         """Coverage-event payload (obs.events.COVERAGE_KEYS), fleet-summed
@@ -1879,27 +1676,12 @@ class ShardedBFS:
 
     def _telemetry_manifest(self) -> dict:
         """Run-provenance fields of the telemetry manifest event."""
-        dev = self.mesh.devices.flat[0]
-        ident = self._ckpt_ident()
-        return {
-            "engine": "sharded",
-            "ident": ident,
-            "hashv": hashv_of(ident),
-            "model": self.model.name,
-            "platform": dev.platform,
-            "device": str(getattr(dev, "device_kind", dev.platform)),
-            "device_count": self.D,
-            "chunk": self.chunk,
-            "frontier_cap": self.FCAP,
-            "journal_cap": self.JCAP,
-            "max_seen_cap": self.MAX_SCAP,
-            "valid_cap": self.VC,
-            "symmetry": bool(self.canon.symmetry),
-            "invariants": list(self.invariants),
-            "action_names": list(getattr(self.model, "ACTION_NAMES", ())),
-            "when": time.strftime("%Y-%m-%dT%H:%M:%S"),
-            "dedup_plan": self._dedup_plan(),
-        }
+        return manifest_fields(
+            self, "sharded", self.mesh.devices.flat[0], device_count=self.D,
+            frontier_cap=self.FCAP, journal_cap=self.JCAP,
+            max_seen_cap=self.MAX_SCAP, valid_cap=self.VC,
+            dedup_plan=self._dedup_plan(),
+        )
 
     def _dedup_plan(self) -> dict:
         """util.dedup_plan of a chip's chunk program as it stands: every

@@ -273,7 +273,7 @@ def validate_resume(path: str, expect_ident: str,
                     allow_reshard: bool = False) -> tuple[int, int]:
     """Fail-fast --resume validation: prove the checkpoint exists, loads
     (falling back through generations), and matches the model identity —
-    BEFORE the caller pays the multi-second precompile. Returns
+    BEFORE the caller pays the run's first compiles. Returns
     ``(generation, depth)`` of the checkpoint that will be used."""
     payload, gen, _skipped = load_npz(path, keep=keep)
     check_spec(payload, expect_ident, path, allow_reshard=allow_reshard)

@@ -1,0 +1,145 @@
+"""The device programs of the two device engines, pinned.
+
+``checker/engine.py`` holds what the three BFS engines say and do alike;
+two of its functions (``expand_chunk``, ``compact_chunk``) are traced
+into ``DeviceBFS``'s wave program and ``ShardedBFS``'s chunk program.
+A plain function called under the caller's ``obs.stage`` scope adds no
+scope and no equation, so neither program may change: this file holds
+``(equations, conftest.jaxpr_digest)`` of every entry of both engines'
+``audit_programs()`` (the production jit objects and their abstract
+arguments) at PR 45's tree, ``8ace3e6``, computed there on a
+``git archive`` copy before the functions were lifted out. Nothing is
+compiled or run.
+
+A PR that means to change a program re-pins its digest on purpose, says
+so, and checks the benchmark's cells; a PR that does not must leave
+every line here as it is. The models are ``tests/test_expand_sparse.py``
+``FAMILIES`` (the lowerings behind the benchmark's cells at test size),
+at that file's engine capacities; ``raft-dense`` is ``raft`` behind its
+``DenseShim``, the dense arm of the same two functions.
+"""
+
+import jax
+import pytest
+
+from conftest import jaxpr_digest
+from raft_tpu.checker.device_bfs import DeviceBFS
+from raft_tpu.parallel.sharded import ShardedBFS
+from test_expand_sparse import _HEAVY, DenseShim, FAMILIES
+
+KW = dict(symmetry=True, chunk=128, frontier_cap=1 << 12, seen_cap=1 << 15)
+ENGINES = {"device": DeviceBFS, "sharded": ShardedBFS}
+
+# the seen merge has no model in it: one digest for every family
+SEEN_MERGE = (12, "19ac660b935d83db")
+
+# {family: {engine: {program: (equations, digest)}}} at 8ace3e6
+PARENT_PROGRAMS = {
+    "raft": {
+        "device": {"wave": (4901, "a4b8345acbe15467"),
+                   "seen_merge": SEEN_MERGE},
+        "sharded": {"chunk": (5294, "0f27c066634699d4")}},
+    "raft-dense": {
+        "device": {"wave": (3640, "d346cbc4776edf65"),
+                   "seen_merge": SEEN_MERGE},
+        "sharded": {"chunk": (4033, "8343aa054ce2fa4d")}},
+    "pull_raft": {
+        "device": {"wave": (5261, "47ca4f01c82b1de8"),
+                   "seen_merge": SEEN_MERGE},
+        "sharded": {"chunk": (5654, "3b9cc4970b89dbde")}},
+    "kraft": {
+        "device": {"wave": (6600, "d0cb4efe89286904"),
+                   "seen_merge": SEEN_MERGE},
+        "sharded": {"chunk": (6993, "53d7d4c1edb6e9c0")}},
+    "joint_raft": {
+        "device": {"wave": (10941, "8e65fb328d86b50d"),
+                   "seen_merge": SEEN_MERGE},
+        "sharded": {"chunk": (11334, "c27bc6142a4343b8")}},
+    "kraft_reconfig": {
+        "device": {"wave": (14975, "b1e57916defb3f26"),
+                   "seen_merge": SEEN_MERGE},
+        "sharded": {"chunk": (15368, "38f4b8d518e3a7f8")}},
+    "reconfig_raft": {
+        "device": {"wave": (10568, "23bfb35c76d89f20"),
+                   "seen_merge": SEEN_MERGE},
+        "sharded": {"chunk": (10961, "fca98ba4f8fb79ab")}},
+}
+
+
+def _model(family):
+    if family == "raft-dense":
+        return DenseShim(FAMILIES["raft"]())
+    return FAMILIES[family]()
+
+
+@pytest.mark.parametrize("engine", sorted(ENGINES))
+@pytest.mark.parametrize("family", [
+    pytest.param(f, marks=pytest.mark.slow) if f in _HEAVY else f
+    for f in PARENT_PROGRAMS])
+def test_device_programs_are_the_parents(family, engine):
+    model = _model(family)
+    eng = ENGINES[engine](
+        model, invariants=tuple(list(model.invariants)[:1]), **KW)
+    assert eng._sparse == (family != "raft-dense")
+    found = {
+        entry["name"]: jaxpr_digest(
+            jax.make_jaxpr(entry["fn"])(*entry["args"]))
+        for entry in eng.audit_programs()}
+    assert found == PARENT_PROGRAMS[family][engine]
+
+
+def test_stages_one_and_two_are_written_once():
+    """The compaction's index buffer is built in one place under
+    ``raft_tpu/`` (``engine.compact_chunk``), and both device programs
+    trace it."""
+    import inspect
+    import pathlib
+
+    import raft_tpu
+    from raft_tpu.checker import engine
+
+    needle = "jnp.full((VC + 1,), C * A, jnp.int32)"
+    root = pathlib.Path(raft_tpu.__file__).parent
+    assert [p.relative_to(root).as_posix() for p in sorted(root.rglob("*.py"))
+            if needle in p.read_text()] == ["checker/engine.py"]
+    assert needle in inspect.getsource(engine.compact_chunk)
+    for fn in (DeviceBFS._st_expand, ShardedBFS._cs_pre):
+        src = inspect.getsource(fn)
+        assert "expand_chunk(" in src and "compact_chunk(" in src
+
+
+def test_one_definition_each():
+    """What the engines say alike has one author under ``raft_tpu/``:
+    the row's derived keys are keys of a dict literal in
+    ``checker/engine.py`` alone (``obs/events.py`` holds the schema's
+    tuples, not dicts), the ``hashv=`` fragment of an ident is formatted
+    there alone, and the fleet's queue arm and its supervised run are
+    defined once, there, for both device engines."""
+    import ast
+    import pathlib
+
+    import raft_tpu
+    from raft_tpu.checker import engine
+
+    root = pathlib.Path(raft_tpu.__file__).parent
+    keys = {"dedup_hit_rate", "enabled_density", "host_s"}
+    literal_in, hashv_in, defs_in = {}, set(), {}
+    for path in sorted(root.rglob("*.py")):
+        rel = path.relative_to(root).as_posix()
+        text = path.read_text()
+        if "/hashv={" in text:
+            hashv_in.add(rel)
+        for node in ast.walk(ast.parse(text)):
+            if isinstance(node, ast.Dict):
+                for k in node.keys:
+                    if isinstance(k, ast.Constant) and k.value in keys:
+                        literal_in.setdefault(k.value, set()).add(rel)
+            elif (isinstance(node, ast.FunctionDef)
+                  and node.name == "_run_supervised"):
+                defs_in.setdefault(node.name, set()).add(rel)
+    assert literal_in == {k: {"checker/engine.py"} for k in keys}
+    assert hashv_in == {"checker/engine.py"}
+    assert defs_in == {"_run_supervised": {"checker/engine.py"}}
+    for cls in (DeviceBFS, ShardedBFS):
+        assert cls.run_fleet is engine.FleetQueue.run_fleet
+        assert cls._run_supervised is engine.FleetQueue._run_supervised

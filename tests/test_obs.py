@@ -248,6 +248,115 @@ def test_schema_and_renderer_stay_in_sync():
     assert "Finished" in text and "(exhausted)" in text
 
 
+# What a stream carries beyond the schema's keys, an engine: the key
+# sets of a depth-4 run at PR 45's tree (8ace3e6, read from a `git
+# archive` copy). `checker/engine.py` makes the declared keys of every
+# row and summary (`wave_row`, `summary_fields`); an engine adds its own
+# where it calls them, after the declared ones.
+_PHASES = ("dispatch_s", "fetch_s", "merge_s", "grow_s", "compiles",
+           "compile_s")
+_RUN_LOADED = ("run_compiles", "run_compile_s", "run_cache_hits",
+               "run_cache_read_s")
+_TRACED_RUN = (*_RUN_LOADED, "init_s", "waves_s", "finish_s", "programs",
+               "hbm_peak_bytes", "hbm_peak_wave", "hbm_budget_bytes",
+               "hbm_peak_frac")
+ROW_OWN = {
+    "device": (*_PHASES, "dedup_sort_lanes", "dedup_search_queries",
+               "seen_lanes"),
+    "host": (),
+    "sharded": (*_PHASES, "a2a_lanes", "a2a_bytes", "shard_new",
+                "shard_new_min", "shard_new_max"),
+    "host_fleet": ("jobs_active",),
+}
+SUMMARY_OWN = {
+    "device": (*_TRACED_RUN, "dedup_plan", "dedup_sort_lanes",
+               "dedup_search_queries"),
+    "host": _TRACED_RUN,
+    "sharded": (*_TRACED_RUN, "dedup_plan", "shard_dup_lanes",
+                "shard_skew"),
+    # the group's summary, then a synthesized one a job
+    "host_fleet": (*_RUN_LOADED, "fleet_jobs"),
+}
+STATS_OWN = {  # beyond obs/compiles.py run_stats and the top spans
+    "device": {"dedup_plan", "canon_tier3_local", "canon_tier3_full",
+               "dedup_sort_lanes", "dedup_search_queries"},
+    "host": set(),
+    "sharded": {"dedup_plan", "canon_tier3_local", "canon_tier3_full",
+                "canon_dup_lanes", "canon_dup_rate", "shard_dup_lanes",
+                "shard_distinct", "shard_skew", "coverage"},
+}
+
+
+def _packed_fleet():
+    """A BFSChecker over two packed Raft jobs (tests/test_fleet.py's
+    small grid), and the jobs' names."""
+    from raft_tpu.checker.bfs import BFSChecker
+    from raft_tpu.fleet.grouping import group_jobs
+    from raft_tpu.fleet.manifest import parse_manifest_obj
+    from raft_tpu.fleet.packer import build_packed
+
+    mf = parse_manifest_obj({
+        "spec": "Raft",
+        "defaults": {
+            "constants": {"Server": ["s1", "s2"], "Value": ["v1"],
+                          "MaxElections": 1, "MaxRestarts": 0},
+            "invariants": list(INVS), "msg_slots": 16},
+        "grid": {"MaxElections": [1, 2]},
+    }, path="<test>")
+    (group,) = group_jobs(mf)
+    setup = group.setups[0]
+    return BFSChecker(
+        build_packed(group), invariants=setup.invariants,
+        symmetry=setup.symmetry, chunk=512,
+    ), [j.name for j in group.jobs]
+
+
+@pytest.mark.parametrize("engine", ["device", "host", "sharded", "host_fleet"])
+def test_rows_and_summary_have_one_author(engine):
+    """Every engine's wave rows are the schema's keys in the schema's
+    order, then that engine's declared own keys; its summary and its
+    result's ``stats`` likewise: the key sets of the parent tree."""
+    from raft_tpu.checker.bfs import BFSChecker
+    from raft_tpu.obs.events import PROCESS_KEYS
+
+    tel = Telemetry()
+    stats = None
+    if engine == "device":
+        stats = _device().run(max_depth=4, telemetry=tel).stats
+    elif engine == "host":
+        stats = BFSChecker(
+            cached_model(SMALL), invariants=INVS, symmetry=True, chunk=256,
+        ).run(max_depth=4, telemetry=tel).stats
+    elif engine == "sharded":
+        stats = _sharded(2, frontier_cap=2048, seen_cap=1 << 13).run(
+            max_depth=4, telemetry=tel).stats
+    else:
+        eng, names = _packed_fleet()
+        eng.run_fleet(job_names=names, max_depth=4, telemetry=tel)
+    tel.close()
+
+    rows = tel.wave_events()
+    assert [r["depth"] for r in rows] == [1, 2, 3, 4]
+    for row in rows:
+        assert tuple(row) == (*WAVE_KEYS, *ROW_OWN[engine])
+    summaries = [e for e in tel.events if e["event"] == "summary"]
+    # "waves" and "stalls" are the collector's, after the engine's
+    declared = set(SUMMARY_KEYS)
+    assert set(summaries[0]) == declared | set(SUMMARY_OWN[engine])
+    if engine == "host_fleet":
+        assert [s["job"] for s in summaries[1:]] == names
+        for s in summaries[1:]:
+            assert set(s) == declared | {*_RUN_LOADED, "job"}
+    else:
+        assert len(summaries) == 1
+        base = {*PROCESS_KEYS, *_RUN_LOADED, "init_s", "waves_s", "finish_s"}
+        assert set(stats) == base | STATS_OWN[engine]
+        # stats is the summary's own dict, but for the sharded engine's
+        # fleet aggregates
+        assert all(summaries[0][k] == v for k, v in stats.items()
+                   if k in summaries[0])
+
+
 def test_format_count():
     assert format_count(1234) == "1,234"
     assert format_count(310_000) == "310k"

@@ -169,7 +169,7 @@ def test_reshard_smoke_d2_to_d1(tmp_path):
         max_depth=2, checkpoint_path=ck, checkpoint_every_s=0.0)
     assert r1.depth == 2
     eng1 = ShardedBFS(model, devices=jax.devices()[:1], **kw)
-    # refusal: fails fast in check_spec, before the D=1 precompile
+    # refusal: fails fast in check_spec, before the D=1 engine compiles
     with pytest.raises(ValueError) as ei:
         eng1.run(resume=ck, reshard=False)
     assert "D=2 mesh" in str(ei.value) and "D=1" in str(ei.value)
