@@ -48,6 +48,14 @@ count against the pure-Python oracle's golden
          through the same wave program, at its cell's chunk (a
          32,768-lane worklist, twice the size at which leg D's lowering
          lost writes).
+  leg H  configs/standard-raft/RaftWithReconfigAddRemove.cfg under
+         --lenient (the thesis's one-at-a-time membership change: 4
+         servers, 24 permutations, 735-lane rows, 192 actions a state;
+         models/reconfig_raft.py; upstream's file omits MaxClusterSize,
+         which --lenient sets to 4) to depth 9 against
+         tests/golden/addremove4_cfg_depth_counts.json: a sixth model
+         file through the same wave program, at its cell's chunk (a
+         16,384-lane worklist, and a wave of nine chunks).
 
 This process never imports jax or raft_tpu: a chip belongs to one process
 at a time, so every leg is a child of its own, one after the other, and
@@ -86,6 +94,10 @@ KRAFTRC_CFG = os.path.join(
 PULL_GOLDEN = os.path.join(
     ROOT, "tests", "golden", "pull3_cfg_depth_counts.json")
 PULL_CFG = os.path.join(ROOT, "configs", "pull-raft", "PullRaft.cfg")
+ADDREMOVE_GOLDEN = os.path.join(
+    ROOT, "tests", "golden", "addremove4_cfg_depth_counts.json")
+ADDREMOVE_CFG = os.path.join(
+    ROOT, "configs", "standard-raft", "RaftWithReconfigAddRemove.cfg")
 UNSAFE_CFG = os.path.join(
     ROOT, "configs", "flexible-raft", "unsafe-quorums", "FlexibleRaft.cfg")
 SCHEMA_CHECK = os.path.join(ROOT, "scripts", "check_metrics_schema.py")
@@ -275,7 +287,7 @@ def leg_c(dev: dict, golden: dict) -> None:
 
 def cfg_leg(letter: str, cfg: str, chunk: int, dev: dict, golden: dict,
             flags: tuple = ()) -> None:
-    """Legs D to G: another model file's cfg through the CLI to its
+    """Legs D to H: another model file's cfg through the CLI to its
     golden's depth, at its cell's chunk, with the flags the cfg needs."""
     depth = golden["max_depth"]
     res = bfs_leg(f"leg{letter}", dev, golden,
@@ -289,8 +301,9 @@ def cfg_leg(letter: str, cfg: str, chunk: int, dev: dict, golden: dict,
 def main() -> int:
     try:
         for path in (GOLDEN, JOINT_GOLDEN, KRAFT_GOLDEN, KRAFTRC_GOLDEN,
-                     PULL_GOLDEN, TRACE_GOLDEN, RAFT_CFG, JOINT_CFG, KRAFT_CFG,
-                     KRAFTRC_CFG, PULL_CFG, UNSAFE_CFG, SCHEMA_CHECK,
+                     PULL_GOLDEN, ADDREMOVE_GOLDEN, TRACE_GOLDEN, RAFT_CFG,
+                     JOINT_CFG, KRAFT_CFG, KRAFTRC_CFG, PULL_CFG,
+                     ADDREMOVE_CFG, UNSAFE_CFG, SCHEMA_CHECK,
                      os.path.join(ROOT, "raft_tpu", "__main__.py")):
             check(os.path.exists(path),
                   f"{os.path.relpath(path, ROOT)} is missing: chip_smoke.py "
@@ -307,7 +320,10 @@ def main() -> int:
                 ("E", KRAFT_CFG, 2048, KRAFT_GOLDEN, ()),
                 # upstream's cfg declares v1 and uses v2
                 ("F", KRAFTRC_CFG, 1024, KRAFTRC_GOLDEN, ("--lenient",)),
-                ("G", PULL_CFG, 2048, PULL_GOLDEN, ("--lenient",))):
+                ("G", PULL_CFG, 2048, PULL_GOLDEN, ("--lenient",)),
+                # upstream's cfg omits MaxClusterSize
+                ("H", ADDREMOVE_CFG, 1024, ADDREMOVE_GOLDEN,
+                 ("--lenient",))):
             with open(path) as f:
                 cfg_leg(letter, cfg, chunk, dev,
                         json.load(f)["depth_limited"], flags)

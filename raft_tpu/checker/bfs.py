@@ -505,7 +505,9 @@ class BFSChecker:
             )
 
         dt = time.perf_counter() - t0
-        stats_run = run_stats(comp_run, ph)
+        stats_run = run_stats(
+            comp_run, ph,
+            frontier_peak_rows=max(depth_counts[1:], default=0))
         if violation is not None:
             exit_cause = "violation"
         elif exit_cause is None:
@@ -619,7 +621,7 @@ class BFSChecker:
         violation_j: list[Violation | None] = [None] * J
         cov_j = np.zeros((J, K, 3), dtype=np.int64)
         active = np.ones(J, dtype=bool)
-        depth = 0
+        depth = peak_rows = 0
         next_gid = len(frontier)
         exit_cause_global = None
 
@@ -764,6 +766,7 @@ class BFSChecker:
             with span("seen_merge"):
                 seen = _merge_sorted(seen, wave_fps)
             depth += 1
+            peak_rows = max(peak_rows, len(wave_states))
             new_by_job = np.bincount(wave_jobs, minlength=J)
             for j in range(J):
                 if new_by_job[j]:
@@ -866,7 +869,7 @@ class BFSChecker:
                 max(dc) for dc in depth_counts_j)),
             peak_journal_cap=journal_rows,
             seen_lanes=int(len(seen)), canon_dup_rate=0.0,
-            stats=stats_run,
+            stats=stats_run, frontier_peak_rows=peak_rows,
             canon_tier3_local=0, canon_tier3_full=0, fleet_jobs=J,
         ))
         # per-job synthesized runs: one manifest/coverage/summary triple
@@ -897,6 +900,7 @@ class BFSChecker:
                     peak_journal_cap=journal_rows,
                     seen_lanes=int(len(seen)), canon_dup_rate=0.0,
                     stats=stats_run,
+                    frontier_peak_rows=max(r.depth_counts[1:], default=0),
                     canon_tier3_local=0, canon_tier3_full=0, job=name,
                 ))
         return results

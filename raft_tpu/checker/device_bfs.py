@@ -423,32 +423,41 @@ class DeviceBFS(FleetQueue):
         # stage sum in round 5's stage profile. Rows [FCAP, FCAP+VC) /
         # [JCAP, JCAP+VC) are the drop region replacing the scatter's
         # drop row; overflow semantics are bit-identical (emit_append).
-        ncount = stats[0].astype(jnp.int32)
-        jcount = stats[1].astype(jnp.int32)
-        npos = (jnp.cumsum(new) - 1).astype(jnp.int32)
-        esel = dense_prefix_sel(new, npos, VC)
-        blk = jnp.concatenate(
-            [flatc, jnp.zeros((1, W), jnp.int32)], axis=0
-        )[esel]
-        jp_blk = jnp.concatenate(
-            [base_gid + cursor + sel // A, jnp.zeros((1,), jnp.int32)]
-        )[esel]
-        jc_blk = jnp.concatenate([sel % A, jnp.zeros((1,), jnp.int32)])[esel]
-        next_buf, frontier_ovf = emit_append(next_buf, blk, ncount, n_new, FCAP)
-        jparent, journal_ovf = emit_append(jparent, jp_blk, jcount, n_new, JCAP)
-        jcand, _ = emit_append(jcand, jc_blk, jcount, n_new, JCAP)
-        # NOTE: a searchsorted+scatter linear merge looks asymptotically
-        # better than sort-concat for merging sorted sets, but arbitrary-
-        # index scatters serialize on this hardware while XLA's bitonic
-        # sort is fast (scripts/emit_micro.py reproduces the scatter
-        # penalty on the current backend). All seen merges therefore use
-        # sort-concat (as 2-key u32 sorts — hashing.py), and the
-        # per-chunk sort below, VC lanes, is the compaction: the chunk's
-        # new fingerprints first and padding after, a dense block to
-        # append at the wave's count like the rows above. Its padding
-        # tail lands on padding, and the next append overwrites it.
-        new_run = sort_u64(jnp.where(new, fps, U64_MAX))
-        wave_new, _ = emit_append(wave_new, new_run, ncount, n_new, FCAP)
+        # A scope of its own, `emit/append`, beside `emit/coverage` and
+        # `emit/invariants`: what a trace charges to writing into the
+        # frontier, the journal and the wave's fingerprint buffer.
+        with jax.named_scope("append"):
+            ncount = stats[0].astype(jnp.int32)
+            jcount = stats[1].astype(jnp.int32)
+            npos = (jnp.cumsum(new) - 1).astype(jnp.int32)
+            esel = dense_prefix_sel(new, npos, VC)
+            blk = jnp.concatenate(
+                [flatc, jnp.zeros((1, W), jnp.int32)], axis=0
+            )[esel]
+            jp_blk = jnp.concatenate(
+                [base_gid + cursor + sel // A, jnp.zeros((1,), jnp.int32)]
+            )[esel]
+            jc_blk = jnp.concatenate(
+                [sel % A, jnp.zeros((1,), jnp.int32)])[esel]
+            next_buf, frontier_ovf = emit_append(
+                next_buf, blk, ncount, n_new, FCAP)
+            jparent, journal_ovf = emit_append(
+                jparent, jp_blk, jcount, n_new, JCAP)
+            jcand, _ = emit_append(jcand, jc_blk, jcount, n_new, JCAP)
+            # NOTE: a searchsorted+scatter linear merge looks
+            # asymptotically better than sort-concat for merging sorted
+            # sets, but arbitrary-index scatters serialize on this
+            # hardware while XLA's bitonic sort is fast
+            # (scripts/emit_micro.py reproduces the scatter penalty on
+            # the current backend). All seen merges therefore use
+            # sort-concat (as 2-key u32 sorts — hashing.py), and the
+            # per-chunk sort below, VC lanes, is the compaction: the
+            # chunk's new fingerprints first and padding after, a dense
+            # block to append at the wave's count like the rows above.
+            # Its padding tail lands on padding, and the next append
+            # overwrites it.
+            new_run = sort_u64(jnp.where(new, fps, U64_MAX))
+            wave_new, _ = emit_append(wave_new, new_run, ncount, n_new, FCAP)
 
         # 6. invariants on the compacted candidates; fold first-bad gid
         jidx = jnp.where(new, jcount + npos, I32_MAX)
@@ -866,7 +875,7 @@ class DeviceBFS(FleetQueue):
             MemWatch(tel, device_budget(jax.devices()[0]))
             if tel.active else None
         )
-        sort_lanes_run = search_queries_run = 0
+        sort_lanes_run = search_queries_run = peak_rows = 0
 
         while fcount and violation is None:
             exit_cause = loop_exit(
@@ -993,6 +1002,7 @@ class DeviceBFS(FleetQueue):
             depth += 1
             distinct += ncount
             depth_counts.append(ncount)
+            peak_rows = max(peak_rows, ncount)
             if self.invariants:
                 for k, name in enumerate(self.invariants):
                     if viol_h[k] != I32_MAX:
@@ -1119,7 +1129,7 @@ class DeviceBFS(FleetQueue):
 
         dt = time.perf_counter() - t0
         stats_run = run_stats(
-            comp_run, ph,
+            comp_run, ph, frontier_peak_rows=peak_rows,
             dedup_plan=self._dedup_plan(),
             canon_tier3_local=int(canon_prev[1]),
             canon_tier3_full=int(canon_prev[2]),

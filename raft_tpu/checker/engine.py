@@ -238,12 +238,16 @@ def wave_row(
 # ---------------- the end of a run ----------------
 
 
-def run_stats(comp_run, ph, **own) -> dict:
+def run_stats(comp_run, ph, *, frontier_peak_rows: int, **own) -> dict:
     """What a result's ``stats`` and the summary share: what the run
-    loaded into the process (obs/compiles.py) and its top-level spans'
-    seconds, then the engine's ``own``. Call it beside the run's wall
-    clock: ``init_s + waves_s + finish_s`` add up to that."""
-    return {**COMPILES.run_stats(comp_run), **ph.top_seconds(), **own}
+    loaded into the process (obs/compiles.py), its top-level spans'
+    seconds and ``frontier_peak_rows``, the most rows a wave of the run
+    wrote (the max of the wave rows' ``new``: how full the frontier
+    got, beside the summary's ``peak_frontier_cap``, how large it was),
+    then the engine's ``own``. Call it beside the run's wall clock:
+    ``init_s + waves_s + finish_s`` add up to that."""
+    return {**COMPILES.run_stats(comp_run), **ph.top_seconds(),
+            "frontier_peak_rows": int(frontier_peak_rows), **own}
 
 
 def summary_fields(

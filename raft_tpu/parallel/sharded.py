@@ -502,33 +502,38 @@ class ShardedBFS(FleetQueue):
             # 7. emit survivors: compact to a dense prefix of a [D*RC, W]
             # block, then ONE dynamic_update_slice per buffer appends at the
             # running cursor (rows [F, F+D*RC) / [JC, JC+D*RC) are the drop
-            # region — checker/util.py emit_append)
-            ncount = stats[0].astype(jnp.int32)
-            jcount = stats[1].astype(jnp.int32)
-            npos = (jnp.cumsum(new) - 1).astype(jnp.int32)
-            states_s = recv_pay[sidx, :W]
-            B = D * RC
-            esel = dense_prefix_sel(new, npos, B)
-            blk = jnp.concatenate(
-                [states_s, jnp.zeros((1, W), jnp.int32)], axis=0
-            )[esel]
-            jps_blk = jnp.concatenate(
-                [(sidx // RC).astype(jnp.int32), jnp.zeros((1,), jnp.int32)]
-            )[esel]
-            jpl_blk = jnp.concatenate(
-                [recv_pay[sidx, W], jnp.zeros((1,), jnp.int32)]
-            )[esel]
-            jc_blk = jnp.concatenate(
-                [recv_pay[sidx, W + 1], jnp.zeros((1,), jnp.int32)]
-            )[esel]
-            jfp_blk = jnp.concatenate(
-                [rf, jnp.full((1,), U64_MAX, jnp.uint64)]
-            )[esel]
-            next_buf, frontier_ovf = emit_append(next_buf, blk, ncount, n_new, F)
-            jps, journal_ovf = emit_append(jps, jps_blk, jcount, n_new, JC)
-            jpl, _ = emit_append(jpl, jpl_blk, jcount, n_new, JC)
-            jcand, _ = emit_append(jcand, jc_blk, jcount, n_new, JC)
-            jfp, _ = emit_append(jfp, jfp_blk, jcount, n_new, JC)
+            # region — checker/util.py emit_append), under `emit/append`,
+            # the scope DeviceBFS gives the same code
+            with jax.named_scope("append"):
+                ncount = stats[0].astype(jnp.int32)
+                jcount = stats[1].astype(jnp.int32)
+                npos = (jnp.cumsum(new) - 1).astype(jnp.int32)
+                states_s = recv_pay[sidx, :W]
+                B = D * RC
+                esel = dense_prefix_sel(new, npos, B)
+                blk = jnp.concatenate(
+                    [states_s, jnp.zeros((1, W), jnp.int32)], axis=0
+                )[esel]
+                jps_blk = jnp.concatenate(
+                    [(sidx // RC).astype(jnp.int32),
+                     jnp.zeros((1,), jnp.int32)]
+                )[esel]
+                jpl_blk = jnp.concatenate(
+                    [recv_pay[sidx, W], jnp.zeros((1,), jnp.int32)]
+                )[esel]
+                jc_blk = jnp.concatenate(
+                    [recv_pay[sidx, W + 1], jnp.zeros((1,), jnp.int32)]
+                )[esel]
+                jfp_blk = jnp.concatenate(
+                    [rf, jnp.full((1,), U64_MAX, jnp.uint64)]
+                )[esel]
+                next_buf, frontier_ovf = emit_append(
+                    next_buf, blk, ncount, n_new, F)
+                jps, journal_ovf = emit_append(
+                    jps, jps_blk, jcount, n_new, JC)
+                jpl, _ = emit_append(jpl, jpl_blk, jcount, n_new, JC)
+                jcand, _ = emit_append(jcand, jc_blk, jcount, n_new, JC)
+                jfp, _ = emit_append(jfp, jfp_blk, jcount, n_new, JC)
             if K:
                 # new-distinct per rank on the owner chip (a lane that is
                 # not new does not count: its routed rank column may be
@@ -1276,6 +1281,7 @@ class ShardedBFS(FleetQueue):
         state["cov"] = jax.device_put(cov_hd, self._sharding)
         dup_prev = 0
         tiers_prev = np.zeros((2,), np.int64)
+        peak_rows = 0
         per_shard_dup = np.zeros(D, np.int64)
         wave_times: list[float] = []  # stall-watchdog rolling window
         # every wave gets the phase split + analytic HBM watermark
@@ -1461,6 +1467,7 @@ class ShardedBFS(FleetQueue):
             depth += 1
             distinct += global_new
             depth_counts.append(global_new)
+            peak_rows = max(peak_rows, global_new)
             base_lgid = n0 + stats_h[:, 1] - new_d
             scounts += new_d
             jcounts = stats_h[:, 1].copy()
@@ -1590,7 +1597,7 @@ class ShardedBFS(FleetQueue):
 
         dt = time.perf_counter() - t0
         stats_run = run_stats(
-            comp_run, ph,
+            comp_run, ph, frontier_peak_rows=peak_rows,
             dedup_plan=self._dedup_plan(),
             canon_tier3_local=int(tiers_prev[0]),
             canon_tier3_full=int(tiers_prev[1]),

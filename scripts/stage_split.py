@@ -144,6 +144,7 @@ def main(argv=None):
         res["dedup_plan"] = got["stats"].get("dedup_plan")
         res["dedup_sort_lanes"] = got["stats"].get("dedup_sort_lanes")
         res["dedup_search_queries"] = got["stats"].get("dedup_search_queries")
+        res["frontier_peak_rows"] = got["stats"].get("frontier_peak_rows")
         res["canon_lanes"] = {
             k: sum(w[k] for w in got["waves"])
             for k in ("generated", "canon_dup_lanes", "canon_tier3_local",

@@ -224,10 +224,14 @@ def test_chip_smoke_leg_holds_the_cli_to_the_golden(smoke):
     # leg G: the same latent bug in PullRaft.cfg, the cell's chunk
     ("PULL", 64, 6, [1, 1, 3, 7, 18, 40, 86], [1, 1, 3, 7, 18, 40, 87],
      ("G", 14, 2048, ["--lenient"])),
+    # leg H: upstream's cfg omits MaxClusterSize, so --lenient; no
+    # --msg-slots (the registry's own), the cell's chunk
+    ("ADDREMOVE", None, 3, [1, 6, 27, 91], [1, 6, 27, 92],
+     ("H", 9, 1024, ["--lenient"])),
 ])
 def test_chip_smoke_second_lowerings_legs_run_their_own_cfg_and_golden(
         smoke, monkeypatch, leg, msg_slots, depth, counts, wrong, args):
-    """Legs D to G at a tiny depth: another cfg, its own chunk, its
+    """Legs D to H at a tiny depth: another cfg, its own chunk, its
     own golden and that golden's bag width; then the leg itself, its
     arguments held: the frontier, the golden's depth, the chunk, the
     flags the cfg needs."""
@@ -254,7 +258,7 @@ def test_chip_smoke_second_lowerings_legs_run_their_own_cfg_and_golden(
     monkeypatch.setattr(chip_smoke, "leg_b", lambda *a: None)
     monkeypatch.setattr(chip_smoke, "leg_c", lambda *a: None)
     assert chip_smoke.main() == 0
-    (a, kw) = calls["DEFG".index(letter)]
+    (a, kw) = calls["DEFGH".index(letter)]
     assert a[:1] + a[2:] == (
         f"leg{letter}", golden,
         ["--checker", "tpu", "--frontier-cap", "65536", *flags], max_depth, 1)

@@ -343,13 +343,16 @@ def test_rows_and_summary_have_one_author(engine):
     # "waves" and "stalls" are the collector's, after the engine's
     declared = set(SUMMARY_KEYS)
     assert set(summaries[0]) == declared | set(SUMMARY_OWN[engine])
+    # how full the frontier got: the widest wave's `new`, said alike
+    assert summaries[0]["frontier_peak_rows"] == max(r["new"] for r in rows)
     if engine == "host_fleet":
         assert [s["job"] for s in summaries[1:]] == names
         for s in summaries[1:]:
             assert set(s) == declared | {*_RUN_LOADED, "job"}
     else:
         assert len(summaries) == 1
-        base = {*PROCESS_KEYS, *_RUN_LOADED, "init_s", "waves_s", "finish_s"}
+        base = {*PROCESS_KEYS, *_RUN_LOADED, "init_s", "waves_s", "finish_s",
+                "frontier_peak_rows"}
         assert set(stats) == base | STATS_OWN[engine]
         # stats is the summary's own dict, but for the sharded engine's
         # fleet aggregates
