@@ -10,10 +10,10 @@ Pipeline per chunk (one jitted program, all device):
   2. compact the valid successor lanes (typically <20% of chunk*A) so
      canonicalization/hashing only runs on real candidates
   3. canonical fingerprints (VIEW + SYMMETRY, ops/symmetry.py)
-  4. dedup: one merged sort of the seen run, what the wave has written
-     of its new fingerprints so far and the chunk's fingerprints gives
-     membership and first-occurrence within the chunk at once
-     (checker/util.py first_new)
+  4. dedup: one merged sort of what the seen run holds, what the wave
+     has written of its new fingerprints so far and the chunk's
+     fingerprints gives membership and first-occurrence within the
+     chunk at once (checker/util.py first_new)
   5. compact survivors to a dense prefix block and APPEND it at the
      running cursor of the device next-frontier buffer — and their
      (parent gid, candidate) rows at the journal cursor — with one
@@ -36,15 +36,21 @@ a searchsorted is a serial gather, 467.5 us for 65,536 queries whatever
 they hold, and four runs of 17-19 steps were 92 ms of a 152 ms
 chunk-step, where a 720,896-lane 2-key sort takes 1.187 ms (the
 recorded trace benchmark/testdata/scoped_v5e, PR 24). So a chunk-step
-sorts its fingerprints together with the seen run, while that is short
-enough (util.first_new; the rule reads shapes alone), and with the
-smallest of a few static prefixes of the buffer that holds the wave's
-count: the sort costs what the wave has written, not what it could
-hold. Only a seen run past the crossover is binary-searched, at a cost
-of O(VC log) that is INDEPENDENT of the total state count — the round-3
-design re-sorted an FCAP-lane buffer per chunk and SCAP+FCAP lanes per
-wave, which dominated small and deep runs alike (round-3 verdict Weak
-#2, Next #4).
+sorts its fingerprints together with the seen run and the buffer, and
+the sort costs what the two hold, not what they could: the wave's count
+(stats lane 0) and the seen run's (``_seen_real``, a scalar argument of
+the wave program like ``fcount``) choose among a few static operands.
+Against a seen run at the sort's floor (2^18 lanes: nothing to cut)
+that is the run whole and the smallest of a few prefixes of the buffer
+that holds the wave's count; against a longer one, the smallest rung
+(util.merge_rungs: 1 : 1.5 : 2 : 3 : 4 ... from the floor) that holds
+both counts together, the run's front with the buffer's lanes laid
+into its padding. A seen run is binary-searched only by a chunk-step
+whose content is past the last rung short enough to merge (64 lanes a
+query), at a cost of O(VC log) that is INDEPENDENT of the total state
+count — the round-3 design re-sorted an FCAP-lane buffer per chunk and
+SCAP+FCAP lanes per wave, which dominated small and deep runs alike
+(round-3 verdict Weak #2, Next #4).
 
 This replaces TLC's shared fingerprint set + BFS queue (SURVEY.md §3.1
 hot loop); `-deadlock` semantics are preserved (terminal states counted,
@@ -77,9 +83,9 @@ from .engine import (
 )
 from .lsm import pow2_at_least
 from .util import (
-    GROWTH, HEADROOM, I32_MAX, dedup_plan, dense_prefix_sel, emit_append,
-    first_new, jit_with_donation, next_cap, rank_counts, rank_onehot,
-    wave_prefix_sizes,
+    GROWTH, HEADROOM, I32_MAX, SORT_FLOOR_LANES, dedup_plan,
+    dense_prefix_sel, emit_append, first_new, jit_with_donation,
+    merge_rungs, next_cap, rank_counts, rank_onehot, wave_prefix_sizes,
 )
 
 
@@ -113,6 +119,8 @@ class DeviceBFS(FleetQueue):
 
     GROWTH = GROWTH
     HEADROOM = HEADROOM
+    # a seen run of at most this many lanes is sorted whole (util.py)
+    SORT_FLOOR = SORT_FLOOR_LANES
 
     # overflow-bit vocabulary (mirrors the in-program stats lane); the
     # seen-set has no in-program bit — its host-side guard raises with
@@ -359,19 +367,24 @@ class DeviceBFS(FleetQueue):
         return canon_chunk(self.canon, flatc, selv)
 
     @stage("dedup")
-    def _st_dedup(self, fps, occ, wave_new, ncount, *runs):
+    def _st_dedup(self, fps, occ, wave_new, ncount, seen_real, *runs):
         """Stage 4: new = not in the seen run, not among the ``ncount``
         fingerprints earlier chunks of this wave appended to
         ``wave_new``, and first occurrence in the chunk (lowest lane),
-        by one merged sort of the seen run, the smallest static prefix
-        of ``wave_new`` that holds ``ncount`` lanes and the chunk
-        (util.first_new; a seen run past its crossover is still
-        searched, under ``occ``). A fingerprint chunk k appended is a
-        run lane for chunk k + 1, so cross-chunk in-wave dedup falls out
-        of the same lookup. Returns (new, i32[2]: the lanes that sort
-        sorted and the query lanes searched against an occupied run)."""
+        by one merged sort of the chunk with what the seen run and
+        ``wave_new`` hold (util.first_new): against a seen run at the
+        sort's floor, the run whole and the smallest static prefix of
+        ``wave_new`` that holds ``ncount`` lanes; against a longer one,
+        the smallest rung (``_rungs``) that holds its ``seen_real``
+        fingerprints and the wave's ``ncount`` together, and the binary
+        search, under ``occ``, only where no rung does. A fingerprint
+        chunk k appended is a run lane for chunk k + 1, so cross-chunk
+        in-wave dedup falls out of the same lookup. Returns (new,
+        i32[2]: the lanes that sort sorted and the query lanes searched
+        against an occupied run)."""
         new, lanes, queries = first_new(
-            fps, occ, runs, wave=(wave_new, ncount, self._wave_prefix()))
+            fps, occ, runs, wave=(wave_new, ncount, self._wave_prefix()),
+            real=(seen_real, self._rungs(runs[0].shape[0])))
         return new, jnp.stack([lanes, queries])
 
     @stage("emit")
@@ -489,7 +502,7 @@ class DeviceBFS(FleetQueue):
 
     def _chunk_step(
         self, frontier, next_buf, jparent, jcand, viol, stats, cov,
-        wave_new, cursor, fcount, base_gid, occ, *runs,
+        wave_new, cursor, fcount, base_gid, seen_real, occ, *runs,
     ):
         """One chunk of the current wave (the four stage methods above,
         composed — one traced program). stats is the i64[N_STATS]
@@ -499,15 +512,27 @@ class DeviceBFS(FleetQueue):
         reset, so host snapshots are monotone); wave_new is the wave's
         fingerprint buffer, u64[FCAP + VC], whose first stats[0] lanes
         are the fingerprints the wave's earlier chunks found new and
-        whose rest is U64_MAX; occ is bool[n_runs] (the binary search of
-        an unoccupied run is skipped via lax.cond; a merged run is
-        sorted either way). Returns the carries, wave_new with the
+        whose rest is U64_MAX; seen_real is the i32 count of the seen
+        run's fingerprints, its first lanes; occ is bool[n_runs] (the
+        binary search of an unoccupied run is skipped via lax.cond; a
+        merged run is sorted either way). Returns the carries, wave_new with the
         chunk's new fingerprints appended."""
         (flatc, sel, selv, valid, rank, n_gen, terminal, expand_ovf,
          compact_ovf) = self._st_expand(frontier, cursor, fcount)
         fps, canon_n = self._st_canon(flatc, selv)
         new, dedup_n = self._st_dedup(
-            fps, occ, wave_new, stats[0].astype(jnp.int32), *runs)
+            fps, occ, wave_new, stats[0].astype(jnp.int32), seen_real,
+            *runs)
+        if self._rungs(runs[0].shape[0]):
+            # Against a run with rungs, the candidates reach the emit
+            # stage only once the dedup stage has its answer. Left
+            # free, the compiler pads them for the survivors' gather
+            # before canon and holds that block (34 MB in pull3-full)
+            # across the dedup switch; with the rungs' longer switch
+            # it no longer keeps it in VMEM there, and the gather read
+            # HBM at 0.39 ms a chunk-step for 0.23 (PERF.md section 6,
+            # PR 49). A first-size wave program is as it was.
+            flatc, new = lax.optimization_barrier((flatc, new))
         return self._st_finish(
             next_buf, jparent, jcand, viol, stats, cov, wave_new, flatc,
             fps, sel, valid, rank, new, n_gen, terminal, expand_ovf,
@@ -521,9 +546,17 @@ class DeviceBFS(FleetQueue):
         (the frontier overflow bit aborts the run otherwise)."""
         return wave_prefix_sizes(self.R0, self.FCAP)
 
+    def _rungs(self, seen_lanes: int) -> tuple[int, ...]:
+        """The rungs of the dedup stage's merged sort against a seen run
+        of ``seen_lanes`` lanes (util.merge_rungs): none for a run at
+        the sort's floor, whose wave program is the prefix switch
+        alone."""
+        return merge_rungs(
+            seen_lanes, self.VC, self._wave_prefix(), self.SORT_FLOOR)
+
     def _wave_step(
         self, frontier, next_buf, jparent, jcand, viol, stats, cov,
-        fcount, base_gid, occ, *runs,
+        fcount, base_gid, seen_real, occ, *runs,
     ):
         """One WAVE as a single dispatched program (round 5, verdict Next
         #1): a lax.while_loop drives the chunk pipeline over the frontier,
@@ -548,7 +581,8 @@ class DeviceBFS(FleetQueue):
         def body(carry):
             k, *carries = carry
             return (k + 1, *self._chunk_step(
-                frontier, *carries, k * C, fcount, base_gid, occ, *runs))
+                frontier, *carries, k * C, fcount, base_gid, seen_real,
+                occ, *runs))
 
         def cond(carry):
             return carry[0] * C < fcount
@@ -636,7 +670,7 @@ class DeviceBFS(FleetQueue):
         yield {
             "name": "wave", "fn": self._wave_fn,
             "args": (frontier, next_buf, jparent, jcand, viol, stats,
-                     cov, i32s, i32s, occ, seen),
+                     cov, i32s, i32s, i32s, occ, seen),
             "carries": dict(wave_carries),
             "pinned": {0: "frontier"},
             "site": site(self._wave_step), "per_wave": 1,
@@ -931,7 +965,8 @@ class DeviceBFS(FleetQueue):
                     out = self._wave_fn(
                         frontier, next_buf, jparent, jcand, viol,
                         stats, cov, np.int32(fcount),
-                        np.int32(base_gid), self._occ_one, self._seen,
+                        np.int32(base_gid), np.int32(self._seen_real),
+                        self._occ_one, self._seen,
                     )
                 (next_buf, jparent, jcand, viol, stats, cov,
                  wave_new) = out
@@ -1210,8 +1245,9 @@ class DeviceBFS(FleetQueue):
         run and the prefixes of the wave's fingerprint buffer against VC
         query lanes (the manifest has it at the run's first seen size,
         ``stats`` and the summary at its last)."""
+        size = self._seen.shape[0]
         return dedup_plan(
-            [self._seen.shape[0]], self.VC, self._wave_prefix())
+            [size], self.VC, self._wave_prefix(), self._rungs(size))
 
     def _ckpt_ident(self) -> str:
         """Everything the saved run's soundness depends on: symmetry mode

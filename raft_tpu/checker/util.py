@@ -4,6 +4,7 @@ the sharded engine), so a policy fix lands once."""
 
 from __future__ import annotations
 
+import functools
 import warnings
 
 import jax
@@ -11,7 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from ..ops.hashing import eq_u64, ne_u64, split_u64
+from ..ops.hashing import U64_MAX, eq_u64, ne_u64, split_u64
 
 GROWTH = 4  # enlarge factor per growth step
 HEADROOM = 3  # grow when the next wave could need more than cap/HEADROOM
@@ -25,8 +26,17 @@ _U32_MAX = np.uint32(0xFFFFFFFF)
 # PERF.md section 6, PR 25, has the table): what a run of 2^21 lanes adds
 # to a chunk-step is 9.2 ms merged and 22.3 ms searched, one of 2^22
 # lanes 21.8 and 23.3 (and the search's gathers cost more inside the wave
-# program than alone), one of 2^23 lanes 48.8 and 24.3.
+# program than alone), one of 2^23 lanes 48.8 and 24.3. The lanes are
+# those the sort would sort: a caller that knows how many of a run's
+# lanes are real (``first_new``'s ``real``) asks it of the rung that
+# holds them, the others of the run's capacity.
 MERGE_LANES_PER_QUERY = 64
+
+# Under some 300,000 lanes a merged sort costs the same on the v5e (1.0 ms
+# in place, 1.86-1.92 ms a call for 278,528-327,680 lanes and 3.0-4.3 ns
+# for each lane more: PERF.md section 6, PRs 36 and 47), so a run of at
+# most this many lanes is sorted whole: there is nothing to cut.
+SORT_FLOOR_LANES = 1 << 18
 
 
 def probe_sorted(sorted_arr, vals):
@@ -42,23 +52,23 @@ def probe_sorted(sorted_arr, vals):
 
 
 def merges(run_lanes: int, n_queries: int) -> bool:
-    """Whether ``first_new`` merges a sorted run of ``run_lanes`` lanes
-    with ``n_queries`` queries or searches it: the static choice, from
-    shapes alone. It is asked of the seen run and of the sharded
-    engine's ``RunLSM`` levels. The wave's append buffer is not asked:
-    it is not sorted, so it cannot be searched, and what it costs
-    follows the wave's count and not its capacity (at the default
-    ``max_frontier_cap`` of 2^22 its largest prefix costs what the table
-    above gives a merged run of 2^22 lanes, 21.8 ms against 23.3
-    searched)."""
+    """Whether ``first_new`` merges ``run_lanes`` lanes of a sorted run
+    with ``n_queries`` queries or searches the run: the static choice,
+    from shapes alone. It is asked of a run's capacity where nothing
+    says how much of it is real (the sharded engine's ``RunLSM``
+    levels, a seen run at the sort's floor) and of each rung of
+    ``merge_rungs`` where the run's real-lane count chooses the rung.
+    The wave's append buffer is not asked: it is not sorted, so it
+    cannot be searched, and what it costs follows the wave's count and
+    not its capacity."""
     return run_lanes <= MERGE_LANES_PER_QUERY * n_queries
 
 
 def wave_prefix_sizes(r0: int, cap: int) -> tuple[int, ...]:
-    """The prefixes of a wave's append buffer that ``first_new`` can
-    sort: none of it, then ``r0`` lanes and four times as many again
-    while that is under ``cap``, then all ``cap`` lanes (four apart, as
-    the seen run's own sizes are)."""
+    """The prefixes of a wave's append buffer that ``first_new`` sorts
+    beside a run it does not cut (one at the sort's floor, one it
+    searches): none of it, then ``r0`` lanes and four times as many
+    again while that is under ``cap``, then all ``cap`` lanes."""
     sizes = [0]
     s = r0
     while s < cap:
@@ -67,19 +77,55 @@ def wave_prefix_sizes(r0: int, cap: int) -> tuple[int, ...]:
     return (*sizes, cap)
 
 
-def dedup_plan(run_lanes, n_queries: int, wave_prefix=()) -> dict:
+def merge_rungs(
+    run_lanes: int, n_queries: int, wave_prefix, floor: int = SORT_FLOOR_LANES,
+) -> tuple[int, ...]:
+    """The rungs of ``first_new``'s merged sort against a sorted run of
+    ``run_lanes`` lanes whose real-lane count it is given: rising lane
+    counts, each the front of the run with the wave's appended lanes
+    laid into its padding, of which a chunk-step sorts the smallest
+    that holds the run's real lanes and the wave's count together.
+
+    From ``floor`` they stand in the ratios 1 : 1.5 : 2 : 3 : 4 ... up
+    to the run and the whole buffer (``wave_prefix[-1]`` lanes), and
+    the run with each of ``wave_prefix`` is a rung too, so no step
+    sorts more than the uncut program did. A rung past the run's end
+    is the run and padding. Of a run too long to merge whole, only the
+    rungs that ``merges`` allows: content past the last is searched.
+    A run of at most ``floor`` lanes has no rungs: ``wave_prefix`` is
+    the whole choice there, as it was."""
+    if run_lanes <= floor:
+        return ()
+    top = run_lanes + wave_prefix[-1]
+    rungs = {run_lanes + p for p in wave_prefix}
+    g = floor
+    while g < top:
+        rungs |= {g, min(g + g // 2, top)}
+        g <<= 1
+    return tuple(
+        r for r in sorted(rungs) if merges(min(r, run_lanes), n_queries))
+
+
+def dedup_plan(run_lanes, n_queries: int, wave_prefix=(), rungs=()) -> dict:
     """``first_new``'s choice for sorted runs of ``run_lanes`` lanes and
     an append buffer it sorts a prefix of (``wave_prefix``: the sizes;
     empty where the engine has no such buffer), as the engines' run
     records carry it: the run sizes merged, the run sizes searched, the
-    prefix sizes, and the most lanes a chunk-step sorts."""
-    merge = [int(n) for n in run_lanes if merges(n, n_queries)]
+    prefix sizes, the rungs (``merge_rungs`` of the one run, where its
+    real-lane count chooses among them; a run with rungs is listed
+    under ``search`` if content past the last rung is searched and
+    under ``merge`` otherwise), and the most lanes a chunk-step
+    sorts."""
     prefix = [int(p) for p in wave_prefix]
+    whole = [int(n) for n in run_lanes if merges(n, n_queries)]
     return {
-        "merge": merge,
+        "merge": whole,
         "search": [int(n) for n in run_lanes if not merges(n, n_queries)],
         "wave_prefix": prefix,
-        "sort_lanes": sum(merge) + max(prefix, default=0) + int(n_queries),
+        "rungs": [int(r) for r in rungs],
+        "sort_lanes": max(
+            sum(whole) + max(prefix, default=0), max(rungs, default=0),
+        ) + int(n_queries),
     }
 
 
@@ -106,7 +152,22 @@ def _merged_new(vals, merged):
     return (lax.sort(back)[:n] & 1).astype(bool)
 
 
-def first_new(vals, occ, runs, wave=None):
+def _laid(run, buf, real, lanes: int):
+    """u64[lanes]: the front of the sorted run ``run``, U64_MAX past its
+    first ``real`` lanes, with the wave buffer's lanes laid into that
+    padding from lane ``real`` on — every real lane of both, as long as
+    ``lanes`` holds them, in one select over a slice of the buffer that
+    starts ``real`` lanes before its front. A rung past the run's end is
+    the run and padding."""
+    pad = functools.partial(jnp.pad, constant_values=U64_MAX)
+    front = pad(run[:lanes], (0, max(0, lanes - run.shape[0])))
+    held = buf[:lanes]
+    shifted = lax.dynamic_slice(
+        pad(held, (lanes, lanes - held.shape[0])), (lanes - real,), (lanes,))
+    return jnp.where(jnp.arange(lanes, dtype=jnp.int32) < real, front, shifted)
+
+
+def first_new(vals, occ, runs, wave=None, real=None):
     """bool[n] in lane order: lane i holds a value that is not U64_MAX,
     is in none of the sorted U64_MAX-padded ``runs``, is not among the
     first ``count`` lanes of the wave's append buffer, and is in no
@@ -134,17 +195,31 @@ def first_new(vals, occ, runs, wave=None):
     (``wave_prefix_sizes``; the last covers every lane that can be
     real). What is sorted is the smallest prefix that holds ``count``
     lanes, by a ``lax.switch`` over the sizes, so the sorts cost what
-    the wave has written and not what it could hold. With ``wave`` the
-    result is ``(new, lanes, queries)``: lanes the i32 number of lanes
-    the merged sort sorted, queries the i32 number of query lanes
-    handed to ``probe_sorted`` (all ``n`` for each searched run that
-    ``occ`` says is occupied; 0 while every run is merged); without,
-    the program is the one it always was."""
+    the wave has written and not what it could hold.
+
+    ``real`` is ``(seen_real, rungs)``, with ``wave`` and one run: the
+    traced i32 count of the run's real lanes, which are its first, and
+    ``merge_rungs`` of it. The sorts then cost what the run holds too:
+    the switch's first cases are the rungs, each the run's front with
+    the buffer's lanes laid into its padding (``_laid``), and a
+    chunk-step takes the smallest that holds ``seen_real + count``
+    lanes. Content past the last rung (a run too long to merge whole)
+    takes the cases of ``sizes`` and is searched; content under it is
+    not, whatever the run's capacity. Without rungs the count is not
+    read.
+
+    With ``wave`` the result is ``(new, lanes, queries)``: lanes the i32
+    number of lanes the merged sort sorted, queries the i32 number of
+    query lanes handed to ``probe_sorted`` (all ``n`` for each searched
+    run that ``occ`` says is occupied, in a step that searches; 0 while
+    every run is merged); without, the program is the one it always
+    was."""
     n = vals.shape[0]
     assert n < 1 << 31
     merged = [r for r in runs if merges(r.shape[0], n)]
     searched = [(i, r) for i, r in enumerate(runs) if not merges(r.shape[0], n)]
     lanes = None
+    past = None  # traced: the step's content is past the last rung
     with jax.named_scope("merge"):
         if wave is None:
             new = _merged_new(vals, merged)
@@ -157,14 +232,35 @@ def first_new(vals, occ, runs, wave=None):
                     _merged_new(v, [*m, b[:p]]), jnp.int32(fixed + p))
 
             case = sum((count > p).astype(jnp.int32) for p in sizes[:-1])
-            new, lanes = lax.switch(
-                case, [prefix(p) for p in sizes], buf, vals, *merged)
+            branches = [prefix(p) for p in sizes]
+            seen_real, rungs = real if real is not None else (None, ())
+            if rungs:
+                (run,) = runs
+
+                def rung(r):
+                    return lambda b, v, *m: (
+                        _merged_new(v, [_laid(run, b, seen_real, r)]),
+                        jnp.int32(r + n))
+
+                need = seen_real + count
+                held = sum((need > r).astype(jnp.int32) for r in rungs[:-1])
+                if merged:  # the last rung is the run and the buffer whole
+                    case, branches = held, [rung(r) for r in rungs]
+                else:
+                    past = need > rungs[-1]
+                    case = jnp.where(past, len(rungs) + case, held)
+                    branches = [*(rung(r) for r in rungs), *branches]
+            new, lanes = lax.switch(case, branches, buf, vals, *merged)
+
+    def searches(i):
+        return occ[i] if past is None else occ[i] & past
+
     queries = jnp.int32(0)
     with jax.named_scope("search"):
         for i, r in searched:
-            queries = queries + jnp.where(occ[i], jnp.int32(n), 0)
+            queries = queries + jnp.where(searches(i), jnp.int32(n), 0)
             hit = lax.cond(
-                occ[i],
+                searches(i),
                 lambda rr: probe_sorted(rr, vals),
                 # vals != vals: all False, and typed as the other branch
                 # is inside a shard_map (a plain zeros is unvarying there

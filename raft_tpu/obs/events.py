@@ -312,13 +312,16 @@ def _dedup_plan_problems(plan) -> list[str]:
     """What is wrong with a manifest's or summary's ``dedup_plan``
     (checker/util.py dedup_plan): the sizes of the runs the dedup stage
     merges and searches, the prefix sizes of the wave's append buffer
-    it can sort (none on the sharded engine; else from 0 up) and the
-    most lanes a chunk-step sorts, which are the merged runs and the
-    largest prefix at least."""
+    it can sort (none on the sharded engine; else from 0 up), the rungs
+    it cuts a longer seen run by, where it says them (rising lane
+    counts; none against a run at the sort's floor) and the most lanes
+    a chunk-step sorts, which are the merged runs and the largest
+    prefix at least, and the last rung."""
     if not isinstance(plan, dict) or any(
             k not in plan for k in DEDUP_PLAN_KEYS):
         return [f"{plan!r} must carry {DEDUP_PLAN_KEYS}"]
-    lists = [plan[k] for k in DEDUP_PLAN_KEYS[:3]]
+    rungs = plan.get("rungs", [])
+    lists = [*(plan[k] for k in DEDUP_PLAN_KEYS[:3]), rungs]
     if not all(isinstance(v, list) and all(map(_is_count, v))
                for v in lists) or not _is_count(plan["sort_lanes"]):
         return [f"{plan!r}: sizes are non-negative ints (lanes)"]
@@ -327,10 +330,14 @@ def _dedup_plan_problems(plan) -> list[str]:
     if prefix and (prefix[0] != 0 or any(
             a >= b for a, b in zip(prefix, prefix[1:]))):
         found.append(f"wave_prefix {prefix!r} must rise strictly from 0")
-    if plan["sort_lanes"] < sum(plan["merge"]) + max(prefix, default=0):
+    if any(a >= b for a, b in zip(rungs, rungs[1:])):
+        found.append(f"rungs {rungs!r} must rise strictly")
+    if plan["sort_lanes"] < max(
+            sum(plan["merge"]) + max(prefix, default=0),
+            max(rungs, default=0)):
         found.append(
             f"sort_lanes {plan['sort_lanes']} is under the merged runs "
-            f"and the largest prefix together")
+            f"and the largest prefix together, or under the last rung")
     return found
 
 

@@ -38,9 +38,10 @@ canon_dup_lanes (the representatives its in-chunk dedup let through),
 and its dedup_sort_lanes, where the engine counts them (the lanes its
 dedup stage's merged sort sorted), a non-negative int. A `manifest` or
 `summary` event's dedup_plan, where it has one, must list merge, search
-and wave_prefix as non-negative ints, wave_prefix strictly increasing
-from 0 where it is not empty, and sort_lanes the merged runs, the
-largest prefix and the queries together at least.
+and wave_prefix (and rungs, where it says them) as non-negative ints,
+wave_prefix strictly increasing from 0 where it is not empty, rungs
+strictly increasing, and sort_lanes the merged runs, the largest prefix
+and the queries together at least, and the last rung.
 A `summary` event's set-up keys (programs_loaded, programs_traced,
 setup_*_s, load_*_s: obs/compiles.py) must be non-negative numbers or
 null, and its `programs`, where it has them, a list of records with a

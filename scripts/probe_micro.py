@@ -30,14 +30,19 @@ seconds are the compile's). Milliseconds are the host's clock round
 ``block_until_ready``, median and fastest of ``--reps`` calls.
 
 ``--cells`` times instead what the dedup stage's lookup costs a
-chunk-step in the benchmark's five cells (``benchmark/workloads``; four
-shapes, and a fifth for the 2^20-lane seen run of ``kraft3-wide``'s last
-wave): ``util.first_new`` with the seen run, the wave's append buffer and
-VC queries, one program a shape, called with a count in each of its
-prefix sizes, so every branch of its switch is timed in place, with the
-lanes it sorted and the nanoseconds a lane; and beside it ``ladder``, the
-lookup as it was, the seen run and every level of the ladder sorted
-whatever they held.
+chunk-step in the benchmark's cells (``benchmark/workloads``; a shape
+for each (seen run, VC, frontier) the cells' first wave programs have,
+and one for each later size of the seen run that ``kraft3-wide``,
+``pull3-full`` and ``addremove4-wide`` reach): ``util.first_new`` with
+the seen run, the wave's append buffer and VC queries, one program a
+shape, called with a count in each of its prefix sizes, so every branch
+of its switch is timed in place, with the lanes it sorted and the
+nanoseconds a lane; for a run past the sort's floor also every rung of
+``util.merge_rungs`` (``rungs``: the program the engine runs there since
+PR 49, called with the run's and the wave's counts filling the rung);
+and beside it ``ladder``, the lookup as it was, the seen run and every
+level of the ladder sorted whatever they held. ``--only`` keeps the
+shapes whose cells' names hold the word.
 
     python scripts/probe_micro.py [--vc 65536] [--lo 16] [--hi 25]
         [--few 18 22 24] [--reps 10] [--out chiprun_out/probe_micro.json]
@@ -76,6 +81,14 @@ def _time(fn, args, reps, want):
             "first_call_s": first_s}
 
 
+# the seen run's later sizes, where a cell's job reaches them
+LATER_SEEN = {
+    "kraft3-wide": [(1 << 20, "wave 20")],
+    "pull3-full": [(1 << 20, "waves 23-32"), (1 << 22, "waves 33-37")],
+    "addremove4-wide": [(1 << 20, "waves 15-16")],
+}
+
+
 def cell_shapes():
     """[(cells, seen lanes, VC, FCAP)]: the shapes the dedup stage's
     lookup has in the benchmark's cells, by DeviceBFS's own rules (16
@@ -95,9 +108,9 @@ def cell_shapes():
         vc = params["chunk"] * default["valid_per_state"].default
         fcap = params.get("frontier_cap", default["frontier_cap"].default)
         shapes.setdefault((1 << 18, vc, fcap), []).append(cell["name"])
-        if cell["name"] == "kraft3-wide":  # wave 20: 322,004 seen
-            shapes.setdefault((1 << 20, vc, fcap), []).append(
-                cell["name"] + " wave 20")
+        for size, waves in LATER_SEEN.get(cell["name"], ()):
+            shapes.setdefault((size, vc, fcap), []).append(
+                f"{cell['name']} {waves}")
     return [(cells, *shape) for shape, cells in sorted(shapes.items())]
 
 
@@ -117,6 +130,8 @@ def time_cells(args):
     dev = jax.devices()[0]
     rows = []
     for cells, seen_lanes, vc, fcap in cell_shapes():
+        if args.only and not any(args.only in c for c in cells):
+            continue
         sizes = util.wave_prefix_sizes(pow2_at_least(vc), fcap)
         seen_h = np.full((seen_lanes,), pad)
         seen_h[: seen_lanes // 2] = np.sort(rng.integers(
@@ -155,6 +170,28 @@ def time_cells(args):
             row["prefix"].append({
                 "prefix_lanes": p, "count": count, "sort_lanes": lanes,
                 "ns_per_lane": 1e6 * t["median_ms"] / lanes, **t})
+        # a run past the sort's floor: every rung of its own switch in
+        # place, the run's fingerprints filling three quarters of the
+        # rung (or the run) and the wave's count the rest
+        rungs = util.merge_rungs(seen_lanes, vc, sizes)
+        rung_fn = jax.jit(lambda v, s, b, c, r: util.first_new(
+            v, occ, (s,), wave=(b, c, sizes), real=(r, rungs))[0])
+        row["rungs"] = []
+        for r in rungs:
+            real = min(3 * r // 4, seen_lanes)
+            count = min(r - real, fcap)
+            s_h = np.full((seen_lanes,), pad)
+            s_h[:real] = np.sort(rng.integers(
+                0, 1 << 63, size=real, dtype=np.uint64))
+            b, _ = buffer_of(count)
+            want = first & (v_h != pad) & ~np.isin(v_h, s_h) & ~np.isin(
+                v_h, np.asarray(b)[:count])
+            t = _time(rung_fn, (v, jnp.asarray(s_h), b, np.int32(count),
+                                np.int32(real)), args.reps, want)
+            row["rungs"].append({
+                "rung_lanes": r, "real": real, "count": count,
+                "sort_lanes": r + vc,
+                "ns_per_lane": 1e6 * t["median_ms"] / (r + vc), **t})
         # the lookup as it was: every level of the ladder, sorted
         levels, n = [], pow2_at_least(vc)
         while n < pow2_at_least(fcap):
@@ -190,6 +227,8 @@ def main(argv=None):
     ap.add_argument("--reps", type=int, default=10)
     ap.add_argument("--cells", action="store_true",
                     help="time first_new at the benchmark cells' shapes")
+    ap.add_argument("--only", default=None,
+                    help="with --cells: the shapes of cells named so")
     ap.add_argument("--out", default=None)
     ap.add_argument("--platform", default=None)
     args = ap.parse_args(argv)

@@ -196,8 +196,8 @@ def stages(args, ref):
         jnp.full((max(1, len(eng.invariants)),), I32_MAX, jnp.int32),
         jnp.zeros((eng.N_STATS,), jnp.int64),
         jnp.zeros((eng.n_actions, 3), jnp.int64),
-        np.int32(len(frontier)), np.int32(0), eng._occ_one,
-        jnp.asarray(seen))
+        np.int32(len(frontier)), np.int32(0), np.int32(len(seen_fp)),
+        eng._occ_one, jnp.asarray(seen))
     nxt, jparent, jcand, viol, stats, cov, wave_new = jax.device_get(res)
     print(f"wave on {len(frontier)} rows: stats {stats.tolist()} "
           f"violations {viol.tolist()}", flush=True)

@@ -93,7 +93,7 @@ def test_wave_program_consumes_donated_carries():
     seen = jnp.full((dev._seen_sizes[0],), np.uint64(2**64 - 1), jnp.uint64)
     out = dev._wave_fn(
         frontier, *donated.values(), np.int32(0), np.int32(0),
-        dev._occ_one, seen,
+        np.int32(0), dev._occ_one, seen,
     )
     jax.block_until_ready(out)
     for name, buf in donated.items():

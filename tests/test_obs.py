@@ -594,7 +594,7 @@ def test_dedup_plan_schema_rule(etype, keys):
     """`dedup_plan` on a manifest and a summary: the sizes merged and
     searched, the wave buffer's prefix sizes from 0 up (none on the
     sharded engine) and the most lanes a chunk-step sorts."""
-    from raft_tpu.checker.util import dedup_plan
+    from raft_tpu.checker.util import dedup_plan, merge_rungs
     from raft_tpu.obs.events import validate_event
 
     ev = _fields(keys, ident="x/hashv=5", exit_cause="exhausted")
@@ -605,7 +605,22 @@ def test_dedup_plan_schema_rule(etype, keys):
     sharded = dedup_plan([1 << 16, 1 << 17, 1 << 23], 1 << 16)
     assert sharded["wave_prefix"] == []
     assert validate_event({**ev, "dedup_plan": sharded}) == []
+    # a run past the sort's floor says its rungs; a stream written
+    # before PR 49 has no such key and stays clean
+    prefix = (0, 1 << 15, 1 << 17, 1 << 19)
+    for size in (1 << 20, 1 << 22):
+        cut = dedup_plan([size], 1 << 15, prefix,
+                         merge_rungs(size, 1 << 15, prefix))
+        assert cut["rungs"][0] == 1 << 18 and cut["sort_lanes"] == max(
+            cut["rungs"][-1], sum(cut["merge"]) + prefix[-1]) + (1 << 15)
+        assert validate_event({**ev, "dedup_plan": cut}) == []
+    assert plan["rungs"] == [] and validate_event({**ev, "dedup_plan": {
+        k: v for k, v in plan.items() if k != "rungs"}}) == []
     for bad, says in (
+        ({**cut, "rungs": cut["rungs"][::-1]}, "rungs"),
+        ({**cut, "rungs": [1 << 18, 1 << 18]}, "rise strictly"),
+        ({**cut, "sort_lanes": cut["rungs"][-1] - 1}, "under the last rung"),
+        ({**cut, "rungs": [1.5]}, "non-negative ints"),
         ({**plan, "wave_prefix": [65536, 262144]}, "strictly from 0"),
         ({**plan, "wave_prefix": [0, 262144, 65536]}, "strictly from 0"),
         ({**plan, "sort_lanes": 1 << 18}, "under the merged runs"),

@@ -270,40 +270,59 @@ def test_golden_is_the_whole_space_and_the_smoke_file_its_prefix(golden):
         "totals"][str(depth)]
 
 
-def test_search_queries_count_the_chunk_steps_past_the_crossover(setup):
+def test_search_queries_count_the_chunk_steps_whose_content_no_rung_holds(
+        setup):
     """A small engine whose seen run is past the crossover for part of
-    the verdict: 128 query lanes a chunk-step (16 rows x 8), a ladder of
-    4,096 and 16,384 lanes by hand. 128 queries merge a run of up to
-    8,192 lanes and search a longer one, so waves 1-11 (4,150 distinct
-    after wave 11) merge and waves 12 and 13, 116 and 195 chunk-steps,
-    search: the counter is those steps' query lanes and nothing else,
-    each row says which run it met, and the counts stay the golden's."""
+    the verdict: 128 query lanes a chunk-step (16 rows x 8), a floor of
+    1,024 lanes and a ladder of 4,096, 16,384 and 65,536 lanes by hand.
+    128 queries merge up to 8,192 lanes, so the 16,384-lane run is
+    merged by its rungs while it and the wave hold no more than that:
+    all of wave 12 (4,150 fingerprints before it, 7,258 after) and the
+    chunk-steps of wave 13 that start with 934 new states or fewer;
+    the rest of wave 13 and all 315 steps of wave 14, whose run starts
+    with 12,288 fingerprints, search it. The counter is those steps'
+    query lanes and nothing else, each row says which run it met, and
+    the counts stay the golden's."""
     from raft_tpu.checker.device_bfs import DeviceBFS
 
     eng = DeviceBFS(setup.model, invariants=setup.invariants, symmetry=True,
                     chunk=16, valid_per_state=8, frontier_cap=1 << 13,
                     max_frontier_cap=1 << 13, journal_cap=1 << 15)
-    eng._seen_sizes = [1 << 12, 1 << 14]
+    eng._seen_sizes = [1 << 12, 1 << 14, 1 << 16]
+    eng.SORT_FLOOR = 1 << 10
     assert eng.VC == 128
-    assert util.merges(1 << 12, 128) and not util.merges(1 << 14, 128)
-    res = eng.run(max_depth=13, collect_metrics=True)
-    counts = _load(SMOKE_GOLDEN)["depth_limited"]["depth_counts"][:14]
+    assert util.merges(1 << 13, 128) and not util.merges(1 << 14, 128)
+    assert eng._rungs(1 << 14) == (
+        1024, 1536, 2048, 3072, 4096, 6144, 8192)
+    res = eng.run(max_depth=14, collect_metrics=True)
+    counts = _load(SMOKE_GOLDEN)["depth_limited"]["depth_counts"][:15]
     assert [int(x) for x in res.depth_counts] == counts
-    assert res.violation is None and res.distinct == 12288
+    assert res.violation is None and res.distinct == 20091
+    assert (sum(counts[:12]), sum(counts[:13]), sum(counts[:14])) == (
+        4150, 7258, 12288)
     rows = res.metrics
     assert not any(w["overflow_bits"] for w in rows)
-    assert [w["seen_lanes"] for w in rows] == [1 << 12] * 11 + [1 << 14] * 2
+    assert [w["seen_lanes"] for w in rows] == [1 << 12] * 11 + [1 << 14] * 3
     # the row's own size is the run the wave met; lsm_lanes is the run
-    # it left behind, a wave earlier at the step
-    assert [w["lsm_lanes"] for w in rows] == [1 << 12] * 10 + [1 << 14] * 3
+    # it left behind, a wave earlier at each step
+    assert [w["lsm_lanes"] for w in rows] == (
+        [1 << 12] * 10 + [1 << 14] * 3 + [1 << 16])
     steps = [-(-w["frontier"] // 16) for w in rows]
-    assert steps[11:] == [116, 195]
-    assert [w["dedup_search_queries"] for w in rows] == (
-        [0] * 11 + [116 * 128, 195 * 128])
-    assert res.stats["dedup_search_queries"] == 311 * 128
-    assert eng._dedup_plan()["search"] == [1 << 14]
-    # a searched run is not among the lanes the merged sort sorted
-    assert rows[12]["dedup_sort_lanes"] <= 195 * ((1 << 13) + 128)
+    assert steps[11:] == [116, 195, 315]
+    queries = [w["dedup_search_queries"] for w in rows]
+    assert queries[:12] == [0] * 12 and queries[13] == 315 * 128
+    # wave 13 searches from the step at which its count passes 934
+    assert 0 < queries[12] < 195 * 128 and queries[12] % 128 == 0
+    assert res.stats["dedup_search_queries"] == sum(queries)
+    plan = eng._dedup_plan()
+    assert plan["search"] == [1 << 16] and plan["rungs"][-1] == 8192
+    # a step sorts the rung that holds its content or, searching, the
+    # wave's prefix and its queries: never the run's capacity
+    assert rows[11]["dedup_sort_lanes"] <= 116 * (8192 + 128)
+    searched13 = queries[12] // 128
+    assert rows[12]["dedup_sort_lanes"] <= (
+        (195 - searched13) * (8192 + 128) + searched13 * ((1 << 13) + 128))
+    assert rows[13]["dedup_sort_lanes"] <= 315 * ((1 << 13) + 128)
 
 
 def test_wave_row_schema_knows_the_two_new_keys(device_run):
