@@ -309,9 +309,10 @@ def dense_prefix_sel(new, npos, n_lanes: int):
     ``npos = cumsum(new) - 1`` (int32, the destination rank of each new
     lane). Returns ``sel`` [n_lanes] with sel[j] = lane index of the
     j-th new lane for j < n_new, and ``n_lanes`` (the caller's pad/drop
-    row) past the prefix. Same one-hot-scatter idiom as the valid-lane
-    compaction in the chunk pipeline: the scatter is confined to an
-    (n_lanes+1)-sized index buffer, never a capacity-sized one.
+    row) past the prefix. The scatter is confined to an
+    (n_lanes+1)-sized index buffer, never a capacity-sized one
+    (``emit``'s compaction; ``expand``'s two are sorts of one key,
+    ``engine.compact_chunk``, and this one is ROADMAP S13's).
     """
     edst = jnp.where(new, npos, n_lanes)
     return (

@@ -269,9 +269,10 @@ class SparseExpandMixin:
                   built per GROUP in fixed-budget blocks so every wave
                   stays on one precompiled signature. Per-lane switch
                   would execute ALL branches under vmap (costing more
-                  than the dense pass it replaces); segmenting the
-                  worklist by group runs each kernel only on its own
-                  lanes.
+                  than the dense pass it replaces); the worklist is
+                  segmented by group by ONE sort of one int32 key
+                  (group, flat lane), so each kernel runs only on its
+                  own lanes and no compaction is a scatter.
 
     Subclass contract: ``self.bindings`` (same-named candidates
     contiguous, as every lowering already builds them), kernels named
@@ -412,6 +413,7 @@ class SparseExpandMixin:
         groups (the message bag) still pay for every slot. The
         per-wave ``enabled_density`` gauge and the coverage table's
         enabled column are the tuning inputs."""
+        self.segment_stride(chunk)  # the apply's sort key fits, or raise
         plan = []
         for g in self.sparse_groups():
             if isinstance(valid_per_group, dict):
@@ -422,53 +424,83 @@ class SparseExpandMixin:
             plan.append(int(min(math.ceil(chunk * cap), worklist)))
         return tuple(plan)
 
+    def segment_stride(self, chunk: int) -> int:
+        """The key stride ``chunk * A + 1`` of ``sparse_apply``'s one
+        sort: group g's keys are ``g * stride + flat`` and the drop
+        key lies past the last group's, so ``(G + 1) * stride`` has to
+        fit an int32."""
+        stride = chunk * self.A + 1
+        G = len(self.sparse_groups())
+        if (G + 1) * stride >= 1 << 31:
+            raise ValueError(
+                f"chunk={chunk} x A={self.A} candidate lanes in {G} "
+                f"groups pass the int32 key of the sparse apply's sort: "
+                f"({G} + 1) * ({chunk} * {self.A} + 1) >= 2^31; lower "
+                f"the chunk")
+        return stride
+
     def sparse_apply(self, batch, sel, selv, plan):
         """Successor rows of a compacted enabled worklist.
 
         ``batch`` [C, W] chunk states; ``sel`` [VC] flat candidate ids
-        (lane * A + cand) with the drop value C*A past the enabled
-        prefix; ``selv`` = sel < C*A; ``plan`` the static per-group
-        budgets from sparse_plan. Returns (flatc [VC, W], apply_ovf):
-        bit-identical to the dense ``flatp[sel]`` gather for every
-        in-budget worklist lane (drop lanes select a zeros row, exactly
-        as the dense path's appended pad row). Lanes of a group past
-        its budget also land on the zeros row, with ``apply_ovf`` set —
-        the engines fold it into the overflow abort, so no surviving
-        wave ever reads one."""
+        (lane * A + cand), ascending, with the drop value C*A past the
+        enabled prefix (``engine.compact_chunk``'s); ``selv`` = sel <
+        C*A; ``plan`` the static per-group budgets from sparse_plan.
+        Returns (flatc [VC, W], apply_ovf): bit-identical to the dense
+        ``flatp[sel]`` gather for every in-budget worklist lane (drop
+        lanes select a zeros row, exactly as the dense path's appended
+        pad row). Lanes of a group past its budget also land on the
+        zeros row, with ``apply_ovf`` set — the engines fold it into
+        the overflow abort, so no surviving wave ever reads one.
+
+        The worklist is segmented by group with ONE sort of one int32
+        key, ``group * (C*A + 1) + flat``: the group of a candidate is a
+        step function of ``sel % A`` over the groups' static offsets
+        (compares, no table gather), and group g's lanes are then the
+        ``count_g`` sorted keys from the running sum of the counts
+        before it, in the worklist's own order. Never a compaction by
+        ``.at[dst].set``: a scatter is a serial pass over all VC lanes
+        on the TPU, 4.6 ns a lane a group."""
         import jax
+        from jax import lax
 
         C, W = batch.shape
         A = self.A
         groups = self.sparse_groups()
+        G = len(groups)
         VC = sel.shape[0]
         total = sum(plan)
-        group_of = np.zeros((A,), np.int32)
-        for gi, g in enumerate(groups):
-            group_of[g.off : g.off + g.n] = gi
+        stride = self.segment_stride(C)
+        cand = sel % A
         wg = jnp.where(
             selv,
-            jnp.asarray(group_of)[jnp.clip(sel, 0, C * A - 1) % A],
-            len(groups),
+            sum((cand >= g.off).astype(jnp.int32) for g in groups[1:]),
+            G,
         )
-        selp = jnp.concatenate([sel, jnp.full((1,), C * A, jnp.int32)])
+        drop = G * stride + C * A
+        # padded by the largest budget so that no slice below clamps
+        keys = jnp.concatenate([
+            lax.sort(jnp.where(selv, wg * stride + sel, drop)),
+            jnp.full((max(plan),), drop, jnp.int32),
+        ])
         row = jnp.full((VC,), total, jnp.int32)  # default: the zeros row
         apply_ovf = jnp.zeros((), bool)
         blocks = []
         base = 0
+        start = jnp.zeros((), jnp.int32)
         for gi, (g, eb) in enumerate(zip(groups, plan)):
             mask = wg == gi
             pos = jnp.cumsum(mask.astype(jnp.int32)) - 1
-            apply_ovf = apply_ovf | (jnp.sum(mask.astype(jnp.int32)) > eb)
-            # compact the group's worklist lanes to a dense [eb] prefix
-            # (same confined one-hot scatter as the engines' valid-lane
-            # compaction; destination eb is the drop slot)
-            edst = jnp.where(mask, jnp.minimum(pos, eb), eb)
-            idx = (
-                jnp.full((eb + 1,), VC, jnp.int32)
-                .at[edst]
-                .set(jnp.arange(VC, dtype=jnp.int32))[:eb]
+            count = jnp.sum(mask.astype(jnp.int32))
+            apply_ovf = apply_ovf | (count > eb)
+            # the group's segment of the sorted keys: [eb] flat
+            # candidate ids, C*A (the drop value) past its count
+            flat = jnp.where(
+                jnp.arange(eb, dtype=jnp.int32) < count,
+                lax.dynamic_slice(keys, (start,), (eb,)) - gi * stride,
+                C * A,
             )
-            flat = selp[idx]  # [eb] flat candidate ids, drop -> C*A
+            start = start + count
             lane = jnp.clip(flat // A, 0, C - 1)
             k = jnp.clip(flat % A - g.off, 0, g.n - 1)
             srows = batch[lane]

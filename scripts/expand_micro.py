@@ -5,9 +5,17 @@ expansion (models/base.py SparseExpandMixin): the dense path runs every
 per-action kernel over all chunk*A candidate lanes and gathers the
 VC-compacted survivors, while the guard-first path runs the DCE-derived
 guard pass (valid/rank/ovf only, no W-wide rows) over the same grid and
-then constructs successors just for the enabled worklist, vmapped per
-action group over a static budget plan. Both paths produce bit-identical
-[VC, W] compacted blocks.
+then constructs successors just for the enabled worklist, segmented by
+group by one sort of one int32 key and vmapped per action group over a
+static budget plan. Both paths produce bit-identical [VC, W] compacted
+blocks, and both take their worklist as the engines do, from
+``engine.compact_chunk`` (the valid lanes by one sort, PR 50).
+
+``--compaction N [N ...]`` times that idiom alone, on the chip: a stream
+compaction of N int32 lanes as one single-key ``lax.sort`` and as the
+cumsum and ``.at[dst].set`` scatter it replaced, K times inside one
+program at two K, so the call's own 0.8 ms cancels: ns a lane of each
+(ROADMAP S3, S8 d and S13 price their sorts from it).
 
 Two dense baselines are timed, because they differ enormously:
 
@@ -49,6 +57,7 @@ Usage:
                                  [--elections 3] [--restarts 1]
                                  [--msg-slots 32] [--depth 10]
                                  [--reps 5] [--platform cpu]
+  python scripts/expand_micro.py --compaction 16384 65536 217088
 
 Writes chiprun_out/expand_micro.json (device provenance + one row per
 (chunk, vpg) cell), where a chip run's results come back.
@@ -89,35 +98,21 @@ def bench_cell(model, batch_h, vpg, reps):
     VC = min(C * A, C * 16)
     batch = jnp.asarray(batch_h)
 
+    from raft_tpu.checker.engine import compact_chunk
+
     # -- dense path: full kernels over every lane + compaction gather
     def dense(b):
         succs, valid, rank, ovf = jax.vmap(model._expand1)(b)
-        vflat = valid.reshape(-1)
-        vpos = jnp.cumsum(vflat) - 1
-        sdst = jnp.where(vflat, jnp.minimum(vpos, VC), VC)
-        sel = (
-            jnp.full((VC + 1,), C * A, jnp.int32)
-            .at[sdst]
-            .set(jnp.arange(C * A, dtype=jnp.int32))[:VC]
-        )
-        flatp = jnp.concatenate(
-            [succs.reshape(C * A, W), jnp.zeros((1, W), jnp.int32)],
-            axis=0,
-        )
-        return flatp[sel], sel < C * A
+        flatc, _sel, selv, _ovf = compact_chunk(
+            None, None, b, succs, valid, jnp.sum(valid), VC)
+        return flatc, selv
 
     # -- guard-first path, split so each phase gets its own row
     guards = jax.jit(lambda b: jax.vmap(model.guards1)(b))
 
     def worklist(valid):
-        vflat = valid.reshape(-1)
-        vpos = jnp.cumsum(vflat) - 1
-        sdst = jnp.where(vflat, jnp.minimum(vpos, VC), VC)
-        sel = (
-            jnp.full((VC + 1,), C * A, jnp.int32)
-            .at[sdst]
-            .set(jnp.arange(C * A, dtype=jnp.int32))[:VC]
-        )
+        sel = jnp.sort(jnp.where(
+            valid.reshape(-1), jnp.arange(C * A, dtype=jnp.int32), C * A))[:VC]
         return sel, sel < C * A
 
     plan = model.sparse_plan(C, VC, vpg)
@@ -161,6 +156,56 @@ def bench_cell(model, batch_h, vpg, reps):
     return row
 
 
+def bench_compaction(lanes, reps):
+    """ns a lane of one stream compaction of ``lanes`` int32 lanes, as
+    the single-key sort ``expand`` runs and as the scatter it replaced.
+    Each form runs K times inside one program (the mask re-drawn from
+    the loop's counter, the result folded into the carry so nothing is
+    dead) at K = 8 and K = 72: the difference is 64 compactions with no
+    call in it."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    N = lanes
+    idx = jnp.arange(N, dtype=jnp.int32)
+
+    def mask(i):  # a third of the lanes valid, another third each turn
+        return ((idx * 7 + i * 13) % 3) == 0
+
+    def by_sort(i, acc):
+        sel = lax.sort(jnp.where(mask(i), idx, N))
+        return acc + sel[N // 4]
+
+    def by_scatter(i, acc):
+        m = mask(i)
+        pos = jnp.cumsum(m) - 1
+        sel = (
+            jnp.full((N + 1,), N, jnp.int32)
+            .at[jnp.where(m, pos, N)]
+            .set(idx)[:N]
+        )
+        return acc + sel[N // 4]
+
+    row = {"lanes": N}
+    for name, body in (("sort", by_sort), ("scatter", by_scatter)):
+        ts = {}
+        for K in (8, 72):
+            fn = jax.jit(lambda a, K=K, body=body: lax.fori_loop(
+                0, K, body, a))
+            ts[K] = _time(fn, jnp.int32(0), reps=reps)
+        row[f"{name}_ns_per_lane"] = round(
+            (ts[72] - ts[8]) / 64 / N * 1e9, 3)
+        row[f"{name}_ms"] = round((ts[72] - ts[8]) / 64 * 1e3, 4)
+    # equal answers, outside the timed loop
+    m = mask(3)
+    want = jnp.full((N + 1,), N, jnp.int32).at[
+        jnp.where(m, jnp.cumsum(m) - 1, N)].set(idx)[:N]
+    row["parity"] = bool(jnp.array_equal(
+        lax.sort(jnp.where(m, idx, N)), want))
+    return row
+
+
 def main():
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--chunk", type=int, nargs="+", default=[1024, 4096])
@@ -173,12 +218,27 @@ def main():
     ap.add_argument("--depth", type=int, default=10)
     ap.add_argument("--reps", type=int, default=5)
     ap.add_argument("--platform", default=None)
+    ap.add_argument("--compaction", type=int, nargs="+", default=None,
+                    metavar="LANES",
+                    help="time one compaction of LANES int32 lanes by "
+                         "sort and by scatter, and nothing else")
     args = ap.parse_args()
 
     import jax
 
     if args.platform:
         jax.config.update("jax_platforms", args.platform)
+    if args.compaction:
+        rows = [bench_compaction(n, args.reps) for n in args.compaction]
+        for row in rows:
+            print(json.dumps(row), flush=True)
+        path = os.path.join(ROOT, "chiprun_out", "expand_compaction.json")
+        os.makedirs(os.path.dirname(path), exist_ok=True)
+        with open(path, "w") as f:
+            json.dump({"device": str(jax.devices()[0]), "rows": rows}, f,
+                      indent=1)
+        print(f"wrote {path}")
+        return
     import numpy as np
 
     from raft_tpu.models.raft import RaftModel, RaftParams

@@ -229,6 +229,18 @@ def _sharded_chunk():
     return eng, "chunk"
 
 
+def _traced(make):
+    """(engine, program name, every equation) of the program a wave
+    dispatches, traced from the engine's own audit entry; nothing
+    compiled."""
+    import jax
+
+    eng, name = make()
+    (prog,) = [p for p in eng.audit_programs() if p["name"] == name]
+    return eng, name, list(
+        _eqns(jax.make_jaxpr(prog["fn"])(*prog["args"]).jaxpr))
+
+
 @pytest.mark.parametrize(
     "make", [_device_wave, _sharded_chunk], ids=["device", "sharded"])
 def test_wave_program_scatter_adds_nothing(make):
@@ -241,12 +253,8 @@ def test_wave_program_scatter_adds_nothing(make):
     here. The one place allowed is the spec lowering's own per-state
     body, ``expand/vmap()`` (``log_len.at[i].add(1)``): the model's, not
     the engine's."""
-    import jax
-
-    eng, name = make()
+    eng, name, eqns = _traced(make)
     assert eng.n_actions > 0
-    (prog,) = [p for p in eng.audit_programs() if p["name"] == name]
-    eqns = list(_eqns(jax.make_jaxpr(prog["fn"])(*prog["args"]).jaxpr))
     stacks = {str(e.source_info.name_stack) for e in eqns}
     # the scopes this test reads are there, so an empty list means none
     assert any(s.startswith("emit/coverage") or s.startswith("expand")
@@ -257,6 +265,37 @@ def test_wave_program_scatter_adds_nothing(make):
         and "expand/vmap()" not in str(e.source_info.name_stack)
     ]
     assert not adds, f"scatter-add in the {name} program under: {adds}"
+
+
+@pytest.mark.parametrize(
+    "make", [_device_wave, _sharded_chunk], ids=["device", "sharded"])
+def test_wave_program_expand_compacts_by_sorts(make):
+    """The engagement check of ``expand``'s two stream compactions
+    (PR 50): the valid lanes of a chunk (``engine.compact_chunk``) and
+    the worklist's segmentation by group (``sparse_apply``) are each ONE
+    sort of one int32 key. An ``.at[dst].set`` over the lanes is a
+    serial pass on the TPU (4.6 ns a lane: PERF.md section 6, PR 50;
+    seven of them a chunk-step were 11.6 % of ``raft3-wide``'s device
+    time), so the program a wave dispatches holds no ``scatter`` under
+    the ``expand`` scope outside the spec lowering's own per-state body,
+    ``expand/vmap()``, and exactly two ``sort`` equations there, of one
+    operand each."""
+    eng, name, eqns = _traced(make)
+    assert eng._sparse
+    own = [
+        e for e in eqns
+        if str(e.source_info.name_stack).startswith("expand")
+        and "expand/vmap()" not in str(e.source_info.name_stack)
+    ]
+    # the scope is there, so an empty list below means none
+    assert len(own) > 50, len(own)
+    scatters = [
+        (e.primitive.name, str(e.source_info.name_stack)) for e in own
+        if e.primitive.name.startswith("scatter")]
+    assert not scatters, f"scatter in the {name} program under: {scatters}"
+    sorts = [e for e in own if e.primitive.name == "sort"]
+    assert [len(e.invars) for e in sorts] == [1, 1], sorts
+    assert all(str(v.aval.dtype) == "int32" for e in sorts for v in e.invars)
 
 
 @pytest.mark.parametrize(

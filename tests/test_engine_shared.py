@@ -7,9 +7,15 @@ A plain function called under the caller's ``obs.stage`` scope adds no
 scope and no equation, so neither program may change: this file holds
 ``(equations, conftest.jaxpr_digest)`` of every entry of both engines'
 ``audit_programs()`` (the production jit objects and their abstract
-arguments) at PR 45's tree, ``8ace3e6``, computed there on a
-``git archive`` copy before the functions were lifted out. Nothing is
-compiled or run.
+arguments). PR 46 pinned them at PR 45's tree, ``8ace3e6``, computed
+there on a ``git archive`` copy before the functions were lifted out;
+PR 50 re-pinned every wave and chunk program on purpose: the two stream
+compactions of ``expand`` became sorts of one int32 key (31 equations
+fewer in ``raft``'s wave, 11 in its dense arm, where only
+``compact_chunk``'s changed), every successor row, ``sel`` and
+fingerprint bit-equal to that tree's (``tests/test_expand_compaction.py``'s
+``_reference_*``; ``scripts/stage_diff.py`` against the parent). Nothing
+is compiled or run.
 
 A PR that means to change a program re-pins its digest on purpose, says
 so, and checks the benchmark's cells; a PR that does not must leave
@@ -33,36 +39,36 @@ ENGINES = {"device": DeviceBFS, "sharded": ShardedBFS}
 # the seen merge has no model in it: one digest for every family
 SEEN_MERGE = (12, "19ac660b935d83db")
 
-# {family: {engine: {program: (equations, digest)}}} at 8ace3e6
+# {family: {engine: {program: (equations, digest)}}} at PR 50's tree
 PARENT_PROGRAMS = {
     "raft": {
-        "device": {"wave": (4901, "a4b8345acbe15467"),
+        "device": {"wave": (4870, "dcc3dea65d1ff173"),
                    "seen_merge": SEEN_MERGE},
-        "sharded": {"chunk": (5294, "0f27c066634699d4")}},
+        "sharded": {"chunk": (5263, "412fbb6646b02d52")}},
     "raft-dense": {
-        "device": {"wave": (3640, "d346cbc4776edf65"),
+        "device": {"wave": (3629, "c65e516fe661a2fa"),
                    "seen_merge": SEEN_MERGE},
-        "sharded": {"chunk": (4033, "8343aa054ce2fa4d")}},
+        "sharded": {"chunk": (4022, "0c324dc0195f6d06")}},
     "pull_raft": {
-        "device": {"wave": (5261, "47ca4f01c82b1de8"),
+        "device": {"wave": (5232, "b06f24e8491a3621"),
                    "seen_merge": SEEN_MERGE},
-        "sharded": {"chunk": (5654, "3b9cc4970b89dbde")}},
+        "sharded": {"chunk": (5625, "c78689338e8b8c3f")}},
     "kraft": {
-        "device": {"wave": (6600, "d0cb4efe89286904"),
+        "device": {"wave": (6571, "e77d35780b284154"),
                    "seen_merge": SEEN_MERGE},
-        "sharded": {"chunk": (6993, "53d7d4c1edb6e9c0")}},
+        "sharded": {"chunk": (6964, "0af9478b76eb04b8")}},
     "joint_raft": {
-        "device": {"wave": (10941, "8e65fb328d86b50d"),
+        "device": {"wave": (10904, "aa0cc4895f42ae35"),
                    "seen_merge": SEEN_MERGE},
-        "sharded": {"chunk": (11334, "c27bc6142a4343b8")}},
+        "sharded": {"chunk": (11297, "c784cfbb06a290e0")}},
     "kraft_reconfig": {
-        "device": {"wave": (14975, "b1e57916defb3f26"),
+        "device": {"wave": (14940, "e500f4fe2f766729"),
                    "seen_merge": SEEN_MERGE},
-        "sharded": {"chunk": (15368, "38f4b8d518e3a7f8")}},
+        "sharded": {"chunk": (15333, "2cd40b537e56476e")}},
     "reconfig_raft": {
-        "device": {"wave": (10568, "23bfb35c76d89f20"),
+        "device": {"wave": (10529, "73e3b7ab5c88da6b"),
                    "seen_merge": SEEN_MERGE},
-        "sharded": {"chunk": (10961, "fca98ba4f8fb79ab")}},
+        "sharded": {"chunk": (10922, "d6e14931ae318f00")}},
 }
 
 
@@ -89,16 +95,16 @@ def test_device_programs_are_the_parents(family, engine):
 
 
 def test_stages_one_and_two_are_written_once():
-    """The compaction's index buffer is built in one place under
-    ``raft_tpu/`` (``engine.compact_chunk``), and both device programs
-    trace it."""
+    """The valid lanes' compaction (one sort of the lanes' indices) is
+    written in one place under ``raft_tpu/`` (``engine.compact_chunk``),
+    and both device programs trace it."""
     import inspect
     import pathlib
 
     import raft_tpu
     from raft_tpu.checker import engine
 
-    needle = "jnp.full((VC + 1,), C * A, jnp.int32)"
+    needle = "jnp.arange(C * A, dtype=jnp.int32), C * A))[:VC]"
     root = pathlib.Path(raft_tpu.__file__).parent
     assert [p.relative_to(root).as_posix() for p in sorted(root.rglob("*.py"))
             if needle in p.read_text()] == ["checker/engine.py"]
