@@ -56,6 +56,14 @@ count against the pure-Python oracle's golden
          tests/golden/addremove4_cfg_depth_counts.json: a sixth model
          file through the same wave program, at its cell's chunk (a
          16,384-lane worklist, and a wave of nine chunks).
+  leg I  configs/raft-and-fsync/RaftFsync.cfg (Raft with an explicit
+         fsyncIndex: 3 servers, the published constants and fsync
+         policy, 192-lane rows, 78 actions a state in 9 kernel groups;
+         models/raft.py's has_fsync branches) to depth 14 against
+         tests/golden/fsync3_cfg_depth_counts.json, strictly and at the
+         registry's own bag width: the kernels of Timeout,
+         RequestVotePair and AdvanceFsyncIndex, which no other leg
+         fires, and the fsync gates (MaxRestarts is 0: no crash).
 
 This process never imports jax or raft_tpu: a chip belongs to one process
 at a time, so every leg is a child of its own, one after the other, and
@@ -98,6 +106,9 @@ ADDREMOVE_GOLDEN = os.path.join(
     ROOT, "tests", "golden", "addremove4_cfg_depth_counts.json")
 ADDREMOVE_CFG = os.path.join(
     ROOT, "configs", "standard-raft", "RaftWithReconfigAddRemove.cfg")
+FSYNC_GOLDEN = os.path.join(
+    ROOT, "tests", "golden", "fsync3_cfg_depth_counts.json")
+FSYNC_CFG = os.path.join(ROOT, "configs", "raft-and-fsync", "RaftFsync.cfg")
 UNSAFE_CFG = os.path.join(
     ROOT, "configs", "flexible-raft", "unsafe-quorums", "FlexibleRaft.cfg")
 SCHEMA_CHECK = os.path.join(ROOT, "scripts", "check_metrics_schema.py")
@@ -287,7 +298,7 @@ def leg_c(dev: dict, golden: dict) -> None:
 
 def cfg_leg(letter: str, cfg: str, chunk: int, dev: dict, golden: dict,
             flags: tuple = ()) -> None:
-    """Legs D to H: another model file's cfg through the CLI to its
+    """Legs D to I: another model file's cfg through the CLI to its
     golden's depth, at its cell's chunk, with the flags the cfg needs."""
     depth = golden["max_depth"]
     res = bfs_leg(f"leg{letter}", dev, golden,
@@ -301,9 +312,10 @@ def cfg_leg(letter: str, cfg: str, chunk: int, dev: dict, golden: dict,
 def main() -> int:
     try:
         for path in (GOLDEN, JOINT_GOLDEN, KRAFT_GOLDEN, KRAFTRC_GOLDEN,
-                     PULL_GOLDEN, ADDREMOVE_GOLDEN, TRACE_GOLDEN, RAFT_CFG,
-                     JOINT_CFG, KRAFT_CFG, KRAFTRC_CFG, PULL_CFG,
-                     ADDREMOVE_CFG, UNSAFE_CFG, SCHEMA_CHECK,
+                     PULL_GOLDEN, ADDREMOVE_GOLDEN, FSYNC_GOLDEN,
+                     TRACE_GOLDEN, RAFT_CFG, JOINT_CFG, KRAFT_CFG,
+                     KRAFTRC_CFG, PULL_CFG, ADDREMOVE_CFG, FSYNC_CFG,
+                     UNSAFE_CFG, SCHEMA_CHECK,
                      os.path.join(ROOT, "raft_tpu", "__main__.py")):
             check(os.path.exists(path),
                   f"{os.path.relpath(path, ROOT)} is missing: chip_smoke.py "
@@ -323,7 +335,8 @@ def main() -> int:
                 ("G", PULL_CFG, 2048, PULL_GOLDEN, ("--lenient",)),
                 # upstream's cfg omits MaxClusterSize
                 ("H", ADDREMOVE_CFG, 1024, ADDREMOVE_GOLDEN,
-                 ("--lenient",))):
+                 ("--lenient",)),
+                ("I", FSYNC_CFG, 2048, FSYNC_GOLDEN, ())):
             with open(path) as f:
                 cfg_leg(letter, cfg, chunk, dev,
                         json.load(f)["depth_limited"], flags)

@@ -31,8 +31,8 @@ from ..ops.symmetry import Canonicalizer
 from ..resilience import ckpt as rckpt
 from ..resilience.errors import CapacityOverflow
 from .engine import (
-    canon_ident, manifest_fields, resume_events, run_stats, summary_fields,
-    wave_row,
+    canon_ident, manifest_fields, restart_fired, resume_events, run_stats,
+    summary_fields, wave_row,
 )
 
 
@@ -506,8 +506,9 @@ class BFSChecker:
 
         dt = time.perf_counter() - t0
         stats_run = run_stats(
-            comp_run, ph,
-            frontier_peak_rows=max(depth_counts[1:], default=0))
+            self, comp_run, ph,
+            frontier_peak_rows=max(depth_counts[1:], default=0),
+            coverage=cov)
         if violation is not None:
             exit_cause = "violation"
         elif exit_cause is None:
@@ -870,6 +871,7 @@ class BFSChecker:
             peak_journal_cap=journal_rows,
             seen_lanes=int(len(seen)), canon_dup_rate=0.0,
             stats=stats_run, frontier_peak_rows=peak_rows,
+            restart_fired=restart_fired(self, cov_j),
             canon_tier3_local=0, canon_tier3_full=0, fleet_jobs=J,
         ))
         # per-job synthesized runs: one manifest/coverage/summary triple
@@ -901,6 +903,7 @@ class BFSChecker:
                     seen_lanes=int(len(seen)), canon_dup_rate=0.0,
                     stats=stats_run,
                     frontier_peak_rows=max(r.depth_counts[1:], default=0),
+                    restart_fired=restart_fired(self, cov_j[j]),
                     canon_tier3_local=0, canon_tier3_full=0, job=name,
                 ))
         return results

@@ -1164,8 +1164,8 @@ class DeviceBFS(FleetQueue):
 
         dt = time.perf_counter() - t0
         stats_run = run_stats(
-            comp_run, ph, frontier_peak_rows=peak_rows,
-            dedup_plan=self._dedup_plan(),
+            self, comp_run, ph, frontier_peak_rows=peak_rows,
+            coverage=cov_h, dedup_plan=self._dedup_plan(),
             canon_tier3_local=int(canon_prev[1]),
             canon_tier3_full=int(canon_prev[2]),
             dedup_sort_lanes=sort_lanes_run,

@@ -10,6 +10,7 @@ and their own device program. What does not depend on either is here:
   loop_exit          the wave loop's exits that are not a result
   phase_clocks       a device engine's wave clocks from its brackets
   wave_row           the wave event's declared keys, in schema order
+  restart_fired      a run's crashes, from its coverage
   run_stats          what ``stats`` and the summary share of a run
   summary_fields     the summary event
   FleetQueue         ``run_fleet`` / ``_run_supervised`` of the two
@@ -30,6 +31,7 @@ import time
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from ..obs import COMPILES, JobTaggedTelemetry, hashv_of
@@ -238,16 +240,35 @@ def wave_row(
 # ---------------- the end of a run ----------------
 
 
-def run_stats(comp_run, ph, *, frontier_peak_rows: int, **own) -> dict:
+def restart_fired(engine, coverage) -> int:
+    """The ``fired`` column of the run's per-action ``coverage``
+    ([actions, 3] as the wave loop fetched it last, or one such block a
+    shard or a job), summed over the actions the model declares as
+    crashes (``ActionLabelMixin.CRASH_ACTIONS``). 0 on a model that
+    declares none."""
+    names = getattr(engine.model, "ACTION_NAMES", ())
+    crashes = getattr(engine.model, "CRASH_ACTIONS", ())
+    if not names:
+        return 0
+    fired = np.asarray(coverage, np.int64).reshape(-1, len(names), 3)[
+        :, :, 1].sum(axis=0)
+    return sum(int(f) for n, f in zip(names, fired) if n in crashes)
+
+
+def run_stats(
+    engine, comp_run, ph, *, frontier_peak_rows: int, coverage, **own
+) -> dict:
     """What a result's ``stats`` and the summary share: what the run
     loaded into the process (obs/compiles.py), its top-level spans'
-    seconds and ``frontier_peak_rows``, the most rows a wave of the run
+    seconds, ``frontier_peak_rows``, the most rows a wave of the run
     wrote (the max of the wave rows' ``new``: how full the frontier
     got, beside the summary's ``peak_frontier_cap``, how large it was),
-    then the engine's ``own``. Call it beside the run's wall clock:
+    and ``restart_fired`` of ``engine``'s ``coverage``, then the
+    engine's ``own``. Call it beside the run's wall clock:
     ``init_s + waves_s + finish_s`` add up to that."""
     return {**COMPILES.run_stats(comp_run), **ph.top_seconds(),
-            "frontier_peak_rows": int(frontier_peak_rows), **own}
+            "frontier_peak_rows": int(frontier_peak_rows),
+            "restart_fired": restart_fired(engine, coverage), **own}
 
 
 def summary_fields(

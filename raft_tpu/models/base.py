@@ -113,9 +113,14 @@ class ActionLabelMixin:
     (the Next-disjunct rank -> action-name table; index == the rank
     that ``_expand1`` reports). Fused ``HandleMessage`` kernels resolve
     their disjunct at run time, so the label comes from the fired rank;
-    every other kernel is named by its binding."""
+    every other kernel is named by its binding.
+
+    ``CRASH_ACTIONS`` names the actions of ``ACTION_NAMES`` in which a
+    server crashes and restarts (a run's ``restart_fired`` sums their
+    ``fired`` counts): the family's specs call it ``Restart``."""
 
     ACTION_NAMES: list[str]
+    CRASH_ACTIONS: tuple[str, ...] = ("Restart",)
 
     def action_label(self, rank: int, cand: int) -> str:
         name, binding = self.bindings[cand]
@@ -493,23 +498,28 @@ class SparseExpandMixin:
             pos = jnp.cumsum(mask.astype(jnp.int32)) - 1
             count = jnp.sum(mask.astype(jnp.int32))
             apply_ovf = apply_ovf | (count > eb)
-            # the group's segment of the sorted keys: [eb] flat
-            # candidate ids, C*A (the drop value) past its count
-            flat = jnp.where(
-                jnp.arange(eb, dtype=jnp.int32) < count,
-                lax.dynamic_slice(keys, (start,), (eb,)) - gi * stride,
-                C * A,
-            )
-            start = start + count
-            lane = jnp.clip(flat // A, 0, C - 1)
-            k = jnp.clip(flat % A - g.off, 0, g.n - 1)
-            srows = batch[lane]
-            tbl = jnp.asarray(g.params)
-            kern = self.kernel_for(g.name)
-            args = [tbl[:, c][k] for c in range(tbl.shape[1])]
-            blocks.append(
-                jax.vmap(lambda s, *a, _k=kern: _k(s, *a)[1])(srows, *args)
-            )
+            # what building the group's successors costs is read from a
+            # trace by its name (``expand/Restart/fusion.N``): the scope
+            # is opened outside the vmap, which would hide it
+            with jax.named_scope(g.name):
+                # the group's segment of the sorted keys: [eb] flat
+                # candidate ids, C*A (the drop value) past its count
+                flat = jnp.where(
+                    jnp.arange(eb, dtype=jnp.int32) < count,
+                    lax.dynamic_slice(keys, (start,), (eb,)) - gi * stride,
+                    C * A,
+                )
+                start = start + count
+                lane = jnp.clip(flat // A, 0, C - 1)
+                k = jnp.clip(flat % A - g.off, 0, g.n - 1)
+                srows = batch[lane]
+                tbl = jnp.asarray(g.params)
+                kern = self.kernel_for(g.name)
+                args = [tbl[:, c][k] for c in range(tbl.shape[1])]
+                blocks.append(
+                    jax.vmap(lambda s, *a, _k=kern: _k(s, *a)[1])(
+                        srows, *args)
+                )
             row = jnp.where(
                 mask & (pos < eb), base + jnp.minimum(pos, eb - 1), row
             )

@@ -299,6 +299,7 @@ class KRaftReconfigModel(SparseExpandMixin, ActionLabelMixin):
 
     name = "KRaftWithReconfig"
     ACTION_NAMES = ACTION_NAMES
+    CRASH_ACTIONS = ("RestartWithState",)
 
     def __init__(self, params: KRaftReconfigParams, server_names=None,
                  value_names=None):

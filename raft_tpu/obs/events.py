@@ -179,7 +179,8 @@ SUMMARY_KEYS = (
     "event", "engine", "ident", "exit_cause", "violation", "distinct",
     "total", "depth", "terminal", "seconds", "distinct_per_s",
     "exhausted", "waves", "stalls", "peak_frontier_cap",
-    "frontier_peak_rows", "peak_journal_cap", "seen_lanes",
+    "frontier_peak_rows", "restart_fired", "peak_journal_cap",
+    "seen_lanes",
     "canon_dup_rate",
     "canon_tier3_local", "canon_tier3_full",
     *PROCESS_KEYS,
@@ -507,12 +508,15 @@ def validate_event(ev: object, lineno: int | None = None) -> list[str]:
                     f"{where}summary {key} {v!r} must be a non-negative "
                     f"number"
                 )
-        rows = ev.get("frontier_peak_rows")
-        if rows is not None and not _is_count(rows):
-            problems.append(
-                f"{where}summary frontier_peak_rows {rows!r} must be a "
-                f"non-negative int (the most rows a wave wrote)"
-            )
+        for key, what in (
+                ("frontier_peak_rows", "the most rows a wave wrote"),
+                ("restart_fired", "successors the crash actions generated")):
+            v = ev.get(key)
+            if v is not None and not _is_count(v):
+                problems.append(
+                    f"{where}summary {key} {v!r} must be a non-negative "
+                    f"int ({what})"
+                )
         problems += [
             f"{where}summary programs[{i}]: {p}"
             for i, p in _program_problems(ev.get("programs", []))

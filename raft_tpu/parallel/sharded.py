@@ -1597,8 +1597,8 @@ class ShardedBFS(FleetQueue):
 
         dt = time.perf_counter() - t0
         stats_run = run_stats(
-            comp_run, ph, frontier_peak_rows=peak_rows,
-            dedup_plan=self._dedup_plan(),
+            self, comp_run, ph, frontier_peak_rows=peak_rows,
+            coverage=cov_hd, dedup_plan=self._dedup_plan(),
             canon_tier3_local=int(tiers_prev[0]),
             canon_tier3_full=int(tiers_prev[1]),
         )

@@ -42,6 +42,9 @@ and wave_prefix (and rungs, where it says them) as non-negative ints,
 wave_prefix strictly increasing from 0 where it is not empty, rungs
 strictly increasing, and sort_lanes the merged runs, the largest prefix
 and the queries together at least, and the last rung.
+A `summary` event's frontier_peak_rows (the most rows a wave wrote) and
+restart_fired (the successors the model's crash actions generated, from
+the run's coverage block) must be non-negative ints where it has them.
 A `summary` event's set-up keys (programs_loaded, programs_traced,
 setup_*_s, load_*_s: obs/compiles.py) must be non-negative numbers or
 null, and its `programs`, where it has them, a list of records with a

@@ -15,7 +15,11 @@ cpu`` rehearses.
         [--depth 14 20] [--top 16] [--gather] [--platform cpu]
         [--out chiprun_out/stage_split.json]
 
-``--top N`` keeps the N heaviest ops; ``--gather`` puts the per-lane
+``expand_by_group_s`` is ``expand``'s seconds by the action group whose
+successors an op built (``sparse_apply``'s scope round each group,
+``expand/Restart``; ``-`` is what ``expand`` runs outside any group: the
+guard pass, the two sorts, the worklist's assembly). ``--top N`` keeps
+the N heaviest ops; ``--gather`` puts the per-lane
 reads back behind ``models/base.py``'s one-hot read helpers
 (``scripts/stage_diff.py``'s switch), so old reads and new are timed from
 one tree.
@@ -40,7 +44,8 @@ sys.path.insert(0, ROOT)
 
 def split(path, top=16):
     """Seconds of device self time by scope path two levels deep (the
-    benchmark's own rule, ``xplane.scope_path``), and the ``top``
+    benchmark's own rule, ``xplane.scope_path``), ``expand``'s by action
+    group (its second level; ``-`` for its own), and the ``top``
     heaviest (op, name stack) pairs, of one .xplane.pb."""
     from benchmark import xplane, xspace
 
@@ -64,6 +69,10 @@ def split(path, top=16):
     per = 1e9 * max(1, n_planes)
     return {
         "by_scope_s": {k: ns / per for k, ns in by_scope.most_common()},
+        "expand_by_group_s": {
+            (k.partition("/")[2] or "-"): ns / per
+            for k, ns in by_scope.most_common()
+            if k.partition("/")[0] == "expand"},
         "top_ops_s": [[*k, ns / per] for k, ns in by_op.most_common(top)],
     }
 
@@ -145,6 +154,7 @@ def main(argv=None):
         res["dedup_sort_lanes"] = got["stats"].get("dedup_sort_lanes")
         res["dedup_search_queries"] = got["stats"].get("dedup_search_queries")
         res["frontier_peak_rows"] = got["stats"].get("frontier_peak_rows")
+        res["restart_fired"] = got["stats"].get("restart_fired")
         res["canon_lanes"] = {
             k: sum(w[k] for w in got["waves"])
             for k in ("generated", "canon_dup_lanes", "canon_tier3_local",

@@ -241,6 +241,14 @@ def _traced(make):
         _eqns(jax.make_jaxpr(prog["fn"])(*prog["args"]).jaxpr))
 
 
+def _spec_body(stack: str) -> bool:
+    """An equation of the spec lowering's own per-state body: the guard
+    pass, ``expand/vmap()``, or a group's kernels under the group's
+    scope, ``expand/Restart/vmap()`` (PR 51)."""
+    head, _, rest = stack.partition("/")
+    return head == "expand" and "vmap()" in rest.split("/")[:2]
+
+
 @pytest.mark.parametrize(
     "make", [_device_wave, _sharded_chunk], ids=["device", "sharded"])
 def test_wave_program_scatter_adds_nothing(make):
@@ -251,7 +259,7 @@ def test_wave_program_scatter_adds_nothing(make):
     chunk-step for the counters it used to make), so a feature that
     brings a ``segment_sum`` or an ``.at[].add`` into the wave fails
     here. The one place allowed is the spec lowering's own per-state
-    body, ``expand/vmap()`` (``log_len.at[i].add(1)``): the model's, not
+    body (``_spec_body``; ``log_len.at[i].add(1)``): the model's, not
     the engine's."""
     eng, name, eqns = _traced(make)
     assert eng.n_actions > 0
@@ -262,7 +270,7 @@ def test_wave_program_scatter_adds_nothing(make):
     adds = [
         str(e.source_info.name_stack) for e in eqns
         if e.primitive.name == "scatter-add"
-        and "expand/vmap()" not in str(e.source_info.name_stack)
+        and not _spec_body(str(e.source_info.name_stack))
     ]
     assert not adds, f"scatter-add in the {name} program under: {adds}"
 
@@ -277,18 +285,30 @@ def test_wave_program_expand_compacts_by_sorts(make):
     serial pass on the TPU (4.6 ns a lane: PERF.md section 6, PR 50;
     seven of them a chunk-step were 11.6 % of ``raft3-wide``'s device
     time), so the program a wave dispatches holds no ``scatter`` under
-    the ``expand`` scope outside the spec lowering's own per-state body,
-    ``expand/vmap()``, and exactly two ``sort`` equations there, of one
+    the ``expand`` scope outside the spec lowering's own per-state body
+    (``_spec_body``), and exactly two ``sort`` equations there, of one
     operand each."""
     eng, name, eqns = _traced(make)
     assert eng._sparse
     own = [
         e for e in eqns
         if str(e.source_info.name_stack).startswith("expand")
-        and "expand/vmap()" not in str(e.source_info.name_stack)
+        and not _spec_body(str(e.source_info.name_stack))
     ]
     # the scope is there, so an empty list below means none
     assert len(own) > 50, len(own)
+    # each group's slice, row gather and parameter selects are under the
+    # group's own scope (PR 51), opened outside its kernels' vmap
+    groups = {g.name for g in eng.model.sparse_groups()}
+
+    def second(es):
+        return {(str(e.source_info.name_stack).split("/") + [""])[1]
+                for e in es}
+
+    assert groups == second(own) & groups
+    assert groups == second(
+        e for e in eqns if _spec_body(str(e.source_info.name_stack))
+    ) - {"vmap()"}
     scatters = [
         (e.primitive.name, str(e.source_info.name_stack)) for e in own
         if e.primitive.name.startswith("scatter")]

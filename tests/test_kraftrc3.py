@@ -519,6 +519,12 @@ def test_cli_refuses_the_cfg_and_under_lenient_counts_the_goldens_prefix(
         golden["totals"][str(DEPTH)])
     assert summary["exit_cause"] == "max_depth"
     assert all(w["overflow_bits"] == 0 for w in waves)
+    # the one cell whose cfg lets a server crash (MaxRestarts = 1), under
+    # a name of its own: the model declares it (CRASH_ACTIONS, PR 51)
+    (manifest,) = [e for e in events if e["event"] == "manifest"]
+    coverage = [e for e in events if e["event"] == "coverage"][-1]
+    crash = manifest["action_names"].index("RestartWithState")
+    assert summary["restart_fired"] == coverage["actions"][crash][1] > 0
 
 
 def test_permutations_run_once_a_distinct_raw_view_and_the_rows_say_so(

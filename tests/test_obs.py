@@ -352,7 +352,7 @@ def test_rows_and_summary_have_one_author(engine):
     else:
         assert len(summaries) == 1
         base = {*PROCESS_KEYS, *_RUN_LOADED, "init_s", "waves_s", "finish_s",
-                "frontier_peak_rows"}
+                "frontier_peak_rows", "restart_fired"}
         assert set(stats) == base | STATS_OWN[engine]
         # stats is the summary's own dict, but for the sharded engine's
         # fleet aggregates
@@ -1041,6 +1041,9 @@ def test_stage_split_reduces_a_recorded_trace(tmp_path, capsys):
     table = capsys.readouterr().out
     res = json.loads(table.strip().splitlines()[-1])
     buckets = res["by_scope_s"]
+    # recorded before sparse_apply named its groups (PR 51): all of
+    # expand is its own
+    assert res["expand_by_group_s"] == {"-": buckets["expand"]}
     device_stages = set(TIMELINE_STAGES) - {"checkpoint", "host"}
     assert {b.split("/")[0] for b in buckets} <= device_stages | {"unscoped"}
     assert {"expand", "canon", "dedup", "emit", "seen_merge"} <= set(buckets)
