@@ -78,14 +78,14 @@ from ..resilience.errors import CapacityOverflow
 from .bfs import CheckResult, Violation
 from .engine import (
     FleetQueue, canon_ident, compact_chunk, expand_chunk, loop_exit,
-    manifest_fields, phase_clocks, resume_events, run_stats,
-    summary_fields, wave_row,
+    manifest_fields, phase_clocks, rank_key_bits, resume_events,
+    run_stats, summary_fields, wave_row,
 )
 from .lsm import pow2_at_least
 from .util import (
-    GROWTH, HEADROOM, I32_MAX, SORT_FLOOR_LANES, dedup_plan,
-    dense_prefix_sel, emit_append, first_new, jit_with_donation,
-    merge_rungs, next_cap, rank_counts, rank_onehot, wave_prefix_sizes,
+    GROWTH, HEADROOM, I32_MAX, SORT_FLOOR_LANES, dedup_plan, emit_append,
+    first_new, jit_with_donation, merge_rungs, next_cap, rank_counts,
+    rank_onehot, wave_prefix_sizes,
 )
 
 
@@ -184,6 +184,7 @@ class DeviceBFS(FleetQueue):
         self.MAX_SCAP = max(max_seen_cap, seen_cap)
         self.MAX_JCAP = max(max_journal_cap, journal_cap)
         self.VC = min(chunk * self.A, chunk * valid_per_state)
+        rank_key_bits(chunk, self.A, self.n_actions)  # the key fits, or raise
         # guard-first sparse expansion (SparseExpandMixin models): cheap
         # guards over the dense [chunk, A] grid, then a vmapped apply
         # over a static per-group budget plan instead of materializing
@@ -352,9 +353,10 @@ class DeviceBFS(FleetQueue):
         lane the later stages consume."""
         batch, succs, valid, rank, n_gen, terminal, expand_ovf = expand_chunk(
             self.model, self._sparse, frontier, cursor, fcount, self.chunk)
-        flatc, sel, selv, compact_ovf = compact_chunk(
-            self.model, self._plan, batch, succs, valid, n_gen, self.VC)
-        return (flatc, sel, selv, valid, rank, n_gen, terminal,
+        flatc, sel, selv, sel_rank, compact_ovf = compact_chunk(
+            self.model, self._plan, batch, succs, valid, rank,
+            self.n_actions, n_gen, self.VC)
+        return (flatc, sel, selv, sel_rank, valid, rank, n_gen, terminal,
                 expand_ovf, compact_ovf)
 
     @stage("canon")
@@ -390,14 +392,14 @@ class DeviceBFS(FleetQueue):
     @stage("emit")
     def _st_finish(
         self, next_buf, jparent, jcand, viol, stats, cov, wave_new,
-        flatc, fps, sel, valid, rank, new, n_gen, terminal, expand_ovf,
-        compact_ovf, canon_n, dedup_n, cursor, base_gid,
+        flatc, fps, sel, sel_rank, valid, rank, new, n_gen, terminal,
+        expand_ovf, compact_ovf, canon_n, dedup_n, cursor, base_gid,
     ):
         """Stages 4b-6: per-action coverage, the cursor-append emit of
         rows, journal and new fingerprints, invariants on the new states
         and the stats fold. Returns the updated carries."""
         model = self.model
-        C, A, W, VC = self.chunk, self.A, self.W, self.VC
+        A, W, VC = self.A, self.W, self.VC
         FCAP, JCAP = self.FCAP, self.JCAP
         n_new = jnp.sum(new)
 
@@ -406,10 +408,11 @@ class DeviceBFS(FleetQueue):
         # _expand1 already returns; rank is -1 or under a false mask
         # wherever a lane does not count. enabled counts states where
         # the disjunct's guard held; fired counts valid candidate lanes;
-        # new-distinct counts first-writer lanes (rank gathered through
-        # the compaction `sel`; a new lane is a valid one). A chunk-step
-        # counts at most C * A lanes in int32 and widens once into the
-        # cumulative i64 `cov`.
+        # new-distinct counts first-writer lanes by `sel_rank`, the rank
+        # of each compacted lane, which rode in the compaction's sort
+        # key (a new lane is a valid one). A chunk-step counts at most
+        # C * A lanes in int32 and widens once into the cumulative i64
+        # `cov`.
         K = self.n_actions
         if K:
             with jax.named_scope("coverage"):
@@ -418,24 +421,30 @@ class DeviceBFS(FleetQueue):
                     jnp.any(en, axis=1), axis=0, dtype=jnp.int32)
                 # the one-hot again: XLA keeps one compare for both
                 fired_k = rank_counts(rank, valid, K)
-                flat_rk = jnp.concatenate(
-                    [rank.reshape(-1), jnp.full((1,), -1, rank.dtype)]
-                )[sel]  # [VC] rank per compacted lane (drop row -> -1)
-                new_k = rank_counts(flat_rk, new, K)
+                new_k = rank_counts(sel_rank, new, K)
                 cov = cov + jnp.stack(
                     [enabled_k, fired_k, new_k], axis=1
                 ).astype(jnp.int64)
 
         # 5. emit: compact survivors to a dense prefix of a [VC, W]
-        # block (scatter confined to a chunk-sized index buffer), then
-        # ONE dynamic_update_slice per buffer appends the block at the
-        # running cursor. The destinations ncount + (cumsum(new) - 1)
-        # are provably contiguous, but XLA cannot prove it, so the old
-        # `.at[bdst].set()` emit lowered to general scatters over the
-        # full (FCAP, W)/(JCAP,) buffers — most of the raft3 per-chunk
-        # stage sum in round 5's stage profile. Rows [FCAP, FCAP+VC) /
-        # [JCAP, JCAP+VC) are the drop region replacing the scatter's
-        # drop row; overflow semantics are bit-identical (emit_append).
+        # block, then ONE dynamic_update_slice per buffer appends the
+        # block at the running cursor. What the block needs of a lane
+        # besides its row comes out of ONE sort of one int32 key: `esel`,
+        # the survivors' lanes in lane order (util.dense_prefix_sel's
+        # key), with `sel` as its payload, so `ssel[j]` is the j-th
+        # survivor's `sel`, from which the journal's parent and
+        # candidate follow by arithmetic. The stage gathers nothing but
+        # the rows and scatters nothing: a 1-D gather or scatter by a
+        # traced index is a serial pass on this chip, 7.2 and 4.6 ns a
+        # lane, where this sort is 0.8 to 1.0 (and two sorts of one
+        # operand 1.3 to 1.5: scripts/emit_micro.py --journal; PERF.md
+        # section 6, PR 52). The append is a slice update because the
+        # destinations ncount + (cumsum(new) - 1) are contiguous and
+        # XLA cannot prove it: an `.at[bdst].set()` lowers to a general
+        # scatter over the full (FCAP, W)/(JCAP,) buffers. Rows [FCAP,
+        # FCAP+VC) / [JCAP, JCAP+VC) are the drop region that replaces
+        # such a scatter's drop row; overflow semantics are
+        # bit-identical (emit_append).
         # A scope of its own, `emit/append`, beside `emit/coverage` and
         # `emit/invariants`: what a trace charges to writing into the
         # frontier, the journal and the wave's fingerprint buffer.
@@ -443,15 +452,15 @@ class DeviceBFS(FleetQueue):
             ncount = stats[0].astype(jnp.int32)
             jcount = stats[1].astype(jnp.int32)
             npos = (jnp.cumsum(new) - 1).astype(jnp.int32)
-            esel = dense_prefix_sel(new, npos, VC)
+            lane = jnp.arange(VC, dtype=jnp.int32)
+            esel, ssel = lax.sort(
+                (jnp.where(new, lane, VC), sel), num_keys=1)
             blk = jnp.concatenate(
                 [flatc, jnp.zeros((1, W), jnp.int32)], axis=0
             )[esel]
-            jp_blk = jnp.concatenate(
-                [base_gid + cursor + sel // A, jnp.zeros((1,), jnp.int32)]
-            )[esel]
-            jc_blk = jnp.concatenate(
-                [sel % A, jnp.zeros((1,), jnp.int32)])[esel]
+            live = lane < n_new
+            jp_blk = jnp.where(live, base_gid + cursor + ssel // A, 0)
+            jc_blk = jnp.where(live, ssel % A, 0)
             next_buf, frontier_ovf = emit_append(
                 next_buf, blk, ncount, n_new, FCAP)
             jparent, journal_ovf = emit_append(
@@ -517,8 +526,8 @@ class DeviceBFS(FleetQueue):
         binary search of an unoccupied run is skipped via lax.cond; a
         merged run is sorted either way). Returns the carries, wave_new with the
         chunk's new fingerprints appended."""
-        (flatc, sel, selv, valid, rank, n_gen, terminal, expand_ovf,
-         compact_ovf) = self._st_expand(frontier, cursor, fcount)
+        (flatc, sel, selv, sel_rank, valid, rank, n_gen, terminal,
+         expand_ovf, compact_ovf) = self._st_expand(frontier, cursor, fcount)
         fps, canon_n = self._st_canon(flatc, selv)
         new, dedup_n = self._st_dedup(
             fps, occ, wave_new, stats[0].astype(jnp.int32), seen_real,
@@ -535,8 +544,8 @@ class DeviceBFS(FleetQueue):
             flatc, new = lax.optimization_barrier((flatc, new))
         return self._st_finish(
             next_buf, jparent, jcand, viol, stats, cov, wave_new, flatc,
-            fps, sel, valid, rank, new, n_gen, terminal, expand_ovf,
-            compact_ovf, canon_n, dedup_n, cursor, base_gid,
+            fps, sel, sel_rank, valid, rank, new, n_gen, terminal,
+            expand_ovf, compact_ovf, canon_n, dedup_n, cursor, base_gid,
         )
 
     def _wave_prefix(self) -> tuple[int, ...]:

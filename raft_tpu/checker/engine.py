@@ -450,24 +450,52 @@ def expand_chunk(model, sparse: bool, frontier, cursor, fcount, C: int):
     return batch, succs, valid, rank, n_gen, terminal, expand_ovf
 
 
-def compact_chunk(model, plan, batch, succs, valid, n_gen, VC: int):
+def rank_key_bits(chunk: int, A: int, n_actions: int) -> int:
+    """log2 of ``R``, the stride of ``compact_chunk``'s sort key: a
+    valid lane's key is ``flat * R + (rank + 1)`` and an invalid one's
+    ``chunk * A * R``, ``R`` the power of two over ``n_actions + 2``
+    (the rank of a lane is -1 .. n_actions - 1), so ``(chunk * A + 1)
+    * R`` has to fit an int32. 0, the lanes' indices alone, where the
+    engine counts no coverage."""
+    bits = (n_actions + 1).bit_length() if n_actions else 0
+    if (chunk * A + 1) << bits >= 1 << 31:
+        raise ValueError(
+            f"chunk={chunk} x A={A} candidate lanes with {n_actions} "
+            f"action ranks pass the int32 key of the compaction's sort: "
+            f"({chunk} * {A} + 1) * {1 << bits} >= 2^31; lower the chunk")
+    return bits
+
+
+def compact_chunk(
+    model, plan, batch, succs, valid, rank, n_actions: int, n_gen, VC: int,
+):
     """Stage 2: compact the valid lanes (``sel[j]`` = flat lane of the
-    j-th valid successor, ``C * A`` past the last: the first VC of the
-    lanes' indices sorted with every invalid lane keyed ``C * A``) into
-    the [VC, W] successor block. With ``succs`` None (the sparse
+    j-th valid successor, ``C * A`` past the last) into the [VC, W]
+    successor block, by the first VC of one sort of one int32 key a
+    lane. The key carries the lane's action rank under its flat index
+    (``rank_key_bits``), so ``sel_rank[j]``, the rank of the j-th valid
+    successor and -1 past the last, falls out of the same sort and is
+    never gathered through ``sel``; with ``n_actions`` 0 ``rank`` is
+    not read and every lane's is -1. With ``succs`` None (the sparse
     contract) this is the apply pass: successors are constructed ONLY
     for the compacted worklist lanes, vmapped per group over the static
     budget ``plan``, and a budget overflow folds into the compaction
     bit: both mean "a static worklist bound was exceeded, raise the
-    knob". Returns (flatc, sel, selv, compact_ovf)."""
+    knob". Returns (flatc, sel, selv, sel_rank, compact_ovf)."""
     C, A = valid.shape
     W = batch.shape[1]
     compact_ovf = n_gen > VC
-    # a stream compaction of int32 indices is one sort of one key on
-    # this chip, never an ``.at[dst].set`` (a serial pass, 4.6 ns a lane)
-    sel = lax.sort(jnp.where(
-        valid.reshape(-1), jnp.arange(C * A, dtype=jnp.int32), C * A))[:VC]
+    bits = rank_key_bits(C, A, n_actions)
+    flat = jnp.arange(C * A, dtype=jnp.int32)
+    if bits:
+        flat = (flat << bits) + (rank.reshape(-1) + 1)
+    # a stream compaction of int32 lanes is one sort of one key on this
+    # chip, never an ``.at[dst].set`` (a serial pass, 4.6 ns a lane),
+    # and a 1-D gather by its result is another (7.1 ns a lane)
+    key = lax.sort(jnp.where(valid.reshape(-1), flat, (C * A) << bits))[:VC]
+    sel = key >> bits
     selv = sel < C * A
+    sel_rank = (key & ((1 << bits) - 1)) - 1  # the drop key's is -1
     if succs is None:
         flatc, apply_ovf = model.sparse_apply(batch, sel, selv, plan)
         compact_ovf = compact_ovf | apply_ovf
@@ -477,4 +505,4 @@ def compact_chunk(model, plan, batch, succs, valid, n_gen, VC: int):
             axis=0,
         )
         flatc = flatp[sel]  # [VC, W]
-    return flatc, sel, selv, compact_ovf
+    return flatc, sel, selv, sel_rank, compact_ovf

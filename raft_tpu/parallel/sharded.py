@@ -66,8 +66,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..checker.engine import (
     FleetQueue, canon_ident, compact_chunk, expand_chunk, loop_exit,
-    manifest_fields, phase_clocks, resume_events, run_stats,
-    summary_fields, wave_row,
+    manifest_fields, phase_clocks, rank_key_bits, resume_events,
+    run_stats, summary_fields, wave_row,
 )
 from ..checker.lsm import RunLSM, pow2_at_least
 from ..obs import (
@@ -184,6 +184,7 @@ class ShardedBFS(FleetQueue):
         self.A = model.A
         self.W = model.layout.W
         self.VC = min(chunk * self.A, chunk * valid_per_state)
+        rank_key_bits(chunk, self.A, self.n_actions)  # the key fits, or raise
         # guard-first sparse expansion (SparseExpandMixin models): see
         # checker/device_bfs.py — same two-phase contract per shard
         self._sparse = hasattr(model, "sparse_apply")
@@ -416,8 +417,8 @@ class ShardedBFS(FleetQueue):
                 fired_k = rank_counts(rank, valid, K)
 
             # 2. compact the valid lanes into the [VC, W] successor block
-            flatc, sel, selv, compact_ovf = compact_chunk(
-                model, self._plan, batch, succs, valid, n_gen, VC)
+            flatc, sel, selv, sel_rank, compact_ovf = compact_chunk(
+                model, self._plan, batch, succs, valid, rank, K, n_gen, VC)
             parent_lgid = base_lgid + cursor + sel // A
             cand = sel % A
 
@@ -430,13 +431,11 @@ class ShardedBFS(FleetQueue):
         with stage("exchange"), jax.named_scope("route"):
             # 4. route to owner chip = fp mod D: sort by owner, positional
             # slots. The action rank rides the payload so the OWNER chip can
-            # attribute new-distinct states per action after dedup.
-            lane_rank = jnp.concatenate(
-                [rank.reshape(-1), jnp.full((1,), -1, rank.dtype)]
-            )[sel]  # [VC] rank per compacted lane (drop row -> -1)
+            # attribute new-distinct states per action after dedup: it is
+            # ``sel_rank``, out of the compaction's sort key.
             payload = jnp.concatenate(
                 [flatc, parent_lgid[:, None], cand[:, None],
-                 lane_rank[:, None].astype(jnp.int32)], axis=1
+                 sel_rank[:, None]], axis=1
             )  # [VC, W+3] i32
             # fp mod D in u32 pieces (u64 div/mod lanes are slow on this TPU):
             # (hi*2^32 + lo) % D == ((hi%D) * (2^32%D) + lo%D) % D
@@ -510,7 +509,7 @@ class ShardedBFS(FleetQueue):
                 npos = (jnp.cumsum(new) - 1).astype(jnp.int32)
                 states_s = recv_pay[sidx, :W]
                 B = D * RC
-                esel = dense_prefix_sel(new, npos, B)
+                esel = dense_prefix_sel(new, B)
                 blk = jnp.concatenate(
                     [states_s, jnp.zeros((1, W), jnp.int32)], axis=0
                 )[esel]

@@ -14,10 +14,23 @@ rebuilt OUTSIDE the timed window each rep. The scatter's cost scales
 with FCAP (the whole buffer is touched by the lowering), the appends'
 with VC — sweeping FCAP at fixed VC is the point of the grid.
 
+``--journal N [N ...]`` times, on the chip, what the emit stage needs
+of a chunk's N compacted lanes besides the rows: the survivors' lanes
+(``esel``) and the journal's two blocks. Three forms of it, each run K
+times inside one program at K = 8 and K = 72 (the difference is 64
+repetitions with no call in them): ``gather``, the scatter into an index
+buffer and the two gathers through it that the stage ran until PR 52
+(with ``rank_gather``, the third gather of that kind, the action rank
+through the compaction's ``sel``, timed alone); ``two_sorts``, a sort of
+one int32 key for the lanes and another for their ``sel``; and
+``payload_sort``, one sort of the lanes' key carrying ``sel``. The
+cheaper sort form is the one in ``DeviceBFS._st_finish``.
+
 Usage:
   python scripts/emit_micro.py [--vc 32768 65536] [--fcap 262144 4194304]
                                [--w 64] [--reps 5] [--density 0.5]
                                [--platform cpu]
+  python scripts/emit_micro.py --journal 16384 32768 65536
 
 Writes chiprun_out/emit_micro.json (device provenance + one row per
 (VC, FCAP) cell), where a chip run's results come back.
@@ -75,7 +88,7 @@ def bench_cell(vc, fcap, w, reps, density, rng):
     # -- round-6 production emit: compact to a dense [VC, W] block, one
     #    dynamic_update_slice at the cursor
     def compact_dus(nb):
-        esel = dense_prefix_sel(new, npos, vc)
+        esel = dense_prefix_sel(new, vc)
         blk = jnp.concatenate(
             [flatc, jnp.zeros((1, w), jnp.int32)], axis=0)[esel]
         nb, _ = emit_append(nb, blk, count, jnp.int32(n_new), fcap)
@@ -107,6 +120,90 @@ def bench_cell(vc, fcap, w, reps, density, rng):
     return row
 
 
+def _scatter_sel(new, n):
+    """``util.dense_prefix_sel`` as it was until PR 52: a cumsum and a
+    scatter into an n + 1 index buffer."""
+    import jax.numpy as jnp
+
+    edst = jnp.where(new, jnp.cumsum(new) - 1, n)
+    return (jnp.full((n + 1,), n, jnp.int32).at[edst]
+            .set(jnp.arange(n, dtype=jnp.int32))[:n])
+
+
+def bench_journal(lanes, reps):
+    """ns a lane of the survivors' lanes and journal blocks of one
+    chunk-step of ``lanes`` compacted lanes, by form (module docstring).
+    The grid behind ``sel`` is 4 * lanes wide with A = 53, seven eighths
+    of the lanes are valid and a third of those new, re-drawn from the
+    loop's counter; every output is summed into the carry so nothing is
+    dead."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    N, A = lanes, 53
+    CA = 4 * N
+    lane = jnp.arange(N, dtype=jnp.int32)
+    n_valid = N * 7 // 8
+    rank_grid = (jnp.arange(CA, dtype=jnp.int32) * 5) % 13
+
+    def draw(i):
+        sel = jnp.where(lane < n_valid, 4 * lane + i % 4, CA)
+        new = (((lane * 7 + i * 13) % 3) == 0) & (lane < n_valid)
+        return sel, new
+
+    def blocks(ssel, n_new):  # the journal's blocks of the sorted sel
+        live = lane < n_new
+        return (jnp.where(live, 1000 + ssel // A, 0),
+                jnp.where(live, ssel % A, 0))
+
+    def gather(i):
+        sel, new = draw(i)
+        esel = _scatter_sel(new, N)
+        z = jnp.zeros((1,), jnp.int32)
+        return (esel, jnp.concatenate([1000 + sel // A, z])[esel],
+                jnp.concatenate([sel % A, z])[esel])
+
+    def two_sorts(i):
+        sel, new = draw(i)
+        esel = lax.sort(jnp.where(new, lane, N))
+        ssel = lax.sort(jnp.where(new, sel, CA))
+        return (esel, *blocks(ssel, jnp.sum(new)))
+
+    def payload_sort(i):
+        sel, new = draw(i)
+        esel, ssel = lax.sort((jnp.where(new, lane, N), sel), num_keys=1)
+        return (esel, *blocks(ssel, jnp.sum(new)))
+
+    def rank_gather(i):
+        sel, _ = draw(i)
+        return (jnp.concatenate(
+            [rank_grid, jnp.full((1,), -1, jnp.int32)])[sel],)
+
+    forms = {"gather": gather, "two_sorts": two_sorts,
+             "payload_sort": payload_sort, "rank_gather": rank_gather}
+    row = {"lanes": N}
+    for name, form in forms.items():
+        def body(i, acc, form=form):
+            return acc + sum(jnp.sum(x) for x in form(i))
+
+        fn = jax.jit(lambda k, a, body=body: lax.fori_loop(0, k, body, a))
+        jax.block_until_ready(fn(jnp.int32(1), jnp.int32(0)))  # compile
+        ts = {K: _time_donated(
+            fn, lambda K=K: (jnp.int32(K), jnp.int32(0)), reps)
+            for K in (8, 72)}
+        row[f"{name}_ns_per_lane"] = round(
+            (ts[72] - ts[8]) / 64 / N * 1e9, 3)
+        row[f"{name}_ms"] = round((ts[72] - ts[8]) / 64 * 1e3, 4)
+    # equal answers, outside the timed loop
+    want = [jax.device_get(x) for x in jax.jit(gather)(jnp.int32(3))]
+    row["parity"] = all(
+        all((jax.device_get(g) == w).all() for g, w in zip(
+            jax.jit(form)(jnp.int32(3)), want))
+        for form in (two_sorts, payload_sort))
+    return row
+
+
 def main():
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--vc", type=int, nargs="+", default=[32768, 65536])
@@ -116,12 +213,28 @@ def main():
     ap.add_argument("--reps", type=int, default=5)
     ap.add_argument("--density", type=float, default=0.5)
     ap.add_argument("--platform", default=None)
+    ap.add_argument("--journal", type=int, nargs="+", default=None,
+                    metavar="LANES",
+                    help="time the survivors' lanes and journal blocks "
+                         "of LANES compacted lanes by form, and nothing "
+                         "else")
     args = ap.parse_args()
 
     import jax
 
     if args.platform:
         jax.config.update("jax_platforms", args.platform)
+    if args.journal:
+        rows = [bench_journal(n, args.reps) for n in args.journal]
+        for row in rows:
+            print(json.dumps(row), flush=True)
+        path = os.path.join(ROOT, "chiprun_out", "emit_journal.json")
+        os.makedirs(os.path.dirname(path), exist_ok=True)
+        with open(path, "w") as f:
+            json.dump({"device": str(jax.devices()[0]), "rows": rows}, f,
+                      indent=1)
+        print(f"wrote {path}")
+        return
     import numpy as np
 
     rng = np.random.default_rng(0)

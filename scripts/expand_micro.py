@@ -103,8 +103,9 @@ def bench_cell(model, batch_h, vpg, reps):
     # -- dense path: full kernels over every lane + compaction gather
     def dense(b):
         succs, valid, rank, ovf = jax.vmap(model._expand1)(b)
-        flatc, _sel, selv, _ovf = compact_chunk(
-            None, None, b, succs, valid, jnp.sum(valid), VC)
+        flatc, _sel, selv, _rank, _ovf = compact_chunk(
+            None, None, b, succs, valid, rank,
+            len(model.ACTION_NAMES), jnp.sum(valid), VC)
         return flatc, selv
 
     # -- guard-first path, split so each phase gets its own row

@@ -11,8 +11,9 @@ action and state field that differ:
                  ``--depth`` (at most ``--cap``): successors, valid, rank,
                  overflow
   guards         ``vmap(model.guards1)`` of the same rows
-  sparse expand  ``DeviceBFS._st_expand``: guard pass, compaction and
-                 the budgeted sparse apply, as the wave program runs them
+  sparse expand  ``DeviceBFS._st_expand``: guard pass, compaction (with
+                 the compacted lanes' action ranks) and the budgeted
+                 sparse apply, as the wave program runs them
   canon          raw and canonical fingerprints and the engines' canon
                  (in-chunk dedup, then the tiers), on the CPU's rows of
                  the sparse expand (so a fault upstream does not compound)
@@ -164,9 +165,10 @@ def stages(args, ref):
         buf[: len(block)] = block
         return buf
 
-    flatc, sel, selv, _v, _r, n_gen, _t, eo, co = jax.device_get(
+    flatc, sel, selv, sel_rank, _v, _r, n_gen, _t, eo, co = jax.device_get(
         jax.jit(eng._st_expand)(padded(rows), np.int32(0), np.int32(n)))
     out.update(sparse_rows=flatc, sparse_sel=sel, sparse_selv=selv,
+               sparse_sel_rank=sel_rank,
                sparse_ngen=np.asarray(n_gen), sparse_ovf=np.asarray([eo, co]))
     if ref is not None:
         flatc, selv = ref["sparse_rows"], ref["sparse_selv"]

@@ -319,6 +319,52 @@ def test_wave_program_expand_compacts_by_sorts(make):
 
 
 @pytest.mark.parametrize(
+    "make", [_device_wave, _sharded_chunk], ids=["device", "sharded"])
+def test_wave_program_emit_reads_only_rows_by_index(make):
+    """The engagement check of ``emit``'s index work (PR 52). A 1-D
+    gather or an ``.at[dst].set`` by a traced index is a serial pass on
+    the TPU (7.1 and 4.6 ns a lane: PERF.md section 6, PR 52; three
+    such gathers and the scatter were 26 ns for every lane of VC in
+    every chunk-step), so the program a wave dispatches holds no
+    ``scatter`` under ``emit`` (the invariants' ``scatter-min`` writes a
+    static index of the violations vector), the action rank of a
+    compacted lane is nowhere gathered out of the [C * A] guard grid (it
+    rides in the compaction's sort key), and ``emit/append`` holds one
+    sort of int32 lanes, by one key. In the device wave program
+    ``emit/coverage`` gathers nothing, and ``emit/append`` gathers
+    once, the survivors' [VC, W] rows, beside that sort: the survivors'
+    lanes the key and their ``sel``, for the journal, the payload (the
+    cheaper form by ``scripts/emit_micro.py --journal``); the sharded
+    chunk program keeps its four reads through ``esel`` (ROADMAP S13)
+    and its sort is ``util.dense_prefix_sel``, of one operand."""
+    eng, name, eqns = _traced(make)
+    assert eng.n_actions > 0
+
+    def under(scope, prim):
+        return [e for e in eqns if e.primitive.name == prim
+                and str(e.source_info.name_stack).startswith(scope)]
+
+    # the scopes are there, so an empty list below means none
+    assert len(under("emit/append", "dynamic_update_slice")) >= 3
+    assert not under("emit", "scatter"), name
+    grid = eng.chunk * eng.A + 1  # the guard grid and its drop lane
+    assert not [e for e in eqns if e.primitive.name == "gather"
+                and e.invars[0].aval.shape == (grid,)], name
+    (sort,) = [e for e in under("emit/append", "sort")
+               if str(e.invars[0].aval.dtype) == "int32"]
+    assert sort.params["num_keys"] == 1
+    assert all(str(v.aval.dtype) == "int32" for v in sort.invars)
+    if name == "wave":
+        assert under("emit/coverage", "eq")
+        assert not under("emit/coverage", "gather")
+        (rows,) = under("emit/append", "gather")
+        assert rows.invars[0].aval.shape == (eng.VC + 1, eng.W)
+        assert len(sort.invars) == 2
+    else:
+        assert len(sort.invars) == 1
+
+
+@pytest.mark.parametrize(
     "make,names",
     [(_device_wave, ["wave", "seen_merge"]), (_sharded_chunk, ["chunk"])],
     ids=["device", "sharded"])

@@ -62,8 +62,7 @@ def test_emit_append_matches_scatter_rows(seed, cap, n_lanes):
         ref_buf = np.zeros((cap + 1, W), np.int32)
         ref, ref_ovf = _reference_scatter(ref_buf, vals, new, count, cap)
         # production: compact to a dense prefix block, append at cursor
-        npos = jnp.asarray((np.cumsum(new) - 1).astype(np.int32))
-        esel = dense_prefix_sel(jnp.asarray(new), npos, n_lanes)
+        esel = dense_prefix_sel(jnp.asarray(new), n_lanes)
         blk = jnp.concatenate(
             [jnp.asarray(vals), jnp.zeros((1, W), jnp.int32)], axis=0
         )[esel]
@@ -90,8 +89,7 @@ def test_emit_append_1d_journal_parity():
         ref, ref_ovf = _reference_scatter(
             ref_buf[:, None], vals[:, None], new, count, cap
         )
-        npos = jnp.asarray((np.cumsum(new) - 1).astype(np.int32))
-        esel = dense_prefix_sel(jnp.asarray(new), npos, n_lanes)
+        esel = dense_prefix_sel(jnp.asarray(new), n_lanes)
         blk = jnp.concatenate(
             [jnp.asarray(vals), jnp.zeros((1,), jnp.int32)]
         )[esel]
@@ -105,8 +103,7 @@ def test_emit_append_1d_journal_parity():
 
 def test_dense_prefix_sel_compacts_in_order():
     new = jnp.asarray([False, True, False, True, True, False])
-    npos = jnp.cumsum(new).astype(jnp.int32) - 1
-    sel = np.asarray(dense_prefix_sel(new, npos, 6))
+    sel = np.asarray(dense_prefix_sel(new, 6))
     # first n_new entries are the new lanes in order; the rest point at
     # the caller's pad row (index n_lanes)
     assert sel[:3].tolist() == [1, 3, 4]

@@ -14,8 +14,16 @@ compactions of ``expand`` became sorts of one int32 key (31 equations
 fewer in ``raft``'s wave, 11 in its dense arm, where only
 ``compact_chunk``'s changed), every successor row, ``sel`` and
 fingerprint bit-equal to that tree's (``tests/test_expand_compaction.py``'s
-``_reference_*``; ``scripts/stage_diff.py`` against the parent). Nothing
-is compiled or run.
+``_reference_*``; ``scripts/stage_diff.py`` against the parent). PR 52
+re-pinned them again, on purpose: the compaction's key carries the
+lane's action rank, so the rank's gather through ``sel`` left both
+programs, ``util.dense_prefix_sel`` is a sort of one int32 key, and
+the wave program's survivors' lanes and journal blocks come out of one
+such sort with ``sel`` its payload (eleven equations fewer in every
+wave program, seven in every chunk program); every row, journal entry,
+fingerprint, count and coverage cell bit-equal to PR 51's tree
+(``tests/test_emit_sorts.py``'s ``_reference_*``; ``stage_diff.py``
+against the parent). Nothing is compiled or run.
 
 A PR that means to change a program re-pins its digest on purpose, says
 so, and checks the benchmark's cells; a PR that does not must leave
@@ -39,36 +47,36 @@ ENGINES = {"device": DeviceBFS, "sharded": ShardedBFS}
 # the seen merge has no model in it: one digest for every family
 SEEN_MERGE = (12, "19ac660b935d83db")
 
-# {family: {engine: {program: (equations, digest)}}} at PR 50's tree
+# {family: {engine: {program: (equations, digest)}}} at PR 52's tree
 PARENT_PROGRAMS = {
     "raft": {
-        "device": {"wave": (4870, "dcc3dea65d1ff173"),
+        "device": {"wave": (4859, "84234c873a324d74"),
                    "seen_merge": SEEN_MERGE},
-        "sharded": {"chunk": (5263, "412fbb6646b02d52")}},
+        "sharded": {"chunk": (5256, "70d6d3e56703d5de")}},
     "raft-dense": {
-        "device": {"wave": (3629, "c65e516fe661a2fa"),
+        "device": {"wave": (3618, "559c7d1fb87bcf19"),
                    "seen_merge": SEEN_MERGE},
-        "sharded": {"chunk": (4022, "0c324dc0195f6d06")}},
+        "sharded": {"chunk": (4015, "3eaa70114f743cc3")}},
     "pull_raft": {
-        "device": {"wave": (5232, "b06f24e8491a3621"),
+        "device": {"wave": (5221, "9bb0962c3bafdaf8"),
                    "seen_merge": SEEN_MERGE},
-        "sharded": {"chunk": (5625, "c78689338e8b8c3f")}},
+        "sharded": {"chunk": (5618, "b03be548a55e6d78")}},
     "kraft": {
-        "device": {"wave": (6571, "e77d35780b284154"),
+        "device": {"wave": (6560, "ed0a5604e4e5b1b8"),
                    "seen_merge": SEEN_MERGE},
-        "sharded": {"chunk": (6964, "0af9478b76eb04b8")}},
+        "sharded": {"chunk": (6957, "ee8e6d034863c033")}},
     "joint_raft": {
-        "device": {"wave": (10904, "aa0cc4895f42ae35"),
+        "device": {"wave": (10893, "1b25a9de882fc036"),
                    "seen_merge": SEEN_MERGE},
-        "sharded": {"chunk": (11297, "c784cfbb06a290e0")}},
+        "sharded": {"chunk": (11290, "7fe938da5359921a")}},
     "kraft_reconfig": {
-        "device": {"wave": (14940, "e500f4fe2f766729"),
+        "device": {"wave": (14929, "ea9629d1a42c50d2"),
                    "seen_merge": SEEN_MERGE},
-        "sharded": {"chunk": (15333, "2cd40b537e56476e")}},
+        "sharded": {"chunk": (15326, "fb018ac09b5fa3f6")}},
     "reconfig_raft": {
-        "device": {"wave": (10529, "73e3b7ab5c88da6b"),
+        "device": {"wave": (10518, "2d322387cf30603e"),
                    "seen_merge": SEEN_MERGE},
-        "sharded": {"chunk": (10922, "d6e14931ae318f00")}},
+        "sharded": {"chunk": (10915, "8dba11c62761816b")}},
 }
 
 
@@ -95,8 +103,8 @@ def test_device_programs_are_the_parents(family, engine):
 
 
 def test_stages_one_and_two_are_written_once():
-    """The valid lanes' compaction (one sort of the lanes' indices) is
-    written in one place under ``raft_tpu/`` (``engine.compact_chunk``),
+    """The valid lanes' compaction (one sort of a key that holds the
+    lane's index over its action rank) is written in one place under ``raft_tpu/`` (``engine.compact_chunk``),
     and both device programs trace it."""
     import inspect
     import pathlib
@@ -104,7 +112,7 @@ def test_stages_one_and_two_are_written_once():
     import raft_tpu
     from raft_tpu.checker import engine
 
-    needle = "jnp.arange(C * A, dtype=jnp.int32), C * A))[:VC]"
+    needle = "valid.reshape(-1), flat, (C * A) << bits))[:VC]"
     root = pathlib.Path(raft_tpu.__file__).parent
     assert [p.relative_to(root).as_posix() for p in sorted(root.rglob("*.py"))
             if needle in p.read_text()] == ["checker/engine.py"]

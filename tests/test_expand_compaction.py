@@ -160,8 +160,9 @@ def test_sparse_apply_equals_the_retired_scatter_segmentation(family):
 
 @pytest.mark.parametrize("family", sorted(FAMILIES))
 def test_compact_chunk_equals_the_retired_scatter(family):
-    """``sort(where(valid, lane, C*A))[:VC]`` is the retired scatter's
-    ``sel``: with room to spare, with ``n_gen`` exactly VC (fits) and
+    """The sorted keys' lane part (the key carries the lane's rank under
+    its index since PR 52; tests/test_emit_sorts.py holds the rank) is
+    the retired scatter's ``sel``: with room to spare, with ``n_gen`` exactly VC (fits) and
     VC + 1 (``compact_ovf``, the last valid lane cut), and on a chunk
     with no valid lane. The dense arm (``succs`` given) needs no apply
     pass, so each VC is a cheap program; the rows gathered through
@@ -172,7 +173,8 @@ def test_compact_chunk_equals_the_retired_scatter(family):
     C = 16
     A, W = model.A, model.layout.W
     batch = jnp.asarray(_chunk_of(model, C))
-    succs, valid, _, _ = jax.jit(jax.vmap(model._expand1))(batch)
+    succs, valid, rank, _ = jax.jit(jax.vmap(model._expand1))(batch)
+    K = len(model.ACTION_NAMES)
     n = int(np.asarray(valid).sum())
     assert n >= 2
     flatp = np.concatenate(
@@ -181,9 +183,10 @@ def test_compact_chunk_equals_the_retired_scatter(family):
                        (valid, n - 1, True),
                        (jnp.zeros_like(valid), 8, False)]:
         n_gen = jnp.sum(v)
-        flatc, sel, selv, got_ovf = jax.device_get(jax.jit(
+        flatc, sel, selv, _, got_ovf = jax.device_get(jax.jit(
             lambda b, s, vv, ng, VC=VC: compact_chunk(
-                None, None, b, s, vv, ng, VC))(batch, succs, v, n_gen))
+                None, None, b, s, vv, rank, K, ng, VC))(
+                    batch, succs, v, n_gen))
         want_sel, want_selv = jax.device_get(_reference_compact(v, VC))
         assert sel.shape == (VC,) and bool(got_ovf) == ovf, VC
         np.testing.assert_array_equal(sel, want_sel)
