@@ -23,7 +23,7 @@ import jax
 import numpy as np
 
 from ..obs import (
-    COMPILES, MemWatch, NULL_TELEMETRY, device_budget, setup_phase, span,
+    COMPILES, MemWatch, NO_READING, NULL_TELEMETRY, setup_phase, span,
     traced_run,
 )
 from ..ops.hashing import U64_MAX
@@ -187,6 +187,10 @@ class BFSChecker:
         exhausted = True
         exit_cause = None
         tel = telemetry if telemetry is not None else NULL_TELEMETRY
+        # this engine's arrays are the host's: it hands the watch no
+        # device, so the measured keys are None here as on the CPU and
+        # only the plan below, of host RAM, stands (obs/memwatch.py)
+        memwatch = MemWatch(tel, ())
         self._ckpt_keep = checkpoint_keep
         self._chaos = chaos
 
@@ -255,10 +259,7 @@ class BFSChecker:
         # the row's device_s counts the jax-facing sections of a chunk
         # (expand/guards dispatch + fetches, fingerprinting); dedup, emit
         # and the seen merge are host bookkeeping and land in host_s
-        memwatch = (
-            MemWatch(tel, device_budget(jax.devices()[0]))
-            if tel.active else None
-        )
+        memwatch.init()
         tel_s_last = 0.0
         while len(frontier) and violation is None:
             if preempt is not None and preempt.requested:
@@ -414,7 +415,7 @@ class BFSChecker:
             if wave_sb.n == 0:
                 exit_cause = "exhausted"
                 break
-            emit_bytes = wave_sb.nbytes + wave_pb.nbytes + wave_cb.nbytes
+            wave_emit = wave_sb.nbytes + wave_pb.nbytes + wave_cb.nbytes
             wave_states = wave_sb.take()
             wave_parents = wave_pb.take()
             wave_cands = wave_cb.take()
@@ -444,24 +445,21 @@ class BFSChecker:
                 last_ckpt = time.perf_counter()
                 ckpt_s = last_ckpt - t_ck
             wave_s_val = time.perf_counter() - tw
+            # the plan here is the host-RAM analog of the device
+            # engines': the live working set is the frontier, the sorted
+            # seen array, the parent/candidate journal and this wave's
+            # emit block
+            hbm = memwatch.wave(depth, {
+                "frontier": int(frontier.nbytes),
+                "seen": int(seen.nbytes),
+                "journal": int(
+                    sum(p.nbytes for p in self._parents)
+                    + sum(c.nbytes for c in self._cands)
+                ),
+                "wave_emit": int(wave_emit),
+            })
             if tel.active or metrics is not None or verbose:
                 el = time.perf_counter() - t0
-                hbm_frac = None
-                if memwatch is not None:
-                    # host-RAM analog of the device engines' HBM model:
-                    # the live working set is the frontier, the sorted
-                    # seen array, the parent/candidate journal and this
-                    # wave's emit block
-                    frac = memwatch.update(depth, depth, {
-                        "frontier": int(frontier.nbytes),
-                        "seen": int(seen.nbytes),
-                        "journal": int(
-                            sum(p.nbytes for p in self._parents)
-                            + sum(c.nbytes for c in self._cands)
-                        ),
-                        "wave_emit": int(emit_bytes),
-                    })
-                    hbm_frac = round(frac, 6)
                 wm = wave_row(
                     depth=depth, frontier=prev_frontier,
                     new=len(wave_states), distinct=distinct,
@@ -469,14 +467,12 @@ class BFSChecker:
                     terminal=terminal, canon=(0, 0, 0), overflow_bits=0,
                     lsm_runs=1, lsm_lanes=int(len(seen)),
                     wave_s=wave_s_val, elapsed_s=el,
-                    # no fixed-capacity frontier buffer: fill is 0
-                    emit_bytes=emit_bytes, frontier_fill=0.0,
                     # extra fixed-size apply blocks past one per chunk:
                     # the host analog of the device engines' budget
                     # overflow bit (it loops instead of aborting)
                     A=model.A, expand_budget_ovf=wave_extra,
                     device_s=dev_s, ckpt_s=ckpt_s, tel_s=tel_s_last,
-                    hbm_frac=hbm_frac,
+                    hbm=hbm,
                 )
                 t_tel = time.perf_counter()
                 tel.wave(wm)
@@ -506,7 +502,7 @@ class BFSChecker:
 
         dt = time.perf_counter() - t0
         stats_run = run_stats(
-            self, comp_run, ph,
+            self, comp_run, ph, memwatch,
             frontier_peak_rows=max(depth_counts[1:], default=0),
             coverage=cov)
         if violation is not None:
@@ -529,7 +525,6 @@ class BFSChecker:
             peak_journal_cap=int(next_gid - len(self._init_distinct)),
             seen_lanes=int(len(seen)), canon_dup_rate=0.0,
             stats=stats_run, programs=COMPILES.programs(comp_run),
-            memwatch=memwatch,
             canon_tier3_local=0, canon_tier3_full=0,
         ))
         trace = self.reconstruct_trace(violation) if violation else None
@@ -800,10 +795,8 @@ class BFSChecker:
                     terminal=int(terminal_j.sum()), canon=(0, 0, 0),
                     overflow_bits=0, lsm_runs=1, lsm_lanes=int(len(seen)),
                     wave_s=wave_s_val, elapsed_s=el,
-                    emit_bytes=wave_sb.nbytes + wave_pb.nbytes
-                    + wave_cb.nbytes,
-                    frontier_fill=0.0, A=model.A, expand_budget_ovf=0,
-                    device_s=0.0, ckpt_s=0.0, tel_s=0.0, hbm_frac=None,
+                    A=model.A, expand_budget_ovf=0,
+                    device_s=0.0, ckpt_s=0.0, tel_s=0.0, hbm=NO_READING,
                     jobs_active=int(active.sum()),
                 ))
                 if verbose:

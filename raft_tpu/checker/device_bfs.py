@@ -68,7 +68,7 @@ import numpy as np
 from jax import lax
 
 from ..obs import (
-    COMPILES, MemWatch, NULL_TELEMETRY, device_budget, setup_phase, stage,
+    COMPILES, MemWatch, NULL_TELEMETRY, setup_phase, stage,
     traced_run,
 )
 from ..ops.hashing import U64_MAX, sort_u64
@@ -792,7 +792,7 @@ class DeviceBFS(FleetQueue):
         chaos=None,
     ) -> CheckResult:
         model = self.model
-        C, W = self.chunk, self.W
+        W = self.W
         t0 = time.perf_counter()
         # host spans (obs/trace.py): `init` up to the first wave, one
         # `wave` per loop iteration, `finish` after the loop; the phases
@@ -806,6 +806,10 @@ class DeviceBFS(FleetQueue):
         # loop already fetches (stats_h below), so an instrumented run
         # adds no device syncs and stays bit-identical (tests/test_obs.py)
         tel = telemetry if telemetry is not None else NULL_TELEMETRY
+        # the run's device memory, by the allocator (obs/memwatch.py):
+        # read here before any buffer, in init once the buffers are
+        # made, at the end of every wave and at finish; no read syncs
+        memwatch = MemWatch(tel, jax.devices()[:1])
         self._ckpt_keep = checkpoint_keep
         self._chaos = chaos
 
@@ -907,6 +911,7 @@ class DeviceBFS(FleetQueue):
         stats = jnp.asarray(stats0)
         cov = jnp.asarray(cov_h)  # i64[n_actions, 3], cumulative
         canon_prev = np.zeros((3,), np.int64)
+        memwatch.init()
 
         tel.open_run(self._telemetry_manifest())
         if resume is not None:
@@ -914,10 +919,6 @@ class DeviceBFS(FleetQueue):
         metrics: list[dict] | None = [] if collect_metrics else None
         last_ckpt = time.perf_counter()
 
-        memwatch = (
-            MemWatch(tel, device_budget(jax.devices()[0]))
-            if tel.active else None
-        )
         sort_lanes_run = search_queries_run = peak_rows = 0
 
         while fcount and violation is None:
@@ -1092,20 +1093,17 @@ class DeviceBFS(FleetQueue):
             # `telemetry` is the previous wave's bracket (Phases.take)
             ph_s = ph.take()
             comp_now = COMPILES.snapshot()
-            hbm_frac = None
-            if memwatch is not None:
-                # analytic live-bytes: what the run's geometry holds in
-                # device memory right now (allocated buffers — fill-
-                # level gauges ride the wave event separately). Changes
-                # only on growth / seen-resize waves, so the memwatch
-                # event stream stays low-volume by construction.
-                hbm_frac = memwatch.update(depth, depth, {
-                    "frontier": 2 * (self.FCAP + self.VC) * 4 * W,
-                    "journal": 2 * (self.JCAP + self.VC) * 4,
-                    "seen": int(self._seen.shape[0]) * 8,
-                    "wave_new": (self.FCAP + self.VC) * 8,
-                    "chunk": self.VC * (4 * W + 8),
-                })
+            # what the allocator holds now, after the merge and any
+            # growth, and beside it the plan: what the run's geometry
+            # says its buffers take (it changes only on a growth or a
+            # step of the seen run)
+            hbm = memwatch.wave(depth, {
+                "frontier": 2 * (self.FCAP + self.VC) * 4 * W,
+                "journal": 2 * (self.JCAP + self.VC) * 4,
+                "seen": int(self._seen.shape[0]) * 8,
+                "wave_new": (self.FCAP + self.VC) * 8,
+                "chunk": self.VC * (4 * W + 8),
+            })
             if not (tel.active or metrics is not None or verbose):
                 continue
             with ph("telemetry"):
@@ -1118,17 +1116,8 @@ class DeviceBFS(FleetQueue):
                     overflow_bits=ovf_bits,
                     lsm_runs=1, lsm_lanes=int(self._seen.shape[0]),
                     wave_s=wave_s_val, elapsed_s=el,
-                    # one [VC, W] i32 block + two VC i32 journal lanes
-                    # per chunk, vs the retired scatter's full-capacity
-                    # touch
-                    emit_bytes=(
-                        (prev_fcount + C - 1) // C
-                    ) * self.VC * (4 * W + 8),
-                    frontier_fill=round(ncount / self.FCAP, 4),
                     A=self.A, expand_budget_ovf=(ovf_bits >> 1) & 1,
-                    hbm_frac=(
-                        round(hbm_frac, 4) if hbm_frac is not None else None
-                    ),
+                    hbm=hbm,
                     **phase_clocks(ph_s, comp_wave, comp_now),
                     # this engine's own: the lanes the dedup stage's
                     # merged sort sorted, summed over the wave's
@@ -1173,7 +1162,7 @@ class DeviceBFS(FleetQueue):
 
         dt = time.perf_counter() - t0
         stats_run = run_stats(
-            self, comp_run, ph, frontier_peak_rows=peak_rows,
+            self, comp_run, ph, memwatch, frontier_peak_rows=peak_rows,
             coverage=cov_h, dedup_plan=self._dedup_plan(),
             canon_tier3_local=int(canon_prev[1]),
             canon_tier3_full=int(canon_prev[2]),
@@ -1200,7 +1189,6 @@ class DeviceBFS(FleetQueue):
             canon_dup_rate=round(
                 int(canon_prev[0]) / max(1, gen_prev), 4),
             stats=stats_run, programs=COMPILES.programs(comp_run),
-            memwatch=memwatch,
         ))
         trace = self.reconstruct_trace(violation) if violation else None
         res = CheckResult(

@@ -180,8 +180,8 @@ def phase_clocks(ph_s: dict, comp_wave, comp_now) -> dict:
 def wave_row(
     *, depth, frontier, new, distinct, generated, generated_total,
     terminal, canon, overflow_bits, lsm_runs, lsm_lanes, wave_s,
-    elapsed_s, emit_bytes, frontier_fill, A, expand_budget_ovf,
-    device_s, ckpt_s, tel_s, hbm_frac, **own,
+    elapsed_s, A, expand_budget_ovf, device_s, ckpt_s, tel_s, hbm,
+    **own,
 ) -> dict:
     """One wave's row: the declared keys (``obs.events.WAVE_KEYS`` but
     the collector's "event" and "wave", in that order), then ``own``,
@@ -192,17 +192,16 @@ def wave_row(
     has no in-chunk dedup and no tiered canon; the declared keys still
     appear so one consumer reads every engine.
 
-    The emit gauges: rows appended this wave (the new ones), the bytes
-    the cursor-append emit WROTE, and how full the frontier buffer got
-    (the caller's reading: the worst shard's; 0.0 on the unbounded host
-    engine) -- the stall watchdog reads these to attribute growth and
-    cliff waves. The sparse-expand gauges: the enabled fraction of the
-    dense [frontier, A] candidate grid this wave (the guard-first win
-    scales with its inverse) and ``expand_budget_ovf`` (device engines:
-    the apply budget's overflow bit, always 0 on a surviving wave, the
+    ``emit_rows``: the rows appended this wave (the new ones). The
+    sparse-expand gauges: the enabled fraction of the dense
+    [frontier, A] candidate grid this wave (the guard-first win scales
+    with its inverse) and ``expand_budget_ovf`` (device engines: the
+    apply budget's overflow bit, always 0 on a surviving wave, the
     abort fires first; host engine: the extra apply blocks it ran past
     one a chunk). The clocks are unrounded and
-    ``device_s + host_s + ckpt_s == wave_s``."""
+    ``device_s + host_s + ckpt_s == wave_s``. ``hbm`` is the wave's
+    reading of the run's ``MemWatch`` (``MemWatch.wave``; the packed
+    fleet, which nobody watches, hands in ``obs.NO_READING``)."""
     dup, t3_local, t3_full = canon
     return {
         "depth": depth,
@@ -224,15 +223,13 @@ def wave_row(
         "elapsed_s": elapsed_s,
         "distinct_per_s": round(distinct / elapsed_s, 1),
         "emit_rows": new,
-        "emit_bytes": emit_bytes,
-        "frontier_fill": frontier_fill,
         "enabled_density": round(generated / max(1, frontier * A), 4),
         "expand_budget_ovf": expand_budget_ovf,
         "device_s": device_s,
         "host_s": max(0.0, wave_s - device_s - ckpt_s),
         "ckpt_s": ckpt_s,
         "tel_s": tel_s,
-        "hbm_frac": hbm_frac,
+        **hbm,
         **own,
     }
 
@@ -256,32 +253,34 @@ def restart_fired(engine, coverage) -> int:
 
 
 def run_stats(
-    engine, comp_run, ph, *, frontier_peak_rows: int, coverage, **own
+    engine, comp_run, ph, memwatch, *, frontier_peak_rows: int, coverage,
+    **own,
 ) -> dict:
     """What a result's ``stats`` and the summary share: what the run
     loaded into the process (obs/compiles.py), its top-level spans'
     seconds, ``frontier_peak_rows``, the most rows a wave of the run
     wrote (the max of the wave rows' ``new``: how full the frontier
     got, beside the summary's ``peak_frontier_cap``, how large it was),
-    and ``restart_fired`` of ``engine``'s ``coverage``, then the
-    engine's ``own``. Call it beside the run's wall clock:
-    ``init_s + waves_s + finish_s`` add up to that."""
+    ``restart_fired`` of ``engine``'s ``coverage``, the ``hbm_*`` keys
+    of the run's ``memwatch`` with its last read of the allocator
+    (``MemWatch.finish``), then the engine's ``own``. Call it beside the
+    run's wall clock: ``init_s + waves_s + finish_s`` add up to that."""
     return {**COMPILES.run_stats(comp_run), **ph.top_seconds(),
             "frontier_peak_rows": int(frontier_peak_rows),
-            "restart_fired": restart_fired(engine, coverage), **own}
+            "restart_fired": restart_fired(engine, coverage),
+            **memwatch.finish(), **own}
 
 
 def summary_fields(
     engine, name: str, *, exit_cause, violation, distinct, total, depth,
     terminal, seconds, exhausted, peak_frontier_cap, peak_journal_cap,
-    seen_lanes, canon_dup_rate, stats: dict, programs=None,
-    memwatch=None, **own,
+    seen_lanes, canon_dup_rate, stats: dict, programs=None, **own,
 ) -> dict:
     """The summary event of ``engine`` under its stream name ``name``:
     the declared counts, cause, seconds and rates, the caller's ``own``
-    keys beside them, ``stats`` (``run_stats``), the run's program
-    records where the caller has them, and memwatch's fields where it
-    ran. ``seconds`` is the run's wall, unrounded."""
+    keys beside them, ``stats`` (``run_stats``, the ``hbm_*`` keys among
+    them) and the run's program records where the caller has them.
+    ``seconds`` is the run's wall, unrounded."""
     return {
         "engine": name,
         "ident": engine._ckpt_ident(),
@@ -302,7 +301,6 @@ def summary_fields(
         **own,
         **stats,
         **({} if programs is None else {"programs": programs}),
-        **(memwatch.summary_fields() if memwatch is not None else {}),
     }
 
 

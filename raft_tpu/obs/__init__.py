@@ -39,7 +39,9 @@ from .events import (
     validate_event,
     validate_lines,
 )
-from .memwatch import MemWatch, budget_from_env, device_budget
+from .memwatch import (
+    NO_READING, MemWatch, budget_from_env, device_budget,
+)
 from .progress import ProgressRenderer, format_count
 from .compiles import COMPILES
 from .trace import (
@@ -66,6 +68,7 @@ __all__ = [
     "JobTaggedTelemetry",
     "MemWatch",
     "MetricsCollector",
+    "NO_READING",
     "NULL_TELEMETRY",
     "Phases",
     "ProgressRenderer",

@@ -27,8 +27,10 @@ documented at their key tuples below; they interleave with the above
 (retry between attempts, resume/ckpt_generation right after a resumed
 run's manifest, preempt just before a "preempted" summary).
 
-``memwatch`` carries the analytic HBM live-bytes watermarks of
-obs/memwatch.py (peak monotone within a run; before its run's summary).
+``memwatch`` carries the run's device memory as obs/memwatch.py reads
+it: the allocator's reading first (null on a device that reports none,
+the CPU), the geometry's plan second; both peaks monotone within a run,
+and the event before its run's summary.
 
 ``DECLARED_EVENTS`` is pinned by the tier-1 smoke test,
 so the schema cannot silently rot when an engine's stats
@@ -75,12 +77,8 @@ TIMELINE_STAGES = (
     "host",        # host bookkeeping not covered by a device stage
 )
 
-# emit_rows/emit_bytes/frontier_fill (round 6): rows the wave's
-# contiguous cursor-append emit landed, bytes it wrote, and frontier-
-# buffer occupancy (worst shard; 0.0 on the unbounded host engine) — so
-# the stall watchdog can tell an emit-bound or growth/recompile wave
-# from a compute-bound one (round 5's depth-32 wave-time cliff was
-# attributed with exactly these gauges).
+# emit_rows: rows the wave's contiguous cursor-append emit landed (the
+# new ones).
 # enabled_density/expand_budget_ovf (guard-first sparse expansion):
 # enabled fraction of the dense [chunk, A] candidate grid this wave
 # (the guard-first win scales with its inverse — tune valid_per_group
@@ -130,18 +128,26 @@ TIMELINE_STAGES = (
 # seen_lanes: the lanes of the seen run the wave ran against (its size
 # before the wave's own merge, which may step it up), so a trace says
 # which waves merged and which searched.
-# hbm_frac: analytic live-bytes / budget from obs/memwatch.py (null when
-# memwatch is off).
+# hbm_bytes / hbm_peak_rise / hbm_frac (obs/memwatch.py, on every run):
+# the allocator's bytes_in_use at the wave's end, after the seen merge
+# and any growth (what the run holds between programs); by how much its
+# peak_bytes_in_use rose since the read before, so a rise is booked to
+# the wave it happened in (0 on most waves; the peak is the process's,
+# so a later run of a process rises little or not at all); both null on
+# a device that reports nothing (the CPU). hbm_frac is hbm_bytes over the budget
+# where the device reports and the geometry's plan over it otherwise
+# (the CPU dry run): never null on a watched run. The packed fleet's
+# rows, which nobody watches, carry null for all three. The allocator
+# is asked, not the stream: zero extra device syncs.
 WAVE_KEYS = (
     "event", "wave", "depth", "frontier", "new", "distinct",
     "generated", "generated_total", "terminal", "dedup_hit_rate",
     "canon_dup_lanes", "canon_dup_rate", "canon_tier3_local",
     "canon_tier3_full", "overflow_bits",
     "lsm_runs", "lsm_lanes", "wave_s", "elapsed_s", "distinct_per_s",
-    "emit_rows", "emit_bytes", "frontier_fill",
-    "enabled_density", "expand_budget_ovf",
+    "emit_rows", "enabled_density", "expand_budget_ovf",
     "device_s", "host_s", "ckpt_s", "tel_s",
-    "hbm_frac",
+    "hbm_bytes", "hbm_peak_rise", "hbm_frac",
 )
 
 STALL_KEYS = (
@@ -252,17 +258,41 @@ SHARD_STALL_KEYS = (
     "event", "wave", "depth", "shard", "wave_s", "median_wave_s", "factor",
 )
 
-# device-memory watermark (obs/memwatch.py):
-#   memwatch    analytic HBM live-bytes watermark, emitted when a wave
-#               sets a new peak (so the stream stays low-volume and
-#               peak_bytes is monotone within a run by construction).
-#               ``breakdown`` maps a buffer family (frontier / chunk /
-#               seen / journal / ...) to live bytes; ``frac`` =
-#               total_bytes / budget_bytes (may exceed 1.0 — that is
-#               the out-of-core planning signal).
+# device memory (obs/memwatch.py):
+#   memwatch    a wave's reading, emitted when the wave set a new plan
+#               peak or the allocator's peak rose in it (so the stream
+#               stays low-volume). Measured first: ``bytes`` /
+#               ``peak_bytes`` are the allocator's bytes_in_use and
+#               peak_bytes_in_use at the wave's end, ``peak_rise`` the
+#               peak's rise since the read before; null on a device
+#               that reports nothing (the CPU). The plan second:
+#               ``plan_bytes`` is what the run's geometry says its
+#               buffers take now, ``breakdown`` its split by buffer
+#               family (frontier / chunk / seen / journal / ...),
+#               ``plan_peak_bytes`` the run's largest so far,
+#               ``plan_frac`` = plan_bytes / budget_bytes (may exceed
+#               1.0: the out-of-core planning signal). ``frac`` is the
+#               row's hbm_frac: measured where there is a reading, the
+#               plan's otherwise. Both peaks are monotone within a run.
 MEMWATCH_KEYS = (
-    "event", "wave", "depth", "total_bytes", "peak_bytes",
-    "budget_bytes", "frac", "breakdown",
+    "event", "wave", "depth", "bytes", "peak_bytes", "peak_rise",
+    "budget_bytes", "frac", "plan_bytes", "plan_peak_bytes",
+    "plan_frac", "breakdown",
+)
+
+# a run's device memory on its summary and on its result's ``stats``
+# (obs/memwatch.py has each key's meaning; the packed fleet's summaries,
+# which nobody watches, carry none). The measured ones are the
+# allocator's byte counts, and with the three fractions made of them
+# null on a device that reports nothing (the CPU) and on the host
+# engine, which hands in none; the budget and the plan's two always
+# stand.
+HBM_MEASURED_KEYS = (
+    "hbm_peak_bytes", "hbm_live_bytes", "hbm_init_bytes", "hbm_init_rise",
+)
+HBM_KEYS = (
+    "hbm_budget_bytes", *HBM_MEASURED_KEYS, "hbm_plan_bytes",
+    "hbm_plan_frac", "hbm_peak_frac", "hbm_live_frac", "hbm_plan_gap_frac",
 )
 
 DECLARED_EVENTS = (
@@ -461,30 +491,41 @@ def validate_event(ev: object, lineno: int | None = None) -> list[str]:
                     f"number (seconds)"
                 )
         frac = ev.get("hbm_frac")
-        if frac is not None and (
-            isinstance(frac, bool) or not isinstance(frac, (int, float))
-            or frac < 0
-        ):
+        if frac is not None and _negative_or_no_number(frac):
             problems.append(
                 f"{where}wave hbm_frac {frac!r} must be null or a "
                 f"non-negative number"
             )
-    if etype == "memwatch":
-        for key in ("total_bytes", "peak_bytes", "budget_bytes"):
+        for key in ("hbm_bytes", "hbm_peak_rise"):
             v = ev.get(key)
-            if isinstance(v, bool) or not isinstance(v, int) or v < 0:
+            if v is not None and not _is_count(v):
+                problems.append(
+                    f"{where}wave {key} {v!r} must be null or a "
+                    f"non-negative int (bytes, by the allocator)"
+                )
+    if etype == "memwatch":
+        for key in ("bytes", "peak_bytes", "peak_rise"):
+            v = ev.get(key)
+            if v is not None and not _is_count(v):
+                problems.append(
+                    f"{where}memwatch {key} {v!r} must be null or a "
+                    f"non-negative int (bytes, by the allocator)"
+                )
+        for key in ("plan_bytes", "plan_peak_bytes", "budget_bytes"):
+            v = ev.get(key)
+            if not _is_count(v):
                 problems.append(
                     f"{where}memwatch {key} {v!r} must be a non-negative "
                     f"int"
                 )
-        tot, peak = ev.get("total_bytes"), ev.get("peak_bytes")
-        if isinstance(tot, int) and isinstance(peak, int) \
-                and not isinstance(tot, bool) and not isinstance(peak, bool) \
-                and tot > peak:
-            problems.append(
-                f"{where}memwatch total_bytes {tot} exceeds peak_bytes "
-                f"{peak} (the peak must cover the wave that set it)"
-            )
+        for low, high in (("bytes", "peak_bytes"),
+                          ("plan_bytes", "plan_peak_bytes")):
+            lo, hi = ev.get(low), ev.get(high)
+            if _is_count(lo) and _is_count(hi) and lo > hi:
+                problems.append(
+                    f"{where}memwatch {low} {lo} exceeds {high} {hi} "
+                    f"(a peak covers the wave that reports it)"
+                )
         br = ev.get("breakdown")
         if not isinstance(br, dict) or any(
             not isinstance(k, str) or isinstance(v, bool)
@@ -510,13 +551,24 @@ def validate_event(ev: object, lineno: int | None = None) -> list[str]:
                 )
         for key, what in (
                 ("frontier_peak_rows", "the most rows a wave wrote"),
-                ("restart_fired", "successors the crash actions generated")):
+                ("restart_fired", "successors the crash actions generated"),
+                ("hbm_budget_bytes", "the device's memory"),
+                ("hbm_plan_bytes", "the geometry's peak"),
+                *((key, "bytes, by the allocator")
+                  for key in HBM_MEASURED_KEYS)):
             v = ev.get(key)
             if v is not None and not _is_count(v):
                 problems.append(
                     f"{where}summary {key} {v!r} must be a non-negative "
                     f"int ({what})"
                 )
+        live, peak = ev.get("hbm_live_bytes"), ev.get("hbm_peak_bytes")
+        if _is_count(live) and _is_count(peak) and live > peak:
+            problems.append(
+                f"{where}summary hbm_live_bytes {live} exceeds "
+                f"hbm_peak_bytes {peak} (the allocator's peak covers "
+                f"every reading of the run)"
+            )
         problems += [
             f"{where}summary programs[{i}]: {p}"
             for i, p in _program_problems(ev.get("programs", []))
@@ -639,7 +691,7 @@ def validate_lines(lines) -> tuple[dict, list[str]]:
     last_cov_wave = 0
     prev_actions: list | None = None
     last_retry_attempt = 0
-    last_memwatch_peak = 0
+    last_memwatch_peak = {"peak_bytes": 0, "plan_peak_bytes": 0}
     job_wave: dict[str, int] = {}
     job_manifests: dict[str, int] = {}
     job_summaries: dict[str, int] = {}
@@ -664,7 +716,7 @@ def validate_lines(lines) -> tuple[dict, list[str]]:
             summarized = False
             last_cov_wave = 0
             prev_actions = None
-            last_memwatch_peak = 0
+            last_memwatch_peak = {"peak_bytes": 0, "plan_peak_bytes": 0}
             if job is not None:
                 job_manifests[job] = job_manifests.get(job, 0) + 1
                 job_wave[job] = 0
@@ -744,17 +796,19 @@ def validate_lines(lines) -> tuple[dict, list[str]]:
                 problems.append(
                     f"line {lineno}: memwatch event after the run's summary"
                 )
-            peak = ev.get("peak_bytes")
-            if isinstance(peak, int) and not isinstance(peak, bool):
-                if peak < last_memwatch_peak:
+            for key in ("peak_bytes", "plan_peak_bytes"):
+                peak = ev.get(key)
+                if not _is_count(peak):
+                    continue
+                if peak < last_memwatch_peak[key]:
                     problems.append(
-                        f"line {lineno}: memwatch peak_bytes {peak} "
+                        f"line {lineno}: memwatch {key} {peak} "
                         f"regressed below the run's watermark "
-                        f"{last_memwatch_peak} (peaks are monotone "
+                        f"{last_memwatch_peak[key]} (peaks are monotone "
                         f"within a run)"
                     )
                 else:
-                    last_memwatch_peak = peak
+                    last_memwatch_peak[key] = peak
         elif etype == "retry":
             att = ev.get("attempt")
             if isinstance(att, int) and not isinstance(att, bool):

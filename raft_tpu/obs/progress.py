@@ -38,7 +38,7 @@ class ProgressRenderer:
     # cannot drift apart
     CONSUMES = (
         "depth", "generated_total", "distinct", "distinct_per_s",
-        "canon_dup_rate", "hbm_frac",
+        "canon_dup_rate", "hbm_frac", "hbm_bytes",
         "generated", "canon_tier3_local", "canon_tier3_full",
     )
 
@@ -62,8 +62,11 @@ class ProgressRenderer:
             ev.get("canon_tier3_full") or 0)
         if tier3:
             line += f", tier3 {tier3 / max(1, ev['generated']):.0%}"
+        # the device's memory by its allocator; the geometry's plan
+        # where the device reports nothing (a CPU dry run)
         if ev.get("hbm_frac"):
-            line += f", hbm {ev['hbm_frac']:.0%}"
+            gauge = "plan" if ev.get("hbm_bytes") is None else "hbm"
+            line += f", {gauge} {ev['hbm_frac']:.0%}"
         return line
 
     def loaded(self, rec: dict) -> None:

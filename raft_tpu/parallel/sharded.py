@@ -71,7 +71,7 @@ from ..checker.engine import (
 )
 from ..checker.lsm import RunLSM, pow2_at_least
 from ..obs import (
-    COMPILES, MemWatch, NULL_TELEMETRY, device_budget, setup_phase, span,
+    COMPILES, MemWatch, NULL_TELEMETRY, setup_phase, span,
     stage, traced_run,
 )
 from ..checker.util import (
@@ -1112,6 +1112,10 @@ class ShardedBFS(FleetQueue):
         # telemetry rides the once-per-wave stats fetch the loop already
         # does — zero extra collectives or device syncs
         tel = telemetry if telemetry is not None else NULL_TELEMETRY
+        # the mesh's device memory, the fullest chip's, by the allocator
+        # (obs/memwatch.py): read here before any buffer, in init once
+        # the buffers are made, at the end of every wave and at finish
+        memwatch = MemWatch(tel, self.mesh.devices.flat)
 
         init = np.asarray(model.init_states())
         init_fps = np.asarray(
@@ -1278,16 +1282,12 @@ class ShardedBFS(FleetQueue):
         metrics: list[dict] | None = [] if collect_metrics else None
         last_ckpt = time.perf_counter()
         state["cov"] = jax.device_put(cov_hd, self._sharding)
+        memwatch.init()
         dup_prev = 0
         tiers_prev = np.zeros((2,), np.int64)
         peak_rows = 0
         per_shard_dup = np.zeros(D, np.int64)
         wave_times: list[float] = []  # stall-watchdog rolling window
-        # every wave gets the phase split + analytic HBM watermark
-        memwatch = (
-            MemWatch(tel, device_budget(self.mesh.devices.flat[0]))
-            if tel.active else None
-        )
 
         while fcounts.sum() and violation is None:
             exit_cause = loop_exit(
@@ -1323,7 +1323,6 @@ class ShardedBFS(FleetQueue):
             bl_dev = jax.device_put(
                 base_lgid.astype(np.int32).reshape(D, 1), self._sharding)
             max_fc = int(fcounts.max())
-            chunks_done = 0
             with tel.wave_annotation(depth + 1):
                 for cursor in range(0, max_fc, C):
                     occ_dev = self._occ_dev()
@@ -1342,7 +1341,6 @@ class ShardedBFS(FleetQueue):
                         )
                     with ph("seen_merge"):
                         self._lsm.insert(new_run)
-                    chunks_done += 1
                     if chaos is not None:
                         lost = chaos.shard_loss(depth + 1, D)
                         if lost is not None:
@@ -1518,24 +1516,21 @@ class ShardedBFS(FleetQueue):
             # what they leave of the wave, host_s
             ph_s = ph.take()
             comp_now = COMPILES.snapshot()
+            # what the fullest chip's allocator holds now, and beside it
+            # the PER-CHIP plan (the budget is one chip's HBM):
+            # double-buffered frontier, 4-lane journal, this chip's LSM
+            # lanes, the chunk scratch (payload + send/recv blocks)
+            hbm = memwatch.wave(depth, {
+                "frontier": 2 * (self.FCAP + self.EPAD) * 4 * W,
+                "journal": (self.JCAP + self.EPAD) * (4 * 3 + 8),
+                "seen": int(self._lsm.lanes()) * 8,
+                "chunk": (self.VC + 2 * self.D * self.RC)
+                * (4 * (W + 3) + 8),
+            })
             if not (tel.active or metrics is not None or verbose):
                 continue
             with ph("telemetry"):
                 el = time.perf_counter() - t0
-                hbm_frac = None
-                if memwatch is not None:
-                    # PER-CHIP analytic live bytes (the budget is one
-                    # core's HBM): double-buffered frontier, 4-lane
-                    # journal, this chip's LSM lanes, the chunk scratch
-                    # (payload + send/recv blocks)
-                    frac = memwatch.update(depth, depth, {
-                        "frontier": 2 * (self.FCAP + self.EPAD) * 4 * W,
-                        "journal": (self.JCAP + self.EPAD) * (4 * 3 + 8),
-                        "seen": int(self._lsm.lanes()) * 8,
-                        "chunk": (self.VC + 2 * self.D * self.RC)
-                        * (4 * (W + 3) + 8),
-                    })
-                    hbm_frac = round(frac, 6)
                 wm = wave_row(
                     depth=depth, frontier=int(prev_fcounts.sum()),
                     new=global_new, distinct=distinct, generated=wave_gen,
@@ -1545,15 +1540,8 @@ class ShardedBFS(FleetQueue):
                     lsm_runs=sum(self._lsm.occ),
                     lsm_lanes=int(self._lsm.lanes()),
                     wave_s=wave_s_val, elapsed_s=el,
-                    # one [D*RC, W] block + three journal lanes per chip
-                    # per chunk
-                    emit_bytes=chunks_done * D * (D * self.RC)
-                    * (4 * W + 12),
-                    # the worst chip's: nearing 1.0 flags an imminent
-                    # growth/overflow wave for the stall watchdog
-                    frontier_fill=round(int(new_d.max()) / self.FCAP, 4),
                     A=self.A, expand_budget_ovf=(ovf_bits >> 1) & 1,
-                    hbm_frac=hbm_frac,
+                    hbm=hbm,
                     **phase_clocks(ph_s, comp_wave, comp_now),
                     # this engine's own: the all-to-all's lanes and bytes
                     # (payload widened to W+3 by the routed rank column)
@@ -1596,7 +1584,7 @@ class ShardedBFS(FleetQueue):
 
         dt = time.perf_counter() - t0
         stats_run = run_stats(
-            self, comp_run, ph, frontier_peak_rows=peak_rows,
+            self, comp_run, ph, memwatch, frontier_peak_rows=peak_rows,
             coverage=cov_hd, dedup_plan=self._dedup_plan(),
             canon_tier3_local=int(tiers_prev[0]),
             canon_tier3_full=int(tiers_prev[1]),
@@ -1635,7 +1623,6 @@ class ShardedBFS(FleetQueue):
             seen_lanes=int(self._lsm.lanes()),
             canon_dup_rate=fleet_rate,
             stats=stats_run, programs=COMPILES.programs(comp_run),
-            memwatch=memwatch,
             # this engine's own (the schema allows extra keys)
             shard_dup_lanes=fleet_stats["shard_dup_lanes"],
             shard_skew=fleet_stats["shard_skew"],

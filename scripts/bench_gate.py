@@ -28,7 +28,8 @@ Per-metric rules:
   Use for counts the checker must reproduce exactly (distinct, total,
   depth, terminal): a drift here is a correctness bug, not a perf one.
 - ``direction: "max"`` — run must be <= value + tolerance. Use for
-  costs (seconds, hbm_peak_bytes): bigger is worse.
+  costs (seconds, hbm_plan_bytes; on a chip hbm_peak_bytes): bigger is
+  worse.
 - ``direction: "min"`` — run must be >= value - tolerance. Use for
   rates (distinct_per_s): smaller is worse.
 - tolerance is ``tol`` (absolute) or ``rel_tol`` (fraction of the

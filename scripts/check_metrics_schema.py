@@ -26,11 +26,22 @@ written on a different mesh size) must appear after the manifest but
 before any wave and carry distinct from_d/to_d >= 1, while shard_lost /
 shard_stall must name a shard index inside the mesh (0 <= shard <
 device_count), carry a wave no older than the run's last completed
-wave, and come before the summary. A `memwatch` event (emitted only
-when the analytic live-byte watermark sets a new peak) must keep
-peak_bytes monotone non-decreasing across the run with total_bytes <=
-peak_bytes, non-negative byte counts throughout, and a breakdown
-mapping buffer families to non-negative byte counts.
+wave, and come before the summary. A `memwatch` event (a wave's
+reading of the device's memory, emitted when the wave set a new plan
+peak or the allocator's peak rose in it: obs/memwatch.py) must keep
+both peak_bytes (the allocator's; null on a device that reports none)
+and plan_peak_bytes (the geometry's) monotone non-decreasing across the
+run, with bytes <= peak_bytes and plan_bytes <= plan_peak_bytes,
+non-negative int byte counts throughout (the measured three or null),
+and a breakdown mapping buffer families to non-negative byte counts.
+A `wave` event's hbm_bytes and hbm_peak_rise (the allocator's bytes in
+use at the wave's end and its peak's rise since the read before) must
+be non-negative ints or null and its hbm_frac a non-negative number or
+null; a `summary` event's hbm_peak_bytes, hbm_live_bytes,
+hbm_init_bytes and hbm_init_rise likewise, its hbm_budget_bytes and
+hbm_plan_bytes non-negative ints, and hbm_live_bytes <= hbm_peak_bytes
+where both are there (the allocator's peak covers every reading of the
+run).
 A `wave` event's canon_tier3_local and
 canon_tier3_full (lanes its canon routed to tier 3's buckets) must be
 non-negative ints that together do not exceed generated -

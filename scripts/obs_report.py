@@ -3,8 +3,8 @@
 Renders a run recorded with ``--metrics-out`` (see raft_tpu/obs) into a
 human-readable digest: manifest provenance, the summary block, the
 TLC-style per-action coverage table, the frontier depth histogram, an
-occupancy sparkline over waves, any stall events, an analytic HBM
-watermark digest from the memwatch events, and — on sharded runs — a
+occupancy sparkline over waves, any stall events, a device-memory
+digest from the memwatch events (measured, then planned), and — on sharded runs — a
 per-shard balance table (work share, skew) from the rows' ``shard_new``.
 
 Deliberately dependency-free (stdlib only — no jax, no numpy, no
@@ -92,25 +92,38 @@ def _fmt_bytes(n) -> str:
 
 
 def _render_memory(out: list[str], events: list[dict]) -> None:
-    """Analytic HBM watermark digest from the memwatch peak events."""
+    """Device-memory digest from the memwatch events: the allocator's
+    reading where the device reports one, then the geometry's plan."""
     mws = [e for e in events if e["event"] == "memwatch"]
     if not mws:
         return
-    out.append("## Memory watermarks (analytic)")
+    out.append("## Memory watermarks")
     out.append("")
     last = mws[-1]
+    budget = int(last["budget_bytes"])
+    if last["peak_bytes"] is not None:
+        rose = [m for m in mws if m["peak_rise"]]
+        out.append(
+            f"- **measured** (the allocator): peak "
+            f"{_fmt_bytes(last['peak_bytes'])} of {_fmt_bytes(budget)} "
+            f"({last['peak_bytes'] / budget:.1%}), "
+            f"{_fmt_bytes(last['bytes'])} held at wave {last['wave']}; "
+            f"the peak rose in wave(s) "
+            f"{[m['wave'] for m in rose] or 'none of this run'}"
+        )
     out.append(
-        f"- **peak live bytes**: {_fmt_bytes(last['peak_bytes'])} of "
-        f"{_fmt_bytes(last['budget_bytes'])} budget "
-        f"({float(last['frac']):.1%}), set at wave {last['wave']} "
-        f"({len(mws)} peak event(s))"
+        f"- **plan** (the geometry): peak "
+        f"{_fmt_bytes(last['plan_peak_bytes'])} of {_fmt_bytes(budget)} "
+        f"budget ({last['plan_peak_bytes'] / budget:.1%}), as of wave "
+        f"{last['wave']} ({len(mws)} memwatch event(s))"
     )
-    out.append(f"- peak trajectory: `{sparkline([m['peak_bytes'] for m in mws])}`")
+    out.append("- plan trajectory: "
+               f"`{sparkline([m['plan_peak_bytes'] for m in mws])}`")
     breakdown = last.get("breakdown") or {}
     if breakdown:
-        peak = max(int(v) for v in breakdown.values()) if breakdown else 0
+        peak = max(int(v) for v in breakdown.values())
         out.append("")
-        out.append("| buffer family | bytes at peak |  |")
+        out.append("| buffer family | planned bytes |  |")
         out.append("|---|---:|---|")
         for fam, b in sorted(breakdown.items(), key=lambda kv: -int(kv[1])):
             out.append(f"| {fam} | {_fmt_bytes(b)} | {hbar(int(b), peak)} |")
@@ -164,7 +177,8 @@ def render_run(events: list[dict]) -> str:
         for k in ("exit_cause", "violation", "distinct", "total", "depth",
                   "terminal", "seconds", "distinct_per_s", "exhausted",
                   "waves", "stalls", "canon_dup_rate",
-                  "hbm_peak_bytes", "hbm_peak_frac"):
+                  "hbm_peak_bytes", "hbm_peak_frac", "hbm_live_bytes",
+                  "hbm_plan_bytes", "hbm_plan_frac"):
             if k in summ:
                 out.append(f"- **{k}**: {_fmt(summ[k])}")
     out.append("")

@@ -35,6 +35,8 @@ from raft_tpu.obs import (
     validate_lines,
 )
 from raft_tpu.models.raft import RaftParams, cached_model
+from raft_tpu.obs.events import HBM_KEYS, HBM_MEASURED_KEYS
+from raft_tpu.obs.memwatch import ROW_KEYS as HBM_ROW_KEYS
 
 SMALL = RaftParams(
     n_servers=2, n_values=1, max_elections=1, max_restarts=0, msg_slots=16
@@ -257,9 +259,10 @@ _PHASES = ("dispatch_s", "fetch_s", "merge_s", "grow_s", "compiles",
            "compile_s")
 _RUN_LOADED = ("run_compiles", "run_compile_s", "run_cache_hits",
                "run_cache_read_s")
+# the run's device memory (obs/memwatch.py), on ``stats`` and so on the
+# summary, whoever listens
 _TRACED_RUN = (*_RUN_LOADED, "init_s", "waves_s", "finish_s", "programs",
-               "hbm_peak_bytes", "hbm_peak_wave", "hbm_budget_bytes",
-               "hbm_peak_frac")
+               *HBM_KEYS)
 ROW_OWN = {
     "device": (*_PHASES, "dedup_sort_lanes", "dedup_search_queries",
                "seen_lanes"),
@@ -311,28 +314,35 @@ def _packed_fleet():
     ), [j.name for j in group.jobs]
 
 
+def _run_of(engine, **kw):
+    """A depth-4 run of one of the three engines, as the test above
+    builds them."""
+    from raft_tpu.checker.bfs import BFSChecker
+
+    if engine == "device":
+        return _device().run(max_depth=4, **kw)
+    if engine == "host":
+        return BFSChecker(
+            cached_model(SMALL), invariants=INVS, symmetry=True, chunk=256,
+        ).run(max_depth=4, **kw)
+    return _sharded(2, frontier_cap=2048, seen_cap=1 << 13).run(
+        max_depth=4, **kw)
+
+
 @pytest.mark.parametrize("engine", ["device", "host", "sharded", "host_fleet"])
 def test_rows_and_summary_have_one_author(engine):
     """Every engine's wave rows are the schema's keys in the schema's
     order, then that engine's declared own keys; its summary and its
     result's ``stats`` likewise: the key sets of the parent tree."""
-    from raft_tpu.checker.bfs import BFSChecker
     from raft_tpu.obs.events import PROCESS_KEYS
 
     tel = Telemetry()
     stats = None
-    if engine == "device":
-        stats = _device().run(max_depth=4, telemetry=tel).stats
-    elif engine == "host":
-        stats = BFSChecker(
-            cached_model(SMALL), invariants=INVS, symmetry=True, chunk=256,
-        ).run(max_depth=4, telemetry=tel).stats
-    elif engine == "sharded":
-        stats = _sharded(2, frontier_cap=2048, seen_cap=1 << 13).run(
-            max_depth=4, telemetry=tel).stats
-    else:
+    if engine == "host_fleet":
         eng, names = _packed_fleet()
         eng.run_fleet(job_names=names, max_depth=4, telemetry=tel)
+    else:
+        stats = _run_of(engine, telemetry=tel).stats
     tel.close()
 
     rows = tel.wave_events()
@@ -352,12 +362,48 @@ def test_rows_and_summary_have_one_author(engine):
     else:
         assert len(summaries) == 1
         base = {*PROCESS_KEYS, *_RUN_LOADED, "init_s", "waves_s", "finish_s",
-                "frontier_peak_rows", "restart_fired"}
+                "frontier_peak_rows", "restart_fired", *HBM_KEYS}
         assert set(stats) == base | STATS_OWN[engine]
         # stats is the summary's own dict, but for the sharded engine's
         # fleet aggregates
         assert all(summaries[0][k] == v for k, v in stats.items()
                    if k in summaries[0])
+
+
+@pytest.mark.parametrize("engine", ["device", "host", "sharded"])
+def test_the_engines_say_device_memory_alike_telemetry_or_not(engine):
+    """Rows, ``stats`` and the summary of the three engines carry the
+    same ``hbm_*`` keys (``checker/engine.py``'s builders, from the
+    run's ``MemWatch``), and a bare run carries what a telemetry run
+    carries. On the CPU the allocator reports nothing: every measured
+    key is null and the plan stands."""
+    tel = Telemetry()
+    stats = _run_of(engine, telemetry=tel).stats
+    tel.close()
+    bare = _run_of(engine, collect_metrics=True)  # NULL_TELEMETRY
+
+    (summary,) = [e for e in tel.events if e["event"] == "summary"]
+    for got in (stats, bare.stats, summary):
+        assert [k for k in got if k.startswith("hbm_")] == list(HBM_KEYS)
+    assert all(summary[k] == stats[k] for k in HBM_KEYS)
+    for row in (*tel.wave_events(), *bare.metrics):
+        assert [k for k in row if k.startswith("hbm_")] == list(HBM_ROW_KEYS)
+        assert row["hbm_bytes"] is None and row["hbm_peak_rise"] is None
+        # the plan's, on the CPU (the host engine's few KB round to 0)
+        assert (engine == "host" or row["hbm_frac"] > 0) and (
+            row["hbm_frac"] == round(row["hbm_frac"], 6) < 1)
+    assert [r["hbm_frac"] for r in bare.metrics] == [
+        r["hbm_frac"] for r in tel.wave_events()]
+    for got in (stats, bare.stats):
+        assert all(got[k] is None for k in (
+            *HBM_MEASURED_KEYS, "hbm_peak_frac", "hbm_live_frac",
+            "hbm_plan_gap_frac"))
+        assert got["hbm_plan_bytes"] == bare.stats["hbm_plan_bytes"] > 0
+        assert got["hbm_plan_frac"] == (
+            got["hbm_plan_bytes"] / got["hbm_budget_bytes"])
+    # the widest row's plan is the run's
+    assert max(r["hbm_frac"] for r in bare.metrics) == pytest.approx(
+        bare.stats["hbm_plan_frac"], abs=1e-6)
 
 
 def test_format_count():
@@ -534,9 +580,12 @@ def test_telemetry_run_carries_phase_split_and_watermarks(tmp_path):
             assert isinstance(w[k], (int, float)), k
             assert w[k] >= 0, k
 
+    # the CPU's allocator reports nothing: the plan stands, the
+    # measured keys are null
     s = tel.last_summary
-    assert s["hbm_peak_bytes"] > 0
-    assert 0 < s["hbm_peak_frac"] < 1
+    assert s["hbm_plan_bytes"] > 0
+    assert 0 < s["hbm_plan_frac"] < 1
+    assert s["hbm_peak_bytes"] is None and s["hbm_peak_frac"] is None
 
 
 def test_progress_renderer_observatory_gauges():
@@ -546,6 +595,9 @@ def test_progress_renderer_observatory_gauges():
               hbm_frac=0.5)
     line = ProgressRenderer().render_wave(ev)
     assert line.endswith("dup 50%, hbm 50%")
+    # no reading of the allocator (the CPU): the fraction is the plan's
+    ev.update(hbm_bytes=None)
+    assert ProgressRenderer().render_wave(ev).endswith("dup 50%, plan 50%")
     # a null/zero gauge leaves the pinned base line untouched
     ev.update(hbm_frac=0)
     assert ProgressRenderer().render_wave(ev).endswith("dup 50%")
@@ -641,11 +693,16 @@ def _observatory_stream(tmp_path, name="obs.jsonl"):
     c = MetricsCollector(path=str(path))
     c.manifest(_fields(MANIFEST_KEYS, ident="x/hashv=5"))
     c.wave(_wave(0, 0.5))
-    c.event("memwatch", wave=1, depth=0, total_bytes=100, peak_bytes=100,
-            budget_bytes=1000, frac=0.1, breakdown={"frontier": 60, "seen": 40})
+    c.event("memwatch", wave=1, depth=0, bytes=120, peak_bytes=300,
+            peak_rise=300, budget_bytes=1000, frac=0.12, plan_bytes=100,
+            plan_peak_bytes=100, plan_frac=0.1,
+            breakdown={"frontier": 60, "seen": 40})
     c.wave(_wave(1, 0.4))
-    c.event("memwatch", wave=2, depth=1, total_bytes=150, peak_bytes=200,
-            budget_bytes=1000, frac=0.2, breakdown={"frontier": 150})
+    # a CPU's: no reading of the allocator, the plan alone
+    c.event("memwatch", wave=2, depth=1, bytes=None, peak_bytes=None,
+            peak_rise=None, budget_bytes=1000, frac=0.15, plan_bytes=150,
+            plan_peak_bytes=200, plan_frac=0.15,
+            breakdown={"frontier": 150})
     c.summary(_fields(SUMMARY_KEYS, exit_cause="exhausted"))
     c.close()
     return path
@@ -674,14 +731,50 @@ def test_observatory_fixture_nonmonotone_peak(tmp_path):
     from scripts.check_metrics_schema import validate_file
 
     good = _observatory_stream(tmp_path)
-    # second memwatch peak drops below the first: 200 -> 50
-    bad = _perturb(good, tmp_path, '"peak_bytes": 200', '"peak_bytes": 50',
-                   "bad_peak.jsonl")
-    # keep total <= peak so ONLY the monotonicity rule fires
-    bad.write_text(bad.read_text().replace('"total_bytes": 150',
-                                           '"total_bytes": 50'))
+    # second memwatch plan peak drops below the first: 200 -> 50
+    bad = _perturb(good, tmp_path, '"plan_peak_bytes": 200',
+                   '"plan_peak_bytes": 50', "bad_peak.jsonl")
+    # keep plan_bytes <= its peak so ONLY the monotonicity rule fires
+    bad.write_text(bad.read_text().replace('"plan_bytes": 150',
+                                           '"plan_bytes": 50'))
     _, problems = validate_file(str(bad))
     assert any("monotone" in p for p in problems), problems
+
+
+@pytest.mark.parametrize("etype, change, says", [
+    ("wave", {"hbm_bytes": -1}, "hbm_bytes"),
+    ("wave", {"hbm_peak_rise": 1.5}, "hbm_peak_rise"),
+    ("wave", {"hbm_frac": -0.1}, "hbm_frac"),
+    ("memwatch", {"bytes": 400, "peak_bytes": 300}, "exceeds peak_bytes"),
+    ("memwatch", {"plan_bytes": 101}, "exceeds plan_peak_bytes"),
+    ("memwatch", {"peak_rise": True}, "peak_rise"),
+    ("memwatch", {"plan_peak_bytes": None}, "plan_peak_bytes"),
+    ("summary", {"hbm_live_bytes": 9, "hbm_peak_bytes": 8},
+     "hbm_live_bytes 9 exceeds hbm_peak_bytes 8"),
+    ("summary", {"hbm_init_rise": -4}, "hbm_init_rise"),
+    ("summary", {"hbm_plan_bytes": "many"}, "hbm_plan_bytes"),
+])
+def test_device_memory_schema_rules(etype, change, says):
+    """The ``hbm_*`` keys of a row and a summary and the keys of a
+    ``memwatch`` event: byte counts are non-negative ints, the measured
+    ones or null; what a run held is under the allocator's peak."""
+    from raft_tpu.obs.events import MEMWATCH_KEYS, validate_event
+
+    good = {
+        "wave": {**dict.fromkeys(WAVE_KEYS, 0), "hbm_bytes": None,
+                 "hbm_peak_rise": None},
+        "memwatch": {**dict.fromkeys(MEMWATCH_KEYS, 0), "bytes": 120,
+                     "peak_bytes": 300, "plan_bytes": 100,
+                     "plan_peak_bytes": 100, "breakdown": {"seen": 100}},
+        "summary": {**dict.fromkeys(SUMMARY_KEYS, 0),
+                    "exit_cause": "exhausted",
+                    **dict.fromkeys(HBM_MEASURED_KEYS),
+                    "hbm_plan_bytes": 7, "hbm_budget_bytes": 100},
+    }[etype]
+    good["event"] = etype
+    assert validate_event(good) == []
+    (problem,) = validate_event({**good, **change})
+    assert says in problem
 
 
 # ------------------------------------------------------------ bench gate
@@ -867,7 +960,7 @@ def test_cli_metrics_smoke_and_bench_gate(tmp_path, capsys):
     with open(mpath) as fh:
         summ = json.loads(fh.read().strip().splitlines()[-1])
     assert summ["event"] == "summary"
-    assert summ["hbm_peak_bytes"] > 0
+    assert summ["hbm_plan_bytes"] > 0
 
     golden = Path(__file__).parent / "golden" / "raft3_depth4_gate.json"
     assert gate_main([str(mpath), str(golden)]) == 0
