@@ -362,17 +362,21 @@ class DeviceBFS(FleetQueue):
     @stage("canon")
     def _st_canon(self, flatc, selv):
         """Stage 3: canonical fingerprints on compacted lanes only, one
-        tiered canon per distinct raw view of the chunk (duplicate
-        successors skip it; invalid lanes come back masked to U64_MAX).
+        tiered canon per distinct raw view of the chunk, on its first
+        lane (duplicate successors skip it; invalid lanes and in-chunk
+        duplicates of a lower lane come back masked to U64_MAX).
         ``canon_n`` is i32[3]: the chunk's in-chunk duplicate lanes and
         the lanes its canon routed to tier 3's local and full buckets."""
         return canon_chunk(self.canon, flatc, selv)
 
     @stage("dedup")
     def _st_dedup(self, fps, occ, wave_new, ncount, seen_real, *runs):
-        """Stage 4: new = not in the seen run, not among the ``ncount``
+        """Stage 4: ``fps`` is the canon stage's, invalid lanes and
+        in-chunk duplicates of a lower lane masked to U64_MAX, which is
+        never new. new = not in the seen run, not among the ``ncount``
         fingerprints earlier chunks of this wave appended to
-        ``wave_new``, and first occurrence in the chunk (lowest lane),
+        ``wave_new``, and first occurrence in the chunk (lowest lane:
+        two raw views of one canonical class both reach this stage),
         by one merged sort of the chunk with what the seen run and
         ``wave_new`` hold (util.first_new): against a seen run at the
         sort's floor, the run whole and the smallest static prefix of

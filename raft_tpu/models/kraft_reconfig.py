@@ -2149,14 +2149,17 @@ class SlotCanonicalizer:
 
     def fingerprints_dedup(self, states, valid):
         """The engines' canon stage (``ops.symmetry.canon_chunk``):
-        ``(fps, n_dup, tiers)`` with invalid lanes masked to U64_MAX. The
-        permutations run once per distinct raw view of the batch
+        ``(fps, n_dup, tiers)`` with invalid lanes and in-chunk
+        duplicates of a lower lane masked to U64_MAX. The permutations
+        run once per distinct raw view of the batch
         (``fingerprints_by_raw_view``, the in-chunk dedup
-        ``Canonicalizer`` uses): ``n_dup`` is the valid lanes that shared
-        an earlier lane's view and skipped them, and there are no tiers,
-        so ``tiers`` is [0, representatives] (``canon_tier3_full``: the
-        full table wherever it runs). With symmetry off one permutation
-        runs on every lane and nothing is counted."""
+        ``Canonicalizer`` uses), whose first lane carries the
+        fingerprint: ``n_dup`` is the valid lanes that shared a lower
+        lane's view, skipped them and come back masked, and there are no
+        tiers, so ``tiers`` is [0, representatives]
+        (``canon_tier3_full``: the full table wherever it runs). With
+        symmetry off one permutation runs on every lane, nothing is
+        counted and only the invalid lanes are masked."""
         if not self.symmetry:
             fps = jnp.where(valid, self._fingerprints(states), U64_MAX)
             zero = jnp.sum(valid).astype(jnp.int32) * 0  # typed as the lanes

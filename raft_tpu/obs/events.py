@@ -102,8 +102,9 @@ TIMELINE_STAGES = (
 # that grew a buffer) — and compiles/compile_s: programs the iteration
 # loaded, compiled or read from the persistent cache, and the seconds
 # that took (obs/compiles.py).
-# canon_dup_lanes: valid lanes that shared an earlier lane's raw view in
-# their chunk-step and took its fingerprint instead of the permutations
+# canon_dup_lanes: valid lanes that shared a lower lane's raw view in
+# their chunk-step, skipped the permutations and left the canon stage
+# masked, as an invalid lane does: the lower lane carries the fingerprint
 # (ops/symmetry.py fingerprints_by_raw_view, under either canon), summed
 # over the wave's chunk-steps; canon_dup_rate: the same over generated.
 # canon_tier3_local / canon_tier3_full: lanes the wave's canon routed to

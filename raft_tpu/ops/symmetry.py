@@ -316,11 +316,13 @@ def _tie_pattern_tables(S: int):
 
 def canon_chunk(canon, states, valid):
     """The engines' canon stage on one chunk's compacted lanes:
-    ``(fps, canon_n)`` with invalid lanes masked to U64_MAX and
-    ``canon_n`` i32[3] = [in-chunk duplicate lanes, tier-3 local lanes,
-    tier-3 full lanes], as the canon's ``fingerprints_dedup`` counts
-    them (``Canonicalizer``'s, or a ``make_canonicalizer`` model's
-    own)."""
+    ``(fps, canon_n)`` with invalid lanes and in-chunk duplicates of a
+    lower lane masked to U64_MAX (the dedup stage and the sharded
+    engine's route read the mask as "not new", which such a lane is)
+    and ``canon_n`` i32[3] = [in-chunk duplicate lanes, tier-3 local
+    lanes, tier-3 full lanes], as the canon's ``fingerprints_dedup``
+    counts them (``Canonicalizer``'s, or a ``make_canonicalizer``
+    model's own)."""
     fps, n_dup, tiers = canon.fingerprints_dedup(states, valid)
     return fps, jnp.concatenate([n_dup[None], tiers])
 
@@ -1287,11 +1289,15 @@ class Canonicalizer:
 
     def fingerprints_dedup(self, states, valid):
         """Canonical fingerprints of a [B, W] state batch, one tiered
-        canon per distinct raw view (``fingerprints_by_raw_view``).
-        Returns ``(fps, n_dup, tiers)`` with invalid lanes masked to
-        U64_MAX; ``tiers`` i32[2] is the representatives that took
-        ``[tier3_local, tier3_full]`` (``_canon_view``): together at
-        most the valid lanes less ``n_dup``."""
+        canon per distinct raw view, on the view's first lane
+        (``fingerprints_by_raw_view``). Returns ``(fps, n_dup, tiers)``
+        with invalid lanes and in-chunk duplicates of a lower lane
+        masked to U64_MAX (``n_dup`` of the latter); ``tiers`` i32[2] is
+        the representatives that took ``[tier3_local, tier3_full]``
+        (``_canon_view``): together at most the valid lanes less
+        ``n_dup``. Without SYMMETRY the raw key is the fingerprint,
+        nothing is counted and only the invalid lanes are masked.
+        ``fingerprints`` is the plain entry: every lane its canon."""
         view = states[:, : self.VL]
         if not self.symmetry:
             zero, no_tiers = _zero_counts(view)
@@ -1304,7 +1310,8 @@ class Canonicalizer:
             min(B, max(64, B // 4)))
 
 
-# the `inchunk` scope is the raw hash, the sorts and the fill; it is
+# the `inchunk` scope is the raw hash, the three sorts, the
+# representatives' row gather and their fingerprints' buffer; it is
 # opened piecewise so that the scopes a canon opens in the loop's body
 # stay its siblings under `canon`, not its children
 _inchunk = functools.partial(jax.named_scope, "inchunk")
@@ -1319,53 +1326,67 @@ def _zero_counts(rows):
 
 
 def fingerprints_by_raw_view(rows, valid, raw_key, canon_block, block):
-    """The in-chunk dedup both canons share: canonical fingerprints of a
-    [B, L] batch of rows, the canon run once per distinct raw key.
+    """The in-chunk dedup both canons share: the canon of a [B, L] batch
+    of rows run once per distinct raw key, its fingerprint on the FIRST
+    lane of each distinct raw key and U64_MAX on every other lane.
     ``raw_key(rows)`` is u64 [B], equal on two lanes only if the canon
     would be (a canon's hash of what it reads, unpermuted);
     ``canon_block(block_rows, real)`` canonicalizes a [CB, L] block of
     rows, ``real`` marking the lanes that hold a representative, and
     returns ``(fps u64 [CB], counts i32 [2])``; ``block`` is CB, the
     block's lanes, the caller's function of the shape. Returns ``(fps,
-    n_dup, counts)`` with invalid lanes masked to U64_MAX; ``n_dup`` is
-    the valid lanes that shared an earlier lane's raw key and so
-    skipped the permutations, ``counts`` the blocks' sum.
+    n_dup, counts)``: ``fps`` in lane order, invalid lanes and in-chunk
+    duplicates of a lower lane masked to U64_MAX; ``n_dup`` the valid
+    lanes that shared a lower lane's raw key and so skipped the
+    permutations, ``counts`` the blocks' sum (sums over the
+    representatives, whatever order the blocks take them in).
 
-    Sorts alone, no per-lane write: the raw keys are sorted (equal
-    views become segments — duplicate successors inside a chunk are
-    common), the segment heads drain through the canon in fixed-size
-    blocks of an adaptive-trip ``lax.while_loop`` (a chunk of one view
-    pays one block, a chunk of distinct views one canon a lane), the
-    k-th head's fingerprint lands in slot k of a dense buffer (the
-    heads leave ``argsort`` in rising order), each sorted lane reads
-    its segment's slot, and one sort keyed on the lanes' original
-    indices brings the result back to lane order. Deduplication never
-    changes a value: a lane's fingerprint is the canon of its own raw
-    view."""
+    A duplicate comes back masked, not filled, because every consumer
+    keeps the lowest lane of a fingerprint alone and reads U64_MAX as
+    "not new" (``util.first_new``, the sharded engine's route): the
+    lowest lane of a canonical fingerprint is the lowest lane of its
+    own raw key, so ``first_new`` marks the same lanes new on this as
+    on the canon of every lane, and a lane that is new carries the
+    canon of its own raw view. Deduplication never changes a value
+    that survives.
+
+    Sorts and the representatives' row gather alone, no 1-D gather or
+    scatter by a traced index (each a serial pass of 7 ns a lane on the
+    chip where a sort of one int32 key is under 1: PERF.md section 6,
+    PR 54): the raw keys are sorted (equal views become segments, whose
+    head is their lowest lane: ``sort_u64_with_idx`` breaks ties on the
+    lane), one sort of one int32 key lays the lanes out representatives
+    first, each half in rising lane order (a head's key is its lane,
+    another lane's B + its lane), the representatives drain through the
+    canon in fixed-size blocks of an adaptive-trip ``lax.while_loop`` (a
+    chunk of one view pays one block, a chunk of distinct views one
+    canon a lane), block i's fingerprints land in slots [i * CB, (i + 1)
+    * CB) of a dense buffer, which is then already in the order of that
+    key, and one sort keyed on the lanes brings it back to lane order
+    with the padding past the representatives on every other lane."""
     B, CB = rows.shape[0], block
+    NB = -(-B // CB)
     _zero, no_counts = _zero_counts(rows)
     with _inchunk():
         raw = raw_key(rows)
         # a valid raw key equal to the sentinel (p = 2^-64) sorts
         # with the padding and comes back masked, as an invalid lane
         sraw, order = sort_u64_with_idx(jnp.where(valid, raw, U64_MAX))
-        real_s = ne_u64(sraw, U64_MAX)
-        head = real_s & jnp.concatenate(
+        head = ne_u64(sraw, U64_MAX) & jnp.concatenate(
             [jnp.ones((1,), bool), ne_u64(sraw[1:], sraw[:-1])]
         )
         n_rep = jnp.sum(head)
         n_dup = (jnp.sum(valid) - n_rep).astype(jnp.int32)
-        # sorted lane -> its segment's representative, counted from 0
-        rank = jnp.maximum(jnp.cumsum(head.astype(jnp.int32)) - 1, 0)
-        # head positions first, in rising order: representative k
-        psel = jnp.argsort(~head, stable=True).astype(jnp.int32)
-        psel = jnp.concatenate([psel, jnp.full((CB,), B, jnp.int32)])
-        orderp = jnp.concatenate([order, jnp.full((1,), B, jnp.int32)])
+        # slot k < n_rep: the lane of the k-th representative by lane;
+        # the slots after them: B + the lane of each other lane
+        perm = lax.sort(jnp.where(head, order, B + order))
+        # a whole number of blocks: dynamic_slice and
+        # dynamic_update_slice clamp a start that would run off the end
+        permp = jnp.concatenate(
+            [perm, jnp.full((NB * CB - B,), B, jnp.int32)])
         rowsp = jnp.concatenate(
             [rows, jnp.zeros((1, rows.shape[1]), rows.dtype)])
-        # a whole number of blocks: dynamic_update_slice clamps a
-        # start that would run off the end
-        canon_rep = jnp.full((-(-B // CB) * CB,), U64_MAX, jnp.uint64)
+        canon_rep = jnp.full((NB * CB,), U64_MAX, jnp.uint64)
         jcb = jnp.arange(CB, dtype=jnp.int32)
 
     def cond(c):
@@ -1374,9 +1395,9 @@ def fingerprints_by_raw_view(rows, valid, raw_key, canon_block, block):
     def body(c):
         i, acc, counts = c
         with _inchunk():
-            pos = lax.dynamic_slice(psel, (i * CB,), (CB,))
             real = i * CB + jcb < n_rep
-            heads = rowsp[orderp[jnp.where(real, pos, B)]]
+            lane = lax.dynamic_slice(permp, (i * CB,), (CB,))
+            heads = rowsp[jnp.where(real, lane, B)]
         cfp, n = canon_block(heads, real)
         with _inchunk():
             acc = lax.dynamic_update_slice(acc, cfp, (i * CB,))
@@ -1386,9 +1407,12 @@ def fingerprints_by_raw_view(rows, valid, raw_key, canon_block, block):
         cond, body, (jnp.asarray(0, jnp.int32), canon_rep, no_counts)
     )
     with _inchunk():
-        fh, fl = split_u64(jnp.where(real_s, canon_rep[rank], U64_MAX))
-        # `order` is a permutation of the lanes: sorting by it alone
-        # is the inverse permutation (util.first_new's return sort)
-        _, fh, fl = lax.sort((order, fh, fl), num_keys=1)
+        slot = jnp.arange(B, dtype=jnp.int32)
+        fh, fl = split_u64(
+            jnp.where(slot < n_rep, canon_rep[:B], U64_MAX))
+        # the keys' lanes are a permutation of the lanes: sorting by
+        # them alone is its inverse (util.first_new's return sort)
+        _, fh, fl = lax.sort(
+            (jnp.where(perm < B, perm, perm - B), fh, fl), num_keys=1)
         fps = join_u64(fh, fl)
     return fps, n_dup, counts

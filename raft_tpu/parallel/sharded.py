@@ -395,9 +395,12 @@ class ShardedBFS(FleetQueue):
         per-action [enabled, fired] tallied on the generating chip
         ([1,2] zeros when the model has no action ranks) and
         ``pre_stats`` [7] i64 = [n_gen, terminal, pre-exchange ovf bits
-        (1=msg 2=valid 4=route), routed lanes, then the chunk's canon
-        counts (ops/symmetry.canon_chunk): in-chunk duplicate lanes,
-        tier-3 local lanes, tier-3 full lanes]."""
+        (1=msg 2=valid 4=route), routed lanes (the first lane of each
+        distinct raw view of the chunk: an in-chunk duplicate leaves
+        the canon masked and is dropped here with the invalid lanes),
+        then the chunk's canon counts (ops/symmetry.canon_chunk):
+        in-chunk duplicate lanes, tier-3 local lanes, tier-3 full
+        lanes]."""
         model, D, A, W = self.model, self.D, self.A, self.W
         C, VC, RC = self.chunk, self.VC, self.RC
         K = self.n_actions
@@ -445,7 +448,8 @@ class ShardedBFS(FleetQueue):
             t32 = np.uint32((1 << 32) % D)
             owner = (((fhi % np.uint32(D)) * t32 + flo % np.uint32(D))
                      % np.uint32(D)).astype(jnp.int32)
-            owner = jnp.where(eq_u64(fps, U64_MAX), D, owner)  # invalid -> drop
+            # invalid, or an in-chunk duplicate of a lower lane -> drop
+            owner = jnp.where(eq_u64(fps, U64_MAX), D, owner)
             order = jnp.argsort(owner, stable=True)
             owner_s = owner[order]
             fps_s = fps[order]

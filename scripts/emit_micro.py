@@ -26,11 +26,25 @@ one int32 key for the lanes and another for their ``sel``; and
 ``payload_sort``, one sort of the lanes' key carrying ``sel``. The
 cheaper sort form is the one in ``DeviceBFS._st_finish``.
 
+``--fill N [N ...]`` times the same way what the canon stage's in-chunk
+dedup (``ops/symmetry.py::fingerprints_by_raw_view``) does with the N
+raw-sorted lanes of a chunk-step besides the sorts it always ran:
+``fill_gather``, the u64 gather ``canon_rep[rank]`` (with ``rank``'s
+``cumsum``) that handed every lane its representative's fingerprint
+until PR 54; ``payload_sort``, ``argsort(~head, stable=True)``, the
+sort with a payload that laid the representatives out in raw order until
+then; ``perm_sort``, the one sort of one int32 key that lays them out in
+lane order now, after which nothing is filled; and
+``perm_sort_unstable``, the same sort with ``is_stable=False`` (its keys
+are distinct, so the answer is the same, and the compiler carries no
+``iota`` beside the key to break ties by).
+
 Usage:
   python scripts/emit_micro.py [--vc 32768 65536] [--fcap 262144 4194304]
                                [--w 64] [--reps 5] [--density 0.5]
                                [--platform cpu]
   python scripts/emit_micro.py --journal 16384 32768 65536
+  python scripts/emit_micro.py --fill 16384 32768 65536
 
 Writes chiprun_out/emit_micro.json (device provenance + one row per
 (VC, FCAP) cell), where a chip run's results come back.
@@ -64,6 +78,26 @@ def _time_donated(fn, make_args, reps):
         ts.append(time.perf_counter() - t0)
     ts.sort()
     return ts[len(ts) // 2]
+
+
+def _ns_per_lane(form, lanes, reps):
+    """(ns a lane, ms) of one ``form(i)``: K of them inside one program
+    at K = 8 and K = 72, every output summed into the carry so nothing
+    is dead, the difference over its 64 repetitions."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    def body(i, acc):
+        return acc + sum(jnp.sum(x).astype(jnp.int32) for x in form(i))
+
+    fn = jax.jit(lambda k, a: lax.fori_loop(0, k, body, a))
+    jax.block_until_ready(fn(jnp.int32(1), jnp.int32(0)))  # compile
+    ts = {K: _time_donated(
+        fn, lambda K=K: (jnp.int32(K), jnp.int32(0)), reps)
+        for K in (8, 72)}
+    return (round((ts[72] - ts[8]) / 64 / lanes * 1e9, 3),
+            round((ts[72] - ts[8]) / 64 * 1e3, 4))
 
 
 def bench_cell(vc, fcap, w, reps, density, rng):
@@ -184,17 +218,8 @@ def bench_journal(lanes, reps):
              "payload_sort": payload_sort, "rank_gather": rank_gather}
     row = {"lanes": N}
     for name, form in forms.items():
-        def body(i, acc, form=form):
-            return acc + sum(jnp.sum(x) for x in form(i))
-
-        fn = jax.jit(lambda k, a, body=body: lax.fori_loop(0, k, body, a))
-        jax.block_until_ready(fn(jnp.int32(1), jnp.int32(0)))  # compile
-        ts = {K: _time_donated(
-            fn, lambda K=K: (jnp.int32(K), jnp.int32(0)), reps)
-            for K in (8, 72)}
-        row[f"{name}_ns_per_lane"] = round(
-            (ts[72] - ts[8]) / 64 / N * 1e9, 3)
-        row[f"{name}_ms"] = round((ts[72] - ts[8]) / 64 * 1e3, 4)
+        row[f"{name}_ns_per_lane"], row[f"{name}_ms"] = _ns_per_lane(
+            form, N, reps)
     # equal answers, outside the timed loop
     want = [jax.device_get(x) for x in jax.jit(gather)(jnp.int32(3))]
     row["parity"] = all(
@@ -202,6 +227,79 @@ def bench_journal(lanes, reps):
             jax.jit(form)(jnp.int32(3)), want))
         for form in (two_sorts, payload_sort))
     return row
+
+
+def bench_fill(lanes, reps):
+    """ns a lane of what the in-chunk dedup does with ``lanes``
+    raw-sorted lanes, by form (module docstring). ``order`` is a
+    permutation of the lanes and two thirds of the sorted lanes head a
+    segment, both re-drawn from the loop's counter; the buffer of the
+    representatives' fingerprints is drawn once."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    from raft_tpu.ops.hashing import U64_MAX, join_u64, split_u64
+
+    N = lanes
+    assert N & (N - 1) == 0, "a power of two: the permutation is affine"
+    lane = jnp.arange(N, dtype=jnp.int32)
+    canon_rep = join_u64(
+        (lane * 40503).astype(jnp.uint32), (lane * 25173).astype(jnp.uint32))
+
+    def draw(i):
+        order = (lane * 5 + i * 7919) % N  # 5 is odd: a permutation
+        head = ((lane * 7 + i * 13) % 3) != 0
+        return order, head
+
+    def fill_gather(i):
+        _order, head = draw(i)
+        rank = jnp.maximum(jnp.cumsum(head.astype(jnp.int32)) - 1, 0)
+        return split_u64(jnp.where(head, canon_rep[rank], U64_MAX))
+
+    def payload_sort(i):
+        _order, head = draw(i)
+        return (jnp.argsort(~head, stable=True).astype(jnp.int32),)
+
+    def perm_sort(i, stable=True):
+        order, head = draw(i)
+        return (lax.sort(jnp.where(head, order, N + order),
+                         is_stable=stable),)
+
+    def perm_sort_unstable(i):
+        return perm_sort(i, stable=False)
+
+    row = {"lanes": N}
+    for name, form in (("fill_gather", fill_gather),
+                       ("payload_sort", payload_sort),
+                       ("perm_sort", perm_sort),
+                       ("perm_sort_unstable", perm_sort_unstable)):
+        row[f"{name}_ns_per_lane"], row[f"{name}_ms"] = _ns_per_lane(
+            form, N, reps)
+    # the lay-out is the one the engines rely on: the representatives'
+    # lanes first and rising, then B + the rest's, rising
+    order, head = (jax.device_get(x) for x in draw(jnp.int32(3)))
+    perm = jax.device_get(jax.jit(perm_sort)(jnp.int32(3))[0])
+    n_rep = int(head.sum())
+    row["parity"] = bool(
+        (perm[:n_rep] == sorted(order[head])).all()
+        and (perm[n_rep:] == [N + x for x in sorted(order[~head])]).all()
+        and (perm == jax.device_get(
+            jax.jit(perm_sort_unstable)(jnp.int32(3))[0])).all())
+    return row
+
+
+def _write_rows(name, rows):
+    import jax
+
+    for row in rows:
+        print(json.dumps(row), flush=True)
+    path = os.path.join(ROOT, "chiprun_out", name)
+    os.makedirs(os.path.dirname(path), exist_ok=True)
+    with open(path, "w") as f:
+        json.dump({"device": str(jax.devices()[0]), "rows": rows}, f,
+                  indent=1)
+    print(f"wrote {path}")
 
 
 def main():
@@ -218,6 +316,11 @@ def main():
                     help="time the survivors' lanes and journal blocks "
                          "of LANES compacted lanes by form, and nothing "
                          "else")
+    ap.add_argument("--fill", type=int, nargs="+", default=None,
+                    metavar="LANES",
+                    help="time the in-chunk dedup's fill gather, the "
+                         "payload sort and the one-key sort of LANES "
+                         "raw-sorted lanes, and nothing else")
     args = ap.parse_args()
 
     import jax
@@ -225,16 +328,12 @@ def main():
     if args.platform:
         jax.config.update("jax_platforms", args.platform)
     if args.journal:
-        rows = [bench_journal(n, args.reps) for n in args.journal]
-        for row in rows:
-            print(json.dumps(row), flush=True)
-        path = os.path.join(ROOT, "chiprun_out", "emit_journal.json")
-        os.makedirs(os.path.dirname(path), exist_ok=True)
-        with open(path, "w") as f:
-            json.dump({"device": str(jax.devices()[0]), "rows": rows}, f,
-                      indent=1)
-        print(f"wrote {path}")
-        return
+        return _write_rows(
+            "emit_journal.json",
+            [bench_journal(n, args.reps) for n in args.journal])
+    if args.fill:
+        return _write_rows(
+            "canon_fill.json", [bench_fill(n, args.reps) for n in args.fill])
     import numpy as np
 
     rng = np.random.default_rng(0)

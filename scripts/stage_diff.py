@@ -16,12 +16,20 @@ action and state field that differ:
                  sparse apply, as the wave program runs them
   canon          raw and canonical fingerprints and the engines' canon
                  (in-chunk dedup, then the tiers), on the CPU's rows of
-                 the sparse expand (so a fault upstream does not compound)
+                 the sparse expand (so a fault upstream does not compound).
+                 The engines' canon hands the dedup stage a fingerprint
+                 on the first lane of each distinct raw view alone (PR 54:
+                 a duplicate of a lower lane is never new, so it comes
+                 back masked like an invalid lane and nothing is computed
+                 for it), so it is held to the plain per-lane entry on
+                 those lanes and to U64_MAX on every other, on each side
+                 (``canon_dedup_off_rule``: the lanes that break that)
   invariants     each invariant kernel of the cfg, on the same rows
   wave           the fused wave program on the last level as its frontier,
                  the earlier levels in the seen run: stats, violations,
                  emitted rows, journal, coverage, the wave's buffer of
-                 new fingerprints
+                 new fingerprints: lane for lane, since which lane is new,
+                 and what it carries, is what the masking must not move
 
 One command does both sides: before this process imports JAX it starts
 itself as a child held to the CPU backend (``JAX_PLATFORMS=cpu``) that
@@ -177,7 +185,13 @@ def stages(args, ref):
     out["canon_raw"] = np.asarray(canon.raw_fingerprints(flatc))
     out["canon_fp"] = np.where(selv, np.asarray(canon.fingerprints(flatc)), 0)
     fps, n_dup, tiers = jax.jit(canon.fingerprints_dedup)(flatc, selv)
+    lanes = np.flatnonzero(selv)
+    _u, first = np.unique(out["canon_raw"][lanes], return_index=True)
+    head = np.zeros(len(selv), bool)
+    head[lanes[first]] = True  # the first valid lane of each raw view
+    rule = np.where(head, out["canon_fp"], np.uint64(U64_MAX))
     out.update(canon_fp_dedup=np.asarray(fps),
+               canon_dedup_off_rule=np.asarray(fps) != rule,
                canon_tiers=np.asarray([n_dup, *tiers]))
     for name in setup.invariants:
         holds = np.asarray(jax.jit(model.invariants[name])(flatc))
@@ -246,6 +260,8 @@ def report(out, ref, model):
             bad = True
             continue
         diff = got != want
+        if name == "canon_dedup_off_rule":  # a lane off it on either side
+            diff = got | want
         print(f"{name:<32} {int(diff.sum())} of {diff.size} differ")
         if not diff.any():
             continue

@@ -128,6 +128,47 @@ def jaxpr_digest(closed):
     return n, digest.hexdigest()[:16]
 
 
+def indexed_ops(lowered):
+    """[(kind, operand dims, name stack)] of every gather and scatter in
+    a lowered text with debug info: ``kind`` is "gather" or "scatter",
+    the dims are those of the array read from or written into (one
+    entry: a 1-D gather or scatter, a serial pass over its lanes on the
+    chip when the index is traced), the name stack the op's own (bare,
+    "gather", inside a function the lowering outlined)."""
+    import re
+
+    stacks = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', lowered, re.M))
+    found = []
+    for kind in ("gather", "scatter"):
+        # a scatter's signature follows its update region's closing brace
+        for dims, loc in re.findall(
+                r'"stablehlo\.%s"\([^\n]*?(?:\n[^\n]*?)*?: \(tensor<([^>]*)>'
+                r'[^\n]*loc\((#loc\d+)\)' % kind, lowered):
+            found.append((kind, dims.split("x")[:-1], stacks.get(loc, "")))
+    return found
+
+
+def first_lane_masked(canon, rows, valid):
+    """What a canon's ``fingerprints_dedup`` returns for ``rows`` under
+    ``valid``, worked out on the host from the plain per-lane entry:
+    ``(fps, n_dup)``, the lane's own ``canon.fingerprints`` on the first
+    valid lane of every distinct raw view, U64_MAX on every other lane,
+    and the valid lanes that are not such a first lane."""
+    import numpy as np
+
+    from raft_tpu.ops.hashing import U64_MAX
+
+    valid = np.asarray(valid, bool)
+    raw = np.asarray(canon.raw_fingerprints(rows))
+    lanes = np.flatnonzero(valid)
+    _u, first = np.unique(raw[lanes], return_index=True)
+    head = np.zeros(len(raw), bool)
+    head[lanes[first]] = True
+    fps = np.where(head, np.asarray(canon.fingerprints(rows)),
+                   np.uint64(U64_MAX))
+    return fps, int(valid.sum() - head.sum())
+
+
 def lower_dedup_canon(model):
     """Lowered text, with debug info, of the engines' canon (in-chunk
     dedup, then the tiers) of ``model`` over a 256-lane batch; nothing

@@ -23,7 +23,17 @@ such sort with ``sel`` its payload (eleven equations fewer in every
 wave program, seven in every chunk program); every row, journal entry,
 fingerprint, count and coverage cell bit-equal to PR 51's tree
 (``tests/test_emit_sorts.py``'s ``_reference_*``; ``stage_diff.py``
-against the parent). Nothing is compiled or run.
+against the parent). PR 54 re-pinned them once more, on purpose: the
+canon stage's in-chunk dedup (``ops/symmetry.py``
+``fingerprints_by_raw_view``) hands a fingerprint to the first lane of
+each distinct raw view and masks every other lane, so its fill gather,
+``rank``'s ``cumsum``, ``argsort``'s payload sort and the loop's index
+gather left both programs and one sort of one int32 key came (eleven
+equations fewer in every wave and chunk program); which lane is new, and
+every row, journal entry, fingerprint appended, count and coverage cell,
+bit-equal to PR 53's tree (``tests/test_symmetry_v3.py``'s ``first_new``
+property; ``stage_diff.py``'s wave stage against the parent's dump).
+Nothing is compiled or run.
 
 A PR that means to change a program re-pins its digest on purpose, says
 so, and checks the benchmark's cells; a PR that does not must leave
@@ -47,36 +57,36 @@ ENGINES = {"device": DeviceBFS, "sharded": ShardedBFS}
 # the seen merge has no model in it: one digest for every family
 SEEN_MERGE = (12, "19ac660b935d83db")
 
-# {family: {engine: {program: (equations, digest)}}} at PR 52's tree
+# {family: {engine: {program: (equations, digest)}}} at PR 54's tree
 PARENT_PROGRAMS = {
     "raft": {
-        "device": {"wave": (4859, "84234c873a324d74"),
+        "device": {"wave": (4848, "483adb95782f5301"),
                    "seen_merge": SEEN_MERGE},
-        "sharded": {"chunk": (5256, "70d6d3e56703d5de")}},
+        "sharded": {"chunk": (5245, "4605122e34e2e033")}},
     "raft-dense": {
-        "device": {"wave": (3618, "559c7d1fb87bcf19"),
+        "device": {"wave": (3607, "28eb4b312002a35c"),
                    "seen_merge": SEEN_MERGE},
-        "sharded": {"chunk": (4015, "3eaa70114f743cc3")}},
+        "sharded": {"chunk": (4004, "0543045755cbcf6f")}},
     "pull_raft": {
-        "device": {"wave": (5221, "9bb0962c3bafdaf8"),
+        "device": {"wave": (5210, "49da28aba43f0a98"),
                    "seen_merge": SEEN_MERGE},
-        "sharded": {"chunk": (5618, "b03be548a55e6d78")}},
+        "sharded": {"chunk": (5607, "92c04ef174ccfff2")}},
     "kraft": {
-        "device": {"wave": (6560, "ed0a5604e4e5b1b8"),
+        "device": {"wave": (6549, "29788697bcf837b4"),
                    "seen_merge": SEEN_MERGE},
-        "sharded": {"chunk": (6957, "ee8e6d034863c033")}},
+        "sharded": {"chunk": (6946, "5245554c3deba11a")}},
     "joint_raft": {
-        "device": {"wave": (10893, "1b25a9de882fc036"),
+        "device": {"wave": (10882, "e898caf6a7f5b8f2"),
                    "seen_merge": SEEN_MERGE},
-        "sharded": {"chunk": (11290, "7fe938da5359921a")}},
+        "sharded": {"chunk": (11279, "dd9e35d7a0a69421")}},
     "kraft_reconfig": {
-        "device": {"wave": (14929, "ea9629d1a42c50d2"),
+        "device": {"wave": (14918, "7d1e79bd8d7aa2ec"),
                    "seen_merge": SEEN_MERGE},
-        "sharded": {"chunk": (15326, "fb018ac09b5fa3f6")}},
+        "sharded": {"chunk": (15315, "b1b9d3b4c4fe9a52")}},
     "reconfig_raft": {
-        "device": {"wave": (10518, "2d322387cf30603e"),
+        "device": {"wave": (10507, "4fef53434077f94b"),
                    "seen_merge": SEEN_MERGE},
-        "sharded": {"chunk": (10915, "8dba11c62761816b")}},
+        "sharded": {"chunk": (10904, "e6dc777d1a8d2aa0")}},
 }
 
 

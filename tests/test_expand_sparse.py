@@ -316,7 +316,8 @@ def test_stage_diff_rehearses_on_the_cpu(tmp_path):
     assert lines[-1] == "ALL STAGES EQUAL"
     stages = {ln.split()[0] for ln in lines if " differ" in ln}
     assert {"dense_succs", "guards_valid", "sparse_rows", "canon_fp_dedup",
-            "invariant_NoLogDivergence", "wave_rows"} <= stages
+            "canon_dedup_off_rule", "invariant_NoLogDivergence",
+            "wave_rows", "wave_new", "wave_jparent"} <= stages
     # and without --platform cpu it refuses to call the CPU a chip
     r = _stage_diff(
         "scripts/stage_diff.py", "configs/standard-raft/Raft.cfg",

@@ -21,7 +21,7 @@ from raft_tpu.utils.cfg import parse_cfg
 
 from raft_tpu.ops.hashing import U64_MAX
 
-from conftest import collect_states, eqns, lower_dedup_canon
+from conftest import collect_states, eqns, indexed_ops, lower_dedup_canon
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CFG = os.path.join(ROOT, "configs", "flexible-raft", "FlexibleRaft.cfg")
@@ -152,13 +152,15 @@ def test_tiered_canon_is_brute_force_over_120_permutations(setup, sample):
     tier12_only = sum(largest[i] <= 2 for i in sel)
     assert tier12_only + sum(tiers) == len(sel)
 
-    # through the in-chunk dedup: one canon per distinct raw view
+    # through the in-chunk dedup: one canon per distinct raw view, on
+    # its first valid lane, every other lane masked
     fps_d, n_dup, tiers_d = auto.fingerprints_dedup(batch, valid)
-    assert np.array_equal(np.asarray(fps_d)[sel], fa[sel])
-    assert np.all(np.asarray(fps_d)[~valid] == U64_MAX)
     raw = np.asarray(auto.raw_fingerprints(batch))
     _u, first = np.unique(raw[sel], return_index=True)
     reps = sel[first]
+    assert np.array_equal(np.asarray(fps_d)[reps], fa[reps])
+    rest = np.setdiff1d(np.arange(len(batch)), reps)
+    assert np.all(np.asarray(fps_d)[rest] == U64_MAX)
     assert int(n_dup) == len(sel) - len(reps) >= 3
     assert [int(x) for x in np.asarray(tiers_d)] == [
         sum(3 <= largest[i] < 5 for i in reps),
@@ -181,6 +183,20 @@ def test_canon_scopes_nest_as_siblings_at_five_servers(
     assert f"/{scope}/" in dedup_canon_lowered
     assert "inchunk/tier" not in dedup_canon_lowered
     assert "/inchunk/while/" not in dedup_canon_lowered
+
+
+def test_inchunk_dedup_indexes_rows_alone_at_five_servers(dedup_canon_lowered):
+    """Under `canon/inchunk` nothing is read or written a lane at a time
+    (PR 54): its two gathers read rows, the raw hash's view columns and
+    a block's representatives, and it scatters nothing. The tiers, which
+    run on a block of representatives in the loop's body, keep their own
+    (`tier3_local`'s pattern reads, the tier-3 drains' writes)."""
+    ops = indexed_ops(dedup_canon_lowered)
+    inchunk = [op for op in ops if "/inchunk/" in op[2]]
+    assert [(kind, len(dims)) for kind, dims, _ in inchunk] == [
+        ("gather", 2)] * 2, inchunk
+    assert {stack.split("/")[-4] for kind, _dims, stack in ops
+            if kind == "scatter"} == {"tier3_local", "tier3_full"}
 
 
 def test_tier12_looks_servers_up_without_a_gather(setup):

@@ -29,7 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import eqns, scope_paths
+from conftest import eqns, first_lane_masked, indexed_ops, scope_paths
 from test_kraft_reconfig import SMALLP, small_oracle
 from raft_tpu.models import kraft_reconfig
 from raft_tpu.models.registry import build_from_cfg, oracle_for_setup
@@ -197,6 +197,28 @@ def test_no_gather_and_no_scatter_in_the_canonicalizer_and_its_scopes(setup):
     assert "/slot_bag/" in lowered and "/inchunk/sort" in lowered
     assert not [ln for ln in lowered.splitlines()
                 if "/slot_" in ln and ln.split('"')[1].endswith("/sort")]
+
+
+def test_canon_stage_at_the_cells_chunk_shape_indexes_rows_alone(setup):
+    """The slot canon's stage as `kraftrc3-wide` runs it, 16,384
+    compacted lanes a chunk-step (nothing compiled): no scatter, and no
+    gather from a one-dimensional array (the in-chunk dedup's fill and
+    its loop's index read were two such until PR 54; each is a serial
+    pass over its lanes on the chip). What is left reads rows: the raw
+    key's view prefix is a slice, so the stage's one gather is a block's
+    representatives."""
+    from raft_tpu.obs import stage
+
+    model = setup.model
+    canon = model.make_canonicalizer(True)
+    lowered = jax.jit(stage("canon")(canon.fingerprints_dedup)).lower(
+        jax.ShapeDtypeStruct((16384, model.layout.W), np.int32),
+        jax.ShapeDtypeStruct((16384,), bool)).as_text(debug_info=True)
+    ops = indexed_ops(lowered)
+    assert not [op for op in ops if op[0] == "scatter" or len(op[1]) < 2]
+    assert [(dims, stack.split("/")[-2]) for _kind, dims, stack in ops] == [
+        (["16385", str(model.layout.W)], "inchunk")]
+    assert lowered.count("stablehlo.sort") == 3  # raw, lay-out, return
 
 
 def test_fingerprints_are_equal_iff_the_oracles_canon_is_under_all_12(
@@ -624,21 +646,25 @@ def edge_batches(views, setup):
 def test_inchunk_dedup_is_the_canon_of_every_lane_bit_for_bit(
         setup, slot_canon, edge_batches, edge):
     """Dedup decides where the 12 permutations run, never their value:
-    `fingerprints_dedup` is `_fingerprints` on every valid lane, U64_MAX
-    elsewhere, `n_dup` the valid lanes less their distinct raw views and
-    `tiers` [0, representatives]."""
+    `fingerprints_dedup` is `_fingerprints` on the first valid lane of
+    every distinct raw view, U64_MAX on every other lane (invalid, or a
+    duplicate of a lower lane), `n_dup` the valid lanes less their
+    distinct raw views and `tiers` [0, representatives]."""
     from raft_tpu.ops.hashing import U64_MAX
 
     canon, dedup = slot_canon
     rows, valid = edge_batches[edge]
     VL = setup.model.layout.view_len
     fps, n_dup, tiers = jax.device_get(dedup(rows, valid))
-    want = np.where(valid, np.asarray(canon.fingerprints(rows)),
-                    np.uint64(U64_MAX))
+    want, want_dup = first_lane_masked(canon, rows, valid)
     assert np.array_equal(fps, want)
     distinct = len(np.unique(rows[valid][:, :VL], axis=0))
-    assert distinct == EDGES[edge]
-    assert int(n_dup) == int(valid.sum()) - distinct
+    assert distinct == EDGES[edge] == int(np.sum(fps != U64_MAX))
+    # the lanes that carry a fingerprint: each view's first valid one
+    _u, first = np.unique(rows[valid][:, :VL], axis=0, return_index=True)
+    assert np.array_equal(np.flatnonzero(fps != U64_MAX),
+                          np.sort(np.flatnonzero(valid)[first]))
+    assert int(n_dup) == want_dup == int(valid.sum()) - distinct
     assert [int(t) for t in tiers] == [0, distinct]
 
 
@@ -646,7 +672,10 @@ def test_a_permuted_image_is_no_raw_duplicate_and_both_lanes_run(
         setup, oracle, sample, slot_canon):
     """A lane that holds a permuted image of another lane's state shares
     its fingerprint, not its raw view: the dedup runs the permutations on
-    both, and both come back with the one value."""
+    both, and both come back with the one value, for the dedup stage to
+    choose between; the four lanes that repeat them come back masked."""
+    from raft_tpu.ops.hashing import U64_MAX
+
     canon, dedup = slot_canon
     model = setup.model
     st = sample[-1]
@@ -656,8 +685,8 @@ def test_a_permuted_image_is_no_raw_duplicate_and_both_lanes_run(
     rows = np.tile(pair, (B_EDGE // 2, 1))
     valid = np.arange(B_EDGE) < 6
     fps, n_dup, tiers = jax.device_get(dedup(rows, valid))
-    assert len(set(fps[:6].tolist())) == 1
-    assert fps[0] == np.asarray(canon.fingerprints(pair))[0]
+    assert fps[0] == fps[1] == np.asarray(canon.fingerprints(pair))[0]
+    assert fps[0] != U64_MAX and np.all(fps[2:] == U64_MAX)
     assert (int(n_dup), [int(t) for t in tiers]) == (4, [0, 2])
 
 

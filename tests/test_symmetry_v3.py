@@ -32,7 +32,8 @@ from raft_tpu.ops.hashing import U64_MAX
 from raft_tpu.ops.symmetry import Canonicalizer
 
 from conftest import (
-    collect_states, jaxpr_digest, lower_dedup_canon, scope_paths)
+    collect_states, first_lane_masked, indexed_ops, jaxpr_digest,
+    lower_dedup_canon, scope_paths)
 
 
 def raft3():
@@ -333,38 +334,46 @@ def _dedup(auto, batch, valid):
     return np.asarray(fps), int(n_dup), [int(x) for x in np.asarray(tiers)]
 
 
-def _host_dups(auto, batch, valid):
-    """Valid lanes whose raw view an earlier valid lane has, on the host."""
-    raw = np.asarray(auto.raw_fingerprints(batch))[valid]
-    return len(raw) - len(np.unique(raw))
-
-
 @pytest.mark.parametrize("name", ["raft3", "raft5"])
 def test_dedup_equals_plain(name):
-    # one canon per distinct raw view gives every lane, duplicated and
-    # Init-repeated rows among them, what the plain entry gives it
+    # one canon per distinct raw view: the first lane of a view,
+    # duplicated and Init-repeated rows among them, carries what the
+    # plain entry gives it, and every later lane of the view is masked
     model, _oracle, _states, vecs = states_of(name, depth=3, cap=80)
     reps = np.repeat(model.init_states(), 40, axis=0)
     batch = np.concatenate([vecs, reps, vecs], axis=0).astype(np.int32)
     auto, _ = canon_pair(model)
     valid = np.ones(len(batch), dtype=bool)
     fps, n_dup, tiers = _dedup(auto, batch, valid)
-    assert np.array_equal(fps, np.asarray(auto.fingerprints(batch)))
-    assert n_dup == _host_dups(auto, batch, valid) >= len(vecs) + 39
+    want, want_dup = first_lane_masked(auto, batch, valid)
+    assert np.array_equal(fps, want)
+    assert fps[0] == np.asarray(auto.fingerprints(batch[:1]))[0] != U64_MAX
+    assert np.all(fps[-len(vecs):] == U64_MAX)
+    assert n_dup == want_dup >= len(vecs) + 39
     assert sum(tiers) <= len(batch) - n_dup
 
 
 def test_dedup_invalid_lanes_masked():
     model, _oracle, _states, vecs = states_of("raft3", depth=3, cap=60)
     auto, _ = canon_pair(model)
+    _u, first = np.unique(
+        np.asarray(auto.raw_fingerprints(vecs)), return_index=True)
+    vecs = vecs[np.sort(first)]  # states that differ past the view go
+    assert len(vecs) > 20
     batch = np.concatenate([vecs, vecs[:20]]).astype(np.int32)
     valid = np.arange(len(batch)) % 3 != 0
     fps, n_dup, _tiers = _dedup(auto, batch, valid)
     assert np.all(fps[~valid] == U64_MAX)
+    want, want_dup = first_lane_masked(auto, batch, valid)
+    assert np.array_equal(fps, want)
+    # an invalid lane is nobody's duplicate and nobody's representative:
+    # lane 0 is invalid, so its view's fingerprint is on its copy, and a
+    # valid lane's copy is masked
     plain = np.asarray(auto.fingerprints(batch))
-    assert np.array_equal(fps[valid], plain[valid])
-    # an invalid lane is nobody's duplicate and nobody's representative
-    assert n_dup == _host_dups(auto, batch, valid) > 0
+    assert not valid[0] and valid[len(vecs)]
+    assert fps[len(vecs)] == plain[0] != U64_MAX
+    assert valid[1] and valid[len(vecs) + 1] and fps[len(vecs) + 1] == U64_MAX
+    assert n_dup == want_dup > 0
     none = np.zeros(len(batch), bool)
     fps, n_dup, tiers = _dedup(auto, batch, none)
     assert np.all(fps == U64_MAX) and n_dup == 0 and tiers == [0, 0]
@@ -372,16 +381,17 @@ def test_dedup_invalid_lanes_masked():
 
 @pytest.mark.parametrize("name", ["raft3", "raft5"])
 def test_dedup_one_view_many_times(name):
-    # a chunk that is one raw view B times over is one representative:
-    # Init is all-tied, so that one lane takes the S!-table min, and the
-    # loop makes one trip
+    # a chunk that is one raw view B times over is one representative,
+    # lane 0: Init is all-tied, so that one lane takes the S!-table min,
+    # and the loop makes one trip
     model, _oracle = CASES[name]()
     B = 200
     batch = np.repeat(model.init_states()[:1], B, axis=0).astype(np.int32)
     auto, _ = canon_pair(model)
     fps, n_dup, tiers = _dedup(auto, batch, np.ones(B, bool))
     assert n_dup == B - 1 and tiers == [0, 1]
-    assert np.all(fps == np.asarray(auto.fingerprints(batch[:1]))[0])
+    assert fps[0] == np.asarray(auto.fingerprints(batch[:1]))[0]
+    assert np.all(fps[1:] == U64_MAX)
 
 
 def test_dedup_more_representatives_than_a_block():
@@ -396,9 +406,114 @@ def test_dedup_more_representatives_than_a_block():
     valid = np.ones(len(batch), bool)
     assert len(batch) == 150
     fps, n_dup, _tiers = _dedup(auto, batch, valid)
-    assert n_dup == _host_dups(auto, batch, valid) == 10
+    want, want_dup = first_lane_masked(auto, batch, valid)
+    assert n_dup == want_dup == 10
     assert len(batch) - n_dup > 2 * 64
-    assert np.array_equal(fps, np.asarray(auto.fingerprints(batch)))
+    assert np.array_equal(fps, want)
+    assert np.all(fps[100:110] == U64_MAX) and not np.any(fps[:100] == U64_MAX)
+
+
+B_NEW = 64  # one shape a canon: one program of each for the 24 cases
+
+
+def _canon_and_orbits(name):
+    """(canon, rows [n, W] of distinct canonical classes, their images
+    under a permutation that moves the raw view), for the in-chunk
+    dedup's two callers."""
+    if name == "raft3":
+        model, oracle, states, _vecs = states_of("raft3", depth=8, cap=1200)
+        canon = Canonicalizer.for_model(model, symmetry=True)
+        image = [oracle.permute(st, [1, 2, 0]) for st in states]
+    else:
+        from test_kraft_reconfig import small_oracle
+
+        (model,) = _kraftrc_small()
+        oracle = small_oracle()
+        states = collect_states(oracle, max_depth=4, cap=150)
+        canon = model.make_canonicalizer(True)
+        # hosts 0 and 1 hold the initial cluster's two servers
+        image = [oracle.permute(st, [1, 0, 2], [0]) for st in states]
+    rows = np.stack([model.encode(st) for st in states]).astype(np.int32)
+    images = np.stack([model.encode(st) for st in image]).astype(np.int32)
+    fps = np.asarray(canon.fingerprints(rows))
+    assert np.array_equal(fps, np.asarray(canon.fingerprints(images)))
+    _u, first = np.unique(fps, return_index=True)
+    keep = np.sort(first)
+    moved = keep[np.asarray(canon.raw_fingerprints(rows))[keep]
+                 != np.asarray(canon.raw_fingerprints(images))[keep]]
+    assert len(moved) >= B_NEW // 2 and len(keep) >= B_NEW
+    return canon, rows[keep], rows[moved], images[moved]
+
+
+@pytest.fixture(scope="module", params=["raft3", "kraftrc_slot_canon"])
+def new_lane_case(request):
+    canon, rows, moved, images = _canon_and_orbits(request.param)
+    B = B_NEW
+    batches = {
+        "one_view_b_times": np.repeat(rows[:1], B, axis=0),
+        "distinct_views": rows[:B],
+    }
+    # lanes 2k and 2k + 1: two raw views of one canonical class, the
+    # higher lane's raw key the smaller, so the raw sort puts the higher
+    # lane's segment first and the lower lane must still be the new one
+    pair = np.stack([moved[: B // 2], images[: B // 2]], axis=1)
+    raw = np.asarray(canon.raw_fingerprints(pair.reshape(B, -1))).reshape(
+        B // 2, 2)
+    swap = raw[:, 0] < raw[:, 1]
+    pair[swap] = pair[swap][:, ::-1]
+    batches["one_class_two_raw_views_higher_lane_smaller_key"] = (
+        pair.reshape(B, -1))
+    raw = np.asarray(canon.raw_fingerprints(pair.reshape(B, -1)))
+    assert np.all(raw[0::2] > raw[1::2])
+    return canon, jax.jit(canon.fingerprints_dedup), batches
+
+
+@pytest.mark.parametrize("runs", [False, True], ids=["no_runs", "runs"])
+@pytest.mark.parametrize("invalid", [False, True], ids=["valid", "invalid"])
+@pytest.mark.parametrize("batch", [
+    "one_view_b_times", "distinct_views",
+    "one_class_two_raw_views_higher_lane_smaller_key"])
+def test_first_new_of_the_masked_lanes_is_first_new_of_every_lanes_canon(
+        new_lane_case, batch, invalid, runs):
+    """What lets a duplicate come back masked: `util.first_new`, the one
+    consumer, marks the same lanes new, lane for lane, on the in-chunk
+    dedup's output as on the plain canon of every lane, against nothing
+    and against a seen run that holds some of the chunk's fingerprints;
+    and a lane that is new carries the canon of its own row."""
+    from raft_tpu.checker.util import first_new
+
+    canon, dedup, batches = new_lane_case
+    rows = batches[batch]
+    B = len(rows)
+    valid = np.arange(B) % 3 != 0 if invalid else np.ones(B, bool)
+    masked, n_dup, _tiers = jax.device_get(dedup(rows, valid))
+    want, want_dup = first_lane_masked(canon, rows, valid)
+    assert np.array_equal(masked, want) and int(n_dup) == want_dup
+    plain = np.where(valid, np.asarray(canon.fingerprints(rows)),
+                     np.uint64(U64_MAX))
+    seen = ()
+    if runs:
+        run = np.full(2 * B, np.uint64(U64_MAX))
+        held = np.unique(plain[valid])[::3]
+        run[: len(held)] = held
+        seen = (jax.numpy.asarray(run),)
+    occ = np.ones(len(seen), bool)
+    new_masked = np.asarray(first_new(jax.numpy.asarray(masked), occ, seen))
+    new_plain = np.asarray(first_new(jax.numpy.asarray(plain), occ, seen))
+    assert np.array_equal(new_masked, new_plain)
+    assert np.array_equal(masked[new_masked], plain[new_plain])
+    assert not np.any(new_masked & ~valid)
+    # the host's word for it: the lowest valid lane of each fingerprint
+    # the run does not hold
+    lowest = {}
+    for lane in np.flatnonzero(valid)[::-1]:
+        lowest[plain[lane]] = lane
+    held = set(seen[0].tolist()) if runs else set()
+    assert sorted(np.flatnonzero(new_plain)) == sorted(
+        lane for fp, lane in lowest.items() if fp not in held)
+    if batch.startswith("one_class") and not runs:
+        assert new_plain.sum() == len({plain[i] for i in np.flatnonzero(valid)})
+        assert not np.any(new_plain[1::2] & valid[0::2])
 
 
 def _joint4():
@@ -418,15 +533,43 @@ def test_inchunk_dedup_lowers_to_sorts_alone(build):
     (the un-sort, the table's write and the loop's ``.at[pos].set`` were
     28 % of raft3-wide's device time, PERF.md section 6, PR 33). Nothing
     under ``canon/inchunk`` is a scatter, the program takes the rows and
-    their mask and no table, and the two sorts are there."""
+    their mask and no table, and the three sorts are there. Nor is a
+    per-lane read: the two gathers under the scope read rows (the raw
+    hash's view columns, by a constant; a block's representatives), and
+    the fill that read a fingerprint a lane went in PR 54."""
     text = lower_dedup_canon(build()[0])
     under = [ln for ln in text.splitlines() if "/inchunk/" in ln]
     assert any("/inchunk/sort" in ln for ln in under)  # the walk sees in
     assert not [ln for ln in under if "scatter" in ln]
+    inchunk = [op for op in indexed_ops(text) if "/inchunk/" in op[2]]
+    assert [(kind, len(dims)) for kind, dims, _ in inchunk] == [
+        ("gather", 2)] * 2, inchunk
     (main,) = [ln for ln in text.splitlines()
                if "func.func public @main" in ln]
     assert main.count("%arg") == 2 and "ui64" not in main.split("->")[0], main
-    assert text.count("stablehlo.sort") >= 2
+    assert text.count("stablehlo.sort") >= 3
+
+
+def test_canon_stage_at_raft3s_chunk_shape_indexes_rows_alone():
+    """The engines' canon stage as `raft3-wide` runs it, 65,536 compacted
+    lanes a chunk-step (nothing compiled): no scatter, and no gather
+    from a one-dimensional array, each a serial pass of 4.6 and 7.1 ns a
+    lane on the chip (the fill `canon_rep[rank]` and the loop's
+    `orderp[pos]` were two such until PR 54, 7.4 % of the cell's device
+    time). Three servers have no tiers, so this is the whole stage."""
+    from raft_tpu.obs import stage
+
+    model = raft3()[0]
+    canon = Canonicalizer.for_model(model, symmetry=True)
+    text = jax.jit(stage("canon")(canon.fingerprints_dedup)).lower(
+        jax.ShapeDtypeStruct((65536, model.layout.W), np.int32),
+        jax.ShapeDtypeStruct((65536,), bool)).as_text(debug_info=True)
+    ops = indexed_ops(text)
+    assert [op for op in ops if "/inchunk/" in op[2]]  # the walk sees in
+    assert not [op for op in ops if op[0] == "scatter" or len(op[1]) < 2]
+    # the one gather of the stage by a traced index: a block's rows
+    assert [dims for _kind, dims, stack in ops
+            if "/while/body/inchunk/" in stack] == [["65537", "117"]]
 
 
 def _kraftrc_small():
@@ -438,10 +581,13 @@ def _kraftrc_small():
 
 
 # (equations, digest) of `Canonicalizer.fingerprints_dedup`'s jaxpr over a
-# 256-lane batch at PR 40's tree (ad5b664), where the in-chunk dedup was
-# that method's own lines: conftest.jaxpr_digest
-PARENT_JAXPR = {"raft3": (718, "e7ba52e369f44441"),
-                "flexraft5": (5536, "1aa1dbe5d3cebea0")}
+# 256-lane batch (conftest.jaxpr_digest), pinned at PR 54, whose in-chunk
+# dedup traces 11 equations fewer than PR 40's tree (ad5b664: 718 and
+# 5536), where it was that method's own lines: the fill's `cumsum` and
+# gather, `argsort`'s iota and the loop's index gather went, one sort of
+# one key came
+PARENT_JAXPR = {"raft3": (707, "0ddcda304be4b4f4"),
+                "flexraft5": (5525, "a46fe6185be9a9bc")}
 
 
 @pytest.mark.parametrize("name, build", [
@@ -453,8 +599,8 @@ def test_both_canons_run_their_permutations_under_the_one_inchunk_dedup(
     dedup: `Canonicalizer` and the slot canon of `KRaftWithReconfig` both
     lower to it (`canon/inchunk` beside the canon's own scopes, which run
     in its loop's body and stay its siblings), with no per-lane write of
-    its own; and what `Canonicalizer` traces is what it traced when the
-    lines were its method's, equation for equation."""
+    its own; and what `Canonicalizer` traces is what it traced at the
+    pin, equation for equation."""
     from raft_tpu.obs import stage
 
     model = build()[0]
