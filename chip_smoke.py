@@ -64,6 +64,14 @@ count against the pure-Python oracle's golden
          registry's own bag width: the kernels of Timeout,
          RequestVotePair and AdvanceFsyncIndex, which no other leg
          fires, and the fsync gates (MaxRestarts is 0: no crash).
+  leg J  configs/pull-raft/PullRaftVariant2.cfg under --lenient
+         (PullRaft's second variant: votesLastEntry rides on the votes,
+         the new leader notifies every peer with the last common entry
+         and LearnOfLeader truncates to it; 289-lane rows, the same 85
+         actions a state; models/pull_raft.py under variant2) to depth
+         14 against tests/golden/pullv2_cfg_depth_counts.json: leg G's
+         model file with the variant's branches taken, at its cell's
+         chunk.
 
 This process never imports jax or raft_tpu: a chip belongs to one process
 at a time, so every leg is a child of its own, one after the other, and
@@ -109,6 +117,9 @@ ADDREMOVE_CFG = os.path.join(
 FSYNC_GOLDEN = os.path.join(
     ROOT, "tests", "golden", "fsync3_cfg_depth_counts.json")
 FSYNC_CFG = os.path.join(ROOT, "configs", "raft-and-fsync", "RaftFsync.cfg")
+PULLV2_GOLDEN = os.path.join(
+    ROOT, "tests", "golden", "pullv2_cfg_depth_counts.json")
+PULLV2_CFG = os.path.join(ROOT, "configs", "pull-raft", "PullRaftVariant2.cfg")
 UNSAFE_CFG = os.path.join(
     ROOT, "configs", "flexible-raft", "unsafe-quorums", "FlexibleRaft.cfg")
 SCHEMA_CHECK = os.path.join(ROOT, "scripts", "check_metrics_schema.py")
@@ -298,7 +309,7 @@ def leg_c(dev: dict, golden: dict) -> None:
 
 def cfg_leg(letter: str, cfg: str, chunk: int, dev: dict, golden: dict,
             flags: tuple = ()) -> None:
-    """Legs D to I: another model file's cfg through the CLI to its
+    """Legs D to J: another model file's cfg through the CLI to its
     golden's depth, at its cell's chunk, with the flags the cfg needs."""
     depth = golden["max_depth"]
     res = bfs_leg(f"leg{letter}", dev, golden,
@@ -313,9 +324,9 @@ def main() -> int:
     try:
         for path in (GOLDEN, JOINT_GOLDEN, KRAFT_GOLDEN, KRAFTRC_GOLDEN,
                      PULL_GOLDEN, ADDREMOVE_GOLDEN, FSYNC_GOLDEN,
-                     TRACE_GOLDEN, RAFT_CFG, JOINT_CFG, KRAFT_CFG,
-                     KRAFTRC_CFG, PULL_CFG, ADDREMOVE_CFG, FSYNC_CFG,
-                     UNSAFE_CFG, SCHEMA_CHECK,
+                     PULLV2_GOLDEN, TRACE_GOLDEN, RAFT_CFG, JOINT_CFG,
+                     KRAFT_CFG, KRAFTRC_CFG, PULL_CFG, ADDREMOVE_CFG,
+                     FSYNC_CFG, PULLV2_CFG, UNSAFE_CFG, SCHEMA_CHECK,
                      os.path.join(ROOT, "raft_tpu", "__main__.py")):
             check(os.path.exists(path),
                   f"{os.path.relpath(path, ROOT)} is missing: chip_smoke.py "
@@ -336,7 +347,9 @@ def main() -> int:
                 # upstream's cfg omits MaxClusterSize
                 ("H", ADDREMOVE_CFG, 1024, ADDREMOVE_GOLDEN,
                  ("--lenient",)),
-                ("I", FSYNC_CFG, 2048, FSYNC_GOLDEN, ())):
+                ("I", FSYNC_CFG, 2048, FSYNC_GOLDEN, ()),
+                # the same undeclared v2 as leg G's cfg
+                ("J", PULLV2_CFG, 2048, PULLV2_GOLDEN, ("--lenient",))):
             with open(path) as f:
                 cfg_leg(letter, cfg, chunk, dev,
                         json.load(f)["depth_limited"], flags)

@@ -228,10 +228,14 @@ def test_chip_smoke_leg_holds_the_cli_to_the_golden(smoke):
     # --msg-slots (the registry's own), the cell's chunk
     ("ADDREMOVE", None, 3, [1, 6, 27, 91], [1, 6, 27, 92],
      ("H", 9, 1024, ["--lenient"])),
+    # leg J: PullRaftVariant2.cfg, leg G's bug, bag width and chunk; the
+    # variant's counts leave PullRaft's at depth 4 (17 for 18)
+    ("PULLV2", 64, 6, [1, 1, 3, 7, 17, 34, 65], [1, 1, 3, 7, 17, 34, 66],
+     ("J", 14, 2048, ["--lenient"])),
 ])
 def test_chip_smoke_second_lowerings_legs_run_their_own_cfg_and_golden(
         smoke, monkeypatch, leg, msg_slots, depth, counts, wrong, args):
-    """Legs D to H at a tiny depth: another cfg, its own chunk, its
+    """Legs D to H and J at a tiny depth: another cfg, its own chunk, its
     own golden and that golden's bag width; then the leg itself, its
     arguments held: the frontier, the golden's depth, the chunk, the
     flags the cfg needs."""
@@ -258,7 +262,7 @@ def test_chip_smoke_second_lowerings_legs_run_their_own_cfg_and_golden(
     monkeypatch.setattr(chip_smoke, "leg_b", lambda *a: None)
     monkeypatch.setattr(chip_smoke, "leg_c", lambda *a: None)
     assert chip_smoke.main() == 0
-    (a, kw) = calls["DEFGH".index(letter)]
+    (a, kw) = calls["DEFGHIJ".index(letter)]
     assert a[:1] + a[2:] == (
         f"leg{letter}", golden,
         ["--checker", "tpu", "--frontier-cap", "65536", *flags], max_depth, 1)

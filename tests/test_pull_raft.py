@@ -130,16 +130,12 @@ def test_pull_flow_reaches_commit():
     assert st["acked"][0] is True
 
 
-@pytest.mark.skipif(
-    not Path("/root/reference").exists(),
-    reason="reference TLA+ spec tree not checked out at /root/reference",
-)
-def test_reference_pull_cfgs_load_with_diagnosis():
+def _pull_cfgs_load_with_diagnosis(folder):
     from raft_tpu.utils.cfg import CfgError, parse_cfg
     from raft_tpu.models.registry import build_from_cfg
 
     for name in ("PullRaft", "PullRaftVariant2"):
-        path = f"/root/reference/specifications/pull-raft/{name}.cfg"
+        path = f"{folder}/{name}.cfg"
         # strict parse must surface the documented cfg bug
         with pytest.raises(CfgError, match="undeclared model value 'v2'"):
             parse_cfg(path)
@@ -152,3 +148,20 @@ def test_reference_pull_cfgs_load_with_diagnosis():
         assert setup.model.p.variant2 == (name == "PullRaftVariant2")
         assert setup.invariants == ("LeaderHasAllAckedValues", "NoLogDivergence")
         assert setup.symmetry
+
+
+@pytest.mark.skipif(
+    not Path("/root/reference").exists(),
+    reason="reference TLA+ spec tree not checked out at /root/reference",
+)
+def test_reference_pull_cfgs_load_with_diagnosis():
+    _pull_cfgs_load_with_diagnosis("/root/reference/specifications/pull-raft")
+
+
+def test_in_tree_pull_cfgs_load_with_diagnosis():
+    """The twin that runs: the tree's own copies of the two cfgs
+    (configs/pull-raft/, reconstructed, PRs 43 and 55) keep upstream's
+    undeclared `v2` and everything else the reference-cfg test recorded
+    of upstream's files."""
+    _pull_cfgs_load_with_diagnosis(
+        str(Path(__file__).resolve().parent.parent / "configs" / "pull-raft"))
