@@ -133,11 +133,13 @@ class DeviceBFS(FleetQueue):
     # then cumulative canon counts: in-chunk duplicates, tier-3 local
     # lanes, tier-3 full lanes; last the dedup stage's two counts, each
     # summed over the wave's chunk-steps: the lanes its merged sort
-    # sorted and the query lanes it searched an occupied run with].
+    # sorted and the query lanes it searched an occupied run with; and
+    # the successor rows the wave's apply passes built
+    # (SparseExpandMixin.sparse_apply's count)].
     # STATS_KEEP is what a wave's start keeps of it (the wave-new,
-    # overflow and dedup lanes reset in-program).
-    N_STATS = 10
-    STATS_KEEP = (0, 1, 1, 1, 0, 1, 1, 1, 0, 0)
+    # overflow, dedup and built-rows lanes reset in-program).
+    N_STATS = 11
+    STATS_KEEP = (0, 1, 1, 1, 0, 1, 1, 1, 0, 0, 0)
 
     # Donation contract for the wave program: argument indices of
     # the capacity-shaped loop carries updated in place every dispatch
@@ -353,11 +355,11 @@ class DeviceBFS(FleetQueue):
         lane the later stages consume."""
         batch, succs, valid, rank, n_gen, terminal, expand_ovf = expand_chunk(
             self.model, self._sparse, frontier, cursor, fcount, self.chunk)
-        flatc, sel, selv, sel_rank, compact_ovf = compact_chunk(
+        flatc, sel, selv, sel_rank, compact_ovf, rows_built = compact_chunk(
             self.model, self._plan, batch, succs, valid, rank,
             self.n_actions, n_gen, self.VC)
         return (flatc, sel, selv, sel_rank, valid, rank, n_gen, terminal,
-                expand_ovf, compact_ovf)
+                expand_ovf, compact_ovf, rows_built)
 
     @stage("canon")
     def _st_canon(self, flatc, selv):
@@ -397,7 +399,8 @@ class DeviceBFS(FleetQueue):
     def _st_finish(
         self, next_buf, jparent, jcand, viol, stats, cov, wave_new,
         flatc, fps, sel, sel_rank, valid, rank, new, n_gen, terminal,
-        expand_ovf, compact_ovf, canon_n, dedup_n, cursor, base_gid,
+        expand_ovf, compact_ovf, canon_n, dedup_n, rows_built, cursor,
+        base_gid,
     ):
         """Stages 4b-6: per-action coverage, the cursor-append emit of
         rows, journal and new fingerprints, invariants on the new states
@@ -509,6 +512,7 @@ class DeviceBFS(FleetQueue):
                 stats[4] | ovf_bits,
                 *(stats[5:8] + canon_n),
                 *(stats[8:10] + dedup_n),
+                stats[10] + rows_built,
             ]
         )
         return next_buf, jparent, jcand, viol, stats, cov, wave_new
@@ -531,7 +535,8 @@ class DeviceBFS(FleetQueue):
         merged run is sorted either way). Returns the carries, wave_new with the
         chunk's new fingerprints appended."""
         (flatc, sel, selv, sel_rank, valid, rank, n_gen, terminal,
-         expand_ovf, compact_ovf) = self._st_expand(frontier, cursor, fcount)
+         expand_ovf, compact_ovf, rows_built) = self._st_expand(
+             frontier, cursor, fcount)
         fps, canon_n = self._st_canon(flatc, selv)
         new, dedup_n = self._st_dedup(
             fps, occ, wave_new, stats[0].astype(jnp.int32), seen_real,
@@ -549,7 +554,8 @@ class DeviceBFS(FleetQueue):
         return self._st_finish(
             next_buf, jparent, jcand, viol, stats, cov, wave_new, flatc,
             fps, sel, sel_rank, valid, rank, new, n_gen, terminal,
-            expand_ovf, compact_ovf, canon_n, dedup_n, cursor, base_gid,
+            expand_ovf, compact_ovf, canon_n, dedup_n, rows_built, cursor,
+            base_gid,
         )
 
     def _wave_prefix(self) -> tuple[int, ...]:
@@ -924,6 +930,10 @@ class DeviceBFS(FleetQueue):
         last_ckpt = time.perf_counter()
 
         sort_lanes_run = search_queries_run = peak_rows = 0
+        # the apply pass: the rows its tiles built and the rows its plan
+        # budgets, a chunk-step
+        rows_built_run = rows_budget_run = 0
+        plan_rows = sum(self._plan) if self._sparse else 0
 
         while fcount and violation is None:
             exit_cause = loop_exit(
@@ -1091,6 +1101,9 @@ class DeviceBFS(FleetQueue):
             canon_prev = stats_h[5:8].copy()
             sort_lanes_run += int(stats_h[8])
             search_queries_run += int(stats_h[9])
+            rows_built_run += int(stats_h[10])
+            wave_budget = plan_rows * -(-prev_fcount // self.chunk)
+            rows_budget_run += wave_budget
             wave_s_val = time.perf_counter() - tw
             # the wave's brackets, read once a wave whoever listens
             # (engine.phase_clocks makes the row's clocks of them):
@@ -1130,10 +1143,14 @@ class DeviceBFS(FleetQueue):
                     # buffer each step chose and VC; the query lanes
                     # those steps searched the seen run with (lane 9; 0
                     # while the run is merged); and the run's size as
-                    # the wave met it
+                    # the wave met it; then the successor rows the
+                    # wave's apply passes built (lane 10) beside the
+                    # rows their plan budgets, sum(plan) a chunk-step
                     dedup_sort_lanes=int(stats_h[8]),
                     dedup_search_queries=int(stats_h[9]),
                     seen_lanes=seen_lanes,
+                    expand_rows_built=int(stats_h[10]),
+                    expand_rows_budget=wave_budget,
                 )
                 tel.wave(wm)
                 if tel.active:
@@ -1172,6 +1189,8 @@ class DeviceBFS(FleetQueue):
             canon_tier3_full=int(canon_prev[2]),
             dedup_sort_lanes=sort_lanes_run,
             dedup_search_queries=search_queries_run,
+            expand_rows_built=rows_built_run,
+            expand_rows_budget=rows_budget_run,
         )
         if violation is not None:
             exit_cause = "violation"

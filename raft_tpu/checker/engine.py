@@ -479,7 +479,10 @@ def compact_chunk(
     for the compacted worklist lanes, vmapped per group over the static
     budget ``plan``, and a budget overflow folds into the compaction
     bit: both mean "a static worklist bound was exceeded, raise the
-    knob". Returns (flatc, sel, selv, sel_rank, compact_ovf)."""
+    knob". ``rows_built`` is the successor rows the apply pass built
+    (``sparse_apply``'s count: its tiles follow what the worklist holds,
+    not the budgets), 0 on the dense arm, which builds none here.
+    Returns (flatc, sel, selv, sel_rank, compact_ovf, rows_built)."""
     C, A = valid.shape
     W = batch.shape[1]
     compact_ovf = n_gen > VC
@@ -495,12 +498,14 @@ def compact_chunk(
     selv = sel < C * A
     sel_rank = (key & ((1 << bits) - 1)) - 1  # the drop key's is -1
     if succs is None:
-        flatc, apply_ovf = model.sparse_apply(batch, sel, selv, plan)
+        flatc, apply_ovf, rows_built = model.sparse_apply(
+            batch, sel, selv, plan)
         compact_ovf = compact_ovf | apply_ovf
     else:
+        rows_built = jnp.zeros((), jnp.int32)
         flatp = jnp.concatenate(
             [succs.reshape(C * A, W), jnp.zeros((1, W), jnp.int32)],
             axis=0,
         )
         flatc = flatp[sel]  # [VC, W]
-    return flatc, sel, selv, sel_rank, compact_ovf
+    return flatc, sel, selv, sel_rank, compact_ovf, rows_built

@@ -237,6 +237,59 @@ class FleetConstMixin:
         return getattr(self.p, name)
 
 
+def apply_tile(rows: int, chunk: int) -> int:
+    """The tile of ``SparseExpandMixin.sparse_apply``'s loops: how many
+    of a block's ``rows`` (a group's budget, or the worklist's VC) one
+    trip builds, a function of the static shapes alone: a sixteenth of
+    the block, and a quarter of a chunk's lanes at least (a block under
+    that is one tile). ``HandleMessage``'s VC = 16 x chunk rows and the
+    worklist's go a chunk a trip, a group of one candidate a state a
+    quarter of a chunk; a budget of no rows keeps a tile of one, which
+    never runs.
+
+    Measured (``scripts/expand_micro.py --fill``, TPU v5 lite, PR 56):
+    ms a call of one chunk's apply pass at 1/16, 1/8, 1/4, 1/2 and all
+    of VC kept, on ``raft3-wide`` (chunk 4,096, VC 65,536, 151,552
+    budgeted rows) 0.89 / 1.23 / 1.72 / 2.92 / 5.36 under this rule,
+    against 1.24 / 1.60 / 2.02 / 3.23 / 5.59 with tiles of a chunk for
+    every block, 1.62 / 1.61 / 2.27 / 3.16 / 5.51 with an eighth of the
+    block and a chunk at least, 2.36 / 2.34 / 2.30 / 3.57 / 5.27 with a
+    quarter, and 7.94 / 7.93 / 7.83 / 7.72 / 7.48 for the one-shot pass
+    at the budgets; on ``pull3-full`` (2,048; 32,768; 69,632) 0.50 /
+    0.70 / 0.95 / 1.58 / 2.72 against 0.68 / 0.87 / 1.09 / 1.73 / 2.79,
+    0.84 / 0.85 / 1.16 / 1.63 / 2.72, 1.23 / 1.21 / 1.19 / 1.81 / 2.68
+    and 4.29 / 4.28 / 4.24 / 4.18 / 4.07. A built row costs 64 to 75 ns
+    from the coarsest rule to this one at a full worklist, so a trip's
+    own cost is small beside a tile's rows; the cells keep 17 to 55 %
+    of VC a chunk-step, where the smaller tile wins. The last gather
+    alone: 0.06 / 0.11 / 0.19 / 0.36 / 0.74 ms in tiles of a chunk
+    against 0.82 to 0.84 whole (``raft3-wide``)."""
+    return max(1, rows // 16, min(rows, chunk // 4))
+
+
+def tiled_rows(block, row, n, tile: int):
+    """``block[row]`` for the first ``n`` lanes of ``row`` and zeros rows
+    past them, gathered ``tile`` lanes a trip under ``ceil(n / tile)``
+    trips: a row gather by a traced index costs its lanes, whatever they
+    point at."""
+    from jax import lax
+
+    lanes = row.shape[0]
+    room = -(-lanes // tile) * tile  # whole tiles: no slice clamps
+    rowp = jnp.concatenate([row, jnp.zeros((room - lanes,), row.dtype)])
+
+    def trip(t, out):
+        at = t * tile
+        return lax.dynamic_update_slice(
+            out, block[lax.dynamic_slice(rowp, (at,), (tile,))],
+            (at, jnp.int32(0)))
+
+    out = lax.fori_loop(
+        jnp.int32(0), (n + (tile - 1)) // tile, trip,
+        jnp.zeros((room, block.shape[1]), block.dtype))
+    return out[:lanes]
+
+
 @dataclass(frozen=True)
 class SparseGroup:
     """One contiguous run of same-named bindings in ``self.bindings``:
@@ -451,12 +504,13 @@ class SparseExpandMixin:
         (lane * A + cand), ascending, with the drop value C*A past the
         enabled prefix (``engine.compact_chunk``'s); ``selv`` = sel <
         C*A; ``plan`` the static per-group budgets from sparse_plan.
-        Returns (flatc [VC, W], apply_ovf): bit-identical to the dense
-        ``flatp[sel]`` gather for every in-budget worklist lane (drop
-        lanes select a zeros row, exactly as the dense path's appended
-        pad row). Lanes of a group past its budget also land on the
-        zeros row, with ``apply_ovf`` set — the engines fold it into
-        the overflow abort, so no surviving wave ever reads one.
+        Returns (flatc [VC, W], apply_ovf, rows_built): ``flatc``
+        bit-identical to the dense ``flatp[sel]`` gather for every
+        in-budget worklist lane (drop lanes are zeros rows, exactly as
+        the dense path's appended pad row). Lanes of a group past its
+        budget are zeros rows too, with ``apply_ovf`` set — the engines
+        fold it into the overflow abort, so no surviving wave ever reads
+        one.
 
         The worklist is segmented by group with ONE sort of one int32
         key, ``group * (C*A + 1) + flat``: the group of a candidate is a
@@ -465,7 +519,23 @@ class SparseExpandMixin:
         ``count_g`` sorted keys from the running sum of the counts
         before it, in the worklist's own order. Never a compaction by
         ``.at[dst].set``: a scatter is a serial pass over all VC lanes
-        on the TPU, 4.6 ns a lane a group."""
+        on the TPU, 4.6 ns a lane a group.
+
+        A budget is the overflow bound, not the work: a group's rows are
+        built in tiles of ``apply_tile(eb, C)`` lanes under a loop of
+        ``ceil(min(count_g, eb) / tile)`` trips, each tile one slice of
+        the sorted keys, one row gather, the group's kernel (traced once
+        a group, at the tile's width) and one ``dynamic_update_slice``
+        into the one block every group writes: [VC + a tile + 1, W],
+        the groups' kept rows end to end in the sorted worklist's order
+        (together they are VC at most), the last row zeros. A group's
+        last tile runs past what the group keeps, into rows the next
+        group with a lane writes over or nothing reads. The compacted
+        block is gathered out of that one the same way, in tiles of
+        ``apply_tile(VC, C)`` worklist lanes under the worklist's own
+        count. ``rows_built`` is the rows the groups' tiles built, i32:
+        ``sum(plan)`` at most where every budget is whole tiles (the
+        loose plan's are)."""
         import jax
         from jax import lax
 
@@ -474,7 +544,8 @@ class SparseExpandMixin:
         groups = self.sparse_groups()
         G = len(groups)
         VC = sel.shape[0]
-        total = sum(plan)
+        tiles = [apply_tile(eb, C) for eb in plan]
+        pad = max(tiles)
         stride = self.segment_stride(C)
         cand = sel % A
         wg = jnp.where(
@@ -483,51 +554,62 @@ class SparseExpandMixin:
             G,
         )
         drop = G * stride + C * A
-        # padded by the largest budget so that no slice below clamps
+        # padded by the largest tile so that no slice below clamps
         keys = jnp.concatenate([
             lax.sort(jnp.where(selv, wg * stride + sel, drop)),
-            jnp.full((max(plan),), drop, jnp.int32),
+            jnp.full((pad,), drop, jnp.int32),
         ])
-        row = jnp.full((VC,), total, jnp.int32)  # default: the zeros row
+        zeros_row = VC + pad  # past every tile: nothing writes it
+        row = jnp.full((VC,), zeros_row, jnp.int32)
         apply_ovf = jnp.zeros((), bool)
-        blocks = []
-        base = 0
-        start = jnp.zeros((), jnp.int32)
-        for gi, (g, eb) in enumerate(zip(groups, plan)):
+        rows_built = jnp.zeros((), jnp.int32)
+        allb = jnp.zeros((zeros_row + 1, W), jnp.int32)
+        # where the group's lanes start in the sorted keys (the counts
+        # before it) and its rows in the block (what those groups kept)
+        start = at_row = jnp.zeros((), jnp.int32)
+        for gi, (g, eb, T) in enumerate(zip(groups, plan, tiles)):
             mask = wg == gi
             pos = jnp.cumsum(mask.astype(jnp.int32)) - 1
-            count = jnp.sum(mask.astype(jnp.int32))
+            count = jnp.sum(mask, dtype=jnp.int32)
             apply_ovf = apply_ovf | (count > eb)
-            # what building the group's successors costs is read from a
-            # trace by its name (``expand/Restart/fusion.N``): the scope
-            # is opened outside the vmap, which would hide it
-            with jax.named_scope(g.name):
-                # the group's segment of the sorted keys: [eb] flat
-                # candidate ids, C*A (the drop value) past its count
+            kept = jnp.minimum(count, eb)
+            trips = (kept + (T - 1)) // T
+            tbl = jnp.asarray(g.params)
+            kern = self.kernel_for(g.name)
+
+            def tile(t, allb):
+                # lanes [t*T, (t+1)*T) of the group's segment of the
+                # sorted keys: flat candidate ids, C*A (the drop value)
+                # past what the group keeps (traced inside this turn of
+                # the groups' loop, so it reads this group's names)
+                at = t * T
                 flat = jnp.where(
-                    jnp.arange(eb, dtype=jnp.int32) < count,
-                    lax.dynamic_slice(keys, (start,), (eb,)) - gi * stride,
+                    at + jnp.arange(T, dtype=jnp.int32) < kept,
+                    lax.dynamic_slice(keys, (start + at,), (T,))
+                    - gi * stride,
                     C * A,
                 )
-                start = start + count
                 lane = jnp.clip(flat // A, 0, C - 1)
                 k = jnp.clip(flat % A - g.off, 0, g.n - 1)
                 srows = batch[lane]
-                tbl = jnp.asarray(g.params)
-                kern = self.kernel_for(g.name)
                 args = [tbl[:, c][k] for c in range(tbl.shape[1])]
-                blocks.append(
-                    jax.vmap(lambda s, *a, _k=kern: _k(s, *a)[1])(
-                        srows, *args)
-                )
-            row = jnp.where(
-                mask & (pos < eb), base + jnp.minimum(pos, eb - 1), row
-            )
-            base += eb
-        allb = jnp.concatenate(
-            blocks + [jnp.zeros((1, W), jnp.int32)], axis=0
-        )
-        return allb[row], apply_ovf
+                built = jax.vmap(lambda s, *a: kern(s, *a)[1])(srows, *args)
+                return lax.dynamic_update_slice(
+                    allb, built, (at_row + at, jnp.int32(0)))
+
+            # what building the group's successors costs is read from a
+            # trace by its name (``expand/Restart/fusion.N``): the scope
+            # is opened outside the loop and the vmap, which would hide
+            # it
+            with jax.named_scope(g.name):
+                allb = lax.fori_loop(jnp.int32(0), trips, tile, allb)
+            row = jnp.where(mask & (pos < eb), at_row + pos, row)
+            start = start + count
+            at_row = at_row + kept
+            rows_built = rows_built + trips * T
+        return (tiled_rows(allb, row, jnp.sum(selv, dtype=jnp.int32),
+                           apply_tile(VC, C)),
+                apply_ovf, rows_built)
 
     # ---------------- host-engine apply ----------------
 

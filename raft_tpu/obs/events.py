@@ -129,6 +129,14 @@ TIMELINE_STAGES = (
 # seen_lanes: the lanes of the seen run the wave ran against (its size
 # before the wave's own merge, which may step it up), so a trace says
 # which waves merged and which searched.
+# expand_rows_built / expand_rows_budget (the device engine's rows, and
+# the run's totals on stats and the summary): the successor rows the
+# wave's apply passes built (models/base.py sparse_apply: a group's rows
+# in tiles under the group's own count; lane 10 of the same stats
+# vector) and the rows their plan budgets, sum(sparse_plan) a
+# chunk-step. Built over budget is how far the apply pass follows what
+# a chunk keeps; built over generated is what it still builds for
+# nothing (whole tiles, a tile at least for a group that keeps a lane).
 # hbm_bytes / hbm_peak_rise / hbm_frac (obs/memwatch.py, on every run):
 # the allocator's bytes_in_use at the wave's end, after the seen merge
 # and any growth (what the run holds between programs); by how much its
@@ -439,6 +447,8 @@ def validate_event(ev: object, lineno: int | None = None) -> list[str]:
         for key, what in (
             ("dedup_search_queries", "query lanes the dedup stage searched"),
             ("seen_lanes", "lanes of the seen run the wave ran against"),
+            ("expand_rows_built", "successor rows the apply pass built"),
+            ("expand_rows_budget", "successor rows the apply's plan budgets"),
         ):
             val = ev.get(key)
             if val is not None and not _is_count(val):

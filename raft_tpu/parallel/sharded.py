@@ -420,7 +420,9 @@ class ShardedBFS(FleetQueue):
                 fired_k = rank_counts(rank, valid, K)
 
             # 2. compact the valid lanes into the [VC, W] successor block
-            flatc, sel, selv, sel_rank, compact_ovf = compact_chunk(
+            # (the apply pass's count of the rows it built stays here:
+            # this engine's stats have no lane for it)
+            flatc, sel, selv, sel_rank, compact_ovf, _ = compact_chunk(
                 model, self._plan, batch, succs, valid, rank, K, n_gen, VC)
             parent_lgid = base_lgid + cursor + sel // A
             cand = sel % A

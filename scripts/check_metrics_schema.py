@@ -47,7 +47,9 @@ canon_tier3_full (lanes its canon routed to tier 3's buckets) must be
 non-negative ints that together do not exceed generated -
 canon_dup_lanes (the representatives its in-chunk dedup let through),
 and its dedup_sort_lanes, where the engine counts them (the lanes its
-dedup stage's merged sort sorted), a non-negative int. A `manifest` or
+dedup stage's merged sort sorted), a non-negative int, as are its
+expand_rows_built and expand_rows_budget (the successor rows its apply
+passes built and the rows their plan budgets). A `manifest` or
 `summary` event's dedup_plan, where it has one, must list merge, search
 and wave_prefix (and rungs, where it says them) as non-negative ints,
 wave_prefix strictly increasing from 0 where it is not empty, rungs

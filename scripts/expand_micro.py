@@ -17,6 +17,16 @@ cumsum and ``.at[dst].set`` scatter it replaced, K times inside one
 program at two K, so the call's own 0.8 ms cancels: ns a lane of each
 (ROADMAP S3, S8 d and S13 price their sorts from it).
 
+``--fill [CELL ...]`` times what the apply pass costs against what a
+chunk keeps (PR 56), on the chip: one chunk's ``sparse_apply`` of a
+benchmark cell's model at the cell's chunk, VC and plan, on a real
+frontier, with the worklist filled to 1/16 ... 1 of VC in the real
+chunk's own mix of groups, under each of ``TILE_RULES`` (the tile a trip
+of a block builds, ``models/base.py::apply_tile``, swapped in the
+process), ms a call from K calls inside one program at two K; beside it
+the last gather alone (``tiled_rows``) at the same fills and tiles. The
+rule in the tree is the one this read fixed.
+
 Two dense baselines are timed, because they differ enormously:
 
   dense_mat  vmap of the full kernels MATERIALIZING the [chunk, A, W]
@@ -58,6 +68,7 @@ Usage:
                                  [--msg-slots 32] [--depth 10]
                                  [--reps 5] [--platform cpu]
   python scripts/expand_micro.py --compaction 16384 65536 217088
+  python scripts/expand_micro.py --fill raft3-wide pull3-full
 
 Writes chiprun_out/expand_micro.json (device provenance + one row per
 (chunk, vpg) cell), where a chip run's results come back.
@@ -103,7 +114,7 @@ def bench_cell(model, batch_h, vpg, reps):
     # -- dense path: full kernels over every lane + compaction gather
     def dense(b):
         succs, valid, rank, ovf = jax.vmap(model._expand1)(b)
-        flatc, _sel, selv, _rank, _ovf = compact_chunk(
+        flatc, _sel, selv, _rank, _ovf, _built = compact_chunk(
             None, None, b, succs, valid, rank,
             len(model.ACTION_NAMES), jnp.sum(valid), VC)
         return flatc, selv
@@ -130,7 +141,7 @@ def bench_cell(model, batch_h, vpg, reps):
     valid, _, _ = guards(batch)
     sel, selv = wl_j(valid)
     flatc_d, _ = dense_j(batch)
-    flatc_s, ovf = apply_j(batch, sel, selv)
+    flatc_s, ovf, _built = apply_j(batch, sel, selv)
     parity = bool(
         np.array_equal(np.asarray(flatc_d), np.asarray(flatc_s))
     )
@@ -207,6 +218,173 @@ def bench_compaction(lanes, reps):
     return row
 
 
+# the tile one trip of a block of ``rows`` builds at chunk C: the rule
+# in the tree, ``fine`` (``models/base.py::apply_tile``, whose docstring
+# quotes this grid's read on the chip), beside the ones it was chosen
+# among; ``whole`` is the one-shot pass at the budget, under a loop of
+# at most one trip
+TILE_RULES = {
+    "whole": lambda rows, C: rows,
+    "quarter": lambda rows, C: max(rows // 4, min(rows, C)),
+    "eighth": lambda rows, C: max(rows // 8, min(rows, C)),
+    "chunk": lambda rows, C: min(rows, C),
+    "fine": lambda rows, C: max(1, rows // 16, min(rows, C // 4)),
+}
+FILLS = (1 / 16, 1 / 8, 1 / 4, 1 / 2, 1.0)
+
+
+def _frontier(model, rows, depth=64):
+    """``rows`` reachable states of ``model``: the first wave of a
+    manual wave loop with exact-bytes dedup that holds as many, or the
+    wave at ``depth``, tiled if it is short. Guard density and the
+    groups' mix on real states are the honest input, random bit
+    patterns are not."""
+    import jax
+    import numpy as np
+
+    frontier = model.init_states()
+    seen = set()
+    B, W = 1024, model.layout.W
+    for _ in range(depth):
+        nxt = []
+        for off in range(0, len(frontier), B):
+            cs = frontier[off:off + B]
+            nb = len(cs)
+            if nb < B:
+                cs = np.concatenate([cs, np.repeat(cs[-1:], B - nb, axis=0)])
+            succs, valid, _, _ = jax.device_get(model.expand(cs))
+            valid = np.array(valid)
+            valid[nb:] = False
+            flat = np.array(succs).reshape(-1, W)
+            for i in np.nonzero(valid.reshape(-1))[0]:
+                t = flat[i].tobytes()
+                if t not in seen:
+                    seen.add(t)
+                    nxt.append(flat[i])
+        if not nxt:
+            break
+        frontier = np.array(nxt, dtype=np.int32)
+        if len(frontier) >= rows:
+            break
+    return np.tile(frontier, (-(-rows // len(frontier)), 1))[:rows]
+
+
+def _filled(model, valid, n, rng):
+    """An ascending worklist of ``n`` flat candidate lanes in the mix of
+    groups the chunk's ``valid`` lanes have: each group's enabled lanes
+    first, topped up with disabled lanes of the same group (a kernel
+    builds a row whatever its guard says, at the same cost)."""
+    import numpy as np
+
+    C, A = valid.shape
+    flat = np.arange(C * A, dtype=np.int32)
+    cand = flat % A
+    vflat = valid.reshape(-1)
+    groups = model.sparse_groups()
+    member = [(cand >= g.off) & (cand < g.off + g.n) for g in groups]
+    counts = np.array([int((m & vflat).sum()) for m in member])
+    want = np.floor(counts / counts.sum() * n).astype(int)
+    want[int(np.argmax(counts))] += n - want.sum()
+    picked = []
+    for m, k in zip(member, want):
+        on, off = flat[m & vflat], flat[m & ~vflat]
+        k = min(k, len(on) + len(off))
+        take = rng.permutation(on)[:k]
+        picked += [take, rng.permutation(off)[:k - len(take)]]
+    return np.sort(np.concatenate(picked)).astype(np.int32)
+
+
+def bench_fill(cell_name, reps):
+    """Rows of {rule, fill, apply_ms, gather_ms, rows_built, ...} for one
+    benchmark cell's model, chunk, VC and plan."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax import lax
+
+    from benchmark import adapter
+    from raft_tpu.models import base
+
+    bench = os.path.join(ROOT, "benchmark")
+    with open(os.path.join(bench, "workloads", f"{cell_name}.json")) as f:
+        cell = json.load(f)
+    cfg_dir = os.path.join(bench, "configs", cell["config"])
+    with open(os.path.join(cfg_dir, "config.json")) as f:
+        config = json.load(f)
+    eng = adapter.build_engine(
+        os.path.join(cfg_dir, config["cfg"]), "device",
+        cell["engine_params"], jax.devices()[:1])
+    model, C, VC, plan = eng.model, eng.chunk, eng.VC, eng._plan
+    A, W = model.A, model.layout.W
+    rng = np.random.default_rng(56)
+    batch = jnp.asarray(_frontier(model, C))
+    valid = np.asarray(jax.jit(jax.vmap(model.guards1))(batch)[0])
+    sels = {}
+    for fill in FILLS:
+        lanes = _filled(model, valid, int(VC * fill), rng)
+        sels[fill] = jnp.asarray(np.concatenate(
+            [lanes, np.full(VC - len(lanes), C * A, np.int32)]))
+    total = sum(plan)
+    # the last gather alone: VC random rows of a block of the tree's
+    # size, VC rows, a tile and the zeros row
+    block = jnp.asarray(
+        rng.integers(0, 1 << 20, (VC + C + 1, W)).astype(np.int32))
+    rowsel = jnp.asarray(rng.integers(0, VC, (VC,)).astype(np.int32))
+
+    def per_call(fn, *args):
+        """ms a call: K calls inside one program (K a traced count, so
+        one compile), at K = 2 and K = 10; the difference is 8 calls
+        with no dispatch in them."""
+        ts = {K: _time(fn, jnp.int32(K), *args, reps=reps) for K in (2, 10)}
+        return (ts[10] - ts[2]) / 8 * 1e3
+
+    rows = []
+    rule_in_tree = base.apply_tile
+    want = {}  # the one-shot pass's rows a fill: ``whole`` runs first
+    try:
+        for rule, tile in TILE_RULES.items():
+            base.apply_tile = tile
+
+            def apply_k(K, b, s):
+                def body(i, acc):
+                    # the chunk re-read through the counter: nothing
+                    # here is the loop's invariant
+                    flatc, _ovf, built = model.sparse_apply(
+                        b + (i >> 20), s, s < C * A, plan)
+                    return acc + flatc[i].sum(dtype=jnp.int32) + built
+                return lax.fori_loop(jnp.int32(0), K, body, jnp.int32(0))
+
+            def gather_k(K, blk, r, n):
+                def body(i, acc):
+                    out = base.tiled_rows(
+                        blk, r + (i >> 20), n, tile(VC, C))
+                    return acc + out[i].sum(dtype=jnp.int32)
+                return lax.fori_loop(jnp.int32(0), K, body, jnp.int32(0))
+
+            apply_j, gather_j = jax.jit(apply_k), jax.jit(gather_k)
+            once = jax.jit(
+                lambda b, s: model.sparse_apply(b, s, s < C * A, plan))
+            for fill, sel in sels.items():
+                got, _ovf, built = jax.device_get(once(batch, sel))
+                want.setdefault(fill, got)
+                n = int((np.asarray(sel) < C * A).sum())
+                row = {
+                    "workload": cell_name, "chunk": C, "A": A, "W": W,
+                    "vc": VC, "plan_rows": total, "rule": rule,
+                    "tile_of_vc": tile(VC, C), "fill": fill, "lanes": n,
+                    "rows_built": int(built),
+                    "parity": bool(np.array_equal(got, want[fill])),
+                    "apply_ms": round(per_call(apply_j, batch, sel), 4),
+                    "gather_ms": round(per_call(
+                        gather_j, block, rowsel, jnp.int32(n)), 4),
+                }
+                rows.append(row)
+                print(json.dumps(row), flush=True)
+    finally:
+        base.apply_tile = rule_in_tree
+    return rows
+
+
 def main():
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--chunk", type=int, nargs="+", default=[1024, 4096])
@@ -223,12 +401,41 @@ def main():
                     metavar="LANES",
                     help="time one compaction of LANES int32 lanes by "
                          "sort and by scatter, and nothing else")
+    ap.add_argument("--fill", nargs="*", default=None, metavar="CELL",
+                    help="time sparse_apply of these benchmark cells "
+                         "(default raft3-wide pull3-full) against the "
+                         "worklist's fill under each tile rule, and "
+                         "nothing else")
     args = ap.parse_args()
 
     import jax
 
     if args.platform:
         jax.config.update("jax_platforms", args.platform)
+    if args.fill is not None:
+        rows = []
+        for cell in args.fill or ["raft3-wide", "pull3-full"]:
+            rows += bench_fill(cell, args.reps)
+        dev = jax.devices()[0]
+        path = os.path.join(ROOT, "chiprun_out", "expand_micro.json")
+        os.makedirs(os.path.dirname(path), exist_ok=True)
+        with open(path, "w") as f:
+            json.dump({
+                "meta": {
+                    "device": str(dev), "platform": dev.platform,
+                    "device_kind": getattr(dev, "device_kind", None),
+                    "when": time.strftime("%Y-%m-%d %H:%M:%S"),
+                    "reps": args.reps,
+                    "note": "ms a call of one chunk's sparse_apply "
+                            "(apply_ms) and of its last gather alone "
+                            "(gather_ms), 8 calls inside one program, "
+                            "by tile rule and by the share of VC the "
+                            "worklist holds",
+                },
+                "rows": rows,
+            }, f, indent=1)
+        print(f"wrote {path}")
+        return
     if args.compaction:
         rows = [bench_compaction(n, args.reps) for n in args.compaction]
         for row in rows:
@@ -240,8 +447,6 @@ def main():
                       indent=1)
         print(f"wrote {path}")
         return
-    import numpy as np
-
     from raft_tpu.models.raft import RaftModel, RaftParams
 
     model = RaftModel(RaftParams(
@@ -249,35 +454,7 @@ def main():
         max_elections=args.elections, max_restarts=args.restarts,
         msg_slots=args.msg_slots,
     ))
-    # a reachable frontier (manual wave loop with exact-bytes dedup):
-    # guard density on real states is the honest input, random bit
-    # patterns are not; shallow spaces tile the deepest wave
-    frontier = model.init_states()
-    seen = set()
-    for _ in range(args.depth):
-        nxt = []
-        B, W = 1024, model.layout.W
-        for off in range(0, len(frontier), B):
-            cs = frontier[off:off + B]
-            nb = len(cs)
-            if nb < B:
-                cs = np.concatenate(
-                    [cs, np.repeat(cs[-1:], B - nb, axis=0)])
-            succs, valid, _, _ = jax.device_get(model.expand(cs))
-            valid = np.array(valid)
-            valid[nb:] = False
-            flat = np.array(succs).reshape(-1, W)
-            for i in np.nonzero(valid.reshape(-1))[0]:
-                t = flat[i].tobytes()
-                if t not in seen:
-                    seen.add(t)
-                    nxt.append(flat[i])
-        if not nxt:
-            break
-        frontier = np.array(nxt, dtype=np.int32)
-        if len(frontier) >= max(args.chunk):
-            break
-    del seen
+    frontier = _frontier(model, max(args.chunk), args.depth)
 
     rows = []
     hdr = (f"{'chunk':>6} {'vpg':>6} {'lanes':>8} {'dense':>10} "
@@ -285,8 +462,7 @@ def main():
            f"{'vs_fused':>8} {'vs_mat':>8} {'ovf':>5}")
     print(hdr)
     for C in args.chunk:
-        reps_needed = -(-C // len(frontier))
-        batch_h = np.tile(frontier, (reps_needed, 1))[:C]
+        batch_h = frontier[:C]
         for v in args.vpg:
             # "tuned" = per-group budgets measured on this geometry
             if v == "loose":

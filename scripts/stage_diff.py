@@ -173,7 +173,7 @@ def stages(args, ref):
         buf[: len(block)] = block
         return buf
 
-    flatc, sel, selv, sel_rank, _v, _r, n_gen, _t, eo, co = jax.device_get(
+    flatc, sel, selv, sel_rank, _v, _r, n_gen, _t, eo, co, _b = jax.device_get(
         jax.jit(eng._st_expand)(padded(rows), np.int32(0), np.int32(n)))
     out.update(sparse_rows=flatc, sparse_sel=sel, sparse_selv=selv,
                sparse_sel_rank=sel_rank,

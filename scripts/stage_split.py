@@ -16,9 +16,14 @@ cpu`` rehearses.
         [--out chiprun_out/stage_split.json]
 
 ``expand_by_group_s`` is ``expand``'s seconds by the action group whose
-successors an op built (``sparse_apply``'s scope round each group,
-``expand/Restart``; ``-`` is what ``expand`` runs outside any group: the
-guard pass, the two sorts, the worklist's assembly). ``--top N`` keeps
+successors an op built (``sparse_apply``'s scope round each group's loop
+over the tiles of what it keeps, ``expand/Restart``: an op of the loop's
+body reads ``expand/Restart/while/body/...`` and is booked to the group,
+control flow being no scope; ``-`` is what ``expand`` runs outside any
+group: the guard pass, the two sorts, the worklist's assembly and the
+last gather's loop). Beside it ``expand_rows_built`` and
+``expand_rows_budget``, the program's own count of the rows those loops
+built and the rows their plan budgets. ``--top N`` keeps
 the N heaviest ops; ``--gather`` puts the per-lane
 reads back behind ``models/base.py``'s one-hot read helpers
 (``scripts/stage_diff.py``'s switch), so old reads and new are timed from
@@ -153,6 +158,8 @@ def main(argv=None):
         res["dedup_plan"] = got["stats"].get("dedup_plan")
         res["dedup_sort_lanes"] = got["stats"].get("dedup_sort_lanes")
         res["dedup_search_queries"] = got["stats"].get("dedup_search_queries")
+        res["expand_rows_built"] = got["stats"].get("expand_rows_built")
+        res["expand_rows_budget"] = got["stats"].get("expand_rows_budget")
         res["frontier_peak_rows"] = got["stats"].get("frontier_peak_rows")
         res["restart_fired"] = got["stats"].get("restart_fired")
         res["canon_lanes"] = {

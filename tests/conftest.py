@@ -40,12 +40,33 @@ def collect_states(oracle, max_depth, cap=150):
     return list(seen.values())
 
 
-def eqns(jaxpr):
-    """Every equation of a jaxpr and of the jaxprs nested in it."""
+def eqns(jaxpr, under=None):
+    """Every equation of a jaxpr and of the jaxprs nested in it. An
+    equation inside control flow or a call that was traced under a named
+    scope carries that scope's path in front of its own name stack, as
+    the lowering composes it: a group's kernels under ``sparse_apply``'s
+    loop read ``expand/Restart/while/body/vmap()``, where the body's own
+    jaxpr says ``vmap()`` alone. Control flow under no scope (a wave
+    program's loop over its chunks) adds nothing, so a stage's path
+    starts a stack as it does in the program's own jaxpr."""
     for eqn in jaxpr.eqns:
+        if under is not None:
+            info = eqn.source_info
+            eqn = eqn.replace(source_info=info.replace(
+                name_stack=under + info.name_stack))
         yield eqn
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            yield from eqns(sub)
+        stack = eqn.source_info.name_stack
+        for key, val in eqn.params.items():
+            subs = jax.core.jaxprs_in_params({key: val})
+            for i, sub in enumerate(subs):
+                inner = None
+                if str(stack):
+                    inner = stack.extend(eqn.primitive.name)
+                    if eqn.primitive.name == "while":
+                        inner = inner.extend(key.partition("_")[0])
+                    elif eqn.primitive.name == "cond":
+                        inner = inner.extend(f"branch_{i}_fun")
+                yield from eqns(sub, inner)
 
 
 def _kernel_primitives(model, batch=None):

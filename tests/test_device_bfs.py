@@ -7,6 +7,8 @@ uses one-hot writes because of one); the chip half is the runtime parity
 gate in checker/parity.py.
 """
 
+import collections
+
 import numpy as np
 import pytest
 
@@ -93,6 +95,72 @@ def test_device_bfs_max_depth_and_time_budget():
     full = _device(SMALL, INVS).run()
     assert full.exhausted
     assert full.depth_counts[:6] == res.depth_counts[:6]
+
+
+@pytest.mark.parametrize("engine", ["device", "sharded"])
+def test_tiled_apply_keeps_the_host_checkers_counts(engine):
+    """A depth-bounded run of each device engine at a chunk of 32 rows,
+    where a chunk-step of the wider waves holds several tiles of
+    ``HandleMessage`` (PR 56: a group's rows are built a tile a trip
+    under the group's count) and the narrow ones hold none of most
+    groups: per-depth distinct, generated and terminal counts equal the
+    host checker's."""
+    import jax
+
+    from raft_tpu.parallel.sharded import ShardedBFS
+
+    model = cached_model(SMALL)
+    want = BFSChecker(
+        model, invariants=INVS, symmetry=True, chunk=256).run(max_depth=14)
+    kw = dict(invariants=INVS, symmetry=True, chunk=32,
+              frontier_cap=1 << 10, seen_cap=1 << 12)
+    if engine == "device":
+        eng = DeviceBFS(model, journal_cap=1 << 12, **kw)
+    else:
+        eng = ShardedBFS(model, devices=jax.devices()[:2], **kw)
+    got = eng.run(max_depth=14)
+    assert want.violation is None and got.trace is None
+    assert (got.distinct, got.total, got.terminal, got.depth_counts) == (
+        want.distinct, want.total, want.terminal, want.depth_counts)
+    assert max(got.depth_counts) > 2 * eng.chunk
+
+
+def test_expand_rows_built_is_the_hand_count():
+    """``expand_rows_built`` on the wave rows, ``stats`` and the summary
+    (PR 56): the rows the apply pass's tiles built, beside
+    ``expand_rows_budget``, ``sum(plan)`` a chunk-step. Wave 1 expands
+    ``Init`` alone, so the hand count is each group's enabled lanes of
+    that one state in whole tiles; every wave builds no more than it
+    budgets, far less while the frontier is narrow, and the run's
+    totals are the rows' sums."""
+    import jax
+
+    from raft_tpu.models.base import apply_tile
+
+    eng = _device(SMALL, INVS, chunk=64, frontier_cap=1 << 10,
+                  seen_cap=1 << 12, journal_cap=1 << 12)
+    model, C = eng.model, eng.chunk
+    res = eng.run(max_depth=8, collect_metrics=True)
+    rows = res.metrics
+    init = model.init_states()
+    assert len(init) == 1
+    valid = np.asarray(jax.vmap(model.guards1)(init)[0])
+    counts = [int(valid[:, g.off:g.off + g.n].sum())
+              for g in model.sparse_groups()]
+    assert sum(counts) == rows[0]["generated"] > 0
+    tiles = [apply_tile(eb, C) for eb in eng._plan]
+    assert rows[0]["expand_rows_built"] == sum(
+        -(-min(n, eb) // T) * T for n, eb, T in zip(counts, eng._plan, tiles))
+    for w in rows:
+        steps = -(-w["frontier"] // C)
+        assert w["expand_rows_budget"] == steps * sum(eng._plan)
+        assert w["generated"] <= w["expand_rows_built"] <= (
+            w["expand_rows_budget"])
+    # one state's wave builds a tile of each group that has a lane, not
+    # the plan's 2.3 x VC rows
+    assert rows[0]["expand_rows_built"] * 4 < rows[0]["expand_rows_budget"]
+    for key in ("expand_rows_built", "expand_rows_budget"):
+        assert res.stats[key] == sum(w[key] for w in rows)
 
 
 def test_device_bfs_rejects_indivisible_chunk():
@@ -198,18 +266,6 @@ def test_device_bfs_checkpoint_spec_mismatch(tmp_path):
 # ------------------------------------------------ no scatter-add in a wave
 
 
-def _eqns(jaxpr):
-    """Every equation of a jaxpr and of the jaxprs its equations hold
-    (pjit, while, cond, shard_map, ...)."""
-    for eqn in jaxpr.eqns:
-        yield eqn
-        for param in eqn.params.values():
-            for sub in param if isinstance(param, (list, tuple)) else (param,):
-                sub = getattr(sub, "jaxpr", sub)
-                if hasattr(sub, "eqns"):
-                    yield from _eqns(sub)
-
-
 def _device_wave():
     eng = _device(SMALL, INVS, chunk=256, frontier_cap=1 << 12,
                   seen_cap=1 << 14, journal_cap=1 << 14)
@@ -231,22 +287,27 @@ def _sharded_chunk():
 
 def _traced(make):
     """(engine, program name, every equation) of the program a wave
-    dispatches, traced from the engine's own audit entry; nothing
-    compiled."""
+    dispatches, each under the scopes its control flow was traced in
+    (``conftest.eqns``), traced from the engine's own audit entry;
+    nothing compiled."""
     import jax
+
+    from conftest import eqns
 
     eng, name = make()
     (prog,) = [p for p in eng.audit_programs() if p["name"] == name]
     return eng, name, list(
-        _eqns(jax.make_jaxpr(prog["fn"])(*prog["args"]).jaxpr))
+        eqns(jax.make_jaxpr(prog["fn"])(*prog["args"]).jaxpr))
 
 
 def _spec_body(stack: str) -> bool:
     """An equation of the spec lowering's own per-state body: the guard
     pass, ``expand/vmap()``, or a group's kernels under the group's
-    scope, ``expand/Restart/vmap()`` (PR 51)."""
+    scope and, since PR 56, its loop over the tiles of what the group
+    keeps, ``expand/Restart/while/body/vmap()``."""
     head, _, rest = stack.partition("/")
-    return head == "expand" and "vmap()" in rest.split("/")[:2]
+    path = [p for p in rest.split("/") if p not in ("while", "body")]
+    return head == "expand" and "vmap()" in path[:2]
 
 
 @pytest.mark.parametrize(
@@ -316,6 +377,54 @@ def test_wave_program_expand_compacts_by_sorts(make):
     sorts = [e for e in own if e.primitive.name == "sort"]
     assert [len(e.invars) for e in sorts] == [1, 1], sorts
     assert all(str(v.aval.dtype) == "int32" for e in sorts for v in e.invars)
+
+
+@pytest.mark.parametrize(
+    "make", [_device_wave, _sharded_chunk], ids=["device", "sharded"])
+def test_wave_program_builds_a_group_in_tiles_under_its_count(make):
+    """The engagement check of the apply pass's loops (PR 56): a group's
+    rows are built a tile a trip under a trip count that comes from the
+    group's own count, so the program a wave dispatches holds ONE
+    ``while`` a group under ``expand/<Group>``, the group's kernel is
+    traced once, in that loop's body, at the tile's width
+    (``models/base.py::apply_tile`` of the group's budget) and not at
+    the budget, nothing concatenates the groups' blocks, and the last
+    gather is one more ``while`` under the bare ``expand``. The group's
+    scope is opened outside the loop and the vmap, so the benchmark's
+    rule (``xplane.scope_path``) names an op of the loop's body by its
+    group."""
+    from benchmark import xplane
+    from raft_tpu.models.base import apply_tile
+
+    eng, name, eqns = _traced(make)
+    groups = eng.model.sparse_groups()
+    stack = lambda e: str(e.source_info.name_stack)
+    whiles = collections.Counter(
+        stack(e) for e in eqns
+        if e.primitive.name == "while" and stack(e).startswith("expand"))
+    assert whiles == {
+        "expand": 1, **{f"expand/{g.name}": 1 for g in groups}}, whiles
+    for g, eb in zip(groups, eng._plan):
+        T = apply_tile(eb, eng.chunk)
+        body = f"expand/{g.name}/while/body"
+        kernel = [e for e in eqns if stack(e).startswith(body + "/vmap()")]
+        assert kernel, g.name
+        assert not [e for e in eqns
+                    if stack(e).startswith(f"expand/{g.name}/vmap()")]
+        # every row the loop's body touches is a tile's, never the budget's
+        lead = {v.aval.shape[0] for e in kernel for v in e.outvars
+                if v.aval.shape}
+        assert T in lead and (eb == T or eb not in lead), (g.name, lead)
+        (write,) = [e for e in eqns if stack(e) == body
+                    and e.primitive.name == "dynamic_update_slice"]
+        assert write.invars[1].aval.shape == (T, eng.W)
+        assert xplane.scope_path(
+            f"jit(_wave_step)/while/body/{body}/vmap()/select_n:"
+        ) == ("expand", g.name)
+    assert not [e for e in eqns if e.primitive.name == "concatenate"
+                and stack(e).startswith("expand")
+                and e.outvars[0].aval.shape[1:] == (eng.W,)
+                and e.outvars[0].aval.shape[0] > eng.VC]
 
 
 @pytest.mark.parametrize(

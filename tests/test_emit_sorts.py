@@ -75,7 +75,7 @@ def _finish(model, C, VC, K, FCAP, JCAP):
 
     def run(flatc, sel, sel_rank, valid, rank, new, ncount, cursor,
             base_gid):
-        stats = jnp.zeros((10,), jnp.int64).at[:2].set(ncount)
+        stats = jnp.zeros((DeviceBFS.N_STATS,), jnp.int64).at[:2].set(ncount)
         fps = jnp.arange(VC, dtype=jnp.uint64)
         out = DeviceBFS._st_finish(
             eng, jnp.full((FCAP + VC, W), -7, jnp.int32),
@@ -86,7 +86,7 @@ def _finish(model, C, VC, K, FCAP, JCAP):
             jnp.zeros((FCAP + VC,), jnp.uint64), flatc, fps, sel,
             sel_rank, valid, rank, new, jnp.sum(valid), jnp.int32(0),
             jnp.bool_(False), jnp.bool_(False), jnp.zeros((3,), jnp.int32),
-            jnp.zeros((2,), jnp.int32), cursor, base_gid)
+            jnp.zeros((2,), jnp.int32), jnp.int32(0), cursor, base_gid)
         return out[0], out[1], out[2], out[5]
 
     return jax.jit(run)
@@ -121,7 +121,7 @@ def test_emit_equals_the_retired_gathers_and_scatter(family):
                         ("room", vh, n + 5), ("all", vh, n),
                         ("overflow", vh, n - 1)]:
         v = jnp.asarray(v)
-        flatc, sel, selv, sel_rank, ovf = jax.jit(
+        flatc, sel, selv, sel_rank, ovf, _ = jax.jit(
             lambda b, s, vv, r, VC=VC: compact_chunk(
                 None, None, b, s, vv, r, K, jnp.sum(vv), VC)
         )(batch, succs, v, rank)
@@ -169,9 +169,9 @@ def test_compact_chunk_without_ranks_sorts_the_lanes_alone():
     succs, valid, rank, _ = jax.jit(jax.vmap(model._expand1))(batch)
     assert rank_key_bits(C, A, 0) == 0
     VC = int(np.asarray(valid).sum()) + 3
-    _, sel0, selv0, no_rank, _ = compact_chunk(
+    _, sel0, selv0, no_rank, _, _ = compact_chunk(
         None, None, batch, succs, valid, None, 0, jnp.sum(valid), VC)
-    _, sel, selv, _, _ = compact_chunk(
+    _, sel, selv, _, _, _ = compact_chunk(
         None, None, batch, succs, valid, rank, K, jnp.sum(valid), VC)
     assert (np.asarray(no_rank) == -1).all()
     np.testing.assert_array_equal(sel0, sel)

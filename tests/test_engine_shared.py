@@ -33,6 +33,19 @@ equations fewer in every wave and chunk program); which lane is new, and
 every row, journal entry, fingerprint appended, count and coverage cell,
 bit-equal to PR 53's tree (``tests/test_symmetry_v3.py``'s ``first_new``
 property; ``stage_diff.py``'s wave stage against the parent's dump).
+PR 56 re-pinned them, on purpose: ``sparse_apply`` builds each group's
+rows a tile a trip under a loop whose trip count is the group's own
+count, into one block (no ``concatenate``), gathers the compacted block
+in tiles of the worklist under its count, and counts the rows it built;
+the device engine's stats vector has an eleventh lane for that count
+(which is the four equations the dense arm's wave gained; the dense
+chunk program is as it was). A loop's body adds its own slice, gather
+and ``dynamic_update_slice`` and the loop's counter a group: 205 to 345
+equations more in a wave program, five fewer than that in a chunk
+program, which drops the count. Every row of every in-budget worklist
+lane, the overflow bits and every count bit-equal to PR 55's tree
+(``tests/test_expand_compaction.py``'s ``_reference_sparse_apply``;
+``stage_diff.py``'s stages against the parent's dump).
 Nothing is compiled or run.
 
 A PR that means to change a program re-pins its digest on purpose, says
@@ -57,36 +70,36 @@ ENGINES = {"device": DeviceBFS, "sharded": ShardedBFS}
 # the seen merge has no model in it: one digest for every family
 SEEN_MERGE = (12, "19ac660b935d83db")
 
-# {family: {engine: {program: (equations, digest)}}} at PR 54's tree
+# {family: {engine: {program: (equations, digest)}}} at PR 56's tree
 PARENT_PROGRAMS = {
     "raft": {
-        "device": {"wave": (4848, "483adb95782f5301"),
+        "device": {"wave": (5081, "fd71131f4988c26d"),
                    "seen_merge": SEEN_MERGE},
-        "sharded": {"chunk": (5245, "4605122e34e2e033")}},
+        "sharded": {"chunk": (5473, "2e2723c1286523e4")}},
     "raft-dense": {
-        "device": {"wave": (3607, "28eb4b312002a35c"),
+        "device": {"wave": (3611, "c45a2f745ea0f2fe"),
                    "seen_merge": SEEN_MERGE},
         "sharded": {"chunk": (4004, "0543045755cbcf6f")}},
     "pull_raft": {
-        "device": {"wave": (5210, "49da28aba43f0a98"),
+        "device": {"wave": (5415, "10950deba96e8a0d"),
                    "seen_merge": SEEN_MERGE},
-        "sharded": {"chunk": (5607, "92c04ef174ccfff2")}},
+        "sharded": {"chunk": (5807, "a923a619c5d67983")}},
     "kraft": {
-        "device": {"wave": (6549, "29788697bcf837b4"),
+        "device": {"wave": (6754, "11dee5598087e3ea"),
                    "seen_merge": SEEN_MERGE},
-        "sharded": {"chunk": (6946, "5245554c3deba11a")}},
+        "sharded": {"chunk": (7146, "958c32a38d91af06")}},
     "joint_raft": {
-        "device": {"wave": (10882, "e898caf6a7f5b8f2"),
+        "device": {"wave": (11199, "74543251b72d4e28"),
                    "seen_merge": SEEN_MERGE},
-        "sharded": {"chunk": (11279, "dd9e35d7a0a69421")}},
+        "sharded": {"chunk": (11591, "b134551dbdaa8f1a")}},
     "kraft_reconfig": {
-        "device": {"wave": (14918, "7d1e79bd8d7aa2ec"),
+        "device": {"wave": (15207, "fa04d9286b841468"),
                    "seen_merge": SEEN_MERGE},
-        "sharded": {"chunk": (15315, "b1b9d3b4c4fe9a52")}},
+        "sharded": {"chunk": (15599, "7c18ccfab891ea11")}},
     "reconfig_raft": {
-        "device": {"wave": (10507, "4fef53434077f94b"),
+        "device": {"wave": (10852, "5e6a34885911c755"),
                    "seen_merge": SEEN_MERGE},
-        "sharded": {"chunk": (10904, "e6dc777d1a8d2aa0")}},
+        "sharded": {"chunk": (11244, "b2a6225c29a55675")}},
 }
 
 

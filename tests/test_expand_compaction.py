@@ -15,12 +15,16 @@ tests/test_expand_sparse.py, whose cases are the longest file of the
 suite: each family compiles its apply pass twice here.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from raft_tpu.models.base import apply_tile
 from test_expand_sparse import FAMILIES, _chunk_of, _raft
+
 
 def _reference_compact(valid, VC):
     """The retired valid-lane compaction of ``engine.compact_chunk``:
@@ -85,12 +89,29 @@ def _reference_sparse_apply(model, batch, sel, selv, plan):
     return allb[row], apply_ovf
 
 
+# what the busiest group of a worklist holds, by name: against its
+# budget ``eb`` and its tile ``T = apply_tile(eb, C)`` (PR 56: a group's
+# rows are built a tile a trip under the group's own count)
+GROUP_COUNTS = {
+    "count_0": lambda eb, T: 0,
+    "count_1": lambda eb, T: 1,
+    "count_tile_less_1": lambda eb, T: T - 1,
+    "count_tile": lambda eb, T: T,
+    "count_tile_plus_1": lambda eb, T: T + 1,
+    "at_budget": lambda eb, T: eb,
+    "one_past_budget": lambda eb, T: eb + 1,
+}
+CASES = (*GROUP_COUNTS, "empty_group", "random", "all_drop")
+
+
 def _worklists(model, valid, VC, seed=0):
-    """(plan, {case: sel}) over the enabled lanes of a real chunk: one
-    static plan, and ascending worklists (subsets of the chunk's valid
-    flat lanes, as ``compact_chunk`` would hand them) that put the
-    busiest group exactly at its budget and one lane past it, empty a
-    group that had lanes, keep a random subset, and drop every lane."""
+    """(plan, {case: (sel, overflows, lanes a group)}) over the enabled
+    lanes of a real chunk: one static plan, and ascending worklists
+    (subsets of the chunk's valid flat lanes, as ``compact_chunk`` would
+    hand them) that give the busiest group each count of
+    ``GROUP_COUNTS`` (none, one, a lane either side of a whole tile, its
+    budget and one lane past it), empty a group that had lanes, keep a
+    random subset, and drop every lane."""
     rng = np.random.default_rng(seed)
     C, A = valid.shape
     groups = model.sparse_groups()
@@ -100,45 +121,44 @@ def _worklists(model, valid, VC, seed=0):
     counts = [len(m) for m in member]
     gi = int(np.argmax(counts))
     assert counts[gi] >= 3, "frontier too shallow to exercise budgets"
-    # the busiest group's budget is under what the chunk enables; the
-    # others hold all of theirs (a group with nothing enabled keeps one
-    # row: a budget is never 0)
+    # the busiest group's budget is one lane under what the chunk
+    # enables; the others hold all of theirs (a group with nothing
+    # enabled keeps one row: a budget is never 0)
     plan = tuple(
-        counts[gi] // 2 if i == gi else max(1, c)
+        counts[gi] - 1 if i == gi else max(1, c)
         for i, c in enumerate(counts))
+    T = apply_tile(plan[gi], C)
 
     def pick(sizes):
         lanes = np.sort(np.concatenate([
             rng.choice(m, size=n, replace=False)
             for m, n in zip(member, sizes)]))
         assert len(lanes) <= VC
-        return np.concatenate([
-            lanes, np.full(VC - len(lanes), C * A)]).astype(np.int32)
+        return (np.concatenate([
+            lanes, np.full(VC - len(lanes), C * A)]).astype(np.int32),
+            any(n > eb for n, eb in zip(sizes, plan)), tuple(sizes))
 
     others = [int(rng.integers(0, c + 1)) for c in counts]
-    at = [plan[gi] if i == gi else n for i, n in enumerate(others)]
-    past = [plan[gi] + 1 if i == gi else n for i, n in enumerate(others)]
+    cases = {}
+    for case, count in GROUP_COUNTS.items():
+        n = min(max(count(plan[gi], T), 0), counts[gi])
+        cases[case] = pick(
+            [n if i == gi else m for i, m in enumerate(others)])
     # empty the second busiest group; the busiest stays in budget
     gj = int(np.argsort(counts)[-2])
     assert counts[gj] >= 1
-    emptied = [0 if i == gj else min(c, plan[i]) for i, c in enumerate(counts)]
-    return plan, {
-        "at_budget": (pick(at), False),
-        "one_past_budget": (pick(past), True),
-        "empty_group": (pick(emptied), False),
-        "random": (pick([min(n, plan[i]) for i, n in enumerate(others)]),
-                   False),
-        "all_drop": (np.full(VC, C * A, np.int32), False),
-    }
+    cases["empty_group"] = pick(
+        [0 if i == gj else min(c, plan[i]) for i, c in enumerate(counts)])
+    cases["random"] = pick([min(n, plan[i]) for i, n in enumerate(others)])
+    cases["all_drop"] = pick([0] * len(counts))
+    assert set(cases) == set(CASES)
+    return plan, cases
 
 
-@pytest.mark.parametrize("family", sorted(FAMILIES))
-def test_sparse_apply_equals_the_retired_scatter_segmentation(family):
-    """One sort of (group, flat lane) segments the worklist exactly as
-    the per-group scatters did: every row of the [VC, W] block and the
-    budget bit, on worklists with a group at and one past its budget
-    (``apply_ovf``), an emptied group, a random subset and no lane at
-    all. One plan a family, so each form compiles once."""
+@functools.lru_cache(maxsize=None)
+def _both_forms(family):
+    """One plan a family, so each form compiles once for all the cases
+    of the family (the test runner hands this file to one worker)."""
     model = FAMILIES[family]()
     C = 64
     A = model.A
@@ -149,13 +169,33 @@ def test_sparse_apply_equals_the_retired_scatter_segmentation(family):
     new = jax.jit(lambda b, s: model.sparse_apply(b, s, s < C * A, plan))
     old = jax.jit(
         lambda b, s: _reference_sparse_apply(model, b, s, s < C * A, plan))
-    for case, (sel, ovf) in cases.items():
-        got, got_ovf = jax.device_get(new(batch, jnp.asarray(sel)))
-        want, want_ovf = jax.device_get(old(batch, jnp.asarray(sel)))
-        assert bool(got_ovf) == bool(want_ovf) == ovf, case
-        np.testing.assert_array_equal(got, want, err_msg=case)
-        if case == "all_drop":
-            assert not np.asarray(got).any()
+    return C, batch, plan, cases, new, old
+
+
+@pytest.mark.parametrize("case", CASES)
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_sparse_apply_equals_the_retired_scatter_segmentation(family, case):
+    """One sort of (group, flat lane) segments the worklist exactly as
+    the per-group scatters did, and a group's rows built a tile a trip
+    under the group's count are the rows the one-shot block held: every
+    row of the [VC, W] block and the budget bit, on worklists whose
+    busiest group holds nothing, one lane, a lane either side of a whole
+    tile, its budget, and one lane past it (``apply_ovf``, the lane past
+    the budget a zeros row), with an emptied group, a random subset and
+    no lane at all. The rows built are whole tiles of what each group
+    keeps."""
+    C, batch, plan, cases, new, old = _both_forms(family)
+    sel, ovf, sizes = cases[case]
+    got, got_ovf, built = jax.device_get(new(batch, jnp.asarray(sel)))
+    want, want_ovf = jax.device_get(old(batch, jnp.asarray(sel)))
+    assert bool(got_ovf) == bool(want_ovf) == ovf
+    np.testing.assert_array_equal(got, want)
+    tiles = [apply_tile(eb, C) for eb in plan]
+    assert int(built) == sum(
+        -(-min(n, eb) // T) * T for n, eb, T in zip(sizes, plan, tiles))
+    assert int(built) <= sum(-(-eb // T) * T for eb, T in zip(plan, tiles))
+    if case == "all_drop":
+        assert not np.asarray(got).any() and int(built) == 0
 
 
 @pytest.mark.parametrize("family", sorted(FAMILIES))
@@ -183,7 +223,7 @@ def test_compact_chunk_equals_the_retired_scatter(family):
                        (valid, n - 1, True),
                        (jnp.zeros_like(valid), 8, False)]:
         n_gen = jnp.sum(v)
-        flatc, sel, selv, _, got_ovf = jax.device_get(jax.jit(
+        flatc, sel, selv, _, got_ovf, _ = jax.device_get(jax.jit(
             lambda b, s, vv, ng, VC=VC: compact_chunk(
                 None, None, b, s, vv, rank, K, ng, VC))(
                     batch, succs, v, n_gen))

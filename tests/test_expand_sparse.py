@@ -377,7 +377,7 @@ def test_sparse_apply_parity_loose_plan(family):
         [succs.reshape(C * A, W), jnp.zeros((1, W), jnp.int32)], axis=0,
     )[sel])
     plan = model.sparse_plan(C, VC)  # loose: overflow-impossible
-    flatc, ovf = jax.device_get(jax.jit(
+    flatc, ovf, _ = jax.device_get(jax.jit(
         lambda b, s, sv: model.sparse_apply(b, s, sv, plan)
     )(batch, sel, selv))
     assert not bool(ovf)
@@ -416,7 +416,7 @@ def test_apply_budget_exact_thresholds():
 
     # exactly-full: per-group budgets == enabled counts
     plan_exact = tuple(counts)
-    flatc, ovf = jax.device_get(jax.jit(
+    flatc, ovf, _ = jax.device_get(jax.jit(
         lambda b, s, sv: model.sparse_apply(b, s, sv, plan_exact)
     )(batch, sel, selv))
     assert not bool(ovf)
@@ -425,7 +425,7 @@ def test_apply_budget_exact_thresholds():
     # one-past-full: the squeezed group's LAST worklist lane spills
     plan_tight = tuple(
         c - 1 if i == gi else c for i, c in enumerate(counts))
-    flatc_t, ovf_t = jax.device_get(jax.jit(
+    flatc_t, ovf_t, _ = jax.device_get(jax.jit(
         lambda b, s, sv: model.sparse_apply(b, s, sv, plan_tight)
     )(batch, sel, selv))
     assert bool(ovf_t)

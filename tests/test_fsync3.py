@@ -450,7 +450,8 @@ def test_no_restart_fires_on_raft_cfg(checker, capsys):
 def test_each_group_has_a_scope_of_its_own_under_expand(device_run):
     """`expand/Restart`, `expand/HandleMessage`, ...: a group's segment
     slice, row gather, parameter selects and kernels are under the
-    group's name, opened outside the kernels' vmap, and the benchmark's
+    group's name, opened outside the kernels' vmap and, since PR 56,
+    the loop over the tiles of what the group keeps, and the benchmark's
     rule reads it as the stage's second level, which is what
     `expand_restart_share` and `stage_split.py`'s `expand_by_group_s`
     sum."""
@@ -468,11 +469,12 @@ def test_each_group_has_a_scope_of_its_own_under_expand(device_run):
             if stack.startswith(f"expand/{name}")))
         assert {"dynamic_slice", "gather"} <= under, name
         # the kernels run inside the scope, not beside it
-        assert any(stack.startswith(f"expand/{name}/vmap()")
+        assert any(stack.startswith(f"expand/{name}/while/body/vmap()")
                    for stack in found), name
     assert not any(stack.startswith("expand/vmap()/Restart")
                    for stack in found)
-    stack = "jit(_wave_step)/while/body/expand/Restart/vmap()/select_n:"
+    stack = ("jit(_wave_step)/while/body/expand/Restart/while/body/vmap()/"
+             "select_n:")
     assert xplane.scope_path(stack) == ("expand", "Restart")
     spec = load(BENCH, "layer_metrics", "expand_restart_share.json")
     assert re.match(spec["reduce"]["regex"],

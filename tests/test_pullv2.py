@@ -489,11 +489,13 @@ def test_handlemessage_has_a_scope_of_its_own_that_the_new_metric_reads(
     stacks = {str(e.source_info.name_stack)
               for e in eqns(jax.make_jaxpr(prog["fn"])(*prog["args"]).jaxpr)}
     for name, _n in GROUPS:
-        assert any(s.startswith(f"expand/{name}/vmap()") for s in stacks), name
+        assert any(s.startswith(f"expand/{name}/while/body/vmap()")
+                   for s in stacks), name
     rx = re.compile(_load(
         BENCH, "layer_metrics", "expand_handlemessage_share.json")[
             "reduce"]["regex"])
-    stack = "jit(_wave_step)/while/body/expand/{}/vmap()/select_n:"
+    stack = ("jit(_wave_step)/while/body/expand/{}/while/body/vmap()/"
+             "select_n:")
     named = lambda g: xplane.scoped_name(
         stack.format(g), "%fusion.7 = fusion()")
     assert rx.search(named("HandleMessage"))

@@ -194,7 +194,8 @@ def _expand_and_apply(model, states):
         dst = jnp.where(vflat, jnp.cumsum(vflat) - 1, C * A)
         sel = (jnp.full((C * A + 1,), C * A, jnp.int32)
                .at[dst].set(jnp.arange(C * A, dtype=jnp.int32))[:C * A])
-        rows, apply_ovf = model.sparse_apply(batch, sel, sel < C * A, plan)
+        rows, apply_ovf, _ = model.sparse_apply(
+            batch, sel, sel < C * A, plan)
         return dict(succs=succs, valid=valid, rank=rank, ovf=ovf, sel=sel,
                     sparse_rows=rows, apply_ovf=apply_ovf[None])
 
