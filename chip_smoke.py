@@ -1,7 +1,12 @@
 #!/usr/bin/env python
 """Chip smoke: the checker's main path, once, on the accelerator.
 
-    python chip_smoke.py
+    python chip_smoke.py [LEGS]
+
+LEGS is the letters of the legs to run, `chip_smoke.py AK` or
+`chip_smoke.py A K`; none runs them all. Each leg has the deadline to
+itself (ROADMAP D20: eleven legs under one no longer fit a call, and leg
+K alone compiles four wave programs).
 
 Drives `python -m raft_tpu` — the entry point a user calls — on the
 reference configuration at its published constants and checks every
@@ -72,6 +77,15 @@ count against the pure-Python oracle's golden
          14 against tests/golden/pullv2_cfg_depth_counts.json: leg G's
          model file with the variant's branches taken, at its cell's
          chunk.
+  leg K  configs/standard-raft/Raft.cfg again, as the benchmark's cell
+         raft3-deep-cross runs it: at the cell's frontier and journal
+         (benchmark/workloads/raft3-deep-cross.json) to the cell's
+         depth, the first wave that runs entirely against a seen run
+         past the merge-or-search crossover, against
+         benchmark/goldens/raft3-deep.json: the seen run's four sizes,
+         the merges that step it up and the binary search at 65,536
+         queries a chunk-step inside the wave program, which no other
+         leg reaches.
 
 This process never imports jax or raft_tpu: a chip belongs to one process
 at a time, so every leg is a child of its own, one after the other, and
@@ -83,6 +97,7 @@ a passing run is one JSON object naming the device as JAX reports it.
 
 from __future__ import annotations
 
+import functools
 import json
 import os
 import signal
@@ -120,12 +135,17 @@ FSYNC_CFG = os.path.join(ROOT, "configs", "raft-and-fsync", "RaftFsync.cfg")
 PULLV2_GOLDEN = os.path.join(
     ROOT, "tests", "golden", "pullv2_cfg_depth_counts.json")
 PULLV2_CFG = os.path.join(ROOT, "configs", "pull-raft", "PullRaftVariant2.cfg")
+DEEP_GOLDEN = os.path.join(ROOT, "benchmark", "goldens", "raft3-deep.json")
+DEEP_CELL = os.path.join(
+    ROOT, "benchmark", "workloads", "raft3-deep-cross.json")
+DEEP_TRAFFIC = os.path.join(ROOT, "benchmark", "traffic")
 UNSAFE_CFG = os.path.join(
     ROOT, "configs", "flexible-raft", "unsafe-quorums", "FlexibleRaft.cfg")
 SCHEMA_CHECK = os.path.join(ROOT, "scripts", "check_metrics_schema.py")
-# the whole script must finish inside 1200 s; each child gets what is left
+LEGS = "ABCDEFGHIJK"
+# a leg must finish inside 1200 s; each of its children gets what is left
 DEADLINE_S = 1150.0
-T0 = time.monotonic()
+T0 = time.monotonic()  # of the leg that runs: main() sets it at each
 
 
 class SmokeFailure(Exception):
@@ -308,23 +328,60 @@ def leg_c(dev: dict, golden: dict) -> None:
 
 
 def cfg_leg(letter: str, cfg: str, chunk: int, dev: dict, golden: dict,
-            flags: tuple = ()) -> None:
-    """Legs D to J: another model file's cfg through the CLI to its
-    golden's depth, at its cell's chunk, with the flags the cfg needs."""
+            flags: tuple = (), frontier_cap: int = 65536) -> None:
+    """Legs D to K: a cfg through the CLI to its golden's depth, at its
+    cell's chunk, with the flags the cfg needs."""
     depth = golden["max_depth"]
     res = bfs_leg(f"leg{letter}", dev, golden,
-                  ["--checker", "tpu", "--frontier-cap", "65536", *flags],
+                  ["--checker", "tpu", "--frontier-cap", str(frontier_cap),
+                   *flags],
                   depth, 1, cfg=cfg, chunk=chunk)
     print(f"leg {letter} ok: {os.path.basename(cfg)} to depth {depth}, "
           f"{res['distinct']} distinct / {res['total']} generated "
           f"(run wall {res['wall_s']} s)")
 
 
-def main() -> int:
+def golden_leg(letter: str, dev: dict, cfg: str, chunk: int, path: str,
+               flags: tuple) -> None:
+    """Legs D to J, each against its own file of tests/golden/."""
+    with open(path) as f:
+        cfg_leg(letter, cfg, chunk, dev, json.load(f)["depth_limited"],
+                flags)
+
+
+def deep_cell() -> tuple[dict, dict]:
+    """(engine parameters, golden in a leg's shape) of the benchmark's
+    cell raft3-deep-cross: the cell's depth is its traffic mix's, the
+    counts to it the benchmark's golden's."""
+    with open(DEEP_CELL) as f:
+        cell = json.load(f)
+    with open(os.path.join(DEEP_TRAFFIC, f"{cell['traffic']}.json")) as f:
+        depth = json.load(f)["max_depth"]
+    with open(DEEP_GOLDEN) as f:
+        golden = json.load(f)
+    return cell["engine_params"], {
+        "max_depth": depth, "msg_slots": golden["msg_slots"],
+        "depth_counts": golden["depth_counts"][: depth + 1],
+        **golden["totals"][str(depth)]}
+
+
+def leg_k(dev: dict) -> None:
+    params, golden = deep_cell()
+    cfg_leg("K", RAFT_CFG, params["chunk"], dev, golden,
+            ("--journal-cap", str(params["journal_cap"])),
+            frontier_cap=params["frontier_cap"])
+
+
+def main(legs: str = LEGS) -> int:
+    global T0
+    legs = legs.upper()
     try:
+        check(bool(legs) and set(legs) <= set(LEGS),
+              f"legs {legs!r}: choose from {LEGS}")
         for path in (GOLDEN, JOINT_GOLDEN, KRAFT_GOLDEN, KRAFTRC_GOLDEN,
                      PULL_GOLDEN, ADDREMOVE_GOLDEN, FSYNC_GOLDEN,
-                     PULLV2_GOLDEN, TRACE_GOLDEN, RAFT_CFG, JOINT_CFG,
+                     PULLV2_GOLDEN, DEEP_GOLDEN, DEEP_CELL, TRACE_GOLDEN,
+                     RAFT_CFG, JOINT_CFG,
                      KRAFT_CFG, KRAFTRC_CFG, PULL_CFG, ADDREMOVE_CFG,
                      FSYNC_CFG, PULLV2_CFG, UNSAFE_CFG, SCHEMA_CHECK,
                      os.path.join(ROOT, "raft_tpu", "__main__.py")):
@@ -335,10 +392,9 @@ def main() -> int:
         dev = probe_device()
         with open(GOLDEN) as f:
             golden = json.load(f)["depth_limited"]
-        leg_a(dev, golden)
-        leg_b(dev)
-        leg_c(dev, golden)
-        for letter, cfg, chunk, path, flags in (
+        runs = {"A": lambda: leg_a(dev, golden), "B": lambda: leg_b(dev),
+                "C": lambda: leg_c(dev, golden), "K": lambda: leg_k(dev)}
+        for letter, *its in (
                 ("D", JOINT_CFG, 1024, JOINT_GOLDEN, ()),
                 ("E", KRAFT_CFG, 2048, KRAFT_GOLDEN, ()),
                 # upstream's cfg declares v1 and uses v2
@@ -350,9 +406,11 @@ def main() -> int:
                 ("I", FSYNC_CFG, 2048, FSYNC_GOLDEN, ()),
                 # the same undeclared v2 as leg G's cfg
                 ("J", PULLV2_CFG, 2048, PULLV2_GOLDEN, ("--lenient",))):
-            with open(path) as f:
-                cfg_leg(letter, cfg, chunk, dev,
-                        json.load(f)["depth_limited"], flags)
+            runs[letter] = functools.partial(golden_leg, letter, dev, *its)
+        for letter in LEGS:
+            if letter in legs:
+                T0 = time.monotonic()  # the deadline is the leg's
+                runs[letter]()
     except SmokeFailure as e:
         print(f"chip_smoke: FAILED: {e}", file=sys.stderr)
         return 1
@@ -363,4 +421,4 @@ def main() -> int:
 
 
 if __name__ == "__main__":
-    sys.exit(main())
+    sys.exit(main("".join(sys.argv[1:]) or LEGS))

@@ -131,15 +131,16 @@ class DeviceBFS(FleetQueue):
     # the in-program stats vector, i64[N_STATS]: [wave new count, journal
     # count, cumulative generated, cumulative terminal, overflow bits,
     # then cumulative canon counts: in-chunk duplicates, tier-3 local
-    # lanes, tier-3 full lanes; last the dedup stage's two counts, each
+    # lanes, tier-3 full lanes; then the dedup stage's two counts, each
     # summed over the wave's chunk-steps: the lanes its merged sort
-    # sorted and the query lanes it searched an occupied run with; and
-    # the successor rows the wave's apply passes built
-    # (SparseExpandMixin.sparse_apply's count)].
+    # sorted and the query lanes it searched an occupied run with; the
+    # successor rows the wave's apply passes built
+    # (SparseExpandMixin.sparse_apply's count); and last the dedup
+    # stage's third count, the chunk-steps that searched].
     # STATS_KEEP is what a wave's start keeps of it (the wave-new,
     # overflow, dedup and built-rows lanes reset in-program).
-    N_STATS = 11
-    STATS_KEEP = (0, 1, 1, 1, 0, 1, 1, 1, 0, 0, 0)
+    N_STATS = 12
+    STATS_KEEP = (0, 1, 1, 1, 0, 1, 1, 1, 0, 0, 0, 0)
 
     # Donation contract for the wave program: argument indices of
     # the capacity-shaped loop carries updated in place every dispatch
@@ -388,12 +389,13 @@ class DeviceBFS(FleetQueue):
         search, under ``occ``, only where no rung does. A fingerprint
         chunk k appended is a run lane for chunk k + 1, so cross-chunk
         in-wave dedup falls out of the same lookup. Returns (new,
-        i32[2]: the lanes that sort sorted and the query lanes searched
-        against an occupied run)."""
+        i32[3]: the lanes that sort sorted, the query lanes searched
+        against an occupied run, and 1 if the step searched at all)."""
         new, lanes, queries = first_new(
             fps, occ, runs, wave=(wave_new, ncount, self._wave_prefix()),
             real=(seen_real, self._rungs(runs[0].shape[0])))
-        return new, jnp.stack([lanes, queries])
+        return new, jnp.stack(
+            [lanes, queries, (queries > 0).astype(jnp.int32)])
 
     @stage("emit")
     def _st_finish(
@@ -511,8 +513,9 @@ class DeviceBFS(FleetQueue):
                 stats[3] + terminal,
                 stats[4] | ovf_bits,
                 *(stats[5:8] + canon_n),
-                *(stats[8:10] + dedup_n),
+                *(stats[8:10] + dedup_n[:2]),
                 stats[10] + rows_built,
+                stats[11] + dedup_n[2],
             ]
         )
         return next_buf, jparent, jcand, viol, stats, cov, wave_new
@@ -929,7 +932,8 @@ class DeviceBFS(FleetQueue):
         metrics: list[dict] | None = [] if collect_metrics else None
         last_ckpt = time.perf_counter()
 
-        sort_lanes_run = search_queries_run = peak_rows = 0
+        sort_lanes_run = search_queries_run = search_steps_run = 0
+        peak_rows = 0
         # the apply pass: the rows its tiles built and the rows its plan
         # budgets, a chunk-step
         rows_built_run = rows_budget_run = 0
@@ -1102,6 +1106,7 @@ class DeviceBFS(FleetQueue):
             sort_lanes_run += int(stats_h[8])
             search_queries_run += int(stats_h[9])
             rows_built_run += int(stats_h[10])
+            search_steps_run += int(stats_h[11])
             wave_budget = plan_rows * -(-prev_fcount // self.chunk)
             rows_budget_run += wave_budget
             wave_s_val = time.perf_counter() - tw
@@ -1142,12 +1147,14 @@ class DeviceBFS(FleetQueue):
                     # fetched): the seen run, the prefix of the wave's
                     # buffer each step chose and VC; the query lanes
                     # those steps searched the seen run with (lane 9; 0
-                    # while the run is merged); and the run's size as
+                    # while the run is merged) and how many of the steps
+                    # searched (lane 11); and the run's size as
                     # the wave met it; then the successor rows the
                     # wave's apply passes built (lane 10) beside the
                     # rows their plan budgets, sum(plan) a chunk-step
                     dedup_sort_lanes=int(stats_h[8]),
                     dedup_search_queries=int(stats_h[9]),
+                    dedup_search_steps=int(stats_h[11]),
                     seen_lanes=seen_lanes,
                     expand_rows_built=int(stats_h[10]),
                     expand_rows_budget=wave_budget,
@@ -1189,6 +1196,7 @@ class DeviceBFS(FleetQueue):
             canon_tier3_full=int(canon_prev[2]),
             dedup_sort_lanes=sort_lanes_run,
             dedup_search_queries=search_queries_run,
+            dedup_search_steps=search_steps_run,
             expand_rows_built=rows_built_run,
             expand_rows_budget=rows_budget_run,
         )

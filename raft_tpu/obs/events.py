@@ -126,6 +126,9 @@ TIMELINE_STAGES = (
 # stage handed to the binary search (util.probe_sorted), summed over the
 # wave's chunk-steps: a step's VC lanes once the occupied seen run is
 # past the merge-or-search crossover (util.merges), 0 while it is merged.
+# dedup_search_steps (lane 11): the wave's chunk-steps that searched, so
+# dedup_search_queries is VC times it on a one-run engine; on the rows,
+# on stats and on the summary.
 # seen_lanes: the lanes of the seen run the wave ran against (its size
 # before the wave's own merge, which may step it up), so a trace says
 # which waves merged and which searched.
@@ -446,6 +449,7 @@ def validate_event(ev: object, lineno: int | None = None) -> list[str]:
             )
         for key, what in (
             ("dedup_search_queries", "query lanes the dedup stage searched"),
+            ("dedup_search_steps", "chunk-steps whose dedup stage searched"),
             ("seen_lanes", "lanes of the seen run the wave ran against"),
             ("expand_rows_built", "successor rows the apply pass built"),
             ("expand_rows_budget", "successor rows the apply's plan budgets"),

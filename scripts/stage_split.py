@@ -158,6 +158,7 @@ def main(argv=None):
         res["dedup_plan"] = got["stats"].get("dedup_plan")
         res["dedup_sort_lanes"] = got["stats"].get("dedup_sort_lanes")
         res["dedup_search_queries"] = got["stats"].get("dedup_search_queries")
+        res["dedup_search_steps"] = got["stats"].get("dedup_search_steps")
         res["expand_rows_built"] = got["stats"].get("expand_rows_built")
         res["expand_rows_budget"] = got["stats"].get("expand_rows_budget")
         res["frontier_peak_rows"] = got["stats"].get("frontier_peak_rows")

@@ -66,7 +66,12 @@ def test_golden_file_is_self_consistent():
     assert len(d["depth_counts"]) == d["max_depth"] + 1
     assert sum(d["depth_counts"]) == d["distinct"]
     assert "oracle" in d["source"] and "oracle_golden.py" in d["command"]
-    assert g["exhaustive"]["source"].startswith("engine on CPU")
+    # the whole space: the oracle's since PR 57, with the chip's seconds
+    ex = g["exhaustive"]
+    assert "oracle" in ex["source"] and "oracle_golden.py" in ex["command"]
+    assert (ex["distinct"], ex["total"], ex["depth"], ex["terminal"]) == (
+        8664032, 30708266, 48, 19514)
+    assert ex["chip_seconds"]["cold"] > ex["chip_seconds"]["device_work_s"] > 0
 
 
 def test_unsafe_quorums_cfg_violates_with_golden_trace(capsys):
@@ -267,3 +272,70 @@ def test_chip_smoke_second_lowerings_legs_run_their_own_cfg_and_golden(
         f"leg{letter}", golden,
         ["--checker", "tpu", "--frontier-cap", "65536", *flags], max_depth, 1)
     assert kw == {"cfg": cfg, "chunk": chunk}
+
+
+def test_chip_smoke_leg_k_takes_the_deep_cells_job_from_its_files(
+        smoke, monkeypatch):
+    """Leg K: Raft.cfg at the capacities and to the depth of the
+    benchmark's cell raft3-deep-cross, against the benchmark's golden;
+    the leg's own checks at a depth the CPU affords, then its arguments."""
+    chip_smoke, dev = smoke
+    params, golden = chip_smoke.deep_cell()
+    cell = json.loads(Path(chip_smoke.DEEP_CELL).read_text())
+    deep = json.loads(Path(chip_smoke.DEEP_GOLDEN).read_text())
+    depth = golden["max_depth"]
+    assert params == cell["engine_params"]
+    assert cell["traffic"] == f"init-d{depth}-warm{depth}"
+    assert golden == {
+        "max_depth": depth, "msg_slots": 32,
+        "depth_counts": deep["depth_counts"][: depth + 1],
+        **deep["totals"][str(depth)]}
+    small = {**golden, "max_depth": 8, **deep["totals"]["8"]}
+    caps = ["--checker", "tpu", "--frontier-cap", "4096",
+            "--journal-cap", "16384"]
+    obs = chip_smoke.bfs_leg("K", dev, small, caps, 8, 1, chunk=256)
+    assert (obs["distinct"], obs["total"]) == (446, 953)
+    with pytest.raises(chip_smoke.SmokeFailure, match="total 953 != golden"):
+        chip_smoke.bfs_leg(
+            "K-bad", dev, dict(small, total=954), caps, 8, 1, chunk=256)
+    calls = []
+    monkeypatch.setattr(
+        chip_smoke, "bfs_leg",
+        lambda *a, **kw: calls.append((a, kw)) or dict(obs))
+    monkeypatch.setattr(chip_smoke, "probe_device", lambda: dev)
+    assert chip_smoke.main("k") == 0
+    ((a, kw),) = calls
+    assert a[:1] + a[2:] == (
+        "legK", golden,
+        ["--checker", "tpu", "--frontier-cap", str(params["frontier_cap"]),
+         "--journal-cap", str(params["journal_cap"])], depth, 1)
+    assert kw == {"cfg": chip_smoke.RAFT_CFG, "chunk": params["chunk"]}
+
+
+def test_chip_smoke_runs_the_legs_asked_for_each_under_its_own_deadline(
+        smoke, monkeypatch, capsys):
+    """`chip_smoke.py CA`: legs A and C, in the script's order, the
+    deadline's clock set anew at each; no argument is every leg; an
+    unknown letter fails before anything runs (ROADMAP D20)."""
+    chip_smoke, dev = smoke
+    ran = []
+    for leg in "abck":
+        monkeypatch.setattr(
+            chip_smoke, f"leg_{leg}",
+            lambda *a, leg=leg: ran.append((leg.upper(), chip_smoke.T0)))
+    monkeypatch.setattr(
+        chip_smoke, "cfg_leg",
+        lambda letter, *a, **kw: ran.append((letter, chip_smoke.T0)))
+    monkeypatch.setattr(chip_smoke, "probe_device", lambda: dev)
+    assert chip_smoke.main("CA") == 0
+    assert [leg for leg, _ in ran] == ["A", "C"]
+    assert ran[0][1] < ran[1][1]
+    del ran[:]
+    assert chip_smoke.main() == 0
+    assert "".join(leg for leg, _ in ran) == chip_smoke.LEGS == "ABCDEFGHIJK"
+    assert sorted(t for _, t in ran) == [t for _, t in ran]
+    assert len({t for _, t in ran}) == len(ran)
+    del ran[:]
+    assert chip_smoke.main("AZ") == 1 and not ran
+    assert "choose from ABCDEFGHIJK" in capsys.readouterr().err
+

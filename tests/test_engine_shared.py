@@ -45,7 +45,17 @@ equations more in a wave program, five fewer than that in a chunk
 program, which drops the count. Every row of every in-budget worklist
 lane, the overflow bits and every count bit-equal to PR 55's tree
 (``tests/test_expand_compaction.py``'s ``_reference_sparse_apply``;
-``stage_diff.py``'s stages against the parent's dump).
+``stage_diff.py``'s stages against the parent's dump). PR 57 re-pinned
+the seven wave programs, on purpose, and no chunk program: the device
+engine's stats vector has a twelfth lane, the chunk-steps whose dedup
+stage searched the seen run (``dedup_search_steps``: one compare of the
+step's searched query lanes with 0, its convert, a third operand of the
+dedup stage's stack, and the slices and the add that fold it, eleven
+equations more in every wave program); lanes 0 to 10, every row and
+every count as they were (``stage_diff.py`` on the chip, ALL STAGES
+EQUAL; the lowered text of ``raft3-wide``'s and ``pull3-full``'s wave
+programs against the parent's differs in those lines alone, PERF.md
+section 6).
 Nothing is compiled or run.
 
 A PR that means to change a program re-pins its digest on purpose, says
@@ -70,34 +80,35 @@ ENGINES = {"device": DeviceBFS, "sharded": ShardedBFS}
 # the seen merge has no model in it: one digest for every family
 SEEN_MERGE = (12, "19ac660b935d83db")
 
-# {family: {engine: {program: (equations, digest)}}} at PR 56's tree
+# {family: {engine: {program: (equations, digest)}}} at PR 56's tree,
+# the wave programs at PR 57's
 PARENT_PROGRAMS = {
     "raft": {
-        "device": {"wave": (5081, "fd71131f4988c26d"),
+        "device": {"wave": (5092, "01c2e2601ae85420"),
                    "seen_merge": SEEN_MERGE},
         "sharded": {"chunk": (5473, "2e2723c1286523e4")}},
     "raft-dense": {
-        "device": {"wave": (3611, "c45a2f745ea0f2fe"),
+        "device": {"wave": (3622, "4befee39e8dab41f"),
                    "seen_merge": SEEN_MERGE},
         "sharded": {"chunk": (4004, "0543045755cbcf6f")}},
     "pull_raft": {
-        "device": {"wave": (5415, "10950deba96e8a0d"),
+        "device": {"wave": (5426, "89c616504dc1e531"),
                    "seen_merge": SEEN_MERGE},
         "sharded": {"chunk": (5807, "a923a619c5d67983")}},
     "kraft": {
-        "device": {"wave": (6754, "11dee5598087e3ea"),
+        "device": {"wave": (6765, "e08b738292c434b5"),
                    "seen_merge": SEEN_MERGE},
         "sharded": {"chunk": (7146, "958c32a38d91af06")}},
     "joint_raft": {
-        "device": {"wave": (11199, "74543251b72d4e28"),
+        "device": {"wave": (11210, "f02504aecf396e2e"),
                    "seen_merge": SEEN_MERGE},
         "sharded": {"chunk": (11591, "b134551dbdaa8f1a")}},
     "kraft_reconfig": {
-        "device": {"wave": (15207, "fa04d9286b841468"),
+        "device": {"wave": (15218, "d38aac2e96b2eea1"),
                    "seen_merge": SEEN_MERGE},
         "sharded": {"chunk": (15599, "7c18ccfab891ea11")}},
     "reconfig_raft": {
-        "device": {"wave": (10852, "5e6a34885911c755"),
+        "device": {"wave": (10863, "7203cf21b208655b"),
                    "seen_merge": SEEN_MERGE},
         "sharded": {"chunk": (11244, "b2a6225c29a55675")}},
 }

@@ -99,6 +99,8 @@ def test_device_metrics_stream_valid_and_count_accurate(tmp_path):
     # which run its wave met
     assert summ["dedup_search_queries"] == 0 == sum(
         w["dedup_search_queries"] for w in waves)
+    assert summ["dedup_search_steps"] == 0 == sum(
+        w["dedup_search_steps"] for w in waves)
     assert {w["seen_lanes"] for w in waves} == {summ["seen_lanes"]}
     # the apply pass's counters: the rows its tiles built follow what a
     # wave's chunks keep, under the rows its plan budgets
@@ -271,7 +273,8 @@ _TRACED_RUN = (*_RUN_LOADED, "init_s", "waves_s", "finish_s", "programs",
                *HBM_KEYS)
 ROW_OWN = {
     "device": (*_PHASES, "dedup_sort_lanes", "dedup_search_queries",
-               "seen_lanes", "expand_rows_built", "expand_rows_budget"),
+               "dedup_search_steps", "seen_lanes", "expand_rows_built",
+               "expand_rows_budget"),
     "host": (),
     "sharded": (*_PHASES, "a2a_lanes", "a2a_bytes", "shard_new",
                 "shard_new_min", "shard_new_max"),
@@ -279,8 +282,8 @@ ROW_OWN = {
 }
 SUMMARY_OWN = {
     "device": (*_TRACED_RUN, "dedup_plan", "dedup_sort_lanes",
-               "dedup_search_queries", "expand_rows_built",
-               "expand_rows_budget"),
+               "dedup_search_queries", "dedup_search_steps",
+               "expand_rows_built", "expand_rows_budget"),
     "host": _TRACED_RUN,
     "sharded": (*_TRACED_RUN, "dedup_plan", "shard_dup_lanes",
                 "shard_skew"),
@@ -290,7 +293,8 @@ SUMMARY_OWN = {
 STATS_OWN = {  # beyond obs/compiles.py run_stats and the top spans
     "device": {"dedup_plan", "canon_tier3_local", "canon_tier3_full",
                "dedup_sort_lanes", "dedup_search_queries",
-               "expand_rows_built", "expand_rows_budget"},
+               "dedup_search_steps", "expand_rows_built",
+               "expand_rows_budget"},
     "host": set(),
     "sharded": {"dedup_plan", "canon_tier3_local", "canon_tier3_full",
                 "canon_dup_lanes", "canon_dup_rate", "shard_dup_lanes",
@@ -634,6 +638,8 @@ def test_wave_tier_counters_schema_rule():
     ("dedup_sort_lanes", True, "non-negative int"),
     ("dedup_search_queries", -1, "non-negative int"),
     ("dedup_search_queries", 1.5, "non-negative int"),
+    ("dedup_search_steps", -1, "non-negative int"),
+    ("dedup_search_steps", True, "non-negative int"),
     ("seen_lanes", -1, "non-negative int"),
     ("seen_lanes", True, "non-negative int"),
     ("expand_rows_built", -1, "non-negative int"),
@@ -646,7 +652,8 @@ def test_wave_dedup_sort_lanes_schema_rule(key, value, says):
 
     ev = dict.fromkeys(WAVE_KEYS, 0)
     ev.update(event="wave", dedup_sort_lanes=327680,
-              dedup_search_queries=65536, seen_lanes=1 << 22,
+              dedup_search_queries=65536, dedup_search_steps=1,
+              seen_lanes=1 << 22,
               expand_rows_built=28672, expand_rows_budget=151552)
     assert validate_event(ev) == []
     (problem,) = validate_event({**ev, key: value})
